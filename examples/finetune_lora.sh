@@ -7,7 +7,7 @@
 #   bash examples/finetune_lora.sh [workdir]
 set -euo pipefail
 cd "$(dirname "$0")/.."
-export PSDT_PLATFORM="${PSDT_PLATFORM:-cpu}"
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 WORK="${1:-/tmp/psdt_lora_example}"
 STEPS="${STEPS:-40}"

@@ -12,7 +12,7 @@
 # run the same flow at full scale.
 set -euo pipefail
 cd "$(dirname "$0")/.."
-export PSDT_PLATFORM="${PSDT_PLATFORM:-cpu}"
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 WORK="${1:-/tmp/psdt_hf_example}"
 STEPS="${STEPS:-30}"

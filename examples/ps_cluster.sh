@@ -9,7 +9,10 @@
 #   bash examples/ps_cluster.sh
 set -euo pipefail
 cd "$(dirname "$0")/.."
-export PSDT_PLATFORM="${PSDT_PLATFORM:-cpu}"
+# One process owns a chip.  This script starts three workers on one host,
+# so every process defaults to the CPU; on a one-chip host at most ONE
+# worker may be left unpinned (launch it by hand with JAX_PLATFORMS unset).
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 export PYTHONUNBUFFERED=1
 
 PORT_BASE="${PORT_BASE:-15750}"

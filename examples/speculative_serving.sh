@@ -8,7 +8,7 @@
 #   bash examples/speculative_serving.sh [workdir]
 set -euo pipefail
 cd "$(dirname "$0")/.."
-export PSDT_PLATFORM="${PSDT_PLATFORM:-cpu}"
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 WORK="${1:-/tmp/psdt_spec_example}"
 STEPS="${STEPS:-60}"

@@ -5,9 +5,10 @@
 #
 #   bash examples/train_to_serve.sh [workdir]
 #
-# Runs in a few minutes on a laptop CPU (PSDT_PLATFORM=cpu pins the host
-# backend on machines where a TPU plugin hijacks JAX_PLATFORMS); on a TPU
-# VM drop that export and raise STEPS/--batch.  Every command is the
+# Runs in a few minutes on a laptop CPU (JAX_PLATFORMS defaults to cpu
+# below); on a TPU VM run with JAX_PLATFORMS=tpu and raise STEPS/--batch
+# — the commands run one after another, so each owns the chip in turn.
+# Every command is the
 # installed console-script surface — nothing here imports the package
 # directly, so this is exactly what a user types.
 set -euo pipefail
@@ -16,7 +17,7 @@ cd "$(dirname "$0")/.."
 WORK="${1:-/tmp/psdt_example}"
 STEPS="${STEPS:-60}"
 mkdir -p "$WORK"
-export PSDT_PLATFORM="${PSDT_PLATFORM:-cpu}"
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 # -- 1. corpus: this package's own source is a fine byte-level dataset
 CORPUS="$WORK/corpus.txt"

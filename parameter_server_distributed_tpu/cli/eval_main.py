@@ -61,8 +61,10 @@ def main(argv: list[str] | None = None) -> int:
 
     from ..models.registry import get_model_and_batches
     from ..models.transformer import Transformer
+    from ..utils.compile_cache import enable_compile_cache
     from .generate_main import load_params, match_layout
 
+    enable_compile_cache()
     name = flags.get("model", "small_lm")
     batch = int(flags.get("batch", 32))
     steps = int(flags.get("steps", 16))

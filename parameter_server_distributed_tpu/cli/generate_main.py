@@ -192,7 +192,9 @@ def main(argv: list[str] | None = None) -> int:
     from ..models.generation import generate
     from ..models.registry import get_model_and_batches
     from ..models.transformer import Transformer
+    from ..utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     seed = int(flags.get("seed", 0))
     hf_tok = None
     if flags.get("hf-gpt2"):

@@ -93,6 +93,11 @@ def main(argv: list[str] | None = None) -> int:
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(levelname)s %(message)s")
     config, coordinator_addr = build_config(argv)
+    if config.optimizer.partition("_")[0] in ("device", "pallas", "sharded"):
+        # only a device optimizer compiles; the host PS stays jax-free
+        from ..utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
 
     live_fn = None
     if config.elastic and coordinator_addr:

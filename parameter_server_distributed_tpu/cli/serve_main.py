@@ -132,7 +132,10 @@ def main(argv: list[str] | None = None) -> int:
                          f"--help lists the accepted flags")
 
     from ..models.serving import DecodeServer
+    from ..utils.compile_cache import enable_compile_cache
     from .generate_main import load_hf, load_params, match_layout
+
+    enable_compile_cache()
 
     hf_tok = None
     if flags.get("hf-gpt2"):

@@ -151,7 +151,9 @@ def main(argv: list[str] | None = None) -> int:
             process_id=int(flags.get("process-id", 0)))
 
     from ..parallel.train_loop import TrainLoopConfig, run_training
+    from ..utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     config = TrainLoopConfig(
         model=flags.get("model", "mnist_mlp"),
         hf_gpt2=flags.get("hf-gpt2", ""),

@@ -56,6 +56,7 @@ import sys
 from .. import freerun as freerun_mod
 from ..config import WorkerConfig, parse_argv
 from ..models.registry import get_model_and_batches
+from ..utils.compile_cache import enable_compile_cache
 from ..worker.trainer import Trainer
 from ..worker.worker import Worker
 
@@ -91,6 +92,7 @@ def main(argv: list[str] | None = None) -> int:
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(levelname)s %(message)s")
     positional, flags = parse_argv(argv)
+    enable_compile_cache()
     config = WorkerConfig(
         coordinator_address=positional[0] if len(positional) > 0 else "127.0.0.1:50052",
         worker_id=int(positional[1]) if len(positional) > 1 else 0,

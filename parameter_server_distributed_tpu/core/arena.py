@@ -82,10 +82,10 @@ ENV_MAX_TENSOR = "PSDT_ARENA_MAX_TENSOR_BYTES"
 # per-tensor overhead per stage.  A store of big tensors is BANDWIDTH
 # bound, and on XLA:CPU's thunk runtime one fused sweep is ONE thunk
 # (one core) while the per-tensor batched stage parallelizes its
-# independent per-tensor ops across the pool — so stores above this
-# MEAN tensor size keep the per-tensor path (byte-identical anyway).
-# On a real accelerator a single fused sweep saturates the chip; raise
-# the bound (0 = no bound) there.
+# independent per-tensor ops across the pool — so ON A CPU BACKEND
+# stores above this MEAN tensor size keep the per-tensor path
+# (byte-identical anyway).  On an accelerator a single fused sweep
+# saturates the chip, so there the default is no bound.
 DEFAULT_MAX_TENSOR_BYTES = 2 << 20
 
 
@@ -103,9 +103,16 @@ def align_elems() -> int:
 
 
 def max_tensor_bytes() -> int:
-    """Mean-tensor-size regime bound; 0 disables the bound."""
-    return int(os.environ.get(ENV_MAX_TENSOR,
-                              str(DEFAULT_MAX_TENSOR_BYTES)))
+    """Mean-tensor-size regime bound; 0 disables the bound.  Unset, it
+    follows the backend the slabs live on: DEFAULT_MAX_TENSOR_BYTES on
+    a CPU backend, no bound on an accelerator."""
+    value = os.environ.get(ENV_MAX_TENSOR)
+    if value:
+        return int(value)
+    import jax
+
+    on_cpu = jax.devices()[0].platform == "cpu"
+    return DEFAULT_MAX_TENSOR_BYTES if on_cpu else 0
 
 
 # Close-path device dispatches per stripe (contributor-mean scale
@@ -513,6 +520,10 @@ class ArenaManager:
         # current store's mean tensor size keeps it on the per-tensor
         # path — re-evaluated whenever the table rebuilds
         self.gated = False
+        # resolved here, where the core has just brought the backend up:
+        # ensure_table runs under the core's locks and must not reach
+        # jax.devices() (a backend init can block)
+        self._max_tensor_bytes = max_tensor_bytes()
         self._obs_closes = obs_stats.counter("ps.apply.arena")
         self._obs_fallbacks = obs_stats.counter("ps.apply.arena_fallback")
         self._obs_pad = obs_stats.gauge("ps.apply.arena_pad")
@@ -565,7 +576,7 @@ class ArenaManager:
                     pad = self.table.padding_elems
                     total = max(1, self.table.total_elems)
                     self._obs_pad.set(round(pad / total, 4))
-                    bound = max_tensor_bytes()
+                    bound = self._max_tensor_bytes
                     mean = (4 * self.table.payload_elems
                             // max(1, len(self.table.entries)))
                     was_gated = self.gated

@@ -58,56 +58,19 @@ def enabled() -> bool:
 
 _available: bool | None = None
 
-ENV_XLA_TUNE = "PSDT_DEVICE_XLA_TUNE"
-_tuned = False
-
-
-def _ensure_cpu_tuning() -> None:
-    """One-time XLA:CPU tuning for the device-apply hot path, applied
-    only when this process is the FIRST jax user (flags are read at
-    backend init).  The legacy (non-thunk) CPU runtime parallel-
-    partitions large elementwise kernels across the intra-op pool —
-    measured ~1.9x the thunk runtime's single-stream sweep throughput
-    on this host's donated-buffer update chains, which is exactly what
-    the barrier close runs.  Rounding is unchanged (same LLVM codegen
-    per element; partitioning never re-associates an elementwise op),
-    re-proven by the oracle tests under the flag.  Respects an explicit
-    operator choice: any user-set thunk-runtime flag wins, and
-    ``PSDT_DEVICE_XLA_TUNE=0`` opts out entirely."""
-    global _tuned
-    if _tuned:
-        return
-    _tuned = True
-    if os.environ.get(ENV_XLA_TUNE, "1") in ("0", "false"):
-        return
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_cpu_use_thunk_runtime" in flags:
-        return  # operator already chose a runtime
-    try:
-        import sys
-
-        bridge = sys.modules.get("jax._src.xla_bridge")
-        if bridge is not None and getattr(bridge, "_backends", None):
-            return  # backend already initialized: flags are locked in
-    except Exception:  # noqa: BLE001 — introspection only
-        return
-    os.environ["XLA_FLAGS"] = (
-        flags + " --xla_cpu_use_thunk_runtime=false").strip()
-
 
 def available(refresh: bool = False) -> bool:
-    """True when a jax backend is importable and owns at least one
-    device.  Cached: the check can cost a backend initialization."""
+    """True when the jax backend owns at least one device.  Cached: the
+    check can cost a backend initialization.  Only code that was ASKED
+    for a device path calls this (a ``device_*``/``pallas_*``/
+    ``sharded_*`` optimizer, ``PSDT_DEVICE_APPLY``, ``PSDT_ARENA``), so
+    a backend that fails to come up raises here, at PS start, instead
+    of turning into a silent host path."""
     global _available
     if _available is None or refresh:
-        try:
-            if enabled():
-                _ensure_cpu_tuning()
-            import jax
+        import jax
 
-            _available = len(jax.devices()) > 0
-        except Exception:  # noqa: BLE001 — any backend failure means "no device"
-            _available = False
+        _available = len(jax.devices()) > 0
     return _available
 
 

@@ -39,7 +39,6 @@ device-resident partition.
 
 from __future__ import annotations
 
-import logging
 import threading
 from typing import Mapping
 
@@ -49,7 +48,6 @@ from ..native import (adam_native, adamw_native, lib as native_lib,
                       momentum_native, sgd_native)
 from .tensor import TensorStore
 
-log = logging.getLogger("pst.optimizer")
 
 _scratch_tls = threading.local()
 
@@ -414,26 +412,6 @@ class Lion(HostOptimizer):
                   for k, v in state.get("m", {}).items()}
 
 
-def _host_optimizer_for_rule(rule: str, learning_rate: float,
-                             momentum: float,
-                             weight_decay: float) -> HostOptimizer | None:
-    """The host optimizer matching a device-family update rule — the
-    downgrade target when accelerator selection fails (``adamw_bf16``
-    maps to plain AdamW: the bf16 slots were an HBM optimization, not a
-    different rule).  None for a rule no host optimizer implements."""
-    if rule == "sgd":
-        return SGD(learning_rate)
-    if rule == "momentum":
-        return Momentum(learning_rate, momentum)
-    if rule == "adam":
-        return Adam(learning_rate)
-    if rule in ("adamw", "adamw_bf16"):
-        return AdamW(learning_rate, weight_decay)
-    if rule == "lion":
-        return Lion(learning_rate, weight_decay=weight_decay)
-    return None
-
-
 def _make_accelerator_optimizer(kind: str, rule: str, learning_rate: float,
                                 momentum: float,
                                 weight_decay: float) -> HostOptimizer | None:
@@ -482,13 +460,12 @@ def make_optimizer(name: str, learning_rate: float, momentum: float = 0.9,
     accelerator-resident apply without renaming (flag off: exactly the
     pre-existing optax family, whole-store serial).
 
-    Accelerator selection failures — no jax backend, no device, an
-    import error — degrade to the MATCHING host optimizer (same rule,
-    same hyperparameters, ``adamw_bf16`` → AdamW) with a logged
-    ``ps.apply.device_fallback`` counter instead of raising at PS boot:
-    a mis-provisioned host must come up training, just slower.  An
-    unknown RULE still raises — a typo must never silently train with a
-    different update rule."""
+    A name that asks for an accelerator optimizer and cannot be built —
+    no jax backend, no device, an import error, a constructor failure —
+    RAISES, with the cause: the matching host optimizer is what the
+    plain name is for, and a PS that quietly trains on the host under a
+    device name reports numbers nobody asked for.  An unknown RULE
+    raises too — a typo must never train with a different update rule."""
     name = name.lower()
     if name == "sgd":
         return SGD(learning_rate)
@@ -504,38 +481,21 @@ def make_optimizer(name: str, learning_rate: float, momentum: float = 0.9,
     if rule and kind in ("device", "pallas", "sharded"):
         from . import device_apply
 
-        reason = None
-        if not device_apply.available():
-            reason = "no jax backend/device"
-        else:
-            try:
-                # inside the try: on a host without jax/optax this
-                # import itself raises, and that is a selection failure
-                # to degrade from, not a boot error
-                if kind == "device" and device_apply.enabled():
-                    from ..async_sgd.device_optimizer import (
-                        ShardedDeviceOptimizer)
-                    if rule in ShardedDeviceOptimizer.RULES:
-                        kind = "sharded"
-                opt = _make_accelerator_optimizer(kind, rule, learning_rate,
-                                                  momentum, weight_decay)
-                if opt is not None:
-                    return opt
-            except Exception as exc:  # noqa: BLE001 — any construction
-                # failure (backend init, pallas/optax import) means
-                # "degrade", not "refuse to boot the parameter server"
-                reason = f"{type(exc).__name__}: {exc}"
-        if reason is not None:
-            host = _host_optimizer_for_rule(rule, learning_rate, momentum,
-                                            weight_decay)
-            if host is not None:
-                from ..obs import flight
-                from ..obs import stats as obs_stats
-
-                obs_stats.counter("ps.apply.device_fallback").add()
-                flight.record("apply.device.fallback", note=reason[:48])
-                log.warning(
-                    "optimizer %r unavailable (%s); degrading to host %s",
-                    name, reason, type(host).__name__)
-                return host
+        try:
+            if not device_apply.available():
+                raise RuntimeError("the jax backend has no device")
+            if kind == "device" and device_apply.enabled():
+                from ..async_sgd.device_optimizer import (
+                    ShardedDeviceOptimizer)
+                if rule in ShardedDeviceOptimizer.RULES:
+                    kind = "sharded"
+            opt = _make_accelerator_optimizer(kind, rule, learning_rate,
+                                              momentum, weight_decay)
+        except Exception as exc:
+            raise RuntimeError(
+                f"optimizer {name!r} was requested but cannot be built "
+                f"({type(exc).__name__}: {exc}); use {rule!r} for the "
+                f"host optimizer") from exc
+        if opt is not None:
+            return opt
     raise ValueError(f"unknown optimizer {name!r}")

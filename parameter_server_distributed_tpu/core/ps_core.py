@@ -391,8 +391,7 @@ class ParameterServerCore:
         self._obs_parallelism = obs_stats.gauge("ps.apply.parallelism")
         # accelerator-resident applies (ISSUE 11): count of barrier
         # closes whose fresh store is device-resident (the pst-status
-        # "device apply" rollup line reads this next to the
-        # ps.apply.device_fallback selection-downgrade counter)
+        # "device apply" rollup line reads this)
         self._obs_device_applies = obs_stats.counter("ps.apply.device")
         # Barrier-completion broadcast over _state_lock: the fused data
         # plane (PushPullStream) parks here and is woken the instant an

@@ -866,9 +866,9 @@ def optimal_draft_depth(accept_frac: float, k: int, k_max: int,
     toward deeper drafts (fewer rounds, less excess overhead).
     ``accept_frac`` is the measured fraction at the CURRENT depth k
     (inverted to per-proposal agreement p first — fractions are not
-    comparable across depths).  This model reproduces the round-4
-    measurements: p=0.57, rho~1/3 -> k* in {1, 2} at ~1.2x, k=4 scoring
-    ~0.9x (the observed 0.76x over-speculation loss)."""
+    comparable across depths).  At p=0.57 and rho~1/3 the model picks
+    k* in {1, 2} and scores k=4 below 1 (the over-speculation regime);
+    the speed of either is not measured on the chip."""
     p = _invert_accept_fraction(accept_frac, k)
     best_k, best = 1, -1.0
     for j in range(1, max(1, k_max) + 1):
@@ -1112,9 +1112,10 @@ def _speculative_adaptive(target, tparams, draft, dparams, prompt,
     controller re-picks the depth k via :func:`optimal_draft_depth`:
     invert the segment's accept fraction to per-proposal agreement p,
     then argmax expected-tokens/round-cost over 1..k_max with the
-    caller-measured draft/target ``cost_ratio``.  Fixed k=4 at accept
-    0.36 measured 0.76x vs greedy (round 4): this controller lands on
-    the profitable depth instead, at ~4 host syncs per generation.
+    caller-measured draft/target ``cost_ratio``.  A fixed k=4 at accept
+    0.36 over-speculates; this controller lands on the depth its cost
+    model scores best instead, at ~4 host syncs per generation (the
+    gain is not measured on the chip).
     Token-exact for greedy at ANY depth sequence."""
     sampling = temperature > 0.0
     if calibration not in ("measured", "model"):

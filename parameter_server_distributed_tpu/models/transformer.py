@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import logging
 import math
 from functools import partial
 from typing import Callable, Mapping
@@ -34,6 +35,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .moe import moe_expert_weight_spec
 from .quant import wdot
+
+log = logging.getLogger("pst.models")
 
 Array = jax.Array
 
@@ -183,9 +186,9 @@ def flash_attention_auto(q: Array, k: Array, v: Array) -> Array:
     memory) when the sequence is block-divisible, falling back to the dense
     einsum otherwise.  GQA K/V stay UNexpanded: the grouped-query kernel
     folds query groups into the block batch, so K/V HBM stays
-    kv_heads-sized end to end (fwd blocks and dK/dV alike).  On non-TPU
-    backends the kernels run in interpret mode, so this is only worth
-    selecting on TPU; pass it explicitly as
+    kv_heads-sized end to end (fwd blocks and dK/dV alike).  On a CPU
+    backend the kernels run in interpret mode, so this is only worth
+    selecting on an accelerator; pass it explicitly as
     ``Transformer(config, attention_fn=flash_attention_auto)`` or set
     ``PSDT_FLASH_ATTENTION=1`` to make it the model default.
 
@@ -298,15 +301,24 @@ def select_attention(name: str, mesh: Mesh | None) -> Callable | None:
 
 def _default_attention() -> Callable:
     """PSDT_FLASH_ATTENTION=1 opts the model default into the pallas flash
-    path — on TPU only: on other backends the kernels run in interpret mode
-    (orders of magnitude slower than the einsum), which is for tests to opt
-    into explicitly, never a shared launch env flag."""
+    path wherever the kernels compile (ops/pallas.interpret_mode).  On a
+    CPU backend they would run interpreted — orders of magnitude slower
+    than the einsum, which is for tests to opt into explicitly, never a
+    shared launch env flag — so there the flag is refused with a warning."""
     import os
 
-    if (os.environ.get("PSDT_FLASH_ATTENTION", "") not in ("", "0")
-            and jax.default_backend() == "tpu"):
-        return flash_attention_auto
-    return causal_attention
+    if os.environ.get("PSDT_FLASH_ATTENTION", "") in ("", "0"):
+        return causal_attention
+    from ..ops.pallas import interpret_mode
+
+    if interpret_mode():
+        log.warning(
+            "PSDT_FLASH_ATTENTION is set but the backend is %s: the pallas "
+            "kernels would run interpreted, using dense attention (pass "
+            "attention_fn=flash_attention_auto to force them)",
+            jax.devices()[0].platform)
+        return causal_attention
+    return flash_attention_auto
 
 
 def repeat_kv(x: Array, groups: int) -> Array:
@@ -934,8 +946,8 @@ def lm_350m(vocab: int = 32000, seq: int = 1024, dtype=jnp.bfloat16,
     KV-cache HBM and ring/Ulysses ICI bytes, and the GQA-folded flash
     kernel keeps K/V unexpanded end to end."""
     # n_heads=8 gives head_dim 128 — a full MXU tile per attention
-    # matmul (head_dim 64 halves MXU utilization; the r02 on-chip flash
-    # measurement showed it) — same parameter count either way
+    # matmul, where head_dim 64 fills half of one (the effect on step
+    # time is not measured on the chip) — same parameter count either way
     return Transformer(TransformerConfig(
         vocab=vocab, d_model=1024, n_heads=n_heads, n_layers=24, d_ff=4096,
         n_kv_heads=kv_heads, remat_policy=remat_policy,

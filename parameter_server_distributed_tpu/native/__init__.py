@@ -1,7 +1,7 @@
 """Native C++ host kernels with automatic build + Python fallback.
 
 `lib()` returns the ctypes-bound shared library, compiling it with g++ on
-first use (cached under native/build/).  Every consumer must handle
+first use (cached under native/build/<host ISA key>/).  Every consumer must handle
 ``lib() is None`` (no compiler available) by falling back to numpy — the
 framework is fully functional without the native path, just slower on the
 host-side PS hot loops.
@@ -19,8 +19,10 @@ fallback — the bench A/B knob.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
+import platform
 import subprocess
 
 import numpy as np
@@ -31,7 +33,32 @@ log = logging.getLogger("pst.native")
 
 _SRC = os.path.join(os.path.dirname(__file__), "psdt_native.cpp")
 _BUILD_DIR = os.path.join(os.path.dirname(__file__), "build")
-_SO_PATH = os.path.join(_BUILD_DIR, "libpsdt_native.so")
+
+
+def build_key() -> str:
+    """Short hash of the host's ISA: ``platform.machine()`` plus the
+    ``flags`` line of /proc/cpuinfo.  The library is compiled with
+    ``-march=native``, so it is only valid on a CPU with the builder's
+    instruction set; keying the build directory by it means a checkout
+    copied to another machine (build/ and all) compiles its own library
+    instead of executing this host's, while one host keeps reusing its
+    own.  A fixed function of the host: no pid, time or path in it."""
+    flags = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    flags = line.partition(":")[2].strip()
+                    break
+    except OSError:
+        pass  # no procfs: the machine name alone keys the build
+    text = f"{platform.machine()}|{flags}"
+    return hashlib.sha256(text.encode()).hexdigest()[:12]
+
+
+def _so_path() -> str:
+    return os.path.join(_BUILD_DIR, build_key(), "libpsdt_native.so")
+
 
 _lock = checked_lock("native._lock")
 _lib: ctypes.CDLL | None = None
@@ -41,24 +68,26 @@ _F32P = ctypes.POINTER(ctypes.c_float)
 
 
 def _build() -> str | None:
+    so_path = _so_path()
     try:
         # makedirs inside the guard: a root-installed package run by an
         # unprivileged user has a read-only site-packages — that must mean
         # numpy fallback, not a crash on the PS hot loop
-        os.makedirs(_BUILD_DIR, exist_ok=True)
-        if (os.path.exists(_SO_PATH)
-                and os.path.getmtime(_SO_PATH) >= os.path.getmtime(_SRC)):
-            return _SO_PATH
+        os.makedirs(os.path.dirname(so_path), exist_ok=True)
+        if (os.path.exists(so_path)
+                and os.path.getmtime(so_path) >= os.path.getmtime(_SRC)):
+            return so_path
         base = ["g++", "-O3", "-ffp-contract=off", "-shared", "-fPIC",
-                "-std=c++17", "-o", _SO_PATH, _SRC]
+                "-std=c++17", "-o", so_path, _SRC]
         try:
             # -march=native lets the codec loops vectorize (the .so is
-            # built on the machine that runs it, so the ISA is known);
-            # IEEE semantics are untouched — no -ffast-math, ever, and
-            # -ffp-contract=off keeps -march from FMA-contracting the
-            # optimizer kernels away from numpy's separate mul+add
-            # rounding: the wire codec must stay bit-identical to the
-            # numpy oracle and the optimizers numpy-trajectory-equal
+            # built on the machine that runs it — build_key() — so the
+            # ISA is known); IEEE semantics are untouched — no
+            # -ffast-math, ever, and -ffp-contract=off keeps -march from
+            # FMA-contracting the optimizer kernels away from numpy's
+            # separate mul+add rounding: the wire codec must stay
+            # bit-identical to the numpy oracle and the optimizers
+            # numpy-trajectory-equal
             cmd = base[:1] + ["-march=native"] + base[1:]
             subprocess.run(cmd, check=True, capture_output=True,
                            timeout=120)
@@ -66,7 +95,7 @@ def _build() -> str | None:
             # cross/exotic toolchains may reject -march=native
             subprocess.run(base, check=True, capture_output=True,
                            timeout=120)
-        return _SO_PATH
+        return so_path
     except (OSError, subprocess.SubprocessError) as exc:
         log.warning("native build failed (%s); using numpy fallback", exc)
         return None

@@ -76,15 +76,9 @@ def _ps_rollup(snap: dict) -> dict:
     if delta:
         out["delta"] = delta
     # accelerator-resident apply (core/device_apply.py, ISSUE 11):
-    # device-resident barrier closes next to the selection downgrades
-    device: dict = {}
-    for key, name in (("applies", "ps.apply.device"),
-                      ("fallbacks", "ps.apply.device_fallback")):
-        value = counters.get(name, 0)
-        if value:
-            device[key] = value
-    if device:
-        out["device_apply"] = device
+    # device-resident barrier closes
+    if counters.get("ps.apply.device", 0):
+        out["device_apply"] = {"applies": counters["ps.apply.device"]}
     # flat arena apply (core/arena.py, ISSUE 15): mega-array closes,
     # per-close downgrades to the per-tensor path, and the packing
     # padding overhead (the PSDT_ARENA_ALIGN cost)
@@ -430,10 +424,8 @@ def render_rollup(rollup: dict) -> str:
                     f"({_fmt_bytes(dserve.get('bytes', 0))} delta)")
             dapply = ps.get("device_apply")
             if dapply:
-                note = f"device apply {dapply.get('applies', 0)} closes"
-                if dapply.get("fallbacks"):
-                    note += f" ({dapply['fallbacks']} fallbacks)"
-                parts.append(note)
+                parts.append(
+                    f"device apply {dapply.get('applies', 0)} closes")
             arena = ps.get("arena")
             if arena:
                 note = f"arena {arena.get('applies', 0)} flat closes"

@@ -147,8 +147,6 @@ EVENTS: dict[str, int] = {
     # accelerator-resident sharded apply (core/device_apply.py, ISSUE 11)
     "apply.device": 100,          # device-resident barrier apply swapped
                                   # in; a = duration_us, b = stripes
-    "apply.device.fallback": 101,  # device optimizer selection degraded
-                                   # to the host family; note = reason
     "apply.readback": 102,        # async D2H readback of the fresh store
                                   # started; a = tensors
     # elastic membership + quorum barriers (elastic/, ISSUE 13)

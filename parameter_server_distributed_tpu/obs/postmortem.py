@@ -123,8 +123,6 @@ EVENT_DECODE: dict[str, str] = {
                     "b=duration_us",
     "publish.lag": "subscriber lag sample; a=versions behind",
     "apply.device": "device-resident apply; a=duration_us b=stripes",
-    "apply.device.fallback": "device apply degraded to host "
-                             "(note = reason)",
     "apply.readback": "async D2H readback started; a=tensors",
     "elastic.join": "member ACTIVE; a=membership epoch",
     "elastic.drain": "member DRAINING; a=epoch (note = reason)",

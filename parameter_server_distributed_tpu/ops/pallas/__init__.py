@@ -1,0 +1,30 @@
+"""Pallas TPU kernels (flash attention, fused optimizer updates).
+
+:func:`interpret_mode` is the single place that decides whether a
+``pallas_call`` lowers through Mosaic or runs in the Pallas interpreter.
+"""
+
+from __future__ import annotations
+
+import jax
+
+
+def interpret_mode(*operands) -> bool:
+    """True when a ``pallas_call`` over ``operands`` must be interpreted.
+
+    Decided from the platform of the devices the call will run on: the
+    devices that hold the concrete operands, or — for tracers and
+    uncommitted host values, i.e. inside ``jit`` — the default backend's
+    devices, which is where jit places such work.  Only a CPU backend
+    interprets; every other platform lowers the kernel for real, so a
+    backend Mosaic cannot target fails at compile time instead of
+    silently timing the interpreter.
+    """
+    platforms = {device.platform
+                 for x in operands
+                 if isinstance(x, jax.Array)
+                 and not isinstance(x, jax.core.Tracer)
+                 for device in x.devices()}
+    if not platforms:
+        platforms = {jax.devices()[0].platform}
+    return platforms == {"cpu"}

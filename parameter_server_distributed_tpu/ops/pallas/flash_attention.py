@@ -24,8 +24,8 @@ than materialized in HBM):
 - dQ kernel: grid (BH, q-blocks, k-blocks), K/V streaming innermost;
 - dK/dV kernel: grid (BH, k-blocks, q-blocks), Q/dO streaming innermost.
 
-On non-TPU backends the kernels run in interpret mode, so tests exercise
-identical code paths on CPU.
+On a CPU backend the kernels run in interpret mode
+(ops/pallas.interpret_mode), so tests exercise identical code paths there.
 """
 
 from __future__ import annotations
@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import interpret_mode
 
 NEG_INF = -1e30
 
@@ -316,7 +318,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         raise ValueError(f"seq len {s} must divide by blocks "
                          f"({block_q}, {block_k})")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode(q, k, v)
 
     def fold(x):  # [B,S,H,D] -> [B*H, S, D]
         return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, s, d)
@@ -353,7 +355,7 @@ def flash_attention_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
         raise ValueError(f"seq len {s} must divide by blocks "
                          f"({block_q}, {block_k})")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode(q, k, v)
 
     # head h = kv_head * G + group (repeat_kv convention)
     qf = jnp.transpose(q.reshape(b, s, kv, groups, d),

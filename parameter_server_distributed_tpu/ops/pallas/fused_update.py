@@ -15,9 +15,10 @@ compile-time constants baked into the kernel; Adam's per-step bias
 corrections change every update, so they enter as SMEM scalars — zero
 recompiles across steps.
 
-Arrays are processed as (rows, 128) tiles (padded as needed).  On non-TPU
-backends kernels run in interpret mode so the same code path is tested on
-CPU.
+Arrays are processed as (rows, 128) tiles (padded as needed), streamed
+through VMEM over a 1-D grid of ``BLOCK_ROWS``-row blocks so a tensor of any
+size compiles.  On a CPU backend the kernels run in interpret mode
+(ops/pallas.interpret_mode) so the same code path is tested there.
 """
 
 from __future__ import annotations
@@ -31,12 +32,15 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret_mode
+
 LANE = 128
 SUBLANE = 8
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+# Rows per grid step: a (1024, 128) f32 block is 512 KiB.  Adam streams
+# four inputs and three outputs, each double-buffered by the pipeline:
+# 7 x 2 x 512 KiB = 7 MiB, inside the 16 MiB of VMEM a kernel gets by
+# default on every TPU generation.
+BLOCK_ROWS = 1024
 
 
 def _sgd_kernel(p_ref, g_ref, out_ref, *, lr: float):
@@ -64,13 +68,17 @@ def _adam_kernel(bc_ref, p_ref, g_ref, m_ref, v_ref, p_out, m_out, v_out, *,
 
 
 def _as_tiles(arr: jax.Array) -> tuple[jax.Array, int]:
-    """Flatten + pad to a (rows, LANE) float32 tile layout."""
+    """Flatten + pad to a (rows, LANE) float32 tile layout whose row count
+    is a sublane multiple and, past one block, a BLOCK_ROWS multiple."""
     flat = arr.reshape(-1).astype(jnp.float32)
     n = flat.shape[0]
     rows = -(-n // LANE)
     rows = -(-rows // SUBLANE) * SUBLANE  # round rows to sublane multiple
-    padded = jnp.zeros((rows * LANE,), jnp.float32).at[:n].set(flat)
-    return padded.reshape(rows, LANE), n
+    if rows > BLOCK_ROWS:
+        rows = -(-rows // BLOCK_ROWS) * BLOCK_ROWS
+    if rows * LANE != n:
+        flat = jnp.pad(flat, (0, rows * LANE - n))
+    return flat.reshape(rows, LANE), n
 
 
 def _from_tiles(tiles: jax.Array, n: int, shape, dtype) -> jax.Array:
@@ -80,7 +88,8 @@ def _from_tiles(tiles: jax.Array, n: int, shape, dtype) -> jax.Array:
 def _run(kernel, arrays: list[jax.Array], num_outputs: int,
          interpret: bool, scalars: jax.Array | None = None) -> list[jax.Array]:
     rows = arrays[0].shape[0]
-    block = pl.BlockSpec((rows, LANE), lambda: (0, 0))
+    block_rows = min(rows, BLOCK_ROWS)
+    block = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
     in_specs = [block] * len(arrays)
     operands = list(arrays)
     if scalars is not None:
@@ -89,6 +98,7 @@ def _run(kernel, arrays: list[jax.Array], num_outputs: int,
     out = pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct((rows, LANE), jnp.float32)] * num_outputs,
+        grid=(rows // block_rows,),
         in_specs=in_specs,
         out_specs=[block] * num_outputs,
         interpret=interpret,
@@ -100,7 +110,8 @@ def fused_sgd(params: Mapping[str, jax.Array],
               grads: Mapping[str, jax.Array], lr: float,
               interpret: bool | None = None) -> dict[str, jax.Array]:
     """param <- param - lr * grad, one fused pass per tensor."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = (interpret_mode(*params.values()) if interpret is None
+                 else interpret)
     kernel = functools.partial(_sgd_kernel, lr=float(lr))
     out = {}
     for name, p in params.items():
@@ -119,7 +130,8 @@ def fused_momentum(params: Mapping[str, jax.Array],
                    velocity: Mapping[str, jax.Array], lr: float,
                    mu: float = 0.9, interpret: bool | None = None):
     """Fused momentum SGD: returns (new_params, new_velocity)."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = (interpret_mode(*params.values()) if interpret is None
+                 else interpret)
     kernel = functools.partial(_momentum_kernel, lr=float(lr), mu=float(mu))
     new_p, new_v = {}, {}
     for name, p in params.items():
@@ -143,7 +155,8 @@ def fused_adam(params: Mapping[str, jax.Array],
     """Fused Adam: returns (new_params, new_m, new_v).  ``step`` (1-based)
     may be a Python int or a traced scalar — bias corrections enter the
     kernel as SMEM data, so stepping never recompiles."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = (interpret_mode(*params.values()) if interpret is None
+                 else interpret)
     kernel = functools.partial(_adam_kernel, lr=float(lr), b1=float(b1),
                                b2=float(b2), eps=float(eps))
     step_f = jnp.asarray(step, jnp.float32)

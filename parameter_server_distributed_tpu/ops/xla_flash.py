@@ -5,14 +5,16 @@ The same flash-attention recurrence as the pallas kernels
 denominator per K/V block — but expressed as a ``lax.scan`` over key
 blocks so XLA compiles it natively on EVERY backend.  Three uses:
 
-- the robust long-context path anywhere pallas is unavailable or the
-  shapes don't fit its tiling (the pallas kernels fall back to interpret
-  mode off-TPU, which is orders of magnitude slower than compiled code);
-- an apples-to-apples A/B contender for the pallas kernels on TPU (XLA's
-  fused scan body is often competitive — `PSDT_BENCH_ATTENTION=xla_flash`);
-- the CPU proxy for long-sequence benchmarking: dense attention
-  materializes the [B, H, S, S] probability tensor (4 GB at S=8192,
-  H=16, f32) while this streams O(S * block) working sets.
+- the long-context path anywhere pallas is unavailable or the shapes
+  don't fit its tiling (on a CPU backend the pallas kernels run in
+  interpret mode, which is orders of magnitude slower than compiled
+  code);
+- an apples-to-apples A/B contender for the pallas kernels on TPU
+  (`PSDT_BENCH_ATTENTION=xla_flash`; which one wins is not measured on
+  the chip);
+- long sequences on a host: dense attention materializes the
+  [B, H, S, S] probability tensor (4 GB at S=8192, H=16, f32) while
+  this streams O(S * block) working sets.
 
 Memory: forward residuals are O(S) (out, running stats) — the scan body
 is wrapped in ``jax.checkpoint`` so the backward pass recomputes each

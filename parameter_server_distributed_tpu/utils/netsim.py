@@ -2,8 +2,8 @@
 
 The top-k/bf16 wire encodings exist to win on a REAL network boundary
 (DCN between PS hosts and workers), where bytes cost wall-clock; on
-localhost the kernel moves 10+ GB/s and the byte advantage vanishes
-(BASELINE.md: top-k at 1B was a null result on loopback).  The honest
+localhost the kernel moves bytes almost for free and the byte
+advantage vanishes.  The honest
 way to measure the wire win without two hosts is to inject latency and
 a bandwidth cap into the path.  Kernel tools (tc netem / tbf) need
 modules this environment's kernel doesn't ship, so this is a portable

@@ -71,7 +71,7 @@ def main(argv: list[str] | None = None) -> int:
         "PSDT_FLIGHT_DIR": flight_dir,
         "PSDT_SHM": "1" if use_shm else "0",
         "JAX_PLATFORMS": "cpu",
-        "PSDT_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
     })
     logs = os.path.join(flight_dir, "logs")
     os.makedirs(logs, exist_ok=True)

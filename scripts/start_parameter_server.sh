@@ -13,10 +13,11 @@ CHECKPOINT_INTERVAL="${CHECKPOINT_INTERVAL:-10}"
 CHECKPOINT_DIR="${CHECKPOINT_DIR:-.}"
 EXTRA_FLAGS="${EXTRA_FLAGS:-}"
 LOG_FILE="${LOG_FILE:-./parameter_server.log}"
-# default the PS to the host backend (control plane + host optimizers);
-# override PSDT_PLATFORM when using a device-resident optimizer
-# (--optimizer=device_*/pallas_* in EXTRA_FLAGS)
-export PSDT_PLATFORM="${PSDT_PLATFORM:-cpu}"
+# default the PS to the host backend (control plane + host optimizers).
+# A device-resident optimizer (--optimizer=device_*/pallas_* in
+# EXTRA_FLAGS) needs JAX_PLATFORMS=tpu and a chip of its own: one process
+# owns a chip, so the PS cannot share one with a worker on the same host.
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 PID_DIR="${PID_DIR:-./run}"
 mkdir -p "$PID_DIR"
 # shellcheck disable=SC2086

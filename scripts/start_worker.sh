@@ -7,6 +7,11 @@
 #   CHECKPOINT_PATH (optional restore-at-start)
 #   MODEL (default mnist_mlp)  BATCH (default 32)  EXTRA_FLAGS
 #   LOG_FILE (default ./worker_${WORKER_ID}.log)  PID_DIR (default ./run)
+# The worker takes whatever JAX gives it (JAX_PLATFORMS unset = the
+# accelerator).  One process owns a chip: start ONE unpinned worker per
+# host and give it the host's chips with --mesh; a second worker on the
+# same host needs JAX_PLATFORMS=cpu, or it fails or hangs reaching for
+# the chip the first one holds.
 set -euo pipefail
 COORDINATOR_ADDR="${COORDINATOR_ADDR:-127.0.0.1:50052}"
 WORKER_ID="${WORKER_ID:-0}"

@@ -4,7 +4,7 @@ the numpy path across optimizers x stripe counts x fold residences,
 dequantize-on-device byte-compat with the codec oracle (and the native
 C++ kernels when buildable), checkpoint round-trips of device slot state
 across restore stripe counts and across the host/device optimizer
-families, the make_optimizer downgrade matrix, the device_fold gate, and
+families, the make_optimizer failure matrix, the device_fold gate, and
 a lockcheck-marked concurrent push/close/serve hammer."""
 
 import os
@@ -253,32 +253,27 @@ def test_make_optimizer_sharded_names(monkeypatch):
         assert opt.rule == rule
 
 
-def test_make_optimizer_degrades_to_matching_host(monkeypatch):
-    """No accelerator => the MATCHING host optimizer (same rule) with a
-    logged ps.apply.device_fallback counter, never a boot failure."""
+def test_make_optimizer_raises_without_a_device(monkeypatch):
+    """No accelerator => an error that names the cause and the host
+    optimizer to ask for instead — never a silent host optimizer under
+    a device name."""
     monkeypatch.setattr(device_apply, "_available", False)
-    before = obs_stats.REGISTRY.snapshot().get("counters", {}).get(
-        "ps.apply.device_fallback", 0)
-    for name, host_cls in (("device_sgd", SGD), ("sharded_momentum",
-                                                 Momentum),
-                           ("device_adam", Adam), ("device_adamw", AdamW),
-                           ("pallas_adamw_bf16", AdamW),
-                           ("sharded_lion", Lion)):
-        opt = make_optimizer(name, 0.01)
-        assert type(opt) is host_cls, name
-    after = obs_stats.REGISTRY.snapshot()["counters"][
-        "ps.apply.device_fallback"]
-    assert after >= before + 6
-    # an unknown RULE still raises — a typo must never silently train
+    for name, rule in (("device_sgd", "sgd"),
+                       ("sharded_momentum", "momentum"),
+                       ("device_adam", "adam"), ("device_adamw", "adamw"),
+                       ("pallas_adam", "adam"), ("sharded_lion", "lion")):
+        with pytest.raises(RuntimeError, match=f"use '{rule}'"):
+            make_optimizer(name, 0.01)
+    monkeypatch.setattr(device_apply, "_available", True)
+    # an unknown RULE raises too — a typo must never silently train
     # with a different update rule
     with pytest.raises(ValueError):
         make_optimizer("device_bogus", 0.01)
-    monkeypatch.setattr(device_apply, "_available", True)
     with pytest.raises(ValueError):
         make_optimizer("sharded_adamw_bf16", 0.01)  # not a sharded rule
 
 
-def test_make_optimizer_degrades_on_constructor_error(monkeypatch):
+def test_make_optimizer_raises_on_constructor_error(monkeypatch):
     monkeypatch.setattr(device_apply, "_available", True)
 
     def boom(*a, **kw):
@@ -286,8 +281,24 @@ def test_make_optimizer_degrades_on_constructor_error(monkeypatch):
 
     import parameter_server_distributed_tpu.core.optimizer as opt_mod
     monkeypatch.setattr(opt_mod, "_make_accelerator_optimizer", boom)
-    opt = make_optimizer("device_adam", 0.01)
-    assert type(opt) is Adam
+    with pytest.raises(RuntimeError, match="backend init failed"):
+        make_optimizer("device_adam", 0.01)
+
+
+def test_available_raises_when_the_backend_does_not_come_up(monkeypatch):
+    """PSDT_DEVICE_APPLY on a host whose backend fails is an error at PS
+    start, not a silent False that takes the host path."""
+    import jax
+
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend")
+
+    monkeypatch.setattr(device_apply, "_available", None)
+    monkeypatch.setattr(jax, "devices", no_backend)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        device_apply.available()
+    monkeypatch.undo()
+    assert device_apply.available(refresh=True)
 
 
 def test_make_optimizer_pallas_unimplemented_rule_raises(monkeypatch):
@@ -477,11 +488,11 @@ def test_relay_raise_puts_back_writeable_host_sums(monkeypatch, rng):
     assert complete and calls["n"] == 2
 
 
-def test_make_optimizer_degrades_when_device_family_unimportable(
+def test_make_optimizer_raises_when_device_family_unimportable(
         monkeypatch):
     """PSDT_DEVICE_APPLY=1 on a host where the device-optimizer module
-    cannot import (no jax/optax) must degrade to the host optimizer at
-    PS boot, not crash — the import happens inside the try."""
+    cannot import (no jax/optax) is an error at PS boot that names the
+    import failure, not a host optimizer under a device name."""
     import sys
 
     monkeypatch.setenv(device_apply.ENV_DEVICE_APPLY, "1")
@@ -490,8 +501,8 @@ def test_make_optimizer_degrades_when_device_family_unimportable(
         sys.modules,
         "parameter_server_distributed_tpu.async_sgd.device_optimizer",
         None)  # import of the module now raises ImportError
-    opt = make_optimizer("device_adam", 0.01)
-    assert type(opt) is Adam
+    with pytest.raises(RuntimeError, match="ModuleNotFoundError"):
+        make_optimizer("device_adam", 0.01)
 
 
 # ------------------------------------------------------------ put-back

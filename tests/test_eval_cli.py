@@ -14,7 +14,7 @@ import pytest
 
 
 def run_eval(*flags: str, timeout: float = 400.0) -> dict:
-    env = dict(os.environ, PSDT_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-m",
          "parameter_server_distributed_tpu.cli.eval_main", *flags],
@@ -39,7 +39,7 @@ def test_trained_checkpoint_beats_fresh_init(tmp_path):
     corpus.write_text((pathlib.Path(__file__).resolve().parents[1]
                        / "parameter_server_distributed_tpu/models/lora.py"
                        ).read_text())
-    env = dict(os.environ, PSDT_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     subprocess.run(
         [sys.executable, "-m",
          "parameter_server_distributed_tpu.cli.train_main",

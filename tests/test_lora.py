@@ -167,7 +167,7 @@ def test_generate_cli_merges_lora_checkpoint(tmp_path, rng):
         model="tiny_lm", batch_size=4, steps=2, optimizer="adam",
         learning_rate=1e-2, lora="2:4", checkpoint_dir=ckpt,
         checkpoint_every=2, log_every=2))
-    env = dict(os.environ, PSDT_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     base = [sys.executable, "-m",
             "parameter_server_distributed_tpu.cli.generate_main",
             "--model=tiny_lm", f"--ckpt-dir={ckpt}", "--tokens=1,2,3",

@@ -234,11 +234,11 @@ def test_transformer_flash_attention_drop_in(rng):
 
 def test_flash_attention_env_default(rng, monkeypatch):
     """PSDT_FLASH_ATTENTION=1 switches the single-device model default to
-    the flash-auto path on TPU only (interpret-mode pallas on other
-    backends is a per-call opt-in, never a launch-env default)."""
-    import jax
-
+    the flash-auto path wherever the kernels compile (interpret-mode
+    pallas on a CPU backend is a per-call opt-in, never a launch-env
+    default)."""
     from parameter_server_distributed_tpu.models import transformer as tr
+    from parameter_server_distributed_tpu.ops import pallas as pallas_ops
 
     config = tr.TransformerConfig(vocab=64, d_model=32, n_heads=2,
                                   n_layers=1, d_ff=64, max_seq=32,
@@ -246,7 +246,7 @@ def test_flash_attention_env_default(rng, monkeypatch):
     monkeypatch.setenv("PSDT_FLASH_ATTENTION", "1")
     # CPU backend (this test session): env flag alone must NOT select flash
     assert tr.Transformer(config).attention_fn is tr.causal_attention
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pallas_ops, "interpret_mode", lambda *a: False)
     assert tr.Transformer(config).attention_fn is tr.flash_attention_auto
     monkeypatch.delenv("PSDT_FLASH_ATTENTION")
     assert tr.Transformer(config).attention_fn is tr.causal_attention

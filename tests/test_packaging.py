@@ -1,5 +1,5 @@
 """Packaging contract: pyproject console scripts resolve and the package
-is installable metadata-wise (VERDICT round 1 missing item 1)."""
+is installable metadata-wise."""
 
 import importlib
 import os

@@ -127,8 +127,8 @@ def test_pipelined_lm_loss_matches_plain(rng):
 
 def test_pipelined_lm_gradients_match_plain(rng):
     """jax.grad through the GPipe schedule == grad of the sequential model,
-    for every parameter (the VERDICT item 6 'verify gradients equal the
-    non-pipelined run' contract)."""
+    for every parameter (the 'verify gradients equal the non-pipelined
+    run' contract)."""
     plain, piped, mesh, tokens = _lm_fixtures(rng)
     plain_params = plain.init_params(0)
     piped_params = piped.init_params(0)

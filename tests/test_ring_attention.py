@@ -109,7 +109,7 @@ def test_sharded_flash_matches_dense(rng):
 def test_sharded_flash_lm_step_matches_dense(rng):
     """Full sharded LM train step on a 2-axis mesh with the pallas flash
     kernel: loss and updated params must match the dense-attention run
-    (VERDICT round 1 item 5 — mesh + flash at the same time)."""
+    (mesh + flash at the same time)."""
     from parameter_server_distributed_tpu.models.transformer import (
         make_sharded_flash_attention)
 

@@ -17,7 +17,7 @@ import pytest
 def run_serve(requests: list[dict], *extra_flags: str,
               timeout: float = 400.0) -> tuple[list[dict], str]:
     env = dict(os.environ)
-    env["PSDT_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-m",
          "parameter_server_distributed_tpu.cli.serve_main",
@@ -54,7 +54,7 @@ def test_malformed_lines_never_kill_the_server():
     (which must not alias the EOF sentinel) all become per-line errors
     while the well-formed request completes."""
     env = dict(os.environ)
-    env["PSDT_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     raw = "\n".join([
         json.dumps({"id": "t", "tokens": 5}),        # non-iterable tokens
         "42", "[1,2]", "null", "{not json",
@@ -135,7 +135,7 @@ def test_hf_checkpoint_serves(tmp_path):
         vocab_size=128, n_positions=64, n_embd=32, n_layer=2, n_head=2)
     transformers.GPT2LMHeadModel(cfg).save_pretrained(tmp_path)
     env = dict(os.environ)
-    env["PSDT_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-m",
          "parameter_server_distributed_tpu.cli.serve_main",

@@ -120,7 +120,6 @@ class CoordinatorCore:
         # refusal.
         self._tier_confirmed: set[str] = set()
         self._tier_epoch = 0
-        self._obs_tier_groups = obs_stats.gauge("tier.groups")
         # Elastic membership (elastic/, ISSUE 13): worker id -> state
         # (JOINING/ACTIVE/DRAINING/GONE) under a monotone membership
         # epoch bumped on EVERY transition, plus a registry generation
@@ -132,7 +131,6 @@ class CoordinatorCore:
         self._member_epochs: dict[int, int] = {}
         self._membership_epoch = 0
         self._registry_generation = 0
-        self._obs_members_live = obs_stats.gauge("coord.members.live")
         # Decode fleet registry (fleet/, ISSUE 14): server id -> row
         # under a monotone fleet epoch bumped on every STATE transition
         # (heartbeat load refreshes don't bump — the router polls the
@@ -143,7 +141,6 @@ class CoordinatorCore:
         self._fleet: dict[int, FleetMember] = {}
         self._fleet_epoch = 0
         self._fleet_target = 0
-        self._obs_fleet_active = obs_stats.gauge("fleet.servers.active")
 
     def register_worker(self, worker_id: int, address: str, port: int,
                         hostname: str) -> int:
@@ -290,9 +287,6 @@ class CoordinatorCore:
         self._member_states[wid] = state
         self._membership_epoch += 1
         self._member_epochs[wid] = self._membership_epoch
-        self._obs_members_live.set(sum(
-            1 for s in self._member_states.values()
-            if s != emsg.MEMBER_GONE))
         return True
 
     def registry_generation(self) -> int:
@@ -368,9 +362,6 @@ class CoordinatorCore:
         member.state = state
         self._fleet_epoch += 1
         member.epoch = self._fleet_epoch
-        self._obs_fleet_active.set(sum(
-            1 for m in self._fleet.values()
-            if m.state == emsg.MEMBER_ACTIVE))
         return True
 
     def fleet_register(self, server_id: int, address: str,
@@ -584,7 +575,6 @@ class CoordinatorCore:
             return
         self._tier_groups = groups
         self._tier_epoch += 1
-        self._obs_tier_groups.set(len(groups))
         for entry in groups:
             if entry.leaf_address not in before:
                 # the coordinator-edge election record: which leader,

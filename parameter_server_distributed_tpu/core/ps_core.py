@@ -83,6 +83,7 @@ included.
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import threading
@@ -97,6 +98,7 @@ from ..async_sgd.damping import StalenessDamping, async_damping
 from ..elastic import quorum as equorum
 from ..obs import flight
 from ..obs import stats as obs_stats
+from ..obs import trace as obs_trace
 from ..replication.messages import STALE_SHARD_MAP
 from . import arena as arena_mod
 from . import device_apply
@@ -252,8 +254,12 @@ class PushSink:
         if self._buffer is not None:
             self._buffer.update(gradients)
         else:
-            stale, redirect = self._core._fold_chunk(
-                self.worker_id, self.iteration, gradients)
+            # in a streamed push the folds run in the stream loop, beside
+            # the transport, outside ps/apply: a leg of their own
+            with obs_trace.span("ps/fold", worker=self.worker_id,
+                                iteration=self.iteration):
+                stale, redirect = self._core._fold_chunk(
+                    self.worker_id, self.iteration, gradients)
             if stale is not None:
                 self.stale_map_epoch = stale
             if redirect is not None and (
@@ -337,6 +343,18 @@ def _block_on_store(store: "TensorStore") -> None:
         wait = getattr(v, "block_until_ready", None)
         if wait is not None:
             wait()
+
+
+def _close_leg(close):
+    """``_close_barrier_locked`` as the ``ps/close`` leg: one span and one
+    observation of ``ps.barrier_close_s`` per attempt (scale + optimizer,
+    the drain of in-flight folds before them)."""
+    @functools.wraps(close)
+    def timed_close(self, iteration, state, received, total=0):
+        with obs_trace.timed("ps/close", self._obs_barrier_close,
+                             iteration=iteration, workers=received):
+            return close(self, iteration, state, received, total)
+    return timed_close
 
 
 class ParameterServerCore:
@@ -930,8 +948,10 @@ class ParameterServerCore:
                 return self._commit_group_push(worker_id, iteration,
                                                dict(gradients), weight,
                                                members)
-            stale_epoch, redirect = self._fold_chunk(worker_id, iteration,
-                                                     gradients)
+            with obs_trace.span("ps/fold", worker=worker_id,
+                                iteration=iteration):
+                stale_epoch, redirect = self._fold_chunk(
+                    worker_id, iteration, gradients)
             if stale_epoch is not None:
                 return self._stale_map_result(iteration, stale_epoch)
             if redirect is not None:
@@ -1567,6 +1587,7 @@ class ParameterServerCore:
         return (state.workers_at_aggregation if state.aggregated
                 else received)
 
+    @_close_leg
     def _close_barrier_locked(self, iteration: int, state: IterationState,
                               received: int, total: int = 0) -> None:
         """Close the barrier.  Streaming mode: take the accumulator, flag
@@ -1577,7 +1598,6 @@ class ParameterServerCore:
         under _state_lock (the escape hatch preserves the original
         semantics and timing exactly).  Caller holds _state_lock; it is
         held again on return."""
-        t0 = time.perf_counter()
         # remember whether THIS close is the bootstrap (store empty →
         # the aggregated payload becomes the parameters): a straggler's
         # late replay of the seed push must then be a plain late-push
@@ -1641,7 +1661,6 @@ class ParameterServerCore:
         state.aggregated = True
         state.workers_at_aggregation = received
         self._aggregated_watermark = max(self._aggregated_watermark, iteration)
-        self._obs_barrier_close.observe(time.perf_counter() - t0)
         flight.record("barrier.publish", iteration=iteration, a=received,
                       b=total)
         self._barrier_cv.notify_all()  # wake fused-RPC barrier waiters

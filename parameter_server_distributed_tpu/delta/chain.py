@@ -52,7 +52,6 @@ import numpy as np
 from ..analysis.lock_order import checked_lock
 from ..core.stripes import partition_names, run_striped, stripe_count
 from ..obs import flight
-from ..obs import stats as obs_stats
 from ..rpc.codec import (WIRE_BF16, WIRE_DTYPE_NAMES, WIRE_F32,
                          WIRE_RAW_F32, bf16_dtype)
 from ..rpc.wire import ArrayPayload
@@ -229,8 +228,6 @@ class DeltaChain:
         self._prev_slabs: tuple | None = None
         self._prev_version = -1
         self._gen = 0
-        self._obs_build_ms = obs_stats.histogram("ps.serve.delta_build_ms")
-        self._obs_pair_bytes = obs_stats.gauge("ps.serve.delta_pair_bytes")
 
     # ------------------------------------------------------------- build
     def note_apply(self, store: Mapping[str, np.ndarray],
@@ -262,7 +259,6 @@ class DeltaChain:
 
     def _note_apply(self, store: Mapping[str, np.ndarray],
                     version: int) -> None:
-        t0 = time.perf_counter()
         with self._lock:
             gen = self._gen
             prev = self._wire_prev
@@ -308,7 +304,6 @@ class DeltaChain:
                 self._pairs[pair.from_version] = pair
                 while len(self._pairs) > self.depth:
                     self._pairs.popitem(last=False)
-                self._obs_pair_bytes.set(pair.nbytes)
                 flight.record("serve.delta.build", a=pair.nbytes,
                               b=version)
             else:
@@ -316,7 +311,6 @@ class DeltaChain:
                 # chain to the current version — drop them
                 self._pairs.clear()
             self._cv.notify_all()
-        self._obs_build_ms.observe(1e3 * (time.perf_counter() - t0))
 
     def _build_per_name(self, store: Mapping[str, np.ndarray],
                         names: list[str], diffable: bool,

@@ -33,7 +33,6 @@ import numpy as np
 
 from ..analysis.lock_order import checked_lock
 from ..obs import flight
-from ..obs import stats as obs_stats
 from ..rpc import messages as m
 from ..rpc.service import RpcClient
 from ..rpc.service import status_code as _status_code
@@ -78,8 +77,6 @@ class WeightFollower:
         self.degraded = False
         self.degrade_reason = ""
         self.versions_received = 0
-        self._obs_version = obs_stats.gauge("serve.follow.version")
-        self._obs_degraded = obs_stats.gauge("serve.follow.degraded")
         self._stop = threading.Event()
         self._client: RpcClient | None = None
         self._thread = threading.Thread(
@@ -155,14 +152,12 @@ class WeightFollower:
             self._pending = (store, self._state.version)
             self.versions_received += 1
             self._cv.notify_all()
-        self._obs_version.set(self._state.version)
 
     def _degrade(self, reason: str) -> None:
         with self._cv:
             self.degraded = True
             self.degrade_reason = reason
             self._cv.notify_all()
-        self._obs_degraded.set(1)
         flight.record("serve.delta.downgrade", note=reason[:48])
         log.warning("weight follower degraded (%s): decode keeps serving "
                     "last-good weights (version %d)", reason,

@@ -46,7 +46,6 @@ import grpc
 from ..analysis.lock_order import checked_lock
 from ..elastic import messages as emsg
 from ..obs import flight
-from ..obs import stats as obs_stats
 from ..rpc import messages as m
 from ..rpc.service import RpcClient, make_server
 from ..rpc.service import status_code as _status_code
@@ -155,10 +154,6 @@ class FleetDecodeServer:
         self._left = threading.Event()   # deregistered (drain complete)
         self._registered = False
         self.streams_served = 0
-        self._obs_streams = obs_stats.counter("fleet.streams")
-        self._obs_errors = obs_stats.counter("fleet.stream_errors")
-        self._obs_swaps = obs_stats.counter("fleet.swaps")
-        self._obs_queue = obs_stats.gauge("fleet.queue_depth")
         self._grpc: grpc.Server | None = None
         self.port = 0
         self._decode_thread = threading.Thread(
@@ -268,12 +263,10 @@ class FleetDecodeServer:
         chunks.  Rejections (draining, bad request) are an error chunk,
         never a transport failure — the router relays them verbatim."""
         if self._draining or self._stopped.is_set():
-            self._obs_errors.add()
             yield fmsg.DecodeChunk(error="server draining", done=True)
             return
         tokens = [int(t) for t in request.tokens]
         if not tokens:
-            self._obs_errors.add()
             yield fmsg.DecodeChunk(error="empty prompt", done=True)
             return
         stream = _Stream(
@@ -281,7 +274,6 @@ class FleetDecodeServer:
             None if request.temperature < 0 else float(request.temperature),
             [int(t) for t in request.stop])
         self._admit.put(stream)
-        self._obs_queue.set(self._admit.qsize())
         self._wake.set()
         try:
             while True:
@@ -290,7 +282,6 @@ class FleetDecodeServer:
                 except queue.Empty:
                     # a wedged decode loop must not hold the client
                     # forever
-                    self._obs_errors.add()
                     yield fmsg.DecodeChunk(error="decode stalled",
                                            done=True)
                     return
@@ -400,7 +391,6 @@ class FleetDecodeServer:
                         fresh = (self._transform(store) if self._transform
                                  else store)
                         self.server.swap_params(fresh, version=version)
-                        self._obs_swaps.add()
                         flight.record("fleet.swap", a=version,
                                       b=self.server_id)
                         box_ok(box)
@@ -439,7 +429,6 @@ class FleetDecodeServer:
             except Exception as exc:  # noqa: BLE001 — per-request error
                 # boundary, exactly cli/serve_main.py admit(): malformed
                 # requests must never kill in-flight streams
-                self._obs_errors.add()
                 stream.out.put(fmsg.DecodeChunk(error=str(exc), done=True))
                 stream.out.put(None)
                 continue
@@ -455,14 +444,12 @@ class FleetDecodeServer:
                                                 weight_version=version))
                 stream.out.put(None)
                 self.streams_served += 1
-                self._obs_streams.add()
                 continue
             # the prefill already produced the first token
             stream.out.put(fmsg.DecodeChunk(
                 request_id=rid, token=int(self.server.peek(rid)[0]),
                 weight_version=version))
             self._live[rid] = stream
-        self._obs_queue.set(self._admit.qsize())
 
     def _reap_cancelled(self) -> None:
         """Free the slots of in-flight streams whose client vanished
@@ -504,7 +491,6 @@ class FleetDecodeServer:
                                                 weight_version=version))
                 stream.out.put(None)
                 self.streams_served += 1
-                self._obs_streams.add()
 
     def _finish_drain(self) -> None:
         """Drain completed: every in-flight stream finished.  Leave the

@@ -112,9 +112,6 @@ class FleetRouter:
         self._clients: dict[str, RpcClient] = {}
         self._next_stream = 0
         self.streams_routed = 0
-        self._obs_routed = obs_stats.counter("fleet.routed")
-        self._obs_rejected = obs_stats.counter("fleet.route_rejected")
-        self._obs_backends = obs_stats.gauge("fleet.route_backends")
         # prefix blocks of the last routed prompt already cached on the
         # chosen backend (0 = no reusable prefix / fingerprints absent)
         self._obs_overlap = obs_stats.gauge("fleet.route_overlap")
@@ -180,9 +177,6 @@ class FleetRouter:
             self._epoch = int(resp.epoch)
             self._table_at = time.monotonic()
             self._claims.clear()  # the table now reflects past claims
-            self._obs_backends.set(sum(
-                1 for e in self._entries
-                if int(e.state) == fmsg.MEMBER_ACTIVE))
 
     def _pick_backend(self, prompt_tokens=None):
         """Best backend entry or None.  Debits a claim so concurrent
@@ -230,7 +224,6 @@ class FleetRouter:
     def SubmitStream(self, request: fmsg.DecodeRequest, context):
         backend = self._pick_backend([int(t) for t in request.tokens])
         if backend is None:
-            self._obs_rejected.add()
             yield fmsg.DecodeChunk(error="no decode servers available",
                                    done=True)
             return
@@ -241,7 +234,6 @@ class FleetRouter:
         flight.record("fleet.route", a=stream_id, b=sid,
                       note=backend.address[:48])
         self.streams_routed += 1
-        self._obs_routed.add()
         client = self._backend_client(backend.address)
         try:
             # pinned for the stream's lifetime: every chunk relays from
@@ -254,7 +246,6 @@ class FleetRouter:
         except grpc.RpcError as exc:
             # the backend died mid-stream: its decode context is gone,
             # so the honest answer is an error, not a silent restart
-            self._obs_rejected.add()
             yield fmsg.DecodeChunk(
                 error=f"backend {sid} lost mid-stream "
                       f"({_status_code(exc)})", done=True)

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import stats as obs_stats
 from .transformer import Transformer
 
 Array = jax.Array
@@ -204,56 +205,58 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
         if quant:
             k, ks = _kv_quantize(k)
             v, vs = _kv_quantize(v)
-        if ragged:
-            # mode="drop": rows that finished generating keep advancing
-            # their lengths each speculative round, so their scatter
-            # positions intentionally overshoot cache.max_len — those
-            # writes must be dropped, not clamped onto the last slot.
-            new_k = new_k.at[i, bidx, positions].set(
-                k.astype(new_k.dtype), mode="drop")
-            new_v = new_v.at[i, bidx, positions].set(
-                v.astype(new_v.dtype), mode="drop")
+        with jax.named_scope("cache_update"):
+            if ragged:
+                # mode="drop": rows that finished generating keep advancing
+                # their lengths each speculative round, so their scatter
+                # positions intentionally overshoot cache.max_len — those
+                # writes must be dropped, not clamped onto the last slot.
+                new_k = new_k.at[i, bidx, positions].set(
+                    k.astype(new_k.dtype), mode="drop")
+                new_v = new_v.at[i, bidx, positions].set(
+                    v.astype(new_v.dtype), mode="drop")
+                if quant:
+                    new_ks = new_ks.at[i, bidx, positions].set(ks, mode="drop")
+                    new_vs = new_vs.at[i, bidx, positions].set(vs, mode="drop")
+            else:
+                new_k = jax.lax.dynamic_update_slice(
+                    new_k, k[None].astype(new_k.dtype), (i, 0, pos, 0, 0))
+                new_v = jax.lax.dynamic_update_slice(
+                    new_v, v[None].astype(new_v.dtype), (i, 0, pos, 0, 0))
+                if quant:
+                    new_ks = jax.lax.dynamic_update_slice(
+                        new_ks, ks[None], (i, 0, pos, 0))
+                    new_vs = jax.lax.dynamic_update_slice(
+                        new_vs, vs[None], (i, 0, pos, 0))
+        with jax.named_scope("cache_attn"):
+            # dense attention against the cache, f32 softmax.  GQA: contract
+            # query-head groups directly against the UNexpanded cache — the
+            # cache bytes streamed per step stay kv_heads-sized (the point of
+            # the smaller cache), no materialized repeat
+            b, s_q = q.shape[:2]
+            qg = q.reshape(b, s_q, c.kv_heads, groups, c.head_dim)
+            # int8 cache: contract against the int8 array (only int8 bytes
+            # stream from HBM; the convert fuses into the einsum) and fold the
+            # per-(position, head) scale into the product afterwards
+            scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg,
+                                new_k[i].astype(c.dtype) if quant else new_k[i],
+                                preferred_element_type=jnp.float32)
             if quant:
-                new_ks = new_ks.at[i, bidx, positions].set(ks, mode="drop")
-                new_vs = new_vs.at[i, bidx, positions].set(vs, mode="drop")
-        else:
-            new_k = jax.lax.dynamic_update_slice(
-                new_k, k[None].astype(new_k.dtype), (i, 0, pos, 0, 0))
-            new_v = jax.lax.dynamic_update_slice(
-                new_v, v[None].astype(new_v.dtype), (i, 0, pos, 0, 0))
+                # k_scale[i]: [B, M, H] -> [B, H, 1, 1, M] over score axes
+                scores = scores * jnp.transpose(
+                    new_ks[i], (0, 2, 1))[:, :, None, None, :]
+            scores = scores / jnp.sqrt(jnp.asarray(c.head_dim, jnp.float32))
+            scores = jnp.where(mask, scores, -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
             if quant:
-                new_ks = jax.lax.dynamic_update_slice(
-                    new_ks, ks[None], (i, 0, pos, 0))
-                new_vs = jax.lax.dynamic_update_slice(
-                    new_vs, vs[None], (i, 0, pos, 0))
-        # dense attention against the cache, f32 softmax.  GQA: contract
-        # query-head groups directly against the UNexpanded cache — the
-        # cache bytes streamed per step stay kv_heads-sized (the point of
-        # the smaller cache), no materialized repeat
-        b, s_q = q.shape[:2]
-        qg = q.reshape(b, s_q, c.kv_heads, groups, c.head_dim)
-        # int8 cache: contract against the int8 array (only int8 bytes
-        # stream from HBM; the convert fuses into the einsum) and fold the
-        # per-(position, head) scale into the product afterwards
-        scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg,
-                            new_k[i].astype(c.dtype) if quant else new_k[i],
-                            preferred_element_type=jnp.float32)
-        if quant:
-            # k_scale[i]: [B, M, H] -> [B, H, 1, 1, M] over score axes
-            scores = scores * jnp.transpose(
-                new_ks[i], (0, 2, 1))[:, :, None, None, :]
-        scores = scores / jnp.sqrt(jnp.asarray(c.head_dim, jnp.float32))
-        scores = jnp.where(mask, scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-        if quant:
-            # fold v_scale into probs (tiny [.., M] multiply) so the value
-            # contraction streams raw int8
-            probs = probs * jnp.transpose(
-                new_vs[i], (0, 2, 1))[:, :, None, None, :].astype(c.dtype)
-        attn = jnp.einsum("bhgqk,bkhd->bqhgd", probs,
-                          new_v[i].astype(c.dtype) if quant else new_v[i],
-                          preferred_element_type=jnp.float32).astype(c.dtype)
-        attn = attn.reshape(b, s_q, c.n_heads, c.head_dim)
+                # fold v_scale into probs (tiny [.., M] multiply) so the value
+                # contraction streams raw int8
+                probs = probs * jnp.transpose(
+                    new_vs[i], (0, 2, 1))[:, :, None, None, :].astype(c.dtype)
+            attn = jnp.einsum("bhgqk,bkhd->bqhgd", probs,
+                              new_v[i].astype(c.dtype) if quant else new_v[i],
+                              preferred_element_type=jnp.float32).astype(c.dtype)
+            attn = attn.reshape(b, s_q, c.n_heads, c.head_dim)
         h = model.attn_residual(lp, p, h, attn)
         # MoE-aware, drop-free at decode time; aux loss unused here
         h, _ = model.ffn_residual(params, i, h, decode=True)
@@ -349,6 +352,9 @@ def _cached_runner(key: tuple, build):
             _RUNNERS.move_to_end(key)
             return run
     run = build()
+    # a program built where none should be (inside a serving window) then
+    # has a name inside the program: the counter moves
+    obs_stats.counter("serve.programs").add()
     with _RUNNERS_LOCK:
         _RUNNERS[key] = run
         while len(_RUNNERS) > _RUNNERS_MAX:

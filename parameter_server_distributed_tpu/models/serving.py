@@ -31,6 +31,7 @@ weight-quantized ``params`` store works unchanged.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -43,6 +44,7 @@ import numpy as np
 
 from ..obs import flight
 from ..obs import stats as obs_stats
+from ..obs import trace as obs_trace
 from .generation import (KVCache, QuantKVCache, _cached_runner,
                          _kv_quantize, _model_key, _spec_round_runner,
                          check_position_budget, decode_block, init_cache,
@@ -166,6 +168,7 @@ def _splice_runner(model: Transformer, bucket: int, cache_dtype: str):
         # donate the cache: the host drops its old reference immediately,
         # so XLA may update the (large) K/V buffers in place
         @partial(jax.jit, donate_argnums=(0,))
+        @jax.named_scope("splice")
         def run(cache, row, slot):
             if cache_dtype == "int8":
                 k8, v8, ks, vs = row
@@ -286,8 +289,9 @@ def _decode_round(model, top_k, top_p, params, tokens, cache, lengths,
     sequence)."""
     logits, cache = decode_block(model, params, tokens[:, None], cache,
                                  lengths=lengths)
-    rng, sub = jax.random.split(rng)
-    nxt = sample_token_rowwise(logits[:, 0], sub, temps, top_k, top_p)
+    with jax.named_scope("sample"):
+        rng, sub = jax.random.split(rng)
+        nxt = sample_token_rowwise(logits[:, 0], sub, temps, top_k, top_p)
     return nxt, cache, rng
 
 
@@ -432,9 +436,18 @@ class DecodeServer:
         # registry the RPC layer and train loops report to (obs/stats.py)
         self._obs_round = obs_stats.histogram("serve.round_s")
         self._obs_tokens = obs_stats.counter("serve.tokens")
-        self._obs_active = obs_stats.gauge("serve.active_slots")
-        self._obs_rate = obs_stats.gauge("serve.tokens_per_s")
-        self._obs_accept = obs_stats.gauge("serve.accept_rate")
+        # the legs of an admission and of a round (always on; each is
+        # also a span while obs/trace records): all of an admitting
+        # submit() and, inside it, first dispatch to first token on the
+        # host; a round's dispatch-to-tokens-on-the-host and the rest of
+        # the call; and the caller's time between two rounds
+        self._obs_admit = obs_stats.histogram("serve.admit_s")
+        self._obs_admit_device = obs_stats.histogram("serve.admit_device_s")
+        self._obs_round_device = obs_stats.histogram("serve.round_device_s")
+        self._obs_round_host = obs_stats.histogram("serve.round_host_s")
+        self._obs_between = obs_stats.histogram("serve.between_rounds_s")
+        # perf_counter at the last round's return, while a slot is active
+        self._round_returned: float | None = None
         # radix-tree prefix cache (ISSUE 20): token-level index over
         # cached K/V rows — exact hits replay, any shared prefix seeds
         # a suffix-only extension, byte-accounted LRU eviction.
@@ -453,7 +466,6 @@ class DecodeServer:
         # restriction is gone); a k==0-era ancestor without a draft row
         # falls back to a full draft prefill for the draft side only.
         self._prefix_hits = 0
-        self._obs_prefix = obs_stats.counter("serve.prefix_hits")
         # prompt-phase accounting for the fleet bench's reuse ratio:
         # tokens actually forwarded in a prompt phase vs prompt tokens
         # admitted (exact hit: 0, extension: the suffix, miss: all)
@@ -755,10 +767,22 @@ class DecodeServer:
                 f"{self.max_len}")
         check_position_budget(self.model, real_len,
                               max_new_tokens + slack)
-        bucket = min(_bucket(real_len), self.max_len)
         if self.draft is not None:
             check_position_budget(self.draft, real_len,
                                   max_new_tokens + slack)
+        with obs_trace.timed("serve/admit", self._obs_admit,
+                             prompt_tokens=real_len):
+            return self._admit(
+                slot, prompt, real_len, max_new_tokens,
+                self._temperature if temperature is None else temperature,
+                frozenset(stop))
+
+    def _admit(self, slot: int, prompt: np.ndarray, real_len: int,
+               max_new_tokens: int, req_temp: float,
+               stop: frozenset) -> int:
+        """The admitting part of :meth:`submit`, after its checks: prefix
+        lookup, prefill or suffix extension, first token, splice."""
+        bucket = min(_bucket(real_len), self.max_len)
         tree = self._prefix_tree
         pkey = tuple(int(t) for t in prompt) if tree is not None else None
         hit = None
@@ -768,6 +792,10 @@ class DecodeServer:
             if (matched == real_len and not partial
                     and anc.last is not None):
                 hit = anc  # whole-prompt node: replayable logits + row
+        # from the first dispatch to the first token on the host
+        device = obs_trace.timed("serve/admit/device",
+                                 self._obs_admit_device)
+        device.__enter__()
         if hit is not None:
             tree.touch(hit)  # the whole ancestor path, not one entry
             self._prompt_hits += 1
@@ -802,7 +830,6 @@ class DecodeServer:
                 # splices below under its own (wider) width
                 last, row, d_row = extended
                 self._prefix_hits += 1
-                self._obs_prefix.add()
                 flight.record("serve.prefix.hit",
                               a=min(matched, real_len - 1),
                               b=real_len - min(matched, real_len - 1))
@@ -827,10 +854,10 @@ class DecodeServer:
             self._prompt_tokens += real_len
             if tree is not None:
                 self._admit_to_tree(pkey, last, row, d_row)
-        req_temp = self._temperature if temperature is None else temperature
         self._rng, sub = jax.random.split(self._rng)
         first = int(sample_token(last[None], sub, req_temp,
                                  self._top_k, self._top_p)[0])
+        device.__exit__(None, None, None)
         # splice widths come from the rows themselves: a radix-served
         # row is prefix-bucket + suffix-bucket wide, and the target and
         # draft rows may differ (each extended from its own ancestor
@@ -849,7 +876,7 @@ class DecodeServer:
         self._next_id += 1
         self._n_requests += 1
         entry = _Slot(request_id=rid, tokens=[first],
-                      max_new=max_new_tokens, stop=frozenset(stop))
+                      max_new=max_new_tokens, stop=stop)
         self._slot[slot] = entry
         self._lengths[slot] = real_len
         self._tokens[slot] = first
@@ -866,23 +893,26 @@ class DecodeServer:
         decoded token(s) (already appended to its result)."""
         if self.idle:
             return []
-        t0 = time.perf_counter()
-        if self.draft is not None and self._k > 0:
-            # k can reach 0 when the adaptive controller concludes this
-            # draft cannot pay (optimal_draft_depth allow_disable) —
-            # the server then serves plain greedy rounds below, which
-            # read the same _tokens/_lengths state the spec rounds kept.
-            # Disable is NOT forever: submit() re-probes at the next idle
-            # admission boundary (see _maybe_rearm_speculation).
-            emitted = self._spec_step()
-            self._obs_record_round(t0, len(emitted))
-            return emitted
+        with self._round() as device:
+            if self.draft is not None and self._k > 0:
+                # k can reach 0 when the adaptive controller concludes
+                # this draft cannot pay (optimal_draft_depth
+                # allow_disable) — the server then serves plain greedy
+                # rounds below, which read the same _tokens/_lengths
+                # state the spec rounds kept.  Disable is NOT forever:
+                # submit() re-probes at the next idle admission boundary
+                # (see _maybe_rearm_speculation).
+                return self._spec_step(device)
+            return self._plain_step(device)
+
+    def _plain_step(self, device) -> list[tuple[int, int]]:
         self._plain_rounds += 1
-        nxt, self._cache, self._rng = self._step(
-            self.params, jnp.asarray(self._tokens), self._cache,
-            jnp.asarray(self._lengths), jnp.asarray(self._temps),
-            self._rng)
-        nxt = np.asarray(nxt)
+        inputs = (jnp.asarray(self._tokens), self._cache,
+                  jnp.asarray(self._lengths), jnp.asarray(self._temps))
+        with device:
+            nxt, self._cache, self._rng = self._step(
+                self.params, *inputs, self._rng)
+            nxt = np.asarray(nxt)
         emitted: list[tuple[int, int]] = []
         for i, entry in enumerate(self._slot):
             if entry is None:
@@ -897,7 +927,6 @@ class DecodeServer:
                 self._retire(i)
         self._n_steps += 1
         self._n_emitted += len(emitted)
-        self._obs_record_round(t0, len(emitted))
         return emitted
 
     def step_many(self, max_rounds: int = 8) -> list[tuple[int, int]]:
@@ -920,11 +949,8 @@ class DecodeServer:
         sequence and math; tested)."""
         if self.idle:
             return []
-        t0 = time.perf_counter()
         if self.draft is not None and self._k > 0:
-            emitted = self._spec_step()
-            self._obs_record_round(t0, len(emitted))
-            return emitted
+            return self.step()
         remaining = [entry.max_new - len(entry.tokens)
                      for entry in self._slot if entry is not None]
         n = max(1, min([max_rounds] + remaining))
@@ -935,52 +961,57 @@ class DecodeServer:
         n = 1 << (n.bit_length() - 1)
         if n == 1:
             return self.step()
-        runner = _multi_step_runner(self.model, self.slots, self._top_k,
-                                    self._top_p, self.cache_dtype, n)
-        outs, last, self._cache, self._rng = runner(
-            self.params, jnp.asarray(self._tokens), self._cache,
-            jnp.asarray(self._lengths), jnp.asarray(self._temps),
-            self._rng)
-        outs = np.asarray(outs)                   # [n, B]
-        last = np.asarray(last)
-        emitted: list[tuple[int, int]] = []
-        for r in range(n):
-            for i, entry in enumerate(self._slot):
-                if entry is None:
-                    continue
-                token = int(outs[r, i])
-                entry.tokens.append(token)
-                emitted.append((entry.request_id, token))
-                if self._finishes(entry, token):
-                    # later fused rounds decoded garbage continuations
-                    # for this lane; they are simply not appended
-                    self._retire(i)
-        # mirror what the device wrote: every lane (retired included)
-        # advanced n positions and holds its last fused token
-        self._lengths += n
-        self._tokens[:] = last
-        self._n_steps += n
-        self._n_emitted += len(emitted)
-        self._plain_rounds += n
-        self._obs_record_round(t0, len(emitted))
+        with self._round(rounds=n) as device:
+            runner = _multi_step_runner(self.model, self.slots,
+                                        self._top_k, self._top_p,
+                                        self.cache_dtype, n)
+            inputs = (jnp.asarray(self._tokens), self._cache,
+                      jnp.asarray(self._lengths),
+                      jnp.asarray(self._temps))
+            with device:
+                outs, last, self._cache, self._rng = runner(
+                    self.params, *inputs, self._rng)
+                outs = np.asarray(outs)                   # [n, B]
+                last = np.asarray(last)
+            emitted: list[tuple[int, int]] = []
+            for r in range(n):
+                for i, entry in enumerate(self._slot):
+                    if entry is None:
+                        continue
+                    token = int(outs[r, i])
+                    entry.tokens.append(token)
+                    emitted.append((entry.request_id, token))
+                    if self._finishes(entry, token):
+                        # later fused rounds decoded garbage
+                        # continuations for this lane; they are simply
+                        # not appended
+                        self._retire(i)
+            # mirror what the device wrote: every lane (retired included)
+            # advanced n positions and holds its last fused token
+            self._lengths += n
+            self._tokens[:] = last
+            self._n_steps += n
+            self._n_emitted += len(emitted)
+            self._plain_rounds += n
         return emitted
 
-    def _spec_step(self) -> list[tuple[int, int]]:
+    def _spec_step(self, device) -> list[tuple[int, int]]:
         """One speculative round: commit each slot's accepted prefix plus
         the target's correction token.  Free/garbage lanes advance their
         device-side frontiers like active ones (host state must mirror
-        what the device wrote; a reused slot's splice resets both)."""
-        (commit, n_commit, cur_new, y_new, self._cache, self._d_cache,
-         self._rng) = self._spec_round(
-            self.params, self.draft_params,
-            jnp.asarray(self._tokens), jnp.asarray(self._prev),
-            self._cache, self._d_cache,
-            jnp.asarray(self._lengths), jnp.asarray(self._d_lengths),
-            self._rng)
-        commit = np.asarray(commit)
-        n_commit = np.asarray(n_commit)
-        cur_new = np.asarray(cur_new)
-        y_new = np.asarray(y_new)
+        what the device wrote; a reused slot's splice resets both).
+        ``device`` is the round's dispatch-to-host leg (see step())."""
+        inputs = (jnp.asarray(self._tokens), jnp.asarray(self._prev),
+                  self._cache, self._d_cache,
+                  jnp.asarray(self._lengths), jnp.asarray(self._d_lengths))
+        with device:
+            (commit, n_commit, cur_new, y_new, self._cache, self._d_cache,
+             self._rng) = self._spec_round(
+                self.params, self.draft_params, *inputs, self._rng)
+            commit = np.asarray(commit)
+            n_commit = np.asarray(n_commit)
+            cur_new = np.asarray(cur_new)
+            y_new = np.asarray(y_new)
         emitted: list[tuple[int, int]] = []
         round_proposed = round_accepted = 0
         for i, entry in enumerate(self._slot):
@@ -1011,19 +1042,28 @@ class DecodeServer:
         self._n_emitted += len(emitted)
         return emitted
 
-    def _obs_record_round(self, t0: float, n_tokens: int) -> None:
-        """Mirror one decode round into the process-wide obs registry:
-        round latency, emitted tokens, queue depth (active slots), the
-        instantaneous token rate, and (speculative mode) the lifetime
-        accept rate — what obs/export rolls up for pst-status."""
-        dt = time.perf_counter() - t0
-        self._obs_round.observe(dt)
-        self._obs_tokens.add(n_tokens)
-        self._obs_active.set(self.active)
-        if dt > 0:
-            self._obs_rate.set(n_tokens / dt)
-        if self._spec_proposed:
-            self._obs_accept.set(self._spec_accepted / self._spec_proposed)
+    @contextlib.contextmanager
+    def _round(self, **args):
+        """One decode round as its legs, into the process-wide obs registry
+        (and the span buffer while obs/trace records).  Yields the leg to
+        hold open from the dispatch until the tokens are on the host
+        (``serve.round_device_s``); the rest of the block is
+        ``serve.round_host_s``, the whole ``serve.round_s``.  What passed
+        since the last round returned, while a slot was active, is the
+        caller's time (its loop, its admissions): one observation of
+        ``serve.between_rounds_s``.  Slots in use and the draft's accept
+        rate are in :attr:`stats`."""
+        t0 = time.perf_counter()
+        if self._round_returned is not None:
+            self._obs_between.observe(t0 - self._round_returned)
+        emitted = self._n_emitted
+        with obs_trace.timed("serve/round/host", self._obs_round_host,
+                             **args) as call:
+            yield call.carve("serve/round/device", self._obs_round_device)
+        now = time.perf_counter()
+        self._round_returned = None if self.idle else now
+        self._obs_round.observe(now - t0)
+        self._obs_tokens.add(self._n_emitted - emitted)
 
     def _finishes(self, entry: _Slot, token: int) -> bool:
         return (len(entry.tokens) >= entry.max_new

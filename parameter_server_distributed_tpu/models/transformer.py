@@ -22,6 +22,7 @@ transformer flows through the PS protocol, checkpointing, and ShardedTrainer.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import logging
 import math
@@ -139,6 +140,21 @@ class TransformerConfig:
         return self.moe_every > 0 and (i + 1) % self.moe_every == 0
 
 
+def scoped(name: str):
+    """Run the decorated block of the model under ``jax.named_scope(name)``:
+    every device operation it lowers to then carries the block's path in
+    its metadata (a trace, an HLO dump).  Metadata only: the compiled
+    program and its compile-cache key do not change."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def in_scope(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return in_scope
+    return decorate
+
+
+@scoped("loss")
 def next_token_nll(logits: Array, tokens: Array) -> Array:
     """Mean next-token cross-entropy from full-sequence logits.  The single
     definition shared by Transformer.loss and the pipelined LM
@@ -549,6 +565,7 @@ class Transformer:
 
     # --- shared layer pieces (used by _forward AND generation.decode_step,
     # so the layer math exists exactly once) -----------------------------
+    @scoped("norm")
     def _norm(self, params: Mapping[str, Array], key: str, x: Array) -> Array:
         """rms_norm or layer_norm per config — ``key`` is the ln prefix
         (e.g. "layer0/ln1")."""
@@ -558,6 +575,7 @@ class Transformer:
                               params[f"{key}/bias"], c.norm_eps)
         return rms_norm(x, params[f"{key}/scale"], c.norm_eps)
 
+    @scoped("attn_qkv")
     def qkv(self, params: Mapping[str, Array], prefix: str, h: Array,
             positions: Array) -> tuple[Array, Array, Array]:
         """ln1 -> q/k/v projections (+ biases) -> head split -> rope (or
@@ -587,6 +605,7 @@ class Transformer:
         return (rope(q, positions, c.rope_theta),
                 rope(k, positions, c.rope_theta), v)
 
+    @scoped("attn_out")
     def attn_residual(self, params: Mapping[str, Array], prefix: str,
                       h: Array, attn: Array) -> Array:
         """h + wo(attn) (+ bias).  attn: [B, S, H, D]."""
@@ -599,6 +618,7 @@ class Transformer:
             out = out + params[f"{prefix}/attn/bo"].astype(jnp.float32)
         return h + out.astype(c.dtype)
 
+    @scoped("mlp")
     def mlp_residual(self, params: Mapping[str, Array], prefix: str,
                      h: Array) -> Array:
         """h + w2(gelu(w1(ln2(h)))) (+ biases), or the SwiGLU gated form
@@ -649,11 +669,13 @@ class Transformer:
                                        capacity_override=cap)
         return h + moe_out.astype(self.config.dtype), aux
 
+    @scoped("head")
     def final_logits(self, params: Mapping[str, Array], h: Array) -> Array:
         h = self._norm(params, "final_ln", h)
         return wdot(h, params["lm_head/w"],
                     preferred_element_type=jnp.float32)
 
+    @scoped("embed")
     def embed(self, params: Mapping[str, Array], tokens: Array,
               positions: Array) -> Array:
         """Token (+ learned positional) embedding — the single definition
@@ -697,7 +719,8 @@ class Transformer:
             # K/V go to the attention fn UNexpanded (kv_heads-sized);
             # each implementation expands at the math (expand_gqa), so
             # ring/Ulysses communicate the small tensors
-            attn = self.attention_fn(q, k, v)
+            with jax.named_scope("attn"):
+                attn = self.attention_fn(q, k, v)
             h = self.attn_residual(layer_params, p, h, attn)
             h = self._constrain(h, ("data", "fsdp"), "seq", None)
             if i is None:  # scan body: homogeneous dense layers
@@ -767,6 +790,7 @@ class Transformer:
             nll = next_token_nll(self.final_logits(params, h), tokens)
         return nll + self.config.moe_aux_coef * aux
 
+    @scoped("loss")
     def _chunked_next_token_nll(self, params: Mapping[str, Array],
                                 h: Array, tokens: Array) -> Array:
         """Mean next-token NLL with the LM head computed in seq chunks of

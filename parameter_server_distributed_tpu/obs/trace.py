@@ -16,6 +16,26 @@ Enable with :func:`enable`, ``PSDT_TRACE=1``, or ``PSDT_TRACE_FILE=path``
 expands to the pid — how multi-process cluster runs each drop their slice;
 :func:`merge_chrome_traces` stitches the slices into one file that renders
 in ``chrome://tracing`` / Perfetto with a shared trace id per step).
+
+**Clock.**  A span's ``ts`` is ``time.time()`` at its opening: the Unix
+clock, in seconds.  ``dur`` is a difference of that clock for ``span`` /
+``server_span`` / ``SpanHolder`` and of ``time.perf_counter()`` for
+:class:`timed` (which times its block once for the histogram and the
+span).
+
+**One timeline with the profiler.**  While recording is on and ``jax`` is
+ALREADY imported in the process, every span also holds a
+``jax.profiler.TraceAnnotation("psdt/<span name>")`` open for its
+lifetime, on the thread that does the work: a ``jax.profiler`` trace taken
+at the same time (``PSDT_TRACE_DIR``) then shows the program's spans on
+the profiler's own clock beside the device operations.  A process that
+never imports JAX (parameter server, coordinator) pays nothing and is
+never made to import it.  ``jax.profiler.ProfileData`` counts an event's
+``start_ns`` from the start of the profiler session (the trace's own
+``profile_start_time``, on the Unix clock), so ``ts - start_ns * 1e-9`` is
+one constant per session: over 200 spans of one session on the v5e it
+held to 7 us, and an annotation outlasts its span's ``dur`` by 5 us
+(PERF.md section 6, PR 24).
 """
 
 from __future__ import annotations
@@ -25,6 +45,7 @@ import contextlib
 import json
 import os
 import signal
+import sys
 import threading
 import time
 from collections import deque
@@ -57,6 +78,53 @@ def _stack() -> list:
     if stack is None:
         stack = _tls.stack = []
     return stack
+
+
+def _mirror(name: str):
+    """An open ``psdt/<name>`` annotation on the profiler's timeline, or
+    None where this process has not imported JAX (see module docstring).
+    Only called while recording is on."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)  # None mid-import too
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation("psdt/" + name)
+    annotation.__enter__()
+    return annotation
+
+
+def _open(name: str, args: dict, remote: tuple[str, str] | None = None):
+    """Push a new span onto this thread's stack (child of ``remote`` when
+    given, else of the innermost open span, else the root of a fresh
+    trace) and open its mirror.  A span without an ``iteration`` of its
+    own takes its parent's, so that the legs opened deep in the data
+    plane (rings, codec) can be matched to the step they served."""
+    stack = _stack()
+    if remote is not None:
+        trace_id, parent_id = remote
+    elif stack:
+        trace_id, parent_id = stack[-1][0], stack[-1][1]
+    else:
+        trace_id, parent_id = _new_id(), ""
+    if "iteration" not in args and stack and stack[-1][2] is not None:
+        args["iteration"] = stack[-1][2]
+    span_id = _new_id()
+    stack.append((trace_id, span_id, args.get("iteration")))
+    return trace_id, span_id, parent_id, _mirror(name)
+
+
+def _close(name: str, opened, t0: float, dur: float, args: dict) -> None:
+    trace_id, span_id, parent_id, annotation = opened
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
+    stack = _stack()
+    for i in range(len(stack) - 1, -1, -1):
+        if stack[i][1] == span_id:
+            # the top, unless a span opened inside this one was left open
+            # by an exception: that one goes with it
+            del stack[i:]
+            break
+    _record(name, trace_id, span_id, parent_id, t0, dur, args)
 
 
 def current() -> tuple[str, str] | None:
@@ -109,18 +177,91 @@ def span(name: str, **args: Any) -> Iterator[None]:
     if not _enabled:
         yield
         return
-    stack = _stack()
-    trace_id = stack[-1][0] if stack else _new_id()
-    parent_id = stack[-1][1] if stack else ""
-    span_id = _new_id()
-    stack.append((trace_id, span_id))
+    opened = _open(name, args)
     t0 = time.time()
     try:
         yield
     finally:
-        dur = time.time() - t0
-        stack.pop()
-        _record(name, trace_id, span_id, parent_id, t0, dur, args)
+        _close(name, opened, t0, time.time() - t0, args)
+
+
+class timed:
+    """Time a block ONCE for both records: the histogram (always, when one
+    is given) and the span ``name`` (while recording is on).
+
+    >>> with timed("worker/pack", hist, bytes=n):
+    ...     pack()
+
+    :meth:`carve` takes a leg OUT of the block: the time spent inside the
+    carved leg (entered any number of times) is summed into one record of
+    its own and subtracted from this block's, so that the two add up to
+    the block's wall time.  That is how a ring frame splits into moving
+    bytes and being blocked on the peer without one span per probe.  In
+    the span buffer the carved leg is laid first and the block's own time
+    after it, both inside the block's real interval (durations exact,
+    order inside the block not kept); on the profiler's timeline the
+    block's annotation stays open and each entry of the carved leg nests
+    in it where it happened."""
+
+    __slots__ = ("_name", "_hist", "args", "_opened", "_ts", "_t0",
+                 "_carved")
+
+    def __init__(self, name: str, hist=None, **args: Any):
+        self._name = name
+        self._hist = hist
+        self.args = args          # may be added to until the block ends
+        self._carved: _Carved | None = None
+
+    def __enter__(self) -> "timed":
+        if _enabled:
+            self._opened = _open(self._name, self.args)
+            self._ts = time.time()
+        else:
+            self._opened = None
+        self._t0 = time.perf_counter()
+        return self
+
+    def carve(self, name: str, hist=None) -> "_Carved":
+        self._carved = _Carved(name, hist)
+        return self._carved
+
+    def __exit__(self, *exc) -> None:
+        own = time.perf_counter() - self._t0
+        carved = self._carved
+        out = carved.total if carved is not None else 0.0
+        own -= out
+        if self._hist is not None:
+            self._hist.observe(own)
+        if out and carved.hist is not None:
+            carved.hist.observe(out)
+        if self._opened is None:
+            return
+        if out:
+            # a sibling of the block's own span: same parent, same args
+            _record(carved.name, self._opened[0], _new_id(),
+                    self._opened[2], self._ts, out, self.args)
+        _close(self._name, self._opened, self._ts + out, own, self.args)
+
+
+class _Carved:
+    """The leg a :class:`timed` block carves out of itself; re-enterable."""
+
+    __slots__ = ("name", "hist", "total", "_t0", "_annotation")
+
+    def __init__(self, name: str, hist):
+        self.name = name
+        self.hist = hist
+        self.total = 0.0
+
+    def __enter__(self) -> "_Carved":
+        self._annotation = _mirror(self.name) if _enabled else None
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.total += time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
 
 
 @contextlib.contextmanager
@@ -134,7 +275,7 @@ def attach(ctx: tuple[str, str] | None) -> Iterator[None]:
         yield
         return
     stack = _stack()
-    stack.append((ctx[0], ctx[1]))
+    stack.append((ctx[0], ctx[1], None))
     try:
         yield
     finally:
@@ -149,22 +290,12 @@ def server_span(name: str, ctx: bytes | str, **args: Any) -> Iterator[None]:
     if not _enabled:
         yield
         return
-    parsed = parse_context(ctx)
-    if parsed is None:
-        with span(name, **args):
-            yield
-        return
-    trace_id, parent_id = parsed
-    span_id = _new_id()
-    stack = _stack()
-    stack.append((trace_id, span_id))
+    opened = _open(name, args, remote=parse_context(ctx))
     t0 = time.time()
     try:
         yield
     finally:
-        dur = time.time() - t0
-        stack.pop()
-        _record(name, trace_id, span_id, parent_id, t0, dur, args)
+        _close(name, opened, t0, time.time() - t0, args)
 
 
 class SpanHolder:
@@ -179,13 +310,14 @@ class SpanHolder:
     the handler call)."""
 
     __slots__ = ("name", "args", "_t0", "_span_id", "_trace_id",
-                 "_parent_id", "_pushed")
+                 "_parent_id", "_pushed", "_annotation")
 
     def __init__(self, name: str, **args: Any):
         self.name = name
         self.args = args
         self._t0 = time.time() if _enabled else 0.0
         self._span_id = _new_id() if _enabled else ""
+        self._annotation = _mirror(name) if _enabled else None
         self._trace_id: str | None = None
         self._parent_id = ""
         self._pushed = False
@@ -197,10 +329,13 @@ class SpanHolder:
         if parsed is None:
             return
         self._trace_id, self._parent_id = parsed
-        _stack().append((self._trace_id, self._span_id))
+        _stack().append((self._trace_id, self._span_id, None))
         self._pushed = True
 
     def finish(self) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if not _enabled:
             return
         if self._pushed:

@@ -361,7 +361,6 @@ def run_training(config: TrainLoopConfig) -> dict:
     obs_data = obs_stats.histogram("train.data_s")
     obs_dispatch = obs_stats.histogram("train.dispatch_s")
     obs_step = obs_stats.histogram("train.step_s")
-    obs_rate = obs_stats.gauge("train.samples_per_sec_chip")
 
     last_saved_step = -1
     last_eval = (-1, float("nan"))
@@ -386,8 +385,6 @@ def run_training(config: TrainLoopConfig) -> dict:
                     dt = (time.perf_counter() - window_t0) / window_steps
                     timer.record(dt)
                     obs_step.observe(dt)
-                    obs_rate.set(samples_per_sec(config.batch_size, dt,
-                                                 n_chips))
                     metrics_log.log(step=step_idx + 1, loss=last_loss,
                                     step_time_s=dt,
                                     samples_per_sec_chip=samples_per_sec(

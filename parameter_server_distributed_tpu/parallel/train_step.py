@@ -245,8 +245,10 @@ def make_train_step(loss_fn: Callable,
             grads = jax.tree.map(
                 lambda g, p: (g / accum_steps).astype(p.dtype), gsum,
                 state.params)
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, state.opt_state,
+                                                state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(params=new_params, opt_state=new_opt,
                                step=state.step + 1)
         grad_norm = optax.global_norm(grads)

@@ -44,7 +44,6 @@ from .replicator import (OPT_PREFIX, delta_chunks, replication_client,
 log = logging.getLogger("pst.reshard")
 
 _obs_moved = obs_stats.counter("ps.reshard.moved_bytes")
-_obs_ops = obs_stats.counter("ps.reshard.ops")
 
 
 class ReshardError(RuntimeError):
@@ -180,7 +179,6 @@ class ReshardController:
             # repartition)
             epoch = self._core.set_shard_map(new_entries)
             _obs_moved.add(moved_bytes)
-            _obs_ops.add()
             log.info("reshard complete: %d -> %d shards at epoch %d "
                      "(%d tensors, %.1f MB moved)", len(old_primaries),
                      n_new, epoch, moved_tensors, moved_bytes / 1e6)

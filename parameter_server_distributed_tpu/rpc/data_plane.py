@@ -273,8 +273,6 @@ class PSClient(RpcClient):
         # mismatch / version-bookkeeping failure)
         self._delta_state = DeltaPullState()
         self._delta_ok: bool | None = None
-        self._obs_delta_rounds = obs_stats.counter("rpc.client.delta.rounds")
-        self._obs_delta_bytes = obs_stats.counter("rpc.client.delta.bytes")
 
     def _streaming(self) -> bool:
         return self.chunk_bytes > 0 and self._stream_ok is not False
@@ -417,9 +415,6 @@ class PSClient(RpcClient):
             self._delta_downgrade(f"base mismatch: {exc}")
             return None
         self._delta_ok = True
-        self._obs_delta_rounds.add()
-        if result.served_delta:
-            self._obs_delta_bytes.add(result.wire_bytes)
         return result
 
     def delta_pull(self, request: m.PullRequest,
@@ -568,12 +563,20 @@ class PSClient(RpcClient):
                         for chunk in chunks():
                             if ctx:
                                 chunk.trace_context = ctx
-                            yield chunk.encode()
+                            with obs_trace.span("rpc/client/encode"):
+                                frame = chunk.encode()
+                            yield frame
+
+                    def decoded(frames) -> Iterator[m.PushPullResponse]:
+                        for f in frames:
+                            with obs_trace.span("rpc/client/decode",
+                                                bytes=len(f)):
+                                frame = m.PushPullResponse.decode(
+                                    memoryview(f))
+                            yield frame
 
                     frames = conn.round_trip(encoded_frames(), timeout)
-                    result = self._assemble_fused(
-                        (m.PushPullResponse.decode(memoryview(f))
-                         for f in frames), on_chunk)
+                    result = self._assemble_fused(decoded(frames), on_chunk)
                 # the server just proved it speaks the fused protocol
                 self._fused_ok = True
                 ok = True

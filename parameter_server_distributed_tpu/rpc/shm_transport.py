@@ -49,7 +49,9 @@ rings by this process; ``rpc.shm.fallback`` counts downgrades to TCP
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import itertools
 import logging
 import os
 import socket
@@ -254,6 +256,10 @@ class ShmRing:
             del carr  # export released; the address outlives it
         else:
             self._base = 0
+        # the blocked-on-the-peer leg of the frame being moved (obs/trace
+        # ``timed.carve``); one thread drives a ring's end, so a plain
+        # attribute does
+        self._blocked = None
 
     # ------------------------------------------------------------- cursors
     def _tail(self) -> int:
@@ -300,6 +306,14 @@ class ShmRing:
         loop (each hand-off costs a full switch interval), so yielding
         immediately is strictly faster in-process and costs at most one
         ~20 us sleep cross-process."""
+        n = ready()
+        if n:
+            return n
+        with self._blocked or contextlib.nullcontext():
+            return self._park(ready, deadline, what)
+
+    def _park(self, ready: Callable[[], int], deadline: float,
+              what: str) -> int:
         while True:
             n = ready()
             if n:
@@ -358,6 +372,18 @@ class ShmRing:
                 self.doorbell.ring()
             sent += n
 
+    @contextlib.contextmanager
+    def _frame(self, **args):
+        """One frame through the ring as two legs: ``rpc/shm/copy`` (moving
+        the bytes) and, carved out of it, ``rpc/shm/wait`` (every wait of
+        the frame summed: ring full when writing, empty when reading)."""
+        with obs_trace.timed("rpc/shm/copy", **args) as frame:
+            self._blocked = frame.carve("rpc/shm/wait")
+            try:
+                yield frame
+            finally:
+                self._blocked = None
+
     # End-of-stream sentinel in the length slot.  Deliberately NOT length
     # zero: a fully-default GradientUpdate legally encodes to b"" under
     # proto3 default elision (the sharded-topology empty barrier
@@ -369,12 +395,14 @@ class ShmRing:
         """One length-prefixed frame (zero-length payloads are legal).
         Frames larger than the ring stream through it — the consumer
         drains while the producer refills."""
-        try:
-            self._write_bytes(struct.pack("<I", len(payload)), deadline)
-            if len(payload):
-                self._write_bytes(payload, deadline)
-        except ValueError as exc:  # memoryview released under us
-            raise ShmTransportError(f"shm segment released: {exc}") from exc
+        with self._frame(bytes=len(payload)):
+            try:
+                self._write_bytes(struct.pack("<I", len(payload)), deadline)
+                if len(payload):
+                    self._write_bytes(payload, deadline)
+            except ValueError as exc:  # memoryview released under us
+                raise ShmTransportError(
+                    f"shm segment released: {exc}") from exc
         _obs_bytes.add(4 + len(payload))
 
     def write_end(self, deadline: float) -> None:
@@ -420,15 +448,19 @@ class ShmRing:
 
     def read_frame(self, deadline: float) -> bytes | None:
         """The next frame's payload, or None at an end-of-stream marker."""
-        try:
-            (length,) = struct.unpack("<I", self._read_bytes(4, deadline))
-            if length == self._END:
-                _obs_bytes.add(4)
-                return None
-            payload = bytes(self._read_bytes(length, deadline)) if length \
-                else b""
-        except ValueError as exc:  # memoryview released under us
-            raise ShmTransportError(f"shm segment released: {exc}") from exc
+        with self._frame() as frame:
+            try:
+                (length,) = struct.unpack("<I",
+                                          self._read_bytes(4, deadline))
+                if length == self._END:
+                    _obs_bytes.add(4)
+                    return None
+                payload = bytes(self._read_bytes(length, deadline)) \
+                    if length else b""
+            except ValueError as exc:  # memoryview released under us
+                raise ShmTransportError(
+                    f"shm segment released: {exc}") from exc
+            frame.args["bytes"] = length
         _obs_bytes.add(4 + length)
         return payload
 
@@ -658,11 +690,11 @@ class _ServerConnection:
                                               transport="shm")
 
                 def chunks() -> Iterator[m.Message]:
-                    chunk = m.GradientUpdate.decode(first)
-                    holder.adopt(getattr(chunk, "trace_context", b""))
-                    yield chunk
-                    for frame in self._request_frames():
-                        chunk = m.GradientUpdate.decode(frame)
+                    for frame in itertools.chain((first,),
+                                                 self._request_frames()):
+                        with obs_trace.span("rpc/server/decode",
+                                            bytes=len(frame)):
+                            chunk = m.GradientUpdate.decode(frame)
                         holder.adopt(getattr(chunk, "trace_context", b""))
                         yield chunk
                     drained[0] = True
@@ -670,7 +702,9 @@ class _ServerConnection:
                 deadline = time.monotonic() + 3600.0
                 try:
                     for resp in self._handler(chunks(), None):
-                        self.s2c.write_frame(resp.encode(), deadline)
+                        with obs_trace.span("rpc/server/encode"):
+                            frame = resp.encode()
+                        self.s2c.write_frame(frame, deadline)
                 finally:
                     holder.finish()
                     flight.record(

@@ -220,11 +220,8 @@ class ParameterServerService:
             lambda chunks, ctx: self.PushPullStream(chunks, ctx))
         # aggregation/serve timing net of RPC plumbing (the handler-level
         # latency histograms live in rpc/service.bind_service)
-        self._obs_apply = obs_stats.histogram("ps.apply_s")
-        self._obs_serve = obs_stats.histogram("ps.serve_s")
         # fused data plane: how long PushPullStream handlers park on the
         # barrier condition variable before serving
-        self._obs_barrier = obs_stats.histogram("ps.barrier_wait_s")
         # encode-once broadcast cache (see EncodedServeCache): hit = this
         # serve replayed cached wire bytes; miss = it ran the encode
         self._serve_cache = EncodedServeCache()
@@ -255,7 +252,6 @@ class ParameterServerService:
         self._obs_delta_hit = obs_stats.counter("ps.serve.delta_hit")
         self._obs_delta_miss = obs_stats.counter("ps.serve.delta_miss")
         self._obs_delta_bytes = obs_stats.counter("ps.serve.delta_bytes")
-        self._obs_sub_refused = obs_stats.counter("ps.publish.refused")
         # replication sink (replication/replicator.py): installs
         # primary->backup delta streams and tracks the replication
         # high-water mark.  Always present — ANY PS can serve as a
@@ -273,22 +269,26 @@ class ParameterServerService:
         """Decoded-gradients -> core aggregation, timed and traced (the
         "PS apply" leg of the distributed step trace — the enclosing
         handler span carries the worker's trace id)."""
-        t0 = time.perf_counter()
         with obs_trace.span("ps/apply", worker=worker_id,
                             iteration=iteration):
             result = self.core.receive_gradients(worker_id, iteration, grads)
-        self._obs_apply.observe(time.perf_counter() - t0)
         return result
+
+    @staticmethod
+    def _decode(chunk: m.GradientUpdate, device: bool) -> dict:
+        """One push chunk's wire tensors to fold-ready arrays: the decode
+        leg of the server's codec."""
+        with obs_trace.span("rpc/server/decode", worker=chunk.worker_id,
+                            iteration=chunk.iteration):
+            return decode_gradients(chunk.gradients, device)
 
     def _commit(self, sink: PushSink):
         """End-of-stream commit of a chunk-folded push, timed/traced like
         :meth:`_apply` (the fold legs were already accounted inside the
         stream loop — they overlap transport)."""
-        t0 = time.perf_counter()
         with obs_trace.span("ps/apply", worker=sink.worker_id,
                             iteration=sink.iteration):
             result = sink.commit()
-        self._obs_apply.observe(time.perf_counter() - t0)
         return result
 
     @staticmethod
@@ -346,10 +346,11 @@ class ParameterServerService:
         (see _parameter_chunks for why the fill must not be
         client-paced)."""
         _, params, _, version = self.core.serve_view(request_iteration)
-        tensors = to_wire(params, wire_dtype=eff_dtype)
-        bodies = encode_parameter_record_groups(
-            list(split_tensors(tensors, budget)),
-            stripes=self.core.stripes)
+        with obs_trace.span("rpc/server/encode", version=version):
+            tensors = to_wire(params, wire_dtype=eff_dtype)
+            bodies = encode_parameter_record_groups(
+                list(split_tensors(tensors, budget)),
+                stripes=self.core.stripes)
         return bodies, version
 
     def _serve_key(self, wire_dtype: int) -> tuple:
@@ -410,7 +411,6 @@ class ParameterServerService:
         return bodies, version
 
     def ServeParameters(self, request: m.PullRequest, context):
-        t0 = time.perf_counter()
         with obs_trace.span("ps/serve", worker=request.worker_id,
                             iteration=request.iteration):
             # label read BEFORE the bodies resolve: a serve must never
@@ -421,7 +421,6 @@ class ParameterServerService:
             bodies = self._encoded_parameter_chunks(request.iteration,
                                                     request.wire_dtype)
             resp = PreEncodedParameterUpdate(iteration, True, bodies)
-        self._obs_serve.observe(time.perf_counter() - t0)
         return resp
 
     # RPC (framework extension, rpc/data_plane.py): client-streamed push.
@@ -439,7 +438,7 @@ class ParameterServerService:
                 # each chunk straight to device buffers
                 device = self.core.device_fold
             if chunk.gradients:
-                sink.fold(decode_gradients(chunk.gradients, device))
+                sink.fold(self._decode(chunk, device))
         if sink is None:
             return m.PushResponse(success=False, message="empty push stream")
         return self._push_result_response(self._commit(sink))
@@ -526,7 +525,7 @@ class ParameterServerService:
                 pull_wire_dtype = chunk.pull_wire_dtype
                 device = self.core.device_fold  # see PushGradientsStream
             if chunk.gradients:
-                sink.fold(decode_gradients(chunk.gradients, device))
+                sink.fold(self._decode(chunk, device))
         if sink is None:
             yield m.PushPullResponse(push=m.PushResponse(
                 success=False, message="empty push stream"))
@@ -540,12 +539,10 @@ class ParameterServerService:
         if not result.success:
             return
         if not result.aggregation_complete:
-            t0 = time.perf_counter()
             with obs_trace.span("ps/barrier_wait", worker=worker_id,
                                 iteration=iteration):
                 ready, received, total = self.core.wait_for_aggregation(
                     iteration, timeout=self._fused_barrier_timeout_s())
-            self._obs_barrier.observe(time.perf_counter() - t0)
             if not ready:
                 log.warning(
                     "PushPullStream: barrier timeout at iteration %d "
@@ -554,12 +551,10 @@ class ParameterServerService:
                 yield m.PushPullResponse(params=m.ParameterUpdate(
                     iteration=self.core.current_iteration, ready=False))
                 return
-        t0 = time.perf_counter()
         with obs_trace.span("ps/serve", worker=worker_id,
                             iteration=iteration):
             for chunk in self._parameter_chunks(iteration, pull_wire_dtype):
                 yield m.PushPullResponse(params=chunk)
-        self._obs_serve.observe(time.perf_counter() - t0)
 
     # ------------------------------------------------------------ delta serve
     # Versioned delta serving + live weight publication (delta/, ISSUE
@@ -655,13 +650,11 @@ class ParameterServerService:
     # request advertises the held store version; the response is a delta
     # chain or a stamped full serve.
     def PullParametersDelta(self, request: dmsg.DeltaPullRequest, context):
-        t0 = time.perf_counter()
         with obs_trace.span("ps/serve", worker=request.worker_id,
                             iteration=request.iteration):
             frames, _ = self._delta_serve(request.held_version,
                                           request.wire_dtype,
                                           request.iteration)
-        self._obs_serve.observe(time.perf_counter() - t0)
         yield from frames
 
     # RPC (framework extension, delta/): the version-aware fused round.
@@ -695,7 +688,7 @@ class ParameterServerService:
                 held_version = int(dchunk.held_version)
                 device = self.core.device_fold  # see PushGradientsStream
             if chunk.gradients:
-                sink.fold(decode_gradients(chunk.gradients, device))
+                sink.fold(self._decode(chunk, device))
         if sink is None:
             yield dmsg.DeltaFrame(push=m.PushResponse(
                 success=False, message="empty push stream"))
@@ -707,12 +700,10 @@ class ParameterServerService:
         if not result.success:
             return
         if not result.aggregation_complete:
-            t0 = time.perf_counter()
             with obs_trace.span("ps/barrier_wait", worker=worker_id,
                                 iteration=iteration):
                 ready, received, total = self.core.wait_for_aggregation(
                     iteration, timeout=self._fused_barrier_timeout_s())
-            self._obs_barrier.observe(time.perf_counter() - t0)
             if not ready:
                 log.warning(
                     "PushPullDeltaStream: barrier timeout at iteration %d "
@@ -721,12 +712,10 @@ class ParameterServerService:
                 yield dmsg.DeltaFrame(params=m.ParameterUpdate(
                     iteration=self.core.current_iteration, ready=False))
                 return
-        t0 = time.perf_counter()
         with obs_trace.span("ps/serve", worker=worker_id,
                             iteration=iteration):
             frames, _ = self._delta_serve(held_version, pull_wire_dtype,
                                           iteration)
-        self._obs_serve.observe(time.perf_counter() - t0)
         yield from frames
 
     # How often a parked subscription handler re-probes liveness.  Short
@@ -763,7 +752,6 @@ class ParameterServerService:
             if admitted:
                 self._active_subscribers += 1
         if not admitted:
-            self._obs_sub_refused.add()
             log.warning(
                 "SubscribeWeights refused: %d live subscriptions at the "
                 "PSDT_MAX_SUBSCRIBERS=%d bound (subscriber %d backs off "

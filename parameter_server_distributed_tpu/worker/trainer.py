@@ -33,6 +33,7 @@ import numpy as np
 
 from ..analysis.lock_order import checked_lock
 from ..core.tensor import TensorStore
+from ..obs import trace as obs_trace
 
 # One dispatch at a time per process: trainer-originated XLA work (step
 # launch, bucket slice fetches) may run from several threads at once —
@@ -100,7 +101,9 @@ class GradientBuckets:
 
     @property
     def loss(self) -> float:
-        return float(self._fetch(0)[0])
+        # the first real fetch of bucket 0 blocks until the step is done:
+        # that wait is the step itself, not a D2H leg
+        return float(self._fetch(0, "worker/device_wait")[0])
 
     def _dev_slice(self, i: int):
         s = self._slices[i]
@@ -110,13 +113,15 @@ class GradientBuckets:
                 s = self._slices[i] = self._device[a:b]
         return s
 
-    def _fetch(self, i: int) -> np.ndarray:
+    def _fetch(self, i: int, leg: str = "worker/d2h") -> np.ndarray:
         with self._lock:
             buf = self._host.get(i)
             if buf is None:
                 if self.on_fetch is not None:
                     self.on_fetch(i, len(self._plan))
-                buf = self._host[i] = np.asarray(self._dev_slice(i))
+                a, b, _ = self._plan[i]
+                with obs_trace.span(leg, bucket=i, bytes=4 * (b - a)):
+                    buf = self._host[i] = np.asarray(self._dev_slice(i))
         return buf
 
     def _prefetch(self, i: int) -> None:
@@ -277,10 +282,14 @@ class Trainer:
     def _dispatch_step(self, params: Mapping[str, np.ndarray], batch):
         """Pack + upload + launch the jitted step; returns the (async)
         flat device output without fetching it."""
-        packed = self._pack(params)
+        with obs_trace.span("worker/pack", bytes=4 * self._padded_in):
+            packed = self._pack(params)
         with _DISPATCH_LOCK:
-            flat = jax.device_put(packed, self._flat_sharding)
-            return self._step(flat, self._shard_batch(batch))
+            with obs_trace.span("worker/h2d", bytes=4 * self._padded_in):
+                flat = jax.device_put(packed, self._flat_sharding)
+                batch = self._shard_batch(batch)
+            with obs_trace.span("worker/dispatch"):
+                return self._step(flat, batch)
 
     def compute_gradients(self, params: Mapping[str, np.ndarray],
                           batch) -> tuple[TensorStore, float]:
@@ -288,7 +297,13 @@ class Trainer:
 
         One H2D upload (packed params), one D2H fetch (loss + packed
         grads), regardless of tensor count."""
-        packed = np.asarray(self._dispatch_step(params, batch))
+        out = self._dispatch_step(params, batch)
+        # wait for the step first, so that the one whole-output fetch
+        # below times the copy alone
+        with obs_trace.span("worker/device_wait"):
+            out.block_until_ready()
+        with obs_trace.span("worker/d2h", bytes=4 * self._padded_out):
+            packed = np.asarray(out)
         loss = float(packed[0])
         grads = {name: packed[1 + off:1 + off + size].reshape(shape)
                  for name, off, size, shape, _dtype in self._layout}

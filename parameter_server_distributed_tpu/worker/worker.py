@@ -421,6 +421,15 @@ class Worker:
             return None
 
     # ------------------------------------------------------------ data plane
+    @staticmethod
+    def _chunk_converter(local: TensorStore):
+        """The ``on_chunk`` consumer of a pull: wire tensors of one chunk
+        to f32 arrays in ``local`` (the decode leg of the round)."""
+        def convert_chunk(tensors) -> None:
+            with obs_trace.span("rpc/client/decode", tensors=len(tensors)):
+                local.update(from_wire(tensors))
+        return convert_chunk
+
     def pull_parameters(self, iteration: int) -> tuple[int, TensorStore]:
         """reference: src/worker.cpp:240-252."""
         t0 = time.perf_counter()
@@ -437,10 +446,9 @@ class Worker:
             # dict, never into this retry's
             local: TensorStore = {}
 
-            def convert_chunk(tensors) -> None:
-                # f32 conversion per chunk AS IT ARRIVES, overlapping the
-                # transport of later chunks (rpc/data_plane.py on_chunk)
-                local.update(from_wire(tensors))
+            # f32 conversion per chunk AS IT ARRIVES, overlapping the
+            # transport of later chunks (rpc/data_plane.py on_chunk)
+            convert_chunk = self._chunk_converter(local)
 
             # Version-aware pull (delta/, ISSUE 10): advertise the held
             # version and let the PS answer O(changed bytes).  The
@@ -607,15 +615,20 @@ class Worker:
                 if compress:
                     adjusted = (ef_stage.adjust(name, g) if ef_stage
                                 else g)
-                    t = m.Tensor.from_array(
-                        name, adjusted, wire_dtype=push_dtype,
-                        topk_density=self.config.topk_density)
+                    with obs_trace.span("rpc/client/encode", tensor=name,
+                                        bytes=4 * g.size):
+                        t = m.Tensor.from_array(
+                            name, adjusted, wire_dtype=push_dtype,
+                            topk_density=self.config.topk_density)
                     if ef_stage is not None:
                         # what the receiver did NOT see carries into the
                         # next push
                         ef_stage.stage(name, adjusted, t)
                 else:
-                    t = m.Tensor.from_array(name, g, wire_dtype=push_dtype)
+                    with obs_trace.span("rpc/client/encode", tensor=name,
+                                        bytes=4 * g.size):
+                        t = m.Tensor.from_array(name, g,
+                                                wire_dtype=push_dtype)
                 wire += t.encoded_size()
                 yield t
             self._obs_push_payload.add(payload)
@@ -637,10 +650,7 @@ class Worker:
         tensors_fn, ef_stage = self._wire_tensors(
             grads, push_dtype=tier.push_dtype, ef=tier.push_ef)
         local: TensorStore = {}
-
-        def convert_chunk(chunk_tensors) -> None:
-            local.update(from_wire(chunk_tensors))
-
+        convert_chunk = self._chunk_converter(local)
         t0 = time.perf_counter()
         flight.record("fused.start", iteration=iteration,
                       worker=self.config.worker_id)
@@ -718,15 +728,11 @@ class Worker:
 
             # fresh store per attempt, same rationale as _pull_parameters
             local: TensorStore = {}
-
-            def convert_chunk(chunk_tensors) -> None:
-                local.update(from_wire(chunk_tensors))
-
             push, params = self._ps.push_pull(
                 self.config.worker_id, iteration, tensors_fn,
                 pull_wire_dtype=self._pull_wire_dtype(),
                 timeout=self.config.fused_timeout_s,
-                on_chunk=convert_chunk)
+                on_chunk=self._chunk_converter(local))
             return push, params, (local if params is not None else None)
 
         t0 = time.perf_counter()

@@ -1,0 +1,348 @@
+"""The legs of the PS round and of the decode round (ISSUE 24): spans and
+histograms where the work happens, their mirror on the profiler's timeline,
+and the ``jax.named_scope`` names on the device operations.
+
+Recording is process-wide state; every test that turns it on goes through
+the ``recording`` fixture, which puts it back.
+"""
+
+import collections
+import contextlib
+import glob
+import re
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from parameter_server_distributed_tpu.cli.worker_main import build_worker
+from parameter_server_distributed_tpu.config import (CoordinatorConfig,
+                                                     ParameterServerConfig,
+                                                     WorkerConfig)
+from parameter_server_distributed_tpu.models import generation
+from parameter_server_distributed_tpu.models.serving import DecodeServer
+from parameter_server_distributed_tpu.models.transformer import (
+    Transformer, TransformerConfig)
+from parameter_server_distributed_tpu.obs import stats as obs_stats
+from parameter_server_distributed_tpu.obs import trace as obs_trace
+from parameter_server_distributed_tpu.parallel.train_step import (
+    TrainState, make_train_step)
+from parameter_server_distributed_tpu.server.coordinator_service import (
+    Coordinator)
+from parameter_server_distributed_tpu.server.ps_service import ParameterServer
+
+WORKER_LEAVES = ("worker/pack", "worker/h2d", "worker/dispatch",
+                 "worker/device_wait", "worker/d2h", "rpc/client/encode",
+                 "rpc/client/decode", "rpc/shm/copy", "rpc/shm/wait")
+SERVING_HISTOGRAMS = ("serve.admit_s", "serve.admit_device_s",
+                      "serve.round_device_s", "serve.round_host_s",
+                      "serve.between_rounds_s", "serve.round_s")
+
+
+@pytest.fixture
+def recording():
+    obs_trace.clear()
+    obs_trace.enable(True)
+    yield
+    obs_trace.enable(False)
+    obs_trace.clear()
+
+
+def tiny(**kw):
+    cfg = dict(vocab=96, d_model=48, n_heads=4, n_layers=2, d_ff=96,
+               max_seq=128, dtype=jnp.float32)
+    cfg.update(kw)
+    return Transformer(TransformerConfig(**cfg))
+
+
+def counts() -> dict:
+    snap = obs_stats.REGISTRY.snapshot()
+    out = {name: snap["histograms"].get(name, {}).get("count", 0)
+           for name in SERVING_HISTOGRAMS}
+    out["serve.programs"] = snap["counters"].get("serve.programs", 0)
+    return out
+
+
+# ------------------------------------------------ (a) the profiler's mirror
+def host_events(trace_dir) -> list[tuple[str, str, float, float]]:
+    path = sorted(glob.glob(
+        f"{trace_dir}/plugins/profile/*/*.xplane.pb"))[-1]
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for index, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("psdt/"):
+                    out.append((ev.name, f"{line.name}#{index}",
+                                ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_spans_are_mirrored_onto_the_profilers_timeline(tmp_path, recording):
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.span("mirror/outer", iteration=7):
+            with obs_trace.timed("mirror/inner") as block:
+                blocked = block.carve("mirror/carved")
+                with blocked:
+                    time.sleep(0.002)
+                jnp.ones((8, 8)).sum().block_until_ready()
+                with blocked:
+                    time.sleep(0.002)
+        holder = obs_trace.SpanHolder("mirror/holder")
+        holder.finish()
+        obs_trace.enable(False)
+        with obs_trace.span("mirror/off"):
+            with obs_trace.timed("mirror/off_timed"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    events = host_events(tmp_path)
+    by_name = collections.defaultdict(list)
+    for name, thread, start, end in events:
+        by_name[name].append((thread, start, end))
+    assert {n: len(v) for n, v in by_name.items()} == {
+        "psdt/mirror/outer": 1, "psdt/mirror/inner": 1,
+        "psdt/mirror/carved": 2, "psdt/mirror/holder": 1}
+    (thread, o0, o1), = by_name["psdt/mirror/outer"]
+    (thread_i, i0, i1), = by_name["psdt/mirror/inner"]
+    # nested as the spans nest, on the thread that did the work
+    assert thread_i == thread and o0 <= i0 and i1 <= o1
+    for thread_c, c0, c1 in by_name["psdt/mirror/carved"]:
+        assert thread_c == thread and i0 <= c0 and c1 <= i1
+    # the buffer: the carved leg once, summed, and the block's own time
+    spans = {s["name"]: s for s in obs_trace.spans()}
+    assert set(spans) == {"mirror/outer", "mirror/inner", "mirror/carved",
+                          "mirror/holder"}
+    inner, carved = spans["mirror/inner"], spans["mirror/carved"]
+    assert carved["dur"] >= 0.004 and carved["parent_id"] == \
+        inner["parent_id"] == spans["mirror/outer"]["span_id"]
+    # laid end to end inside the block's real interval, and the iteration
+    # comes down from the enclosing span
+    assert carved["ts"] + carved["dur"] == pytest.approx(inner["ts"])
+    assert inner["args"]["iteration"] == carved["args"]["iteration"] == 7
+    # one clock per session: ts less start_ns is the same for every span
+    offsets = [spans[n[5:]]["ts"] - s * 1e-9 for n, _, s, _ in events
+               if n != "psdt/mirror/carved"]
+    assert max(offsets) - min(offsets) < 0.005
+
+
+def test_a_process_without_jax_is_not_made_to_import_it(monkeypatch,
+                                                        recording):
+    monkeypatch.delitem(sys.modules, "jax")
+    with obs_trace.span("nojax/a"):
+        with obs_trace.timed("nojax/b"):
+            pass
+    assert "jax" not in sys.modules
+    assert [s["name"] for s in obs_trace.spans()] == ["nojax/b", "nojax/a"]
+
+
+# ------------------------------------------------------ (c) recording off
+def test_recording_off_opens_nothing(monkeypatch):
+    assert not obs_trace.enabled()
+    obs_trace.clear()
+
+    def never(*a, **k):
+        raise AssertionError("allocated while recording is off")
+
+    monkeypatch.setattr(obs_trace, "_new_id", never)
+    monkeypatch.setattr(obs_trace, "_mirror", never)
+    monkeypatch.setattr(obs_trace, "_stack", never)
+    hist = obs_stats.Histogram()
+    with obs_trace.span("off/a", iteration=1):
+        with obs_trace.timed("off/b", hist) as block:
+            with block.carve("off/c"):
+                pass
+    with obs_trace.server_span("off/d", b""):
+        pass
+    obs_trace.SpanHolder("off/e").finish()
+    assert obs_trace.spans() == [] and hist.count == 1
+
+
+# --------------------------------------------- (b) the decode round's legs
+@pytest.mark.parametrize("rec", [False, True], ids=["off", "recording"])
+def test_decode_server_legs_once_per_admission_and_round(rng, rec):
+    model = tiny()
+    srv = DecodeServer(model, model.init_params(0), slots=4, max_len=64)
+    srv.submit(list(rng.integers(0, 96, 9)), max_new_tokens=2)
+    srv.run_to_completion()            # every program built, server idle
+    obs_trace.clear()
+    obs_trace.enable(rec)
+    try:
+        before = counts()
+        srv.submit(list(rng.integers(0, 96, 9)), max_new_tokens=5)
+        srv.step()
+        srv.submit(list(rng.integers(0, 96, 9)), max_new_tokens=3)
+        rounds = 1
+        while not srv.idle:
+            srv.step()
+            rounds += 1
+        with pytest.raises(ValueError):
+            srv.submit([], max_new_tokens=1)     # refused: no admission
+        after = counts()
+        spans = collections.Counter(s["name"] for s in obs_trace.spans())
+    finally:
+        obs_trace.enable(False)
+        obs_trace.clear()
+    moved = {k: after[k] - before[k] for k in after}
+    # always on, with recording off too; the first round after idle has no
+    # round before it, the second admission falls between two rounds
+    assert moved == {"serve.admit_s": 2, "serve.admit_device_s": 2,
+                     "serve.round_device_s": rounds,
+                     "serve.round_host_s": rounds, "serve.round_s": rounds,
+                     "serve.between_rounds_s": rounds - 1,
+                     "serve.programs": 0}
+    assert spans == ({"serve/admit": 2, "serve/admit/device": 2,
+                      "serve/round/host": rounds,
+                      "serve/round/device": rounds} if rec else {})
+
+
+def test_round_legs_add_up_and_programs_are_counted(rng):
+    model = tiny(d_model=32, n_heads=2)     # a model no other test built
+    before = counts()
+    srv = DecodeServer(model, model.init_params(0), slots=2, max_len=64)
+    srv.submit(list(rng.integers(0, 96, 5)), max_new_tokens=4)
+    srv.run_to_completion()
+    after = counts()
+    # step, prefill and splice programs at the least
+    assert after["serve.programs"] - before["serve.programs"] >= 3
+    snap = obs_stats.REGISTRY.snapshot()["histograms"]
+    parts = (snap["serve.round_device_s"]["sum"]
+             + snap["serve.round_host_s"]["sum"])
+    assert parts == pytest.approx(snap["serve.round_s"]["sum"], rel=0.05)
+
+
+# ------------------------------------------------- (b) the PS round's legs
+@pytest.fixture
+def cluster1(tmp_path):
+    ps = ParameterServer(ParameterServerConfig(
+        bind_address="127.0.0.1", port=0, total_workers=1,
+        checkpoint_dir=str(tmp_path), learning_rate=0.05,
+        autosave_period_s=600.0))
+    ps_port = ps.start()
+    coordinator = Coordinator(CoordinatorConfig(
+        bind_address="127.0.0.1", port=0, ps_address="127.0.0.1",
+        ps_port=ps_port, reap_period_s=600.0))
+    port = coordinator.start()
+    yield port
+    coordinator.stop()
+    ps.stop()
+
+
+def test_ps_round_legs_are_disjoint_and_cover_the_step(cluster1,
+                                                       monkeypatch):
+    # small buckets and chunks: several D2H fetches and ring frames a round
+    monkeypatch.setenv("PSDT_BUCKET_BYTES", str(64 << 10))
+    monkeypatch.setenv("PSDT_STREAM_CHUNK_BYTES", str(64 << 10))
+    worker = build_worker(WorkerConfig(
+        coordinator_address=f"127.0.0.1:{cluster1}", worker_id=0,
+        iterations=4, batch_size=16, model="mnist_mlp",
+        heartbeat_period_s=600.0, fused_step=True))
+    worker.initialize()
+    fetched = []
+    compute = worker.trainer.compute_gradient_buckets
+    worker.trainer.compute_gradient_buckets = lambda params, batch: compute(
+        params, batch, on_fetch=lambda i, n: fetched.append((i, n)))
+    try:
+        for iteration in range(3):      # seed, renegotiate, steady state
+            worker.run_iteration(iteration)
+        assert worker._ps.shm_active
+        del fetched[:]
+        obs_trace.clear()
+        obs_trace.enable(True)
+        worker.run_iteration(3)
+        obs_trace.enable(False)
+        spans = obs_trace.spans()
+    finally:
+        obs_trace.enable(False)
+        obs_trace.clear()
+        worker.shutdown()
+    step, = [s for s in spans if s["name"] == "worker/step"]
+    mine = [s for s in spans if s["tid"] == step["tid"]]
+    theirs = [s for s in spans if s["tid"] != step["tid"]]
+    count = collections.Counter(s["name"] for s in mine)
+    served = collections.Counter(s["name"] for s in theirs)
+    buckets = fetched[0][1]
+    assert buckets > 2 and len(fetched) == buckets
+    # once per unit of work: per round, per bucket, per tensor and chunk,
+    # per ring frame (a read also sees the 4-byte end marker)
+    assert count["worker/pack"] == count["worker/h2d"] == \
+        count["worker/dispatch"] == count["worker/device_wait"] == 1
+    assert count["worker/d2h"] == buckets - 1   # bucket 0 is device_wait
+    tensors = len(worker.trainer._layout)
+    sent = served["ps/fold"]                     # chunks with gradients
+    assert sent > 1 and served["rpc/server/decode"] == 2 * sent
+    assert count["rpc/client/encode"] == tensors + sent
+    received = sum(1 for s in mine if s["name"] == "rpc/client/decode"
+                   and "bytes" in s["args"])
+    assert count["rpc/client/decode"] == 2 * received - 1   # push verdict
+    assert count["rpc/shm/copy"] == sent + received + 1
+    assert count["rpc/shm/wait"] <= count["rpc/shm/copy"]
+    assert served["ps/close"] == served["ps/apply"] == 1
+    assert served["rpc/server/encode"] == 1 + received
+    # every leg knows its round, the ones opened deep in the rings too
+    assert all(s["args"]["iteration"] == 3 for s in mine)
+    # the leaves are disjoint (to the clocks' 0.2 ms) and cover the step
+    # but for its glue: the batch, the generators, the bookkeeping after
+    # the round (a third of a round of milliseconds; under 5% on the chip)
+    leaves = sorted((s for s in mine if s["name"] in WORKER_LEAVES),
+                    key=lambda s: s["ts"])
+    for a, b in zip(leaves, leaves[1:]):
+        assert b["ts"] >= a["ts"] + a["dur"] - 2e-4, (a, b)
+    assert leaves[0]["ts"] >= step["ts"] - 2e-4
+    assert leaves[-1]["ts"] + leaves[-1]["dur"] <= \
+        step["ts"] + step["dur"] + 2e-4
+    covered = sum(s["dur"] for s in leaves) / step["dur"]
+    assert 0.66 <= covered <= 1.001, covered
+
+
+# -------------------------------------------------- (e) names, and no more
+def lowered_step(model):
+    optimizer = optax.adam(1e-3)
+    step = jax.jit(make_train_step(model.loss, optimizer))
+    params = model.init_params(0)
+    state = TrainState.create(params, optimizer)
+    return step.lower(state, jnp.zeros((2, 32), jnp.int32))
+
+
+def lowered_decode(model):
+    params = model.init_params(0)
+    cache = generation.init_cache(model, 2, 32, "native")
+    return jax.jit(lambda p, t, c, n: generation.decode_block(
+        model, p, t, c, lengths=n)).lower(
+        params, jnp.zeros((2, 1), jnp.int32), cache,
+        jnp.zeros((2,), jnp.int32))
+
+
+@pytest.mark.parametrize("lower,scopes", [
+    (lowered_step, ("embed", "norm", "attn_qkv", "attn", "attn_out", "mlp",
+                    "head", "loss", "optimizer")),
+    (lowered_decode, ("embed", "attn_qkv", "cache_update", "cache_attn",
+                      "attn_out", "mlp", "head")),
+], ids=["train_step", "decode_block"])
+@pytest.mark.parametrize("layout", ["unrolled", "scan_remat"])
+def test_named_scope_changes_metadata_only(monkeypatch, lower, scopes,
+                                           layout):
+    kw = dict(scan_layers=True, remat=True, loss_chunk=16) \
+        if layout == "scan_remat" else {}
+    named = lower(tiny(max_seq=32, **kw))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = lower(tiny(max_seq=32, **kw))
+    # the program (what the compile-cache key is made from: debug
+    # information stripped) is the same text; only the locations differ
+    assert named.as_text() == bare.as_text()
+    with_names = named.as_text(debug_info=True)
+    assert with_names != bare.as_text(debug_info=True)
+    # (inside a scan or a checkpoint the paths restart: "checkpoint/mlp/...")
+    paths = set(re.findall(r'loc\("([^"]*)"', with_names))
+    for scope in scopes:
+        assert any(re.search(rf"(^|[/(]){scope}[/)]", p)
+                   for p in paths), scope
+    matmul = re.compile(r"mlp\)?/dot_general")
+    assert matmul.search(with_names)
+    assert not matmul.search(bare.as_text(debug_info=True))

@@ -184,7 +184,8 @@ BLOCKING_ALLOWED: frozenset[str] = frozenset({
     # single-flight g++ build of the native kernels
     "native._lock",
     # serializes one fused shm round (write frames, doorbell-wait, read
-    # frames) — the ring waits ARE the serialized blocking section
+    # and consume the response frames) — the ring waits ARE the
+    # serialized blocking section
     "ShmClientConnection._lock",
     # single-flight tier-topology refresh: the provider under it may be a
     # coordinator RPC (core/ps_core.py _contribution_for, ISSUE 9)

@@ -571,12 +571,16 @@ class PSClient(RpcClient):
                         for f in frames:
                             with obs_trace.span("rpc/client/decode",
                                                 bytes=len(f)):
-                                frame = m.PushPullResponse.decode(
-                                    memoryview(f))
+                                frame = m.PushPullResponse.decode(f)
                             yield frame
 
-                    frames = conn.round_trip(encoded_frames(), timeout)
-                    result = self._assemble_fused(decoded(frames), on_chunk)
+                    # each response frame is decoded and handed to
+                    # on_chunk as it leaves the ring, inside the
+                    # connection's round lock
+                    result = conn.round_trip(
+                        encoded_frames(), timeout,
+                        lambda frames: self._assemble_fused(
+                            decoded(frames), on_chunk))
                 # the server just proved it speaks the fused protocol
                 self._fused_ok = True
                 ok = True

@@ -39,19 +39,32 @@ platform CPython runs on — and each cursor has exactly one writer; the
 socket carries no data, only wakeups, so a lost/skipped doorbell is a
 latency blip, never a correctness problem (waits recheck the cursors).
 
+Consume side: a frame leaves a ring ONCE, into a receive buffer the ring
+end owns and has already touched (``_FramePool``: two per ring end, grown
+to the largest frame seen), and the caller gets a read-only view of it.
+Both ends consume a frame before they ask for the next: the server's
+handler folds chunk k before frame k+1 is read, and the client decodes
+and converts each response frame as it arrives, inside the connection's
+round lock (``ShmClientConnection.round_trip``), so its decode runs
+under the server's encode of the next frame and it never holds the whole
+encoded response.  A buffer is reused only when the interpreter says no
+view of it is alive; whoever keeps a view keeps the buffer.
+
 Env knobs: ``PSDT_SHM`` (default on; 0 disables both ends),
 ``PSDT_SHM_RING_BYTES`` (per-direction ring capacity, default 32 MB —
 frames larger than the ring stream through it).
 Observability: ``rpc.shm.bytes`` counts payload bytes moved through
-rings by this process; ``rpc.shm.fallback`` counts downgrades to TCP
-(refused negotiation, attach failure, or a mid-flight transport error).
+rings by this process; ``rpc.shm.frames`` the data frames read out of
+one and ``rpc.shm.frame_allocs`` the receive buffers allocated or grown
+for them (0 once every frame size has been seen); ``rpc.shm.fallback``
+counts downgrades to TCP (refused negotiation, attach failure, or a
+mid-flight transport error).
 """
 
 from __future__ import annotations
 
 import contextlib
 import ctypes
-import itertools
 import logging
 import os
 import socket
@@ -59,7 +72,7 @@ import struct
 import threading
 import time
 import uuid
-from typing import Callable, Iterator
+from typing import Callable, Iterator, TypeVar
 
 import numpy as np
 
@@ -71,6 +84,8 @@ from ..obs import trace as obs_trace
 from .wire import Field, Message
 
 log = logging.getLogger("pst.shm")
+
+T = TypeVar("T")
 
 ENV_FLAG = "PSDT_SHM"
 ENV_RING_BYTES = "PSDT_SHM_RING_BYTES"
@@ -90,6 +105,8 @@ _OFF_HEAD = 8
 _OFF_CLOSED = 16
 
 _obs_bytes = obs_stats.counter("rpc.shm.bytes")
+_obs_frames = obs_stats.counter("rpc.shm.frames")
+_obs_frame_allocs = obs_stats.counter("rpc.shm.frame_allocs")
 _obs_fallback = obs_stats.counter("rpc.shm.fallback")
 
 
@@ -226,6 +243,83 @@ def _doorbell_connect(addr: str, timeout: float = 10.0) -> socket.socket:
     return sock
 
 
+def _exported(buf: bytearray) -> bool:
+    """Whether any view of ``buf`` is alive, however derived (slices of
+    the memoryview handed out, ``np.frombuffer`` arrays of those, a
+    device transfer still reading one).  The interpreter keeps that count
+    for a bytearray and refuses to resize one under an export, so ask it:
+    a pop/append pair changes nothing when it is allowed (the allocation
+    has room for the byte it just gave up) and raises before touching
+    anything when it is not."""
+    try:
+        buf.append(buf.pop())
+    except BufferError:
+        return True
+    return False
+
+
+def _address(buf: bytearray) -> int:
+    # the export dies with the temporary
+    return ctypes.addressof(ctypes.c_char.from_buffer(buf))
+
+
+class _FramePool:
+    """The receive buffers of one ring end.  A frame that leaves the ring
+    lands in a buffer this pool owns and has already touched, not in
+    fresh pages: at the sizes a parameter store is chunked into (far
+    above malloc's mmap threshold) a per-frame buffer is new address
+    space every time, and first-touch page faults, not the copy, set a
+    ring read's pace.
+
+    Two slots, because the consumer of frame k still holds its views
+    while frame k+1 is read (the loop variable of whoever iterates the
+    frames).  A slot is reused only when :func:`_exported` says no view
+    of it is left; a consumer that keeps one keeps that buffer, and the
+    ring replaces the slot with a fresh buffer of the frame's own size
+    (``rpc.shm.frame_allocs``).  Free slots grow to the largest frame
+    seen (and a little over), at every take and at the end of a frame
+    group, so that after one whole exchange nothing is allocated again
+    whichever slot a frame falls on."""
+
+    __slots__ = ("_slots", "_largest")
+
+    def __init__(self):
+        self._slots: list[bytearray] = []
+        self._largest = 1
+
+    @staticmethod
+    def _fresh(size: int) -> bytearray:
+        _obs_frame_allocs.add()
+        return bytearray(size)  # zero-filled: every page touched here
+
+    def settle(self) -> bytearray | None:
+        """Grow every slot no consumer holds to the largest frame seen;
+        returns one of them, or None when all are held."""
+        free = None
+        for i, buf in enumerate(self._slots):
+            if _exported(buf):
+                continue
+            if len(buf) < self._largest:
+                buf = self._slots[i] = self._fresh(self._largest)
+            if free is None:
+                free = buf
+        return free
+
+    def take(self, n: int) -> bytearray:
+        """A buffer of at least ``n`` >= 1 bytes that nothing refers to."""
+        if n > self._largest:
+            # with headroom: the same tensors arrive under headers that
+            # differ by a few bytes from round to round (the iteration's
+            # varint, a trace context), which must not cost two buffers
+            self._largest = n + (n >> 6) + 4096
+        buf = self.settle()
+        if buf is None:
+            buf = self._fresh(n)
+            del self._slots[:-1]  # the longest-held slot is its holder's
+            self._slots.append(buf)
+        return buf
+
+
 class ShmRing:
     """One direction of a connection: SPSC byte ring over a shared-memory
     segment.  Exactly one producer process/thread calls the ``write*``
@@ -233,7 +327,8 @@ class ShmRing:
     hand-off safe without any cross-process lock.  ``doorbell`` (shared
     by both of a connection's rings at each endpoint) turns waits into
     kernel sleeps; without one — unit tests — waits degrade to timed
-    polling."""
+    polling.  The consumer's ``read_frame`` hands out views of pooled
+    receive buffers (``_FramePool``), never a per-frame allocation."""
 
     def __init__(self, shm, capacity: int,
                  doorbell: _Doorbell | None = None):
@@ -260,6 +355,10 @@ class ShmRing:
         # ``timed.carve``); one thread drives a ring's end, so a plain
         # attribute does
         self._blocked = None
+        # consume side: where frames land (see _FramePool), and the four
+        # bytes of a length prefix
+        self._pool = _FramePool()
+        self._prefix = bytearray(4)
 
     # ------------------------------------------------------------- cursors
     def _tail(self) -> int:
@@ -414,19 +513,20 @@ class ShmRing:
         _obs_bytes.add(4)
 
     # ------------------------------------------------------------- consume
-    def _copy_out(self, out: bytearray, dst, dst_off: int, pos: int,
+    def _copy_out(self, out: bytearray, dst: int, dst_off: int, pos: int,
                   n: int) -> None:
         base, copy = self._base, self._copy  # see _copy_in
-        if dst is not None and copy is not None and base:
-            copy(dst.ctypes.data + dst_off, base + _HEADER + pos, n)
+        if dst and copy is not None and base:
+            copy(dst + dst_off, base + _HEADER + pos, n)
         else:
             out[dst_off:dst_off + n] = self._buf[_HEADER + pos:
                                                  _HEADER + pos + n]
 
-    def _read_bytes(self, n: int, deadline: float) -> bytearray:
-        out = bytearray(n)
-        dst = np.frombuffer(out, np.uint8) if self._copy is not None \
-            else None
+    def _read_into(self, out: bytearray, n: int, deadline: float) -> None:
+        """Fill ``out[:n]`` from the ring, block by block.  ``out`` is
+        this ring's own (the prefix scratch or a pool buffer nothing else
+        refers to), so its address holds for the call."""
+        dst = _address(out) if self._copy is not None else 0
         done = 0
         cap = self.capacity
         head = self._head()
@@ -444,24 +544,37 @@ class ShmRing:
             if self.doorbell is not None:
                 self.doorbell.ring()
             done += take
-        return out
 
-    def read_frame(self, deadline: float) -> bytes | None:
-        """The next frame's payload, or None at an end-of-stream marker."""
+    def read_frame(self, deadline: float) -> memoryview | None:
+        """The next frame's payload, or None at an end-of-stream marker.
+
+        The payload is a READ-ONLY view of a buffer this ring end owns and
+        reuses: it was copied out of the ring once, into pages already
+        touched.  Consume it (decode, fold, convert) and let go of it;
+        whoever keeps a view, of any depth, keeps that buffer, and the
+        ring takes another (see :class:`_FramePool`).  Read-only, so that
+        ``Tensor.to_array`` copies out of it exactly once and in-place
+        aggregation can never write into a buffer about to be refilled."""
         with self._frame() as frame:
             try:
-                (length,) = struct.unpack("<I",
-                                          self._read_bytes(4, deadline))
+                self._read_into(self._prefix, 4, deadline)
+                (length,) = struct.unpack("<I", self._prefix)
                 if length == self._END:
+                    self._pool.settle()
                     _obs_bytes.add(4)
                     return None
-                payload = bytes(self._read_bytes(length, deadline)) \
-                    if length else b""
+                if length:
+                    out = self._pool.take(length)
+                    self._read_into(out, length, deadline)
+                    payload = memoryview(out).toreadonly()[:length]
+                else:
+                    payload = memoryview(b"")
             except ValueError as exc:  # memoryview released under us
                 raise ShmTransportError(
                     f"shm segment released: {exc}") from exc
             frame.args["bytes"] = length
         _obs_bytes.add(4 + length)
+        _obs_frames.add()
         return payload
 
 
@@ -532,46 +645,68 @@ class ShmClientConnection:
         # are the lock's purpose (BLOCKING_ALLOWED, analysis/lock_order.py)
         self._lock = checked_lock("ShmClientConnection._lock")
 
-    def round_trip(self, frames: Iterator[bytes],
-                   timeout: float | None) -> Iterator[bytes]:
+    def round_trip(self, frames: Iterator[bytes], timeout: float | None,
+                   consume: Callable[[Iterator[memoryview]], T]) -> T:
         """One request/response exchange: stream the request frames out,
-        then collect response frames until the server's end marker.  The
-        response is fully drained inside the lock before yielding — a
-        half-consumed iterator must not hold the connection hostage, and
-        the buffered encoded frames are the same bytes the server's
-        encode-once cache already holds per version, so peak memory
-        matches the TCP fan-out's server side (the cost is losing the
-        per-chunk decode ⊕ transport overlap the gRPC path streams;
-        acceptable against the ~2x round-time win on loopback)."""
+        then hand ``consume`` an iterator over the response frames, each
+        read off the ring when the consumer asks for it, so the decode
+        of frame k runs while the server encodes and writes frame k+1 and
+        this end never holds more of the response than the receive pool.
+        Returns what ``consume`` returns.
+
+        Everything happens INSIDE the round lock, and two things hold by
+        construction.  The connection is never left half-read: frames the
+        consumer did not take are drained to the server's end marker, and
+        if it (or the frame source) raises, both rings are latched closed.
+        No lazily-consumed iterator escapes the lock: the one ``consume``
+        was given is exhausted or closed before this returns.  Each frame
+        is a read-only view of a pool buffer (``ShmRing.read_frame``):
+        take what is needed out of it before asking for the next."""
         deadline = time.monotonic() + (timeout if timeout else 3600.0)
+
+        def response() -> Iterator[memoryview]:
+            while True:
+                frame = self.s2c.read_frame(deadline)
+                if frame is None:
+                    return
+                yield frame
+
         with self._lock:
+            if self.c2s.closed or self.s2c.closed:
+                # latched by an earlier round's failure (below): frames of
+                # that round may still sit in the ring, and a read that
+                # finds bytes never looks at the latch
+                raise ShmTransportError("shm connection latched closed")
             try:
                 for frame in frames:
                     self.c2s.write_frame(frame, deadline)
                 self.c2s.write_end(deadline)
-                out: list[bytes] = []
-                while True:
-                    frame = self.s2c.read_frame(deadline)
-                    if frame is None:
-                        break
-                    out.append(frame)
+                answer = response()
+                try:
+                    result = consume(answer)
+                    for _ in answer:  # what the consumer left unread
+                        pass
+                finally:
+                    answer.close()
             except ShmTransportError:
                 raise
             except BaseException:
-                # the FRAME SOURCE raised mid-round (lazy D2H fetch,
-                # encode validation): the stream is desynced — the server
-                # is parked mid-round and would fold the NEXT round's
-                # frames into this one.  Latch the rings closed so the
-                # server thread exits (and is reaped) and the next
-                # attempt on this connection downgrades to TCP; the
-                # original error still propagates like the gRPC path's.
+                # the FRAME SOURCE (lazy D2H fetch, encode validation) or
+                # the CONSUMER (decode, the worker's converter) raised
+                # mid-round: the stream is desynced — the server is
+                # parked mid-round and would fold the NEXT round's frames
+                # into this one, or is still writing a response nobody
+                # reads.  Latch the rings closed so the server thread
+                # exits (and is reaped) and the next attempt on this
+                # connection downgrades to TCP; the original error still
+                # propagates like the gRPC path's.
                 for ring in (self.c2s, self.s2c):
                     try:
                         ring.close()
                     except (ValueError, OSError):
                         pass
                 raise
-        return iter(out)
+        return result
 
     def close(self) -> None:
         # taking the round lock first means an in-flight fused round
@@ -627,7 +762,7 @@ class _ServerConnection:
             name=f"shm-conn-{index}")
         self._thread.start()
 
-    def _request_frames(self) -> Iterator[bytes]:
+    def _request_frames(self) -> Iterator[memoryview]:
         """Frames of ONE request (until the client's end marker); empty
         frames are legal data (an all-default GradientUpdate)."""
         while True:
@@ -678,6 +813,12 @@ class _ServerConnection:
             try:
                 if first is None:
                     continue  # stray end marker (client retry teardown)
+                # the generator below takes the frame out of this list: a
+                # frame is a view of a receive buffer the ring reuses once
+                # nothing refers to it, and a name bound for the whole
+                # round would keep that buffer out of the pool
+                pending = [first]
+                del first
                 drained = [False]
                 # a shm round IS a fused PushPullStream round: give it
                 # the same server-side span (adopting the caller's trace
@@ -690,13 +831,18 @@ class _ServerConnection:
                                               transport="shm")
 
                 def chunks() -> Iterator[m.Message]:
-                    for frame in itertools.chain((first,),
-                                                 self._request_frames()):
+                    # tensor payloads stay views of the frame until the
+                    # handler's decode copies them out (Tensor.to_array),
+                    # which it does before it asks for the next chunk
+                    frame = pending.pop()
+                    while frame is not None:
                         with obs_trace.span("rpc/server/decode",
                                             bytes=len(frame)):
                             chunk = m.GradientUpdate.decode(frame)
                         holder.adopt(getattr(chunk, "trace_context", b""))
                         yield chunk
+                        frame = self.c2s.read_frame(
+                            time.monotonic() + 3600.0)
                     drained[0] = True
 
                 deadline = time.monotonic() + 3600.0

@@ -238,6 +238,9 @@ def test_ps_round_legs_are_disjoint_and_cover_the_step(cluster1,
     # small buckets and chunks: several D2H fetches and ring frames a round
     monkeypatch.setenv("PSDT_BUCKET_BYTES", str(64 << 10))
     monkeypatch.setenv("PSDT_STREAM_CHUNK_BYTES", str(64 << 10))
+    # a ring smaller than the pull's LAST frame: the server is still
+    # serving while the worker decodes the frames before it
+    monkeypatch.setenv("PSDT_SHM_RING_BYTES", str(8 << 10))
     worker = build_worker(WorkerConfig(
         coordinator_address=f"127.0.0.1:{cluster1}", worker_id=0,
         iterations=4, batch_size=16, model="mnist_mlp",
@@ -284,6 +287,26 @@ def test_ps_round_legs_are_disjoint_and_cover_the_step(cluster1,
     assert count["rpc/shm/wait"] <= count["rpc/shm/copy"]
     assert served["ps/close"] == served["ps/apply"] == 1
     assert served["rpc/server/encode"] == 1 + received
+    # the response is consumed as it arrives: each frame that leaves the
+    # ring is decoded (and its chunk converted) before the next is read,
+    # so the decode spans lie between the ring reads of the response and
+    # begin before the server's serve leg is over (the last frame does not
+    # fit the ring, so the server cannot finish before the worker asks
+    # for it)
+    decodes = [s for s in mine if s["name"] == "rpc/client/decode"
+               and "bytes" in s["args"]]
+    reads = sorted((s for s in mine if s["name"] == "rpc/shm/copy"),
+                   key=lambda s: s["ts"])[-(received + 1):]
+    for decode, read, after in zip(decodes, reads, reads[1:]):
+        assert read["args"]["bytes"] == decode["args"]["bytes"]
+        assert read["ts"] + read["dur"] - 2e-4 <= decode["ts"]
+        assert decode["ts"] + decode["dur"] <= after["ts"] + 2e-4
+    converts = [s for s in mine if s["name"] == "rpc/client/decode"
+                and "tensors" in s["args"]]
+    assert converts[0]["ts"] + converts[0]["dur"] <= reads[2]["ts"] + 2e-4
+    serve, = [s for s in theirs if s["name"] == "ps/serve"]
+    assert reads[-2]["args"]["bytes"] > 8 << 10
+    assert decodes[-2]["ts"] < serve["ts"] + serve["dur"]
     # every leg knows its round, the ones opened deep in the rings too
     assert all(s["args"]["iteration"] == 3 for s in mine)
     # the leaves are disjoint (to the clocks' 0.2 ms) and cover the step

@@ -142,6 +142,220 @@ def test_ring_close_unblocks_waiters_and_timeout_raises():
         _cleanup(seg)
 
 
+# ---------------------------------------------------- ring consume side
+
+def _shm_counters():
+    snap = obs_stats.REGISTRY.snapshot()["counters"]
+    return (snap.get("rpc.shm.frames", 0),
+            snap.get("rpc.shm.frame_allocs", 0))
+
+
+def _send(prod, payloads, end=True):
+    """Write ``payloads`` (and the end marker) from a thread of its own."""
+    def produce():
+        for p in payloads:
+            prod.write_frame(p, time.monotonic() + 30)
+        if end:
+            prod.write_end(time.monotonic() + 30)
+
+    th = threading.Thread(target=produce, daemon=True, name="t-prod")
+    th.start()
+    return th
+
+
+def _consume_group(cons):
+    """Read one frame group as the data plane does: take what is needed
+    out of a frame, let go of it, ask for the next."""
+    got = []
+    while True:
+        frame = cons.read_frame(time.monotonic() + 30)
+        if frame is None:
+            return got
+        assert isinstance(frame, memoryview) and frame.readonly
+        got.append(bytes(frame))
+
+
+CAP = 8192
+
+
+@pytest.mark.parametrize("native_copy", [True, False],
+                         ids=["native", "memoryview"])
+@pytest.mark.parametrize("sizes", [
+    (100, 3000), (CAP,), (5 * CAP + 17,), (5000, 5000, 5000),
+    (0, 1, 0), (CAP - 4, 3, CAP + 1, 0, 2 * CAP)],
+    ids=["smaller", "equal", "several_rings", "wraps", "empty_frames",
+         "mixed"])
+def test_ring_consume_path_sizes(sizes, native_copy):
+    """Frames smaller than, equal to and several times the ring, frames
+    that wrap, zero-length data frames and the end marker all come out of
+    the pooled consume path byte for byte, as read-only views, through
+    the native copy and through the memoryview fallback."""
+    seg, prod, cons = _ring_pair(capacity=CAP)
+    try:
+        if not native_copy:
+            cons.invalidate()
+        elif cons._copy is None:
+            pytest.skip("no native library on this machine")
+        rng = np.random.default_rng(len(sizes))
+        payloads = [rng.bytes(n) for n in sizes]
+        frames_before, _ = _shm_counters()
+        for _ in range(2):          # the second lap reuses the buffers
+            th = _send(prod, payloads)
+            assert _consume_group(cons) == payloads
+            th.join(timeout=30)
+            assert not th.is_alive()
+        assert _shm_counters()[0] - frames_before == 2 * len(sizes)
+    finally:
+        _cleanup(seg)
+
+
+def test_ring_pool_grows_then_stops_allocating():
+    """Two frame sizes in turn: the receive buffers grow to the larger in
+    the first group (and a little over), and from then on no frame needs
+    an allocation, whichever buffer it falls on (an odd count flips the
+    turn)."""
+    seg, prod, cons = _ring_pair(capacity=CAP)
+    try:
+        rng = np.random.default_rng(7)
+        payloads = [rng.bytes(n) for n in (1000, 50_000, 1000)]
+        _, before = _shm_counters()
+        th = _send(prod, payloads)
+        assert _consume_group(cons) == payloads
+        th.join(timeout=30)
+        _, grown = _shm_counters()
+        assert grown > before
+        sizes = [len(b) for b in cons._pool._slots]
+        assert sizes[0] == sizes[1] and 50_000 <= sizes[0] < 60_000
+        slots = [id(b) for b in cons._pool._slots]
+        for lap in range(3):
+            # the same tensors under a header a few bytes longer (the
+            # iteration's varint, a trace context) fit the headroom
+            payloads = [p + b"\x01" * lap for p in payloads]
+            th = _send(prod, payloads)
+            assert _consume_group(cons) == payloads
+            th.join(timeout=30)
+        assert _shm_counters()[1] == grown
+        assert [id(b) for b in cons._pool._slots] == slots
+    finally:
+        _cleanup(seg)
+
+
+def test_ring_kept_view_keeps_its_buffer():
+    """A consumer that keeps an ``np.frombuffer`` view across later reads
+    keeps that buffer: its bytes do not change, the ring takes another
+    and counts it, and once the view is gone the pool settles again."""
+    seg, prod, cons = _ring_pair(capacity=CAP)
+    try:
+        rng = np.random.default_rng(11)
+        payloads = [rng.bytes(20_000) for _ in range(6)]
+        th = _send(prod, payloads[:3])
+        assert _consume_group(cons) == payloads[:3]     # pool is warm
+        th.join(timeout=30)
+        _, warm = _shm_counters()
+        th = _send(prod, payloads[3:] + payloads)
+        frame = cons.read_frame(time.monotonic() + 30)
+        kept = np.frombuffer(frame, np.uint8)[10:20]    # a view of a view
+        assert not kept.flags.writeable
+        held = {id(b) for b in cons._pool._slots if st._exported(b)}
+        assert len(held) == 1
+        del frame
+        for want in payloads[4:]:
+            frame = cons.read_frame(time.monotonic() + 30)
+            assert frame == want
+        assert kept.tobytes() == payloads[3][10:20]
+        # the held buffer left the pool; exactly one fresh one replaced it
+        assert _shm_counters()[1] == warm + 1
+        assert not held & {id(b) for b in cons._pool._slots}
+        del kept, frame
+        assert _consume_group(cons) == payloads
+        th.join(timeout=30)
+        # the replacement was cut to its frame; free again, it grew to the
+        # pool's size once, and from there on nothing is allocated
+        steady = _shm_counters()[1]
+        assert steady <= warm + 2
+        th = _send(prod, payloads)
+        assert _consume_group(cons) == payloads
+        th.join(timeout=30)
+        assert _shm_counters()[1] == steady
+    finally:
+        _cleanup(seg)
+
+
+def test_frame_view_is_read_only_and_to_array_owns_its_data():
+    """What the ring hands out cannot be written, so a decoded f32 tensor
+    is copied out exactly once (``Tensor.to_array``: copy iff not
+    writeable) and the array the fold gets is its own: refilling the
+    buffer with the next frame does not reach it."""
+    seg, prod, cons = _ring_pair()
+    try:
+        values = np.arange(4096, dtype=np.float32)
+        update = m.GradientUpdate(
+            worker_id=0, iteration=1,
+            gradients=[m.Tensor.from_array("w", values.reshape(64, 64))])
+        other = m.GradientUpdate(
+            worker_id=0, iteration=2,
+            gradients=[m.Tensor.from_array("w", -values.reshape(64, 64))])
+        for msg in (update, other, other):
+            prod.write_frame(msg.encode(), time.monotonic() + 10)
+        frame = cons.read_frame(time.monotonic() + 10)
+        assert frame.readonly
+        with pytest.raises(TypeError):
+            frame[0] = 0
+        decoded = m.GradientUpdate.decode(frame)
+        wire = decoded.gradients[0].data
+        assert not np.asarray(wire).flags.writeable     # still the frame
+        arr = decoded.gradients[0].to_array()
+        assert arr.flags.writeable and arr.shape == (64, 64)
+        assert not np.shares_memory(arr, np.asarray(wire))
+        buffers = list(cons._pool._slots)
+        del frame, decoded, wire
+        assert not any(st._exported(b) for b in buffers)
+        for _ in range(2):          # both buffers refilled
+            assert cons.read_frame(time.monotonic() + 10) is not None
+        np.testing.assert_array_equal(arr, values.reshape(64, 64))
+        arr += 1.0                  # in-place aggregation is the array's own
+    finally:
+        _cleanup(seg)
+
+
+def test_invalidate_during_read_falls_back_then_fails_cleanly():
+    """``invalidate()`` under a reader in mid-frame: the rest of the frame
+    comes through the memoryview path, and once the segment is unmapped
+    the parked reader fails as ShmTransportError, not at a stale
+    address."""
+    seg, prod, cons = _ring_pair(capacity=CAP)
+    payload = np.random.default_rng(3).bytes(3 * CAP)
+    got, errs = [], []
+
+    def reader():
+        try:
+            got.append(bytes(cons.read_frame(time.monotonic() + 30)))
+            cons.read_frame(time.monotonic() + 30)
+        except st.ShmTransportError as exc:
+            errs.append(exc)
+
+    try:
+        th = threading.Thread(target=reader, daemon=True, name="t-cons")
+        th.start()
+        deadline = time.monotonic() + 30
+        prod._write_bytes((3 * CAP).to_bytes(4, "little"), deadline)
+        prod._write_bytes(payload[:CAP], deadline)      # reader mid-frame
+        while cons._head() < 4 + CAP:
+            time.sleep(0.001)
+        cons.invalidate()
+        assert cons._copy is None and cons._base == 0
+        prod._write_bytes(payload[CAP:], deadline)
+        while not got and th.is_alive():
+            time.sleep(0.001)
+        assert got == [payload]
+        prod.invalidate()
+        seg.close()                 # unmap under the parked reader
+        th.join(timeout=10)
+        assert not th.is_alive() and len(errs) == 1
+    finally:
+        _cleanup(seg)
+
+
 # ------------------------------------------------------------- negotiation
 
 @pytest.fixture
@@ -414,5 +628,67 @@ def test_concurrent_fused_rounds_over_shm_lockcheck(tmp_path):
             np.full(1024, -0.75 * 5, np.float32), rtol=1e-5)
         for c in clients:
             c.close()
+    finally:
+        server.stop()
+
+
+@pytest.mark.parametrize("consumer", ["raises", "stops_early"])
+def test_client_consumer_mid_response_leaves_connection_usable(
+        tmp_path, monkeypatch, consumer):
+    """The response is consumed as it arrives, inside the round lock.  A
+    consumer that raises in the middle of it latches the rings closed
+    (the next round on the same PSClient replays over TCP); one that
+    stops reading early has the rest drained for it (the next round
+    rides the rings).  Either way the connection is never half-read."""
+    monkeypatch.setenv("PSDT_STREAM_CHUNK_BYTES", "256")    # 64 floats
+    server = ParameterServer(ParameterServerConfig(
+        bind_address="127.0.0.1", port=0, total_workers=1,
+        checkpoint_dir=str(tmp_path), learning_rate=0.5,
+        autosave_period_s=3600.0))
+    port = server.start()
+    names = [f"w{i}" for i in range(4)]
+    store = {n: np.full(64, float(i), np.float32)
+             for i, n in enumerate(names)}
+    server.core.initialize_parameters(store)
+
+    def grads():
+        return [m.Tensor.from_array(n, np.full(64, 0.1, np.float32))
+                for n in names]
+
+    def check(params, rounds):
+        got = {t.name: t.to_array() for t in params.parameters}
+        for n in names:
+            np.testing.assert_allclose(got[n], store[n] - 0.05 * rounds,
+                                       rtol=1e-5)
+
+    try:
+        with PSClient(f"127.0.0.1:{port}") as client:
+            push, params = client.push_pull(0, 1, grads())
+            assert push.success and client.shm_active
+            check(params, 1)
+            if consumer == "raises":
+                seen = []
+
+                def boom(tensors):
+                    seen.append(len(tensors))
+                    if len(seen) == 2:
+                        raise RuntimeError("converter failed")
+
+                with pytest.raises(RuntimeError, match="converter failed"):
+                    client.push_pull(0, 2, grads(), on_chunk=boom)
+                assert len(seen) == 2       # mid-response: 4 chunks came
+                conn = client._shm_conn
+                assert conn.c2s.closed and conn.s2c.closed
+            else:
+                frames = [m.GradientUpdate(worker_id=0, iteration=2,
+                                           gradients=grads()).encode()]
+                first = client._shm_conn.round_trip(
+                    iter(frames), 20.0, lambda answer: bytes(next(answer)))
+                assert m.PushPullResponse.decode(first).push.success
+            # the push of round 2 landed either way; round 3 must work
+            push, params = client.push_pull(0, 3, grads(), timeout=20.0)
+            assert push.success and params is not None and params.ready
+            check(params, 3)
+            assert client.shm_active is (consumer == "stops_early")
     finally:
         server.stop()

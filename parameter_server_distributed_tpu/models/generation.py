@@ -45,15 +45,53 @@ Array = jax.Array
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class KVCache:
-    """Per-layer key/value cache.  k/v: [L, B, max_len, H, D]; length is the
-    number of valid positions (a traced scalar so decode never retraces)."""
+    """Key/value cache, one part per KIND of layer.
+
+    ``k``/``v`` [Lf, B, max_len, H, D] hold the layers stored BY POSITION
+    (index j is position j): every full-attention layer, so for a model
+    of full layers only (GPT-2) this is the whole cache, [L, B, max_len,
+    H, D] as it has always been.  ``wk``/``wv`` [Lw, B, W, H, D] hold the
+    window layers as RINGS of W positions (position p lives at index
+    p % W) however long the context, and are None where the model has no
+    window layer or the window is no shorter than ``max_len``.
+    ``ring_layers`` names the layers kept as rings (static); the others
+    are stored by position, in layer order.  ``length`` is the number of
+    valid positions (a traced scalar so decode never retraces)."""
     k: Array
     v: Array
     length: Array
+    wk: Array | None = None
+    wv: Array | None = None
+    ring_layers: tuple = dataclasses.field(
+        default=(), metadata=dict(static=True))
 
     @property
     def max_len(self) -> int:
         return self.k.shape[2]
+
+    def place(self, layer: int) -> tuple[bool, int]:
+        """(kept as a ring?, index within its part) of a layer."""
+        if layer in self.ring_layers:
+            return True, self.ring_layers.index(layer)
+        return False, layer - sum(1 for r in self.ring_layers if r < layer)
+
+    def nbytes_by_kind(self) -> dict[str, int]:
+        ring = 0 if self.wk is None else self.wk.nbytes + self.wv.nbytes
+        return {"full": int(self.k.nbytes + self.v.nbytes),
+                "window": int(ring)}
+
+
+def ring_layers_of(model: Transformer, max_len: int) -> tuple[int, ...]:
+    """The layers a cache of ``max_len`` positions keeps as rings: those
+    whose window is shorter than that.  One ring size per model."""
+    c = model.config
+    rings = tuple(i for i in range(c.n_layers)
+                  if 0 < c.layer_spec(i).window < max_len)
+    sizes = {c.layer_spec(i).window for i in rings}
+    if len(sizes) > 1:
+        raise ValueError(f"window layers of more than one size {sizes}: "
+                         "the cache keeps one ring size")
+    return rings
 
 
 @jax.tree_util.register_dataclass
@@ -93,17 +131,55 @@ def init_cache(model: Transformer, batch: int, max_len: int,
     if cache_dtype not in ("native", "int8"):
         raise ValueError(
             f"cache_dtype must be 'native' or 'int8', got {cache_dtype!r}")
+    rings = ring_layers_of(model, max_len)
     # GQA: the cache stores kv_heads (< n_heads) — n_heads/kv_heads x less
     # cache HBM; heads expand to the query count at attention time
-    shape = (c.n_layers, batch, max_len, c.kv_heads, c.head_dim)
+    shape = (c.n_layers - len(rings), batch, max_len, c.kv_heads,
+             c.head_dim)
     if cache_dtype == "int8":
+        if rings:
+            raise ValueError("the int8 cache stores every layer by "
+                             "position; a model with window layers takes "
+                             "the native cache")
         return QuantKVCache(
             k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(shape, jnp.int8),
             k_scale=jnp.ones(shape[:-1], jnp.float32),
             v_scale=jnp.ones(shape[:-1], jnp.float32),
             length=jnp.zeros((), jnp.int32))
-    return KVCache(k=jnp.zeros(shape, c.dtype), v=jnp.zeros(shape, c.dtype),
-                   length=jnp.zeros((), jnp.int32))
+    cache = KVCache(k=jnp.zeros(shape, c.dtype), v=jnp.zeros(shape, c.dtype),
+                    length=jnp.zeros((), jnp.int32))
+    if not rings:
+        return cache
+    ring = (len(rings), batch, c.layer_spec(rings[0]).window, c.kv_heads,
+            c.head_dim)
+    return dataclasses.replace(cache, wk=jnp.zeros(ring, c.dtype),
+                               wv=jnp.zeros(ring, c.dtype),
+                               ring_layers=rings)
+
+
+def ring_of_row(row: Array, length: Array, window: int) -> Array:
+    """A ring of ``window`` positions from K or V stored by position:
+    row [..., S, H, D] (positions on axis -3) -> [..., window, H, D] where
+    index s holds the latest position p < length with p % window == s
+    (any position where there is none yet: the ring's readers hide it)."""
+    held = length - 1 - (length - 1 - jnp.arange(window)) % window
+    return jnp.take(row, jnp.clip(held, 0, row.shape[-3] - 1), axis=-3)
+
+
+def split_row(cache: KVCache, k: Array, v: Array, length: Array
+              ) -> tuple[Array, Array, Array | None, Array | None]:
+    """Every layer's K and V by position ([L, ..., S, H, D]) as the parts
+    of ``cache``: (k, v) of the layers it stores by position and, where it
+    keeps rings, (wk, wv) of the last ring's worth of positions before
+    ``length``."""
+    if not cache.ring_layers:
+        return k, v, None, None
+    rings = np.asarray(cache.ring_layers)
+    lin = np.asarray([i for i in range(k.shape[0])
+                      if i not in cache.ring_layers])
+    window = cache.wk.shape[2]
+    return (k[lin], v[lin], ring_of_row(k[rings], length, window),
+            ring_of_row(v[rings], length, window))
 
 
 def check_position_budget(model: Transformer, prompt_len: int,
@@ -143,18 +219,27 @@ def prefill(model: Transformer, params: Mapping[str, Array], tokens: Array,
             v_scale=jax.lax.dynamic_update_slice(cache.v_scale, vs, at0[:-1]),
             length=jnp.asarray(prompt_len, jnp.int32))
         return logits[:, -1], cache
-    cache = KVCache(
-        k=jax.lax.dynamic_update_slice(cache.k, k.astype(cache.k.dtype),
-                                       at0),
-        v=jax.lax.dynamic_update_slice(cache.v, v.astype(cache.v.dtype),
-                                       at0),
-        length=jnp.asarray(prompt_len, jnp.int32))
+    length = jnp.asarray(prompt_len, jnp.int32)
+    k, v, wk, wv = split_row(cache, k.astype(cache.k.dtype),
+                             v.astype(cache.v.dtype), length)
+    cache = dataclasses.replace(
+        cache, k=jax.lax.dynamic_update_slice(cache.k, k, at0),
+        v=jax.lax.dynamic_update_slice(cache.v, v, at0), wk=wk, wv=wv,
+        length=length)
     return logits[:, -1], cache
+
+
+# a block of this many queries or more against this many positions or
+# more runs blockwise attention (an extension of a long cached prefix);
+# anything smaller the dense product a decode round has always run
+_BLOCKWISE_QUERIES = 128
 
 
 def decode_block(model: Transformer, params: Mapping[str, Array],
                  tokens: Array, cache: KVCache | QuantKVCache,
                  lengths: Array | None = None,
+                 counts: Array | None = None,
+                 route_stats: list | None = None,
                  ) -> tuple[Array, KVCache | QuantKVCache]:
     """Forward a block of ``tokens`` [B, T] against the cache at positions
     length..length+T-1, causally masked within the block — the verify
@@ -170,6 +255,16 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
     the per-row lengths.  This is what batched speculative decoding needs:
     rows accept different numbers of draft tokens, so their caches advance
     at different rates (models/generation.speculative_generate_batched).
+
+    A layer's kind (``config.layer_spec``) decides its mask and where its
+    K/V go.  A window layer the cache keeps as a RING attends the ring
+    and then the block itself, and writes the block over the ring's
+    oldest positions afterwards; a ring cannot be rolled back, and pad
+    positions would overwrite live ones, so ``counts`` [B] says how many
+    of a row's T tokens are real (default: all) and only those are
+    written.  A window layer stored by position (an extension against a
+    cached row) takes the window as a mask.  ``route_stats``, where
+    given, gains each ``experts`` layer's tokens per expert.
     """
     c = model.config
     batch, t = tokens.shape
@@ -177,16 +272,25 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
     offsets = jnp.arange(t, dtype=jnp.int32)
     if ragged:
         positions = lengths[:, None] + offsets[None, :]      # [B, T]
-        # row b's query j may attend its cache positions 0..lengths[b]+j
-        mask = (jnp.arange(cache.max_len)[None, None, :]
-                <= positions[:, :, None])[:, None, None]     # [B,1,1,T,M]
-        bidx = jnp.arange(batch, dtype=jnp.int32)[:, None]
     else:
         pos = cache.length                                   # scalar int32
         positions = pos + offsets[None, :].repeat(batch, 0)  # [B, T]
-        # query j may attend cache positions 0..pos+j
-        mask = (jnp.arange(cache.max_len)[None, :]
-                <= (pos + offsets)[:, None])[None, None, None]  # [1,1,1,T,M]
+
+    def position_mask(window: int) -> Array:
+        """[B or 1, 1, 1, T, M]: query j of row b may attend cache
+        positions 0..position, and under a window only the last W."""
+        where = positions if ragged else positions[:1]
+        held = jnp.arange(cache.max_len)[None, None, :]
+        mask = held <= where[:, :, None]
+        if 0 < window < cache.max_len:
+            mask &= where[:, :, None] - held < window
+        return mask[:, None, None]
+
+    # the plain causal mask first, as ever (a model of full layers only
+    # compiles to the program it always has); a window's on first use
+    masks: dict[int, Array] = {0: position_mask(0)}
+    if ragged:
+        bidx = jnp.arange(batch, dtype=jnp.int32)[:, None]
     # shared embed: adds learned positional embeddings at the ragged
     # positions when the config uses them (positions overshooting max_seq
     # for finished speculative rows hit embed's explicit mode="clip" —
@@ -196,76 +300,173 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
     new_k, new_v = cache.k, cache.v
     new_ks = cache.k_scale if quant else None
     new_vs = cache.v_scale if quant else None
-    groups = c.kv_groups
-    for i in range(c.n_layers):
+    new_wk, new_wv = (None, None) if quant else (cache.wk, cache.wv)
+    for layer in range(c.n_layers):
         # layer_view resolves either param layout (unrolled layer<i>/* or
         # scan_layers' stacked blocks/*)
-        lp, p = model.layer_view(params, i)
-        q, k, v = model.qkv(lp, p, h, positions)  # k/v: [B, T, KV, D]
-        if quant:
-            k, ks = _kv_quantize(k)
-            v, vs = _kv_quantize(v)
-        with jax.named_scope("cache_update"):
-            if ragged:
-                # mode="drop": rows that finished generating keep advancing
-                # their lengths each speculative round, so their scatter
-                # positions intentionally overshoot cache.max_len — those
-                # writes must be dropped, not clamped onto the last slot.
-                new_k = new_k.at[i, bidx, positions].set(
-                    k.astype(new_k.dtype), mode="drop")
-                new_v = new_v.at[i, bidx, positions].set(
-                    v.astype(new_v.dtype), mode="drop")
-                if quant:
-                    new_ks = new_ks.at[i, bidx, positions].set(ks, mode="drop")
-                    new_vs = new_vs.at[i, bidx, positions].set(vs, mode="drop")
-            else:
-                new_k = jax.lax.dynamic_update_slice(
-                    new_k, k[None].astype(new_k.dtype), (i, 0, pos, 0, 0))
-                new_v = jax.lax.dynamic_update_slice(
-                    new_v, v[None].astype(new_v.dtype), (i, 0, pos, 0, 0))
-                if quant:
-                    new_ks = jax.lax.dynamic_update_slice(
-                        new_ks, ks[None], (i, 0, pos, 0))
-                    new_vs = jax.lax.dynamic_update_slice(
-                        new_vs, vs[None], (i, 0, pos, 0))
-        with jax.named_scope("cache_attn"):
-            # dense attention against the cache, f32 softmax.  GQA: contract
-            # query-head groups directly against the UNexpanded cache — the
-            # cache bytes streamed per step stay kv_heads-sized (the point of
-            # the smaller cache), no materialized repeat
-            b, s_q = q.shape[:2]
-            qg = q.reshape(b, s_q, c.kv_heads, groups, c.head_dim)
-            # int8 cache: contract against the int8 array (only int8 bytes
-            # stream from HBM; the convert fuses into the einsum) and fold the
-            # per-(position, head) scale into the product afterwards
-            scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg,
-                                new_k[i].astype(c.dtype) if quant else new_k[i],
-                                preferred_element_type=jnp.float32)
+        lp, p = model.layer_view(params, layer)
+        spec = c.layer_spec(layer)
+        ring, i = (False, layer) if quant else cache.place(layer)
+        router = model.pre_attention_router(lp, p, spec, h)
+        q, k, v = model.qkv(lp, p, h, positions, spec)  # k/v: [B, T, KV, D]
+        if ring:
+            attn, new_wk, new_wv = _ring_attention(
+                c, q, k, v, new_wk, new_wv, i, positions, counts)
+        else:
             if quant:
-                # k_scale[i]: [B, M, H] -> [B, H, 1, 1, M] over score axes
-                scores = scores * jnp.transpose(
-                    new_ks[i], (0, 2, 1))[:, :, None, None, :]
-            scores = scores / jnp.sqrt(jnp.asarray(c.head_dim, jnp.float32))
-            scores = jnp.where(mask, scores, -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-            if quant:
-                # fold v_scale into probs (tiny [.., M] multiply) so the value
-                # contraction streams raw int8
-                probs = probs * jnp.transpose(
-                    new_vs[i], (0, 2, 1))[:, :, None, None, :].astype(c.dtype)
-            attn = jnp.einsum("bhgqk,bkhd->bqhgd", probs,
-                              new_v[i].astype(c.dtype) if quant else new_v[i],
-                              preferred_element_type=jnp.float32).astype(c.dtype)
-            attn = attn.reshape(b, s_q, c.n_heads, c.head_dim)
+                k, ks = _kv_quantize(k)
+                v, vs = _kv_quantize(v)
+            with jax.named_scope("cache_update"):
+                if ragged:
+                    # mode="drop": rows that finished generating keep
+                    # advancing their lengths each speculative round, so
+                    # their scatter positions intentionally overshoot
+                    # cache.max_len — those writes must be dropped, not
+                    # clamped onto the last slot.
+                    new_k = new_k.at[i, bidx, positions].set(
+                        k.astype(new_k.dtype), mode="drop")
+                    new_v = new_v.at[i, bidx, positions].set(
+                        v.astype(new_v.dtype), mode="drop")
+                    if quant:
+                        new_ks = new_ks.at[i, bidx, positions].set(
+                            ks, mode="drop")
+                        new_vs = new_vs.at[i, bidx, positions].set(
+                            vs, mode="drop")
+                else:
+                    new_k = jax.lax.dynamic_update_slice(
+                        new_k, k[None].astype(new_k.dtype),
+                        (i, 0, pos, 0, 0))
+                    new_v = jax.lax.dynamic_update_slice(
+                        new_v, v[None].astype(new_v.dtype),
+                        (i, 0, pos, 0, 0))
+                    if quant:
+                        new_ks = jax.lax.dynamic_update_slice(
+                            new_ks, ks[None], (i, 0, pos, 0))
+                        new_vs = jax.lax.dynamic_update_slice(
+                            new_vs, vs[None], (i, 0, pos, 0))
+            with jax.named_scope("cache_attn"), jax.named_scope("attn"), \
+                    jax.named_scope("window" if spec.window else "full"):
+                if (not quant and t >= _BLOCKWISE_QUERIES
+                        and cache.max_len >= model.BLOCKWISE_FROM):
+                    from ..ops.xla_flash import blockwise_attention
+
+                    attn = blockwise_attention(
+                        q, new_k[i], new_v[i], positions[:, 0],
+                        window=spec.window)
+                else:
+                    if spec.window not in masks:
+                        masks[spec.window] = position_mask(spec.window)
+                    attn = _dense_cache_attention(
+                        c, q, new_k, new_v, i, masks[spec.window],
+                        (new_ks, new_vs) if quant else None)
         h = model.attn_residual(lp, p, h, attn)
-        # MoE-aware, drop-free at decode time; aux loss unused here
-        h, _ = model.ffn_residual(params, i, h, decode=True)
+        # the FFN's weights viewed where they are used, as ever (under
+        # scan_layers a view is slices, and their place in the program is
+        # part of what the compiler is handed)
+        lp, p = model.layer_view(params, layer)
+        # experts never drop and moe decodes drop-free; aux loss unused
+        h, _ = model.ffn_residual(lp, p, spec, h, decode=True,
+                                  router_logits=router,
+                                  route_stats=route_stats)
     logits = model.final_logits(params, h)
     new_length = cache.length if ragged else pos + t
     if quant:
         return logits, QuantKVCache(k=new_k, v=new_v, k_scale=new_ks,
                                     v_scale=new_vs, length=new_length)
-    return logits, KVCache(k=new_k, v=new_v, length=new_length)
+    return logits, dataclasses.replace(cache, k=new_k, v=new_v, wk=new_wk,
+                                       wv=new_wv, length=new_length)
+
+
+def _dense_cache_attention(c, q: Array, keys: Array, values: Array, i: int,
+                           mask: Array, scales) -> Array:
+    """Dense attention of q [B, T, H, D] against layer ``i`` of the cache's
+    part stored by position (keys/values [L, B, M, KV, D]), f32 softmax.
+    GQA: query-head groups contract directly against the UNexpanded cache
+    — the cache bytes streamed per step stay kv_heads-sized (the point of
+    the smaller cache), no materialized repeat.  int8 cache (``scales`` =
+    (k_scale, v_scale), each [L, B, M, KV]): contract against the int8
+    array (only int8 bytes stream from HBM; the convert fuses into the
+    einsum) and fold the per-(position, head) scale into the product
+    afterwards."""
+    quant = scales is not None
+    b, s_q = q.shape[:2]
+    qg = q.reshape(b, s_q, c.kv_heads, c.kv_groups, c.head_dim)
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg,
+                        keys[i].astype(c.dtype) if quant else keys[i],
+                        preferred_element_type=jnp.float32)
+    if quant:
+        # k_scale[i]: [B, M, H] -> [B, H, 1, 1, M] over score axes
+        scores = scores * jnp.transpose(
+            scales[0][i], (0, 2, 1))[:, :, None, None, :]
+    scores = scores / jnp.sqrt(jnp.asarray(c.head_dim, jnp.float32))
+    scores = jnp.where(mask, scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
+    if quant:
+        # fold v_scale into probs (tiny [.., M] multiply) so the value
+        # contraction streams raw int8
+        probs = probs * jnp.transpose(
+            scales[1][i], (0, 2, 1))[:, :, None, None, :].astype(c.dtype)
+    attn = jnp.einsum("bhgqk,bkhd->bqhgd", probs,
+                      values[i].astype(c.dtype) if quant else values[i],
+                      preferred_element_type=jnp.float32).astype(c.dtype)
+    return attn.reshape(b, s_q, c.n_heads, c.head_dim)
+
+
+def _ring_attention(c, q: Array, k: Array, v: Array, ring_k: Array,
+                    ring_v: Array, i: int, positions: Array,
+                    counts: Array | None) -> tuple[Array, Array, Array]:
+    """A window layer against its ring.  q [B, T, H, D] at
+    ``positions`` [B, T]; k/v [B, T, KV, D] the block's own; ring_k/ring_v
+    [Lw, B, W, KV, D] with position p at index p % W.  The queries attend
+    what the ring held BEFORE the block (index s: the latest position
+    below the block's first that is congruent to s, seen while within the
+    window) and then the block itself, causally; afterwards the block's
+    real positions (the first ``counts[b]``; all by default) overwrite the
+    ring's oldest.  Returns (attn [B, T, H, D], ring_k, ring_v)."""
+    batch, t = positions.shape
+    window = ring_k.shape[2]
+    if t > window:
+        raise ValueError(f"a block of {t} positions does not go through a "
+                         f"ring of {window}: forward it against a cache "
+                         "stored by position")
+    qg = q.reshape(batch, t, c.kv_heads, c.kv_groups, c.head_dim)
+    scale = jnp.sqrt(jnp.asarray(c.head_dim, jnp.float32))
+    first = positions[:, :1]                                  # [B, 1]
+    with jax.named_scope("cache_attn"), jax.named_scope("attn"), \
+            jax.named_scope("window"):
+        held = first - 1 - (first - 1 - jnp.arange(window)[None]) % window
+        # [B, T, W]: in the ring yet, and still within the query's window
+        seen = ((held >= 0)[:, None, :]
+                & (positions[:, :, None] - held[:, None, :] < window))
+        offsets = jnp.arange(t)
+        within = ((offsets[None, :] <= offsets[:, None])
+                  & (offsets[:, None] - offsets[None, :] < window))
+        mask = jnp.concatenate(
+            [seen, jnp.broadcast_to(within[None], (batch, t, t))], axis=-1)
+        scores = jnp.concatenate([
+            jnp.einsum("bqhgd,bkhd->bhgqk", qg, ring_k[i],
+                       preferred_element_type=jnp.float32),
+            jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(ring_k.dtype),
+                       preferred_element_type=jnp.float32)], axis=-1) / scale
+        scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
+        attn = (jnp.einsum("bhgqk,bkhd->bqhgd", probs[..., :window],
+                           ring_v[i], preferred_element_type=jnp.float32)
+                + jnp.einsum("bhgqk,bkhd->bqhgd", probs[..., window:],
+                             v.astype(ring_v.dtype),
+                             preferred_element_type=jnp.float32))
+        attn = attn.astype(c.dtype).reshape(batch, t, c.n_heads, c.head_dim)
+    with jax.named_scope("cache_update"):
+        at = positions % window
+        if counts is not None:
+            # a pad position's write falls outside the ring and is dropped
+            at = jnp.where(offsets[None, :] < counts[:, None], at, window)
+        bidx = jnp.arange(batch, dtype=jnp.int32)[:, None]
+        ring_k = ring_k.at[i, bidx, at].set(k.astype(ring_k.dtype),
+                                            mode="drop")
+        ring_v = ring_v.at[i, bidx, at].set(v.astype(ring_v.dtype),
+                                            mode="drop")
+    return attn, ring_k, ring_v
 
 
 def decode_step(model: Transformer, params: Mapping[str, Array],
@@ -330,7 +531,12 @@ def sample_token_rowwise(logits: Array, rng: Array, temps: Array,
 # otherwise pin compiled executables (and their models) for process
 # lifetime.  Lock-guarded — concurrent generate() calls share the cache.
 _RUNNERS: "OrderedDict[tuple, object]" = OrderedDict()
-_RUNNERS_MAX = 32
+# Room for one serving process's programs: a server holds an extension and
+# a splice program per (resident row width, suffix bucket) — four resident
+# documents by six buckets are already 57 with the prefills and the step —
+# and a runner pushed out is retraced and loaded again mid-service, a stall
+# of 0.2-0.7 s (chip runs, PR 27, at the 32 this was).
+_RUNNERS_MAX = 256
 _RUNNERS_LOCK = threading.Lock()
 
 
@@ -412,10 +618,12 @@ def _beam_runner(model: Transformer, max_new_tokens: int, beam_width: int,
             lengths = jnp.ones((b, w), jnp.int32)
 
             # beams live interleaved in the cache batch dim: row b*W + j
-            def tile(x):
-                return jnp.repeat(x, w, axis=1)
-            cache = KVCache(k=tile(cache.k), v=tile(cache.v),
-                            length=cache.length)
+            def over_rows(fn, cache):
+                """fn on the batch axis of every part of the cache."""
+                return jax.tree.map(
+                    lambda x: fn(x) if x.ndim == 5 else x, cache)
+
+            cache = over_rows(lambda x: jnp.repeat(x, w, axis=1), cache)
             seqs = jnp.zeros((b, w, max_new_tokens), jnp.int32)
             seqs = seqs.at[:, :, 0].set(first)
 
@@ -452,9 +660,8 @@ def _beam_runner(model: Transformer, max_new_tokens: int, beam_width: int,
                 if eos_id is not None:
                     finished = finished | (token == eos_id)
                 rows = (jnp.arange(b)[:, None] * w + parent).reshape(-1)
-                cache = KVCache(k=jnp.take(cache.k, rows, axis=1),
-                                v=jnp.take(cache.v, rows, axis=1),
-                                length=cache.length)
+                cache = over_rows(lambda x: jnp.take(x, rows, axis=1),
+                                  cache)
                 return (seqs, scores, finished, lengths, cache), None
 
             (seqs, scores, _, lengths, _), _ = jax.lax.scan(

@@ -300,6 +300,8 @@ def config_from_hf_llama(hf_config: Any, *, dtype=jnp.bfloat16,
         vocab=hf_config.vocab_size,
         d_model=hf_config.hidden_size,
         n_heads=hf_config.num_attention_heads,
+        # a config may give the head size apart from hidden / heads
+        head_dim=int(getattr(hf_config, "head_dim", 0) or 0),
         n_kv_heads=(hf_config.num_key_value_heads
                     if hf_config.num_key_value_heads
                     != hf_config.num_attention_heads else 0),

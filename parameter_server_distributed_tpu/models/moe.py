@@ -1,6 +1,24 @@
-"""Mixture-of-Experts layer with expert parallelism.
+"""Mixture-of-Experts layers: two, for two uses.
 
-Top-k routing with capacity (k=1 gives the Switch transformer, k=2 the
+:func:`dropless_experts` is what a served or forwarded model's ``experts``
+layers run (``LayerSpec.ffn == "experts"``): every (token, choice)
+assignment is computed whatever the imbalance.  Assignments are sorted by
+expert and the experts run as ONE grouped matmul per weight
+(``jax.lax.ragged_dot``: rows of expert e meet only expert e's matrix), so
+nothing of size tokens x experts x capacity is ever built and prefill,
+extension and decode share the path.  Gates are the softmax over the
+selected logits; the experts take the model's ``mlp_act`` form (gated:
+``w2(act(w1 x) * (w3 x))``).
+
+:class:`MoELayer` is the capacity-dropping Switch/top-k layer of the
+home-made presets (``moe_lm``, ``switch_lm``, ``moe_350m``;
+``LayerSpec.ffn == "moe"``, ``moe_every``).  Those presets stay what they
+were: CPU fixtures of expert parallelism over the mesh's ``expert`` axis,
+of the pipeline's MoE stage and of the load-balancing loss, with their
+tests.  None of them is a benchmark configuration, and none runs the
+dropless path.
+
+MoELayer: top-k routing with capacity (k=1 gives the Switch transformer, k=2 the
 Mixtral/GShard shape): the router picks each token's top-k experts, gates
 are the top-k probabilities renormalized to sum one, and (token, choice)
 assignments beyond an expert's capacity are dropped (pass through the
@@ -17,6 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import Mapping
 
 import jax
@@ -168,6 +187,47 @@ class MoELayer:
         return out.reshape(b, s, d), aux
 
 
+def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
+                     w3: Array | None, *, top_k: int,
+                     act: str = "gelu") -> tuple[Array, Array]:
+    """Dropless top-k experts over a flat batch of tokens.
+
+    x [N, D]; router_logits [N, E] float32; w1 (and the gate pair's up
+    projection w3, None for an ungated ``act``) [E, D, F]; w2 [E, F, D].
+    Returns (out [N, D] float32, loads [E] int32: assignments per expert).
+
+    Token n's output is sum over its top_k experts e of gate_e *
+    expert_e(x_n), gate = softmax over the SELECTED logits (float32).
+    The N * top_k assignments are sorted by expert (stable, so ties keep
+    token order) and each weight runs once as a grouped matmul over the
+    sorted rows; the results are un-sorted by gather and summed per token.
+    """
+    n, d = x.shape
+    experts = w1.shape[0]
+    with jax.named_scope("router"):
+        top_logits, top_idx = jax.lax.top_k(
+            router_logits.astype(jnp.float32), top_k)          # [N, k]
+        gates = jax.nn.softmax(top_logits, axis=-1)
+        flat = top_idx.reshape(n * top_k)
+        order = jnp.argsort(flat, stable=True)                 # [A]
+        loads = jnp.zeros((experts,), jnp.int32).at[flat].add(1)
+        rows = x[order // top_k]                               # [A, D]
+    with jax.named_scope("experts"):
+        dot = partial(jax.lax.ragged_dot, group_sizes=loads,
+                      preferred_element_type=jnp.float32)
+        hidden = dot(rows, w1).astype(x.dtype)
+        if act == "gelu":
+            hidden = jax.nn.gelu(hidden)
+        else:
+            gate = jax.nn.silu if act == "swiglu" else jax.nn.relu
+            hidden = gate(hidden) * dot(rows, w3).astype(x.dtype)
+        out = dot(hidden, w2)                                  # [A, D] f32
+    with jax.named_scope("router"):
+        # back to (token, choice) order, weighted and summed per token
+        out = out[jnp.argsort(order)].reshape(n, top_k, d)
+        return jnp.sum(out * gates[..., None], axis=1), loads
+
+
 def moe_expert_weight_spec(name: str, shape: tuple[int, ...], n_exp: int,
                            n_tp: int, n_fsdp: int) -> PartitionSpec:
     """Sharding for a [E, in, out] expert weight: ``expert`` on the expert
@@ -178,7 +238,7 @@ def moe_expert_weight_spec(name: str, shape: tuple[int, ...], n_exp: int,
     spec: list = [None] * len(shape)
     if n_exp > 1 and shape[0] % n_exp == 0:
         spec[0] = "expert"
-    is_w1 = name.endswith("w1")
+    is_w1 = name.endswith(("w1", "w3"))
     ff_axis = len(shape) - 1 if is_w1 else 1
     d_axis = 1 if is_w1 else len(shape) - 1
     if n_tp > 1 and shape[ff_axis] % n_tp == 0:

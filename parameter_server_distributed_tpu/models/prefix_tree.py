@@ -17,7 +17,11 @@ sequences where
 - **eviction** is byte-accounted LRU over tree nodes (the
   ``PSDT_PREFIX_CACHE_BYTES`` budget replaces the PR 14 entry count),
   with a touch bumping the WHOLE ancestor path — a hot shared prefix is
-  never evicted out from under its live descendants;
+  never evicted out from under its live descendants.  A row holds its
+  WHOLE path, so a short turn under a 12,288-token document pins a row
+  as wide as the document for the sake of its own few tokens: such
+  TAILS (:attr:`RadixNode.is_tail`) go before any other row, or a
+  stream of them pushes the documents themselves out of the budget;
 - every tree path is summarised into a compact **fingerprint** (chained
   CRC32 at block boundaries) the decode fleet heartbeats to the
   coordinator, so the router can score cached-prefix overlap.
@@ -152,12 +156,19 @@ class RadixNode:
         self.depth = (0 if parent is None else parent.depth) + len(edge)
         self.tick = 0
 
+    @property
+    def is_tail(self) -> bool:
+        """Its own tokens are at most an eighth of its path: its row
+        repeats seven eighths of what an ancestor already holds, and
+        losing it costs a forward of the last eighth."""
+        return 8 * len(self.edge) <= self.depth
+
 
 class PrefixTree:
     """See module docstring.  ``budget_bytes`` bounds the summed size of
     UNIQUE row handles; inserts over budget evict least-recently-touched
-    leaves (path-compressing parents left with a single child and no
-    complete-prompt payload)."""
+    leaves, tails first (path-compressing parents left with a single
+    child and no complete-prompt payload)."""
 
     def __init__(self, budget_bytes: int):
         self.budget_bytes = int(budget_bytes)
@@ -194,18 +205,25 @@ class PrefixTree:
         the partially-entered child (``partial=True``; its handle's
         first ``matched`` positions are still the prefix K/V, which is
         the whole point of a token-level tree)."""
+        if not isinstance(tokens, tuple):
+            tokens = tuple(int(t) for t in tokens)
         node = self.root
         matched = 0
         n = len(tokens)
         while matched < n:
-            child = node.children.get(int(tokens[matched]))
+            child = node.children.get(tokens[matched])
             if child is None:
                 return node, matched, False
             edge = child.edge
             limit = min(len(edge), n - matched)
-            j = 0
-            while j < limit and edge[j] == int(tokens[matched + j]):
-                j += 1
+            # a whole edge that matches (a resident document of thousands
+            # of tokens) is one tuple comparison, not a loop over it
+            if edge[:limit] == tokens[matched:matched + limit]:
+                j = limit
+            else:
+                j = 0
+                while edge[j] == tokens[matched + j]:
+                    j += 1
             matched += j
             if j < len(edge):
                 return child, matched, True
@@ -232,7 +250,8 @@ class PrefixTree:
         evicts afterwards (:meth:`evict_over_budget`) so the freshly
         admitted row participates in — and by recency survives — the
         LRU pass."""
-        tokens = tuple(int(t) for t in tokens)
+        if not isinstance(tokens, tuple):
+            tokens = tuple(int(t) for t in tokens)
         node, matched, partial = self.lookup(tokens)
         if partial:
             node = self._split(node, matched - node.parent.depth)
@@ -284,15 +303,16 @@ class PrefixTree:
     # ------------------------------------------------------------ eviction
     def evict_over_budget(self) -> int:
         """Pop least-recently-touched LEAVES until the unique-handle
-        byte total fits the budget; returns nodes evicted.  Removing a
-        leaf may leave its parent with one child and no complete-prompt
-        payload — such parents merge back into their child (path
-        compression), shedding their handle references."""
+        byte total fits the budget, the tails (see
+        :attr:`RadixNode.is_tail`) before any other; returns nodes
+        evicted.  Removing a leaf may leave its parent with one child and
+        no complete-prompt payload — such parents merge back into their
+        child (path compression), shedding their handle references."""
         evicted = 0
         while self.bytes > self.budget_bytes and self.nodes:
             leaf = min(
                 (n for n in self._walk() if not n.children),
-                key=lambda n: n.tick)
+                key=lambda n: (not n.is_tail, n.tick))
             self._remove_leaf(leaf)
             evicted += 1
         if evicted:

@@ -48,7 +48,8 @@ from ..obs import trace as obs_trace
 from .generation import (KVCache, QuantKVCache, _cached_runner,
                          _kv_quantize, _model_key, _spec_round_runner,
                          check_position_budget, decode_block, init_cache,
-                         sample_token, sample_token_rowwise)
+                         ring_layers_of, sample_token, sample_token_rowwise,
+                         split_row)
 from .prefix_tree import PrefixTree, RowRef
 from .transformer import Transformer
 
@@ -65,7 +66,15 @@ class _Slot:
     stop: frozenset = frozenset()
 
 
+# rows longer than this are padded to its next multiple, not to the next
+# power of two: a 12,288-token row stays 12,288 wide (a power of two would
+# make it 16,384, and with a suffix it would no longer fit a 16,384 slot)
+_FINE_BUCKET = 2048
+
+
 def _bucket(n: int, lo: int = 16) -> int:
+    if n > _FINE_BUCKET:
+        return -(-n // _FINE_BUCKET) * _FINE_BUCKET
     b = lo
     while b < n:
         b *= 2
@@ -136,22 +145,30 @@ def _shard_cache(cache, mesh):
 
 def _prefill_runner(model: Transformer, bucket: int, cache_dtype: str):
     """Jitted per (model, prompt bucket): forward the padded prompt, return
-    the last REAL position's logits and the prompt's K/V stack (quantized
-    already when the slot cache is int8, so splicing is dtype-pure)."""
+    the last REAL position's logits, the prompt's K/V stack (quantized
+    already when the slot cache is int8, so splicing is dtype-pure) and
+    the tokens per expert of every experts layer ([L * E], else None)."""
     key = (_model_key(model), "serve_prefill", bucket, cache_dtype)
 
     def build():
         @jax.jit
         def run(params, padded, real_len):
-            logits, kvs = model.apply_collect_kv(params, padded)
-            last = logits[0, real_len - 1]                  # [vocab]
+            # the head runs on the last REAL position alone: the logits
+            # of a whole long row would be gigabytes
+            routed: list = []
+            h, kvs, _ = model._forward(params, padded, collect_kv=True,
+                                       route_stats=routed)
+            loads = jnp.concatenate(routed) if routed else None
+            last = model.final_logits(
+                params, jax.lax.dynamic_slice_in_dim(
+                    h, real_len - 1, 1, axis=1))[0, 0]      # [vocab]
             k = jnp.stack([k for k, _ in kvs])[:, 0]        # [L, S', H, D]
             v = jnp.stack([v for _, v in kvs])[:, 0]
             if cache_dtype == "int8":
                 k8, ks = _kv_quantize(k)
                 v8, vs = _kv_quantize(v)
-                return last, (k8, v8, ks, vs)
-            return last, (k, v)
+                return last, (k8, v8, ks, vs), loads
+            return last, (k, v), loads
 
         return run
 
@@ -161,7 +178,8 @@ def _prefill_runner(model: Transformer, bucket: int, cache_dtype: str):
 def _splice_runner(model: Transformer, bucket: int, cache_dtype: str):
     """Jitted per (model, bucket): write one prefilled row's K/V into slot
     ``slot`` of the batch cache (dynamic slot index — one program serves
-    every slot)."""
+    every slot).  A row holds every layer by position; a layer the cache
+    keeps as a ring takes the last ring's worth before ``length``."""
     key = (_model_key(model), "serve_splice", bucket, cache_dtype)
 
     def build():
@@ -169,7 +187,7 @@ def _splice_runner(model: Transformer, bucket: int, cache_dtype: str):
         # so XLA may update the (large) K/V buffers in place
         @partial(jax.jit, donate_argnums=(0,))
         @jax.named_scope("splice")
-        def run(cache, row, slot):
+        def run(cache, row, slot, length):
             if cache_dtype == "int8":
                 k8, v8, ks, vs = row
                 return QuantKVCache(
@@ -182,15 +200,18 @@ def _splice_runner(model: Transformer, bucket: int, cache_dtype: str):
                     v_scale=jax.lax.dynamic_update_slice(
                         cache.v_scale, vs[:, None], (0, slot, 0, 0)),
                     length=cache.length)
-            k, v = row
-            return KVCache(
-                k=jax.lax.dynamic_update_slice(
-                    cache.k, k[:, None].astype(cache.k.dtype),
-                    (0, slot, 0, 0, 0)),
-                v=jax.lax.dynamic_update_slice(
-                    cache.v, v[:, None].astype(cache.v.dtype),
-                    (0, slot, 0, 0, 0)),
-                length=cache.length)
+            k, v, wk, wv = split_row(cache, *row, length)
+
+            def put(part, new):
+                if new is None:
+                    return part
+                return jax.lax.dynamic_update_slice(
+                    part, new[:, None].astype(part.dtype),
+                    (0, slot, 0, 0, 0))
+
+            return dataclasses.replace(
+                cache, k=put(cache.k, k), v=put(cache.v, v),
+                wk=put(cache.wk, wk), wv=put(cache.wv, wv))
 
         return run
 
@@ -208,8 +229,9 @@ def _extend_runner(model: Transformer, pbucket: int, sbucket: int,
     then decoding forward would have computed; pad positions past the
     real suffix write garbage beyond the frontier, masked and
     overwritten exactly like prefill pad positions.  Returns the last
-    REAL suffix position's logits and the combined (prefix + suffix)
-    K/V row, ready for the ordinary slot splice."""
+    REAL suffix position's logits, the combined (prefix + suffix) K/V
+    row, ready for the ordinary slot splice, and the experts layers'
+    tokens per expert as :func:`_prefill_runner` returns them."""
     key = (_model_key(model), "serve_extend", pbucket, sbucket,
            cache_dtype)
     total = pbucket + sbucket
@@ -242,14 +264,18 @@ def _extend_runner(model: Transformer, pbucket: int, sbucket: int,
                     v=jnp.zeros((layers, 1, total, heads, dim), dtype)
                     .at[:, 0, :pbucket].set(v.astype(dtype)),
                     length=jnp.zeros((), jnp.int32))
+            routed: list = []
             logits, cache = decode_block(model, params, padded_suffix,
                                          cache,
-                                         lengths=prefix_len[None])
+                                         lengths=prefix_len[None],
+                                         route_stats=routed)
+            loads = jnp.concatenate(routed) if routed else None
             last = logits[0, suffix_len - 1]
             if cache_dtype == "int8":
                 return last, (cache.k[:, 0], cache.v[:, 0],
-                              cache.k_scale[:, 0], cache.v_scale[:, 0])
-            return last, (cache.k[:, 0], cache.v[:, 0])
+                              cache.k_scale[:, 0],
+                              cache.v_scale[:, 0]), loads
+            return last, (cache.k[:, 0], cache.v[:, 0]), loads
 
         return run
 
@@ -287,12 +313,16 @@ def _decode_round(model, top_k, top_p, params, tokens, cache, lengths,
     so step_many's token-exactness vs a step() loop holds by
     construction (same decode_block -> rng split -> rowwise sample
     sequence)."""
+    routed: list = []
     logits, cache = decode_block(model, params, tokens[:, None], cache,
-                                 lengths=lengths)
+                                 lengths=lengths, route_stats=routed)
     with jax.named_scope("sample"):
         rng, sub = jax.random.split(rng)
         nxt = sample_token_rowwise(logits[:, 0], sub, temps, top_k, top_p)
-    return nxt, cache, rng
+    # tokens per expert of every experts layer ([L * E]) leave with the
+    # round's tokens, in the fetch the round makes anyway
+    loads = jnp.concatenate(routed) if routed else None
+    return nxt, cache, rng, loads
 
 
 def _multi_step_runner(model: Transformer, slots: int, top_k: int,
@@ -312,7 +342,8 @@ def _multi_step_runner(model: Transformer, slots: int, top_k: int,
         def run(params, tokens, cache, lengths, temps, rng):
             def body(carry, _):
                 tokens, cache, lengths, rng = carry
-                nxt, cache, rng = _decode_round(
+                # the fused rounds keep no loads
+                nxt, cache, rng, _ = _decode_round(
                     model, top_k, top_p, params, tokens, cache, lengths,
                     temps, rng)
                 return (nxt, cache, lengths + 1, rng), nxt
@@ -419,6 +450,15 @@ class DecodeServer:
         self._cache = init_cache(model, slots, max_len, cache_dtype)
         if mesh is not None:
             self._cache = _shard_cache(self._cache, mesh)
+        config = model.config
+        self._moe_layers = sum(config.layer_spec(i).ffn == "experts"
+                               for i in range(config.n_layers))
+        if draft is not None and (ring_layers_of(model, max_len)
+                                  or ring_layers_of(draft, max_len)):
+            raise ValueError(
+                "speculative serving rolls rejected positions back, and a "
+                "window layer's ring cannot be rolled back: serve a model "
+                "with window layers without a draft")
         self._lengths = np.zeros((slots,), np.int32)
         self._tokens = np.zeros((slots,), np.int32)
         self._slot: list[_Slot | None] = [None] * slots
@@ -446,6 +486,16 @@ class DecodeServer:
         self._obs_round_device = obs_stats.histogram("serve.round_device_s")
         self._obs_round_host = obs_stats.histogram("serve.round_host_s")
         self._obs_between = obs_stats.histogram("serve.between_rounds_s")
+        # what the experts layers routed, a round at a time (see
+        # _count_routing), and the bytes held by kind of layer
+        self._moe_assignments = 0
+        self._obs_moe = {name: obs_stats.counter(f"serve.moe.{name}")
+                         for name in ("assignments", "layer_rounds",
+                                      "experts_touched", "expert_places",
+                                      "load_max_over_mean",
+                                      "admit_experts_touched")}
+        for kind, held in self._cache_bytes_by_kind().items():
+            obs_stats.gauge(f"serve.cache.{kind}_bytes").set(held)
         # perf_counter at the last round's return, while a slot is active
         self._round_returned: float | None = None
         # radix-tree prefix cache (ISSUE 20): token-level index over
@@ -657,7 +707,8 @@ class DecodeServer:
         """Shared-prefix extension from the deepest cached ancestor:
         forward only the suffix past the ``matched``-token tree prefix
         against the covering node's K/V row (_extend_runner).  Returns
-        (last logits, combined row, draft row | None) or None (no
+        (last logits, combined row, draft row | None, the experts
+        layers' loads | None) or None (no
         usable prefix / combined row would not fit the slot cache —
         the caller full-prefills).  The suffix math is a ragged
         decode_block — exactly what decoding those tokens one round at
@@ -685,28 +736,28 @@ class DecodeServer:
         suffix = jnp.asarray(padded)
         plen_j = jnp.asarray(plen, jnp.int32)
         slen_j = jnp.asarray(slen, jnp.int32)
-        last, row = _extend_runner(self.model, pbucket, sbucket,
-                                   self.cache_dtype)(
+        last, row, loads = _extend_runner(self.model, pbucket, sbucket,
+                                          self.cache_dtype)(
             self.params, pre_row, suffix, plen_j, slen_j)
         d_row = None
         if self.draft is not None and self._k > 0:
             dpre = node.dhandle.row if node.dhandle is not None else None
             dbucket = int(dpre[0].shape[1]) if dpre is not None else 0
             if dpre is not None and dbucket + sbucket <= self.max_len:
-                _, d_row = _extend_runner(self.draft, dbucket, sbucket,
-                                          self.cache_dtype)(
+                _, d_row, _ = _extend_runner(self.draft, dbucket, sbucket,
+                                             self.cache_dtype)(
                     self.draft_params, dpre, suffix, plen_j, slen_j)
             else:
                 dbucket = min(_bucket(real_len), self.max_len)
                 dpadded = np.zeros((1, dbucket), np.int32)
                 dpadded[0, :real_len] = prompt
-                _, d_row = _prefill_runner(self.draft, dbucket,
-                                           self.cache_dtype)(
+                _, d_row, _ = _prefill_runner(self.draft, dbucket,
+                                              self.cache_dtype)(
                     self.draft_params, jnp.asarray(dpadded),
                     jnp.asarray(real_len, jnp.int32))
         self._prefix_tree.touch(node)  # the whole ancestor path is hot
         self._prefill_tokens += slen
-        return last, row, d_row
+        return last, row, d_row, loads
 
     def _admit_to_tree(self, pkey: tuple, last, row, d_row) -> None:
         """Insert an admitted prompt's rows into the radix tree (an
@@ -784,9 +835,10 @@ class DecodeServer:
         lookup, prefill or suffix extension, first token, splice."""
         bucket = min(_bucket(real_len), self.max_len)
         tree = self._prefix_tree
-        pkey = tuple(int(t) for t in prompt) if tree is not None else None
+        pkey = tuple(prompt.tolist()) if tree is not None else None
         hit = None
         anc, matched = None, 0
+        loads = None   # what an admission's forward routed, if it ran one
         if tree is not None:
             anc, matched, partial = tree.lookup(pkey)
             if (matched == real_len and not partial
@@ -811,8 +863,8 @@ class DecodeServer:
                 # backfill the draft half and attach it to the node
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :real_len] = prompt
-                _, d_row = _prefill_runner(self.draft, bucket,
-                                           self.cache_dtype)(
+                _, d_row, _ = _prefill_runner(self.draft, bucket,
+                                              self.cache_dtype)(
                     self.draft_params, jnp.asarray(padded),
                     jnp.asarray(real_len, jnp.int32))
                 self._admit_to_tree(pkey, last, row, d_row)
@@ -828,7 +880,7 @@ class DecodeServer:
             if extended is not None:
                 # only the suffix ran a forward; the combined row
                 # splices below under its own (wider) width
-                last, row, d_row = extended
+                last, row, d_row, loads = extended
                 self._prefix_hits += 1
                 flight.record("serve.prefix.hit",
                               a=min(matched, real_len - 1),
@@ -836,8 +888,8 @@ class DecodeServer:
             else:
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :real_len] = prompt
-                last, row = _prefill_runner(self.model, bucket,
-                                            self.cache_dtype)(
+                last, row, loads = _prefill_runner(self.model, bucket,
+                                                   self.cache_dtype)(
                     self.params, jnp.asarray(padded),
                     jnp.asarray(real_len, jnp.int32))
                 d_row = None
@@ -847,8 +899,8 @@ class DecodeServer:
                     # cache is not read while disabled, so skip its
                     # prefill + splice; a later re-probe backfills via
                     # the cache-hit repair above
-                    _, d_row = _prefill_runner(self.draft, bucket,
-                                               self.cache_dtype)(
+                    _, d_row, _ = _prefill_runner(self.draft, bucket,
+                                                  self.cache_dtype)(
                         self.draft_params, jnp.asarray(padded),
                         jnp.asarray(real_len, jnp.int32))
             self._prompt_tokens += real_len
@@ -858,18 +910,22 @@ class DecodeServer:
         first = int(sample_token(last[None], sub, req_temp,
                                  self._top_k, self._top_p)[0])
         device.__exit__(None, None, None)
+        if loads is not None:
+            # already on the host's side of the fetch of the first token
+            self._count_routing(np.asarray(loads), admission=True)
         # splice widths come from the rows themselves: a radix-served
         # row is prefix-bucket + suffix-bucket wide, and the target and
         # draft rows may differ (each extended from its own ancestor
         # width)
+        length = jnp.asarray(real_len, jnp.int32)
         self._cache = _splice_runner(self.model, int(row[0].shape[1]),
                                      self.cache_dtype)(
-            self._cache, row, jnp.asarray(slot, jnp.int32))
+            self._cache, row, jnp.asarray(slot, jnp.int32), length)
         if self.draft is not None and d_row is not None:
             self._d_cache = _splice_runner(self.draft,
                                            int(d_row[0].shape[1]),
                                            self.cache_dtype)(
-                self._d_cache, d_row, jnp.asarray(slot, jnp.int32))
+                self._d_cache, d_row, jnp.asarray(slot, jnp.int32), length)
             self._d_lengths[slot] = real_len
             self._prev[slot] = int(prompt[-1])
         rid = self._next_id
@@ -910,9 +966,11 @@ class DecodeServer:
         inputs = (jnp.asarray(self._tokens), self._cache,
                   jnp.asarray(self._lengths), jnp.asarray(self._temps))
         with device:
-            nxt, self._cache, self._rng = self._step(
+            nxt, self._cache, self._rng, loads = self._step(
                 self.params, *inputs, self._rng)
-            nxt = np.asarray(nxt)
+            nxt, loads = jax.device_get((nxt, loads))
+        if loads is not None:
+            self._count_routing(loads)
         emitted: list[tuple[int, int]] = []
         for i, entry in enumerate(self._slot):
             if entry is None:
@@ -1065,6 +1123,37 @@ class DecodeServer:
         self._obs_round.observe(now - t0)
         self._obs_tokens.add(self._n_emitted - emitted)
 
+    def _cache_bytes_by_kind(self) -> dict[str, int]:
+        """Bytes of the slot cache by kind of layer: ``full`` the layers
+        stored by position, ``window`` the rings."""
+        if isinstance(self._cache, KVCache):
+            return self._cache.nbytes_by_kind()
+        return {"full": sum(int(leaf.nbytes) for leaf in
+                            jax.tree_util.tree_leaves(self._cache)),
+                "window": 0}
+
+    def _count_routing(self, loads: np.ndarray,
+                       admission: bool = False) -> None:
+        """One forward's tokens per expert ([L * E], every experts layer
+        in order) into the counters a per-layer metric divides.  Of every
+        forward: assignments routed (pad positions' and idle lanes' too:
+        the device computes them).  Of an admission's: the distinct
+        experts it touched.  Of a decode round's: (layer, round) pairs
+        seen, distinct experts touched over them, expert places over them,
+        and the largest expert's load over the mean, summed."""
+        loads = loads.reshape(self._moe_layers, -1)
+        routed, touched = int(loads.sum()), int((loads > 0).sum())
+        self._moe_assignments += routed
+        self._obs_moe["assignments"].add(routed)
+        if admission:
+            self._obs_moe["admit_experts_touched"].add(touched)
+            return
+        self._obs_moe["layer_rounds"].add(loads.shape[0])
+        self._obs_moe["experts_touched"].add(touched)
+        self._obs_moe["expert_places"].add(loads.size)
+        self._obs_moe["load_max_over_mean"].add(
+            float((loads.max(axis=1) / loads.mean(axis=1)).sum()))
+
     def _finishes(self, entry: _Slot, token: int) -> bool:
         return (len(entry.tokens) >= entry.max_new
                 or (self.eos_id is not None and token == self.eos_id)
@@ -1114,6 +1203,11 @@ class DecodeServer:
         # prompt phase actually forwarded vs prompt tokens admitted
         out["prefill_tokens"] = self._prefill_tokens
         out["prompt_tokens"] = self._prompt_tokens
+        kinds = self._cache_bytes_by_kind()
+        out["cache_full_bytes"] = kinds["full"]
+        out["cache_window_bytes"] = kinds["window"]
+        if self._moe_layers:
+            out["moe_assignments"] = self._moe_assignments
         if self.draft is not None:
             out["draft_accept_rate"] = (
                 self._spec_accepted / self._spec_proposed

@@ -42,11 +42,45 @@ log = logging.getLogger("pst.models")
 Array = jax.Array
 
 
+FFN_KINDS = ("mlp", "moe", "experts")
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One layer of a model's pattern: what its attention sees and what
+    its feed-forward branch is.  ``TransformerConfig.pattern`` holds one
+    period; layer ``i`` is ``pattern[i % len(pattern)]``."""
+    # 0: every earlier position; W: the last W (query i sees key j where
+    # 0 <= i - j < W), and a cache slot of this layer holds W positions
+    window: int = 0
+    # rotary positions on q and k.  False: the layer has no position
+    # signal of its own (and never has one under pos_emb="learned", where
+    # positions enter at the embedding)
+    rope: bool = True
+    # mlp: the dense MLP.  moe: the capacity-dropping Switch/top-k layer
+    # (models/moe.py MoELayer; training fixtures).  experts: dropless
+    # sort-and-group routing (models/moe.py dropless_experts) over experts
+    # of ``mlp_act``'s form and ``d_ff``'s width, gates the softmax over
+    # the selected logits, the router BEFORE attention (it reads the
+    # attention's normed input)
+    ffn: str = "mlp"
+
+    def __post_init__(self):
+        if self.ffn not in FFN_KINDS:
+            raise ValueError(f"ffn must be one of {FFN_KINDS}, "
+                             f"got {self.ffn!r}")
+        if self.window < 0:
+            raise ValueError(f"window must be >= 0, got {self.window}")
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 32000
     d_model: int = 512
     n_heads: int = 8
+    # size of one attention head; 0 = d_model // n_heads.  Where it is
+    # given, wq maps d_model -> n_heads * head_dim and wo back
+    head_dim: int = 0
     # Grouped-query attention: number of K/V heads (0 = n_heads, i.e. MHA;
     # 1 = multi-query).  Shrinks wk/wv and the decode KV cache by
     # n_heads/n_kv_heads; each K/V head serves a group of query heads.
@@ -85,6 +119,13 @@ class TransformerConfig:
     # LM loss scaled by ``moe_aux_coef``.
     moe_every: int = 0
     moe_experts: int = 8
+    # The layer pattern, one period of it (see LayerSpec).  Empty = what
+    # the flags above describe: every layer full attention with the dense
+    # MLP, each ``moe_every``-th one a ``moe`` layer.  GPT-2 is the
+    # one-entry pattern under pos_emb="learned"; a model that alternates
+    # window and full layers, or routes every layer, writes its period
+    # here.  ``scan_layers`` scans over whole periods.
+    pattern: tuple = ()
     # experts per token: 1 = Switch (default), 2 = Mixtral-style top-2
     moe_top_k: int = 1
     # Scan over layers: store block weights stacked with a leading [L]
@@ -106,7 +147,8 @@ class TransformerConfig:
     bias: bool = False            # biases on attn/mlp projections
     norm_eps: float = 1e-6
     # gelu: w2(gelu(w1 x)); swiglu: w2(silu(w1 x) * (w3 x)) — the
-    # LLaMA-family gated MLP (w1 = gate_proj, w3 = up_proj)
+    # LLaMA-family gated MLP (w1 = gate_proj, w3 = up_proj); reglu: the
+    # same gate with relu.  An ``experts`` layer's experts take this form
     mlp_act: str = "gelu"
 
     def __post_init__(self):
@@ -116,16 +158,47 @@ class TransformerConfig:
         if self.norm not in ("rms", "layernorm"):
             raise ValueError(
                 f"norm must be 'rms' or 'layernorm', got {self.norm!r}")
-        if self.mlp_act not in ("gelu", "swiglu"):
-            raise ValueError(
-                f"mlp_act must be 'gelu' or 'swiglu', got {self.mlp_act!r}")
+        if self.mlp_act not in ("gelu", "swiglu", "reglu"):
+            raise ValueError(f"mlp_act must be 'gelu', 'swiglu' or "
+                             f"'reglu', got {self.mlp_act!r}")
         if self.remat_policy not in ("full", "dots"):
             raise ValueError(f"remat_policy must be 'full' or 'dots', "
                              f"got {self.remat_policy!r}")
+        if not self.head_dim:
+            if self.d_model % self.n_heads:
+                raise ValueError("d_model must divide by n_heads (or give "
+                                 "head_dim)")
+            object.__setattr__(self, "head_dim",
+                               self.d_model // self.n_heads)
+        object.__setattr__(self, "pattern", tuple(self.pattern))
+        if any(not isinstance(spec, LayerSpec) for spec in self.pattern):
+            raise ValueError("pattern holds LayerSpec entries")
+        if self.pattern and self.moe_every:
+            raise ValueError("give the layers as a pattern or by "
+                             "moe_every, not both")
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def attn_dim(self) -> int:
+        """Width of the attention's inner side: n_heads * head_dim."""
+        return self.n_heads * self.head_dim
+
+    @property
+    def gated_mlp(self) -> bool:
+        return self.mlp_act in ("swiglu", "reglu")
+
+    @property
+    def period(self) -> tuple:
+        """One period of the layer pattern, given or from the flags."""
+        if self.pattern:
+            return self.pattern
+        if self.moe_every > 0:
+            return ((LayerSpec(),) * (self.moe_every - 1)
+                    + (LayerSpec(ffn="moe"),))
+        return (LayerSpec(),)
+
+    def layer_spec(self, i: int) -> LayerSpec:
+        period = self.period
+        return period[i % len(period)]
 
     @property
     def kv_heads(self) -> int:
@@ -137,7 +210,7 @@ class TransformerConfig:
         return self.n_heads // self.kv_heads
 
     def is_moe_layer(self, i: int) -> bool:
-        return self.moe_every > 0 and (i + 1) % self.moe_every == 0
+        return self.layer_spec(i).ffn == "moe"
 
 
 def scoped(name: str):
@@ -374,10 +447,12 @@ def prepare_gqa_kv(q: Array, k: Array, v: Array,
     return k, v
 
 
-def causal_attention(q: Array, k: Array, v: Array) -> Array:
+def causal_attention(q: Array, k: Array, v: Array,
+                     window: int = 0) -> Array:
     """Reference einsum attention.  q: [B, S, H, D], k/v: [B, S, H, D] or
     the GQA [B, S, KV, D] (expanded here) -> [B, S, H, D].  float32
-    logits/softmax for stability."""
+    logits/softmax for stability.  ``window`` W > 0 also hides keys W or
+    more positions back (query i sees key j where 0 <= i - j < W)."""
     k, v = expand_gqa(q, k, v)
     head_dim = q.shape[-1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
@@ -385,6 +460,8 @@ def causal_attention(q: Array, k: Array, v: Array) -> Array:
     scores = scores / math.sqrt(head_dim)
     s_q, s_k = q.shape[1], k.shape[1]
     mask = jnp.tril(jnp.ones((s_q, s_k), jnp.bool_))
+    if 0 < window < s_k:
+        mask = mask & ~jnp.tril(jnp.ones((s_q, s_k), jnp.bool_), -window)
     scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v,
@@ -398,18 +475,23 @@ class Transformer:
     def __init__(self, config: TransformerConfig,
                  attention_fn: Callable | None = None,
                  mesh: Mesh | None = None):
-        if config.d_model % config.n_heads:
-            raise ValueError("d_model must divide by n_heads")
         if config.n_heads % config.kv_heads:
             raise ValueError(
                 f"n_heads={config.n_heads} must divide by "
                 f"n_kv_heads={config.kv_heads}")
-        if config.scan_layers and config.moe_every > 0:
+        period = config.period
+        if config.scan_layers and any(s.ffn == "moe" for s in period):
             raise ValueError(
-                "scan_layers needs homogeneous layers; MoE interleaving "
-                "(moe_every > 0) makes the scan body layer-dependent")
+                "scan_layers needs homogeneous periods whose layers carry "
+                "no aux loss; the capacity-dropping moe layer "
+                "(moe_every > 0) does not scan, an experts layer does")
+        if config.scan_layers and config.n_layers % len(period):
+            raise ValueError(
+                f"scan_layers scans whole periods: n_layers="
+                f"{config.n_layers} must divide by the pattern's "
+                f"{len(period)}")
         self.config = config
-        if config.moe_every > 0:
+        if any(s.ffn == "moe" for s in period):
             from .moe import MoEConfig, MoELayer
             self._moe = MoELayer(MoEConfig(
                 d_model=config.d_model, d_ff=config.d_ff,
@@ -434,45 +516,68 @@ class Transformer:
         shapes: dict[str, tuple[int, ...]] = {"embed/tok": (c.vocab, c.d_model)}
         if c.pos_emb == "learned":
             shapes["embed/pos"] = (c.max_seq, c.d_model)
-        kv_dim = c.kv_heads * c.head_dim
-        block = {"ln1/scale": (c.d_model,),
-                 "attn/wq": (c.d_model, c.d_model),
-                 "attn/wk": (c.d_model, kv_dim),
-                 "attn/wv": (c.d_model, kv_dim),
-                 "attn/wo": (c.d_model, c.d_model),
-                 "ln2/scale": (c.d_model,)}
-        if c.norm == "layernorm":
-            block["ln1/bias"] = (c.d_model,)
-            block["ln2/bias"] = (c.d_model,)
-        if c.bias:
-            block.update({"attn/bq": (c.d_model,), "attn/bk": (kv_dim,),
-                          "attn/bv": (kv_dim,), "attn/bo": (c.d_model,)})
-        mlp = {"mlp/w1": (c.d_model, c.d_ff), "mlp/w2": (c.d_ff, c.d_model)}
-        if c.mlp_act == "swiglu":
-            mlp["mlp/w3"] = (c.d_model, c.d_ff)   # up_proj of the gate pair
-        if c.bias:
-            mlp.update({"mlp/b1": (c.d_ff,), "mlp/b2": (c.d_model,)})
         if c.scan_layers:
-            # stacked layout: one [L, ...] array per block weight, scanned
-            for suffix, shape in {**block, **mlp}.items():
-                shapes[f"blocks/{suffix}"] = (c.n_layers, *shape)
+            # stacked layout: one array per block weight with a leading
+            # axis over the layers that HAVE it, in layer order ([L, ...]
+            # where every layer does), scanned a period at a time
+            for suffix in self._stacked_suffixes():
+                holders = self._holders(suffix, c.n_layers)
+                shape = self.block_shapes(c.layer_spec(holders[0]))[suffix]
+                shapes[f"blocks/{suffix}"] = (len(holders), *shape)
         else:
             for i in range(c.n_layers):
-                p = f"layer{i}"
-                for suffix, shape in block.items():
-                    shapes[f"{p}/{suffix}"] = shape
-                if c.is_moe_layer(i):
-                    shapes[f"{p}/moe/router/w"] = (c.d_model, c.moe_experts)
-                    shapes[f"{p}/moe/w1"] = (c.moe_experts, c.d_model, c.d_ff)
-                    shapes[f"{p}/moe/w2"] = (c.moe_experts, c.d_ff, c.d_model)
-                else:
-                    for suffix, shape in mlp.items():
-                        shapes[f"{p}/{suffix}"] = shape
+                for suffix, shape in self.block_shapes(
+                        c.layer_spec(i)).items():
+                    shapes[f"layer{i}/{suffix}"] = shape
         shapes["final_ln/scale"] = (c.d_model,)
         if c.norm == "layernorm":
             shapes["final_ln/bias"] = (c.d_model,)
         shapes["lm_head/w"] = (c.d_model, c.vocab)
         return shapes
+
+    def block_shapes(self, spec: LayerSpec) -> dict[str, tuple[int, ...]]:
+        """The weights of one layer of kind ``spec``, by suffix."""
+        c = self.config
+        kv_dim = c.kv_heads * c.head_dim
+        block = {"ln1/scale": (c.d_model,),
+                 "attn/wq": (c.d_model, c.attn_dim),
+                 "attn/wk": (c.d_model, kv_dim),
+                 "attn/wv": (c.d_model, kv_dim),
+                 "attn/wo": (c.attn_dim, c.d_model),
+                 "ln2/scale": (c.d_model,)}
+        if c.norm == "layernorm":
+            block["ln1/bias"] = (c.d_model,)
+            block["ln2/bias"] = (c.d_model,)
+        if c.bias:
+            block.update({"attn/bq": (c.attn_dim,), "attn/bk": (kv_dim,),
+                          "attn/bv": (kv_dim,), "attn/bo": (c.d_model,)})
+        if spec.ffn == "mlp":
+            block.update({"mlp/w1": (c.d_model, c.d_ff),
+                          "mlp/w2": (c.d_ff, c.d_model)})
+            if c.gated_mlp:
+                block["mlp/w3"] = (c.d_model, c.d_ff)   # up_proj
+            if c.bias:
+                block.update({"mlp/b1": (c.d_ff,), "mlp/b2": (c.d_model,)})
+            return block
+        block.update({"moe/router/w": (c.d_model, c.moe_experts),
+                      "moe/w1": (c.moe_experts, c.d_model, c.d_ff),
+                      "moe/w2": (c.moe_experts, c.d_ff, c.d_model)})
+        if spec.ffn == "experts" and c.gated_mlp:
+            block["moe/w3"] = (c.moe_experts, c.d_model, c.d_ff)
+        return block
+
+    def _stacked_suffixes(self) -> list[str]:
+        seen: dict[str, None] = {}
+        for spec in self.config.period:
+            seen.update(dict.fromkeys(self.block_shapes(spec)))
+        return list(seen)
+
+    def _holders(self, suffix: str, layers: int) -> list[int]:
+        """Which of the first ``layers`` layers have the weight
+        ``suffix``: a stack's leading axis runs over them, in order."""
+        c = self.config
+        return [i for i in range(layers)
+                if suffix in self.block_shapes(c.layer_spec(i))]
 
     def num_params(self) -> int:
         return sum(math.prod(s) for s in self.param_shapes().values())
@@ -500,10 +605,12 @@ class Transformer:
         c = self.config
         seq = c.max_seq
         n_params = self.num_params()
-        if c.moe_every > 0:
-            n_moe = sum(1 for i in range(c.n_layers) if c.is_moe_layer(i))
-            inactive = max(0, c.moe_experts - c.moe_top_k)
-            n_params -= n_moe * inactive * 2 * c.d_model * c.d_ff
+        inactive = max(0, c.moe_experts - c.moe_top_k)
+        for i in range(c.n_layers):
+            shapes = self.block_shapes(c.layer_spec(i))
+            n_params -= inactive * sum(
+                math.prod(shape[1:]) for suffix, shape in shapes.items()
+                if suffix in ("moe/w1", "moe/w2", "moe/w3"))
         params_mult, attn_mult = 6.0, 12.0
         if remat_credited:
             attn_mult = 16.0
@@ -577,9 +684,11 @@ class Transformer:
 
     @scoped("attn_qkv")
     def qkv(self, params: Mapping[str, Array], prefix: str, h: Array,
-            positions: Array) -> tuple[Array, Array, Array]:
+            positions: Array, spec: LayerSpec | None = None,
+            ) -> tuple[Array, Array, Array]:
         """ln1 -> q/k/v projections (+ biases) -> head split -> rope (or
-        pass-through under learned positions).  h: [B, S, d].
+        pass-through under learned positions and on a layer whose
+        ``spec`` has no rotary).  h: [B, S, d].
         K/V come back with ``kv_heads`` heads (UNexpanded under GQA — the
         cache-friendly form); expand to the query head count with
         :func:`repeat_kv` before a plain attention kernel."""
@@ -598,9 +707,10 @@ class Transformer:
         q = q.astype(c.dtype).reshape(batch, seq, c.n_heads, c.head_dim)
         k = k.astype(c.dtype).reshape(batch, seq, c.kv_heads, c.head_dim)
         v = v.astype(c.dtype).reshape(batch, seq, c.kv_heads, c.head_dim)
-        if c.pos_emb == "learned":
+        if c.pos_emb == "learned" or (spec is not None and not spec.rope):
             # learned positions live in the residual stream (embed/pos,
-            # added at embedding time) — K/V need no positional transform
+            # added at embedding time) — K/V need no positional transform;
+            # a layer without rotary has no position signal at all
             return q, k, v
         return (rope(q, positions, c.rope_theta),
                 rope(k, positions, c.rope_theta), v)
@@ -611,7 +721,7 @@ class Transformer:
         """h + wo(attn) (+ bias).  attn: [B, S, H, D]."""
         c = self.config
         batch, seq = h.shape[:2]
-        out = wdot(attn.reshape(batch, seq, c.d_model),
+        out = wdot(attn.reshape(batch, seq, c.attn_dim),
                    params[f"{prefix}/attn/wo"],
                    preferred_element_type=jnp.float32)
         if c.bias:
@@ -621,17 +731,19 @@ class Transformer:
     @scoped("mlp")
     def mlp_residual(self, params: Mapping[str, Array], prefix: str,
                      h: Array) -> Array:
-        """h + w2(gelu(w1(ln2(h)))) (+ biases), or the SwiGLU gated form
-        h + w2(silu(w1 x) * (w3 x)) under ``mlp_act="swiglu"``."""
+        """h + w2(gelu(w1(ln2(h)))) (+ biases), or the gated form
+        h + w2(act(w1 x) * (w3 x)) under ``mlp_act="swiglu"`` (silu) and
+        ``"reglu"`` (relu)."""
         c = self.config
         dot = partial(wdot, preferred_element_type=jnp.float32)
         x = self._norm(params, f"{prefix}/ln2", h)
         ff = dot(x, params[f"{prefix}/mlp/w1"])
         if c.bias:
             ff = ff + params[f"{prefix}/mlp/b1"].astype(jnp.float32)
-        if c.mlp_act == "swiglu":
+        if c.gated_mlp:
             up = dot(x, params[f"{prefix}/mlp/w3"]).astype(c.dtype)
-            ff = jax.nn.silu(ff.astype(c.dtype)) * up
+            gate = jax.nn.silu if c.mlp_act == "swiglu" else jax.nn.relu
+            ff = gate(ff.astype(c.dtype)) * up
         else:
             ff = jax.nn.gelu(ff.astype(c.dtype))
         out = dot(ff, params[f"{prefix}/mlp/w2"])
@@ -646,28 +758,108 @@ class Transformer:
         ``blk/*`` view of the stacked ``blocks/*`` arrays under
         ``scan_layers`` — so per-layer consumers (generation's decode
         loop) work on both layouts."""
-        if self.config.scan_layers:
-            return ({f"blk/{name[len('blocks/'):]}": value[layer]
-                     for name, value in params.items()
-                     if name.startswith("blocks/")}, "blk")
-        return params, f"layer{layer}"
+        c = self.config
+        if not c.scan_layers:
+            return params, f"layer{layer}"
+        mine = self.block_shapes(c.layer_spec(layer))
+        # a stack's leading axis runs over the layers that hold the suffix
+        return ({f"blk/{name[len('blocks/'):]}": value[
+                    len(self._holders(name[len("blocks/"):], layer))]
+                 for name, value in params.items()
+                 if name.startswith("blocks/")
+                 and name[len("blocks/"):] in mine}, "blk")
 
-    def ffn_residual(self, params: Mapping[str, Array], layer: int,
-                     h: Array, decode: bool = False) -> tuple[Array, Array]:
-        """The layer's FFN branch: dense MLP or Switch MoE per the config.
-        Returns (new_h, aux_loss) — aux is 0 for dense layers.  ``decode``
-        runs MoE drop-free (capacity = token count): capacity dropping is a
+    def router_logits(self, params: Mapping[str, Array], prefix: str,
+                      x: Array) -> Array:
+        """An ``experts`` layer's router on its normed input x [B, S, d]:
+        float32 logits [B, S, E]."""
+        with jax.named_scope("moe"), jax.named_scope("router"):
+            return jnp.einsum(
+                "bsd,de->bse", x.astype(jnp.float32),
+                params[f"{prefix}/moe/router/w"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+
+    def pre_attention_router(self, params: Mapping[str, Array], prefix: str,
+                             spec: LayerSpec, h: Array) -> Array | None:
+        """The logits of an ``experts`` layer's router, which stands
+        BEFORE attention (it reads the attention's normed input; the same
+        norm ``qkv`` computes, which the compiler shares); None for every
+        other layer."""
+        if spec.ffn != "experts":
+            return None
+        return self.router_logits(params, prefix,
+                                  self._norm(params, f"{prefix}/ln1", h))
+
+    def ffn_residual(self, params: Mapping[str, Array], prefix: str,
+                     spec: LayerSpec, h: Array, decode: bool = False,
+                     router_logits: Array | None = None,
+                     route_stats: list | None = None,
+                     ) -> tuple[Array, Array]:
+        """The layer's FFN branch by its kind: the dense MLP, the
+        capacity-dropping ``moe`` layer or dropless ``experts``.
+        Returns (new_h, aux_loss) — aux is 0 but for ``moe``.  ``params``
+        and ``prefix`` are a :meth:`layer_view`.  ``decode`` runs ``moe``
+        drop-free (capacity = token count): capacity dropping is a
         batch-global training mechanism and cannot be reproduced causally
-        during KV-cached decoding."""
-        if not self.config.is_moe_layer(layer):
-            lp, p = self.layer_view(params, layer)
-            return self.mlp_residual(lp, p, h), jnp.zeros((), jnp.float32)
-        p = f"layer{layer}"
-        x = self._norm(params, f"{p}/ln2", h)
-        cap = h.shape[0] * h.shape[1] if decode else None
-        moe_out, aux = self._moe.apply(params, x, prefix=f"{p}/",
-                                       capacity_override=cap)
-        return h + moe_out.astype(self.config.dtype), aux
+        during KV-cached decoding; ``experts`` never drops, so prefill,
+        extension and decode run one path.  ``router_logits`` are those of
+        :meth:`pre_attention_router`, which an ``experts`` layer needs;
+        ``route_stats``, where given, gains this layer's tokens per
+        expert ([E] int32) for the caller's counters."""
+        zero = jnp.zeros((), jnp.float32)
+        if spec.ffn == "mlp":
+            return self.mlp_residual(params, prefix, h), zero
+        x = self._norm(params, f"{prefix}/ln2", h)
+        if spec.ffn == "moe":
+            cap = h.shape[0] * h.shape[1] if decode else None
+            moe_out, aux = self._moe.apply(params, x, prefix=f"{prefix}/",
+                                           capacity_override=cap)
+            return h + moe_out.astype(self.config.dtype), aux
+        from .moe import dropless_experts
+
+        c = self.config
+        batch, seq = h.shape[:2]
+        with jax.named_scope("moe"):
+            out, loads = dropless_experts(
+                x.reshape(batch * seq, c.d_model),
+                router_logits.reshape(batch * seq, c.moe_experts),
+                params[f"{prefix}/moe/w1"], params[f"{prefix}/moe/w2"],
+                params.get(f"{prefix}/moe/w3"), top_k=c.moe_top_k,
+                act=c.mlp_act)
+        if route_stats is not None:
+            route_stats.append(loads)
+        return h + out.reshape(batch, seq, c.d_model).astype(c.dtype), zero
+
+    # sequences from this length on run blockwise attention (scores of a
+    # block at a time, blocks wholly outside the mask skipped) on the
+    # default path; shorter ones the dense einsum the cells have always run
+    BLOCKWISE_FROM = 2048
+
+    def attend(self, q: Array, k: Array, v: Array,
+               spec: LayerSpec) -> Array:
+        """Causal attention of a whole sequence for a layer of kind
+        ``spec``, under ``attn/full`` or ``attn/window``.  A caller's
+        ``attention_fn`` runs as given (it knows no window, so a window
+        that binds is refused); the default chooses by what it sees: the
+        dense einsum for a short sequence, blockwise for a long one."""
+        seq = q.shape[1]
+        window = spec.window if 0 < spec.window < seq else 0
+        with jax.named_scope("attn"), jax.named_scope(
+                "window" if spec.window else "full"):
+            if self.attention_fn is not causal_attention:
+                if window:
+                    raise ValueError(
+                        f"a window of {spec.window} binds at sequence "
+                        f"length {seq}: window layers run the default "
+                        "attention (dense or blockwise), not a caller's "
+                        "attention_fn")
+                return self.attention_fn(q, k, v)
+            if seq >= self.BLOCKWISE_FROM and self.mesh is None:
+                from ..ops.xla_flash import blockwise_attention
+
+                starts = jnp.zeros((q.shape[0],), jnp.int32)
+                return blockwise_attention(q, k, v, starts, window=window)
+            return causal_attention(q, k, v, window=window)
 
     @scoped("head")
     def final_logits(self, params: Mapping[str, Array], h: Array) -> Array:
@@ -696,7 +888,8 @@ class Transformer:
         return h
 
     def _forward(self, params: Mapping[str, Array], tokens: Array,
-                 collect_kv: bool) -> tuple[Array, list, Array]:
+                 collect_kv: bool, route_stats: list | None = None,
+                 ) -> tuple[Array, list, Array]:
         c = self.config
         batch, seq = tokens.shape
         if c.pos_emb == "learned" and seq > c.max_seq:
@@ -713,36 +906,61 @@ class Transformer:
         kvs: list = []
         aux_total = jnp.zeros((), jnp.float32)
 
-        def layer_body(layer_params, i, h, p=None):
-            p = f"layer{i}" if p is None else p
-            q, k, v = self.qkv(layer_params, p, h, positions)
+        def layer_body(layer_params, p, spec, h):
+            router = self.pre_attention_router(layer_params, p, spec, h)
+            q, k, v = self.qkv(layer_params, p, h, positions, spec)
             # K/V go to the attention fn UNexpanded (kv_heads-sized);
             # each implementation expands at the math (expand_gqa), so
             # ring/Ulysses communicate the small tensors
-            with jax.named_scope("attn"):
-                attn = self.attention_fn(q, k, v)
+            attn = self.attend(q, k, v, spec)
             h = self.attn_residual(layer_params, p, h, attn)
             h = self._constrain(h, ("data", "fsdp"), "seq", None)
-            if i is None:  # scan body: homogeneous dense layers
-                h = self.mlp_residual(layer_params, p, h)
-                aux = jnp.zeros((), jnp.float32)
-            else:
-                h, aux = self.ffn_residual(layer_params, i, h)
+            h, aux = self.ffn_residual(layer_params, p, spec, h,
+                                       router_logits=router,
+                                       route_stats=route_stats)
             h = self._constrain(h, ("data", "fsdp"), "seq", None)
             return h, aux, (k, v)
 
         if c.scan_layers:
-            # one scan body traced once, block weights streamed from their
-            # stacked [L, ...] arrays — compile cost is depth-independent
-            blocks = {name[len("blocks/"):]: value
-                      for name, value in params.items()
-                      if name.startswith("blocks/")}
+            # one scan body traced once, holding one PERIOD of the layer
+            # pattern; block weights stream from their stacked arrays —
+            # compile cost is depth-independent.  A stack's leading axis
+            # runs over the layers that hold the suffix, so per period it
+            # folds to [periods, holders in a period, ...]
+            period = c.period
+            periods = c.n_layers // len(period)
+            holders = {name[len("blocks/"):]: self._holders(
+                name[len("blocks/"):], len(period))
+                for name in params if name.startswith("blocks/")}
+
+            def fold(x, held):
+                # (one holder a period: the stack is already [periods, ...])
+                return x if len(held) == 1 else x.reshape(
+                    periods, len(held), *x.shape[1:])
+
+            # (tree.map: an int8 QTensor stack folds leaf by leaf)
+            blocks = {suffix: jax.tree.map(lambda x, held=held: fold(x, held),
+                                           params[f"blocks/{suffix}"])
+                      for suffix, held in holders.items()}
 
             def scan_body(h, blk):
-                view = {f"blk/{suffix}": value
-                        for suffix, value in blk.items()}
-                h, aux, kv = layer_body(view, None, h, p="blk")
-                return h, (kv if collect_kv else aux)
+                kvs_p, aux = [], jnp.zeros((), jnp.float32)
+                for j, spec in enumerate(period):
+                    view = {f"blk/{suffix}": value
+                            if len(holders[suffix]) == 1 else jax.tree.map(
+                                lambda x, at=holders[suffix].index(j): x[at],
+                                value)
+                            for suffix, value in blk.items()
+                            if j in holders[suffix]}
+                    h, layer_aux, kv = layer_body(view, "blk", spec, h)
+                    aux = layer_aux if len(period) == 1 else aux + layer_aux
+                    kvs_p.append(kv)
+                if not collect_kv:
+                    return h, aux
+                if len(period) == 1:
+                    return h, kvs_p[0]
+                return h, (jnp.stack([k for k, _ in kvs_p]),
+                           jnp.stack([v for _, v in kvs_p]))
 
             if c.remat and not collect_kv:
                 # scan's internals already rule out the CSE hazard that
@@ -750,9 +968,12 @@ class Transformer:
                 # the default would insert optimization barriers per step
                 scan_body = jax.checkpoint(scan_body, prevent_cse=False,
                                            policy=self._remat_policy())
+            route_stats = None   # a scan body's values cannot leave it
             h, ys = jax.lax.scan(scan_body, h, blocks)
             if collect_kv:
-                k_stack, v_stack = ys  # [L, B, S, H, D] each
+                # [periods, (P,) B, S, H, D] -> per layer
+                k_stack, v_stack = (
+                    y.reshape(c.n_layers, *y.shape[-4:]) for y in ys)
                 kvs = [(k_stack[i], v_stack[i]) for i in range(c.n_layers)]
             else:
                 aux_total = jnp.sum(ys)
@@ -763,7 +984,8 @@ class Transformer:
         # exists to SAVE per-layer tensors (generation prefill)
         if c.remat and not collect_kv:
             body = jax.checkpoint(
-                lambda lp, i, h: layer_body(lp, i, h)[:2],
+                lambda lp, i, h: layer_body(lp, f"layer{i}",
+                                            c.layer_spec(i), h)[:2],
                 static_argnums=(1,), policy=self._remat_policy())
         else:
             body = None
@@ -771,7 +993,8 @@ class Transformer:
             if body is not None:
                 h, aux = body(params, i, h)
             else:
-                h, aux, kv = layer_body(params, i, h)
+                h, aux, kv = layer_body(params, f"layer{i}",
+                                        c.layer_spec(i), h)
                 if collect_kv:
                     kvs.append(kv)
             aux_total = aux_total + aux

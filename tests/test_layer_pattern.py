@@ -1,0 +1,392 @@
+"""A model written as a layer PATTERN (window and full attention mixed,
+rotary on some layers and no position signal on others, dropless experts
+routed before attention) against the benchmark's plain reference
+(``perfbench/reference/smallthinker.py``), on seeded random weights at a
+small size: the full forward, prefill + decode through the cache past the
+point where a window layer's ring wraps, the same through ``DecodeServer``
+with a prefix longer than the window reused from the prefix tree, dropless
+routing under total imbalance, the two kinds of layer told apart, and GPT-2
+through the same pattern and cache.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from parameter_server_distributed_tpu.models import generation, moe
+from parameter_server_distributed_tpu.models import transformer as tr
+from parameter_server_distributed_tpu.models.serving import (DecodeServer,
+                                                             _bucket)
+from parameter_server_distributed_tpu.ops.xla_flash import blockwise_attention
+from perfbench.families import smallthinker as family
+from perfbench.reference import smallthinker as reference
+
+# 8 layers = two periods of (full without positions, window, window,
+# window); width 64, 4 heads of 32 over 2 K/V heads (4 x 32 = 128 != 64),
+# 8 experts top-3 of width 48, window 8, vocabulary 512
+CONFIG = {
+    "hidden_size": 64, "head_dim": 32, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "num_hidden_layers": 8,
+    "moe_ffn_hidden_size": 48, "moe_num_primary_experts": 8,
+    "moe_num_active_primary_experts": 3,
+    "moe_primary_router_apply_softmax": True, "norm_topk_prob": True,
+    "rope_layout": [0, 1, 1, 1] * 2, "sliding_window_layout": [0, 1, 1, 1] * 2,
+    "sliding_window_size": 8, "rope_theta": 1500000, "rope_scaling": None,
+    "rms_norm_eps": 1e-6, "tie_word_embeddings": False, "vocab_size": 512,
+    "max_position_embeddings": 128,
+    "assumed": {"dtype": "float32", "scan_layers": False, "remat": False,
+                "remat_policy": "full", "loss_chunk": 32}}
+TOLERANCE = 2e-5    # float32 against float32: rounding order only
+
+
+@pytest.fixture(scope="module")
+def built():
+    model = family.model(CONFIG)
+    params = family.make_weights(model, 11)
+    return model, params, family.reference_weights(CONFIG, params)
+
+
+def _reference_logits(weights, tokens):
+    return np.asarray(family.reference_forward(CONFIG, weights,
+                                               jnp.asarray(tokens)))
+
+
+def _tokens(seed, batch, seq):
+    return np.random.default_rng(seed).integers(
+        0, CONFIG["vocab_size"], (batch, seq)).astype(np.int32)
+
+
+def test_the_pattern_is_one_period_and_the_head_size_is_its_own(built):
+    model, params, _ = built
+    c = model.config
+    assert len(c.period) == 4 and c.n_layers == 8
+    assert [s.window for s in c.period] == [0, 8, 8, 8]
+    assert [s.rope for s in c.period] == [False, True, True, True]
+    assert all(s.ffn == "experts" for s in c.period)
+    assert c.head_dim == 32 and c.attn_dim == 128 != c.d_model
+    assert params["layer0/attn/wq"].shape == (64, 128)
+    assert params["layer0/attn/wo"].shape == (128, 64)
+    assert params["layer3/moe/w3"].shape == (8, 64, 48)
+
+
+@pytest.mark.parametrize("blockwise_from", [2048, 16],
+                         ids=["dense", "blockwise"])
+def test_full_forward_agrees_with_the_reference(built, blockwise_from):
+    model, params, weights = built
+    model = tr.Transformer(model.config)
+    model.BLOCKWISE_FROM = blockwise_from
+    tokens = _tokens(0, 2, 40)
+    got = np.asarray(jax.jit(model.apply)(params, tokens))
+    np.testing.assert_allclose(got, _reference_logits(weights, tokens),
+                               atol=TOLERANCE)
+
+
+def test_scanning_whole_periods_agrees_with_the_unrolled_layers(built):
+    model, params, weights = built
+    scanned = family.model(CONFIG, scan_layers=True)
+    shapes = scanned.param_shapes()
+    assert shapes["blocks/moe/w1"] == (8, 8, 64, 48)
+    stacked = tr.stack_layers(params, 8)
+    tokens = _tokens(1, 2, 24)
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(scanned.apply)(stacked, tokens)),
+        _reference_logits(weights, tokens), atol=TOLERANCE)
+    # and the cached decode reads a layer out of the stacks
+    logits, cache = generation.prefill(scanned, stacked, tokens[:, :20], 32)
+    step, _ = generation.decode_step(scanned, stacked, tokens[:, 20], cache)
+    np.testing.assert_allclose(
+        np.asarray(step), _reference_logits(weights, tokens[:, :21])[:, 20],
+        atol=TOLERANCE)
+
+
+def test_scan_needs_whole_periods():
+    config = family.transformer_config(CONFIG, n_layers=6, scan_layers=True)
+    with pytest.raises(ValueError, match="whole periods"):
+        tr.Transformer(config)
+
+
+def test_decode_through_the_cache_past_a_wrapped_ring(built):
+    model, params, weights = built
+    tokens = _tokens(2, 2, 40)
+    want = _reference_logits(weights, tokens)
+    logits, cache = jax.jit(
+        lambda p, t: generation.prefill(model, p, t, 64))(params,
+                                                         tokens[:, :12])
+    # a cache per kind: 2 full layers hold 64 positions, 6 window layers 8
+    assert cache.k.shape == (2, 2, 64, 2, 32)
+    assert cache.wk.shape == (6, 2, 8, 2, 32)
+    assert cache.ring_layers == (1, 2, 3, 5, 6, 7)
+    np.testing.assert_allclose(np.asarray(logits), want[:, 11],
+                               atol=TOLERANCE)
+    step = jax.jit(lambda p, t, c: generation.decode_step(model, p, t, c))
+    for at in range(12, 40):      # the rings wrap at 16, 24, 32
+        logits, cache = step(params, tokens[:, at], cache)
+        np.testing.assert_allclose(np.asarray(logits), want[:, at],
+                                   atol=TOLERANCE)
+
+
+def test_a_block_against_a_ring_writes_only_its_real_positions(built):
+    """Ragged rows, blocks of 3 with pad positions: what a pad position
+    would overwrite in a ring is still needed, so it is not written."""
+    model, params, weights = built
+    tokens = _tokens(3, 2, 30)
+    want = _reference_logits(weights, tokens)
+    _, cache = generation.prefill(model, params, tokens[:, :10], 64)
+    lengths = jnp.asarray([10, 10], jnp.int32)
+    block = jax.jit(lambda p, t, c, n, k: generation.decode_block(
+        model, p, t, c, lengths=n, counts=k))
+    at = np.array([10, 10])
+    for counts in ([3, 1], [2, 3], [3, 3], [1, 2], [3, 3], [3, 3]):
+        rows = np.stack([np.pad(tokens[b, at[b]:at[b] + n], (0, 3 - n))
+                         for b, n in enumerate(counts)])
+        logits, cache = block(params, rows, cache, jnp.asarray(at, jnp.int32),
+                              jnp.asarray(counts, jnp.int32))
+        for b, n in enumerate(counts):
+            np.testing.assert_allclose(
+                np.asarray(logits)[b, :n], want[b, at[b]:at[b] + n],
+                atol=TOLERANCE)
+        at = at + np.asarray(counts)
+
+
+def test_a_block_longer_than_the_ring_is_refused(built):
+    model, params, _ = built
+    cache = generation.init_cache(model, 1, 64)
+    with pytest.raises(ValueError, match="does not go through a ring"):
+        generation.decode_block(model, params, jnp.zeros((1, 9), jnp.int32),
+                                cache)
+
+
+@pytest.mark.parametrize("blockwise_queries", [128, 4],
+                         ids=["dense", "blockwise"])
+def test_the_server_reuses_a_prefix_longer_than_the_window(
+        built, blockwise_queries, monkeypatch):
+    """A shared prefix of 37 tokens (window 8) is prefilled once; a user
+    turn then extends it from the prefix tree (the window taken as a mask
+    against the row stored by position), the slot takes the last ring's
+    worth, and the decode rounds wrap the rings again: every token is the
+    reference's argmax over the whole sequence."""
+    model, params, weights = built
+    model = tr.Transformer(model.config)
+    model.BLOCKWISE_FROM = 32
+    monkeypatch.setattr(generation, "_BLOCKWISE_QUERIES", blockwise_queries)
+    rng = np.random.default_rng(4)
+    prefix = rng.integers(0, 512, 37).astype(np.int32)
+    server = DecodeServer(model, params, slots=3, max_len=96,
+                          prompt_cache=8, prefix_cache_bytes=1 << 24)
+    server.submit(prefix, max_new_tokens=1)
+    server.run_to_completion()
+    turns = [rng.integers(0, 512, n).astype(np.int32) for n in (5, 9, 17)]
+    ids = [server.submit(np.concatenate([prefix, turn]), max_new_tokens=14)
+           for turn in turns]
+    served = server.run_to_completion()
+    stats = server.stats
+    assert stats["prefix_hits"] == 3
+    assert stats["prefill_tokens"] == 37 + 5 + 9 + 17
+    # bytes by kind: 2 full layers x 96 positions, 6 window layers x 8
+    position = 2 * 2 * 32 * 4
+    assert stats["cache_full_bytes"] == 3 * 2 * 96 * position
+    assert stats["cache_window_bytes"] == 3 * 6 * 8 * position
+    assert stats["moe_assignments"] > 0
+    for rid, turn in zip(ids, turns):
+        sequence = np.concatenate([prefix, turn, served[rid]])[None]
+        logits = _reference_logits(weights, sequence)[0]
+        rows = logits[37 + len(turn) - 1:-1]
+        np.testing.assert_array_equal(np.argmax(rows, axis=-1), served[rid])
+
+
+def test_speculative_serving_refuses_a_model_with_rings(built):
+    model, params, _ = built
+    with pytest.raises(ValueError, match="cannot be rolled back"):
+        DecodeServer(model, params, slots=2, max_len=64, draft=model,
+                     draft_params=params)
+
+
+def test_dropless_under_total_imbalance(built, monkeypatch):
+    """A router rigged so that every token picks the same 3 experts (the
+    same logits whatever the input, in the program and in the reference):
+    the capacity path would drop most of them, the dropless path none."""
+    model, params, weights = built
+    tokens = _tokens(5, 2, 24)
+
+    def rigged(x):
+        logits = jnp.zeros(x.shape[:-1] + (8,), jnp.float32)
+        return logits.at[..., jnp.asarray([1, 4, 6])].set(
+            jnp.asarray([3.0, 2.0, 1.0]))
+
+    model = tr.Transformer(model.config)
+    model.router_logits = lambda params, prefix, x: rigged(x)
+    routed: list = []
+    h, _, _ = model._forward(params, tokens, collect_kv=False,
+                             route_stats=routed)
+    got = np.asarray(model.final_logits(params, h))
+    assert len(routed) == 8
+    for loads in routed:
+        assert np.asarray(loads).tolist() == [0, 48, 0, 0, 48, 0, 48, 0]
+    # the capacity layer, sized as in training, would keep this many of
+    # the 48 rows of each chosen expert
+    capacity = moe.MoELayer(moe.MoEConfig(num_experts=8, top_k=3)
+                            ).capacity(48 * 3)
+    assert capacity < 48
+    experts = reference._experts
+    monkeypatch.setattr(
+        reference, "_experts",
+        lambda h, router_logits, w, top_k: experts(h, rigged(h), w, top_k))
+    np.testing.assert_allclose(got, _reference_logits(weights, tokens),
+                               atol=TOLERANCE)
+
+
+def test_dropless_experts_builds_nothing_of_size_tokens_by_experts_by_capacity():
+    """The jaxpr of the routed layer holds no array with as many elements
+    as tokens x experts x capacity (capacity = tokens when dropless)."""
+    n, d, e, f, k = 96, 16, 8, 24, 2
+    rng = np.random.default_rng(6)
+    args = (jnp.asarray(rng.normal(size=(n, d)), jnp.float32),
+            jnp.asarray(rng.normal(size=(n, e)), jnp.float32),
+            jnp.asarray(rng.normal(size=(e, d, f)), jnp.float32),
+            jnp.asarray(rng.normal(size=(e, f, d)), jnp.float32),
+            jnp.asarray(rng.normal(size=(e, d, f)), jnp.float32))
+    jaxpr = jax.make_jaxpr(lambda *a: moe.dropless_experts(
+        *a, top_k=k, act="reglu"))(*args)
+    largest = max(int(np.prod(v.aval.shape)) for eqn in jaxpr.eqns
+                  for v in eqn.outvars)
+    assert largest < n * e * n
+    assert largest <= max(n * k * max(d, f), e * d * f)
+    out, loads = moe.dropless_experts(*args, top_k=k, act="reglu")
+    assert int(loads.sum()) == n * k
+    # against the plain sum over every token's chosen experts
+    x, logits, w1, w2, w3 = (np.asarray(a) for a in args)
+    want = np.zeros((n, d), np.float32)
+    for t in range(n):
+        chosen = np.argsort(-logits[t], kind="stable")[:k]
+        gates = np.exp(logits[t, chosen] - logits[t, chosen].max())
+        gates /= gates.sum()
+        for g, ex in zip(gates, chosen):
+            want[t] += g * (np.maximum(x[t] @ w1[ex], 0) * (x[t] @ w3[ex])
+                            ) @ w2[ex]
+    np.testing.assert_allclose(np.asarray(out), want, atol=1e-4, rtol=1e-4)
+
+
+def test_a_nope_layer_ignores_positions_and_a_rotary_layer_sees_distances(
+        built):
+    model, params, _ = built
+    c = model.config
+    h = jnp.asarray(np.random.default_rng(7).normal(size=(1, 12, 64)),
+                    jnp.float32)
+    at = jnp.arange(12, dtype=jnp.int32)[None]
+    nope, rotary = c.layer_spec(0), c.layer_spec(1)
+
+    def scores(spec, layer, positions):
+        q, k, _ = model.qkv(params, f"layer{layer}", h, positions, spec)
+        return np.asarray(jnp.einsum("bqhd,bkhd->bhqk", q,
+                                     tr.repeat_kv(k, c.kv_groups)))
+
+    # no position signal: q and k do not change with the positions at all
+    np.testing.assert_array_equal(scores(nope, 0, at),
+                                  scores(nope, 0, at + 1000))
+    # rotary: q and k change, their products depend on distances only
+    q0, _, _ = model.qkv(params, "layer1", h, at, rotary)
+    q1, _, _ = model.qkv(params, "layer1", h, at + 1000, rotary)
+    assert not np.allclose(np.asarray(q0), np.asarray(q1), atol=1e-3)
+    np.testing.assert_allclose(scores(rotary, 1, at),
+                               scores(rotary, 1, at + 1000), atol=2e-3)
+    # and a layer's kind, not its weights, decides: the same layer read as
+    # the other kind gives the other behaviour
+    assert not np.allclose(scores(rotary, 0, at), scores(nope, 0, at),
+                           atol=1e-3)
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+@pytest.mark.parametrize("window", [0, 5, 16])
+@pytest.mark.parametrize("start,t,m", [(0, 24, 24), (9, 7, 16), (13, 11, 29)])
+def test_blockwise_attention_is_the_masked_dense_product(window, start, t, m,
+                                                         batch):
+    """Blocks of 4 queries by 4 keys, an M that does not divide, a start
+    inside the keys (one row: the scan is as short as the window allows;
+    two rows at different starts: it covers every block): the dense
+    product under the same mask."""
+    rng = np.random.default_rng(8)
+    q = jnp.asarray(rng.normal(size=(batch, t, 4, 8)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(batch, m, 2, 8)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(batch, m, 2, 8)), jnp.float32)
+    starts = jnp.asarray([start, max(0, start - 3)][:batch], jnp.int32)
+    got = blockwise_attention(q, k, v, starts, window=window, block_q=4,
+                              block_k=4)
+    kk, vv = (np.repeat(np.asarray(a), 2, axis=2) for a in (k, v))
+    scores = np.einsum("bqhd,bkhd->bhqk", np.asarray(q), kk) / np.sqrt(8)
+    at = np.asarray(starts)[:, None] + np.arange(t)[None]        # [B, T]
+    keys = np.arange(m)[None, None]
+    seen = keys <= at[:, :, None]
+    if window:
+        seen &= at[:, :, None] - keys < window
+    scores = np.where(seen[:, None], scores, -np.inf)
+    scores = np.exp(scores - scores.max(-1, keepdims=True))
+    want = np.einsum("bhqk,bkhd->bqhd",
+                     scores / scores.sum(-1, keepdims=True), vv)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+
+
+def test_blockwise_attention_differentiates():
+    rng = np.random.default_rng(9)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 16, 2, 8)), jnp.float32)
+               for _ in range(3))
+    starts = jnp.zeros((1,), jnp.int32)
+
+    def dense(q, k, v):
+        return jnp.sum(tr.causal_attention(q, k, v, window=6) ** 2)
+
+    def blocked(q, k, v):
+        return jnp.sum(blockwise_attention(q, k, v, starts, window=6,
+                                           block_q=4, block_k=4) ** 2)
+
+    for a, b in zip(jax.grad(dense, (0, 1, 2))(q, k, v),
+                    jax.grad(blocked, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+def test_a_callers_attention_is_refused_where_a_window_binds(built):
+    model, params, _ = built
+    from parameter_server_distributed_tpu.ops.xla_flash import (
+        make_xla_flash_attention)
+
+    other = tr.Transformer(model.config,
+                           attention_fn=make_xla_flash_attention())
+    with pytest.raises(ValueError, match="window of 8 binds"):
+        other.apply(params, _tokens(10, 1, 16))
+    # where it does not bind (a sequence inside the window) it runs
+    other.apply(params, _tokens(10, 1, 8))
+
+
+def test_long_rows_are_bucketed_finely():
+    assert [_bucket(n) for n in (1, 16, 17, 1000, 2048)] == [
+        16, 16, 32, 1024, 2048]
+    assert [_bucket(n) for n in (2049, 6144, 12288, 12289)] == [
+        4096, 6144, 12288, 14336]
+
+
+def test_gpt2_is_the_patterns_simplest_member():
+    """Learned positions, one full-attention MLP layer as the whole
+    period, and a cache that is one array by position, as it has always
+    been."""
+    config = tr.TransformerConfig(
+        vocab=128, d_model=32, n_heads=4, n_layers=3, d_ff=64, max_seq=64,
+        dtype=jnp.float32, pos_emb="learned", norm="layernorm", bias=True)
+    assert config.period == (tr.LayerSpec(),) and config.head_dim == 8
+    model = tr.Transformer(config)
+    cache = generation.init_cache(model, batch=2, max_len=16)
+    assert cache.k.shape == (3, 2, 16, 4, 8)
+    assert cache.wk is None and cache.ring_layers == ()
+    assert cache.nbytes_by_kind() == {"full": 2 * cache.k.nbytes,
+                                      "window": 0}
+    assert jax.tree.structure(cache).num_leaves == 3
+    # moe_every still says where the capacity-dropping layers are
+    moe_lm = tr.TransformerConfig(n_layers=4, moe_every=2)
+    assert [moe_lm.layer_spec(i).ffn for i in range(4)] == [
+        "mlp", "moe", "mlp", "moe"]
+    with pytest.raises(ValueError, match="not both"):
+        tr.TransformerConfig(moe_every=2, pattern=(tr.LayerSpec(),))

@@ -191,3 +191,37 @@ def test_pack_unpack_roundtrip_and_truncation():
 def test_fp_block_env_default():
     assert "PSDT_PREFIX_FP_BLOCK" not in os.environ or True
     assert fp_block() >= 1
+
+
+def test_a_tail_as_wide_as_its_document_goes_before_the_document():
+    """Four shared documents, then a stream of one-token turns under
+    three of them, each pinning a row as wide as its document: the tails
+    evict each other and every document stays, the one that is never
+    used too, and the one admitted LAST and not yet used while the
+    others' tails fill the budget (a warm-up's order).  Plain LRU evicted
+    a document as soon as the rows admitted since its last use filled the
+    budget."""
+    t = PrefixTree(1000)
+    docs = [tuple([d] * 50) for d in range(4)]
+    for doc in docs:
+        t.insert(doc, last="l", handle=ref(100))
+    for i in range(40):                   # all under documents 0-2
+        doc = docs[i % 3]
+        node, matched, _ = t.lookup(doc + (100 + i,))
+        assert matched == 50
+        t.touch(node)
+        leaf = t.insert(doc + (100 + i,), last="l", handle=ref(102))
+        assert leaf.is_tail and not node.is_tail
+        t.evict_over_budget()
+        assert t.bytes <= 1000
+    for doc in docs:
+        node, matched, partial = t.lookup(doc)
+        assert matched == 50 and not partial and node.handle is not None
+    assert t.evictions == 35              # 5 tails fit beside the docs
+    # a turn that is more than an eighth of its path is no tail: among
+    # such rows, and once no tail is left, it is plain LRU
+    long_turn = docs[0] + tuple(range(200, 208))
+    assert not t.insert(long_turn, last="l", handle=ref(102)).is_tail
+    t.budget_bytes = 350
+    t.evict_over_budget()
+    assert t.lookup(docs[3])[1] == 0 and t.lookup(long_turn)[1] == 58

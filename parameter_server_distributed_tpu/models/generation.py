@@ -30,7 +30,7 @@ import dataclasses
 import threading
 from collections import OrderedDict
 from functools import partial
-from typing import Any, Mapping
+from typing import Any, ClassVar, Mapping
 
 import jax
 import jax.numpy as jnp
@@ -42,32 +42,57 @@ from .transformer import Transformer
 Array = jax.Array
 
 
+# the minor dimension of a TPU vector register: a row of a cache part is
+# laid along it
+_LANES = 128
+
+
+def heads_per_row(kv_heads: int, head_dim: int) -> int:
+    """The layout rule of the cache, from shapes alone: how many whole K/V
+    heads share one row of a part.  As many as fit the 128 lanes (two
+    heads of 64, one of 128), and a divisor of the head count.  A head
+    narrower than the lanes left alone is padded to them on the device, or
+    the compiler turns the part around (positions onto the lanes) for its
+    products and back for the write: either way a decode round copies the
+    whole cache in and out (PERF.md, PR 28)."""
+    pack = max(1, _LANES // head_dim)
+    while kv_heads % pack:
+        pack -= 1
+    return pack
+
+
+def pack_heads(x: Array, pack: int) -> Array:
+    """K or V by head [..., KV, D] as the cache stores it: ``pack`` heads
+    side by side in a row, [..., KV / pack, pack * D]."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] // pack, pack * x.shape[-1]))
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class KVCache:
-    """Key/value cache, one part per KIND of layer.
+    """Key/value cache: a part per LAYER, each an array of its own, so
+    that a round reads and writes a layer where it lies (an index into a
+    stacked array is an operation, and a copy of the layer).
 
-    ``k``/``v`` [Lf, B, max_len, H, D] hold the layers stored BY POSITION
-    (index j is position j): every full-attention layer, so for a model
-    of full layers only (GPT-2) this is the whole cache, [L, B, max_len,
-    H, D] as it has always been.  ``wk``/``wv`` [Lw, B, W, H, D] hold the
-    window layers as RINGS of W positions (position p lives at index
-    p % W) however long the context, and are None where the model has no
-    window layer or the window is no shorter than ``max_len``.
-    ``ring_layers`` names the layers kept as rings (static); the others
-    are stored by position, in layer order.  ``length`` is the number of
+    ``k``/``v`` hold the layers stored BY POSITION (index j is position
+    j), in layer order: every full-attention layer, each [B, max_len,
+    KV / pack, pack * D] with ``pack`` = :func:`heads_per_row` heads side
+    by side in a row.  ``wk``/``wv`` hold the window layers as RINGS of W
+    positions (position p lives at index p % W) however long the context,
+    each [B, W, KV / pack, pack * D]; empty where the model has no window
+    layer or the window is no shorter than ``max_len``.  ``ring_layers``
+    names the layers kept as rings (static).  ``length`` is the number of
     valid positions (a traced scalar so decode never retraces)."""
-    k: Array
-    v: Array
+    k: tuple
+    v: tuple
     length: Array
-    wk: Array | None = None
-    wv: Array | None = None
+    wk: tuple = ()
+    wv: tuple = ()
     ring_layers: tuple = dataclasses.field(
         default=(), metadata=dict(static=True))
-
-    @property
-    def max_len(self) -> int:
-        return self.k.shape[2]
+    max_len: int = dataclasses.field(default=0, metadata=dict(static=True))
+    # the fields that hold a part per layer
+    PARTS: ClassVar[tuple] = ("k", "v", "wk", "wv")
 
     def place(self, layer: int) -> tuple[bool, int]:
         """(kept as a ring?, index within its part) of a layer."""
@@ -76,9 +101,8 @@ class KVCache:
         return False, layer - sum(1 for r in self.ring_layers if r < layer)
 
     def nbytes_by_kind(self) -> dict[str, int]:
-        ring = 0 if self.wk is None else self.wk.nbytes + self.wv.nbytes
-        return {"full": int(self.k.nbytes + self.v.nbytes),
-                "window": int(ring)}
+        return {"full": sum(int(x.nbytes) for x in self.k + self.v),
+                "window": sum(int(x.nbytes) for x in self.wk + self.wv)}
 
 
 def ring_layers_of(model: Transformer, max_len: int) -> tuple[int, ...]:
@@ -97,22 +121,24 @@ def ring_layers_of(model: Transformer, max_len: int) -> tuple[int, ...]:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class QuantKVCache:
-    """int8 KV cache: k/v int8 [L, B, max_len, H, D] with a per-(position,
-    head) f32 absmax scale [L, B, max_len, H].  Long-context decode is
-    cache-bandwidth-bound (the cache bytes streamed per token dwarf the
-    weights once B*S is large), so int8 storage nearly halves the HBM
-    traffic of every decode step; the int8->compute-dtype convert fuses
-    into the attention einsums.  Scale overhead is 4/D bytes/elem (~6% at
-    D=64).  Companion to the weight-only path in models/quant.py."""
-    k: Array
-    v: Array
-    k_scale: Array
-    v_scale: Array
+    """int8 KV cache, a part per layer like :class:`KVCache` (every layer
+    by position): k/v int8 [B, max_len, KV / pack, pack * D] with a
+    per-(position, head) f32 absmax scale [B, max_len, KV].  Long-context
+    decode is cache-bandwidth-bound (the cache bytes streamed per token
+    dwarf the weights once B*S is large), so int8 storage nearly halves
+    the HBM traffic of every decode step; the int8->compute-dtype convert
+    fuses into the attention einsums.  Scale overhead is 4/D bytes/elem
+    (~6% at D=64).  Companion to the weight-only path in models/quant.py."""
+    k: tuple
+    v: tuple
+    k_scale: tuple
+    v_scale: tuple
     length: Array
+    max_len: int = dataclasses.field(default=0, metadata=dict(static=True))
+    PARTS: ClassVar[tuple] = ("k", "v", "k_scale", "v_scale")
 
-    @property
-    def max_len(self) -> int:
-        return self.k.shape[2]
+    def place(self, layer: int) -> tuple[bool, int]:
+        return False, layer
 
 
 def _kv_quantize(x: Array) -> tuple[Array, Array]:
@@ -132,29 +158,39 @@ def init_cache(model: Transformer, batch: int, max_len: int,
         raise ValueError(
             f"cache_dtype must be 'native' or 'int8', got {cache_dtype!r}")
     rings = ring_layers_of(model, max_len)
-    # GQA: the cache stores kv_heads (< n_heads) — n_heads/kv_heads x less
-    # cache HBM; heads expand to the query count at attention time
-    shape = (c.n_layers - len(rings), batch, max_len, c.kv_heads,
-             c.head_dim)
+    pack = heads_per_row(c.kv_heads, c.head_dim)
+
+    def parts(count: int, positions: int, dtype) -> tuple:
+        # GQA: the cache stores kv_heads (< n_heads) — n_heads/kv_heads x
+        # less cache HBM; heads expand to the query count at attention time
+        return tuple(jnp.zeros((batch, positions, c.kv_heads // pack,
+                                pack * c.head_dim), dtype)
+                     for _ in range(count))
+
+    length = jnp.zeros((), jnp.int32)
     if cache_dtype == "int8":
         if rings:
             raise ValueError("the int8 cache stores every layer by "
                              "position; a model with window layers takes "
                              "the native cache")
+
+        def scales() -> tuple:
+            return tuple(jnp.ones((batch, max_len, c.kv_heads), jnp.float32)
+                         for _ in range(c.n_layers))
+
         return QuantKVCache(
-            k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(shape, jnp.int8),
-            k_scale=jnp.ones(shape[:-1], jnp.float32),
-            v_scale=jnp.ones(shape[:-1], jnp.float32),
-            length=jnp.zeros((), jnp.int32))
-    cache = KVCache(k=jnp.zeros(shape, c.dtype), v=jnp.zeros(shape, c.dtype),
-                    length=jnp.zeros((), jnp.int32))
-    if not rings:
-        return cache
-    ring = (len(rings), batch, c.layer_spec(rings[0]).window, c.kv_heads,
-            c.head_dim)
-    return dataclasses.replace(cache, wk=jnp.zeros(ring, c.dtype),
-                               wv=jnp.zeros(ring, c.dtype),
-                               ring_layers=rings)
+            k=parts(c.n_layers, max_len, jnp.int8),
+            v=parts(c.n_layers, max_len, jnp.int8),
+            k_scale=scales(), v_scale=scales(), length=length,
+            max_len=max_len)
+    by_position = c.n_layers - len(rings)
+    window = c.layer_spec(rings[0]).window if rings else 0
+    return KVCache(
+        k=parts(by_position, max_len, c.dtype),
+        v=parts(by_position, max_len, c.dtype), length=length,
+        wk=parts(len(rings), window, c.dtype),
+        wv=parts(len(rings), window, c.dtype), ring_layers=rings,
+        max_len=max_len)
 
 
 def ring_of_row(row: Array, length: Array, window: int) -> Array:
@@ -167,19 +203,27 @@ def ring_of_row(row: Array, length: Array, window: int) -> Array:
 
 
 def split_row(cache: KVCache, k: Array, v: Array, length: Array
-              ) -> tuple[Array, Array, Array | None, Array | None]:
-    """Every layer's K and V by position ([L, ..., S, H, D]) as the parts
-    of ``cache``: (k, v) of the layers it stores by position and, where it
-    keeps rings, (wk, wv) of the last ring's worth of positions before
-    ``length``."""
-    if not cache.ring_layers:
-        return k, v, None, None
-    rings = np.asarray(cache.ring_layers)
-    lin = np.asarray([i for i in range(k.shape[0])
-                      if i not in cache.ring_layers])
-    window = cache.wk.shape[2]
-    return (k[lin], v[lin], ring_of_row(k[rings], length, window),
-            ring_of_row(v[rings], length, window))
+              ) -> tuple[tuple, tuple, tuple, tuple]:
+    """Every layer's K and V by position (k/v [L, ..., S, H, D], or a
+    sequence of L such layers) as the parts of ``cache``, a layer each:
+    (k, v) of the layers it stores by position and (wk, wv) of its rings,
+    the last ring's worth of positions before ``length``."""
+    window = cache.wk[0].shape[1] if cache.ring_layers else 0
+
+    def split(row) -> tuple[tuple, tuple]:
+        rings = range(len(row))
+        return (tuple(row[i] for i in rings if i not in cache.ring_layers),
+                tuple(ring_of_row(row[i], length, window)
+                      for i in rings if i in cache.ring_layers))
+
+    (k, wk), (v, wv) = split(k), split(v)
+    return k, v, wk, wv
+
+
+def _seeded(part: Array, block: Array) -> Array:
+    """``part`` with ``block`` written from its origin."""
+    return jax.lax.dynamic_update_slice(part, block.astype(part.dtype),
+                                        (0,) * part.ndim)
 
 
 def check_position_budget(model: Transformer, prompt_len: int,
@@ -206,27 +250,21 @@ def prefill(model: Transformer, params: Mapping[str, Array], tokens: Array,
         raise ValueError(f"prompt {prompt_len} exceeds cache {max_len}")
     logits, kvs = model.apply_collect_kv(params, tokens)
     cache = init_cache(model, batch, max_len, cache_dtype)
-    k = jnp.stack([k for k, _ in kvs])        # [L, B, S, H, D]
-    v = jnp.stack([v for _, v in kvs])
-    at0 = (0, 0, 0, 0, 0)
-    if isinstance(cache, QuantKVCache):
-        k8, ks = _kv_quantize(k)
-        v8, vs = _kv_quantize(v)
-        cache = QuantKVCache(
-            k=jax.lax.dynamic_update_slice(cache.k, k8, at0),
-            v=jax.lax.dynamic_update_slice(cache.v, v8, at0),
-            k_scale=jax.lax.dynamic_update_slice(cache.k_scale, ks, at0[:-1]),
-            v_scale=jax.lax.dynamic_update_slice(cache.v_scale, vs, at0[:-1]),
-            length=jnp.asarray(prompt_len, jnp.int32))
-        return logits[:, -1], cache
+    c = model.config
+    pack = heads_per_row(c.kv_heads, c.head_dim)
     length = jnp.asarray(prompt_len, jnp.int32)
-    k, v, wk, wv = split_row(cache, k.astype(cache.k.dtype),
-                             v.astype(cache.v.dtype), length)
-    cache = dataclasses.replace(
-        cache, k=jax.lax.dynamic_update_slice(cache.k, k, at0),
-        v=jax.lax.dynamic_update_slice(cache.v, v, at0), wk=wk, wv=wv,
-        length=length)
-    return logits[:, -1], cache
+    if isinstance(cache, QuantKVCache):
+        k, ks = zip(*(_kv_quantize(k) for k, _ in kvs))
+        v, vs = zip(*(_kv_quantize(v) for _, v in kvs))
+        fresh = ([pack_heads(x, pack) for x in k],
+                 [pack_heads(x, pack) for x in v], ks, vs)
+    else:
+        fresh = split_row(
+            cache, [pack_heads(k, pack) for k, _ in kvs],   # [B, S, KV', D']
+            [pack_heads(v, pack) for _, v in kvs], length)
+    return logits[:, -1], dataclasses.replace(cache, length=length, **{
+        name: tuple(map(_seeded, getattr(cache, name), layers))
+        for name, layers in zip(cache.PARTS, fresh)})
 
 
 # a block of this many queries or more against this many positions or
@@ -297,68 +335,63 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
     # those lanes' outputs are discarded)
     h = model.embed(params, tokens, positions)               # [B, T, d]
     quant = isinstance(cache, QuantKVCache)
-    new_k, new_v = cache.k, cache.v
-    new_ks = cache.k_scale if quant else None
-    new_vs = cache.v_scale if quant else None
-    new_wk, new_wv = (None, None) if quant else (cache.wk, cache.wv)
+    pack = heads_per_row(c.kv_heads, c.head_dim)
+
+    def written(part: Array, block: Array) -> Array:
+        """``part`` [B, M, ...] with the block's ``block`` [B, T, ...] at
+        its positions: an update of the (donated) part where it lies."""
+        block = block.astype(part.dtype)
+        if ragged:
+            # mode="drop": rows that finished generating keep advancing
+            # their lengths each speculative round, so their scatter
+            # positions intentionally overshoot cache.max_len — those
+            # writes must be dropped, not clamped onto the last slot.
+            return part.at[bidx, positions].set(block, mode="drop")
+        return jax.lax.dynamic_update_slice(
+            part, block, (0, pos) + (0,) * (part.ndim - 2))
+
+    # a part per layer, each replaced by its written self as its layer runs
+    parts = {name: list(getattr(cache, name)) for name in cache.PARTS}
     for layer in range(c.n_layers):
         # layer_view resolves either param layout (unrolled layer<i>/* or
         # scan_layers' stacked blocks/*)
         lp, p = model.layer_view(params, layer)
         spec = c.layer_spec(layer)
-        ring, i = (False, layer) if quant else cache.place(layer)
+        ring, i = cache.place(layer)
         router = model.pre_attention_router(lp, p, spec, h)
         q, k, v = model.qkv(lp, p, h, positions, spec)  # k/v: [B, T, KV, D]
         if ring:
-            attn, new_wk, new_wv = _ring_attention(
-                c, q, k, v, new_wk, new_wv, i, positions, counts)
+            attn, parts["wk"][i], parts["wv"][i] = _ring_attention(
+                c, q, pack_heads(k, pack), pack_heads(v, pack),
+                parts["wk"][i], parts["wv"][i], positions, counts)
         else:
-            if quant:
-                k, ks = _kv_quantize(k)
-                v, vs = _kv_quantize(v)
             with jax.named_scope("cache_update"):
-                if ragged:
-                    # mode="drop": rows that finished generating keep
-                    # advancing their lengths each speculative round, so
-                    # their scatter positions intentionally overshoot
-                    # cache.max_len — those writes must be dropped, not
-                    # clamped onto the last slot.
-                    new_k = new_k.at[i, bidx, positions].set(
-                        k.astype(new_k.dtype), mode="drop")
-                    new_v = new_v.at[i, bidx, positions].set(
-                        v.astype(new_v.dtype), mode="drop")
-                    if quant:
-                        new_ks = new_ks.at[i, bidx, positions].set(
-                            ks, mode="drop")
-                        new_vs = new_vs.at[i, bidx, positions].set(
-                            vs, mode="drop")
-                else:
-                    new_k = jax.lax.dynamic_update_slice(
-                        new_k, k[None].astype(new_k.dtype),
-                        (i, 0, pos, 0, 0))
-                    new_v = jax.lax.dynamic_update_slice(
-                        new_v, v[None].astype(new_v.dtype),
-                        (i, 0, pos, 0, 0))
-                    if quant:
-                        new_ks = jax.lax.dynamic_update_slice(
-                            new_ks, ks[None], (i, 0, pos, 0))
-                        new_vs = jax.lax.dynamic_update_slice(
-                            new_vs, vs[None], (i, 0, pos, 0))
+                if quant:
+                    k, ks = _kv_quantize(k)
+                    v, vs = _kv_quantize(v)
+                    parts["k_scale"][i] = written(parts["k_scale"][i], ks)
+                    parts["v_scale"][i] = written(parts["v_scale"][i], vs)
+                keys = parts["k"][i] = written(parts["k"][i],
+                                               pack_heads(k, pack))
+                values = parts["v"][i] = written(parts["v"][i],
+                                                 pack_heads(v, pack))
             with jax.named_scope("cache_attn"), jax.named_scope("attn"), \
                     jax.named_scope("window" if spec.window else "full"):
                 if (not quant and t >= _BLOCKWISE_QUERIES
                         and cache.max_len >= model.BLOCKWISE_FROM):
                     from ..ops.xla_flash import blockwise_attention
 
+                    by_head = keys.shape[:2] + (c.kv_heads, c.head_dim)
                     attn = blockwise_attention(
-                        q, new_k[i], new_v[i], positions[:, 0],
-                        window=spec.window)
+                        q, keys.reshape(by_head), values.reshape(by_head),
+                        positions[:, 0], window=spec.window)
                 else:
                     if spec.window not in masks:
                         masks[spec.window] = position_mask(spec.window)
                     attn = _dense_cache_attention(
-                        c, q, new_k, new_v, i, masks[spec.window],
-                        (new_ks, new_vs) if quant else None)
+                        c, q, keys, values, masks[spec.window],
+                        (parts["k_scale"][i], parts["v_scale"][i])
+                        if quant else None)
         h = model.attn_residual(lp, p, h, attn)
         # the FFN's weights viewed where they are used, as ever (under
         # scan_layers a view is slices, and their place in the program is
@@ -369,35 +402,67 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                                   router_logits=router,
                                   route_stats=route_stats)
     logits = model.final_logits(params, h)
-    new_length = cache.length if ragged else pos + t
-    if quant:
-        return logits, QuantKVCache(k=new_k, v=new_v, k_scale=new_ks,
-                                    v_scale=new_vs, length=new_length)
-    return logits, dataclasses.replace(cache, k=new_k, v=new_v, wk=new_wk,
-                                       wv=new_wv, length=new_length)
+    return logits, dataclasses.replace(
+        cache, length=cache.length if ragged else pos + t,
+        **{name: tuple(layers) for name, layers in parts.items()})
 
 
-def _dense_cache_attention(c, q: Array, keys: Array, values: Array, i: int,
-                           mask: Array, scales) -> Array:
-    """Dense attention of q [B, T, H, D] against layer ``i`` of the cache's
-    part stored by position (keys/values [L, B, M, KV, D]), f32 softmax.
-    GQA: query-head groups contract directly against the UNexpanded cache
-    — the cache bytes streamed per step stay kv_heads-sized (the point of
-    the smaller cache), no materialized repeat.  int8 cache (``scales`` =
-    (k_scale, v_scale), each [L, B, M, KV]): contract against the int8
-    array (only int8 bytes stream from HBM; the convert fuses into the
-    einsum) and fold the per-(position, head) scale into the product
-    afterwards."""
-    quant = scales is not None
-    b, s_q = q.shape[:2]
-    qg = q.reshape(b, s_q, c.kv_heads, c.kv_groups, c.head_dim)
-    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg,
-                        keys[i].astype(c.dtype) if quant else keys[i],
+def _cache_scores(c, q: Array, keys: Array) -> Array:
+    """q [B, T, H, D] against ``keys`` [B, K, KV / pack, pack * D] (a part
+    of the cache, or a block packed like one): [B, KV, G, T, K] in f32,
+    unscaled.  GQA: query-head groups contract directly against the
+    UNexpanded keys — the cache bytes streamed per step stay
+    kv_heads-sized (the point of the smaller cache), no materialized
+    repeat.  Where ``pack`` heads share a row, each head's queries sit in
+    its own lanes of a row of zeros, so the product reads the part as it
+    is stored: ``pack`` times the multiplications, on a round that waits
+    for the cache's bytes, and sums that differ from the unpacked ones by
+    added zeros."""
+    b, t = q.shape[:2]
+    pack = c.kv_heads // keys.shape[2]
+    qg = q.reshape(b, t, c.kv_heads // pack, pack, c.kv_groups, c.head_dim)
+    if pack > 1:
+        mine = jnp.eye(pack, dtype=q.dtype)[:, None, :, None]
+        qg = qg[:, :, :, :, :, None, :] * mine    # [B, T, KV', j, G, j', D]
+    qg = qg.reshape(b, t, c.kv_heads // pack, pack * c.kv_groups,
+                    pack * c.head_dim)
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, keys,
                         preferred_element_type=jnp.float32)
+    return scores.reshape(b, c.kv_heads, c.kv_groups, t, keys.shape[1])
+
+
+def _cache_weighted(c, probs: Array, values: Array) -> Array:
+    """probs [B, KV, G, T, K] over ``values`` [B, K, KV / pack, pack * D]:
+    [B, T, H, D] in f32.  The other half of :func:`_cache_scores`: a
+    head's result is its own lanes of its row's."""
+    b, _, _, t, held = probs.shape
+    pack = c.kv_heads // values.shape[2]
+    out = jnp.einsum(
+        "bhgqk,bkhd->bqhgd",
+        probs.reshape(b, c.kv_heads // pack, pack * c.kv_groups, t, held),
+        values, preferred_element_type=jnp.float32)
+    if pack > 1:
+        out = out.reshape(b, t, c.kv_heads // pack, pack, c.kv_groups, pack,
+                          c.head_dim)
+        mine = jnp.eye(pack, dtype=out.dtype)[:, None, :, None]
+        out = (out * mine).sum(axis=5)
+    return out.reshape(b, t, c.n_heads, c.head_dim)
+
+
+def _dense_cache_attention(c, q: Array, keys: Array, values: Array,
+                           mask: Array, scales) -> Array:
+    """Dense attention of q [B, T, H, D] against one layer's part of the
+    cache stored by position (keys/values [B, M, KV / pack, pack * D]),
+    f32 softmax.  int8 cache (``scales`` = (k_scale, v_scale), each
+    [B, M, KV]): contract against the int8 array (only int8 bytes stream
+    from HBM; the convert fuses into the einsum) and fold the
+    per-(position, head) scale into the product afterwards."""
+    quant = scales is not None
+    scores = _cache_scores(c, q, keys.astype(c.dtype) if quant else keys)
     if quant:
-        # k_scale[i]: [B, M, H] -> [B, H, 1, 1, M] over score axes
+        # k_scale: [B, M, H] -> [B, H, 1, 1, M] over score axes
         scores = scores * jnp.transpose(
-            scales[0][i], (0, 2, 1))[:, :, None, None, :]
+            scales[0], (0, 2, 1))[:, :, None, None, :]
     scores = scores / jnp.sqrt(jnp.asarray(c.head_dim, jnp.float32))
     scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
@@ -405,31 +470,31 @@ def _dense_cache_attention(c, q: Array, keys: Array, values: Array, i: int,
         # fold v_scale into probs (tiny [.., M] multiply) so the value
         # contraction streams raw int8
         probs = probs * jnp.transpose(
-            scales[1][i], (0, 2, 1))[:, :, None, None, :].astype(c.dtype)
-    attn = jnp.einsum("bhgqk,bkhd->bqhgd", probs,
-                      values[i].astype(c.dtype) if quant else values[i],
-                      preferred_element_type=jnp.float32).astype(c.dtype)
-    return attn.reshape(b, s_q, c.n_heads, c.head_dim)
+            scales[1], (0, 2, 1))[:, :, None, None, :].astype(c.dtype)
+    return _cache_weighted(
+        c, probs, values.astype(c.dtype) if quant else values
+    ).astype(c.dtype)
 
 
 def _ring_attention(c, q: Array, k: Array, v: Array, ring_k: Array,
-                    ring_v: Array, i: int, positions: Array,
+                    ring_v: Array, positions: Array,
                     counts: Array | None) -> tuple[Array, Array, Array]:
     """A window layer against its ring.  q [B, T, H, D] at
-    ``positions`` [B, T]; k/v [B, T, KV, D] the block's own; ring_k/ring_v
-    [Lw, B, W, KV, D] with position p at index p % W.  The queries attend
+    ``positions`` [B, T]; k/v [B, T, KV', D'] the block's own, packed like
+    the ring; ring_k/ring_v [B, W, KV', D'] with position p at index
+    p % W.  The queries attend
     what the ring held BEFORE the block (index s: the latest position
     below the block's first that is congruent to s, seen while within the
     window) and then the block itself, causally; afterwards the block's
     real positions (the first ``counts[b]``; all by default) overwrite the
     ring's oldest.  Returns (attn [B, T, H, D], ring_k, ring_v)."""
     batch, t = positions.shape
-    window = ring_k.shape[2]
+    window = ring_k.shape[1]
     if t > window:
         raise ValueError(f"a block of {t} positions does not go through a "
                          f"ring of {window}: forward it against a cache "
                          "stored by position")
-    qg = q.reshape(batch, t, c.kv_heads, c.kv_groups, c.head_dim)
+    k, v = k.astype(ring_k.dtype), v.astype(ring_v.dtype)
     scale = jnp.sqrt(jnp.asarray(c.head_dim, jnp.float32))
     first = positions[:, :1]                                  # [B, 1]
     with jax.named_scope("cache_attn"), jax.named_scope("attn"), \
@@ -443,29 +508,20 @@ def _ring_attention(c, q: Array, k: Array, v: Array, ring_k: Array,
                   & (offsets[:, None] - offsets[None, :] < window))
         mask = jnp.concatenate(
             [seen, jnp.broadcast_to(within[None], (batch, t, t))], axis=-1)
-        scores = jnp.concatenate([
-            jnp.einsum("bqhgd,bkhd->bhgqk", qg, ring_k[i],
-                       preferred_element_type=jnp.float32),
-            jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(ring_k.dtype),
-                       preferred_element_type=jnp.float32)], axis=-1) / scale
+        scores = jnp.concatenate([_cache_scores(c, q, ring_k),
+                                  _cache_scores(c, q, k)], axis=-1) / scale
         scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-        attn = (jnp.einsum("bhgqk,bkhd->bqhgd", probs[..., :window],
-                           ring_v[i], preferred_element_type=jnp.float32)
-                + jnp.einsum("bhgqk,bkhd->bqhgd", probs[..., window:],
-                             v.astype(ring_v.dtype),
-                             preferred_element_type=jnp.float32))
-        attn = attn.astype(c.dtype).reshape(batch, t, c.n_heads, c.head_dim)
+        attn = (_cache_weighted(c, probs[..., :window], ring_v)
+                + _cache_weighted(c, probs[..., window:], v)).astype(c.dtype)
     with jax.named_scope("cache_update"):
         at = positions % window
         if counts is not None:
             # a pad position's write falls outside the ring and is dropped
             at = jnp.where(offsets[None, :] < counts[:, None], at, window)
         bidx = jnp.arange(batch, dtype=jnp.int32)[:, None]
-        ring_k = ring_k.at[i, bidx, at].set(k.astype(ring_k.dtype),
-                                            mode="drop")
-        ring_v = ring_v.at[i, bidx, at].set(v.astype(ring_v.dtype),
-                                            mode="drop")
+        ring_k = ring_k.at[bidx, at].set(k, mode="drop")
+        ring_v = ring_v.at[bidx, at].set(v, mode="drop")
     return attn, ring_k, ring_v
 
 
@@ -621,9 +677,9 @@ def _beam_runner(model: Transformer, max_new_tokens: int, beam_width: int,
             def over_rows(fn, cache):
                 """fn on the batch axis of every part of the cache."""
                 return jax.tree.map(
-                    lambda x: fn(x) if x.ndim == 5 else x, cache)
+                    lambda x: fn(x) if x.ndim > 2 else x, cache)
 
-            cache = over_rows(lambda x: jnp.repeat(x, w, axis=1), cache)
+            cache = over_rows(lambda x: jnp.repeat(x, w, axis=0), cache)
             seqs = jnp.zeros((b, w, max_new_tokens), jnp.int32)
             seqs = seqs.at[:, :, 0].set(first)
 
@@ -660,7 +716,7 @@ def _beam_runner(model: Transformer, max_new_tokens: int, beam_width: int,
                 if eos_id is not None:
                     finished = finished | (token == eos_id)
                 rows = (jnp.arange(b)[:, None] * w + parent).reshape(-1)
-                cache = over_rows(lambda x: jnp.take(x, rows, axis=1),
+                cache = over_rows(lambda x: jnp.take(x, rows, axis=0),
                                   cache)
                 return (seqs, scores, finished, lengths, cache), None
 

@@ -47,9 +47,9 @@ from ..obs import stats as obs_stats
 from ..obs import trace as obs_trace
 from .generation import (KVCache, QuantKVCache, _cached_runner,
                          _kv_quantize, _model_key, _spec_round_runner,
-                         check_position_budget, decode_block, init_cache,
-                         ring_layers_of, sample_token, sample_token_rowwise,
-                         split_row)
+                         check_position_budget, decode_block, heads_per_row,
+                         init_cache, pack_heads, ring_layers_of, sample_token,
+                         sample_token_rowwise, split_row)
 from .prefix_tree import PrefixTree, RowRef
 from .transformer import Transformer
 
@@ -120,24 +120,24 @@ def _place_params(params, mesh, rule):
 
 
 def _shard_cache(cache, mesh):
-    """Place the slot cache on the mesh: batch over ``data``, kv heads
-    over ``tensor`` (where divisible), everything else replicated.  K/V
-    leaves are [L, B, M, H, D]; int8 scale leaves [L, B, M, H]; length
-    is scalar."""
+    """Place the slot cache on the mesh: batch over ``data``, the rows of
+    kv heads over ``tensor`` (where divisible), everything else
+    replicated.  K/V leaves are [B, M, KV / pack, pack * D], a layer each;
+    int8 scale leaves [B, M, KV]; length is scalar."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     def place(leaf):
         ndim = getattr(leaf, "ndim", 0)
-        if ndim < 4:
+        if ndim < 3:
             spec = PartitionSpec()
         else:
             data = ("data" if mesh.shape.get("data", 1) > 1
-                    and leaf.shape[1] % mesh.shape["data"] == 0 else None)
+                    and leaf.shape[0] % mesh.shape["data"] == 0 else None)
             tensor = ("tensor" if mesh.shape.get("tensor", 1) > 1
-                      and leaf.shape[3] % mesh.shape["tensor"] == 0
+                      and leaf.shape[2] % mesh.shape["tensor"] == 0
                       else None)
-            spec = PartitionSpec(*([None, data, None, tensor]
-                                   + [None] * (ndim - 4)))
+            spec = PartitionSpec(*([data, None, tensor]
+                                   + [None] * (ndim - 3)))
         return jax.device_put(leaf, NamedSharding(mesh, spec))
 
     return jax.tree_util.tree_map(place, cache)
@@ -145,9 +145,11 @@ def _shard_cache(cache, mesh):
 
 def _prefill_runner(model: Transformer, bucket: int, cache_dtype: str):
     """Jitted per (model, prompt bucket): forward the padded prompt, return
-    the last REAL position's logits, the prompt's K/V stack (quantized
-    already when the slot cache is int8, so splicing is dtype-pure) and
-    the tokens per expert of every experts layer ([L * E], else None)."""
+    the last REAL position's logits, the prompt's K/V ROW (every layer by
+    position, heads side by side as the cache's parts hold them:
+    [L, S', KV / pack, pack * D]; quantized already when the slot cache is
+    int8, so splicing is dtype-pure) and the tokens per expert of every
+    experts layer ([L * E], else None)."""
     key = (_model_key(model), "serve_prefill", bucket, cache_dtype)
 
     def build():
@@ -162,13 +164,16 @@ def _prefill_runner(model: Transformer, bucket: int, cache_dtype: str):
             last = model.final_logits(
                 params, jax.lax.dynamic_slice_in_dim(
                     h, real_len - 1, 1, axis=1))[0, 0]      # [vocab]
+            c = model.config
+            pack = heads_per_row(c.kv_heads, c.head_dim)
             k = jnp.stack([k for k, _ in kvs])[:, 0]        # [L, S', H, D]
             v = jnp.stack([v for _, v in kvs])[:, 0]
             if cache_dtype == "int8":
-                k8, ks = _kv_quantize(k)
-                v8, vs = _kv_quantize(v)
-                return last, (k8, v8, ks, vs), loads
-            return last, (k, v), loads
+                k, ks = _kv_quantize(k)
+                v, vs = _kv_quantize(v)
+                return last, (pack_heads(k, pack), pack_heads(v, pack),
+                              ks, vs), loads
+            return last, (pack_heads(k, pack), pack_heads(v, pack)), loads
 
         return run
 
@@ -184,38 +189,54 @@ def _splice_runner(model: Transformer, bucket: int, cache_dtype: str):
 
     def build():
         # donate the cache: the host drops its old reference immediately,
-        # so XLA may update the (large) K/V buffers in place
+        # so XLA updates the (large) K/V parts in place
         @partial(jax.jit, donate_argnums=(0,))
         @jax.named_scope("splice")
         def run(cache, row, slot, length):
-            if cache_dtype == "int8":
-                k8, v8, ks, vs = row
-                return QuantKVCache(
-                    k=jax.lax.dynamic_update_slice(
-                        cache.k, k8[:, None], (0, slot, 0, 0, 0)),
-                    v=jax.lax.dynamic_update_slice(
-                        cache.v, v8[:, None], (0, slot, 0, 0, 0)),
-                    k_scale=jax.lax.dynamic_update_slice(
-                        cache.k_scale, ks[:, None], (0, slot, 0, 0)),
-                    v_scale=jax.lax.dynamic_update_slice(
-                        cache.v_scale, vs[:, None], (0, slot, 0, 0)),
-                    length=cache.length)
-            k, v, wk, wv = split_row(cache, *row, length)
-
             def put(part, new):
-                if new is None:
-                    return part
+                """one layer of the row into its part, at the slot"""
                 return jax.lax.dynamic_update_slice(
-                    part, new[:, None].astype(part.dtype),
-                    (0, slot, 0, 0, 0))
+                    part, new[None].astype(part.dtype),
+                    (slot,) + (0,) * (part.ndim - 1))
 
-            return dataclasses.replace(
-                cache, k=put(cache.k, k), v=put(cache.v, v),
-                wk=put(cache.wk, wk), wv=put(cache.wv, wv))
+            if cache_dtype != "int8":
+                row = split_row(cache, *row, length)
+            return dataclasses.replace(cache, **{
+                name: tuple(map(put, getattr(cache, name), layers))
+                for name, layers in zip(cache.PARTS, row)})
 
         return run
 
     return _cached_runner(key, build)
+
+
+def _row_cache(model: Transformer, row, total: int, cache_dtype: str):
+    """A one-slot cache of ``total`` positions seeded with a row, every
+    layer stored by position (a window is then a mask)."""
+    def part(layer, fill=0):
+        wide = jnp.full((1, total) + layer.shape[1:], fill, layer.dtype)
+        return jax.lax.dynamic_update_slice(wide, layer[None],
+                                            (0,) * wide.ndim)
+
+    length = jnp.zeros((), jnp.int32)
+    if cache_dtype == "int8":
+        k8, v8, ks, vs = row
+        return QuantKVCache(
+            k=tuple(map(part, k8)), v=tuple(map(part, v8)),
+            k_scale=tuple(part(layer, 1) for layer in ks),
+            v_scale=tuple(part(layer, 1) for layer in vs),
+            length=length, max_len=total)
+    dtype = model.config.dtype
+    k, v = row
+    return KVCache(k=tuple(part(layer.astype(dtype)) for layer in k),
+                   v=tuple(part(layer.astype(dtype)) for layer in v),
+                   length=length, max_len=total)
+
+
+def _cache_row(cache) -> tuple:
+    """The row of a one-slot cache that stores every layer by position."""
+    return tuple(jnp.stack([part[0] for part in getattr(cache, name)])
+                 for name in cache.PARTS if getattr(cache, name))
 
 
 def _extend_runner(model: Transformer, pbucket: int, sbucket: int,
@@ -234,48 +255,17 @@ def _extend_runner(model: Transformer, pbucket: int, sbucket: int,
     tokens per expert as :func:`_prefill_runner` returns them."""
     key = (_model_key(model), "serve_extend", pbucket, sbucket,
            cache_dtype)
-    total = pbucket + sbucket
 
     def build():
         @jax.jit
         def run(params, row, padded_suffix, prefix_len, suffix_len):
-            if cache_dtype == "int8":
-                k8, v8, ks, vs = row
-                layers, _, heads, dim = k8.shape
-                cache = QuantKVCache(
-                    k=jnp.zeros((layers, 1, total, heads, dim),
-                                jnp.int8).at[:, 0, :pbucket].set(k8),
-                    v=jnp.zeros((layers, 1, total, heads, dim),
-                                jnp.int8).at[:, 0, :pbucket].set(v8),
-                    k_scale=jnp.ones((layers, 1, total, heads),
-                                     jnp.float32)
-                    .at[:, 0, :pbucket].set(ks),
-                    v_scale=jnp.ones((layers, 1, total, heads),
-                                     jnp.float32)
-                    .at[:, 0, :pbucket].set(vs),
-                    length=jnp.zeros((), jnp.int32))
-            else:
-                k, v = row
-                layers, _, heads, dim = k.shape
-                dtype = model.config.dtype
-                cache = KVCache(
-                    k=jnp.zeros((layers, 1, total, heads, dim), dtype)
-                    .at[:, 0, :pbucket].set(k.astype(dtype)),
-                    v=jnp.zeros((layers, 1, total, heads, dim), dtype)
-                    .at[:, 0, :pbucket].set(v.astype(dtype)),
-                    length=jnp.zeros((), jnp.int32))
             routed: list = []
-            logits, cache = decode_block(model, params, padded_suffix,
-                                         cache,
-                                         lengths=prefix_len[None],
-                                         route_stats=routed)
+            logits, cache = decode_block(
+                model, params, padded_suffix,
+                _row_cache(model, row, pbucket + sbucket, cache_dtype),
+                lengths=prefix_len[None], route_stats=routed)
             loads = jnp.concatenate(routed) if routed else None
-            last = logits[0, suffix_len - 1]
-            if cache_dtype == "int8":
-                return last, (cache.k[:, 0], cache.v[:, 0],
-                              cache.k_scale[:, 0],
-                              cache.v_scale[:, 0]), loads
-            return last, (cache.k[:, 0], cache.v[:, 0]), loads
+            return logits[0, suffix_len - 1], _cache_row(cache), loads
 
         return run
 
@@ -294,8 +284,8 @@ def _step_runner(model: Transformer, slots: int,
 
     def build():
         # donate the cache: without it every per-token step would copy the
-        # whole [L, B, max_len, H, D] K/V — doubling HBM traffic in the
-        # exact loop this server exists to keep bandwidth-bound
+        # whole K/V — doubling HBM traffic in the exact loop this server
+        # exists to keep bandwidth-bound
         @partial(jax.jit, donate_argnums=(2,))
         def run(params, tokens, cache, lengths, temps, rng):
             return _decode_round(model, top_k, top_p, params, tokens,

@@ -81,11 +81,35 @@ def test_prompt_longer_than_cache_rejected(rng):
 
 
 def test_init_cache_shapes():
+    """A part per layer, positions on axis 1, and in a row as many whole
+    heads as fit the 128 lanes: all 4 heads of 12."""
     model = tiny_model()
     cache = init_cache(model, batch=3, max_len=32)
-    assert cache.k.shape == (2, 3, 32, 4, 12)
-    assert cache.v.shape == cache.k.shape
-    assert int(cache.length) == 0
+    assert [part.shape for part in cache.k] == [(3, 32, 1, 48)] * 2
+    assert [part.shape for part in cache.v] == [(3, 32, 1, 48)] * 2
+    assert int(cache.length) == 0 and cache.max_len == 32
+
+
+@pytest.mark.parametrize("kv_heads, head_dim, pack", [
+    (16, 64, 2),     # GPT-2 medium: two heads of 64 fill the 128 lanes
+    (4, 128, 1),     # SmallThinker: a head is a row
+    (20, 64, 2), (4, 12, 4), (3, 64, 1), (2, 32, 2), (8, 32, 4),
+    (1, 64, 1), (6, 16, 6), (4, 256, 1)])
+def test_the_layout_rule_reads_only_shapes(kv_heads, head_dim, pack):
+    from parameter_server_distributed_tpu.models.generation import (
+        heads_per_row, pack_heads)
+
+    assert heads_per_row(kv_heads, head_dim) == pack
+    x = jnp.arange(2 * 3 * kv_heads * head_dim).reshape(
+        2, 3, kv_heads, head_dim)
+    rows = pack_heads(x, pack)
+    assert rows.shape == (2, 3, kv_heads // pack, pack * head_dim)
+    # head h is lanes (h % pack) * D .. of row h // pack
+    for h in (0, kv_heads - 1):
+        np.testing.assert_array_equal(
+            np.asarray(rows[:, :, h // pack,
+                            (h % pack) * head_dim:(h % pack + 1) * head_dim]),
+            np.asarray(x[:, :, h]))
 
 
 def test_repeated_generate_does_not_retrace(rng):
@@ -131,8 +155,10 @@ def test_gqa_cached_greedy_matches_full_forward(rng, n_kv):
 def test_gqa_cache_is_smaller(rng):
     mha = init_cache(tiny_model(), batch=2, max_len=16)
     gqa = init_cache(gqa_model(1), batch=2, max_len=16)
-    assert gqa.k.shape[3] == 1 and mha.k.shape[3] == 4
-    assert gqa.k.size == mha.k.size // 4
+    # one K/V head of 12 to a row against four side by side
+    assert gqa.k[0].shape[2:] == (1, 12) and mha.k[0].shape[2:] == (1, 48)
+    assert len(gqa.k) == len(mha.k) == 2
+    assert gqa.k[0].size == mha.k[0].size // 4
 
 
 def test_top_p_restricts_support():

@@ -118,9 +118,10 @@ def test_decode_through_the_cache_past_a_wrapped_ring(built):
     logits, cache = jax.jit(
         lambda p, t: generation.prefill(model, p, t, 64))(params,
                                                          tokens[:, :12])
-    # a cache per kind: 2 full layers hold 64 positions, 6 window layers 8
-    assert cache.k.shape == (2, 2, 64, 2, 32)
-    assert cache.wk.shape == (6, 2, 8, 2, 32)
+    # a part per layer: 2 full layers hold 64 positions, 6 window layers
+    # 8, and both K/V heads of 32 share a row
+    assert [part.shape for part in cache.k] == [(2, 64, 1, 64)] * 2
+    assert [part.shape for part in cache.wk] == [(2, 8, 1, 64)] * 6
     assert cache.ring_layers == (1, 2, 3, 5, 6, 7)
     np.testing.assert_allclose(np.asarray(logits), want[:, 11],
                                atol=TOLERANCE)
@@ -371,22 +372,189 @@ def test_long_rows_are_bucketed_finely():
 
 def test_gpt2_is_the_patterns_simplest_member():
     """Learned positions, one full-attention MLP layer as the whole
-    period, and a cache that is one array by position, as it has always
-    been."""
+    period, and a cache of one part a layer, all by position."""
     config = tr.TransformerConfig(
         vocab=128, d_model=32, n_heads=4, n_layers=3, d_ff=64, max_seq=64,
         dtype=jnp.float32, pos_emb="learned", norm="layernorm", bias=True)
     assert config.period == (tr.LayerSpec(),) and config.head_dim == 8
     model = tr.Transformer(config)
     cache = generation.init_cache(model, batch=2, max_len=16)
-    assert cache.k.shape == (3, 2, 16, 4, 8)
-    assert cache.wk is None and cache.ring_layers == ()
-    assert cache.nbytes_by_kind() == {"full": 2 * cache.k.nbytes,
+    assert [part.shape for part in cache.k] == [(2, 16, 1, 32)] * 3
+    assert cache.wk == () and cache.ring_layers == ()
+    assert cache.nbytes_by_kind() == {"full": 2 * 3 * cache.k[0].nbytes,
                                       "window": 0}
-    assert jax.tree.structure(cache).num_leaves == 3
+    assert jax.tree.structure(cache).num_leaves == 2 * 3 + 1
     # moe_every still says where the capacity-dropping layers are
     moe_lm = tr.TransformerConfig(n_layers=4, moe_every=2)
     assert [moe_lm.layer_spec(i).ffn for i in range(4)] == [
         "mlp", "moe", "mlp", "moe"]
     with pytest.raises(ValueError, match="not both"):
         tr.TransformerConfig(moe_every=2, pattern=(tr.LayerSpec(),))
+
+
+# ---------------------------------------------------------------- the cache
+# Two models over the one cache: full layers only at head size 64 (GPT-2's
+# shape: two heads share a row of the 128 lanes) and one period of full +
+# window layers at head size 128 (SmallThinker's: a head is a row).
+WIDE_HEADS = dict(CONFIG, head_dim=128, num_attention_heads=2,
+                  num_key_value_heads=1, num_hidden_layers=4,
+                  rope_layout=[0, 1, 1, 1], sliding_window_layout=[0, 1, 1, 1])
+
+
+@pytest.fixture(scope="module", params=["full_hd64", "pattern_hd128"])
+def cached(request):
+    if request.param == "full_hd64":
+        model = tr.Transformer(tr.TransformerConfig(
+            vocab=512, d_model=128, n_heads=2, n_layers=3, d_ff=64,
+            max_seq=64, dtype=jnp.float32, pos_emb="learned",
+            norm="layernorm", bias=True))
+        return model, model.init_params(5)
+    model = family.model(WIDE_HEADS)
+    return model, family.make_weights(model, 5)
+
+
+def _cache_parts(cache):
+    return cache.k + cache.v + cache.wk + cache.wv
+
+
+def test_a_row_of_the_cache_is_as_many_heads_as_fit_the_lanes(cached):
+    model, _ = cached
+    c = model.config
+    cache = generation.init_cache(model, 3, 32)
+    pack = generation.heads_per_row(c.kv_heads, c.head_dim)
+    assert pack == 128 // c.head_dim
+    rings = generation.ring_layers_of(model, 32)
+    assert len(cache.k) == len(cache.v) == c.n_layers - len(rings)
+    assert len(cache.wk) == len(cache.wv) == len(rings)
+    assert {part.shape for part in cache.k + cache.v} == {
+        (3, 32, c.kv_heads // pack, 128)}
+    assert {part.shape for part in cache.wk + cache.wv} <= {
+        (3, 8, c.kv_heads // pack, 128)}
+    kinds = cache.nbytes_by_kind()
+    assert kinds["full"] == 2 * len(cache.k) * 3 * 32 * c.kv_heads \
+        * c.head_dim * 4
+    assert kinds["window"] == 2 * len(rings) * 3 * 8 * c.kv_heads \
+        * c.head_dim * 4
+
+
+def _big_operations(text, at_least):
+    """(operation, name, elements) of the ENTRY computation's copies and
+    slices whose result holds ``at_least`` elements or more."""
+    import re
+
+    found = []
+    for line in text.split("\nENTRY")[1].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\][^ ]* "
+                     r"(copy|slice|dynamic-slice|transpose)\(", line)
+        if m:
+            size = int(np.prod([int(d) for d in m.group(2).split(",") if d]))
+            if size >= at_least:
+                found.append((m.group(3), m.group(1), size))
+    return found
+
+
+@pytest.mark.parametrize("program", ["step", "splice"])
+def test_the_compiled_round_updates_every_part_where_it_lies(cached,
+                                                             program):
+    """The decode round (and an admission's splice) take the cache
+    donated: every part is aliased from argument to result, and no
+    operation copies or slices out something as large as a layer stored
+    by position.  (XLA's CPU backend copies a RING that is read and then
+    written; the chip's compiler does not: tests/test_chip_compile.py
+    holds every part, rings too, in the program compiled for the chip.)"""
+    import re
+
+    from parameter_server_distributed_tpu.models import serving
+
+    model, params = cached
+    slots, max_len = 3, 32
+    cache = generation.init_cache(model, slots, max_len)
+    parts = _cache_parts(cache)
+    if program == "step":
+        lowered = serving._step_runner(model, slots, 0, 0.0, "native").lower(
+            params, jnp.zeros((slots,), jnp.int32), cache,
+            jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), jnp.float32),
+            jax.random.key(0))
+    else:
+        _, row, _ = serving._prefill_runner(model, 16, "native")(
+            params, jnp.zeros((1, 16), jnp.int32), jnp.asarray(9, jnp.int32))
+        lowered = serving._splice_runner(model, 16, "native").lower(
+            cache, row, jnp.asarray(1, jnp.int32), jnp.asarray(9, jnp.int32))
+    text = lowered.compile().as_text()
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry",
+                        text.splitlines()[0]).group(1)
+    assert aliased.count("alias)") >= len(parts)
+    assert _big_operations(text, cache.k[0].size) == []
+
+
+def test_the_check_for_big_operations_sees_a_layer_sliced_out():
+    """The check itself, on lines as the compiler writes them: a layer
+    sliced out of a cache stacked over layers is found, small operations
+    and those inside a fusion are not."""
+    text = """HloModule jit_run
+%fused_computation (p: f32[2,4,16,8]) -> f32[1,4,16,8] {
+  %slice.9 = f32[1,4,16,8]{3,2,1,0} slice(%p), slice={[1:2], [0:4], [0:16], [0:8]}
+}
+
+ENTRY %main.1 (cache: f32[2,4,16,8]) -> f32[4,16] {
+  %slice.1 = f32[1,4,16,8]{3,2,1,0} slice(%cache), slice={[1:2], [0:4], [0:16], [0:8]}
+  %copy.2 = f32[4,8]{1,0} copy(%q)
+  ROOT %copy.3 = f32[2,4,16,8]{3,2,1,0:T(8,128)} copy(%fusion.1)
+}"""
+    assert _big_operations(text, 4 * 16 * 8) == [
+        ("slice", "slice.1", 512), ("copy", "copy.3", 1024)]
+
+
+def test_rows_read_back_are_the_layers_own_by_position(cached):
+    """Prefill -> splice -> ragged decode rounds, then every part read
+    back against K and V of the plain forward over the whole sequence: a
+    by-position part holds position j at index j (the overshooting writes
+    of a row already at the cache's end are dropped, not clamped onto its
+    last position), a ring the last window's worth at p % W."""
+    from parameter_server_distributed_tpu.models import serving
+
+    model, params = cached
+    c = model.config
+    slots, max_len, rounds = 3, 32, 6
+    prompt = np.array([5, 12, 30])          # row 2 runs past the end
+    tokens = _tokens(21, slots, max_len + rounds)
+    pack = generation.heads_per_row(c.kv_heads, c.head_dim)
+    cache = generation.init_cache(model, slots, max_len)
+    for slot, n in enumerate(prompt):
+        bucket = 16 if n <= 16 else 32
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, :n] = tokens[slot, :n]
+        _, row, _ = serving._prefill_runner(model, bucket, "native")(
+            params, jnp.asarray(padded), jnp.asarray(n, jnp.int32))
+        assert row[0].shape == (c.n_layers, bucket, c.kv_heads // pack,
+                                pack * c.head_dim)
+        cache = serving._splice_runner(model, bucket, "native")(
+            cache, row, jnp.asarray(slot, jnp.int32),
+            jnp.asarray(n, jnp.int32))
+    step = jax.jit(lambda p, t, cache, n: generation.decode_block(
+        model, p, t, cache, lengths=n), donate_argnums=(2,))
+    lengths = prompt.copy()
+    for _ in range(rounds):
+        fed = tokens[np.arange(slots), lengths][:, None]
+        _, cache = step(params, jnp.asarray(fed), cache,
+                        jnp.asarray(lengths, jnp.int32))
+        lengths += 1
+    _, kvs = model.apply_collect_kv(params, jnp.asarray(tokens))
+    for layer, (k, v) in enumerate(kvs):      # each [B, S, KV, D]
+        ring, i = cache.place(layer)
+        for want, part in ((k, (cache.wk if ring else cache.k)[i]),
+                           (v, (cache.wv if ring else cache.v)[i])):
+            want = np.asarray(generation.pack_heads(want, pack))
+            part = np.asarray(part)
+            for slot, n in enumerate(lengths):
+                if ring and n <= max_len:
+                    window = part.shape[1]
+                    held = [p for p in range(n - window, n) if p >= 0]
+                    np.testing.assert_allclose(
+                        part[slot, [p % window for p in held]],
+                        want[slot, held], atol=TOLERANCE)
+                elif not ring:
+                    upto = min(n, max_len)
+                    np.testing.assert_allclose(
+                        part[slot, :upto], want[slot, :upto],
+                        atol=TOLERANCE)

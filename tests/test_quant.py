@@ -132,7 +132,9 @@ def test_int8_kv_cache_decode_tracks_fp_cache(rng):
     prompt = jnp.asarray(rng.integers(0, 96, (2, 8)), jnp.int32)
     lf, cf = prefill(model, params, prompt, 32)
     lq, cq = prefill(model, params, prompt, 32, cache_dtype="int8")
-    assert isinstance(cq, QuantKVCache) and cq.k.dtype == jnp.int8
+    assert isinstance(cq, QuantKVCache)
+    assert {part.dtype for part in cq.k + cq.v} == {jnp.dtype(jnp.int8)}
+    assert len(cq.k) == len(cq.k_scale) == model.config.n_layers
     np.testing.assert_array_equal(np.asarray(lf), np.asarray(lq))
     tok = jnp.argmax(lf, -1).astype(jnp.int32)
     sf, _ = decode_step(model, params, tok, cf)

@@ -10,7 +10,7 @@ param dicts concurrently and those may alias the apply inputs, so each
 apply transiently holds old+new param buffers (~2x the store) before the
 old copy is released.
 
-Two apply backends, A/B-comparable via ``PSDT_BENCH_PS_OPT`` in bench.py:
+Two apply backends (which one wins is not measured on the chip):
 
 - :class:`DeviceOptimizer` — optax transformation under jit (XLA fuses it).
 - :class:`PallasOptimizer` — the hand-fused pallas kernels from

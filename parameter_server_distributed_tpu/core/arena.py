@@ -117,7 +117,7 @@ def max_tensor_bytes() -> int:
 
 # Close-path device dispatches per stripe (contributor-mean scale
 # included), per update rule — the "one kernel per stage per stripe"
-# acceptance bound tests and the bench probe assert against.  Rules with
+# acceptance bound tests/test_arena.py asserts against.  Rules with
 # a weight-decay mask pay two extra stages (the decay product and the
 # select tail); everything else is the PR 11 stage list collapsed onto
 # one flat operand.

@@ -86,8 +86,8 @@ def wants_device_fold(optimizer) -> bool:
 # python thread can't feed XLA fast enough, so a second dispatcher
 # nearly doubles throughput.  Large kernels are BANDWIDTH-bound: the
 # runtime data-parallelizes each sweep across the intra-op pool, and a
-# second dispatcher only contends with it (both regimes measured on
-# this host via PSDT_BENCH_MODE=apply).
+# second dispatcher only contends with it (both regimes seen on a CPU
+# host; not measured on the chip).
 ENV_STRIPE_DISPATCH_MAX = "PSDT_DEVICE_STRIPE_DISPATCH_MAX"
 
 

@@ -551,7 +551,7 @@ class ParameterServerCore:
         self._serving_version = 0
         # Resident buffered-gradient accounting (accumulators + buffered
         # worker stores across live iteration states), for the
-        # ps.peak_grad_buffer_bytes gauge and the aggregate bench mode.
+        # ps.peak_grad_buffer_bytes gauge (tests/test_aggregation.py).
         self._grad_buffer_bytes = 0
         self._peak_grad_buffer_bytes = 0
         self._obs_peak_buffer = obs_stats.gauge("ps.peak_grad_buffer_bytes")

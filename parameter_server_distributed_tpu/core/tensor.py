@@ -49,7 +49,7 @@ def store_nbytes(store: Mapping[str, np.ndarray]) -> int:
     """Total payload bytes of a store WITHOUT copying device-resident
     arrays to host (``.size``/``.itemsize`` are metadata on numpy and jax
     arrays alike).  Used for the PS gradient-buffer accounting
-    (core/ps_core.py) and the aggregate bench mode."""
+    (core/ps_core.py)."""
     total = 0
     for v in store.values():
         itemsize = getattr(v, "itemsize", None)

@@ -34,7 +34,6 @@ never leak a newer-version continuation (tested).
 from __future__ import annotations
 
 import logging
-import os
 import queue
 import threading
 import time
@@ -132,13 +131,11 @@ class FleetDecodeServer:
                              else bool(auto_advance))
         self._versions_kept = max(1, int(versions_kept))
         self._heartbeat_s = float(heartbeat_s)
-        # Synthetic per-round service time (netsim-style): the fleet
-        # bench and scale tests pin it so per-server capacity is sleep-
-        # bound instead of host-CPU-bound — a tiny CPU model on a 2-core
-        # host would otherwise hide the control plane's scaling behind
-        # the shared cores.  0 (default) = off, production shape.
-        self._round_delay_s = float(
-            os.environ.get("PSDT_DECODE_ROUND_DELAY_MS", "0")) / 1e3
+        # Test seam, no knob: tests/test_fleet.py sets this attribute to
+        # a synthetic per-round service time, so that streams on a tiny
+        # CPU model stay in flight long enough for a rollout or a drain
+        # to land mid-stream.  0 = off, the only value outside tests.
+        self._round_delay_s = 0.0
         # Guards the version store, pin, command queue hand-off flags,
         # and stream bookkeeping shared between gRPC handler threads and
         # the decode loop (leaf — analysis/lock_order.py rank 74).

@@ -162,7 +162,7 @@ class DecodeControlResponse(Message):
         Field(11, "streams_served", "int32"),
         # prompt-phase reuse accounting (ISSUE 20): tokens the prompt
         # phase actually forwarded vs prompt tokens admitted — the
-        # fleet bench's prefill-computed/prompt ratio numerator and
+        # fleet's prefill-computed/prompt ratio numerator and
         # denominator (0/0 from pre-radix builds)
         Field(12, "prefill_tokens", "int64"),
         Field(13, "prompt_tokens", "int64"),

@@ -27,8 +27,8 @@ serving transform.
 
 The reference has no quantized path (its tensors are ``repeated float``
 f32 end to end — reference proto/parameter_server.proto:19-24); this is
-TPU-native added capability, measured by ``PSDT_BENCH_MODE=generate``
-``PSDT_BENCH_QUANT=int8`` as an A/B against the bf16 decoder.
+TPU-native added capability (``pst-serve --quant=int8``); its speed
+against the bf16 decoder is not measured on the chip.
 """
 
 from __future__ import annotations

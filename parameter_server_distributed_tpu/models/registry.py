@@ -86,7 +86,7 @@ REGISTRY: dict[str, tuple[Callable, Callable[[int, int], Iterator], str]] = {
     "lm_350m_hd128": (partial(lm_350m, n_heads=8), _lm_350m_batches,
                       "tokens"),
     # LLaMA-architecture flagship (SwiGLU + GQA): the shape from_hf_llama
-    # conversions have, so its bench rows transfer to real checkpoints
+    # conversions have, so what is measured on it transfers to real checkpoints
     "llama_350m": (llama_350m, _lm_350m_batches, "tokens"),
     # flagship-scale sparse MoE: lm_350m's trunk, every 2nd FFN routed
     # over 8 experts (~350M active / ~1.07B total)
@@ -135,8 +135,8 @@ def _model_kwargs(model_fn: Callable, name: str, dtype: str,
         elif remat:
             # asking for remat on a model that can't honor the memory
             # saving is an error; forcing it OFF on a model that never
-            # remats is a no-op (lets --no-remat / PSDT_BENCH_REMAT=0
-            # sweep across the whole registry)
+            # remats is a no-op (lets --no-remat sweep across the whole
+            # registry)
             raise ValueError(f"model {name!r} does not support remat "
                              f"(transformer LMs only)")
     if scan is not None:

@@ -506,7 +506,7 @@ class DecodeServer:
         # restriction is gone); a k==0-era ancestor without a draft row
         # falls back to a full draft prefill for the draft side only.
         self._prefix_hits = 0
-        # prompt-phase accounting for the fleet bench's reuse ratio:
+        # prompt-phase accounting for the prefix cache's reuse ratio:
         # tokens actually forwarded in a prompt phase vs prompt tokens
         # admitted (exact hit: 0, extension: the suffix, miss: all)
         self._prefill_tokens = 0
@@ -1189,7 +1189,7 @@ class DecodeServer:
             out["prefix_cache_nodes"] = self._prefix_tree.nodes
             out["prefix_cache_bytes"] = self._prefix_tree.bytes
             out["prefix_evictions"] = self._prefix_tree.evictions
-        # prompt-phase reuse ratio inputs (fleet bench): tokens the
+        # prompt-phase reuse ratio inputs (serve.prefix_hit_pct): tokens the
         # prompt phase actually forwarded vs prompt tokens admitted
         out["prefill_tokens"] = self._prefill_tokens
         out["prompt_tokens"] = self._prompt_tokens

@@ -593,8 +593,8 @@ class Transformer:
         term uses P_active = P - n_moe_layers * (E - top_k) * 2*d*d_ff
         (the standard sparse-MoE MFU numerator; an upper bound when
         expert-capacity dropping skips some tokens' experts — callers
-        reporting MoE MFU must say "active-expert accounting", bench.py
-        does).
+        reporting MoE MFU must say "active-expert accounting", as
+        perfbench/families/ does).
 
         ``remat_credited=True`` counts the extra forward the hardware
         actually executes under ``config.remat``: hardware-utilization
@@ -1182,7 +1182,7 @@ def lm_350m(vocab: int = 32000, seq: int = 1024, dtype=jnp.bfloat16,
             remat: bool = True, scan_layers: bool = False,
             kv_heads: int = 0, n_heads: int = 16,
             remat_policy: str = "full") -> Transformer:
-    """~370M-param GPT-style flagship for the LM MFU benchmark: 24 layers,
+    """~370M-param GPT-style flagship (chip_smoke.py's model): 24 layers,
     d_model 1024, seq 1024, bf16 weights/activations with f32 MXU
     accumulation, per-layer remat by default (activation memory, not HBM
     capacity, should bound the batch), chunked cross-entropy (peak f32
@@ -1210,7 +1210,7 @@ def llama_350m(vocab: int = 32000, seq: int = 1024, dtype=jnp.bfloat16,
     """LLaMA-architecture sibling of :func:`lm_350m` (~350M params):
     SwiGLU gated MLP (d_ff scaled to 8/3·d keeping the parameter count
     near the GELU flagship), GQA kv_heads=4, RoPE/RMSNorm — exactly the
-    shape :func:`models.hf.from_hf_llama` produces, so benches on this
+    shape :func:`models.hf.from_hf_llama` produces, so measurements on this
     entry transfer to converted checkpoints."""
     return Transformer(TransformerConfig(
         vocab=vocab, d_model=1024, n_heads=16, n_layers=24,
@@ -1240,7 +1240,7 @@ def moe_350m(vocab: int = 32000, seq: int = 1024, dtype=jnp.bfloat16,
     Pair with a mesh ``expert`` axis to shard the expert stacks
     (``--mesh=expert:4,data:2``); MFU is not reported for MoE configs
     (6*P overcounts inactive experts — flops_per_sample returns None),
-    bench rows report samples/s."""
+    so its rate is reported in samples/s."""
     return Transformer(TransformerConfig(
         vocab=vocab, d_model=1024, n_heads=16, n_layers=24, d_ff=4096,
         max_seq=seq, dtype=dtype, remat=remat, moe_every=2,

@@ -13,7 +13,7 @@ at src/parameter_server.cpp:40-91):
 - core/ps_core.py — fused barrier mean+SGD (`psdt_mean_sgd`)
 
 Set ``PSDT_NATIVE=0`` (or call :func:`set_enabled`) to force the numpy
-fallback — the bench A/B knob.
+fallback — the tests' A/B switch.
 """
 
 from __future__ import annotations
@@ -132,7 +132,7 @@ _enabled = os.environ.get("PSDT_NATIVE", "1").lower() not in ("0", "false")
 
 
 def set_enabled(value: bool) -> None:
-    """Enable/disable the native path at runtime (bench A/B knob).
+    """Enable/disable the native path at runtime (the tests' A/B switch).
 
     Re-enabling also clears the build-attempted latch when no library was
     bound, so a failure (e.g. a transiently missing compiler) is retried

@@ -10,8 +10,8 @@ blocks so XLA compiles it natively on EVERY backend.  Three uses:
   interpret mode, which is orders of magnitude slower than compiled
   code);
 - an apples-to-apples A/B contender for the pallas kernels on TPU
-  (`PSDT_BENCH_ATTENTION=xla_flash`; which one wins is not measured on
-  the chip);
+  (`--attention=xla_flash`; which one wins is not measured on the
+  chip);
 - long sequences on a host: dense attention materializes the
   [B, H, S, S] probability tensor (4 GB at S=8192, H=16, f32) while
   this streams O(S * block) working sets.

@@ -356,8 +356,8 @@ def run_training(config: TrainLoopConfig) -> dict:
     last_loss = float("nan")
     # obs registry mirrors of the JSONL stream: data-wait vs dispatch
     # split per step (cheap: two perf_counter reads), synced step time per
-    # window — what `pst-status --metrics` style rollups and the bench
-    # harness read without parsing logs
+    # window — what `pst-status --metrics` style rollups and the benchmark's
+    # readers read without parsing logs
     obs_data = obs_stats.histogram("train.data_s")
     obs_dispatch = obs_stats.histogram("train.dispatch_s")
     obs_step = obs_stats.histogram("train.step_s")

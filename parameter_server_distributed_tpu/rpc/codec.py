@@ -19,8 +19,8 @@ field — rpc/messages.py) is produced and consumed through the
 
 Selection is per-process: :func:`active_codec` resolves to the native
 codec whenever ``native.lib()`` is available and enabled (``PSDT_NATIVE=0``
-or ``native.set_enabled(False)`` forces the Python path — the bench A/B
-knob).  The resolved choice is exported as the ``rpc.codec.native`` gauge.
+or ``native.set_enabled(False)`` forces the Python path — the tests' A/B
+switch).  The resolved choice is exported as the ``rpc.codec.native`` gauge.
 
 Payload layouts (little-endian, pinned by the Python oracle):
 

@@ -2,7 +2,7 @@
 
 Every entry point that compiles (pst-worker, pst-train, pst-serve,
 pst-generate, pst-eval, pst-parameter-server with a device optimizer,
-bench.py, chip_smoke.py) calls :func:`enable_compile_cache` before its
+perfbench/run.py, chip_smoke.py) calls :func:`enable_compile_cache` before its
 first jit, so a second process on the same machine reads the first one's
 executables instead of compiling them again.
 

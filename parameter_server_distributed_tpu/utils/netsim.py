@@ -1,4 +1,4 @@
-"""Userspace network-condition injection for loopback benchmarks.
+"""Userspace network-condition injection for loopback tests.
 
 The top-k/bf16 wire encodings exist to win on a REAL network boundary
 (DCN between PS hosts and workers), where bytes cost wall-clock; on
@@ -17,9 +17,9 @@ userspace equivalent: a TCP relay that forwards byte-for-byte while
   style: the writer owes ``bytes/rate`` seconds after each chunk).
 
 gRPC/HTTP-2 traffic relays transparently (it is plain TCP).  One relay
-fronts one backend port; `bench.py pushpull` starts one per PS shard
-when PSDT_BENCH_NET="rtt_ms:mbps" is set and points the client at the
-relay ports (reference wire comparison: the reference's repeated-float
+fronts one backend port; a test starts one in front of a PS (or of one
+worker's PS leg: the straggler of tests/test_quorum.py) and points the
+client at the relay port (reference wire comparison: the reference's repeated-float
 proto has no compression at all — reference proto/parameter_server.proto:19-24).
 """
 

@@ -148,15 +148,18 @@ def test_flat_equals_per_tensor_device(numpy_oracle, rng, monkeypatch):
 
 
 # ------------------------------------------------------- dispatch bound
-@pytest.mark.parametrize("rule", ShardedDeviceOptimizer.RULES)
-def test_close_dispatch_bound(rule, numpy_oracle, rng):
-    """The acceptance bound: a flat close dispatches <= stages x stripes
-    kernels REGARDLESS of tensor count (64 tensors here; the per-tensor
-    path's operand count scales O(tensors)).  Counted via the kernel-
-    library probe — fold lanes (slab_update/assemble) never route
-    through k(), so the count is exactly the close stages."""
-    stripes = 2
-    shapes = {f"t{i:03d}": (64, 16) for i in range(64)}
+def _close_operands(n_tensors, flat, monkeypatch, rng, rule="adam",
+                    stripes=2):
+    """(stage kernel invocations, ARRAY operands they were handed) of one
+    warm close over ``n_tensors`` small tensors, on the flat layout or on
+    the per-tensor device path.  Counted via the kernel-library probe:
+    fold lanes on the flat layout (slab_update/assemble) never route
+    through k(), and the per-tensor path's scatter lanes are ingress
+    (fold) work too and are left out."""
+    import jax
+
+    monkeypatch.setenv(arena.ENV_ARENA, "1" if flat else "0")
+    shapes = {f"t{i:03d}": (64, 16) for i in range(n_tensors)}
     params = {k: rng.standard_normal(s).astype(np.float32)
               for k, s in shapes.items()}
     grads = {k: rng.standard_normal(s).astype(np.float32)
@@ -172,21 +175,88 @@ def test_close_dispatch_bound(rule, numpy_oracle, rng):
             core.receive_gradients(1, it, {k: g.copy()
                                            for k, g in grads.items()})
     real_k = device_apply.k
-    calls = {"n": 0}
+    calls = {"n": 0, "operands": 0}
 
-    def counting_k(name, _rk=real_k):
-        calls["n"] += 1
-        return _rk(name)
+    def counting_k(name):
+        fn = real_k(name)
+        if name.startswith("a_scatter"):
+            return fn
 
-    device_apply.k = counting_k
-    try:
+        def counted(*args, **kw):
+            calls["n"] += 1
+            calls["operands"] += sum(
+                1 for leaf in jax.tree_util.tree_leaves(args)
+                if getattr(leaf, "ndim", 0) > 0)
+            return fn(*args, **kw)
+        return counted
+
+    with monkeypatch.context() as probe:
+        probe.setattr(device_apply, "k", counting_k)
         r = core.receive_gradients(1, 2, {k: g.copy()
                                           for k, g in grads.items()})
-    finally:
-        device_apply.k = real_k
     assert r.aggregation_complete
+    return calls["n"], calls["operands"]
+
+
+@pytest.mark.parametrize("rule", ShardedDeviceOptimizer.RULES)
+def test_close_dispatch_bound(rule, numpy_oracle, rng, monkeypatch):
+    """The acceptance bound: a flat close dispatches <= stages x stripes
+    kernels REGARDLESS of tensor count (64 tensors here; the per-tensor
+    path's operand count scales O(tensors): the next test)."""
+    stripes = 2
+    stage_calls, _ = _close_operands(64, True, monkeypatch, rng, rule=rule,
+                                     stripes=stripes)
     budget = arena.close_dispatch_budget(rule, stripes)
-    assert 0 < calls["n"] <= budget, (calls["n"], budget)
+    assert 0 < stage_calls <= budget, (stage_calls, budget)
+
+
+def test_per_tensor_close_operands_grow_with_the_tensor_count(
+        numpy_oracle, rng, monkeypatch):
+    """The other half of the dispatch bound: the per-tensor path hands
+    its stage kernels every tensor of the stripe (operands O(tensors)),
+    the flat path one slab a role whatever the tensor count."""
+    budget = arena.close_dispatch_budget("adam", 2)
+    per_tensor = {n: _close_operands(n, False, monkeypatch, rng)
+                  for n in (16, 64)}
+    flat = {n: _close_operands(n, True, monkeypatch, rng) for n in (16, 64)}
+    for n in (16, 64):
+        assert per_tensor[n][1] >= n, per_tensor
+        assert 0 < flat[n][0] <= budget, flat
+    assert per_tensor[64][1] >= 4 * per_tensor[16][1] > 0, per_tensor
+    assert flat[64] == flat[16] and flat[64][1] < 4 * budget, flat
+
+
+@pytest.mark.parametrize("tensors,tensor_kb,gated", [(48, 4, False),
+                                                     (4, 512, True)],
+                         ids=["small", "big"])
+def test_mean_tensor_size_decides_the_regime(tensors, tensor_kb, gated,
+                                             numpy_oracle, rng,
+                                             monkeypatch):
+    """The regime gate (PSDT_ARENA_MAX_TENSOR_BYTES, here 64 KiB): many
+    small tensors close on the flat layout; a store whose MEAN tensor is
+    larger is bandwidth-bound and rides the per-tensor path, once
+    recorded as a fallback, byte-identical to the host either way."""
+    monkeypatch.setenv(arena.ENV_MAX_TENSOR, "65536")
+    shapes = {f"t{i:03d}": (tensor_kb << 8,) for i in range(tensors)}
+    params = {k: rng.standard_normal(s).astype(np.float32)
+              for k, s in shapes.items()}
+    grads_by_iter = [{k: rng.standard_normal(s).astype(np.float32)
+                      for k, s in shapes.items()} for _ in range(2)]
+    host_core = ParameterServerCore(total_workers=1, stripes=2,
+                                    optimizer=make_optimizer("adam", 0.02))
+    host_core.initialize_parameters(params)
+    host = _closes(host_core, grads_by_iter, workers=1)
+    arena_0, fallback_0 = _arena_counters()
+    core = ParameterServerCore(total_workers=1, stripes=2,
+                               optimizer=ShardedDeviceOptimizer("adam",
+                                                                0.02))
+    core.initialize_parameters(params)
+    got = _closes(core, grads_by_iter, workers=1)
+    arena_1, fallback_1 = _arena_counters()
+    assert core._arena.gated == gated
+    assert (arena_1 - arena_0, fallback_1 - fallback_0) == \
+        ((0, 1) if gated else (2, 0))
+    assert _stores_equal(host, got)
 
 
 # ------------------------------------------------ packing table / epoch

@@ -92,7 +92,7 @@ def test_only_the_helper_names_the_cache_directory():
     roots = [os.path.join(REPO, "parameter_server_distributed_tpu"),
              os.path.join(REPO, "scripts"), os.path.join(REPO, "examples")]
     files = [os.path.join(REPO, name)
-             for name in ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+             for name in ("chip_smoke.py", "__graft_entry__.py")]
     for root in roots:
         for directory, _, names in os.walk(root):
             files += [os.path.join(directory, n) for n in names

@@ -65,15 +65,18 @@ def decode_full_pull(service, wire_dtype, iteration=0):
     return out
 
 
-def delta_round(service, state, wire_dtype, iteration=0):
+def delta_round(service, state, wire_dtype, iteration=0, wire_bytes=None):
     """One client-side PullParametersDelta round against the in-process
-    service (frames re-decoded from their wire bytes, like gRPC would)."""
+    service (frames re-decoded from their wire bytes, like gRPC would;
+    a ``wire_bytes`` list is given the round's encoded size)."""
     req = dmsg.DeltaPullRequest(worker_id=0, iteration=iteration,
                                 wire_dtype=wire_dtype,
                                 held_version=max(state.version, 0))
-    frames = [dmsg.DeltaFrame.decode(f.encode())
-              for f in service.PullParametersDelta(req, None)]
-    return apply_frames(iter(frames), state)
+    encoded = [f.encode() for f in service.PullParametersDelta(req, None)]
+    if wire_bytes is not None:
+        wire_bytes.append(sum(len(e) for e in encoded))
+    return apply_frames(iter(dmsg.DeltaFrame.decode(e) for e in encoded),
+                        state)
 
 
 def delta_counters():
@@ -154,6 +157,54 @@ def test_delta_bitwise_semantics_negzero_and_nan(monkeypatch):
     assert got.tobytes() == want.tobytes()  # -0.0 and NaN, bit for bit
     assert np.signbit(got[1])  # the 0.0 -> -0.0 flip actually shipped
     assert np.isnan(got[2])
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "momentum"])
+def test_delta_serve_ships_a_fraction_of_the_full_serve(monkeypatch,
+                                                        optimizer):
+    """ISSUE 10's bound, in bytes: with fine-tuning-sized steps (most
+    elements move less than a bf16 ulp) a receiver that pulls every
+    version gets at most 30% of the full serve's bytes, one delta pull a
+    step and never a full fallback; one that pulls every second version
+    crosses a two-pair chain and still gets less than the whole model."""
+    from parameter_server_distributed_tpu.core.optimizer import make_optimizer
+
+    monkeypatch.setenv("PSDT_DELTA_DEPTH", "2")
+    pulls, shape = 4, (8192,)
+    rng = np.random.default_rng(0)
+    store = rand_store(rng, {f"w{i}": shape for i in range(4)})
+    ratio = {}
+    for locality in (1, 2):
+        core = ParameterServerCore(total_workers=1,
+                                   optimizer=make_optimizer(optimizer, 1e-3))
+        service = make_service(core)
+        core.initialize_parameters(store)
+        state, sizes = DeltaPullState(), []
+        first = delta_round(service, state, m.WIRE_BF16, wire_bytes=sizes)
+        assert not first.served_delta
+        full_bytes = sizes.pop()
+        grads = np.random.default_rng(1)
+        iteration = 0
+
+        def pull_after(applies, wire_bytes=None):
+            nonlocal iteration
+            for _ in range(applies):
+                iteration += 1
+                core.receive_gradients(0, iteration, {
+                    name: (grads.standard_normal(shape) * 0.1)
+                    .astype(np.float32) for name in store})
+            return delta_round(service, state, m.WIRE_BF16,
+                               iteration=iteration, wire_bytes=wire_bytes)
+
+        # the first pull armed the lazy chain and the first apply after it
+        # only seeds the retained image: one unmeasured apply + pull
+        pull_after(1)
+        served = [pull_after(locality, sizes).served_delta
+                  for _ in range(pulls)]
+        assert served == [True] * pulls, (locality, served)
+        ratio[locality] = sum(sizes) / pulls / full_bytes
+    assert 0 < ratio[1] <= 0.30, ratio
+    assert ratio[1] < ratio[2] < 1.0, ratio
 
 
 @pytest.mark.parametrize("indices,values", [

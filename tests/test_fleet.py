@@ -233,8 +233,8 @@ class _Fleet:
                              prompt_cache=prompt_cache),
                 server_id=sid, coordinator=self.caddr,
                 heartbeat_s=heartbeat_s)
-            # synthetic service time (the PSDT_DECODE_ROUND_DELAY_MS
-            # knob): keeps streams IN FLIGHT long enough for a rollout
+            # synthetic service time (the seam fleet/decode.py leaves
+            # for tests): keeps streams IN FLIGHT long enough for a rollout
             # or drain to land mid-stream on this fast tiny model
             server._round_delay_s = round_delay_s
             server.start()

@@ -353,6 +353,41 @@ def _run_fleet(core, n_workers, steps, lr_noise=0.0):
     assert not [t for t in threads if t.is_alive()]
 
 
+def _run_barriered(core, n_workers, steps):
+    """The same quadratic under an all-of-N barrier: every worker pushes
+    the gradient at the iteration's parameters, the last push closes."""
+    for it in range(1, steps + 1):
+        w = core.get_parameters()["w"].copy()
+        for wid in range(n_workers):
+            core.receive_gradients(wid, it, {"w": w.copy()})
+
+
+@pytest.mark.parametrize("freerun,run", [(True, _run_fleet),
+                                         (False, _run_barriered)],
+                         ids=["freerun", "barriered"])
+def test_only_a_freerun_core_counts_applies_and_publications(freerun, run):
+    """What a run's counters say of its mode: a free-run fleet records an
+    apply for every push and publications of what it applied, a barriered
+    run of the same job records neither, and both bring the quadratic's
+    loss under a quarter of where it began."""
+    n, steps, lr = 4, 8, 0.2
+    init = store(w=np.full(16, 8.0))
+    core = ParameterServerCore(total_workers=n, optimizer=SGD(lr),
+                               freerun=freerun)
+    core.initialize_parameters({k: v.copy() for k, v in init.items()})
+    before = counters()
+    run(core, n, steps)
+    after = counters()
+    applies, publishes = (
+        after.get(name, 0) - before.get(name, 0)
+        for name in ("ps.freerun.applies", "ps.freerun.publishes"))
+    assert applies == (n * steps if freerun else 0)
+    assert (publishes > 0) == freerun and publishes <= applies
+    loss, loss_0 = (0.5 * float(np.square(w).sum())
+                    for w in (core.get_parameters()["w"], init["w"]))
+    assert loss <= 0.25 * loss_0
+
+
 def test_n_worker_freerun_converges_within_tolerance_of_sync():
     """Acceptance: the async free-run fleet lands the quadratic optimum
     to within tolerance of the synchronous all-of-N baseline."""
@@ -361,10 +396,7 @@ def test_n_worker_freerun_converges_within_tolerance_of_sync():
 
     sync = ParameterServerCore(total_workers=n, optimizer=SGD(lr))
     sync.initialize_parameters({k: v.copy() for k, v in init.items()})
-    for it in range(1, steps + 1):
-        w = sync.get_parameters()["w"].copy()
-        for wid in range(n):
-            sync.receive_gradients(wid, it, {"w": w.copy()})
+    _run_barriered(sync, n, steps)
     sync_final = sync.get_parameters()["w"]
     # geometric decay toward 0: the baseline itself converged
     assert float(np.abs(sync_final).max()) < 1.0

@@ -23,8 +23,9 @@ def greedy_by_full_forward(model, params, prompt, n):
     """Reference: re-run the whole sequence through apply() per token."""
     toks = prompt
     out = []
+    apply = jax.jit(model.apply)  # one program a length, not one a primitive
     for _ in range(n):
-        logits = model.apply(params, toks)
+        logits = apply(params, toks)
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         out.append(nxt)
         toks = jnp.concatenate([toks, nxt[:, None]], axis=1)

@@ -82,8 +82,9 @@ def test_cached_decode_matches_full_forward_learned_pos(hf_pair, rng):
     prompt = jnp.asarray(rng.integers(0, 128, (2, 5)), jnp.int32)
     toks = prompt
     expected = []
+    apply = jax.jit(model.apply)  # one program a length, not one a primitive
     for _ in range(5):
-        nxt = jnp.argmax(model.apply(params, toks)[:, -1], -1)
+        nxt = jnp.argmax(apply(params, toks)[:, -1], -1)
         expected.append(nxt.astype(jnp.int32))
         toks = jnp.concatenate([toks, nxt[:, None].astype(jnp.int32)], 1)
     got = generate(model, params, prompt, 5)

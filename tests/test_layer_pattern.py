@@ -53,6 +53,8 @@ def built():
 
 
 def _reference_logits(weights, tokens):
+    # eager on purpose: the benchmark's plain reference, run as the
+    # benchmark runs it (a hundred small programs for the first length)
     return np.asarray(family.reference_forward(CONFIG, weights,
                                                jnp.asarray(tokens)))
 
@@ -98,8 +100,11 @@ def test_scanning_whole_periods_agrees_with_the_unrolled_layers(built):
         np.asarray(jax.jit(scanned.apply)(stacked, tokens)),
         _reference_logits(weights, tokens), atol=TOLERANCE)
     # and the cached decode reads a layer out of the stacks
-    logits, cache = generation.prefill(scanned, stacked, tokens[:, :20], 32)
-    step, _ = generation.decode_step(scanned, stacked, tokens[:, 20], cache)
+    logits, cache = jax.jit(
+        lambda p, t: generation.prefill(scanned, p, t, 32))(stacked,
+                                                           tokens[:, :20])
+    step, _ = jax.jit(lambda p, t, c: generation.decode_step(
+        scanned, p, t, c))(stacked, tokens[:, 20], cache)
     np.testing.assert_allclose(
         np.asarray(step), _reference_logits(weights, tokens[:, :21])[:, 20],
         atol=TOLERANCE)
@@ -138,7 +143,9 @@ def test_a_block_against_a_ring_writes_only_its_real_positions(built):
     model, params, weights = built
     tokens = _tokens(3, 2, 30)
     want = _reference_logits(weights, tokens)
-    _, cache = generation.prefill(model, params, tokens[:, :10], 64)
+    _, cache = jax.jit(
+        lambda p, t: generation.prefill(model, p, t, 64))(params,
+                                                         tokens[:, :10])
     lengths = jnp.asarray([10, 10], jnp.int32)
     block = jax.jit(lambda p, t, c, n, k: generation.decode_block(
         model, p, t, c, lengths=n, counts=k))

@@ -376,7 +376,7 @@ def test_lora_composes_with_moe_and_converted_arch_1f1b(rng):
     state = opt.init(params)
     tokens = rng.integers(0, 128, (4, 16)).astype(np.int32)
     loss_fn = lora_loss(moe.loss, alpha=4.0)
-    grads = jax.grad(loss_fn)(params, tokens)
+    grads = jax.jit(jax.grad(loss_fn))(params, tokens)
     updates, state = opt.update(grads, state, params)
     new = optax.apply_updates(params, updates)
     assert float(np.abs(np.asarray(

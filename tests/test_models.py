@@ -130,7 +130,7 @@ def test_bf16_resnet_trains_with_f32_inputs():
     params = model.init_params(0)
     x = np.random.default_rng(0).standard_normal((2, 16, 16, 3)).astype(np.float32)
     y = np.array([1, 2], np.int32)
-    loss, grads = jax.value_and_grad(model.loss)(params, (x, y))
+    loss, grads = jax.jit(jax.value_and_grad(model.loss))(params, (x, y))
     assert np.isfinite(float(loss))
     assert grads["stem/conv/w"].dtype == jnp.bfloat16
     assert np.isfinite(np.float32(np.asarray(grads["head/w"]))).all()

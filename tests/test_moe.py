@@ -312,8 +312,9 @@ def test_moe_lm_top2_trains_and_decodes(rng):
     out = np.asarray(generate(model, params, prompt, max_new_tokens=6))
     # greedy decode must equal re-running the full forward each step
     ids = list(prompt[0])
+    apply = jax.jit(model.apply)  # one program a length, not one a primitive
     for _ in range(6):
-        logits = model.apply(params, np.asarray([ids], np.int32))
+        logits = apply(params, np.asarray([ids], np.int32))
         ids.append(int(np.asarray(logits)[0, -1].argmax()))
     np.testing.assert_array_equal(out[0], np.asarray(ids[4:]))
 

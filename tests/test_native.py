@@ -74,7 +74,7 @@ def test_native_adam_matches_numpy(rng):
 @pytest.mark.parametrize("name", ["sgd", "momentum", "adam"])
 def test_host_optimizer_native_and_numpy_paths_agree(rng, name):
     """Multi-step optimizer trajectories must be identical (to f32 tolerance)
-    with the native path on and off — the bench A/B contract."""
+    with the native path on and off."""
     from parameter_server_distributed_tpu.core.optimizer import make_optimizer
 
     params = {"w": rng.standard_normal((17, 9)).astype(np.float32),

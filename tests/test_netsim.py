@@ -1,6 +1,6 @@
 """utils/netsim.ThrottledRelay: injected latency/bandwidth are real and
-gRPC traffic relays transparently (the substrate for the wire-encoding
-network A/B — bench.py PSDT_BENCH_NET)."""
+gRPC traffic relays transparently (the substrate of the straggler and
+partition scenarios in test_quorum, test_replication, test_flight)."""
 from __future__ import annotations
 
 import socket
@@ -98,8 +98,7 @@ def test_relay_caps_bandwidth_without_serializing_on_latency():
 
 @pytest.mark.slow
 def test_pushpull_through_relay_roundtrips():
-    """The PS gRPC data plane works unchanged through the relay — the
-    exact path bench.py's PSDT_BENCH_NET mode exercises."""
+    """The PS gRPC data plane works unchanged through the relay."""
     from parameter_server_distributed_tpu.config import (
         ParameterServerConfig)
     from parameter_server_distributed_tpu.core.tensor import to_wire

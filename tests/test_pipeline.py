@@ -37,7 +37,8 @@ def test_pipeline_matches_sequential(rng, n_pipe, microbatches):
     expect = np.asarray(sequential(stages, jnp.asarray(x)))
     stacked = stack_stage_params([{k: jnp.asarray(v) for k, v in s.items()}
                                   for s in stages], mesh)
-    got = np.asarray(pipeline_apply(stage_fn, stacked, x, mesh, microbatches))
+    got = np.asarray(jax.jit(lambda p: pipeline_apply(
+        stage_fn, p, x, mesh, microbatches))(stacked))
     np.testing.assert_allclose(got, expect, rtol=2e-5, atol=2e-5)
 
 
@@ -55,8 +56,8 @@ def test_pipeline_gradients_match_sequential(rng):
     def loss_seq(stage_list):
         return jnp.sum(sequential(stage_list, jnp.asarray(x)) ** 2)
 
-    g_pipe = jax.grad(loss_pipe)(stacked)
-    g_seq = jax.grad(loss_seq)(stages)
+    g_pipe = jax.jit(jax.grad(loss_pipe))(stacked)
+    g_seq = jax.jit(jax.grad(loss_seq))(stages)
     for i in range(4):
         np.testing.assert_allclose(np.asarray(g_pipe["w"][i]),
                                    np.asarray(g_seq[i]["w"]),
@@ -597,7 +598,7 @@ def test_pipelined_moe_gradients_flow_to_experts(rng):
                                    num_microbatches=2, schedule="gpipe")
     tokens = rng.integers(0, 64, (8, 16)).astype(np.int32)
     params = piped.init_params(0)
-    grads = jax.grad(piped.loss)(params, tokens)
+    grads = jax.jit(jax.grad(piped.loss))(params, tokens)
     assert "blocks/moe/w1" in grads
     for name in ("blocks/moe/w1", "blocks/moe/w2", "blocks/moe/router/w"):
         assert float(np.abs(np.asarray(grads[name])).max()) > 0, name
@@ -658,7 +659,7 @@ def test_pipelined_moe_expert_sharded_matches_replicated(rng):
     np.testing.assert_allclose(loss_ep, loss_rep, rtol=1e-5)
 
     # gradients flow to the sharded expert weights
-    grads = jax.grad(piped_ep.loss)(piped_ep.init_params(0), tokens)
+    grads = jax.jit(jax.grad(piped_ep.loss))(piped_ep.init_params(0), tokens)
     for name in ("blocks/moe/w1", "blocks/moe/w2", "blocks/moe/router/w"):
         assert float(np.abs(np.asarray(grads[name])).max()) > 0, name
 

@@ -24,6 +24,7 @@ from parameter_server_distributed_tpu.async_sgd.damping import (
 from parameter_server_distributed_tpu.core.optimizer import SGD
 from parameter_server_distributed_tpu.core.ps_core import ParameterServerCore
 from parameter_server_distributed_tpu.elastic import quorum as equorum
+from parameter_server_distributed_tpu.obs import stats as obs_stats
 
 
 def _core(total=3, quorum=0.5, grace_ms=0.0, **kw):
@@ -36,6 +37,13 @@ def _core(total=3, quorum=0.5, grace_ms=0.0, **kw):
 
 def _grad(value):
     return {"w": np.full(4, float(value), np.float32)}
+
+
+def _barrier_counts():
+    """(quorum closes, stale folds) recorded so far in this process."""
+    counters = obs_stats.REGISTRY.snapshot()["counters"]
+    return (counters.get("ps.barrier.quorum_closes", 0),
+            counters.get("ps.stale.folds", 0))
 
 
 # ------------------------------------------------------------------ policy
@@ -304,11 +312,29 @@ def test_stale_fold_is_idempotent_on_retry(monkeypatch):
     np.testing.assert_allclose(core.get_parameters()["w"], -1.0)
 
 
+@pytest.mark.parametrize("quorum,recorded", [(0.75, (1, 1)), (0.0, (0, 0))],
+                         ids=["k_of_n", "all_of_n"])
+def test_barrier_counters_tell_the_arms_apart(monkeypatch, quorum, recorded):
+    """What a run's counters say of its barriers: with 4 workers of which
+    one pushes after the others, K=3-of-4 records a quorum close at the
+    third push and a stale fold for the late one, and all-of-N records
+    neither (its close is the fourth push)."""
+    monkeypatch.delenv(equorum.ENV_QUORUM, raising=False)
+    core = _core(total=4, quorum=quorum, grace_ms=0.0)
+    closes_0, folds_0 = _barrier_counts()
+    for wid in (0, 1, 2):
+        core.receive_gradients(wid, 1, _grad(1))
+    _, ready, received, _ = core.check_sync_status(1)
+    assert ready == bool(quorum) and received == 3
+    r = core.receive_gradients(3, 1, _grad(1))  # the straggler
+    assert r.success and r.aggregation_complete
+    closes_1, folds_1 = _barrier_counts()
+    assert (closes_1 - closes_0, folds_1 - folds_0) == recorded
+
+
 def test_stale_fold_respects_staleness_bound():
     core = _core(total=2, quorum=0.5, grace_ms=0.0)
-    from parameter_server_distributed_tpu.obs import stats as obs_stats
-    before = obs_stats.REGISTRY.snapshot()["counters"].get(
-        "ps.stale.folds", 0)
+    before = _barrier_counts()[1]
     # close iterations 1 AND 2 with worker 0 alone
     for it in (1, 2):
         core.receive_gradients(0, it, _grad(1))
@@ -320,9 +346,7 @@ def test_stale_fold_respects_staleness_bound():
     r = core.receive_gradients(1, 1, _grad(8))
     assert r.success and r.aggregation_complete
     assert "already aggregated" in r.message
-    after = obs_stats.REGISTRY.snapshot()["counters"].get(
-        "ps.stale.folds", 0)
-    assert after == before
+    assert _barrier_counts()[1] == before
 
 
 def test_stale_fold_via_chunk_streamed_sink(monkeypatch):
@@ -472,11 +496,19 @@ def test_quorum_netsim_straggler_zero_stalled_iterations(tmp_path,
     # shm rings would negotiate past the relay and erase it
     monkeypatch.setenv("PSDT_SHM", "0")
     iterations = 5
+    counts_0 = _barrier_counts()
     clean = _run_quorum_cluster(tmp_path, "clean", iterations)
+    counts_clean = _barrier_counts()
     flight_dir = str(tmp_path / "flight")
     chaos = _run_quorum_cluster(
         tmp_path, "quorum", iterations, quorum=0.75, grace_ms=120.0,
         straggler_delay_ms=600.0, flight_dir=flight_dir)
+    counts_chaos = _barrier_counts()
+    # the counters name the arm: all-of-N records no quorum close and no
+    # stale fold, K-of-N under a straggler records both
+    assert counts_clean == counts_0
+    assert counts_chaos[0] > counts_clean[0]
+    assert counts_chaos[1] > counts_clean[1]
 
     events = postmortem.merge_events(postmortem.load_rings(flight_dir))
     # the quorum actually fired (the straggler missed grace at least once)

@@ -29,6 +29,16 @@ def _counters():
     return dict(obs_stats.REGISTRY.snapshot().get("counters", {}))
 
 
+def _replication_wire_bytes():
+    """TRUE wire bytes of the three replication legs so far: what the
+    client side sent and received, requests and responses."""
+    counters = _counters()
+    return sum(counters.get(f"rpc.client.{method}.{leg}", 0)
+               for method in ("PushReplicaDelta", "ShardedApplySlices",
+                              "InstallSlabSlices")
+               for leg in ("request_bytes", "response_bytes"))
+
+
 def _gauge(name):
     return obs_stats.REGISTRY.snapshot().get("gauges", {}).get(name, 0)
 
@@ -47,9 +57,9 @@ def rand_store(n=6, size=SIZE, seed=0):
             for i in range(n)}
 
 
-def run_closes(primary, store, iterations, seed=1, worker=0):
+def run_closes(primary, store, iterations, seed=1, worker=0, first=1):
     rng = np.random.default_rng(seed)
-    for it in range(1, iterations + 1):
+    for it in range(first, first + iterations):
         grads = {k: rng.standard_normal(len(v)).astype(np.float32)
                  for k, v in store.items()}
         r = primary.core.receive_gradients(worker, it, grads)
@@ -179,7 +189,7 @@ def test_flat_ship_replica_flags_idle_accelerator(tmp_path, arena_env):
 def test_single_replica_declines_to_local_apply(tmp_path, arena_env):
     """sharded_update=1 with NO backup configured: the updater stays
     disarmed and every close runs the ordinary local arena apply."""
-    before = _counters()
+    before, wire_before = _counters(), _replication_wire_bytes()
     solo, _ = make_ps(tmp_path, "solo", optimizer="sharded_momentum",
                       sharded_update="1")
     try:
@@ -191,8 +201,59 @@ def test_single_replica_declines_to_local_apply(tmp_path, arena_env):
         assert (after.get("ps.apply.sharded", 0)
                 == before.get("ps.apply.sharded", 0))
         assert solo.core.current_iteration == 3
+        # one replica: no replication byte moves at all
+        assert _replication_wire_bytes() == wire_before
     finally:
         solo.stop(0)
+
+
+# ------------------------------------------------------- wire bytes
+
+@pytest.mark.parametrize("replicas,raw_share,int8_share",
+                         [(2, 0.85, 0.47), (4, 0.60, 0.29)],
+                         ids=["2_replicas", "4_replicas"])
+def test_sharded_exchange_moves_fewer_bytes_than_the_flat_ship(
+        tmp_path, arena_env, replicas, raw_share, int8_share):
+    """ISSUE 18's count: after the first close (a flat ship, by which the
+    backups learn the base version) EVERY close of a sharded arm runs
+    sharded with no fallback, a flat-ship arm runs none, and an
+    iteration's replication wire bytes are, against the flat ship's,
+    0.83x (raw exchange) and 0.46x (int8) at 2 replicas, 0.58x and 0.27x
+    at 4: counts that follow from the shapes.  32 tensors of 3,125 (1e5
+    parameters): the exchange's per-slice framing must not eat them."""
+    closes = 3
+    store = rand_store(n=32, size=3125)
+    per_iteration = {}
+    for arm, kw in (("flat", {}),
+                    ("raw", {"sharded_update": "1"}),
+                    ("int8", {"sharded_update": "1",
+                              "sharded_update_dtype": "int8"})):
+        bks = [make_ps(tmp_path, f"{arm}-bk{i}", optimizer="sharded_adam")
+               for i in range(replicas - 1)]
+        primary, _ = make_ps(
+            tmp_path, f"{arm}-pr", optimizer="sharded_adam",
+            backup_address=",".join(f"127.0.0.1:{port}" for _, port in bks),
+            replication="sync", **kw)
+        try:
+            primary.core.initialize_parameters(store)
+            run_closes(primary, store, 1)  # the flat ship of the base
+            before, wire_before = _counters(), _replication_wire_bytes()
+            run_closes(primary, store, closes, seed=2, first=2)
+            after = _counters()
+            per_iteration[arm] = (
+                _replication_wire_bytes() - wire_before) / closes
+        finally:
+            primary.stop(0)
+            for bk, _port in bks:
+                bk.stop(0)
+        sharded, fallbacks = (
+            after.get(name, 0) - before.get(name, 0)
+            for name in ("ps.apply.sharded", "ps.apply.sharded_fallback"))
+        assert (sharded, fallbacks) == (closes if kw else 0, 0), arm
+    flat = per_iteration["flat"]
+    assert 0 < per_iteration["int8"] <= int8_share * flat, per_iteration
+    assert per_iteration["int8"] < per_iteration["raw"] <= raw_share * flat, \
+        per_iteration
 
 
 # ----------------------------------------------- quantized exchange

@@ -302,10 +302,12 @@ def flash_attention_auto(q: Array, k: Array, v: Array) -> Array:
 
 def make_sharded_flash_attention(mesh: Mesh,
                                  batch_axes: tuple[str, ...] = ("data", "fsdp"),
-                                 head_axis: str = "tensor") -> Callable:
+                                 head_axis: str = "tensor",
+                                 inner: Callable | None = None) -> Callable:
     """Pallas flash attention composed with a mesh: shard_map over the
-    batch and head axes, each device running the single-shard flash kernel
-    on its full-sequence [B/n, S, H/n, D] block.  Causal attention is
+    batch and head axes, each device running the single-shard kernel
+    ``inner`` (:func:`flash_attention_auto` where none is given) on its
+    full-sequence [B/n, S, H/n, D] block.  Causal attention is
     independent across batch and heads, so this is exact.
 
     The sequence axis must NOT be sharded here — XLA all-gathers seq-sharded
@@ -316,13 +318,14 @@ def make_sharded_flash_attention(mesh: Mesh,
 
     from jax import shard_map
 
+    inner = inner or flash_attention_auto
     heads_spec = head_axis if mesh.shape.get(head_axis, 1) > 1 else None
     spec = PartitionSpec(batch_axes, None, heads_spec, None)
 
     @_partial(shard_map, mesh=mesh, in_specs=(spec, spec, spec),
               out_specs=spec, check_vma=False)
     def sharded_flash(q, k, v):
-        return flash_attention_auto(q, k, v)
+        return inner(q, k, v)
 
     n_tp = mesh.shape.get(head_axis, 1)
 
@@ -340,14 +343,22 @@ ATTENTION_CHOICES = ("dense", "flash", "xla_flash", "ring", "ulysses",
 def select_attention(name: str, mesh: Mesh | None) -> Callable | None:
     """Attention implementation by name (the ``--attention`` CLI switch).
 
-    dense   — einsum causal attention (GSPMD partitions it over the mesh)
-    flash   — pallas flash kernels; with a mesh, shard_mapped over
-              batch/head shards (seq must be unsharded)
+    dense   — the model's default path (``Transformer.attend``), which
+              chooses by shape (``default_arm``): on a TPU one blockwise
+              pallas kernel that writes no [B, H, S, S] array
+              (ops/pallas/fused_attention.py; under shard_map over batch
+              and heads with a mesh), elsewhere the einsum (GSPMD
+              partitions it) or, long and mesh-less, ops/xla_flash.  On a
+              v5e at [64, 1024, 16, 64], 24 layers forward + backward:
+              481 ms against the einsum's 1,419 (PERF.md, PR 30)
+    flash   — the earlier pallas flash kernels, by name only; with a mesh,
+              shard_mapped over batch/head shards (seq must be unsharded).
+              Same micro-program: 3,049 ms at its 128 blocks, 776 at 512
     xla_flash — the same blockwise online-softmax recurrence in plain
               lax.scan (ops/xla_flash.py): compiled natively on every
               backend, O(S) residuals via per-block remat; the long-
-              context path where pallas is unavailable, and the pallas
-              kernels' A/B contender on TPU
+              context path where pallas is unavailable.  Same
+              micro-program: 2,696 ms
     ring    — ring attention over the mesh's ``seq`` axis (K/V ppermute)
     ulysses — all-to-all seq<->heads swap, dense attention per head shard
     ulysses_flash — same swap, pallas flash kernel on the gathered
@@ -356,7 +367,7 @@ def select_attention(name: str, mesh: Mesh | None) -> Callable | None:
               gathered sequence (compiled on every backend)
 
     Returns None for dense (the Transformer default), letting the model
-    pick its own fallback logic."""
+    pick its arm from the shape."""
     if name == "dense":
         return None
     if name == "flash":
@@ -408,6 +419,13 @@ def _default_attention() -> Callable:
             jax.devices()[0].platform)
         return causal_attention
     return flash_attention_auto
+
+
+def _kernel_backend() -> bool:
+    """Whether the default backend compiles the pallas kernels (a TPU);
+    anywhere else they would run interpreted, so the default path of
+    :meth:`Transformer.attend` keeps to plain XLA there."""
+    return jax.default_backend() == "tpu"
 
 
 def repeat_kv(x: Array, groups: int) -> Array:
@@ -499,10 +517,11 @@ class Transformer:
                 capacity_factor=config.moe_capacity, dtype=config.dtype))
         else:
             self._moe = None
-        # Default with a mesh is the GSPMD einsum path (XLA partitions it);
-        # pass make_sharded_flash_attention(mesh) / make_ring_attention /
-        # make_ulysses_attention — or use select_attention(name, mesh) — to
-        # combine a mesh with the pallas flash kernel or seq parallelism.
+        # causal_attention as attention_fn means the default path: attend()
+        # picks the arm from the shape (default_arm).  Pass
+        # make_ring_attention / make_ulysses_attention — or use
+        # select_attention(name, mesh) — for seq parallelism or to force an
+        # implementation by name.
         self.attention_fn = attention_fn or (
             _default_attention() if mesh is None else causal_attention)
         self.mesh = mesh  # when set, activations get sharding constraints
@@ -832,16 +851,61 @@ class Transformer:
 
     # sequences from this length on run blockwise attention (scores of a
     # block at a time, blocks wholly outside the mask skipped) on the
-    # default path; shorter ones the dense einsum the cells have always run
+    # default path where the kernel does not engage; shorter ones the
+    # dense einsum
     BLOCKWISE_FROM = 2048
+
+    def default_arm(self, q_shape: tuple[int, ...],
+                    kv_shape: tuple[int, ...], window: int) -> str:
+        """Which arm the default path takes, from what :meth:`attend` can
+        observe and nothing else: ``kernel`` (the blockwise kernel of
+        ops/pallas/fused_attention.py, which writes no [B, H, S, S] array),
+        ``sharded_kernel`` (the same under ``shard_map`` over the mesh's
+        batch and head axes), ``blockwise`` (ops/xla_flash, long sequences
+        without a mesh) or ``dense`` (the einsum).
+
+        The kernel runs where the backend is a TPU, no window binds, q and
+        k cover the same positions, the shape tiles (heads of 64 or 128
+        that fill rows of 128 lanes, positions in blocks of 128) and there
+        is more than one sequence or a long one (a single prompt shorter
+        than ``BLOCKWISE_FROM`` is faster through the einsum: PERF.md,
+        PR 30); with a mesh of several devices, where also its ``seq`` and
+        ``pipe`` axes are 1 and all of that holds for a device's shard of
+        batch and heads."""
+        mesh = self.mesh
+        if not window and _kernel_backend() and (
+                mesh is None
+                or mesh.shape.get("seq", 1) == mesh.shape.get("pipe", 1) == 1):
+            # (pallas is imported where a kernel can run, and only there)
+            from ..ops.pallas.fused_attention import fits
+
+            several = mesh is not None and mesh.size > 1
+            rows = tp = 1
+            if several:
+                rows = mesh.shape.get("data", 1) * mesh.shape.get("fsdp", 1)
+                tp = mesh.shape.get("tensor", 1)
+            divides = (q_shape[0] % rows == 0 and q_shape[2] % tp == 0
+                       and kv_shape[2] % tp == 0)
+            q_shard, kv_shard = (
+                (shape[0] // rows, shape[1], shape[2] // tp, shape[3])
+                for shape in (q_shape, kv_shape))
+            if (divides and fits(q_shard, kv_shard)
+                    and (q_shard[0] > 1
+                         or q_shard[1] >= self.BLOCKWISE_FROM)):
+                return "sharded_kernel" if several else "kernel"
+        if q_shape[1] >= self.BLOCKWISE_FROM and self.mesh is None:
+            return "blockwise"
+        return "dense"
 
     def attend(self, q: Array, k: Array, v: Array,
                spec: LayerSpec) -> Array:
         """Causal attention of a whole sequence for a layer of kind
         ``spec``, under ``attn/full`` or ``attn/window``.  A caller's
         ``attention_fn`` runs as given (it knows no window, so a window
-        that binds is refused); the default chooses by what it sees: the
-        dense einsum for a short sequence, blockwise for a long one."""
+        that binds is refused); the default chooses by what it sees
+        (:meth:`default_arm`): the blockwise kernel, under its own
+        ``attn_kernel`` component, wherever its shape fits on a TPU, else
+        blockwise in plain XLA for a long sequence, else the einsum."""
         seq = q.shape[1]
         window = spec.window if 0 < spec.window < seq else 0
         with jax.named_scope("attn"), jax.named_scope(
@@ -854,7 +918,18 @@ class Transformer:
                         "attention (dense or blockwise), not a caller's "
                         "attention_fn")
                 return self.attention_fn(q, k, v)
-            if seq >= self.BLOCKWISE_FROM and self.mesh is None:
+            arm = self.default_arm(q.shape, k.shape, window)
+            if arm in ("kernel", "sharded_kernel"):
+                from ..ops.pallas.fused_attention import (
+                    fused_causal_attention)
+
+                kernel = fused_causal_attention
+                if arm == "sharded_kernel":
+                    kernel = make_sharded_flash_attention(self.mesh,
+                                                          inner=kernel)
+                with jax.named_scope("attn_kernel"):
+                    return kernel(q, k, v)
+            if arm == "blockwise":
                 from ..ops.xla_flash import blockwise_attention
 
                 starts = jnp.zeros((q.shape[0],), jnp.int32)

@@ -1,9 +1,11 @@
-"""The serving programs compiled for the chip, without the chip: the TPU's
+"""The cells' programs compiled for the chip, without the chip: the TPU's
 compiler is installed here and compiles for a v5e that is described and
 not attached (``jax.experimental.topologies``).  Nothing runs; what is
 held is what the compiled decode round DOES with the slot cache, at the
 benchmark's real widths, slots and context (depth cut to keep the compile
-to seconds): every part of the cache is updated where it lies.
+to seconds): every part of the cache is updated where it lies; and that
+the compiled training step holds no ``[B, H, S, S]`` array: its attention
+is the blockwise kernel, on one chip and on a shard of the 2 x 2 mesh.
 
 All such tests live in this one file and describe the topology inside a
 fixture: only one process may load the TPU's library, and the worker that
@@ -30,10 +32,9 @@ CELLS = {"gpt2-medium": (2, 32, 1024),
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -46,9 +47,16 @@ def one_chip():
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topology.devices[0])
 
 
 @pytest.fixture(scope="module", params=sorted(CELLS))
@@ -132,3 +140,66 @@ def test_an_admission_splices_its_row_where_the_slot_lies(cell):
     assert aliased >= parts
     assert moved == []
     assert temporaries < cache_bytes / 4
+
+
+# configuration, mesh axes: the two training cells (64 x 1,024 tokens a step)
+STEPS = {"one-chip": ("gpt2-medium", {}),
+         "fsdp2-tensor2": ("gpt2-large", {"fsdp": 2, "tensor": 2})}
+
+
+@pytest.mark.parametrize("layout", sorted(STEPS))
+def test_the_training_step_holds_no_score_tensor(topology, monkeypatch,
+                                                 layout):
+    """``jit(step)`` of the cell's widths (2 layers, 64 x 1,024, scan +
+    remat, Adam) compiled for the described v5e: with the default rule its
+    attention is four kernel calls (forward, rematerialised forward, dQ,
+    dK/dV) and no array of a shard's ``[B, H, S, S]`` exists in any dtype;
+    with the rule forced to the einsum, the same search finds them."""
+    from parameter_server_distributed_tpu.config import MeshConfig
+    from parameter_server_distributed_tpu.models import transformer
+    from parameter_server_distributed_tpu.ops.pallas import fused_attention
+    from parameter_server_distributed_tpu.parallel.mesh import (
+        batch_sharding, build_mesh)
+    from parameter_server_distributed_tpu.parallel.train_step import (
+        TrainState, make_optimizer, make_train_step, state_shardings)
+
+    name, axes = STEPS[layout]
+    batch, seq = 64, 1024
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           name + ".json")) as handle:
+        config = json.load(handle)
+    family = families.of(config)
+    model = family.model(config, n_layers=2)
+    config_axes = MeshConfig(**axes)
+    mesh = build_mesh(config_axes,
+                      devices=topology.devices[:config_axes.num_devices])
+    model.mesh = mesh
+    optimizer = make_optimizer("adam", 3e-4)
+    state = jax.eval_shape(
+        lambda p: TrainState.create(p, optimizer),
+        jax.eval_shape(lambda: family.make_weights(model, 1)))
+    shardings = state_shardings(state, mesh,
+                                transformer.transformer_rule(mesh))
+    placed = jax.tree.map(
+        lambda x, sharding: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                 sharding=sharding),
+        state, shardings)
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                                  sharding=batch_sharding(mesh))
+    scores = re.compile(r"\w+\[%d,%d,%d,%d\]" % (
+        batch // config_axes.fsdp, model.config.n_heads // config_axes.tensor,
+        seq, seq))
+    # the chip is described, not attached: the backend here is the CPU, so
+    # the test answers the rule's one question about the backend itself
+    monkeypatch.setattr(fused_attention, "interpret_mode", lambda *_: False)
+    found = {}
+    for on_tpu in (True, False):
+        monkeypatch.setattr(transformer, "_kernel_backend", lambda: on_tpu)
+        text = jax.jit(
+            make_train_step(model.loss, optimizer),
+            in_shardings=(shardings, batch_sharding(mesh)),
+            donate_argnums=0).lower(placed, tokens).compile().as_text()
+        found[on_tpu] = (len(scores.findall(text)),
+                         text.count('custom_call_target="tpu_custom_call"'))
+    assert found[True] == (0, 4)
+    assert found[False][0] > 0 and found[False][1] == 0

@@ -74,35 +74,71 @@ class KVCache:
     that a round reads and writes a layer where it lies (an index into a
     stacked array is an operation, and a copy of the layer).
 
-    ``k``/``v`` hold the layers stored BY POSITION (index j is position
-    j), in layer order: every full-attention layer, each [B, max_len,
-    KV / pack, pack * D] with ``pack`` = :func:`heads_per_row` heads side
-    by side in a row.  ``wk``/``wv`` hold the window layers as RINGS of W
-    positions (position p lives at index p % W) however long the context,
-    each [B, W, KV / pack, pack * D]; empty where the model has no window
-    layer or the window is no shorter than ``max_len``.  ``ring_layers``
-    names the layers kept as rings (static).  ``length`` is the number of
-    valid positions (a traced scalar so decode never retraces)."""
+    Four kinds of part.  ``k``/``v`` hold the layers stored BY POSITION
+    (index j is position j), in layer order: every full-attention and
+    every sparse layer.  A full layer's part is [B, max_len, KV / pack,
+    pack * D] with ``pack`` = :func:`heads_per_row` heads side by side in
+    a row; a SPARSE layer's lies BY HEAD, [B, KV, max_len, D]: its round
+    gathers whole blocks of one head (``by_head`` says which parts).
+    ``wk``/``wv`` hold the window layers as RINGS of W positions (position
+    p lives at index p % W) however long the context, each
+    [B, W, KV / pack, pack * D]; empty where the model has no window layer
+    or the window is no shorter than ``max_len``.  ``ck`` holds a sparse
+    layer's COMPRESSED KEYS beside its K/V (index i the mean of positions
+    stride i .. stride i + kernel - 1, written once those are complete),
+    each [B, KV, max_len / stride, D].  ``state`` holds a
+    linear layer's recurrent STATE, [B, H, D, D] float32, which does not
+    grow with the context and cannot be rolled back.  ``ring_layers``,
+    ``sparse_layers`` and ``linear_layers`` name the layers of each kind
+    (static).  ``length`` is the number of valid positions (a traced
+    scalar so decode never retraces)."""
     k: tuple
     v: tuple
     length: Array
     wk: tuple = ()
     wv: tuple = ()
+    ck: tuple = ()
+    state: tuple = ()
     ring_layers: tuple = dataclasses.field(
+        default=(), metadata=dict(static=True))
+    sparse_layers: tuple = dataclasses.field(
+        default=(), metadata=dict(static=True))
+    linear_layers: tuple = dataclasses.field(
         default=(), metadata=dict(static=True))
     max_len: int = dataclasses.field(default=0, metadata=dict(static=True))
     # the fields that hold a part per layer
-    PARTS: ClassVar[tuple] = ("k", "v", "wk", "wv")
+    PARTS: ClassVar[tuple] = ("k", "v", "wk", "wv", "ck", "state")
 
     def place(self, layer: int) -> tuple[bool, int]:
-        """(kept as a ring?, index within its part) of a layer."""
+        """(kept as a ring?, index within its part) of a layer that keeps
+        K/V (a linear layer keeps none: ``linear_layers`` places it)."""
         if layer in self.ring_layers:
             return True, self.ring_layers.index(layer)
-        return False, layer - sum(1 for r in self.ring_layers if r < layer)
+        return False, layer - sum(
+            1 for r in self.ring_layers + self.linear_layers if r < layer)
+
+    def by_head(self, index: int) -> bool:
+        """Whether ``k[index]`` / ``v[index]`` is a sparse layer's."""
+        return index in {self.place(layer)[1] for layer in self.sparse_layers}
 
     def nbytes_by_kind(self) -> dict[str, int]:
-        return {"full": sum(int(x.nbytes) for x in self.k + self.v),
-                "window": sum(int(x.nbytes) for x in self.wk + self.wv)}
+        """Bytes by kind of part; compressed keys count under ``full``
+        (they lie by position beside the K/V they summarise)."""
+        return {"full": sum(int(x.nbytes) for x in self.k + self.v + self.ck),
+                "window": sum(int(x.nbytes) for x in self.wk + self.wv),
+                "state": sum(int(x.nbytes) for x in self.state)}
+
+
+def heads_major(x: Array, kv_heads: int) -> Array:
+    """K or V with positions then (packed) heads, [..., S, KV / pack,
+    pack * D], as a sparse layer's part holds it: [..., KV, S, D]."""
+    by_head = x.reshape(x.shape[:-2] + (kv_heads, -1))
+    return jnp.swapaxes(by_head, -3, -2)
+
+
+def positions_major(x: Array, pack: int) -> Array:
+    """:func:`heads_major` undone: [..., KV, S, D] as a row holds it."""
+    return pack_heads(jnp.swapaxes(x, -3, -2), pack)
 
 
 def ring_layers_of(model: Transformer, max_len: int) -> tuple[int, ...]:
@@ -159,6 +195,7 @@ def init_cache(model: Transformer, batch: int, max_len: int,
             f"cache_dtype must be 'native' or 'int8', got {cache_dtype!r}")
     rings = ring_layers_of(model, max_len)
     pack = heads_per_row(c.kv_heads, c.head_dim)
+    sparse, linear = c.layers_of("sparse"), c.layers_of("linear")
 
     def parts(count: int, positions: int, dtype) -> tuple:
         # GQA: the cache stores kv_heads (< n_heads) — n_heads/kv_heads x
@@ -169,10 +206,10 @@ def init_cache(model: Transformer, batch: int, max_len: int,
 
     length = jnp.zeros((), jnp.int32)
     if cache_dtype == "int8":
-        if rings:
+        if rings or sparse or linear:
             raise ValueError("the int8 cache stores every layer by "
-                             "position; a model with window layers takes "
-                             "the native cache")
+                             "position; a model with window, sparse or "
+                             "linear layers takes the native cache")
 
         def scales() -> tuple:
             return tuple(jnp.ones((batch, max_len, c.kv_heads), jnp.float32)
@@ -183,13 +220,31 @@ def init_cache(model: Transformer, batch: int, max_len: int,
             v=parts(c.n_layers, max_len, jnp.int8),
             k_scale=scales(), v_scale=scales(), length=length,
             max_len=max_len)
-    by_position = c.n_layers - len(rings)
     window = c.layer_spec(rings[0]).window if rings else 0
+    if rings and (sparse or linear):
+        raise ValueError("rings beside sparse or linear layers: a row "
+                         "holds every layer that keeps K/V, in layer order, "
+                         "and one kind of part beside them")
+    if sparse and max_len % c.sparse.block:
+        raise ValueError(f"a cache of {max_len} positions does not divide "
+                         f"into a sparse layer's blocks of {c.sparse.block}")
+
+    def stored() -> tuple:
+        """k or v: a sparse layer's part by head, every other as packed"""
+        return tuple(
+            jnp.zeros((batch, c.kv_heads, max_len, c.head_dim), c.dtype)
+            if i in sparse else parts(1, max_len, c.dtype)[0]
+            for i in range(c.n_layers) if i not in rings + linear)
+
     return KVCache(
-        k=parts(by_position, max_len, c.dtype),
-        v=parts(by_position, max_len, c.dtype), length=length,
+        k=stored(), v=stored(), length=length,
         wk=parts(len(rings), window, c.dtype),
-        wv=parts(len(rings), window, c.dtype), ring_layers=rings,
+        wv=parts(len(rings), window, c.dtype),
+        ck=tuple(jnp.zeros((batch, c.kv_heads, max_len // c.sparse.stride,
+                            c.head_dim), c.dtype) for _ in sparse),
+        state=tuple(jnp.zeros((batch, c.n_heads, c.head_dim, c.head_dim),
+                              jnp.float32) for _ in linear),
+        ring_layers=rings, sparse_layers=sparse, linear_layers=linear,
         max_len=max_len)
 
 
@@ -226,6 +281,16 @@ def _seeded(part: Array, block: Array) -> Array:
                                         (0,) * part.ndim)
 
 
+def check_rolls_back(model: Transformer) -> None:
+    """Speculative decoding rolls rejected positions back by moving the
+    cache's length; a linear layer's state has no length to move."""
+    if model.config.layers_of("linear"):
+        raise ValueError(
+            "speculative decoding rolls rejected positions back, and a "
+            "linear layer's recurrent state cannot be rolled back: decode "
+            "a model with linear layers without a draft")
+
+
 def check_position_budget(model: Transformer, prompt_len: int,
                           max_new_tokens: int) -> None:
     """Learned-position models have a hard position ceiling (the embed/pos
@@ -248,12 +313,27 @@ def prefill(model: Transformer, params: Mapping[str, Array], tokens: Array,
     batch, prompt_len = tokens.shape
     if prompt_len > max_len:
         raise ValueError(f"prompt {prompt_len} exceeds cache {max_len}")
-    logits, kvs = model.apply_collect_kv(params, tokens)
+    logits, kept = model.apply_collect_kv(params, tokens)
     cache = init_cache(model, batch, max_len, cache_dtype)
     c = model.config
     pack = heads_per_row(c.kv_heads, c.head_dim)
     length = jnp.asarray(prompt_len, jnp.int32)
-    if isinstance(cache, QuantKVCache):
+    linear = getattr(cache, "linear_layers", ())
+    kvs = [kv for i, kv in enumerate(kept) if i not in linear]
+    if linear or getattr(cache, "sparse_layers", ()):
+        from ..ops.sparse_attention import compress_keys
+
+        def stored(x, i):
+            return (x.transpose(0, 2, 1, 3) if cache.by_head(i)
+                    else pack_heads(x, pack))
+
+        # (k, v, no rings, the sparse layers' compressed keys, the states)
+        fresh = ([stored(k, i) for i, (k, _) in enumerate(kvs)],
+                 [stored(v, i) for i, (_, v) in enumerate(kvs)], (), (),
+                 [compress_keys(kvs[cache.place(i)[1]][0].transpose(
+                     0, 2, 1, 3), c.sparse) for i in cache.sparse_layers],
+                 [kept[i] for i in linear])
+    elif isinstance(cache, QuantKVCache):
         k, ks = zip(*(_kv_quantize(k) for k, _ in kvs))
         v, vs = zip(*(_kv_quantize(v) for _, v in kvs))
         fresh = ([pack_heads(x, pack) for x in k],
@@ -278,6 +358,8 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                  lengths: Array | None = None,
                  counts: Array | None = None,
                  route_stats: list | None = None,
+                 sparse_stats: list | None = None,
+                 only: Array | None = None,
                  ) -> tuple[Array, KVCache | QuantKVCache]:
     """Forward a block of ``tokens`` [B, T] against the cache at positions
     length..length+T-1, causally masked within the block — the verify
@@ -303,6 +385,18 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
     written.  A window layer stored by position (an extension against a
     cached row) takes the window as a mask.  ``route_stats``, where
     given, gains each ``experts`` layer's tokens per expert.
+
+    A LINEAR layer reads and advances its state (``counts`` keeps pads
+    out of it, and like a ring it cannot be rolled back).  A SPARSE layer
+    writes its K/V by position like a full one and, in a cache that
+    reaches ``dense_len``, keeps its compressed keys up to date and
+    attends a selection of key blocks: a single token a row gathers them
+    (rows under ``dense_len`` their whole context, in the same program), a
+    longer block takes the selection as a mask.  ``sparse_stats``, where
+    given, gains each such single-token layer's [positions attended,
+    kernels scored] over the rows.  ``only`` [B] asks for the logits of
+    one token of each row's block ([B, 1, vocab]): the head over a long
+    block's every token is gigabytes nobody reads.
     """
     c = model.config
     batch, t = tokens.shape
@@ -350,20 +444,71 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
         return jax.lax.dynamic_update_slice(
             part, block, (0, pos) + (0,) * (part.ndim - 2))
 
+    def written_by_head(part: Array, block: Array) -> Array:
+        """The same for a sparse layer's part [B, KV, M, D] and a block
+        [B, T, KV, D]."""
+        block = block.astype(part.dtype)
+        if ragged:
+            # a row of D lanes an index (slot, head, position): windows of
+            # [KV, D] make the compiler turn the part around for the write
+            return part.at[bidx[:, :, None],
+                           jnp.arange(part.shape[1])[None, None, :],
+                           positions[:, :, None]].set(block, mode="drop")
+        return jax.lax.dynamic_update_slice(
+            part, block.transpose(0, 2, 1, 3), (0, 0, pos, 0))
+
     # a part per layer, each replaced by its written self as its layer runs
     parts = {name: list(getattr(cache, name)) for name in cache.PARTS}
+
+    def stored_attention(q, keys, values, spec, i) -> Array:
+        """q against a layer's K/V stored by position, the block written."""
+        with jax.named_scope("cache_attn"), jax.named_scope("attn"), \
+                jax.named_scope("window" if spec.window else "full"):
+            if (not quant and t >= _BLOCKWISE_QUERIES
+                    and cache.max_len >= model.BLOCKWISE_FROM):
+                from ..ops.xla_flash import blockwise_attention
+
+                by_head = keys.shape[:2] + (c.kv_heads, c.head_dim)
+                return blockwise_attention(
+                    q, keys.reshape(by_head), values.reshape(by_head),
+                    positions[:, 0], window=spec.window)
+            if spec.window not in masks:
+                masks[spec.window] = position_mask(spec.window)
+            return _dense_cache_attention(
+                c, q, keys, values, masks[spec.window],
+                (parts["k_scale"][i], parts["v_scale"][i])
+                if quant else None)
+
     for layer in range(c.n_layers):
         # layer_view resolves either param layout (unrolled layer<i>/* or
         # scan_layers' stacked blocks/*)
         lp, p = model.layer_view(params, layer)
         spec = c.layer_spec(layer)
-        ring, i = cache.place(layer)
         router = model.pre_attention_router(lp, p, spec, h)
         q, k, v = model.qkv(lp, p, h, positions, spec)  # k/v: [B, T, KV, D]
-        if ring:
+        # where the layer's part lies among its kind's
+        ring, i = ((False, cache.linear_layers.index(layer))
+                   if spec.mixer == "linear" else cache.place(layer))
+        if spec.mixer == "linear":
+            from ..ops.linear_attention import linear_attention
+
+            with jax.named_scope("cache_attn"), jax.named_scope("attn"), \
+                    jax.named_scope("linear"):
+                attn, parts["state"][i] = linear_attention(
+                    q, k, v, parts["state"][i], counts, model.LINEAR_CHUNK)
+                attn = (attn * c.head_dim ** -0.5).astype(c.dtype)
+        elif ring:
             attn, parts["wk"][i], parts["wv"][i] = _ring_attention(
                 c, q, pack_heads(k, pack), pack_heads(v, pack),
                 parts["wk"][i], parts["wv"][i], positions, counts)
+        elif spec.mixer == "sparse":
+            j = cache.sparse_layers.index(layer)
+            with jax.named_scope("cache_update"):
+                keys = parts["k"][i] = written_by_head(parts["k"][i], k)
+                values = parts["v"][i] = written_by_head(parts["v"][i], v)
+            attn, parts["ck"][j] = _sparse_cache_attention(
+                c, q, keys, values, parts["ck"][j], positions[:, 0],
+                sparse_stats)
         else:
             with jax.named_scope("cache_update"):
                 if quant:
@@ -375,24 +520,8 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                                                pack_heads(k, pack))
                 values = parts["v"][i] = written(parts["v"][i],
                                                  pack_heads(v, pack))
-            with jax.named_scope("cache_attn"), jax.named_scope("attn"), \
-                    jax.named_scope("window" if spec.window else "full"):
-                if (not quant and t >= _BLOCKWISE_QUERIES
-                        and cache.max_len >= model.BLOCKWISE_FROM):
-                    from ..ops.xla_flash import blockwise_attention
-
-                    by_head = keys.shape[:2] + (c.kv_heads, c.head_dim)
-                    attn = blockwise_attention(
-                        q, keys.reshape(by_head), values.reshape(by_head),
-                        positions[:, 0], window=spec.window)
-                else:
-                    if spec.window not in masks:
-                        masks[spec.window] = position_mask(spec.window)
-                    attn = _dense_cache_attention(
-                        c, q, keys, values, masks[spec.window],
-                        (parts["k_scale"][i], parts["v_scale"][i])
-                        if quant else None)
-        h = model.attn_residual(lp, p, h, attn)
+            attn = stored_attention(q, keys, values, spec, i)
+        h = model.attn_residual(lp, p, h, attn, spec)
         # the FFN's weights viewed where they are used, as ever (under
         # scan_layers a view is slices, and their place in the program is
         # part of what the compiler is handed)
@@ -401,10 +530,54 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
         h, _ = model.ffn_residual(lp, p, spec, h, decode=True,
                                   router_logits=router,
                                   route_stats=route_stats)
+    if only is not None:
+        h = jnp.take_along_axis(h, only[:, None, None], axis=1)
     logits = model.final_logits(params, h)
     return logits, dataclasses.replace(
         cache, length=cache.length if ragged else pos + t,
         **{name: tuple(layers) for name, layers in parts.items()})
+
+
+def _sparse_cache_attention(c, q: Array, keys: Array, values: Array,
+                            ck: Array, first: Array,
+                            sparse_stats: list | None
+                            ) -> tuple[Array, Array]:
+    """A sparse layer against its parts of the cache.  q [B, T, H, D] at
+    positions first[b] .. first[b] + T - 1; keys/values [B, KV, M, D] with
+    the block already written; ck [B, KV, M / stride, D] the compressed
+    keys.  One token a row: the compressed key the token completed, if
+    any, is appended and the row gathers its blocks.  A longer block:
+    every compressed key is made anew from the keys (one read of the part;
+    a pad's place is overwritten with the key that replaces it) and the
+    selection is a mask.  A cache shorter than ``dense_len`` keeps no
+    compressed keys up to date: nothing reads them.  Returns (attn
+    [B, T, H, D], ck)."""
+    from ..ops import sparse_attention as sa
+
+    spec = c.sparse
+    batch, t = q.shape[:2]
+    selects = keys.shape[2] >= spec.dense_len
+    if t == 1:
+        if selects:
+            with jax.named_scope("cache_update"):
+                index, key = sa.completed_key(keys, first + 1, spec)
+                ck = ck.at[jnp.arange(batch)[:, None],
+                           jnp.arange(ck.shape[1])[None, :],
+                           index[:, None]].set(key.astype(ck.dtype),
+                                               mode="drop")
+        with jax.named_scope("cache_attn"):
+            attn, counted = sa.sparse_decode_attention(q, keys, values, ck,
+                                                       first, spec)
+        if sparse_stats is not None:
+            sparse_stats.append(counted)
+        return attn, ck
+    with jax.named_scope("cache_attn"):
+        if selects:
+            with jax.named_scope("attn"), jax.named_scope("sparse"), \
+                    jax.named_scope("select"):
+                ck = sa.compress_keys(keys, spec)
+        attn = sa.sparse_blockwise_attention(q, keys, values, ck, first, spec)
+    return attn, ck
 
 
 def _cache_scores(c, q: Array, keys: Array) -> Array:
@@ -838,6 +1011,8 @@ def speculative_generate(target: Transformer, target_params,
     s = prompt.shape[1]
     # + draft_len + 1: a verify block may run past the committed length
     # before rolling back
+    check_rolls_back(target)
+    check_rolls_back(draft)
     check_position_budget(target, s, max_new_tokens + draft_len + 1)
     check_position_budget(draft, s, max_new_tokens + draft_len + 1)
     sampling = temperature > 0.0
@@ -1598,6 +1773,8 @@ def speculative_generate_batched(
     # + draft_len: the last verify round may write a full draft block
     # before the loop notices every row is done (active lanes only —
     # finished rows clip into discarded slack)
+    check_rolls_back(target)
+    check_rolls_back(draft)
     check_position_budget(target, prompt_len, max_new_tokens + draft_len)
     check_position_budget(draft, prompt_len, max_new_tokens + draft_len)
     if adaptive:

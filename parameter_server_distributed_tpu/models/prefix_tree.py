@@ -40,6 +40,17 @@ forward masks positions ``>= L`` (ragged decode_block) and overwrites
 harmless.  One physical row can therefore back several nodes; byte
 accounting is per unique handle via refcounts.
 
+Where inheritance ENDS: a model with recurrent layers (a linear layer's
+decayed state) puts a SNAPSHOT of their states into its row, taken at the
+row's END, and a state cannot be cut back to an earlier depth as K/V can.
+Such a row says at which depth its snapshot holds (``RowRef.state_at``),
+and a tree built with ``snapshots=True`` answers a lookup with the deepest
+matched NODE END whose own row holds a snapshot there, never a depth
+inside an edge: a split node still shares its descendant's row (the K/V
+are good), but that row's snapshot lies deeper than the node, so the node
+supports no match until a prompt that ends there is admitted with a row of
+its own.  Rows' bytes count their snapshots (the caller sizes a row).
+
 Thread model: mutation is single-threaded (the decode loop is the only
 thread that touches a DecodeServer); cross-thread readers (the
 heartbeat loop) read only :attr:`PrefixTree.fingerprint`, an immutable
@@ -124,14 +135,16 @@ def overlap_blocks(prompt_hashes, fp: frozenset) -> int:
 class RowRef:
     """One physical cached row (opaque device payload) shared by one or
     more tree nodes; ``nbytes`` is charged to the tree's budget once,
-    while ``refs`` nodes point at it."""
+    while ``refs`` nodes point at it.  ``state_at`` is the depth at which
+    the row's snapshot of recurrent states holds (None: it carries none)."""
 
-    __slots__ = ("row", "nbytes", "refs")
+    __slots__ = ("row", "nbytes", "refs", "state_at")
 
-    def __init__(self, row: Any, nbytes: int):
+    def __init__(self, row: Any, nbytes: int, state_at: int | None = None):
         self.row = row
         self.nbytes = int(nbytes)
         self.refs = 0
+        self.state_at = state_at
 
 
 class RadixNode:
@@ -170,8 +183,11 @@ class PrefixTree:
     leaves, tails first (path-compressing parents left with a single
     child and no complete-prompt payload)."""
 
-    def __init__(self, budget_bytes: int):
+    def __init__(self, budget_bytes: int, snapshots: bool = False):
         self.budget_bytes = int(budget_bytes)
+        # rows carry snapshots of recurrent states: a match is a node's
+        # end whose own row holds one there
+        self.snapshots = snapshots
         self.root = RadixNode((), None)
         self.bytes = 0          # unique handle bytes currently pinned
         self._tick = 0
@@ -204,9 +220,24 @@ class PrefixTree:
         walk ended ``matched - node.parent.depth`` tokens INTO an edge,
         the partially-entered child (``partial=True``; its handle's
         first ``matched`` positions are still the prefix K/V, which is
-        the whole point of a token-level tree)."""
+        the whole point of a token-level tree).  A tree of ``snapshots``
+        returns the deepest node END on the walk whose row holds a
+        snapshot at that depth (``partial=False`` always; the root and 0
+        where there is none)."""
         if not isinstance(tokens, tuple):
             tokens = tuple(int(t) for t in tokens)
+        if self.snapshots:
+            node, matched, _ = self._walk_down(tokens)
+            if matched < node.depth:        # ended inside node's edge
+                node = node.parent
+            while node is not self.root and (
+                    node.handle is None
+                    or node.handle.state_at != node.depth):
+                node = node.parent
+            return node, node.depth, False
+        return self._walk_down(tokens)
+
+    def _walk_down(self, tokens: tuple) -> tuple[RadixNode, int, bool]:
         node = self.root
         matched = 0
         n = len(tokens)
@@ -252,15 +283,19 @@ class PrefixTree:
         LRU pass."""
         if not isinstance(tokens, tuple):
             tokens = tuple(int(t) for t in tokens)
-        node, matched, partial = self.lookup(tokens)
+        node, matched, partial = self._walk_down(tokens)
         if partial:
             node = self._split(node, matched - node.parent.depth)
         if matched == len(tokens):
             # existing path re-admitted as a complete prompt (an interior
             # split node, or a k==0-era node gaining its draft row)
             node.last = last
-            if node.handle is None:
+            if node.handle is None or (
+                    handle.state_at == node.depth != node.handle.state_at):
+                # (a split node's inherited row holds its snapshot deeper
+                # down: the row that ends here takes its place)
                 self._incref(handle)
+                self._decref(node.handle)
                 node.handle = handle
             if node.dhandle is None and dhandle is not None:
                 self._incref(dhandle)

@@ -47,7 +47,9 @@ from ..obs import stats as obs_stats
 from ..obs import trace as obs_trace
 from .generation import (KVCache, QuantKVCache, _cached_runner,
                          _kv_quantize, _model_key, _spec_round_runner,
-                         check_position_budget, decode_block, heads_per_row,
+                         check_position_budget, check_rolls_back,
+                         decode_block, heads_major, heads_per_row,
+                         positions_major,
                          init_cache, pack_heads, ring_layers_of, sample_token,
                          sample_token_rowwise, split_row)
 from .prefix_tree import PrefixTree, RowRef
@@ -145,11 +147,14 @@ def _shard_cache(cache, mesh):
 
 def _prefill_runner(model: Transformer, bucket: int, cache_dtype: str):
     """Jitted per (model, prompt bucket): forward the padded prompt, return
-    the last REAL position's logits, the prompt's K/V ROW (every layer by
-    position, heads side by side as the cache's parts hold them:
-    [L, S', KV / pack, pack * D]; quantized already when the slot cache is
-    int8, so splicing is dtype-pure) and the tokens per expert of every
-    experts layer ([L * E], else None)."""
+    the last REAL position's logits, the prompt's ROW and the tokens per
+    expert of every experts layer ([L * E], else None).  A row is (k, v):
+    every layer that keeps K/V by position, heads side by side as the
+    cache's parts hold them, [L, S', KV / pack, pack * D] (quantized
+    already when the slot cache is int8, so splicing is dtype-pure); for a
+    model with linear layers (k, v, state), the third their recurrent
+    states after the last real position, [L_linear, H, D, D] float32: a
+    SNAPSHOT, good at that depth and no other."""
     key = (_model_key(model), "serve_prefill", bucket, cache_dtype)
 
     def build():
@@ -158,16 +163,24 @@ def _prefill_runner(model: Transformer, bucket: int, cache_dtype: str):
             # the head runs on the last REAL position alone: the logits
             # of a whole long row would be gigabytes
             routed: list = []
-            h, kvs, _ = model._forward(params, padded, collect_kv=True,
-                                       route_stats=routed)
+            h, kept, _ = model._forward(params, padded, collect_kv=True,
+                                        route_stats=routed,
+                                        counts=real_len[None])
             loads = jnp.concatenate(routed) if routed else None
             last = model.final_logits(
                 params, jax.lax.dynamic_slice_in_dim(
                     h, real_len - 1, 1, axis=1))[0, 0]      # [vocab]
             c = model.config
             pack = heads_per_row(c.kv_heads, c.head_dim)
+            linear = c.layers_of("linear")
+            kvs = [kv for i, kv in enumerate(kept) if i not in linear]
             k = jnp.stack([k for k, _ in kvs])[:, 0]        # [L, S', H, D]
             v = jnp.stack([v for _, v in kvs])[:, 0]
+            if linear:
+                # the snapshot: every linear layer's state after the last
+                # real position
+                return last, (pack_heads(k, pack), pack_heads(v, pack),
+                              jnp.stack([kept[i][0] for i in linear])), loads
             if cache_dtype == "int8":
                 k, ks = _kv_quantize(k)
                 v, vs = _kv_quantize(v)
@@ -200,7 +213,23 @@ def _splice_runner(model: Transformer, bucket: int, cache_dtype: str):
                     (slot,) + (0,) * (part.ndim - 1))
 
             if cache_dtype != "int8":
-                row = split_row(cache, *row, length)
+                k, v, *state = row
+                row = split_row(cache, k, v, length)
+                if cache.sparse_layers or cache.linear_layers:
+                    from ..ops.sparse_attention import compress_keys
+
+                    # a sparse layer's K/V go in by head; its compressed
+                    # keys are made from its keys (those past ``length``
+                    # are not complete, and the rounds that complete them
+                    # write them)
+                    kv_heads = model.config.kv_heads
+                    k, v = (tuple(
+                        heads_major(layer, kv_heads) if cache.by_head(i)
+                        else layer for i, layer in enumerate(layers))
+                        for layers in row[:2])
+                    row = (k, v, (), (), tuple(compress_keys(
+                        k[cache.place(i)[1]], model.config.sparse, axis=1)
+                        for i in cache.sparse_layers), *state)
             return dataclasses.replace(cache, **{
                 name: tuple(map(put, getattr(cache, name), layers))
                 for name, layers in zip(cache.PARTS, row)})
@@ -226,17 +255,43 @@ def _row_cache(model: Transformer, row, total: int, cache_dtype: str):
             k_scale=tuple(part(layer, 1) for layer in ks),
             v_scale=tuple(part(layer, 1) for layer in vs),
             length=length, max_len=total)
-    dtype = model.config.dtype
-    k, v = row
-    return KVCache(k=tuple(part(layer.astype(dtype)) for layer in k),
-                   v=tuple(part(layer.astype(dtype)) for layer in v),
-                   length=length, max_len=total)
+    c = model.config
+    k, v, *state = row
+    sparse, linear = c.layers_of("sparse"), c.layers_of("linear")
+    kept = [i for i in range(c.n_layers) if i not in linear]
+
+    def stored(layers) -> tuple:
+        """a sparse layer's part by head, every other as the row has it"""
+        return tuple(
+            heads_major(wide, c.kv_heads) if i in sparse else wide
+            for i, wide in zip(kept, (part(layer.astype(c.dtype))
+                                      for layer in layers)))
+
+    return KVCache(
+        k=stored(k), v=stored(v),
+        # (a block forwarded against the row makes every compressed key
+        # anew from the keys: decode_block)
+        ck=tuple(jnp.zeros((1, c.kv_heads, total // c.sparse.stride,
+                            c.head_dim), c.dtype) for _ in sparse),
+        state=tuple(layer[None] for layer in state[0]) if state else (),
+        sparse_layers=sparse, linear_layers=linear,
+        length=length, max_len=total)
 
 
 def _cache_row(cache) -> tuple:
-    """The row of a one-slot cache that stores every layer by position."""
-    return tuple(jnp.stack([part[0] for part in getattr(cache, name)])
-                 for name in cache.PARTS if getattr(cache, name))
+    """The row of a one-slot cache that stores every layer by position
+    (and the states of its linear layers, where it has any)."""
+    def layers(name):
+        held = getattr(cache, name)
+        if name not in ("k", "v") or not getattr(cache, "sparse_layers", ()):
+            return [part[0] for part in held]
+        # (a sparse layer's part lies by head: back to the row's form)
+        return [positions_major(part[0], heads_per_row(*part.shape[1::2]))
+                if cache.by_head(i) else part[0]
+                for i, part in enumerate(held)]
+
+    return tuple(jnp.stack(layers(name)) for name in cache.PARTS
+                 if name != "ck" and getattr(cache, name))
 
 
 def _extend_runner(model: Transformer, pbucket: int, sbucket: int,
@@ -263,11 +318,71 @@ def _extend_runner(model: Transformer, pbucket: int, sbucket: int,
             logits, cache = decode_block(
                 model, params, padded_suffix,
                 _row_cache(model, row, pbucket + sbucket, cache_dtype),
-                lengths=prefix_len[None], route_stats=routed)
+                lengths=prefix_len[None], counts=suffix_len[None],
+                route_stats=routed)
             loads = jnp.concatenate(routed) if routed else None
             return logits[0, suffix_len - 1], _cache_row(cache), loads
 
         return run
+
+    return _cached_runner(key, build)
+
+
+# a prompt is forwarded whole while the widest activation of its forward
+# pass, [bucket, the feed-forward width a token meets], has at most this
+# many elements (256 MB in bfloat16; the program's temporaries are several
+# of them: 2.5 GB at 12,288 x 16,384, read on the chip, PERF.md PR 32);
+# a wider one goes through _chunk_runner _PREFILL_CHUNK tokens at a time
+_PREFILL_WHOLE = 1 << 27
+_PREFILL_CHUNK = 4096
+
+
+def _prefills_whole(model: Transformer, bucket: int) -> bool:
+    """The rule of the two prefill paths, from shapes alone."""
+    c = model.config
+    routed = any(spec.ffn != "mlp" for spec in c.period)
+    widest = max(c.d_model, c.d_ff * (c.moe_top_k if routed else 1))
+    return bucket * widest <= _PREFILL_WHOLE
+
+
+def _chunk_runner(model: Transformer, total: int):
+    """Jitted per (model, row width): forward the next ``_PREFILL_CHUNK``
+    tokens of a long prompt against the one-slot cache that holds what
+    came before them (donated: the row is written where it lies).  The
+    same ragged ``decode_block`` as an extension; returns the logits of
+    the chunk's last REAL token and the cache."""
+    key = (_model_key(model), "serve_chunk", total)
+
+    def build():
+        @partial(jax.jit, donate_argnums=(2,))
+        def run(params, tokens, cache, done, real):
+            logits, cache = decode_block(
+                model, params, tokens, cache, lengths=done[None],
+                counts=real[None], only=real[None] - 1)
+            return logits[0, 0], cache
+
+        return run
+
+    return _cached_runner(key, build)
+
+
+def _empty_row_runner(model: Transformer, total: int):
+    """Jitted per (model, row width): (an empty one-slot cache of ``total``
+    positions, every layer by position, for _chunk_runner to fill; its row
+    when it is full)."""
+    key = (_model_key(model), "serve_empty_row", total)
+
+    def build():
+        c = model.config
+        pack = heads_per_row(c.kv_heads, c.head_dim)
+        linear = c.layers_of("linear")
+        kv = jnp.zeros((c.n_layers - len(linear), 16, c.kv_heads // pack,
+                        pack * c.head_dim), c.dtype)
+        state = (jnp.zeros((len(linear), c.n_heads, c.head_dim, c.head_dim),
+                           jnp.float32),) if linear else ()
+        return (jax.jit(lambda: _row_cache(model, (kv, kv, *state), total,
+                                           "native")),
+                jax.jit(_cache_row))
 
     return _cached_runner(key, build)
 
@@ -304,15 +419,20 @@ def _decode_round(model, top_k, top_p, params, tokens, cache, lengths,
     construction (same decode_block -> rng split -> rowwise sample
     sequence)."""
     routed: list = []
+    selected: list = []
     logits, cache = decode_block(model, params, tokens[:, None], cache,
-                                 lengths=lengths, route_stats=routed)
+                                 lengths=lengths, route_stats=routed,
+                                 sparse_stats=selected)
     with jax.named_scope("sample"):
         rng, sub = jax.random.split(rng)
         nxt = sample_token_rowwise(logits[:, 0], sub, temps, top_k, top_p)
     # tokens per expert of every experts layer ([L * E]) leave with the
-    # round's tokens, in the fetch the round makes anyway
-    loads = jnp.concatenate(routed) if routed else None
-    return nxt, cache, rng, loads
+    # round's tokens, in the fetch the round makes anyway; so do a model
+    # with sparse layers' [positions attended, kernels scored], summed
+    # over its layers and the slots
+    counted = (jnp.concatenate(routed) if routed else None,
+               sum(selected) if selected else None)
+    return nxt, cache, rng, counted
 
 
 def _multi_step_runner(model: Transformer, slots: int, top_k: int,
@@ -332,7 +452,7 @@ def _multi_step_runner(model: Transformer, slots: int, top_k: int,
         def run(params, tokens, cache, lengths, temps, rng):
             def body(carry, _):
                 tokens, cache, lengths, rng = carry
-                # the fused rounds keep no loads
+                # the fused rounds keep no counts
                 nxt, cache, rng, _ = _decode_round(
                     model, top_k, top_p, params, tokens, cache, lengths,
                     temps, rng)
@@ -437,10 +557,18 @@ class DecodeServer:
             params = _place_params(dict(params), mesh, self._param_rule)
         self.params = params
         self._n_swaps = 0  # live weight hot-swaps (swap_params)
+        config = model.config
+        # the layers whose state is a snapshot (good at one depth only):
+        # they decide what the prefix tree may match and what cannot be
+        # rolled back
+        self._linear_layers = len(config.layers_of("linear"))
+        self._sparse_layers = len(config.layers_of("sparse"))
+        if draft is not None:
+            check_rolls_back(model)
+            check_rolls_back(draft)
         self._cache = init_cache(model, slots, max_len, cache_dtype)
         if mesh is not None:
             self._cache = _shard_cache(self._cache, mesh)
-        config = model.config
         self._moe_layers = sum(config.layer_spec(i).ffn == "experts"
                                for i in range(config.n_layers))
         if draft is not None and (ring_layers_of(model, max_len)
@@ -486,6 +614,13 @@ class DecodeServer:
                                       "admit_experts_touched")}
         for kind, held in self._cache_bytes_by_kind().items():
             obs_stats.gauge(f"serve.cache.{kind}_bytes").set(held)
+        # what a round's sparse and linear layers read (see _count_mixers)
+        self._obs_mixers = {
+            name: obs_stats.counter(name) for name in (
+                "serve.sparse.positions_selected",
+                "serve.sparse.positions_cached",
+                "serve.sparse.kernels_scored",
+                "serve.linear.state_updates")}
         # perf_counter at the last round's return, while a slot is active
         self._round_returned: float | None = None
         # radix-tree prefix cache (ISSUE 20): token-level index over
@@ -497,7 +632,9 @@ class DecodeServer:
         budget = (int(prefix_cache_bytes) if prefix_cache_bytes is not None
                   else int(os.environ.get("PSDT_PREFIX_CACHE_BYTES",
                                           "268435456")))
-        self._prefix_tree = PrefixTree(budget) if prompt_cache else None
+        self._prefix_tree = (PrefixTree(
+            budget, snapshots=bool(self._linear_layers))
+            if prompt_cache else None)
         self._prompt_hits = 0
         # shared-PREFIX reuse: a miss whose prompt shares a cached
         # prefix forwards only the suffix (_extend_runner).  Speculative
@@ -715,6 +852,8 @@ class DecodeServer:
         plen = min(matched, real_len - 1)
         if plen <= 0 or node.handle is None:
             return None
+        if self._linear_layers and plen != matched:
+            return None  # a snapshot is good at its own depth only
         pre_row = node.handle.row
         pbucket = int(pre_row[0].shape[1])
         slen = real_len - plen
@@ -749,15 +888,35 @@ class DecodeServer:
         self._prefill_tokens += slen
         return last, row, d_row, loads
 
+    def _prefill_in_chunks(self, padded: np.ndarray, real_len: int):
+        """A long prompt (``padded`` [1, bucket]) through _chunk_runner,
+        ``_PREFILL_CHUNK`` tokens at a time against the row so far; returns
+        (the last real position's logits, the row)."""
+        bucket = padded.shape[1]
+        empty, row_of = _empty_row_runner(self.model, bucket)
+        run = _chunk_runner(self.model, bucket)
+        cache = empty()
+        for done in range(0, real_len, _PREFILL_CHUNK):
+            tokens = np.zeros((1, _PREFILL_CHUNK), np.int32)
+            chunk = padded[:, done:done + _PREFILL_CHUNK]
+            tokens[:, :chunk.shape[1]] = chunk
+            last, cache = run(
+                self.params, jnp.asarray(tokens), cache,
+                jnp.asarray(done, jnp.int32),
+                jnp.asarray(min(_PREFILL_CHUNK, real_len - done), jnp.int32))
+        return last, row_of(cache)
+
     def _admit_to_tree(self, pkey: tuple, last, row, d_row) -> None:
         """Insert an admitted prompt's rows into the radix tree (an
         edge split shares the descendant's handles — no device copy)
         and run the byte-budget LRU eviction pass."""
         tree = self._prefix_tree
         splits = tree.splits
-        node = tree.insert(pkey, last, RowRef(row, _row_nbytes(row)),
-                           RowRef(d_row, _row_nbytes(d_row))
-                           if d_row is not None else None)
+        node = tree.insert(
+            pkey, last, RowRef(row, _row_nbytes(row),
+                               state_at=len(pkey) if self._linear_layers
+                               else None),
+            RowRef(d_row, _row_nbytes(d_row)) if d_row is not None else None)
         if tree.splits != splits:
             flight.record("serve.prefix.split", a=node.depth,
                           b=tree.nodes)
@@ -878,10 +1037,14 @@ class DecodeServer:
             else:
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :real_len] = prompt
-                last, row, loads = _prefill_runner(self.model, bucket,
-                                                   self.cache_dtype)(
-                    self.params, jnp.asarray(padded),
-                    jnp.asarray(real_len, jnp.int32))
+                if (self.cache_dtype == "native"
+                        and not _prefills_whole(self.model, bucket)):
+                    last, row = self._prefill_in_chunks(padded, real_len)
+                else:
+                    last, row, loads = _prefill_runner(
+                        self.model, bucket, self.cache_dtype)(
+                        self.params, jnp.asarray(padded),
+                        jnp.asarray(real_len, jnp.int32))
                 d_row = None
                 self._prefill_tokens += real_len
                 if self.draft is not None and self._k > 0:
@@ -956,11 +1119,12 @@ class DecodeServer:
         inputs = (jnp.asarray(self._tokens), self._cache,
                   jnp.asarray(self._lengths), jnp.asarray(self._temps))
         with device:
-            nxt, self._cache, self._rng, loads = self._step(
+            nxt, self._cache, self._rng, counted = self._step(
                 self.params, *inputs, self._rng)
-            nxt, loads = jax.device_get((nxt, loads))
+            nxt, (loads, selected) = jax.device_get((nxt, counted))
         if loads is not None:
             self._count_routing(loads)
+        self._count_mixers(selected)
         emitted: list[tuple[int, int]] = []
         for i, entry in enumerate(self._slot):
             if entry is None:
@@ -1120,7 +1284,27 @@ class DecodeServer:
             return self._cache.nbytes_by_kind()
         return {"full": sum(int(leaf.nbytes) for leaf in
                             jax.tree_util.tree_leaves(self._cache)),
-                "window": 0}
+                "window": 0, "state": 0}
+
+    def _count_mixers(self, selected: np.ndarray | None) -> None:
+        """One decode round into the counters the sparse and linear
+        layers' metrics divide.  ``selected`` is the round's own
+        [positions attended, kernels scored] over its sparse layers and
+        every lane (idle ones too: the device computes them); beside it
+        the positions those lanes held (each lane's length with its new
+        token, a sparse layer each) and the states the round advanced (a
+        lane and linear layer each)."""
+        if selected is not None:
+            self._obs_mixers["serve.sparse.positions_selected"].add(
+                float(selected[0]))
+            self._obs_mixers["serve.sparse.kernels_scored"].add(
+                float(selected[1]))
+            self._obs_mixers["serve.sparse.positions_cached"].add(
+                float(self._sparse_layers
+                      * (int(self._lengths.sum()) + self.slots)))
+        if self._linear_layers:
+            self._obs_mixers["serve.linear.state_updates"].add(
+                self.slots * self._linear_layers)
 
     def _count_routing(self, loads: np.ndarray,
                        admission: bool = False) -> None:
@@ -1196,6 +1380,8 @@ class DecodeServer:
         kinds = self._cache_bytes_by_kind()
         out["cache_full_bytes"] = kinds["full"]
         out["cache_window_bytes"] = kinds["window"]
+        if kinds["state"]:
+            out["cache_state_bytes"] = kinds["state"]
         if self._moe_layers:
             out["moe_assignments"] = self._moe_assignments
         if self.draft is not None:

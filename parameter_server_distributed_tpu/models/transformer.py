@@ -43,6 +43,7 @@ Array = jax.Array
 
 
 FFN_KINDS = ("mlp", "moe", "experts")
+MIXER_KINDS = ("softmax", "sparse", "linear")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,11 +65,34 @@ class LayerSpec:
     # the selected logits, the router BEFORE attention (it reads the
     # attention's normed input)
     ffn: str = "mlp"
+    # what mixes positions.  softmax: causal attention over every earlier
+    # position (or the window's).  sparse: the same while the context is
+    # shorter than ``config.sparse.dense_len``, from there on over a
+    # SELECTION of key blocks (ops/sparse_attention.py); its cache keeps
+    # compressed keys beside K/V.  linear: a per-head decayed outer-product
+    # state (ops/linear_attention.py): no K/V by position, a fixed-size
+    # state instead
+    mixer: str = "softmax"
+    # K/V heads of this layer; 0 = the config's
+    kv_heads: int = 0
+    # RMS norm (a learned gain each) on every head of q and of k, before
+    # rotary
+    qk_norm: bool = False
+    # the attention's output times sigmoid(W_g x), x its normed input,
+    # before the output projection
+    gate: bool = False
+    # RMS norm (a learned gain) on every head of the attention's output
+    out_norm: bool = False
 
     def __post_init__(self):
         if self.ffn not in FFN_KINDS:
             raise ValueError(f"ffn must be one of {FFN_KINDS}, "
                              f"got {self.ffn!r}")
+        if self.mixer not in MIXER_KINDS:
+            raise ValueError(f"mixer must be one of {MIXER_KINDS}, "
+                             f"got {self.mixer!r}")
+        if self.mixer != "softmax" and self.window:
+            raise ValueError("a window belongs to a softmax layer")
         if self.window < 0:
             raise ValueError(f"window must be >= 0, got {self.window}")
 
@@ -150,6 +174,15 @@ class TransformerConfig:
     # LLaMA-family gated MLP (w1 = gate_proj, w3 = up_proj); reglu: the
     # same gate with relu.  An ``experts`` layer's experts take this form
     mlp_act: str = "gelu"
+    # block selection of the ``sparse`` layers (ops/sparse_attention.py
+    # SparseSpec); None where the pattern has none
+    sparse: object = None
+    # muP scalars: the embedding times ``embed_scale``, every residual
+    # branch times ``residual_scale``, the head's input times
+    # ``logit_scale``
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
 
     def __post_init__(self):
         if self.pos_emb not in ("rope", "learned"):
@@ -176,6 +209,12 @@ class TransformerConfig:
         if self.pattern and self.moe_every:
             raise ValueError("give the layers as a pattern or by "
                              "moe_every, not both")
+        mixers = {spec.mixer for spec in self.pattern}
+        if "sparse" in mixers and self.sparse is None:
+            raise ValueError("a sparse layer needs config.sparse")
+        if mixers - {"softmax"} and self.scan_layers:
+            raise ValueError("scan_layers stacks one kind of cache part a "
+                             "layer: sparse and linear layers run unrolled")
 
     @property
     def attn_dim(self) -> int:
@@ -211,6 +250,11 @@ class TransformerConfig:
 
     def is_moe_layer(self, i: int) -> bool:
         return self.layer_spec(i).ffn == "moe"
+
+    def layers_of(self, mixer: str) -> tuple[int, ...]:
+        """The layers whose mixer is ``mixer``, in order."""
+        return tuple(i for i in range(self.n_layers)
+                     if self.layer_spec(i).mixer == mixer)
 
 
 def scoped(name: str):
@@ -493,11 +537,12 @@ class Transformer:
     def __init__(self, config: TransformerConfig,
                  attention_fn: Callable | None = None,
                  mesh: Mesh | None = None):
-        if config.n_heads % config.kv_heads:
-            raise ValueError(
-                f"n_heads={config.n_heads} must divide by "
-                f"n_kv_heads={config.kv_heads}")
         period = config.period
+        for spec in period:
+            if config.n_heads % (spec.kv_heads or config.kv_heads):
+                raise ValueError(
+                    f"n_heads={config.n_heads} must divide by "
+                    f"n_kv_heads={spec.kv_heads or config.kv_heads}")
         if config.scan_layers and any(s.ffn == "moe" for s in period):
             raise ValueError(
                 "scan_layers needs homogeneous periods whose layers carry "
@@ -557,13 +602,20 @@ class Transformer:
     def block_shapes(self, spec: LayerSpec) -> dict[str, tuple[int, ...]]:
         """The weights of one layer of kind ``spec``, by suffix."""
         c = self.config
-        kv_dim = c.kv_heads * c.head_dim
+        kv_dim = (spec.kv_heads or c.kv_heads) * c.head_dim
         block = {"ln1/scale": (c.d_model,),
                  "attn/wq": (c.d_model, c.attn_dim),
                  "attn/wk": (c.d_model, kv_dim),
                  "attn/wv": (c.d_model, kv_dim),
                  "attn/wo": (c.attn_dim, c.d_model),
                  "ln2/scale": (c.d_model,)}
+        if spec.qk_norm:
+            block.update({"attn/q_norm/scale": (c.head_dim,),
+                          "attn/k_norm/scale": (c.head_dim,)})
+        if spec.gate:
+            block["attn/wg"] = (c.d_model, c.attn_dim)
+        if spec.out_norm:
+            block["attn/o_norm/scale"] = (c.head_dim,)
         if c.norm == "layernorm":
             block["ln1/bias"] = (c.d_model,)
             block["ln2/bias"] = (c.d_model,)
@@ -723,9 +775,15 @@ class Transformer:
             q = q + params[f"{prefix}/attn/bq"].astype(jnp.float32)
             k = k + params[f"{prefix}/attn/bk"].astype(jnp.float32)
             v = v + params[f"{prefix}/attn/bv"].astype(jnp.float32)
+        kv_heads = (spec.kv_heads if spec is not None else 0) or c.kv_heads
         q = q.astype(c.dtype).reshape(batch, seq, c.n_heads, c.head_dim)
-        k = k.astype(c.dtype).reshape(batch, seq, c.kv_heads, c.head_dim)
-        v = v.astype(c.dtype).reshape(batch, seq, c.kv_heads, c.head_dim)
+        k = k.astype(c.dtype).reshape(batch, seq, kv_heads, c.head_dim)
+        v = v.astype(c.dtype).reshape(batch, seq, kv_heads, c.head_dim)
+        if spec is not None and spec.qk_norm:
+            q = rms_norm(q, params[f"{prefix}/attn/q_norm/scale"],
+                         c.norm_eps)
+            k = rms_norm(k, params[f"{prefix}/attn/k_norm/scale"],
+                         c.norm_eps)
         if c.pos_emb == "learned" or (spec is not None and not spec.rope):
             # learned positions live in the residual stream (embed/pos,
             # added at embedding time) — K/V need no positional transform;
@@ -736,16 +794,33 @@ class Transformer:
 
     @scoped("attn_out")
     def attn_residual(self, params: Mapping[str, Array], prefix: str,
-                      h: Array, attn: Array) -> Array:
-        """h + wo(attn) (+ bias).  attn: [B, S, H, D]."""
+                      h: Array, attn: Array,
+                      spec: LayerSpec | None = None) -> Array:
+        """h + wo(attn) (+ bias).  attn: [B, S, H, D].  A layer whose
+        ``spec`` has an output norm applies it per head first; one with a
+        gate multiplies by sigmoid(wg x), x the layer's normed input (the
+        norm ``qkv`` computes, which the compiler shares)."""
         c = self.config
         batch, seq = h.shape[:2]
-        out = wdot(attn.reshape(batch, seq, c.attn_dim),
-                   params[f"{prefix}/attn/wo"],
+        if spec is not None and spec.out_norm:
+            attn = rms_norm(attn, params[f"{prefix}/attn/o_norm/scale"],
+                            c.norm_eps)
+        attn = attn.reshape(batch, seq, c.attn_dim)
+        if spec is not None and spec.gate:
+            x = self._norm(params, f"{prefix}/ln1", h)
+            gate = wdot(x, params[f"{prefix}/attn/wg"],
+                        preferred_element_type=jnp.float32)
+            attn = attn * jax.nn.sigmoid(gate).astype(c.dtype)
+        out = wdot(attn, params[f"{prefix}/attn/wo"],
                    preferred_element_type=jnp.float32)
         if c.bias:
             out = out + params[f"{prefix}/attn/bo"].astype(jnp.float32)
-        return h + out.astype(c.dtype)
+        return h + self._branch(out).astype(c.dtype)
+
+    def _branch(self, out: Array) -> Array:
+        """A residual branch's output at the config's ``residual_scale``."""
+        scale = self.config.residual_scale
+        return out if scale == 1.0 else out * scale
 
     @scoped("mlp")
     def mlp_residual(self, params: Mapping[str, Array], prefix: str,
@@ -768,7 +843,7 @@ class Transformer:
         out = dot(ff, params[f"{prefix}/mlp/w2"])
         if c.bias:
             out = out + params[f"{prefix}/mlp/b2"].astype(jnp.float32)
-        return h + out.astype(c.dtype)
+        return h + self._branch(out).astype(c.dtype)
 
     def layer_view(self, params: Mapping[str, Array],
                    layer: int) -> tuple[Mapping[str, Array], str]:
@@ -908,8 +983,12 @@ class Transformer:
         blockwise in plain XLA for a long sequence, else the einsum."""
         seq = q.shape[1]
         window = spec.window if 0 < spec.window < seq else 0
-        with jax.named_scope("attn"), jax.named_scope(
-                "window" if spec.window else "full"):
+        if spec.mixer == "sparse" and seq >= self.config.sparse.dense_len:
+            raise ValueError("a sparse layer's sequence past dense_len "
+                             "goes through mix(), not attend()")
+        kind = ("window" if spec.window
+                else "sparse/attend" if spec.mixer == "sparse" else "full")
+        with jax.named_scope("attn"), jax.named_scope(kind):
             if self.attention_fn is not causal_attention:
                 if window:
                     raise ValueError(
@@ -936,9 +1015,58 @@ class Transformer:
                 return blockwise_attention(q, k, v, starts, window=window)
             return causal_attention(q, k, v, window=window)
 
+    # positions a linear layer works through at a time
+    LINEAR_CHUNK = 128
+
+    def mix(self, q: Array, k: Array, v: Array, spec: LayerSpec,
+            counts: Array | None = None, selections: list | None = None):
+        """A whole sequence through the layer's mixer: (attn [B, S, H, D],
+        what a cache keeps of the layer: its (k, v), or a linear layer's
+        state after the last real position, ``counts`` [B] of them).  A
+        sparse layer whose sequence reaches ``dense_len`` selects key
+        blocks (under ``attn/sparse``: ``select``, ``attend``) and, where
+        ``selections`` is given, adds its selection [B, KV, S, NB] to it;
+        a shorter one pays for no selection.  A linear layer runs in chunks
+        under ``attn/linear`` (``intra``, ``state``)."""
+        c = self.config
+        if spec.mixer == "linear":
+            from ..ops.linear_attention import linear_attention
+
+            with jax.named_scope("attn"), jax.named_scope("linear"):
+                out, state = linear_attention(q, k, v, counts=counts,
+                                              chunk=self.LINEAR_CHUNK)
+                return (out / math.sqrt(c.head_dim)).astype(c.dtype), state
+        if spec.mixer == "sparse" and q.shape[1] >= c.sparse.dense_len:
+            from ..ops.sparse_attention import (compress_keys,
+                                                sparse_blockwise_attention)
+
+            with jax.named_scope("attn"), jax.named_scope("sparse"), \
+                    jax.named_scope("select"):
+                k_heads, v_heads = (x.transpose(0, 2, 1, 3) for x in (k, v))
+                ck = compress_keys(k_heads, c.sparse)
+            out = sparse_blockwise_attention(
+                q, k_heads, v_heads, ck, jnp.zeros((q.shape[0],), jnp.int32),
+                c.sparse, with_mask=selections is not None)
+            if selections is not None:
+                out, chosen = out
+                selections.append(chosen)
+            return out, (k, v)
+        return self.attend(q, k, v, spec), (k, v)
+
+    def sparse_selections(self, params: Mapping[str, Array],
+                          tokens: Array) -> list:
+        """Which key blocks every query of every sparse layer attended in
+        the forward pass of ``tokens`` [B, S]: a [B, KV, S, NB] mask a
+        sparse layer, in layer order (empty where S < ``dense_len``)."""
+        chosen: list = []
+        self._forward(params, tokens, collect_kv=False, selections=chosen)
+        return chosen
+
     @scoped("head")
     def final_logits(self, params: Mapping[str, Array], h: Array) -> Array:
         h = self._norm(params, "final_ln", h)
+        if self.config.logit_scale != 1.0:
+            h = (h * self.config.logit_scale).astype(h.dtype)
         return wdot(h, params["lm_head/w"],
                     preferred_element_type=jnp.float32)
 
@@ -957,6 +1085,8 @@ class Transformer:
         loudly at the entry points (generate / DecodeServer.submit /
         speculative_generate_batched), not silently clamped here."""
         h = jnp.take(params["embed/tok"], tokens, axis=0)
+        if self.config.embed_scale != 1.0:
+            h = (h * self.config.embed_scale).astype(h.dtype)
         if self.config.pos_emb == "learned":
             h = h + jnp.take(params["embed/pos"], positions, axis=0,
                              mode="clip").astype(h.dtype)
@@ -964,7 +1094,12 @@ class Transformer:
 
     def _forward(self, params: Mapping[str, Array], tokens: Array,
                  collect_kv: bool, route_stats: list | None = None,
+                 counts: Array | None = None,
+                 selections: list | None = None,
                  ) -> tuple[Array, list, Array]:
+        """(h, what a cache keeps of every layer (see :meth:`mix`) under
+        ``collect_kv``, aux loss).  ``counts`` [B]: how many of a row's
+        tokens are real, for the layers whose state must not hold a pad."""
         c = self.config
         batch, seq = tokens.shape
         if c.pos_emb == "learned" and seq > c.max_seq:
@@ -987,14 +1122,14 @@ class Transformer:
             # K/V go to the attention fn UNexpanded (kv_heads-sized);
             # each implementation expands at the math (expand_gqa), so
             # ring/Ulysses communicate the small tensors
-            attn = self.attend(q, k, v, spec)
-            h = self.attn_residual(layer_params, p, h, attn)
+            attn, kept = self.mix(q, k, v, spec, counts, selections)
+            h = self.attn_residual(layer_params, p, h, attn, spec)
             h = self._constrain(h, ("data", "fsdp"), "seq", None)
             h, aux = self.ffn_residual(layer_params, p, spec, h,
                                        router_logits=router,
                                        route_stats=route_stats)
             h = self._constrain(h, ("data", "fsdp"), "seq", None)
-            return h, aux, (k, v)
+            return h, aux, kept
 
         if c.scan_layers:
             # one scan body traced once, holding one PERIOD of the layer

@@ -142,6 +142,47 @@ def test_an_admission_splices_its_row_where_the_slot_lies(cell):
     assert temporaries < cache_bytes / 4
 
 
+def test_minicpm_salas_round_gathers_and_updates_in_place(one_chip):
+    """``serve_longdocs_chat_minicpm_sala``'s decode round at its real
+    widths, 16 slots x 65,536 positions, three layers (sparse, linear,
+    linear): every part of the cache (K, V, compressed keys, states) is
+    updated where it lies, and nothing as large as a state part, let alone
+    a K/V part, is copied or sliced: the sparse layer gathers its 128
+    block places from the part as it is stored."""
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "minicpm-sala-12l.json")) as handle:
+        config = json.load(handle)
+    family = families.of(config)
+    model = family.model(config, remat=False, n_layers=3)
+    slots, max_len = 16, 65536
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = placed(jax.eval_shape(lambda: family.make_weights(model, 1)))
+    cache = placed(jax.eval_shape(
+        lambda: generation.init_cache(model, slots, max_len)))
+    assert [x.shape for x in cache.k] == [(16, 2, 65536, 128)]
+    assert [x.shape for x in cache.ck] == [(16, 2, 4096, 128)]
+    assert [(x.shape, x.dtype) for x in cache.state] == [
+        ((16, 32, 128, 128), jnp.float32)] * 2
+    rng = jax.tree.map(lambda x: on_chip(x.shape, x.dtype),
+                       jax.eval_shape(lambda: jax.random.key(0)))
+    compiled = serving._step_runner(model, slots, 0, 0.0, "native").lower(
+        params, on_chip((slots,), jnp.int32), cache,
+        on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32),
+        rng).compile()
+    aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
+    assert parts == 5 and aliased >= parts
+    # (_held's threshold is the smallest part: a state, 8.4M elements)
+    assert moved == []
+    assert temporaries < cache_bytes / 4
+
+
 # configuration, mesh axes: the two training cells (64 x 1,024 tokens a step)
 STEPS = {"one-chip": ("gpt2-medium", {}),
          "fsdp2-tensor2": ("gpt2-large", {"fsdp": 2, "tensor": 2})}

@@ -389,7 +389,7 @@ def test_gpt2_is_the_patterns_simplest_member():
     assert [part.shape for part in cache.k] == [(2, 16, 1, 32)] * 3
     assert cache.wk == () and cache.ring_layers == ()
     assert cache.nbytes_by_kind() == {"full": 2 * 3 * cache.k[0].nbytes,
-                                      "window": 0}
+                                      "window": 0, "state": 0}
     assert jax.tree.structure(cache).num_leaves == 2 * 3 + 1
     # moe_every still says where the capacity-dropping layers are
     moe_lm = tr.TransformerConfig(n_layers=4, moe_every=2)

@@ -225,3 +225,53 @@ def test_a_tail_as_wide_as_its_document_goes_before_the_document():
     t.budget_bytes = 350
     t.evict_over_budget()
     assert t.lookup(docs[3])[1] == 0 and t.lookup(long_turn)[1] == 58
+
+
+# ------------------------------------------- rows that carry a snapshot
+@pytest.mark.parametrize("snapshots", [False, True])
+def test_the_tree_matches_only_where_a_snapshot_lies(snapshots):
+    """A split inherits the K/V handle and not the snapshot; a tree
+    without snapshots matches into the edge as ever."""
+    tree = PrefixTree(1 << 20, snapshots=snapshots)
+
+    def row(tokens):
+        return RowRef(("row", len(tokens)), 100,
+                      state_at=len(tokens) if snapshots else None)
+
+    document = tuple(range(10))
+    tree.insert(document, "d", row(document))
+    first = document + (20, 21, 22, 23)
+    tree.insert(first, "a", row(first))
+    second = document + (20, 21, 30)
+    node, matched, partial = tree.lookup(second)
+    assert (matched, partial) == ((10, False) if snapshots else (12, True))
+    tree.insert(second, "b", row(second))
+    assert tree.splits == 1
+    split, matched, _ = tree.lookup(document + (20, 21, 99))
+    assert matched == (10 if snapshots else 12)
+    assert split.handle.row == (("row", 10) if snapshots else ("row", 14))
+    # the split node admitted as a prompt of its own takes its own row
+    own = document + (20, 21)
+    tree.insert(own, "c", row(own))
+    node, matched, partial = tree.lookup(own + (5,))
+    assert (matched, partial) == (12, False)
+    assert node.handle.row == (("row", 12) if snapshots else ("row", 14))
+    assert tree.bytes == (400 if snapshots else 300)
+
+
+def test_eviction_counts_a_rows_snapshot_and_keeps_the_documents():
+    """A row's bytes are the caller's count, snapshot included; a tail
+    under a resident document goes first, and a lookup then falls back to
+    the document's own snapshot."""
+    tree = PrefixTree(1000, snapshots=True)
+    document = tuple(range(64))
+    tree.insert(document, "d", RowRef("doc", 400 + 50, state_at=64))
+    for turn in (100, 101):
+        prompt = document + (turn, 7)
+        tree.insert(prompt, "t", RowRef("turn", 420 + 50, state_at=66))
+        tree.evict_over_budget()
+    assert tree.bytes == 920 and tree.evictions == 1
+    node, matched, partial = tree.lookup(document + (100, 7))
+    assert (node.handle.row, matched, partial) == ("doc", 64, False)
+    node, matched, _ = tree.lookup(document + (101, 7, 9))
+    assert (node.handle.row, matched) == ("turn", 66)

@@ -382,8 +382,9 @@ def main(argv: list[str] | None = None) -> int:
                     pending.append(payload)
         except queue.Empty:
             pass
-        # between admissions is the swap point: no decode round is in
-        # flight, so the next round reads the fresh weights whole
+        # between admissions is the swap point: the next round dispatched
+        # reads the fresh weights whole (the one step() left in flight
+        # ran under the old ones; its tokens come with the next step())
         maybe_swap()
         admit()
         if srv.idle:

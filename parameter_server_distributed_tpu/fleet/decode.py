@@ -366,9 +366,14 @@ class FleetDecodeServer:
     # ----------------------------------------------------------- decode loop
     def _apply_commands(self) -> None:
         """Round-boundary command point: weight swaps requested by
-        Control/auto-advance apply here, where no decode round is in
-        flight.  The outcome (applied / already current / failed and
-        why) flows back to the Control waiter through its box."""
+        Control/auto-advance apply here.  The DecodeServer runs a round
+        ahead of its step(): the round in flight was dispatched under
+        the weights that leave, so it is landed and its tokens are
+        delivered, stamped with the old version, BEFORE the swap and its
+        acknowledgement — no chunk of an older or newer version follows
+        a swap that reported success.  The outcome (applied / already
+        current / failed and why) flows back to the Control waiter
+        through its box."""
         while True:
             try:
                 kind, version, box = self._commands.get_nowait()
@@ -387,6 +392,9 @@ class FleetDecodeServer:
                     try:
                         fresh = (self._transform(store) if self._transform
                                  else store)
+                        with _DISPATCH_LOCK:
+                            landed = self.server.land()
+                        self._deliver(landed)
                         self.server.swap_params(fresh, version=version)
                         flight.record("fleet.swap", a=version,
                                       b=self.server_id)
@@ -474,20 +482,26 @@ class FleetDecodeServer:
                 emitted = self.server.step()
             if self._round_delay_s:
                 time.sleep(self._round_delay_s)
-            version = self.weight_version()
-            for rid, token in emitted:
-                stream = self._live.get(rid)
-                if stream is not None:
-                    stream.out.put(fmsg.DecodeChunk(
-                        request_id=rid, token=int(token),
-                        weight_version=version))
-            for rid in set(self.server.finished()) & set(self._live):
-                stream = self._live.pop(rid)
-                self.server.result(rid)  # tokens already streamed
-                stream.out.put(fmsg.DecodeChunk(request_id=rid, done=True,
-                                                weight_version=version))
-                stream.out.put(None)
-                self.streams_served += 1
+            self._deliver(emitted)
+
+    def _deliver(self, emitted) -> None:
+        """Stream a round's tokens, stamped with the version that decoded
+        them (the server's, which changes only with nothing in flight),
+        and close the streams of the requests that finished with it."""
+        version = self.weight_version()
+        for rid, token in emitted:
+            stream = self._live.get(rid)
+            if stream is not None:
+                stream.out.put(fmsg.DecodeChunk(
+                    request_id=rid, token=int(token),
+                    weight_version=version))
+        for rid in set(self.server.finished()) & set(self._live):
+            stream = self._live.pop(rid)
+            self.server.result(rid)  # tokens already streamed
+            stream.out.put(fmsg.DecodeChunk(request_id=rid, done=True,
+                                            weight_version=version))
+            stream.out.put(None)
+            self.streams_served += 1
 
     def _finish_drain(self) -> None:
         """Drain completed: every in-flight stream finished.  Leave the

@@ -68,6 +68,15 @@ class _Slot:
     stop: frozenset = frozenset()
 
 
+@dataclasses.dataclass
+class _Flight:
+    """A plain decode round that has been dispatched and whose tokens are
+    still on the device."""
+    out: tuple                 # (tokens [B], counted): the round's outputs
+    lanes: dict[int, _Slot]    # slot -> the request it decoded a token for
+    positions: int             # positions its lanes held, new token included
+
+
 # rows longer than this are padded to its next multiple, not to the next
 # power of two: a 12,288-token row stays 12,288 wide (a power of two would
 # make it 16,384, and with a suffix it would no longer fit a 16,384 slot)
@@ -393,7 +402,12 @@ def _step_runner(model: Transformer, slots: int,
     step over ALL slots + per-row-temperature sampling (temperatures are
     a traced [B] input, so per-request values never recompile).  Free/
     done slots decode garbage lanes that the host discards — the price
-    of a single static program."""
+    of a single static program.
+
+    A lane's token comes from ``prev``, the round before's tokens as that
+    round left them on the device, unless the host names one in ``fresh``
+    (-1: none): the round can be dispatched before the host has seen the
+    tokens it decodes from."""
     key = (_model_key(model), "serve_step", slots, top_k, top_p,
            cache_dtype)
 
@@ -401,10 +415,11 @@ def _step_runner(model: Transformer, slots: int,
         # donate the cache: without it every per-token step would copy the
         # whole K/V — doubling HBM traffic in the exact loop this server
         # exists to keep bandwidth-bound
-        @partial(jax.jit, donate_argnums=(2,))
-        def run(params, tokens, cache, lengths, temps, rng):
-            return _decode_round(model, top_k, top_p, params, tokens,
-                                 cache, lengths, temps, rng)
+        @partial(jax.jit, donate_argnums=(3,))
+        def run(params, prev, fresh, cache, lengths, temps, rng):
+            return _decode_round(model, top_k, top_p, params,
+                                 jnp.where(fresh < 0, prev, fresh), cache,
+                                 lengths, temps, rng)
 
         return run
 
@@ -577,8 +592,15 @@ class DecodeServer:
                 "speculative serving rolls rejected positions back, and a "
                 "window layer's ring cannot be rolled back: serve a model "
                 "with window layers without a draft")
+        # per lane, as the host knows them: the position the next round
+        # dispatched writes (a live lane's grows by one at every dispatch)
+        # and the newest token FETCHED; a plain round is dispatched one
+        # round ahead of the fetch (see step()), so a live lane's newest
+        # token is as a rule still on the device, in ``_last``
         self._lengths = np.zeros((slots,), np.int32)
         self._tokens = np.zeros((slots,), np.int32)
+        self._last = jnp.zeros((slots,), jnp.int32)
+        self._flight: _Flight | None = None
         self._slot: list[_Slot | None] = [None] * slots
         self._results: dict[int, list[int]] = {}
         self._next_id = 0
@@ -604,6 +626,10 @@ class DecodeServer:
         self._obs_round_device = obs_stats.histogram("serve.round_device_s")
         self._obs_round_host = obs_stats.histogram("serve.round_host_s")
         self._obs_between = obs_stats.histogram("serve.between_rounds_s")
+        # plain rounds dispatched, and those of them dispatched on their
+        # predecessor's tokens before the host had fetched them
+        self._obs_rounds = obs_stats.counter("serve.rounds")
+        self._obs_chained = obs_stats.counter("serve.rounds_chained")
         # what the experts layers routed, a round at a time (see
         # _count_routing), and the bytes held by kind of layer
         self._moe_assignments = 0
@@ -762,14 +788,25 @@ class DecodeServer:
                     version: int | None = None) -> None:
         """Hot-swap the model weights (live weight publication — a
         follower tracking a training run feeds fresh versions through
-        here, cli/serve_main.py ``--follow``).  Call BETWEEN decode
-        rounds from the serving thread: the compiled programs take the
+        here, cli/serve_main.py ``--follow``).  Call BETWEEN step()
+        calls from the serving thread: the compiled programs take the
         params as a traced input, so no retrace happens and the very
-        next round reads the new weights.  In-flight requests keep
-        their slots, KV rows, and sampling state — their already-emitted
-        tokens stand and their continuations decode under the new
-        weights, which is the point of tracking a live run (token
+        next round DISPATCHED reads the new weights.  In-flight requests
+        keep their slots, KV rows, and sampling state — their
+        already-emitted tokens stand and their continuations decode under
+        the new weights, which is the point of tracking a live run (token
         streams are uninterrupted, not retroactively recomputed).
+
+        The round step() left in flight was dispatched under the weights
+        that leave, and its tokens have not been handed out.  A caller
+        that tells its clients which version decoded each token (a swap
+        with ``version``: fleet/decode.py stamps every chunk with
+        ``params_version``) calls land() first and delivers what it
+        returns under the old version; a versioned swap with a round
+        still in flight raises, so ``params_version`` is at all times the
+        version that decoded every token step() returns.  Without
+        ``version`` the round stays in flight and the next step() returns
+        its tokens: the last ones of the old weights.
 
         The prefix cache is dropped: its prefill logits/KV rows were
         computed under the old weights, and replaying them would splice
@@ -790,6 +827,10 @@ class DecodeServer:
             raise ValueError(
                 f"published weights do not match the served model "
                 f"(name/shape drift: {sorted(drift)[:4]}...)")
+        if version is not None and self._flight is not None:
+            raise RuntimeError(
+                "a round is in flight under the weights that leave: land() "
+                "it and deliver its tokens before a versioned swap")
         if self.mesh is not None:
             params = _place_params(dict(params), self.mesh,
                                    self._param_rule)
@@ -1096,10 +1137,35 @@ class DecodeServer:
 
     # -------------------------------------------------------------- step
     def step(self) -> list[tuple[int, int]]:
-        """One device decode step over all slots (a speculative round when
-        a draft is configured — each slot may advance several tokens).
-        Returns [(request_id, token), ...] for every ACTIVE slot's newly
-        decoded token(s) (already appended to its result)."""
+        """One decode round's tokens (a speculative round's when a draft is
+        configured — each slot may then advance several tokens).  Returns
+        [(request_id, token), ...]: the newly decoded token(s) of every
+        ACTIVE slot (already appended to its result).
+
+        A plain round runs ONE ROUND AHEAD of the host: this call first
+        dispatches the round after the one it returns, on that round's
+        tokens where they lie on the device, and only then fetches.  The
+        fetch, the bookkeeping and the caller's loop overlap the next
+        round.  What a caller may assume:
+
+        - every call returns one token for every slot that was active
+          when the call before returned; the first call after the server
+          was idle dispatches two rounds and fetches the first.  A request
+          admitted since the last call joins the round this call
+          dispatches: its next token comes with the NEXT call (that round
+          could not start before the one in flight ended anyway);
+        - a slot is freed, and ``idle`` / ``has_free_slot`` / finished()
+          change, when the finishing token is FETCHED.  A request that
+          ends on ``eos_id`` / ``stop`` has by then decoded one token
+          more, which is discarded; an end by ``max_new_tokens`` is known
+          ahead, and a round no request has budget for is not dispatched;
+        - land() fetches the round in flight and returns its tokens, so
+          that nothing is in flight: step_many() does it first and
+          returns those tokens with its own, and a caller that stamps
+          tokens with ``params_version`` does it before swap_params();
+        - a sampled request draws from the key of the round after the one
+          in flight at its admission: reproducible for one seed and one
+          sequence of calls, and not the serial order's draw."""
         if self.idle:
             return []
         with self._round() as device:
@@ -1112,33 +1178,76 @@ class DecodeServer:
                 # submit() re-probes at the next idle admission boundary
                 # (see _maybe_rearm_speculation).
                 return self._spec_step(device)
-            return self._plain_step(device)
+            self._plain_rounds += 1
+            flight = self._flight
+            if flight is None or not any(
+                    self._slot[i] is entry
+                    for i, entry in flight.lanes.items()):
+                # nothing in flight that a live request waits for (the
+                # server was idle, or all were admitted since): this
+                # call's round first
+                flight = self._dispatch(None)
+            self._flight = self._dispatch(flight)
+            return self._land(flight, device)
 
-    def _plain_step(self, device) -> list[tuple[int, int]]:
-        self._plain_rounds += 1
-        inputs = (jnp.asarray(self._tokens), self._cache,
-                  jnp.asarray(self._lengths), jnp.asarray(self._temps))
-        with device:
-            nxt, self._cache, self._rng, counted = self._step(
-                self.params, *inputs, self._rng)
-            nxt, (loads, selected) = jax.device_get((nxt, counted))
-        if loads is not None:
-            self._count_routing(loads)
-        self._count_mixers(selected)
-        emitted: list[tuple[int, int]] = []
+    def _dispatch(self, after: _Flight | None) -> _Flight | None:
+        """Dispatch one plain round over all slots; None where no request
+        has budget left for it.  ``after`` is the round whose tokens the
+        host has not fetched: a request it decoded for takes its token
+        from that round's output on the device, one further into its
+        budget; every other lane takes the host's (an admission's first
+        token; an idle lane's stale one, at a position that stays)."""
+        ahead = after.lanes if after is not None else {}
+        fresh = self._tokens.copy()
+        lanes: dict[int, _Slot] = {}
         for i, entry in enumerate(self._slot):
             if entry is None:
+                continue
+            chained = ahead.get(i) is entry
+            if len(entry.tokens) + chained < entry.max_new:
+                lanes[i] = entry
+                if chained:
+                    fresh[i] = -1
+        if not lanes:
+            return None
+        # (copies: the mirrors change while the round is in flight)
+        lengths = self._lengths.copy()
+        self._last, self._cache, self._rng, counted = self._step(
+            self.params, self._last, jnp.asarray(fresh), self._cache,
+            jnp.asarray(lengths), jnp.asarray(self._temps.copy()),
+            self._rng)
+        for i in lanes:
+            self._lengths[i] += 1
+        self._obs_rounds.add()
+        if (fresh < 0).any():
+            self._obs_chained.add()
+        return _Flight((self._last, counted), lanes,
+                       int(lengths.sum()) + self.slots)
+
+    def _land(self, flight: _Flight, device) -> list[tuple[int, int]]:
+        """Fetch a round's tokens (``device``: the leg in which the host
+        waits for them) and hand them to its requests.  A lane whose
+        request ended or was cancelled after the dispatch decoded for
+        nobody: its token is dropped here, as a retired lane's is."""
+        with device:
+            nxt, (loads, selected) = jax.device_get(flight.out)
+        if loads is not None:
+            self._count_routing(loads)
+        self._count_mixers(selected, flight.positions)
+        emitted: list[tuple[int, int]] = []
+        for i, entry in flight.lanes.items():
+            if self._slot[i] is not entry:
                 continue
             token = int(nxt[i])
             entry.tokens.append(token)
             emitted.append((entry.request_id, token))
-            # the step consumed self._tokens[i] at position lengths[i]
-            self._lengths[i] += 1
             self._tokens[i] = token
             if self._finishes(entry, token):
                 self._retire(i)
         self._n_steps += 1
         self._n_emitted += len(emitted)
+        if self.idle:
+            self._flight = None     # what is in flight decodes for nobody
         return emitted
 
     def step_many(self, max_rounds: int = 8) -> list[tuple[int, int]]:
@@ -1158,11 +1267,15 @@ class DecodeServer:
         a retired lane does between rounds — host truncation discards
         those tokens and the splice on reuse resets the cache rows.
         Token-exact vs the equivalent step() loop (identical rng
-        sequence and math; tested)."""
+        sequence and math; tested).  A round that step() left in flight
+        is landed first, and its tokens lead the list."""
         if self.idle:
             return []
         if self.draft is not None and self._k > 0:
             return self.step()
+        emitted = self.land()
+        if self.idle:
+            return emitted
         remaining = [entry.max_new - len(entry.tokens)
                      for entry in self._slot if entry is not None]
         n = max(1, min([max_rounds] + remaining))
@@ -1172,7 +1285,7 @@ class DecodeServer:
         # programs cover every clamp
         n = 1 << (n.bit_length() - 1)
         if n == 1:
-            return self.step()
+            return emitted + self.step()
         with self._round(rounds=n) as device:
             runner = _multi_step_runner(self.model, self.slots,
                                         self._top_k, self._top_p,
@@ -1185,7 +1298,7 @@ class DecodeServer:
                     self.params, *inputs, self._rng)
                 outs = np.asarray(outs)                   # [n, B]
                 last = np.asarray(last)
-            emitted: list[tuple[int, int]] = []
+            landed = len(emitted)
             for r in range(n):
                 for i, entry in enumerate(self._slot):
                     if entry is None:
@@ -1203,9 +1316,20 @@ class DecodeServer:
             self._lengths += n
             self._tokens[:] = last
             self._n_steps += n
-            self._n_emitted += len(emitted)
+            self._n_emitted += len(emitted) - landed
             self._plain_rounds += n
         return emitted
+
+    def land(self) -> list[tuple[int, int]]:
+        """Fetch the round step() left in flight, if any, as a round of its
+        own, and return its tokens as step() would have: afterwards nothing
+        is in flight, the host's mirrors are all a round needs, and every
+        token the old weights decoded has been handed out (swap_params)."""
+        if self._flight is None:
+            return []
+        with self._round() as device:
+            flight, self._flight = self._flight, None
+            return self._land(flight, device)
 
     def _spec_step(self, device) -> list[tuple[int, int]]:
         """One speculative round: commit each slot's accepted prefix plus
@@ -1258,13 +1382,17 @@ class DecodeServer:
     def _round(self, **args):
         """One decode round as its legs, into the process-wide obs registry
         (and the span buffer while obs/trace records).  Yields the leg to
-        hold open from the dispatch until the tokens are on the host
-        (``serve.round_device_s``); the rest of the block is
-        ``serve.round_host_s``, the whole ``serve.round_s``.  What passed
-        since the last round returned, while a slot was active, is the
-        caller's time (its loop, its admissions): one observation of
-        ``serve.between_rounds_s``.  Slots in use and the draft's accept
-        rate are in :attr:`stats`."""
+        hold open while the host waits for the round's tokens
+        (``serve.round_device_s``: of a plain round the REST of a round
+        that has run since the call before dispatched it; of a speculative
+        or fused one dispatch to tokens, as before); the rest of the block
+        (a plain round's: the next round's dispatch and the bookkeeping)
+        is ``serve.round_host_s``, the whole ``serve.round_s``.  What
+        passed since the last round returned, while a slot was active, is
+        the caller's time (its loop, its admissions): one observation of
+        ``serve.between_rounds_s``; ``serve.round_s`` and it together are
+        the period a user sees.  Slots in use and the draft's accept rate
+        are in :attr:`stats`."""
         t0 = time.perf_counter()
         if self._round_returned is not None:
             self._obs_between.observe(t0 - self._round_returned)
@@ -1286,22 +1414,22 @@ class DecodeServer:
                             jax.tree_util.tree_leaves(self._cache)),
                 "window": 0, "state": 0}
 
-    def _count_mixers(self, selected: np.ndarray | None) -> None:
+    def _count_mixers(self, selected: np.ndarray | None,
+                      positions: int) -> None:
         """One decode round into the counters the sparse and linear
         layers' metrics divide.  ``selected`` is the round's own
         [positions attended, kernels scored] over its sparse layers and
         every lane (idle ones too: the device computes them); beside it
-        the positions those lanes held (each lane's length with its new
-        token, a sparse layer each) and the states the round advanced (a
-        lane and linear layer each)."""
+        ``positions``, what those lanes held in THAT round (each lane's
+        length with its new token; a sparse layer each) and the states
+        the round advanced (a lane and linear layer each)."""
         if selected is not None:
             self._obs_mixers["serve.sparse.positions_selected"].add(
                 float(selected[0]))
             self._obs_mixers["serve.sparse.kernels_scored"].add(
                 float(selected[1]))
             self._obs_mixers["serve.sparse.positions_cached"].add(
-                float(self._sparse_layers
-                      * (int(self._lengths.sum()) + self.slots)))
+                float(self._sparse_layers * positions))
         if self._linear_layers:
             self._obs_mixers["serve.linear.state_updates"].add(
                 self.slots * self._linear_layers)
@@ -1338,7 +1466,8 @@ class DecodeServer:
         the abandoned-stream reap (fleet/decode.py: the client is gone,
         so decoding its remaining budget would burn a slot into a queue
         nobody reads).  The lane decodes garbage until reused, exactly
-        like a retired lane.  False when the id is not in flight."""
+        like a retired lane; its token of a round in flight is dropped
+        when the round lands.  False when the id is not in flight."""
         for i, entry in enumerate(self._slot):
             if entry is not None and entry.request_id == request_id:
                 self._slot[i] = None
@@ -1351,7 +1480,8 @@ class DecodeServer:
         self._results[entry.request_id] = entry.tokens
         self._slot[slot] = None
         self._n_retired += 1
-        # lengths/tokens stay — the lane decodes garbage until reused;
+        # lengths/tokens stay — the lane decodes garbage until reused
+        # (first in the round already in flight, from its real token);
         # the splice on reuse rewrites the cache rows that matter
 
     @property

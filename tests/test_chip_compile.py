@@ -111,18 +111,28 @@ def _held(compiled, cache):
             compiled.memory_analysis().temp_size_in_bytes, cache_bytes)
 
 
+def _compiled_round(model, params, cache, slots, chip):
+    """The decode round as ``DecodeServer`` dispatches it: the round
+    before's tokens as a device array (``prev``: what that round returned,
+    not fetched) beside the host's (``fresh``), lengths and temperatures.
+    What it returns first is what the next round takes as ``prev``."""
+    def lanes(dtype):
+        return jax.ShapeDtypeStruct((slots,), dtype, sharding=chip)
+
+    rng = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(lambda: jax.random.key(0)))
+    lowered = serving._step_runner(model, slots, 0, 0.0, "native").lower(
+        params, lanes(jnp.int32), lanes(jnp.int32), cache, lanes(jnp.int32),
+        lanes(jnp.float32), rng)
+    tokens = lowered.out_info[0]
+    assert (tokens.shape, tokens.dtype) == ((slots,), jnp.int32)
+    return lowered.compile()
+
+
 def test_the_decode_round_updates_every_part_where_it_lies(cell):
     model, params, cache, _, slots, chip = cell
-
-    def on_chip(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
-    rng = jax.tree.map(lambda x: on_chip(x.shape, x.dtype),
-                       jax.eval_shape(lambda: jax.random.key(0)))
-    compiled = serving._step_runner(model, slots, 0, 0.0, "native").lower(
-        params, on_chip((slots,), jnp.int32), cache,
-        on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32),
-        rng).compile()
+    compiled = _compiled_round(model, params, cache, slots, chip)
     aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
     assert aliased >= parts
     assert moved == []
@@ -160,9 +170,6 @@ def test_minicpm_salas_round_gathers_and_updates_in_place(one_chip):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=one_chip), tree)
 
-    def on_chip(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
     params = placed(jax.eval_shape(lambda: family.make_weights(model, 1)))
     cache = placed(jax.eval_shape(
         lambda: generation.init_cache(model, slots, max_len)))
@@ -170,12 +177,7 @@ def test_minicpm_salas_round_gathers_and_updates_in_place(one_chip):
     assert [x.shape for x in cache.ck] == [(16, 2, 4096, 128)]
     assert [(x.shape, x.dtype) for x in cache.state] == [
         ((16, 32, 128, 128), jnp.float32)] * 2
-    rng = jax.tree.map(lambda x: on_chip(x.shape, x.dtype),
-                       jax.eval_shape(lambda: jax.random.key(0)))
-    compiled = serving._step_runner(model, slots, 0, 0.0, "native").lower(
-        params, on_chip((slots,), jnp.int32), cache,
-        on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32),
-        rng).compile()
+    compiled = _compiled_round(model, params, cache, slots, one_chip)
     aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
     assert parts == 5 and aliased >= parts
     # (_held's threshold is the smallest part: a state, 8.4M elements)

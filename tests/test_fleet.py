@@ -741,6 +741,54 @@ def test_pinned_version_survives_continued_publication():
         server.stop()
 
 
+@pytest.mark.parametrize("action", [fmsg.CTRL_SWAP, fmsg.CTRL_ROLLBACK],
+                         ids=["swap", "rollback"])
+def test_every_chunk_carries_the_version_that_decoded_it(rng, action):
+    """A swap to OTHER weights mid-stream, the DecodeServer a round ahead
+    of its step(): the round in flight ran under the weights that leave,
+    so its token is delivered before the swap, under the old stamp.  Each
+    token is the one the version on its chunk decodes (against the one
+    cache, as the stream's is), and no old chunk follows a new one."""
+    from test_serving_pipelined import decoded_by
+
+    stores = {0: _PARAMS, 7: _MODEL.init_params(3)}
+    server = FleetDecodeServer(
+        DecodeServer(_MODEL, _PARAMS, slots=2, max_len=160),
+        server_id=0, heartbeat_s=0.05)
+    server.auto_advance = False
+    server._round_delay_s = 0.02
+    server.start()
+    client = RpcClient(server.address, fmsg.DECODE_SERVICE,
+                       fmsg.DECODE_METHODS)
+    try:
+        prompt = [int(t) for t in rng.integers(1, VOCAB, 6)]
+        stream = client.call("SubmitStream", fmsg.DecodeRequest(
+            tokens=prompt, max_new=40, temperature=-1.0), timeout=None)
+        chunks = [next(stream) for _ in range(4)]   # decoding, a round ahead
+        server.publish_version(
+            {name: np.array(arr) for name, arr in stores[7].items()}, 7)
+        resp = server.Control(fmsg.DecodeControlRequest(
+            action=action, version=7), None)
+        assert resp.success and resp.weight_version == 7, resp.message
+        chunks += list(stream)
+    finally:
+        client.close()
+        server.stop()
+    assert chunks[-1].done and not chunks[-1].error
+    tokens = [int(c.token) for c in chunks if not c.done]
+    stamps = [int(c.weight_version) for c in chunks if not c.done]
+    assert len(tokens) == 40 and set(stamps) == {0, 7}
+    assert stamps == sorted(stamps) and chunks[-1].weight_version == 7
+    assert tokens == decoded_by(_MODEL, stores, prompt, stamps, max_len=160)
+    # (the comparison can tell: the first new token attributed to the old
+    # weights, or the last old one to the new, is another stream)
+    at = stamps.index(7)
+    for wrong in (stamps[:at] + [0] + stamps[at + 1:],
+                  stamps[:at - 1] + [7] + stamps[at:]):
+        assert tokens != decoded_by(_MODEL, stores, prompt, wrong,
+                                    max_len=160)
+
+
 def test_control_swap_reports_real_outcome():
     """Control(SWAP) success means the swap APPLIED — a version evicted
     or a store the DecodeServer rejects must come back success=False

@@ -479,7 +479,8 @@ def test_the_compiled_round_updates_every_part_where_it_lies(cached,
     parts = _cache_parts(cache)
     if program == "step":
         lowered = serving._step_runner(model, slots, 0, 0.0, "native").lower(
-            params, jnp.zeros((slots,), jnp.int32), cache,
+            params, jnp.zeros((slots,), jnp.int32),
+            jnp.zeros((slots,), jnp.int32), cache,
             jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), jnp.float32),
             jax.random.key(0))
     else:

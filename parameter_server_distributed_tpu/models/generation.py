@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import stats as obs_stats
-from .transformer import Transformer
+from .transformer import STATE_MIXERS, Transformer
 
 Array = jax.Array
 
@@ -86,10 +86,12 @@ class KVCache:
     or the window is no shorter than ``max_len``.  ``ck`` holds a sparse
     layer's COMPRESSED KEYS beside its K/V (index i the mean of positions
     stride i .. stride i + kernel - 1, written once those are complete),
-    each [B, KV, max_len / stride, D].  ``state`` holds a
-    linear layer's recurrent STATE, [B, H, D, D] float32, which does not
-    grow with the context and cannot be rolled back.  ``ring_layers``,
-    ``sparse_layers`` and ``linear_layers`` name the layers of each kind
+    each [B, KV, max_len / stride, D].  ``state`` holds the STATE of a
+    layer that keeps no K/V (:func:`state_shape`): a linear layer's
+    decayed outer products, [B, H, D, D] float32, or a conv layer's shift
+    register of its last gated inputs, [B, K - 1, d_model]; neither grows
+    with the context, neither can be rolled back.  ``ring_layers``,
+    ``sparse_layers`` and ``state_layers`` name the layers of each kind
     (static).  ``length`` is the number of valid positions (a traced
     scalar so decode never retraces)."""
     k: tuple
@@ -103,7 +105,7 @@ class KVCache:
         default=(), metadata=dict(static=True))
     sparse_layers: tuple = dataclasses.field(
         default=(), metadata=dict(static=True))
-    linear_layers: tuple = dataclasses.field(
+    state_layers: tuple = dataclasses.field(
         default=(), metadata=dict(static=True))
     max_len: int = dataclasses.field(default=0, metadata=dict(static=True))
     # the fields that hold a part per layer
@@ -111,11 +113,12 @@ class KVCache:
 
     def place(self, layer: int) -> tuple[bool, int]:
         """(kept as a ring?, index within its part) of a layer that keeps
-        K/V (a linear layer keeps none: ``linear_layers`` places it)."""
+        K/V (a linear or conv layer keeps none: ``state_layers`` places
+        it)."""
         if layer in self.ring_layers:
             return True, self.ring_layers.index(layer)
         return False, layer - sum(
-            1 for r in self.ring_layers + self.linear_layers if r < layer)
+            1 for r in self.ring_layers + self.state_layers if r < layer)
 
     def by_head(self, index: int) -> bool:
         """Whether ``k[index]`` / ``v[index]`` is a sparse layer's."""
@@ -152,6 +155,17 @@ def ring_layers_of(model: Transformer, max_len: int) -> tuple[int, ...]:
         raise ValueError(f"window layers of more than one size {sizes}: "
                          "the cache keeps one ring size")
     return rings
+
+
+def state_shape(model: Transformer) -> tuple[tuple[int, ...], Any]:
+    """(shape, dtype) of what ONE slot keeps of one of the model's state
+    layers (a model has one kind of them): a linear layer's [H, D, D]
+    float32, a conv layer's last ``conv_kernel - 1`` gated inputs
+    [K - 1, d_model] in the model's dtype."""
+    c = model.config
+    if c.layers_of("conv"):
+        return (c.conv_kernel - 1, c.d_model), c.dtype
+    return (c.n_heads, c.head_dim, c.head_dim), jnp.float32
 
 
 @jax.tree_util.register_dataclass
@@ -195,7 +209,7 @@ def init_cache(model: Transformer, batch: int, max_len: int,
             f"cache_dtype must be 'native' or 'int8', got {cache_dtype!r}")
     rings = ring_layers_of(model, max_len)
     pack = heads_per_row(c.kv_heads, c.head_dim)
-    sparse, linear = c.layers_of("sparse"), c.layers_of("linear")
+    sparse, states = c.layers_of("sparse"), c.state_layers
 
     def parts(count: int, positions: int, dtype) -> tuple:
         # GQA: the cache stores kv_heads (< n_heads) — n_heads/kv_heads x
@@ -206,10 +220,10 @@ def init_cache(model: Transformer, batch: int, max_len: int,
 
     length = jnp.zeros((), jnp.int32)
     if cache_dtype == "int8":
-        if rings or sparse or linear:
+        if rings or sparse or states:
             raise ValueError("the int8 cache stores every layer by "
-                             "position; a model with window, sparse or "
-                             "linear layers takes the native cache")
+                             "position; a model with window, sparse, "
+                             "linear or conv layers takes the native cache")
 
         def scales() -> tuple:
             return tuple(jnp.ones((batch, max_len, c.kv_heads), jnp.float32)
@@ -221,8 +235,8 @@ def init_cache(model: Transformer, batch: int, max_len: int,
             k_scale=scales(), v_scale=scales(), length=length,
             max_len=max_len)
     window = c.layer_spec(rings[0]).window if rings else 0
-    if rings and (sparse or linear):
-        raise ValueError("rings beside sparse or linear layers: a row "
+    if rings and (sparse or states):
+        raise ValueError("rings beside sparse, linear or conv layers: a row "
                          "holds every layer that keeps K/V, in layer order, "
                          "and one kind of part beside them")
     if sparse and max_len % c.sparse.block:
@@ -234,17 +248,17 @@ def init_cache(model: Transformer, batch: int, max_len: int,
         return tuple(
             jnp.zeros((batch, c.kv_heads, max_len, c.head_dim), c.dtype)
             if i in sparse else parts(1, max_len, c.dtype)[0]
-            for i in range(c.n_layers) if i not in rings + linear)
+            for i in range(c.n_layers) if i not in rings + states)
 
+    shape, dtype = state_shape(model)
     return KVCache(
         k=stored(), v=stored(), length=length,
         wk=parts(len(rings), window, c.dtype),
         wv=parts(len(rings), window, c.dtype),
         ck=tuple(jnp.zeros((batch, c.kv_heads, max_len // c.sparse.stride,
                             c.head_dim), c.dtype) for _ in sparse),
-        state=tuple(jnp.zeros((batch, c.n_heads, c.head_dim, c.head_dim),
-                              jnp.float32) for _ in linear),
-        ring_layers=rings, sparse_layers=sparse, linear_layers=linear,
+        state=tuple(jnp.zeros((batch, *shape), dtype) for _ in states),
+        ring_layers=rings, sparse_layers=sparse, state_layers=states,
         max_len=max_len)
 
 
@@ -283,12 +297,13 @@ def _seeded(part: Array, block: Array) -> Array:
 
 def check_rolls_back(model: Transformer) -> None:
     """Speculative decoding rolls rejected positions back by moving the
-    cache's length; a linear layer's state has no length to move."""
-    if model.config.layers_of("linear"):
+    cache's length; a linear or conv layer's state has no length to
+    move."""
+    if model.config.state_layers:
         raise ValueError(
             "speculative decoding rolls rejected positions back, and a "
-            "linear layer's recurrent state cannot be rolled back: decode "
-            "a model with linear layers without a draft")
+            "linear or conv layer's state cannot be rolled back: decode "
+            "a model with such layers without a draft")
 
 
 def check_position_budget(model: Transformer, prompt_len: int,
@@ -318,9 +333,9 @@ def prefill(model: Transformer, params: Mapping[str, Array], tokens: Array,
     c = model.config
     pack = heads_per_row(c.kv_heads, c.head_dim)
     length = jnp.asarray(prompt_len, jnp.int32)
-    linear = getattr(cache, "linear_layers", ())
-    kvs = [kv for i, kv in enumerate(kept) if i not in linear]
-    if linear or getattr(cache, "sparse_layers", ()):
+    states = getattr(cache, "state_layers", ())
+    kvs = [kv for i, kv in enumerate(kept) if i not in states]
+    if states or getattr(cache, "sparse_layers", ()):
         from ..ops.sparse_attention import compress_keys
 
         def stored(x, i):
@@ -332,7 +347,7 @@ def prefill(model: Transformer, params: Mapping[str, Array], tokens: Array,
                  [stored(v, i) for i, (_, v) in enumerate(kvs)], (), (),
                  [compress_keys(kvs[cache.place(i)[1]][0].transpose(
                      0, 2, 1, 3), c.sparse) for i in cache.sparse_layers],
-                 [kept[i] for i in linear])
+                 [kept[i] for i in states])
     elif isinstance(cache, QuantKVCache):
         k, ks = zip(*(_kv_quantize(k) for k, _ in kvs))
         v, vs = zip(*(_kv_quantize(v) for _, v in kvs))
@@ -386,8 +401,8 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
     cached row) takes the window as a mask.  ``route_stats``, where
     given, gains each ``experts`` layer's tokens per expert.
 
-    A LINEAR layer reads and advances its state (``counts`` keeps pads
-    out of it, and like a ring it cannot be rolled back).  A SPARSE layer
+    A LINEAR or CONV layer reads and advances its state (``counts`` keeps
+    pads out of it, and like a ring it cannot be rolled back).  A SPARSE layer
     writes its K/V by position like a full one and, in a cache that
     reaches ``dense_len``, keeps its compressed keys up to date and
     attends a selection of key blocks: a single token a row gathers them
@@ -479,16 +494,32 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                 (parts["k_scale"][i], parts["v_scale"][i])
                 if quant else None)
 
+    def ffn(layer: int, spec, h: Array, router) -> Array:
+        # the FFN's weights viewed where they are used, as ever (under
+        # scan_layers a view is slices, and their place in the program is
+        # part of what the compiler is handed)
+        lp, p = model.layer_view(params, layer)
+        # experts never drop and moe decodes drop-free; aux loss unused
+        return model.ffn_residual(lp, p, spec, h, decode=True,
+                                  router_logits=router,
+                                  route_stats=route_stats)[0]
+
     for layer in range(c.n_layers):
         # layer_view resolves either param layout (unrolled layer<i>/* or
         # scan_layers' stacked blocks/*)
         lp, p = model.layer_view(params, layer)
         spec = c.layer_spec(layer)
         router = model.pre_attention_router(lp, p, spec, h)
-        q, k, v = model.qkv(lp, p, h, positions, spec)  # k/v: [B, T, KV, D]
         # where the layer's part lies among its kind's
-        ring, i = ((False, cache.linear_layers.index(layer))
-                   if spec.mixer == "linear" else cache.place(layer))
+        ring, i = ((False, cache.state_layers.index(layer))
+                   if spec.mixer in STATE_MIXERS else cache.place(layer))
+        if spec.mixer == "conv":
+            with jax.named_scope("cache_attn"):
+                h, parts["state"][i] = model.conv_residual(
+                    lp, p, h, parts["state"][i], counts)
+            h = ffn(layer, spec, h, router)
+            continue
+        q, k, v = model.qkv(lp, p, h, positions, spec)  # k/v: [B, T, KV, D]
         if spec.mixer == "linear":
             from ..ops.linear_attention import linear_attention
 
@@ -521,15 +552,8 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                 values = parts["v"][i] = written(parts["v"][i],
                                                  pack_heads(v, pack))
             attn = stored_attention(q, keys, values, spec, i)
-        h = model.attn_residual(lp, p, h, attn, spec)
-        # the FFN's weights viewed where they are used, as ever (under
-        # scan_layers a view is slices, and their place in the program is
-        # part of what the compiler is handed)
-        lp, p = model.layer_view(params, layer)
-        # experts never drop and moe decodes drop-free; aux loss unused
-        h, _ = model.ffn_residual(lp, p, spec, h, decode=True,
-                                  router_logits=router,
-                                  route_stats=route_stats)
+        h = ffn(layer, spec, model.attn_residual(lp, p, h, attn, spec),
+                router)
     if only is not None:
         h = jnp.take_along_axis(h, only[:, None, None], axis=1)
     logits = model.final_logits(params, h)

@@ -187,9 +187,32 @@ class MoELayer:
         return out.reshape(b, s, d), aux
 
 
+def select_experts(router_logits: Array, top_k: int, score: str = "softmax",
+                   bias: Array | None = None, scale: float = 1.0,
+                   ) -> tuple[Array, Array]:
+    """(gates [N, k] float32, chosen experts [N, k]) from router logits
+    [N, E], in float32.  ``softmax``: the top_k logits, gated by the
+    softmax over them.  ``sigmoid``: every expert scores sigmoid(logit);
+    the top_k of score + ``bias`` ([E], a stored correction that enters
+    the SELECTION only) are chosen and gated by their own scores over
+    their sum (+ 1e-6), times ``scale``."""
+    logits = router_logits.astype(jnp.float32)
+    if score == "softmax":
+        top_logits, top_idx = jax.lax.top_k(logits, top_k)
+        return jax.nn.softmax(top_logits, axis=-1), top_idx
+    scores = jax.nn.sigmoid(logits)
+    _, top_idx = jax.lax.top_k(
+        scores if bias is None else scores + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(scores, top_idx, axis=-1)
+    return (chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-6)
+            * scale, top_idx)
+
+
 def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
-                     w3: Array | None, *, top_k: int,
-                     act: str = "gelu") -> tuple[Array, Array]:
+                     w3: Array | None, *, top_k: int, act: str = "gelu",
+                     score: str = "softmax", bias: Array | None = None,
+                     scale: float = 1.0, chosen: list | None = None,
+                     ) -> tuple[Array, Array]:
     """Dropless top-k experts over a flat batch of tokens.
 
     x [N, D]; router_logits [N, E] float32; w1 (and the gate pair's up
@@ -197,7 +220,9 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
     Returns (out [N, D] float32, loads [E] int32: assignments per expert).
 
     Token n's output is sum over its top_k experts e of gate_e *
-    expert_e(x_n), gate = softmax over the SELECTED logits (float32).
+    expert_e(x_n), experts and gates as :func:`select_experts` gives them
+    (``score``, ``bias``, ``scale``; by default the softmax over the
+    SELECTED logits); ``chosen``, where given, gains the experts [N, k].
     The N * top_k assignments are sorted by expert (stable, so ties keep
     token order) and each weight runs once as a grouped matmul over the
     sorted rows; the results are un-sorted by gather and summed per token.
@@ -205,9 +230,10 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
     n, d = x.shape
     experts = w1.shape[0]
     with jax.named_scope("router"):
-        top_logits, top_idx = jax.lax.top_k(
-            router_logits.astype(jnp.float32), top_k)          # [N, k]
-        gates = jax.nn.softmax(top_logits, axis=-1)
+        gates, top_idx = select_experts(router_logits, top_k, score, bias,
+                                        scale)                 # [N, k]
+        if chosen is not None:
+            chosen.append(top_idx)
         flat = top_idx.reshape(n * top_k)
         order = jnp.argsort(flat, stable=True)                 # [A]
         loads = jnp.zeros((experts,), jnp.int32).at[flat].add(1)

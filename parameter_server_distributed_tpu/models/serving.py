@@ -51,7 +51,7 @@ from .generation import (KVCache, QuantKVCache, _cached_runner,
                          decode_block, heads_major, heads_per_row,
                          positions_major,
                          init_cache, pack_heads, ring_layers_of, sample_token,
-                         sample_token_rowwise, split_row)
+                         sample_token_rowwise, split_row, state_shape)
 from .prefix_tree import PrefixTree, RowRef
 from .transformer import Transformer
 
@@ -161,8 +161,8 @@ def _prefill_runner(model: Transformer, bucket: int, cache_dtype: str):
     every layer that keeps K/V by position, heads side by side as the
     cache's parts hold them, [L, S', KV / pack, pack * D] (quantized
     already when the slot cache is int8, so splicing is dtype-pure); for a
-    model with linear layers (k, v, state), the third their recurrent
-    states after the last real position, [L_linear, H, D, D] float32: a
+    model with linear or conv layers (k, v, state), the third their
+    states after the last real position, [L_state, *state_shape]: a
     SNAPSHOT, good at that depth and no other."""
     key = (_model_key(model), "serve_prefill", bucket, cache_dtype)
 
@@ -181,15 +181,15 @@ def _prefill_runner(model: Transformer, bucket: int, cache_dtype: str):
                     h, real_len - 1, 1, axis=1))[0, 0]      # [vocab]
             c = model.config
             pack = heads_per_row(c.kv_heads, c.head_dim)
-            linear = c.layers_of("linear")
-            kvs = [kv for i, kv in enumerate(kept) if i not in linear]
+            states = c.state_layers
+            kvs = [kv for i, kv in enumerate(kept) if i not in states]
             k = jnp.stack([k for k, _ in kvs])[:, 0]        # [L, S', H, D]
             v = jnp.stack([v for _, v in kvs])[:, 0]
-            if linear:
-                # the snapshot: every linear layer's state after the last
+            if states:
+                # the snapshot: every state layer's state after the last
                 # real position
                 return last, (pack_heads(k, pack), pack_heads(v, pack),
-                              jnp.stack([kept[i][0] for i in linear])), loads
+                              jnp.stack([kept[i][0] for i in states])), loads
             if cache_dtype == "int8":
                 k, ks = _kv_quantize(k)
                 v, vs = _kv_quantize(v)
@@ -224,7 +224,7 @@ def _splice_runner(model: Transformer, bucket: int, cache_dtype: str):
             if cache_dtype != "int8":
                 k, v, *state = row
                 row = split_row(cache, k, v, length)
-                if cache.sparse_layers or cache.linear_layers:
+                if cache.sparse_layers or cache.state_layers:
                     from ..ops.sparse_attention import compress_keys
 
                     # a sparse layer's K/V go in by head; its compressed
@@ -266,8 +266,8 @@ def _row_cache(model: Transformer, row, total: int, cache_dtype: str):
             length=length, max_len=total)
     c = model.config
     k, v, *state = row
-    sparse, linear = c.layers_of("sparse"), c.layers_of("linear")
-    kept = [i for i in range(c.n_layers) if i not in linear]
+    sparse, states = c.layers_of("sparse"), c.state_layers
+    kept = [i for i in range(c.n_layers) if i not in states]
 
     def stored(layers) -> tuple:
         """a sparse layer's part by head, every other as the row has it"""
@@ -283,13 +283,13 @@ def _row_cache(model: Transformer, row, total: int, cache_dtype: str):
         ck=tuple(jnp.zeros((1, c.kv_heads, total // c.sparse.stride,
                             c.head_dim), c.dtype) for _ in sparse),
         state=tuple(layer[None] for layer in state[0]) if state else (),
-        sparse_layers=sparse, linear_layers=linear,
+        sparse_layers=sparse, state_layers=states,
         length=length, max_len=total)
 
 
 def _cache_row(cache) -> tuple:
     """The row of a one-slot cache that stores every layer by position
-    (and the states of its linear layers, where it has any)."""
+    (and the states of its linear or conv layers, where it has any)."""
     def layers(name):
         held = getattr(cache, name)
         if name not in ("k", "v") or not getattr(cache, "sparse_layers", ()):
@@ -349,8 +349,10 @@ _PREFILL_CHUNK = 4096
 def _prefills_whole(model: Transformer, bucket: int) -> bool:
     """The rule of the two prefill paths, from shapes alone."""
     c = model.config
-    routed = any(spec.ffn != "mlp" for spec in c.period)
-    widest = max(c.d_model, c.d_ff * (c.moe_top_k if routed else 1))
+    widest = max([c.d_model] + [
+        c.d_ff if spec.ffn == "mlp" else c.moe_top_k * (
+            c.expert_width if spec.ffn == "experts" else c.d_ff)
+        for spec in c.specs])
     return bucket * widest <= _PREFILL_WHOLE
 
 
@@ -384,11 +386,11 @@ def _empty_row_runner(model: Transformer, total: int):
     def build():
         c = model.config
         pack = heads_per_row(c.kv_heads, c.head_dim)
-        linear = c.layers_of("linear")
-        kv = jnp.zeros((c.n_layers - len(linear), 16, c.kv_heads // pack,
+        states = c.state_layers
+        kv = jnp.zeros((c.n_layers - len(states), 16, c.kv_heads // pack,
                         pack * c.head_dim), c.dtype)
-        state = (jnp.zeros((len(linear), c.n_heads, c.head_dim, c.head_dim),
-                           jnp.float32),) if linear else ()
+        shape, dtype = state_shape(model)
+        state = (jnp.zeros((len(states), *shape), dtype),) if states else ()
         return (jax.jit(lambda: _row_cache(model, (kv, kv, *state), total,
                                            "native")),
                 jax.jit(_cache_row))
@@ -577,6 +579,8 @@ class DecodeServer:
         # they decide what the prefix tree may match and what cannot be
         # rolled back
         self._linear_layers = len(config.layers_of("linear"))
+        self._conv_layers = len(config.layers_of("conv"))
+        self._state_layers = self._linear_layers + self._conv_layers
         self._sparse_layers = len(config.layers_of("sparse"))
         if draft is not None:
             check_rolls_back(model)
@@ -640,13 +644,15 @@ class DecodeServer:
                                       "admit_experts_touched")}
         for kind, held in self._cache_bytes_by_kind().items():
             obs_stats.gauge(f"serve.cache.{kind}_bytes").set(held)
-        # what a round's sparse and linear layers read (see _count_mixers)
+        # what a round's sparse, linear and conv layers read (see
+        # _count_mixers)
         self._obs_mixers = {
             name: obs_stats.counter(name) for name in (
                 "serve.sparse.positions_selected",
                 "serve.sparse.positions_cached",
                 "serve.sparse.kernels_scored",
-                "serve.linear.state_updates")}
+                "serve.linear.state_updates",
+                "serve.conv.state_updates")}
         # perf_counter at the last round's return, while a slot is active
         self._round_returned: float | None = None
         # radix-tree prefix cache (ISSUE 20): token-level index over
@@ -659,7 +665,7 @@ class DecodeServer:
                   else int(os.environ.get("PSDT_PREFIX_CACHE_BYTES",
                                           "268435456")))
         self._prefix_tree = (PrefixTree(
-            budget, snapshots=bool(self._linear_layers))
+            budget, snapshots=bool(self._state_layers))
             if prompt_cache else None)
         self._prompt_hits = 0
         # shared-PREFIX reuse: a miss whose prompt shares a cached
@@ -893,7 +899,7 @@ class DecodeServer:
         plen = min(matched, real_len - 1)
         if plen <= 0 or node.handle is None:
             return None
-        if self._linear_layers and plen != matched:
+        if self._state_layers and plen != matched:
             return None  # a snapshot is good at its own depth only
         pre_row = node.handle.row
         pbucket = int(pre_row[0].shape[1])
@@ -955,7 +961,7 @@ class DecodeServer:
         splits = tree.splits
         node = tree.insert(
             pkey, last, RowRef(row, _row_nbytes(row),
-                               state_at=len(pkey) if self._linear_layers
+                               state_at=len(pkey) if self._state_layers
                                else None),
             RowRef(d_row, _row_nbytes(d_row)) if d_row is not None else None)
         if tree.splits != splits:
@@ -1416,13 +1422,13 @@ class DecodeServer:
 
     def _count_mixers(self, selected: np.ndarray | None,
                       positions: int) -> None:
-        """One decode round into the counters the sparse and linear
+        """One decode round into the counters the sparse, linear and conv
         layers' metrics divide.  ``selected`` is the round's own
         [positions attended, kernels scored] over its sparse layers and
         every lane (idle ones too: the device computes them); beside it
         ``positions``, what those lanes held in THAT round (each lane's
         length with its new token; a sparse layer each) and the states
-        the round advanced (a lane and linear layer each)."""
+        the round advanced (a lane and linear or conv layer each)."""
         if selected is not None:
             self._obs_mixers["serve.sparse.positions_selected"].add(
                 float(selected[0]))
@@ -1433,6 +1439,9 @@ class DecodeServer:
         if self._linear_layers:
             self._obs_mixers["serve.linear.state_updates"].add(
                 self.slots * self._linear_layers)
+        if self._conv_layers:
+            self._obs_mixers["serve.conv.state_updates"].add(
+                self.slots * self._conv_layers)
 
     def _count_routing(self, loads: np.ndarray,
                        admission: bool = False) -> None:

@@ -43,14 +43,17 @@ Array = jax.Array
 
 
 FFN_KINDS = ("mlp", "moe", "experts")
-MIXER_KINDS = ("softmax", "sparse", "linear")
+MIXER_KINDS = ("softmax", "sparse", "linear", "conv")
+# the mixers that keep a fixed-size STATE in a decode cache and no K/V
+STATE_MIXERS = ("linear", "conv")
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """One layer of a model's pattern: what its attention sees and what
     its feed-forward branch is.  ``TransformerConfig.pattern`` holds one
-    period; layer ``i`` is ``pattern[i % len(pattern)]``."""
+    period; layer ``i`` is ``pattern[i % len(pattern)]``, counted from the
+    end of ``TransformerConfig.prologue`` where the model has one."""
     # 0: every earlier position; W: the last W (query i sees key j where
     # 0 <= i - j < W), and a cache slot of this layer holds W positions
     window: int = 0
@@ -61,9 +64,9 @@ class LayerSpec:
     # mlp: the dense MLP.  moe: the capacity-dropping Switch/top-k layer
     # (models/moe.py MoELayer; training fixtures).  experts: dropless
     # sort-and-group routing (models/moe.py dropless_experts) over experts
-    # of ``mlp_act``'s form and ``d_ff``'s width, gates the softmax over
-    # the selected logits, the router BEFORE attention (it reads the
-    # attention's normed input)
+    # of ``mlp_act``'s form and ``d_expert``'s width (``d_ff``'s where
+    # that is 0); what the router reads and how it scores and gates are
+    # the config's ``moe_router_input`` and ``moe_score``
     ffn: str = "mlp"
     # what mixes positions.  softmax: causal attention over every earlier
     # position (or the window's).  sparse: the same while the context is
@@ -71,7 +74,9 @@ class LayerSpec:
     # SELECTION of key blocks (ops/sparse_attention.py); its cache keeps
     # compressed keys beside K/V.  linear: a per-head decayed outer-product
     # state (ops/linear_attention.py): no K/V by position, a fixed-size
-    # state instead
+    # state instead.  conv: a gated depthwise causal convolution of
+    # ``config.conv_kernel`` taps (ops/short_conv.py): no heads, no K/V,
+    # its state the last ``conv_kernel - 1`` gated inputs
     mixer: str = "softmax"
     # K/V heads of this layer; 0 = the config's
     kv_heads: int = 0
@@ -93,6 +98,10 @@ class LayerSpec:
                              f"got {self.mixer!r}")
         if self.mixer != "softmax" and self.window:
             raise ValueError("a window belongs to a softmax layer")
+        if self.mixer == "conv" and (self.kv_heads or self.qk_norm
+                                     or self.gate or self.out_norm):
+            raise ValueError("a conv layer has no heads: kv_heads, qk_norm, "
+                             "gate and out_norm belong to attention")
         if self.window < 0:
             raise ValueError(f"window must be >= 0, got {self.window}")
 
@@ -150,8 +159,30 @@ class TransformerConfig:
     # window and full layers, or routes every layer, writes its period
     # here.  ``scan_layers`` scans over whole periods.
     pattern: tuple = ()
+    # Leading layers OUTSIDE the period, a ``LayerSpec`` each (a model
+    # whose first layers keep a dense FFN before its expert layers
+    # begin): layer ``i`` is ``prologue[i]`` while there is one, and the
+    # pattern's periods start after them.  They run unrolled.
+    prologue: tuple = ()
     # experts per token: 1 = Switch (default), 2 = Mixtral-style top-2
     moe_top_k: int = 1
+    # width of one of an ``experts`` layer's experts; 0 = ``d_ff``'s (a
+    # model whose dense layers and experts differ in width gives both)
+    d_expert: int = 0
+    # An ``experts`` layer's router.  What it reads: ``attn``, the
+    # attention's normed input (the router stands BEFORE attention), or
+    # ``ffn``, the feed-forward branch's own normed input.  How it scores:
+    # ``softmax`` takes the top-k logits and gates by the softmax over
+    # them; ``sigmoid`` scores every expert sigmoid(logit), selects the
+    # top-k of score + ``moe/router/bias`` (a stored [E] vector, there
+    # under ``moe_expert_bias``; it enters the SELECTION only) and gates
+    # by the chosen scores over their sum, times ``moe_route_scale``
+    moe_router_input: str = "attn"
+    moe_score: str = "softmax"
+    moe_expert_bias: bool = False
+    moe_route_scale: float = 1.0
+    # taps of a ``conv`` layer's kernel
+    conv_kernel: int = 3
     # Scan over layers: store block weights stacked with a leading [L]
     # axis (``blocks/<suffix>``) and run the layer loop as one
     # ``lax.scan`` body traced ONCE, instead of n_layers Python-unrolled
@@ -204,17 +235,38 @@ class TransformerConfig:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.n_heads)
         object.__setattr__(self, "pattern", tuple(self.pattern))
-        if any(not isinstance(spec, LayerSpec) for spec in self.pattern):
-            raise ValueError("pattern holds LayerSpec entries")
+        object.__setattr__(self, "prologue", tuple(self.prologue))
+        if any(not isinstance(spec, LayerSpec)
+               for spec in self.pattern + self.prologue):
+            raise ValueError("pattern and prologue hold LayerSpec entries")
         if self.pattern and self.moe_every:
             raise ValueError("give the layers as a pattern or by "
                              "moe_every, not both")
-        mixers = {spec.mixer for spec in self.pattern}
+        if self.moe_router_input not in ("attn", "ffn"):
+            raise ValueError(f"moe_router_input must be 'attn' or 'ffn', "
+                             f"got {self.moe_router_input!r}")
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_score must be 'softmax' or 'sigmoid', "
+                             f"got {self.moe_score!r}")
+        if self.moe_expert_bias and self.moe_score != "sigmoid":
+            raise ValueError("the stored bias corrects a sigmoid score's "
+                             "selection: moe_expert_bias needs "
+                             "moe_score='sigmoid'")
+        mixers = {spec.mixer for spec in self.specs}
         if "sparse" in mixers and self.sparse is None:
             raise ValueError("a sparse layer needs config.sparse")
-        if mixers - {"softmax"} and self.scan_layers:
+        if set(STATE_MIXERS) <= mixers:
+            raise ValueError("linear beside conv layers: a row's snapshot "
+                             "stacks its layers' states, so a model keeps "
+                             "one shape of state")
+        if "conv" in mixers and (self.conv_kernel < 2 or self.bias):
+            raise ValueError(f"a conv layer has a kernel of 2 taps or more "
+                             f"and no bias, got conv_kernel="
+                             f"{self.conv_kernel}, bias={self.bias}")
+        if (mixers - {"softmax"} or self.prologue) and self.scan_layers:
             raise ValueError("scan_layers stacks one kind of cache part a "
-                             "layer: sparse and linear layers run unrolled")
+                             "layer and scans whole periods: sparse, linear "
+                             "and conv layers and a prologue run unrolled")
 
     @property
     def attn_dim(self) -> int:
@@ -235,9 +287,21 @@ class TransformerConfig:
                     + (LayerSpec(ffn="moe"),))
         return (LayerSpec(),)
 
+    @property
+    def specs(self) -> tuple:
+        """Every kind of layer the model has: the prologue's, then one
+        period's."""
+        return self.prologue + self.period
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_expert or self.d_ff
+
     def layer_spec(self, i: int) -> LayerSpec:
+        if i < len(self.prologue):
+            return self.prologue[i]
         period = self.period
-        return period[i % len(period)]
+        return period[(i - len(self.prologue)) % len(period)]
 
     @property
     def kv_heads(self) -> int:
@@ -255,6 +319,12 @@ class TransformerConfig:
         """The layers whose mixer is ``mixer``, in order."""
         return tuple(i for i in range(self.n_layers)
                      if self.layer_spec(i).mixer == mixer)
+
+    @property
+    def state_layers(self) -> tuple[int, ...]:
+        """The layers whose mixer keeps a state and no K/V, in order."""
+        return tuple(i for i in range(self.n_layers)
+                     if self.layer_spec(i).mixer in STATE_MIXERS)
 
 
 def scoped(name: str):
@@ -538,7 +608,7 @@ class Transformer:
                  attention_fn: Callable | None = None,
                  mesh: Mesh | None = None):
         period = config.period
-        for spec in period:
+        for spec in config.specs:
             if config.n_heads % (spec.kv_heads or config.kv_heads):
                 raise ValueError(
                     f"n_heads={config.n_heads} must divide by "
@@ -554,7 +624,7 @@ class Transformer:
                 f"{config.n_layers} must divide by the pattern's "
                 f"{len(period)}")
         self.config = config
-        if any(s.ffn == "moe" for s in period):
+        if any(s.ffn == "moe" for s in config.specs):
             from .moe import MoEConfig, MoELayer
             self._moe = MoELayer(MoEConfig(
                 d_model=config.d_model, d_ff=config.d_ff,
@@ -603,23 +673,32 @@ class Transformer:
         """The weights of one layer of kind ``spec``, by suffix."""
         c = self.config
         kv_dim = (spec.kv_heads or c.kv_heads) * c.head_dim
-        block = {"ln1/scale": (c.d_model,),
-                 "attn/wq": (c.d_model, c.attn_dim),
-                 "attn/wk": (c.d_model, kv_dim),
-                 "attn/wv": (c.d_model, kv_dim),
-                 "attn/wo": (c.attn_dim, c.d_model),
-                 "ln2/scale": (c.d_model,)}
-        if spec.qk_norm:
-            block.update({"attn/q_norm/scale": (c.head_dim,),
-                          "attn/k_norm/scale": (c.head_dim,)})
-        if spec.gate:
-            block["attn/wg"] = (c.d_model, c.attn_dim)
-        if spec.out_norm:
-            block["attn/o_norm/scale"] = (c.head_dim,)
+        if spec.mixer == "conv":
+            # (B, C, x) come from one projection; a kernel tap is a row,
+            # so that it lies along the lanes like the channels it scales
+            block = {"ln1/scale": (c.d_model,),
+                     "conv/in_proj": (c.d_model, 3 * c.d_model),
+                     "conv/kernel": (c.conv_kernel, c.d_model),
+                     "conv/out_proj": (c.d_model, c.d_model),
+                     "ln2/scale": (c.d_model,)}
+        else:
+            block = {"ln1/scale": (c.d_model,),
+                     "attn/wq": (c.d_model, c.attn_dim),
+                     "attn/wk": (c.d_model, kv_dim),
+                     "attn/wv": (c.d_model, kv_dim),
+                     "attn/wo": (c.attn_dim, c.d_model),
+                     "ln2/scale": (c.d_model,)}
+            if spec.qk_norm:
+                block.update({"attn/q_norm/scale": (c.head_dim,),
+                              "attn/k_norm/scale": (c.head_dim,)})
+            if spec.gate:
+                block["attn/wg"] = (c.d_model, c.attn_dim)
+            if spec.out_norm:
+                block["attn/o_norm/scale"] = (c.head_dim,)
         if c.norm == "layernorm":
             block["ln1/bias"] = (c.d_model,)
             block["ln2/bias"] = (c.d_model,)
-        if c.bias:
+        if c.bias:     # (a model with conv layers has none: __post_init__)
             block.update({"attn/bq": (c.attn_dim,), "attn/bk": (kv_dim,),
                           "attn/bv": (kv_dim,), "attn/bo": (c.d_model,)})
         if spec.ffn == "mlp":
@@ -630,11 +709,14 @@ class Transformer:
             if c.bias:
                 block.update({"mlp/b1": (c.d_ff,), "mlp/b2": (c.d_model,)})
             return block
+        width = c.expert_width if spec.ffn == "experts" else c.d_ff
         block.update({"moe/router/w": (c.d_model, c.moe_experts),
-                      "moe/w1": (c.moe_experts, c.d_model, c.d_ff),
-                      "moe/w2": (c.moe_experts, c.d_ff, c.d_model)})
+                      "moe/w1": (c.moe_experts, c.d_model, width),
+                      "moe/w2": (c.moe_experts, width, c.d_model)})
         if spec.ffn == "experts" and c.gated_mlp:
-            block["moe/w3"] = (c.moe_experts, c.d_model, c.d_ff)
+            block["moe/w3"] = (c.moe_experts, c.d_model, width)
+        if spec.ffn == "experts" and c.moe_expert_bias:
+            block["moe/router/bias"] = (c.moe_experts,)
         return block
 
     def _stacked_suffixes(self) -> list[str]:
@@ -717,7 +799,8 @@ class Transformer:
                 fan_in = shape[-2] if len(shape) == 3 else shape[0]
                 scale = 1.0 / math.sqrt(fan_in)
                 # residual-output projections get depth-scaled init
-                if name.endswith(("attn/wo", "mlp/w2", "moe/w2")):
+                if name.endswith(("attn/wo", "conv/out_proj", "mlp/w2",
+                                  "moe/w2")):
                     scale /= math.sqrt(2.0 * c.n_layers)
                 params[name] = jax.random.normal(sub, shape, c.dtype) * scale
         return params
@@ -822,6 +905,31 @@ class Transformer:
         scale = self.config.residual_scale
         return out if scale == 1.0 else out * scale
 
+    def conv_residual(self, params: Mapping[str, Array], prefix: str,
+                      h: Array, state: Array | None = None,
+                      counts: Array | None = None) -> tuple[Array, Array]:
+        """A ``conv`` layer's whole mixer branch, under ``attn/conv``:
+        h + W_out(C * conv(B * x)) with (B, C, x) = split(W_in ln1(h)).
+        h [B, T, d] at T consecutive positions; ``state`` [B, K - 1, d]
+        holds the gated inputs B * x of the K - 1 positions before them
+        (None: the sequence starts here) and ``counts`` [B] says how many
+        of the T are real (None: all).  Returns (new h, the state after
+        the last real position): one function for a whole sequence, a
+        block against a cached state and a decode round's single token."""
+        from ..ops.short_conv import gated_short_conv
+
+        c = self.config
+        with jax.named_scope("attn"), jax.named_scope("conv"):
+            x = self._norm(params, f"{prefix}/ln1", h)
+            bcx = wdot(x, params[f"{prefix}/conv/in_proj"],
+                       preferred_element_type=jnp.float32).astype(c.dtype)
+            mixed, state = gated_short_conv(
+                *jnp.split(bcx, 3, axis=-1), params[f"{prefix}/conv/kernel"],
+                state, counts)
+            out = wdot(mixed, params[f"{prefix}/conv/out_proj"],
+                       preferred_element_type=jnp.float32)
+            return h + self._branch(out).astype(c.dtype), state
+
     @scoped("mlp")
     def mlp_residual(self, params: Mapping[str, Array], prefix: str,
                      h: Array) -> Array:
@@ -875,11 +983,13 @@ class Transformer:
 
     def pre_attention_router(self, params: Mapping[str, Array], prefix: str,
                              spec: LayerSpec, h: Array) -> Array | None:
-        """The logits of an ``experts`` layer's router, which stands
-        BEFORE attention (it reads the attention's normed input; the same
-        norm ``qkv`` computes, which the compiler shares); None for every
-        other layer."""
-        if spec.ffn != "experts":
+        """The logits of an ``experts`` layer's router where it stands
+        BEFORE attention (``moe_router_input="attn"``: it reads the
+        attention's normed input; the same norm ``qkv`` computes, which
+        the compiler shares); None for every other layer, and where the
+        router reads the feed-forward branch's own input
+        (:meth:`ffn_residual` computes those logits itself)."""
+        if spec.ffn != "experts" or self.config.moe_router_input != "attn":
             return None
         return self.router_logits(params, prefix,
                                   self._norm(params, f"{prefix}/ln1", h))
@@ -888,7 +998,7 @@ class Transformer:
                      spec: LayerSpec, h: Array, decode: bool = False,
                      router_logits: Array | None = None,
                      route_stats: list | None = None,
-                     ) -> tuple[Array, Array]:
+                     chosen: list | None = None) -> tuple[Array, Array]:
         """The layer's FFN branch by its kind: the dense MLP, the
         capacity-dropping ``moe`` layer or dropless ``experts``.
         Returns (new_h, aux_loss) — aux is 0 but for ``moe``.  ``params``
@@ -897,9 +1007,11 @@ class Transformer:
         batch-global training mechanism and cannot be reproduced causally
         during KV-cached decoding; ``experts`` never drops, so prefill,
         extension and decode run one path.  ``router_logits`` are those of
-        :meth:`pre_attention_router`, which an ``experts`` layer needs;
+        :meth:`pre_attention_router` where an ``experts`` layer's router
+        stands there (None: it reads this branch's normed input);
         ``route_stats``, where given, gains this layer's tokens per
-        expert ([E] int32) for the caller's counters."""
+        expert ([E] int32) for the caller's counters, and ``chosen`` the
+        experts every token of an ``experts`` layer took ([B, S, k])."""
         zero = jnp.zeros((), jnp.float32)
         if spec.ffn == "mlp":
             return self.mlp_residual(params, prefix, h), zero
@@ -913,13 +1025,19 @@ class Transformer:
 
         c = self.config
         batch, seq = h.shape[:2]
+        if router_logits is None:
+            router_logits = self.router_logits(params, prefix, x)
         with jax.named_scope("moe"):
             out, loads = dropless_experts(
                 x.reshape(batch * seq, c.d_model),
                 router_logits.reshape(batch * seq, c.moe_experts),
                 params[f"{prefix}/moe/w1"], params[f"{prefix}/moe/w2"],
                 params.get(f"{prefix}/moe/w3"), top_k=c.moe_top_k,
-                act=c.mlp_act)
+                act=c.mlp_act, score=c.moe_score,
+                bias=params.get(f"{prefix}/moe/router/bias"),
+                scale=c.moe_route_scale, chosen=chosen)
+        if chosen is not None:
+            chosen[-1] = chosen[-1].reshape(batch, seq, c.moe_top_k)
         if route_stats is not None:
             route_stats.append(loads)
         return h + out.reshape(batch, seq, c.d_model).astype(c.dtype), zero
@@ -1053,6 +1171,15 @@ class Transformer:
             return out, (k, v)
         return self.attend(q, k, v, spec), (k, v)
 
+    def expert_selections(self, params: Mapping[str, Array],
+                          tokens: Array) -> list:
+        """Which experts every token of every ``experts`` layer took in
+        the forward pass of ``tokens`` [B, S]: [B, S, k] a layer, in layer
+        order."""
+        chosen: list = []
+        self._forward(params, tokens, collect_kv=False, chosen=chosen)
+        return chosen
+
     def sparse_selections(self, params: Mapping[str, Array],
                           tokens: Array) -> list:
         """Which key blocks every query of every sparse layer attended in
@@ -1096,10 +1223,12 @@ class Transformer:
                  collect_kv: bool, route_stats: list | None = None,
                  counts: Array | None = None,
                  selections: list | None = None,
+                 chosen: list | None = None,
                  ) -> tuple[Array, list, Array]:
-        """(h, what a cache keeps of every layer (see :meth:`mix`) under
-        ``collect_kv``, aux loss).  ``counts`` [B]: how many of a row's
-        tokens are real, for the layers whose state must not hold a pad."""
+        """(h, what a cache keeps of every layer (see :meth:`mix` and
+        :meth:`conv_residual`) under ``collect_kv``, aux loss).  ``counts``
+        [B]: how many of a row's tokens are real, for the layers whose
+        state must not hold a pad."""
         c = self.config
         batch, seq = tokens.shape
         if c.pos_emb == "learned" and seq > c.max_seq:
@@ -1118,16 +1247,21 @@ class Transformer:
 
         def layer_body(layer_params, p, spec, h):
             router = self.pre_attention_router(layer_params, p, spec, h)
-            q, k, v = self.qkv(layer_params, p, h, positions, spec)
-            # K/V go to the attention fn UNexpanded (kv_heads-sized);
-            # each implementation expands at the math (expand_gqa), so
-            # ring/Ulysses communicate the small tensors
-            attn, kept = self.mix(q, k, v, spec, counts, selections)
-            h = self.attn_residual(layer_params, p, h, attn, spec)
+            if spec.mixer == "conv":
+                h, kept = self.conv_residual(layer_params, p, h,
+                                             counts=counts)
+            else:
+                q, k, v = self.qkv(layer_params, p, h, positions, spec)
+                # K/V go to the attention fn UNexpanded (kv_heads-sized);
+                # each implementation expands at the math (expand_gqa), so
+                # ring/Ulysses communicate the small tensors
+                attn, kept = self.mix(q, k, v, spec, counts, selections)
+                h = self.attn_residual(layer_params, p, h, attn, spec)
             h = self._constrain(h, ("data", "fsdp"), "seq", None)
             h, aux = self.ffn_residual(layer_params, p, spec, h,
                                        router_logits=router,
-                                       route_stats=route_stats)
+                                       route_stats=route_stats,
+                                       chosen=chosen)
             h = self._constrain(h, ("data", "fsdp"), "seq", None)
             return h, aux, kept
 
@@ -1178,7 +1312,8 @@ class Transformer:
                 # the default would insert optimization barriers per step
                 scan_body = jax.checkpoint(scan_body, prevent_cse=False,
                                            policy=self._remat_policy())
-            route_stats = None   # a scan body's values cannot leave it
+            # a scan body's values cannot leave it
+            route_stats = chosen = None
             h, ys = jax.lax.scan(scan_body, h, blocks)
             if collect_kv:
                 # [periods, (P,) B, S, H, D] -> per layer

@@ -185,6 +185,44 @@ def test_minicpm_salas_round_gathers_and_updates_in_place(one_chip):
     assert temporaries < cache_bytes / 4
 
 
+def test_lfm2s_round_updates_k_v_in_place_beside_its_conv_states(one_chip):
+    """``serve_manychat_lfm2_24b_a2b``'s decode round at its real widths,
+    64 slots x 4,096 positions, four layers (conv and conv with the dense
+    SwiGLU, then attention and conv with 64 experts): the attention
+    layer's K and V are updated where they lie and nothing as large as one
+    of them is copied or sliced.  A conv layer's state is a shift register:
+    a round rewrites all of it (0.5 MB a layer), and the compiler stages it
+    in fast memory first, which is the three copies of exactly a state's
+    size that are let through here."""
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "lfm2-24b-a2b-10l.json")) as handle:
+        config = json.load(handle)
+    family = families.of(config)
+    model = family.model(config, remat=False, n_layers=4)
+    slots, max_len = 64, 4096
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(lambda: family.make_weights(model, 1)))
+    assert params["layer0/mlp/w1"].shape == (2048, 11776)
+    assert params["layer2/moe/w1"].shape == (64, 2048, 1536)
+    cache = placed(jax.eval_shape(
+        lambda: generation.init_cache(model, slots, max_len)))
+    # 8 K/V heads of 64, two to a row of 128 lanes
+    assert [x.shape for x in cache.k] == [(64, 4096, 4, 128)]
+    assert [(x.shape, x.dtype) for x in cache.state] == [
+        ((64, 2, 2048), jnp.bfloat16)] * 3
+    compiled = _compiled_round(model, params, cache, slots, one_chip)
+    aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
+    assert parts == 5 and aliased >= parts
+    state = 64 * 2 * 2048
+    assert [op for op in moved if op[2] != state or op[0] != "copy"] == []
+    assert len(moved) <= 3
+    assert temporaries < cache_bytes / 4
+
+
 # configuration, mesh axes: the two training cells (64 x 1,024 tokens a step)
 STEPS = {"one-chip": ("gpt2-medium", {}),
          "fsdp2-tensor2": ("gpt2-large", {"fsdp": 2, "tensor": 2})}

@@ -66,6 +66,11 @@ WIRE_DTYPE_NAMES = {"f32": WIRE_F32, "raw": WIRE_RAW_F32, "bf16": WIRE_BF16,
 # The packed encodings the codec handles (everything but repeated-float).
 PACKED_WIRE_DTYPES = (WIRE_RAW_F32, WIRE_BF16, WIRE_INT8, WIRE_TOPK)
 
+# Bytes an element takes in the encodings that are a cast of each element
+# alone, so that a slice of the source packs to the same slice of the
+# payload (int8's scale and top-k's selection read the whole source).
+ELEMENT_BYTES = {WIRE_RAW_F32: 4, WIRE_BF16: 2}
+
 TOPK_DEFAULT_DENSITY = 0.01  # fraction of entries a topk tensor keeps
 
 

@@ -38,10 +38,11 @@ target, not a hard message cap.
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import time
-from typing import Iterable, Iterator, Sequence
+from typing import Callable, Iterable, Iterator, Sequence
 
 import grpc
 
@@ -63,7 +64,7 @@ from .codec import (Codec, NativeCodec, PythonCodec,  # noqa: F401 — public
 from .service import RpcClient
 from .service import status_code as _status_code
 from .wire import WT_LEN, WT_VARINT, _len_delimited_size, _tag, _varint_size, \
-    _Writer, encode_varint
+    _Writer, encode_fresh, encode_varint
 
 log = logging.getLogger("pst.data_plane")
 
@@ -141,7 +142,8 @@ _READY_FIELD = 3       # m.ParameterUpdate.ready
 
 def encode_parameter_record_groups(
         groups: Sequence[Sequence[m.Tensor]],
-        stripes: int | None = None) -> list[bytes]:
+        take: Callable[[int, int], memoryview],
+        stripes: int | None = None) -> list[memoryview]:
     """Encode several chunk groups' ``ParameterUpdate.parameters`` bodies,
     fanning the per-group :func:`encode_parameter_records` passes across
     the shared stripe executor (core/stripes.py) when more than one group
@@ -160,16 +162,23 @@ def encode_parameter_record_groups(
     so the payload casts/packs here read the slab directly instead of
     re-gathering per-tensor device buffers — and because view identity
     never changes the f32 values, the encoded bytes are byte-identical
-    to the per-tensor path's."""
+    to the per-tensor path's.
+
+    ``take(i, size)`` hands group ``i``'s encoder its destination (see
+    :func:`encode_parameter_records`)."""
     from ..core.stripes import run_striped, stripe_count
 
+    jobs = [functools.partial(encode_parameter_records, group,
+                              functools.partial(take, i))
+            for i, group in enumerate(groups)]
     if len(groups) <= 1 or stripe_count(stripes) <= 1:
-        return [encode_parameter_records(group) for group in groups]
-    return run_striped([(lambda g=group: encode_parameter_records(g))
-                        for group in groups])
+        return [job() for job in jobs]
+    return run_striped(jobs)
 
 
-def encode_parameter_records(tensors: Iterable[m.Tensor]) -> bytes:
+def encode_parameter_records(
+        tensors: Iterable[m.Tensor],
+        take: Callable[[int], memoryview]) -> memoryview:
     """Encode a group of wire Tensors ONCE into the exact bytes of
     ``ParameterUpdate.parameters`` (field 2) records — tag, length, and
     tensor body per element.  The server's encode-once broadcast cache
@@ -177,15 +186,22 @@ def encode_parameter_records(tensors: Iterable[m.Tensor]) -> bytes:
     of the same (params version, wire dtype) via
     :class:`PreEncodedParameterUpdate`, so the per-tensor payload encode
     (f32→bf16 cast, repeated-float pack) runs once per version instead of
-    once per pulling worker."""
+    once per pulling worker.
+
+    The bytes go where the caller says: ``take(size)`` returns a writable
+    view of exactly ``size`` bytes (the serve cache hands out the buffers
+    of the version it retires, already touched), and a read-only view of
+    it comes back."""
     items = [(t, t.encoded_size()) for t in tensors]
-    writer = _Writer(sum(_len_delimited_size(_PARAMETERS_FIELD, size)
-                         for _, size in items))
+    out = take(sum(_len_delimited_size(_PARAMETERS_FIELD, size)
+                   for _, size in items))
+    writer = _Writer(out)
     for tensor, size in items:
         writer.write(_tag(_PARAMETERS_FIELD, WT_LEN))
         writer.write(encode_varint(size))
         tensor.encode_into(writer)
-    return writer.getvalue()
+    assert writer.pos == len(out), (writer.pos, len(out))
+    return out.toreadonly()
 
 
 class PreEncodedParameterUpdate:
@@ -225,9 +241,7 @@ class PreEncodedParameterUpdate:
             writer.write(b"\x01")
 
     def encode(self) -> bytes:
-        writer = _Writer(self.encoded_size())
-        self.encode_into(writer)
-        return writer.getvalue()
+        return encode_fresh(self.encoded_size(), self.encode_into)
 
 
 class PSClient(RpcClient):
@@ -536,10 +550,12 @@ class PSClient(RpcClient):
                                        iteration=iteration, gradients=[],
                                        pull_wire_dtype=pull_wire_dtype)
 
-        # Same-host fast path: the SAME chunk messages, byte-encoded into
-        # the shared-memory rings instead of the gRPC channel.  Any shm
-        # failure downgrades this connection to TCP permanently and the
-        # round is replayed below (tensors_fn is replayable by contract).
+        # Same-host fast path: the SAME chunk messages, encoded straight
+        # into the shared-memory ring (the ring is the encoder's
+        # destination: no frame-sized buffer on this side) instead of
+        # into a `bytes` for the gRPC channel.  Any shm failure
+        # downgrades this connection to TCP permanently and the round is
+        # replayed below (tensors_fn is replayable by contract).
         conn = self._shm_connection(timeout)
         if conn is not None:
             # a shm round IS a fused PushPullStream round, just not over
@@ -559,13 +575,11 @@ class PSClient(RpcClient):
                                     target=self._target, transport="shm"):
                     ctx = obs_trace.wire_context()
 
-                    def encoded_frames() -> Iterator[bytes]:
+                    def stamped() -> Iterator[m.GradientUpdate]:
                         for chunk in chunks():
                             if ctx:
                                 chunk.trace_context = ctx
-                            with obs_trace.span("rpc/client/encode"):
-                                frame = chunk.encode()
-                            yield frame
+                            yield chunk
 
                     def decoded(frames) -> Iterator[m.PushPullResponse]:
                         for f in frames:
@@ -578,7 +592,7 @@ class PSClient(RpcClient):
                     # on_chunk as it leaves the ring, inside the
                     # connection's round lock
                     result = conn.round_trip(
-                        encoded_frames(), timeout,
+                        stamped(), timeout,
                         lambda frames: self._assemble_fused(
                             decoded(frames), on_chunk))
                 # the server just proved it speaks the fused protocol

@@ -39,6 +39,18 @@ platform CPython runs on — and each cursor has exactly one writer; the
 socket carries no data, only wakeups, so a lost/skipped doorbell is a
 latency blip, never a correctness problem (waits recheck the cursors).
 
+Produce side: a message bound for a ring is encoded INTO the ring
+(``ShmRing.write_message``): the ring writes the frame's length from the
+message's ``encoded_size()`` and hands ``encode_into`` a writer whose
+destination is the ring's own block loop, so a tensor's bytes go from
+their source array to the ring in one copy and no frame-sized buffer
+exists on this side (at the sizes a store is chunked into, a new buffer
+per frame is new address space, and its page faults, not the copy, set
+the encoder's pace).  A payload that needs a real pack (bf16, int8,
+top-k) is packed through a small scratch the ring end owns.  gRPC keeps
+``Message.encode()`` and its new ``bytes``: its serializer accepts
+nothing else.
+
 Consume side: a frame leaves a ring ONCE, into a receive buffer the ring
 end owns and has already touched (``_FramePool``: two per ring end, grown
 to the largest frame seen), and the caller gets a read-only view of it.
@@ -359,6 +371,9 @@ class ShmRing:
         # bytes of a length prefix
         self._pool = _FramePool()
         self._prefix = bytearray(4)
+        # produce side: where a payload that needs a real pack is packed
+        # (see _RingWriter)
+        self._scratch: bytearray | None = None
 
     # ------------------------------------------------------------- cursors
     def _tail(self) -> int:
@@ -490,26 +505,46 @@ class ShmRing:
     # must round-trip.
     _END = 0xFFFFFFFF
 
-    def write_frame(self, payload, deadline: float) -> None:
-        """One length-prefixed frame (zero-length payloads are legal).
-        Frames larger than the ring stream through it — the consumer
-        drains while the producer refills."""
-        with self._frame(bytes=len(payload)):
-            try:
-                self._write_bytes(struct.pack("<I", len(payload)), deadline)
-                if len(payload):
-                    self._write_bytes(payload, deadline)
-            except ValueError as exc:  # memoryview released under us
-                raise ShmTransportError(
-                    f"shm segment released: {exc}") from exc
-        _obs_bytes.add(4 + len(payload))
+    def _put(self, data, deadline: float) -> None:
+        try:
+            self._write_bytes(data, deadline)
+        except ValueError as exc:  # memoryview released under us
+            raise ShmTransportError(
+                f"shm segment released: {exc}") from exc
+
+    def write_message(self, message, deadline: float,
+                      encode_leg: str) -> None:
+        """One length-prefixed frame whose payload is ``message``
+        (anything with ``encoded_size()`` and ``encode_into(writer)``;
+        a zero-length payload is legal), encoded straight into the ring:
+        the bytes of ``message.encode()`` without the frame-sized
+        ``bytes`` in between.  Frames larger than the ring stream through
+        it — the consumer drains while the producer refills.
+
+        What is left of encoding is the span ``encode_leg``: the sizes,
+        before the frame's ``rpc/shm/copy`` opens, and a real pack into
+        the scratch, for which the copy leg is closed and opened again
+        (a frame that packs nothing is one ``rpc/shm/copy``).
+
+        If ``encode_into`` raises, or writes another count than
+        ``encoded_size()`` promised, the frame is torn (its length went
+        out first): the caller latches the rings closed."""
+        with obs_trace.span(encode_leg):
+            size = message.encoded_size()
+        with contextlib.ExitStack() as moving:
+            moving.enter_context(self._frame(bytes=size))
+            writer = _RingWriter(self, deadline, moving, encode_leg)
+            writer.write(struct.pack("<I", size))
+            message.encode_into(writer)
+        if writer.pos != 4 + size:
+            raise RuntimeError(
+                f"{type(message).__name__} encoded {writer.pos - 4} "
+                f"bytes into the ring, encoded_size() said {size}")
+        _obs_bytes.add(4 + size)
 
     def write_end(self, deadline: float) -> None:
         """End-of-stream marker for one request/response group."""
-        try:
-            self._write_bytes(struct.pack("<I", self._END), deadline)
-        except ValueError as exc:
-            raise ShmTransportError(f"shm segment released: {exc}") from exc
+        self._put(struct.pack("<I", self._END), deadline)
         _obs_bytes.add(4)
 
     # ------------------------------------------------------------- consume
@@ -576,6 +611,53 @@ class ShmRing:
         _obs_bytes.add(4 + length)
         _obs_frames.add()
         return payload
+
+
+class _RingWriter:
+    """What a message's ``encode_into`` sees when its destination is a
+    ring: ``wire._Writer``'s two methods over ``ShmRing._write_bytes``
+    (1 MB blocks, wrap, doorbell, the copy and wait legs as they are).
+
+    A payload whose wire form already lies in memory is written from
+    there (``ArrayPayload.wire_view``); one that needs a real pack goes
+    through the ring end's scratch piece by piece, each piece packed
+    under the encode leg with the frame's copy leg (``moving``) closed."""
+
+    __slots__ = ("_ring", "_deadline", "_moving", "_encode_leg", "pos")
+
+    # a few blocks: a cast's pieces keep the consumer's copy-out running
+    # beside the next piece's pack
+    _SCRATCH = 4 * ShmRing._BLOCK
+
+    def __init__(self, ring: ShmRing, deadline: float,
+                 moving: contextlib.ExitStack, encode_leg: str):
+        self._ring = ring
+        self._deadline = deadline
+        self._moving = moving
+        self._encode_leg = encode_leg
+        self.pos = 0
+
+    def write(self, data) -> None:
+        self.pos += len(data)
+        self._ring._put(data, self._deadline)
+
+    def write_array(self, payload) -> None:
+        view = payload.wire_view()
+        if view is not None:
+            self.write(view)
+            return
+        ring = self._ring
+        if ring._scratch is None:
+            ring._scratch = bytearray(self._SCRATCH)  # zeroed: touched
+        pieces = payload.pack_pieces(ring._scratch)
+        end = self.pos + payload.nbytes
+        while self.pos < end:
+            self._moving.close()
+            with obs_trace.span(self._encode_leg):
+                piece = next(pieces)
+            self._moving.enter_context(ring._frame())
+            # the piece moves on before the next one overwrites the scratch
+            self.write(piece)
 
 
 def _pretouch(shm) -> None:
@@ -645,19 +727,23 @@ class ShmClientConnection:
         # are the lock's purpose (BLOCKING_ALLOWED, analysis/lock_order.py)
         self._lock = checked_lock("ShmClientConnection._lock")
 
-    def round_trip(self, frames: Iterator[bytes], timeout: float | None,
+    def round_trip(self, messages: Iterator[Message],
+                   timeout: float | None,
                    consume: Callable[[Iterator[memoryview]], T]) -> T:
-        """One request/response exchange: stream the request frames out,
-        then hand ``consume`` an iterator over the response frames, each
-        read off the ring when the consumer asks for it, so the decode
-        of frame k runs while the server encodes and writes frame k+1 and
-        this end never holds more of the response than the receive pool.
-        Returns what ``consume`` returns.
+        """One request/response exchange: encode the request messages
+        into the ring, a frame each (``ShmRing.write_message``; what is
+        left of encoding is the ``rpc/client/encode`` leg), then hand
+        ``consume`` an iterator over the response frames, each read off
+        the ring when the consumer asks for it, so the decode of frame k
+        runs while the server encodes and writes frame k+1 and this end
+        never holds more of the response than the receive pool.  Returns
+        what ``consume`` returns.
 
         Everything happens INSIDE the round lock, and two things hold by
         construction.  The connection is never left half-read: frames the
         consumer did not take are drained to the server's end marker, and
-        if it (or the frame source) raises, both rings are latched closed.
+        if it (or the message source, or a message's encoder halfway
+        through its frame) raises, both rings are latched closed.
         No lazily-consumed iterator escapes the lock: the one ``consume``
         was given is exhausted or closed before this returns.  Each frame
         is a read-only view of a pool buffer (``ShmRing.read_frame``):
@@ -678,8 +764,9 @@ class ShmClientConnection:
                 # finds bytes never looks at the latch
                 raise ShmTransportError("shm connection latched closed")
             try:
-                for frame in frames:
-                    self.c2s.write_frame(frame, deadline)
+                for message in messages:
+                    self.c2s.write_message(message, deadline,
+                                           "rpc/client/encode")
                 self.c2s.write_end(deadline)
                 answer = response()
                 try:
@@ -691,8 +778,9 @@ class ShmClientConnection:
             except ShmTransportError:
                 raise
             except BaseException:
-                # the FRAME SOURCE (lazy D2H fetch, encode validation) or
-                # the CONSUMER (decode, the worker's converter) raised
+                # the MESSAGE SOURCE (lazy D2H fetch), an ENCODER (its
+                # frame's length is out, the frame is torn) or the
+                # CONSUMER (decode, the worker's converter) raised
                 # mid-round: the stream is desynced — the server is
                 # parked mid-round and would fold the NEXT round's frames
                 # into this one, or is still writing a response nobody
@@ -848,9 +936,8 @@ class _ServerConnection:
                 deadline = time.monotonic() + 3600.0
                 try:
                     for resp in self._handler(chunks(), None):
-                        with obs_trace.span("rpc/server/encode"):
-                            frame = resp.encode()
-                        self.s2c.write_frame(frame, deadline)
+                        self.s2c.write_message(resp, deadline,
+                                               "rpc/server/encode")
                 finally:
                     holder.finish()
                     flight.record(

@@ -27,10 +27,12 @@ from __future__ import annotations
 
 import ctypes
 import struct
-from typing import Any, Callable
+import sys
+from typing import Any, Callable, Iterator
 
 import numpy as np
 
+from ..obs import stats as obs_stats
 from . import codec as _codec
 
 # Wire types
@@ -148,10 +150,11 @@ class Message:
         place.  Naive bytearray appending copies each nested tensor body
         ~3x (child buffer -> parent growth -> final bytes); at config-3
         scale (hundreds of MB per push) those copies dominate push/pull
-        latency, so the encoder is exactly-sized instead."""
-        writer = _Writer(self.encoded_size())
-        self.encode_into(writer)
-        return writer.getvalue()
+        latency, so the encoder is exactly-sized instead.  The
+        destination here is a new ``bytes``, which is what gRPC's
+        serializer demands; a shared-memory ring hands ``encode_into`` a
+        writer over itself (rpc/shm_transport.py ``write_message``)."""
+        return encode_fresh(self.encoded_size(), self.encode_into)
 
     def encoded_size(self) -> int:
         return sum(_field_size(f, getattr(self, f.name))
@@ -270,6 +273,43 @@ class ArrayPayload:
             _codec.active_codec().pack_into(self.wire_dtype, self.src, dst,
                                             self.k)
 
+    def wire_view(self) -> memoryview | None:
+        """The payload's bytes where they already lie in memory: the
+        cached materialisation, or the source's own bytes where the wire
+        form IS the source (raw f32 on a little-endian host).  None when
+        the payload needs a real pack (:meth:`pack_pieces`)."""
+        if self._cache is not None:
+            return memoryview(self._cache)
+        if self.wire_dtype == _codec.WIRE_RAW_F32 \
+                and sys.byteorder == "little":
+            return memoryview(self.src).cast("B")
+        return None
+
+    def pack_pieces(self, scratch: bytearray) -> Iterator[memoryview]:
+        """The payload's exact bytes, packed through ``scratch`` a piece
+        at a time: each piece is a view of the scratch and is overwritten
+        by the next, so the caller moves it on before it asks again.  An
+        elementwise encoding (a dtype cast) packs as many elements as
+        the scratch holds a piece; int8's scale and top-k's selection
+        read the whole source, so such a payload packs in one piece and
+        the scratch grows to hold it."""
+        codec = _codec.active_codec()
+        per = _codec.ELEMENT_BYTES.get(self.wire_dtype)
+        if per is None:
+            if len(scratch) < self.nbytes:
+                scratch.extend(bytes(self.nbytes - len(scratch)))
+            out = memoryview(scratch)[:self.nbytes]
+            codec.pack_into(self.wire_dtype, self.src, out, self.k)
+            yield out
+            return
+        view = memoryview(scratch)
+        step = len(scratch) // per
+        for start in range(0, self.src.size, step):
+            piece = self.src[start:start + step]
+            out = view[:per * piece.size]
+            codec.pack_into(self.wire_dtype, piece, out)
+            yield out
+
     def tobytes(self) -> bytes:
         if self._cache is None:
             buf = bytearray(self.nbytes)
@@ -297,9 +337,17 @@ _pyapi.PyBytes_FromStringAndSize.argtypes = [ctypes.c_char_p, ctypes.c_ssize_t]
 _pyapi.PyBytes_AsString.restype = ctypes.c_void_p
 _pyapi.PyBytes_AsString.argtypes = [ctypes.py_object]
 
+# Bytes of encoder output that went to NEW memory.  At the sizes a
+# parameter store is chunked into, a new buffer is new address space and
+# every 4 KB of it a page fault, so this is what an encode costs beyond
+# its copy; a destination that is handed in (a ring, a buffer the serve
+# cache owns) adds nothing here.
+_obs_fresh_bytes = obs_stats.counter("rpc.wire.fresh_bytes")
+
 
 def _alloc_uninit_bytes(size: int) -> tuple[bytes, np.ndarray]:
     """Return (bytes_of_len_size, writable uint8 view into it)."""
+    _obs_fresh_bytes.add(size)
     obj = _pyapi.PyBytes_FromStringAndSize(None, size)
     addr = _pyapi.PyBytes_AsString(obj)
     view = np.frombuffer((ctypes.c_ubyte * size).from_address(addr), np.uint8)
@@ -307,19 +355,17 @@ def _alloc_uninit_bytes(size: int) -> tuple[bytes, np.ndarray]:
 
 
 class _Writer:
-    """Exact-size in-place buffer writer (see Message.encode), backed by an
-    uninitialized `bytes` object so ``getvalue()`` is zero-copy (gRPC's
-    serializer contract requires `bytes`; anything else would force a final
-    whole-message copy)."""
+    """Exact-size in-place writer over a destination the caller hands it:
+    ``write`` stores bytes, ``write_array`` lets an :class:`ArrayPayload`
+    pack itself, both at the running position.  What the destination is
+    is the caller's choice (:func:`encode_fresh`: a new ``bytes``; the
+    serve cache: a buffer it owns); a shared-memory ring has a writer of
+    its own with the same two methods (rpc/shm_transport.py)."""
 
-    __slots__ = ("_out", "buf", "_view", "pos")
+    __slots__ = ("_view", "pos")
 
-    def __init__(self, size: int):
-        if size:
-            self._out, self.buf = _alloc_uninit_bytes(size)
-        else:
-            self._out, self.buf = b"", np.empty(0, np.uint8)
-        self._view = memoryview(self.buf)
+    def __init__(self, view: memoryview):
+        self._view = view
         self.pos = 0
 
     def write(self, data) -> None:
@@ -335,9 +381,20 @@ class _Writer:
         payload.pack_into(self._view[self.pos:self.pos + n])
         self.pos += n
 
-    def getvalue(self) -> bytes:
-        assert self.pos == len(self._out), (self.pos, len(self._out))
-        return self._out
+
+def encode_fresh(size: int,
+                 encode_into: Callable[["_Writer"], None]) -> bytes:
+    """Run ``encode_into`` with a writer over a new, uninitialised
+    ``bytes`` of exactly ``size`` and return that object: zero-copy, and
+    the only form gRPC's serializer accepts (anything else would force a
+    final whole-message copy)."""
+    if not size:
+        return b""
+    out, buf = _alloc_uninit_bytes(size)
+    writer = _Writer(memoryview(buf))
+    encode_into(writer)
+    assert writer.pos == size, (writer.pos, size)
+    return out
 
 
 def _varint_size(value: int) -> int:

@@ -18,7 +18,11 @@ Two server-side hot-path optimizations live here (ISSUE 3):
   (:class:`EncodedServeCache`), so the post-barrier fan-out to N workers
   runs ONE `to_wire` encode instead of N.  The version key makes
   invalidation automatic: apply/restore/initialize bump the core's store
-  version and the next serve re-encodes.
+  version and the next serve re-encodes.  The bodies are built into
+  buffers the cache owns and reuses from version to version (the store's
+  shapes do not change, so neither do the bodies' sizes): at the sizes a
+  store is chunked into, a new buffer per body is new address space and a
+  page fault every 4 KB of it.
 - **Stripe-parallel miss encode** (ISSUE 5): the one real encode per
   version fans its per-chunk payload passes across the shared stripe
   executor (core/stripes.py), so a multi-chunk store encodes on multiple
@@ -61,19 +65,40 @@ from ..rpc.service import bind_service, make_server
 
 log = logging.getLogger("pst.ps")
 
+# rpc/wire.py's counter of encoder output that went to new memory: a body
+# buffer the serve cache has to allocate is such output
+_obs_fresh_bytes = obs_stats.counter("rpc.wire.fresh_bytes")
+
 
 class _ServeCacheEntry:
-    __slots__ = ("event", "bodies", "failed", "version")
+    __slots__ = ("event", "bodies", "failed", "version", "buffers")
 
     def __init__(self):
         self.event = threading.Event()
-        self.bodies: list[bytes] | None = None
+        self.bodies: list[memoryview] | None = None
         self.failed = False
         # store version the bodies were ACTUALLY encoded at (may differ
         # from the probe key's when the store advanced mid-build) — the
         # delta protocol stamps it on full serves so the receiver's base
         # version is exact, never the probe's guess
         self.version = -1
+        # the buffers the bodies are built into, one per body: those of
+        # the entry this one retired (EncodedServeCache.lookup) until
+        # take() finds one short, missing or still read
+        self.buffers: list[bytearray] = []
+
+    def take(self, place: int, size: int) -> memoryview:
+        """A writable view of exactly ``size`` bytes for body ``place``:
+        over the buffer the retired version's body lay in when nothing
+        reads it any more (``shm_transport._exported``) and it is large
+        enough, over a new one otherwise.  Each place has one taker."""
+        buffers = self.buffers
+        buffers.extend([None] * (place + 1 - len(buffers)))
+        buf = buffers[place]
+        if buf is None or len(buf) < size or shm_transport._exported(buf):
+            _obs_fresh_bytes.add(size)
+            buf = buffers[place] = bytearray(size)  # zeroed: touched
+        return memoryview(buf)[:size]
 
 
 class EncodedServeCache:
@@ -86,13 +111,36 @@ class EncodedServeCache:
     the post-barrier fan-out is exactly the situation where N pullers
     arrive at once.  Entries for superseded versions are dropped on
     insert, so the cache holds at most the current version's encodings
-    (one per requested wire dtype)."""
+    (one per requested wire dtype).
+
+    A dropped entry hands the BUFFERS its bodies lay in to the entry of
+    the same kind (wire dtype, chunk budget) that retires it, and the new
+    version's bodies are built into them (``_ServeCacheEntry.take``).  A
+    puller still streaming the retired version keeps the views it holds
+    and with them the buffer, and the new entry allocates in its place:
+    the cache keeps one generation of bodies, and a second only while
+    someone still reads the one before.  So that two wire dtypes pulled
+    side by side both find their buffers, an insert drops the entries of
+    ANOTHER kind only when they are more than one version behind."""
 
     def __init__(self):
         # leaf rank: held only around dict ops, never while acquiring a
         # core lock (analysis/lock_order.py)
         self._lock = checked_lock("EncodedServeCache._lock")
         self._entries: dict[tuple, _ServeCacheEntry] = {}
+
+    def _retire(self, version: int, kind: tuple,
+                heir: _ServeCacheEntry) -> None:
+        """Drop what ``version`` of ``kind`` supersedes (lock held): the
+        older entries of that kind, their buffers going to ``heir`` (a
+        list of its own: a builder the store overtook may still be taking
+        from the retired one), and the entries of other kinds more than
+        one version behind."""
+        for stale in [k for k in self._entries if k[0] < version]:
+            if stale[1:] == kind:
+                heir.buffers = list(self._entries.pop(stale).buffers)
+            elif stale[0] < version - 1:
+                del self._entries[stale]
 
     def lookup(self, key: tuple) -> tuple[_ServeCacheEntry, bool]:
         """Returns (entry, is_builder).  A builder MUST call :meth:`fill`
@@ -105,9 +153,7 @@ class EncodedServeCache:
             if entry is not None:
                 return entry, False
             entry = _ServeCacheEntry()
-            version = key[0]
-            for stale in [k for k in self._entries if k[0] < version]:
-                del self._entries[stale]
+            self._retire(key[0], key[1:], entry)
             self._entries[key] = entry
             return entry, True
 
@@ -335,7 +381,7 @@ class ParameterServerService:
         return float(os.environ.get("PSDT_SERVE_CACHE_WAIT_S", "20"))
 
     def _encode_chunk_bodies(self, request_iteration: int, eff_dtype: int,
-                             budget: int):
+                             budget: int, entry: _ServeCacheEntry):
         """One real encode pass: (chunk bodies, store version) — the
         single shared recipe under the cache.  The per-chunk payload
         encodes (f32→bf16 casts, repeated-float packs) fan out across the
@@ -344,13 +390,14 @@ class ParameterServerService:
         multi-chunk store runs on multiple cores, and every consumer
         collects the whole body list anyway before touching the network
         (see _parameter_chunks for why the fill must not be
-        client-paced)."""
+        client-paced).  The bodies are built into ``entry``'s buffers."""
         _, params, _, version = self.core.serve_view(request_iteration)
         with obs_trace.span("rpc/server/encode", version=version):
             tensors = to_wire(params, wire_dtype=eff_dtype)
             bodies = encode_parameter_record_groups(
-                list(split_tensors(tensors, budget)),
+                list(split_tensors(tensors, budget)), entry.take,
                 stripes=self.core.stripes)
+        del entry.buffers[len(bodies):]  # a smaller store's spare places
         return bodies, version
 
     def _serve_key(self, wire_dtype: int) -> tuple:
@@ -369,7 +416,8 @@ class ParameterServerService:
             self._obs_cache_hit.add()
             return entry.bodies, True, entry.version
         self._obs_cache_miss.add()
-        bodies, version = self._encode_chunk_bodies(0, key[1], key[2])
+        bodies, version = self._encode_chunk_bodies(
+            0, key[1], key[2], _ServeCacheEntry())
         return bodies, False, version
 
     def _encoded_parameter_chunks(self, request_iteration: int,
@@ -399,7 +447,7 @@ class ParameterServerService:
                 self._obs_cache_miss.add()
                 try:
                     bodies, version = self._encode_chunk_bodies(
-                        request_iteration, key[1], key[2])
+                        request_iteration, key[1], key[2], entry)
                 except BaseException:
                     self._serve_cache.fail(key, entry)
                     raise

@@ -535,6 +535,120 @@ def test_serve_cache_fill_never_resurrects_superseded_version():
     assert not builder and entry.bodies == [b"v3"]
 
 
+def _body_addresses(service):
+    """kind -> the addresses of the buffers its newest entry's bodies
+    lie in."""
+    from parameter_server_distributed_tpu.rpc.shm_transport import _address
+
+    entries = service._serve_cache._entries
+    return {key[1:]: [_address(buf) for buf in entries[key].buffers]
+            for key in sorted(entries)}
+
+
+@pytest.mark.parametrize("wire_dtype", [m.WIRE_F32, m.WIRE_BF16],
+                         ids=["f32", "bf16"])
+def test_serve_cache_builds_each_version_into_the_same_buffers(
+        monkeypatch, wire_dtype):
+    """The store's shapes do not change, so every version's bodies have
+    the last one's sizes: the second version and every later one is built
+    into the first one's buffers, and no encoder output goes to new
+    memory after that."""
+    monkeypatch.setenv("PSDT_STREAM_CHUNK_BYTES", "4096")   # 3 or 2 bodies
+    rng = np.random.default_rng(4)
+    params = {f"w{i}": rng.standard_normal((40, 20)).astype(np.float32)
+              for i in range(3)}
+    core = ParameterServerCore(total_workers=1, aggregation="streaming")
+    core.initialize_parameters(params)
+    service = _make_service(core)
+    fresh = obs_stats.counter("rpc.wire.fresh_bytes")
+    served = service._encoded_parameter_chunks(0, wire_dtype)
+    places = len(served)
+    assert places > 1 and all(
+        isinstance(body, memoryview) and body.readonly for body in served)
+    first = _body_addresses(service)
+    assert [len(held) for held in first.values()] == [places]
+    del served
+    after_first = fresh.value
+    step = {name: np.ones_like(value) for name, value in params.items()}
+    for version in range(1, 4):
+        core.receive_gradients(0, version, step)
+        got = _decode_serve(service, wire_dtype=wire_dtype)
+        for name, value in params.items():
+            np.testing.assert_allclose(got[name], value - version,
+                                       rtol=8e-3)
+        assert _body_addresses(service) == first
+    # _decode_serve's own encode() of each chunk is gRPC's: new bytes, as
+    # at the parent; the bodies under it added nothing
+    bodies = service._encoded_parameter_chunks(0, wire_dtype)
+    per_serve = sum(len(body) for body in bodies) + places * 4  # it, ready
+    assert fresh.value - after_first == 3 * per_serve
+
+
+def test_serve_cache_reader_of_a_retired_version_keeps_its_bytes(
+        monkeypatch):
+    """A puller still streaming version N-1 while version N+1 is built
+    holds views of N-1's bodies: those buffers are its own from then on
+    (the bytes do not change under it), and the cache puts new ones in
+    their places."""
+    monkeypatch.setenv("PSDT_STREAM_CHUNK_BYTES", "4096")
+    rng = np.random.default_rng(6)
+    params = {f"w{i}": rng.standard_normal((40, 20)).astype(np.float32)
+              for i in range(2)}
+    core = ParameterServerCore(total_workers=1, aggregation="streaming")
+    core.initialize_parameters(params)
+    service = _make_service(core)
+    held = service._encoded_parameter_chunks(0, 0)     # version N-1
+    snapshot = [bytes(body) for body in held]
+    (first,) = _body_addresses(service).values()
+    step = {name: np.ones_like(value) for name, value in params.items()}
+    core.receive_gradients(0, 1, step)
+    _decode_serve(service)                              # version N
+    (second,) = _body_addresses(service).values()
+    assert len(second) == len(first)
+    assert not set(second) & set(first)
+    core.receive_gradients(0, 2, step)
+    got = _decode_serve(service)                        # version N+1
+    np.testing.assert_allclose(got["w0"], params["w0"] - 2.0, rtol=1e-6)
+    assert list(_body_addresses(service).values()) == [second]  # N's, free
+    assert [bytes(body) for body in held] == snapshot
+    decoded = m.ParameterUpdate.decode(snapshot[0])
+    np.testing.assert_array_equal(decoded.parameters[0].to_array(),
+                                  params["w0"])
+
+
+def test_serve_cache_keeps_two_kinds_and_lets_go_of_an_idle_one(
+        monkeypatch):
+    """Two wire dtypes pulled side by side both build into their last
+    version's buffers, whichever is asked for first; a dtype nobody pulls
+    for two versions gives its buffers back, and so do the places past a
+    smaller store's."""
+    monkeypatch.setenv("PSDT_STREAM_CHUNK_BYTES", "4096")
+    rng = np.random.default_rng(8)
+    params = {f"w{i}": rng.standard_normal((40, 20)).astype(np.float32)
+              for i in range(2)}
+    core = ParameterServerCore(total_workers=1, aggregation="streaming")
+    core.initialize_parameters(params)
+    service = _make_service(core)
+    _decode_serve(service, wire_dtype=m.WIRE_BF16)
+    _decode_serve(service)
+    first = _body_addresses(service)
+    assert {kind[0] for kind in first} == {m.WIRE_F32, m.WIRE_BF16}
+    step = {name: np.ones_like(value) for name, value in params.items()}
+    core.receive_gradients(0, 1, step)
+    _decode_serve(service)
+    _decode_serve(service, wire_dtype=m.WIRE_BF16)
+    assert _body_addresses(service) == first
+    for version in (2, 3):
+        core.receive_gradients(0, version, step)
+        _decode_serve(service)
+    (kind,) = _body_addresses(service)
+    assert kind[0] == m.WIRE_F32
+    assert _body_addresses(service)[kind] == first[kind]
+    core.initialize_parameters({"w0": params["w0"]})    # a smaller store
+    _decode_serve(service)
+    assert _body_addresses(service)[kind] == first[kind][:1]
+
+
 def test_serve_cache_empty_store_single_empty_chunk():
     core = ParameterServerCore(total_workers=1)
     service = _make_service(core)
@@ -555,14 +669,18 @@ def test_preencoded_parameter_update_is_byte_identical():
                        "b": rng.standard_normal(7).astype(np.float32)})
     plain = m.ParameterUpdate(iteration=9, parameters=tensors,
                               ready=True).encode()
+    def take(size):
+        return memoryview(bytearray(size))
+
     pre = PreEncodedParameterUpdate(
-        9, True, [encode_parameter_records(tensors)]).encode()
+        9, True, [encode_parameter_records(tensors, take)]).encode()
     assert plain == pre
     # default elision: iteration 0 / ready False elide exactly alike
     assert (m.ParameterUpdate(iteration=0, parameters=tensors,
                               ready=False).encode()
             == PreEncodedParameterUpdate(
-                0, False, [encode_parameter_records(tensors)]).encode())
+                0, False,
+                [encode_parameter_records(tensors, take)]).encode())
 
 
 def test_fanout_runs_one_encode_per_version_and_dtype(tmp_path):

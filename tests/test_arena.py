@@ -634,7 +634,8 @@ def test_serve_and_delta_bytes_identical(numpy_oracle, rng):
         _closes(core, grads, workers=1)
         _, store, _, _ = core.serve_view()
         bodies = encode_parameter_record_groups(
-            [g for g in split_tensors(to_wire(store), 1 << 20)], 2)
+            [g for g in split_tensors(to_wire(store), 1 << 20)],
+            lambda i, size: memoryview(bytearray(size)), stripes=2)
         pairs = [(fv, p.to_version, p.crc, p.changed, p.entries)
                  for fv, p in chain._pairs.items()]
         return bodies, pairs

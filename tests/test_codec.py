@@ -112,8 +112,10 @@ def test_fuzz_record_groups_and_chunk_budgets(rng):
                 try:
                     groups = list(split_tensors(
                         to_wire(store, wire_dtype=wd), budget))
-                    bodies[enabled] = [encode_parameter_records(g)
-                                      for g in groups]
+                    bodies[enabled] = [
+                        encode_parameter_records(
+                            g, lambda size: memoryview(bytearray(size)))
+                        for g in groups]
                 finally:
                     native.set_enabled(True)
             assert bodies[True] == bodies[False], \

@@ -277,14 +277,17 @@ def test_shm_ring_invalidate_degrades_cleanly():
                                      + 4096)
     try:
         ring = shm_transport.ShmRing(seg, 4096)
-        ring.write_frame(b"abc", time.monotonic() + 5)
+        hello = shm_transport.ShmNegotiateRequest(host_id="abc")
+        ring.write_message(hello, time.monotonic() + 5,
+                           "rpc/client/encode")
         ring.invalidate()
         assert ring._base == 0 and ring._copy is None
         # the memoryview fallback still works while the segment is mapped
-        assert ring.read_frame(time.monotonic() + 5) == b"abc"
+        assert ring.read_frame(time.monotonic() + 5) == hello.encode()
         seg.close()  # unmap under the ring
         with pytest.raises(shm_transport.ShmTransportError):
-            ring.write_frame(b"xyz", time.monotonic() + 1)
+            ring.write_message(hello, time.monotonic() + 1,
+                               "rpc/client/encode")
     finally:
         try:
             seg.close()

@@ -297,6 +297,24 @@ def test_ps_round_legs_are_disjoint_and_cover_the_step(cluster1,
     assert count["rpc/shm/wait"] <= count["rpc/shm/copy"]
     assert served["ps/close"] == served["ps/apply"] == 1
     assert served["rpc/server/encode"] == 1 + received
+    # a frame is encoded INTO the ring: what is left of encoding on an f32
+    # wire (the sizes) is one span a frame, before the frame's copy leg,
+    # and nests where the frame-sized encode used to: under the client's
+    # call, and on the server under the serve leg (the push verdict, which
+    # goes out before it, under the handler's span)
+    by_id = {s["span_id"]: s for s in spans}
+    call, = [s for s in mine if s["name"] == "rpc/client/PushPullStream"]
+    frames = [s for s in mine if s["name"] == "rpc/client/encode"
+              and "tensor" not in s.get("args", ())]
+    assert len(frames) == sent
+    assert all(s["parent_id"] == call["span_id"] for s in frames)
+    answers = [s for s in theirs if s["name"] == "rpc/server/encode"
+               and "version" not in s.get("args", ())]
+    assert [by_id[s["parent_id"]]["name"] for s in answers] == \
+        ["rpc/server/PushPullStream"] + ["ps/serve"] * (received - 1)
+    build, = [s for s in theirs if s["name"] == "rpc/server/encode"
+              and "version" in s.get("args", ())]
+    assert by_id[build["parent_id"]]["name"] == "ps/serve"
     # the response is consumed as it arrives: each frame that leaves the
     # ring is decoded (and its chunk converted) before the next is read,
     # so the decode spans lie between the ring reads of the response and

@@ -19,7 +19,8 @@ from parameter_server_distributed_tpu.config import ParameterServerConfig
 from parameter_server_distributed_tpu.obs import stats as obs_stats
 from parameter_server_distributed_tpu.rpc import messages as m
 from parameter_server_distributed_tpu.rpc import shm_transport as st
-from parameter_server_distributed_tpu.rpc.data_plane import PSClient
+from parameter_server_distributed_tpu.rpc.data_plane import (
+    PreEncodedParameterUpdate, PSClient, encode_parameter_records)
 from parameter_server_distributed_tpu.server.ps_service import ParameterServer
 
 
@@ -44,6 +45,23 @@ def _cleanup(seg):
         pass
 
 
+class _Bytes:
+    """Bytes that already exist, as a message a ring can send."""
+
+    def __init__(self, data):
+        self.data = data
+
+    def encoded_size(self):
+        return len(self.data)
+
+    def encode_into(self, writer):
+        writer.write(self.data)
+
+
+def _write(ring, data, deadline):
+    ring.write_message(_Bytes(data), deadline, "rpc/client/encode")
+
+
 # ---------------------------------------------------------------- ring unit
 
 @pytest.mark.parametrize("doorbell", [True, False],
@@ -65,7 +83,7 @@ def test_ring_frame_roundtrip_and_wrap(doorbell):
         th = threading.Thread(target=consume, daemon=True, name="t-cons")
         th.start()
         for p in payloads:
-            prod.write_frame(p, time.monotonic() + 20)
+            _write(prod, p, time.monotonic() + 20)
         th.join(timeout=20)
         assert not th.is_alive()
         assert got == payloads
@@ -85,7 +103,7 @@ def test_ring_frame_larger_than_capacity_streams_through():
                 time.monotonic() + 30)),
             daemon=True, name="t-cons")
         th.start()
-        prod.write_frame(big, time.monotonic() + 30)
+        _write(prod, big, time.monotonic() + 30)
         th.join(timeout=30)
         assert out and out[0] == big
     finally:
@@ -109,8 +127,8 @@ def test_ring_empty_data_frame_distinct_from_end_marker():
 
         th = threading.Thread(target=consume, daemon=True, name="t-cons")
         th.start()
-        prod.write_frame(b"", time.monotonic() + 10)
-        prod.write_frame(b"x", time.monotonic() + 10)
+        _write(prod, b"", time.monotonic() + 10)
+        _write(prod, b"x", time.monotonic() + 10)
         prod.write_end(time.monotonic() + 10)
         th.join(timeout=10)
         assert got == [b"", b"x", None]
@@ -154,7 +172,7 @@ def _send(prod, payloads, end=True):
     """Write ``payloads`` (and the end marker) from a thread of its own."""
     def produce():
         for p in payloads:
-            prod.write_frame(p, time.monotonic() + 30)
+            _write(prod, p, time.monotonic() + 30)
         if end:
             prod.write_end(time.monotonic() + 30)
 
@@ -296,7 +314,7 @@ def test_frame_view_is_read_only_and_to_array_owns_its_data():
             worker_id=0, iteration=2,
             gradients=[m.Tensor.from_array("w", -values.reshape(64, 64))])
         for msg in (update, other, other):
-            prod.write_frame(msg.encode(), time.monotonic() + 10)
+            _write(prod, msg.encode(), time.monotonic() + 10)
         frame = cons.read_frame(time.monotonic() + 10)
         assert frame.readonly
         with pytest.raises(TypeError):
@@ -352,6 +370,191 @@ def test_invalidate_during_read_falls_back_then_fails_cleanly():
         seg.close()                 # unmap under the parked reader
         th.join(timeout=10)
         assert not th.is_alive() and len(errs) == 1
+    finally:
+        _cleanup(seg)
+
+
+# ---------------------------------------------------- ring produce side
+
+def _tensors(wire_dtype):
+    """Three tensors whose first payload is long enough for a wrap to fall
+    inside it; top-k keeps half so that its payload is, too."""
+    rng = np.random.default_rng(wire_dtype)
+    return [m.Tensor.from_array(name, rng.standard_normal(shape)
+                                .astype(np.float32), wire_dtype=wire_dtype,
+                                topk_density=0.5)
+            for name, shape in (("a", (700,)), ("bias", (5,)),
+                                ("layer/w", (3, 11)))]
+
+
+def _gradients(wire_dtype):
+    return m.GradientUpdate(worker_id=3, iteration=7,
+                            gradients=_tensors(wire_dtype),
+                            pull_wire_dtype=m.WIRE_BF16,
+                            trace_context=b"0123456789abcdef/01234567")
+
+
+def _response(bodies):
+    """A served chunk as the fused handler yields it.  One body is a
+    ``bytes``, the other a read-only view of a buffer handed in, which
+    is what the serve cache keeps."""
+    def take(size):
+        return memoryview(bytearray(size))
+
+    made = [bytes(encode_parameter_records(_tensors(m.WIRE_F32), take)),
+            encode_parameter_records(_tensors(m.WIRE_BF16), take)]
+    return m.PushPullResponse(
+        params=PreEncodedParameterUpdate(9, True, made[:bodies]))
+
+
+MESSAGES = {
+    "gradients_f32": lambda: _gradients(m.WIRE_F32),
+    "gradients_raw_f32": lambda: _gradients(m.WIRE_RAW_F32),
+    "gradients_bf16": lambda: _gradients(m.WIRE_BF16),
+    "gradients_int8": lambda: _gradients(m.WIRE_INT8),
+    "gradients_topk": lambda: _gradients(m.WIRE_TOPK),
+    "response_one_body": lambda: _response(1),
+    "response_two_bodies": lambda: _response(2),
+    "all_default": m.GradientUpdate,
+}
+RING_OVER_FRAME = {"frame_smaller": 4.0, "frame_equal": 1.0,
+                   "frame_several_rings": 0.2}
+# where the ring wraps, in bytes after the frame's first: inside the length
+# and headers, inside the first payload (for bf16, int8 and top-k a piece of
+# the scratch), late in the frame
+WRAP_AFTER = {"wrap_in_header": 6, "wrap_in_payload": 90, "wrap_late": None}
+
+
+@pytest.mark.parametrize("native_copy", [True, False],
+                         ids=["native", "memoryview"])
+@pytest.mark.parametrize("wrap", list(WRAP_AFTER))
+@pytest.mark.parametrize("ring", list(RING_OVER_FRAME))
+@pytest.mark.parametrize("kind", list(MESSAGES))
+def test_message_encoded_into_the_ring_is_its_encode(
+        kind, ring, wrap, native_copy, monkeypatch):
+    """A message sent through the ring arrives byte for byte as its
+    ``encode()``, with no encoder output in new memory: every payload
+    encoding, a response around one and around several pre-encoded
+    bodies, a fully-default message (a zero-length frame), each followed
+    by the end marker; frames smaller than, equal to and several times
+    the ring; the wrap inside the headers, a payload or the scratch;
+    through the native copy and the memoryview fallback."""
+    # a small scratch, so that payloads of a few KB pack through several
+    # pieces of it
+    monkeypatch.setattr(st._RingWriter, "_SCRATCH", 512)
+    message = MESSAGES[kind]()
+    expected = message.encode()
+    if kind == "all_default":
+        assert expected == b""
+    frame = 4 + len(expected)
+    capacity = max(16, int(frame * RING_OVER_FRAME[ring]))
+    seg, prod, cons = _ring_pair(capacity=capacity)
+    try:
+        if not native_copy:
+            prod.invalidate()
+        elif prod._copy is None:
+            pytest.skip("no native library on this machine")
+        after = WRAP_AFTER[wrap]
+        lead = capacity - (after if after is not None else capacity // 3)
+        lead %= capacity
+        if lead >= 4:       # a frame before it puts the tail where we want
+            th = _send(prod, [bytes(lead - 4)], end=False)
+            assert len(cons.read_frame(time.monotonic() + 30)) == lead - 4
+            th.join(timeout=30)
+            assert prod._tail() == lead
+
+        def produce():
+            deadline = time.monotonic() + 30
+            prod.write_message(message, deadline, "test/encode")
+            prod.write_end(deadline)
+
+        fresh = obs_stats.counter("rpc.wire.fresh_bytes")
+        before = fresh.value
+        th = threading.Thread(target=produce, daemon=True, name="t-prod")
+        th.start()
+        assert _consume_group(cons) == [expected]
+        th.join(timeout=30)
+        assert not th.is_alive()
+        assert fresh.value == before
+        assert prod._tail() == (lead if lead >= 4 else 0) + frame + 4
+    finally:
+        _cleanup(seg)
+
+
+def test_large_cast_goes_through_the_scratch_in_pieces():
+    """At the thresholds the data plane runs with: a bf16 payload of 6 MB
+    through a 4 MB scratch and a 1 MB ring, beside a bias of one short
+    piece and an int8 payload packed whole.  The scratch is the ring
+    end's own and is allocated once."""
+    rng = np.random.default_rng(5)
+    message = m.GradientUpdate(worker_id=1, iteration=2, gradients=[
+        m.Tensor.from_array("w", rng.standard_normal(3 << 20)
+                            .astype(np.float32), wire_dtype=m.WIRE_BF16),
+        m.Tensor.from_array("b", rng.standard_normal(9)
+                            .astype(np.float32), wire_dtype=m.WIRE_BF16),
+        m.Tensor.from_array("q", rng.standard_normal(1 << 20)
+                            .astype(np.float32), wire_dtype=m.WIRE_INT8)])
+    expected = message.encode()
+    seg, prod, cons = _ring_pair(capacity=1 << 20)
+    try:
+        for _ in range(2):
+            th = threading.Thread(
+                target=prod.write_message, daemon=True,
+                args=(message, time.monotonic() + 60, "test/encode"))
+            th.start()
+            assert bytes(cons.read_frame(time.monotonic() + 60)) == expected
+            th.join(timeout=60)
+            assert not th.is_alive()
+            scratch = prod._scratch
+            assert len(scratch) == st._RingWriter._SCRATCH
+        assert prod._scratch is scratch
+    finally:
+        _cleanup(seg)
+
+
+def test_packed_frame_is_encode_and_copy_legs_in_turn(monkeypatch):
+    """What is left of encoding has spans of its own and no time under
+    the copy leg: the sizes before the frame's ``rpc/shm/copy`` opens,
+    and each piece's pack with the copy leg closed, so the legs of a
+    frame lie one after the other; a frame that packs nothing is one
+    encode span and one copy span."""
+    from parameter_server_distributed_tpu.obs import trace as obs_trace
+
+    monkeypatch.setattr(st._RingWriter, "_SCRATCH", 512)
+    values = np.arange(600, dtype=np.float32)
+    seg, prod, cons = _ring_pair(capacity=1 << 16)
+    obs_trace.clear()
+    obs_trace.enable(True)
+    try:
+        for wire_dtype, pieces in ((m.WIRE_RAW_F32, 0), (m.WIRE_BF16, 3)):
+            message = m.GradientUpdate(worker_id=1, gradients=[
+                m.Tensor.from_array("w", values, wire_dtype=wire_dtype)])
+            prod.write_message(message, time.monotonic() + 5, "test/encode")
+            legs = sorted(obs_trace.spans(), key=lambda s: s["ts"])
+            assert cons.read_frame(time.monotonic() + 5) == message.encode()
+            obs_trace.clear()
+            assert [s["name"] for s in legs] == \
+                ["test/encode", "rpc/shm/copy"] * (1 + pieces)
+            assert all(a["ts"] + a["dur"] <= b["ts"] + 1e-4
+                       for a, b in zip(legs, legs[1:]))
+    finally:
+        obs_trace.enable(False)
+        obs_trace.clear()
+        _cleanup(seg)
+
+
+def test_message_that_miscounts_its_size_tears_the_frame():
+    """``encode_into`` writing another count than ``encoded_size()`` said
+    cannot be repaired (the length went out first): it raises."""
+    class Short(m.GradientUpdate):
+        def encoded_size(self):
+            return super().encoded_size() + 1
+
+    seg, prod, cons = _ring_pair(capacity=1 << 16)
+    try:
+        with pytest.raises(RuntimeError, match="encoded_size"):
+            prod.write_message(Short(worker_id=1), time.monotonic() + 5,
+                               "test/encode")
     finally:
         _cleanup(seg)
 
@@ -680,10 +883,10 @@ def test_client_consumer_mid_response_leaves_connection_usable(
                 conn = client._shm_conn
                 assert conn.c2s.closed and conn.s2c.closed
             else:
-                frames = [m.GradientUpdate(worker_id=0, iteration=2,
-                                           gradients=grads()).encode()]
+                chunks = [m.GradientUpdate(worker_id=0, iteration=2,
+                                           gradients=grads())]
                 first = client._shm_conn.round_trip(
-                    iter(frames), 20.0, lambda answer: bytes(next(answer)))
+                    iter(chunks), 20.0, lambda answer: bytes(next(answer)))
                 assert m.PushPullResponse.decode(first).push.success
             # the push of round 2 landed either way; round 3 must work
             push, params = client.push_pull(0, 3, grads(), timeout=20.0)
@@ -692,3 +895,51 @@ def test_client_consumer_mid_response_leaves_connection_usable(
             assert client.shm_active is (consumer == "stops_early")
     finally:
         server.stop()
+
+
+@pytest.mark.parametrize("side", ["client", "server"])
+def test_encoder_raising_mid_frame_latches_the_rings(ps, monkeypatch, side):
+    """A frame's length goes out before its bytes, so an ``encode_into``
+    that raises halfway leaves a torn frame.  On the worker's side the
+    error reaches the caller as the gRPC path's would, both rings are
+    latched and the retry rides TCP; on the server's the handler thread
+    latches them, the worker sees a transport error and replays the same
+    round over TCP.  No round is lost either way."""
+    server, port = ps
+    with PSClient(f"127.0.0.1:{port}") as client:
+        w0 = _seed(client)
+        good = m.Tensor.from_array("w", np.full(16, 0.1, np.float32))
+        push, params = client.push_pull(0, 1, [good])
+        assert push.success and client.shm_active
+        conn = client._shm_conn
+        if side == "client":
+            class Torn(m.Tensor):
+                def encode_into(self, writer):
+                    writer.write(b"\x0a\x01w")      # some of it is out
+                    raise RuntimeError("encoder failed")
+
+            torn = Torn(name="w", shape=[16], data=good.data)
+            with pytest.raises(RuntimeError, match="encoder failed"):
+                client.push_pull(0, 2, [torn])
+            assert conn.c2s.closed and conn.s2c.closed
+        else:
+            real = PreEncodedParameterUpdate.encode_into
+            calls = []
+
+            def once(self, writer):
+                calls.append(1)
+                if len(calls) == 1:
+                    writer.write(b"\x08")
+                    raise RuntimeError("encoder failed")
+                real(self, writer)
+
+            monkeypatch.setattr(PreEncodedParameterUpdate, "encode_into",
+                                once)
+        push, params = client.push_pull(0, 2, [good], timeout=20.0)
+        assert push.success and params is not None and params.ready
+        if side == "server":    # torn over shm, whole over TCP
+            assert len(calls) == 2
+        assert conn.c2s.closed and conn.s2c.closed
+        assert not client.shm_active
+        np.testing.assert_allclose(params.parameters[0].to_array(),
+                                   w0 - 0.10, rtol=1e-6)

@@ -542,7 +542,8 @@ def test_striped_encode_is_byte_identical(monkeypatch):
         monkeypatch.setenv(st.ENV_STRIPES, stripes)
         tensors = to_wire(store, wire_dtype=m.WIRE_BF16)
         return encode_parameter_record_groups(
-            list(split_tensors(tensors, budget)))
+            list(split_tensors(tensors, budget)),
+            lambda i, size: memoryview(bytearray(size)))
 
     serial = bodies("1")
     striped = bodies("4")

@@ -35,6 +35,7 @@ import contextlib
 import dataclasses
 import os
 import time
+import weakref
 from functools import partial
 from typing import Any, Mapping
 
@@ -43,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import flight
+from ..obs import legs as obs_legs
 from ..obs import stats as obs_stats
 from ..obs import trace as obs_trace
 from .generation import (KVCache, QuantKVCache, _cached_runner,
@@ -627,6 +629,16 @@ class DecodeServer:
         # the call; and the caller's time between two rounds
         self._obs_admit = obs_stats.histogram("serve.admit_s")
         self._obs_admit_device = obs_stats.histogram("serve.admit_device_s")
+        # an admission by its five legs (disjoint; with the slot
+        # bookkeeping they cover serve.admit_s): the key's build and the
+        # tree's lookup; the host's time to put the forward on the device;
+        # the tree's insert, split and eviction pass; the sampling and the
+        # BLOCKED fetch of the first token (the rest of the round in
+        # flight, then the forward); the splice's dispatch
+        self._obs_admit_legs = {
+            name: obs_stats.histogram(f"serve.admit_{name}_s")
+            for name in ("lookup", "forward", "tree", "first_token",
+                         "splice")}
         self._obs_round_device = obs_stats.histogram("serve.round_device_s")
         self._obs_round_host = obs_stats.histogram("serve.round_host_s")
         self._obs_between = obs_stats.histogram("serve.between_rounds_s")
@@ -653,8 +665,21 @@ class DecodeServer:
                 "serve.sparse.kernels_scored",
                 "serve.linear.state_updates",
                 "serve.conv.state_updates")}
-        # perf_counter at the last round's return, while a slot is active
-        self._round_returned: float | None = None
+        # the mark (obs/legs.py: perf_counter first) at the last round's
+        # return, while a slot is active
+        self._round_returned: tuple | None = None
+        # a leg of this thread that takes over obs_legs.SLOW_LEG_S keeps
+        # its evidence (``slow_legs``, the newest 64; the log; the flight
+        # ring; five counters): the caller's time between two rounds LESS
+        # the admissions inside it (``_admissions``: their marks since the
+        # last round), a round's host and device legs, an admission's five
+        # (through a weak reference: the watch must not keep the server,
+        # and the device memory it holds, alive past its last user)
+        held = weakref.WeakMethod(self._held)
+        self._watch = obs_legs.SlowLegs(lambda: held()())
+        self.slow_legs = self._watch.records
+        self._admissions: list[tuple] = []
+        self._admission: dict | None = None   # the one in hand, for _held
         # radix-tree prefix cache (ISSUE 20): token-level index over
         # cached K/V rows — exact hits replay, any shared prefix seeds
         # a suffix-only extension, byte-accounted LRU eviction.
@@ -907,30 +932,31 @@ class DecodeServer:
         sbucket = _bucket(slen)
         if pbucket + sbucket > self.max_len:
             return None  # combined row would overflow the slot cache
-        padded = np.zeros((1, sbucket), np.int32)
-        padded[0, :slen] = prompt[plen:]
-        suffix = jnp.asarray(padded)
-        plen_j = jnp.asarray(plen, jnp.int32)
-        slen_j = jnp.asarray(slen, jnp.int32)
-        last, row, loads = _extend_runner(self.model, pbucket, sbucket,
-                                          self.cache_dtype)(
-            self.params, pre_row, suffix, plen_j, slen_j)
-        d_row = None
-        if self.draft is not None and self._k > 0:
-            dpre = node.dhandle.row if node.dhandle is not None else None
-            dbucket = int(dpre[0].shape[1]) if dpre is not None else 0
-            if dpre is not None and dbucket + sbucket <= self.max_len:
-                _, d_row, _ = _extend_runner(self.draft, dbucket, sbucket,
-                                             self.cache_dtype)(
-                    self.draft_params, dpre, suffix, plen_j, slen_j)
-            else:
-                dbucket = min(_bucket(real_len), self.max_len)
-                dpadded = np.zeros((1, dbucket), np.int32)
-                dpadded[0, :real_len] = prompt
-                _, d_row, _ = _prefill_runner(self.draft, dbucket,
+        with self._forward_leg(slen):
+            padded = np.zeros((1, sbucket), np.int32)
+            padded[0, :slen] = prompt[plen:]
+            suffix = jnp.asarray(padded)
+            plen_j = jnp.asarray(plen, jnp.int32)
+            slen_j = jnp.asarray(slen, jnp.int32)
+            last, row, loads = _extend_runner(self.model, pbucket, sbucket,
                                               self.cache_dtype)(
-                    self.draft_params, jnp.asarray(dpadded),
-                    jnp.asarray(real_len, jnp.int32))
+                self.params, pre_row, suffix, plen_j, slen_j)
+            d_row = None
+            if self.draft is not None and self._k > 0:
+                dpre = node.dhandle.row if node.dhandle is not None else None
+                dbucket = int(dpre[0].shape[1]) if dpre is not None else 0
+                if dpre is not None and dbucket + sbucket <= self.max_len:
+                    _, d_row, _ = _extend_runner(
+                        self.draft, dbucket, sbucket, self.cache_dtype)(
+                        self.draft_params, dpre, suffix, plen_j, slen_j)
+                else:
+                    dbucket = min(_bucket(real_len), self.max_len)
+                    dpadded = np.zeros((1, dbucket), np.int32)
+                    dpadded[0, :real_len] = prompt
+                    _, d_row, _ = _prefill_runner(self.draft, dbucket,
+                                                  self.cache_dtype)(
+                        self.draft_params, jnp.asarray(dpadded),
+                        jnp.asarray(real_len, jnp.int32))
         self._prefix_tree.touch(node)  # the whole ancestor path is hot
         self._prefill_tokens += slen
         return last, row, d_row, loads
@@ -953,23 +979,55 @@ class DecodeServer:
                 jnp.asarray(min(_PREFILL_CHUNK, real_len - done), jnp.int32))
         return last, row_of(cache)
 
+    def _forward_leg(self, forwarded: int):
+        """The leg in which an admission's forward goes to the device:
+        padding, uploads, the runner's lookup and every dispatch (the
+        draft's too).  It ends when the dispatch returns; the device's
+        time is the first-token leg's."""
+        self._admission["forwarded_tokens"] = forwarded
+        return self._watch.leg(
+            "serve/admit/forward", self._obs_admit_legs["forward"],
+            forwarded_tokens=forwarded,
+            behind_round=self._flight is not None)
+
+    def _held(self) -> dict:
+        """What the server held when a leg came out slow (obs/legs.py asks
+        once, after the leg): slots, the round in flight, the admission in
+        hand and the device's memory."""
+        out = {"active_slots": self.active,
+               "round_in_flight": self._flight is not None,
+               **(self._admission or {})}
+        device = next(iter(self._last.devices()))
+        stats = device.memory_stats() or {}
+        for key in ("bytes_in_use", "bytes_reserved",
+                    "largest_free_block_bytes"):
+            if key in stats:
+                out[f"device_{key}"] = stats[key]
+        return out
+
     def _admit_to_tree(self, pkey: tuple, last, row, d_row) -> None:
         """Insert an admitted prompt's rows into the radix tree (an
         edge split shares the descendant's handles — no device copy)
         and run the byte-budget LRU eviction pass."""
         tree = self._prefix_tree
-        splits = tree.splits
-        node = tree.insert(
-            pkey, last, RowRef(row, _row_nbytes(row),
-                               state_at=len(pkey) if self._state_layers
-                               else None),
-            RowRef(d_row, _row_nbytes(d_row)) if d_row is not None else None)
-        if tree.splits != splits:
-            flight.record("serve.prefix.split", a=node.depth,
-                          b=tree.nodes)
-        evicted = tree.evict_over_budget()
-        if evicted:
-            flight.record("serve.prefix.evict", a=evicted, b=tree.bytes)
+        with self._watch.leg("serve/admit/tree",
+                             self._obs_admit_legs["tree"]) as leg:
+            splits = tree.splits
+            node = tree.insert(
+                pkey, last, RowRef(row, _row_nbytes(row),
+                                   state_at=len(pkey) if self._state_layers
+                                   else None),
+                RowRef(d_row, _row_nbytes(d_row)) if d_row is not None
+                else None)
+            if tree.splits != splits:
+                flight.record("serve.prefix.split", a=node.depth,
+                              b=tree.nodes)
+            held = tree.bytes
+            evicted = tree.evict_over_budget()
+            if evicted:
+                flight.record("serve.prefix.evict", a=evicted, b=tree.bytes)
+            leg.args.update(evicted=evicted, freed_bytes=held - tree.bytes,
+                            tree_bytes=tree.bytes)
 
     # ------------------------------------------------------------ submit
     def submit(self, prompt, max_new_tokens: int = 64, *,
@@ -1017,99 +1075,119 @@ class DecodeServer:
         if self.draft is not None:
             check_position_budget(self.draft, real_len,
                                   max_new_tokens + slack)
-        with obs_trace.timed("serve/admit", self._obs_admit,
-                             prompt_tokens=real_len):
-            return self._admit(
-                slot, prompt, real_len, max_new_tokens,
-                self._temperature if temperature is None else temperature,
-                frozenset(stop))
+        start = self._watch.enter()
+        try:
+            with obs_trace.timed("serve/admit", self._obs_admit,
+                                 prompt_tokens=real_len,
+                                 request_id=self._next_id):
+                return self._admit(
+                    slot, prompt, real_len, max_new_tokens,
+                    self._temperature if temperature is None else temperature,
+                    frozenset(stop))
+        finally:
+            # what the caller's leg leaves out: an admission's seconds
+            # are counted under its own legs
+            self._admissions.append((start, self._watch.mark()))
+            self._admission = None
 
     def _admit(self, slot: int, prompt: np.ndarray, real_len: int,
                max_new_tokens: int, req_temp: float,
                stop: frozenset) -> int:
-        """The admitting part of :meth:`submit`, after its checks: prefix
-        lookup, prefill or suffix extension, first token, splice."""
+        """The admitting part of :meth:`submit`, after its checks, as its
+        five legs (``serve/admit/<leg>``, histogram ``serve.admit_<leg>_s``;
+        each watched, obs/legs.py): ``lookup``, the key's build and the
+        prefix lookup; ``forward``, the prefill's or the suffix extension's
+        way to the device; ``tree``, the insert (:meth:`_admit_to_tree`);
+        ``first_token``, the sampling and the blocked fetch; ``splice``.  A
+        whole-prompt hit runs no forward and no tree leg."""
         bucket = min(_bucket(real_len), self.max_len)
         tree = self._prefix_tree
-        pkey = tuple(prompt.tolist()) if tree is not None else None
-        hit = None
+        legs, hists = self._watch.leg, self._obs_admit_legs
+        self._admission = {"prompt_tokens": real_len}
+        pkey = hit = None
         anc, matched = None, 0
         loads = None   # what an admission's forward routed, if it ran one
         if tree is not None:
-            anc, matched, partial = tree.lookup(pkey)
+            with legs("serve/admit/lookup", hists["lookup"],
+                      prompt_tokens=real_len) as leg:
+                pkey = tuple(prompt.tolist())
+                anc, matched, partial = tree.lookup(pkey)
+                leg.args["matched"] = self._admission["matched"] = matched
             if (matched == real_len and not partial
                     and anc.last is not None):
                 hit = anc  # whole-prompt node: replayable logits + row
         # from the first dispatch to the first token on the host
-        device = obs_trace.timed("serve/admit/device",
-                                 self._obs_admit_device)
-        device.__enter__()
-        if hit is not None:
-            tree.touch(hit)  # the whole ancestor path, not one entry
-            self._prompt_hits += 1
-            self._prompt_tokens += real_len
-            last = hit.last
-            row = hit.handle.row
-            d_row = hit.dhandle.row if hit.dhandle is not None else None
-            if self.draft is not None and self._k > 0 and d_row is None:
-                # node was cached while the controller had speculation
-                # off (k=0 skips the draft prefill below); replaying it
-                # as-is after a re-probe re-armed k would skip the draft
-                # splice and leave this slot's _d_lengths/_prev stale —
-                # backfill the draft half and attach it to the node
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :real_len] = prompt
-                _, d_row, _ = _prefill_runner(self.draft, bucket,
-                                              self.cache_dtype)(
-                    self.draft_params, jnp.asarray(padded),
-                    jnp.asarray(real_len, jnp.int32))
-                self._admit_to_tree(pkey, last, row, d_row)
-        else:
-            # Shared-prefix extension serves the prompt phase whenever
-            # the tree holds ANY prefix of this prompt — including the
-            # interior of a longer cached prompt (the radix point) —
-            # and in speculative mode the draft row extends alongside
-            # the target row (_radix_extend), so spec admissions no
-            # longer fall back to full prefill (ISSUE 20 satellite).
-            extended = (self._radix_extend(prompt, real_len, anc, matched)
-                        if tree is not None else None)
-            if extended is not None:
-                # only the suffix ran a forward; the combined row
-                # splices below under its own (wider) width
-                last, row, d_row, loads = extended
-                self._prefix_hits += 1
-                flight.record("serve.prefix.hit",
-                              a=min(matched, real_len - 1),
-                              b=real_len - min(matched, real_len - 1))
+        with obs_trace.timed("serve/admit/device", self._obs_admit_device):
+            if hit is not None:
+                tree.touch(hit)  # the whole ancestor path, not one entry
+                self._prompt_hits += 1
+                self._prompt_tokens += real_len
+                last = hit.last
+                row = hit.handle.row
+                d_row = hit.dhandle.row if hit.dhandle is not None else None
+                if self.draft is not None and self._k > 0 and d_row is None:
+                    # node was cached while the controller had speculation
+                    # off (k=0 skips the draft prefill below); replaying it
+                    # as-is after a re-probe re-armed k would skip the draft
+                    # splice and leave this slot's _d_lengths/_prev stale —
+                    # backfill the draft half and attach it to the node
+                    with self._forward_leg(real_len):
+                        padded = np.zeros((1, bucket), np.int32)
+                        padded[0, :real_len] = prompt
+                        _, d_row, _ = _prefill_runner(self.draft, bucket,
+                                                      self.cache_dtype)(
+                            self.draft_params, jnp.asarray(padded),
+                            jnp.asarray(real_len, jnp.int32))
+                    self._admit_to_tree(pkey, last, row, d_row)
             else:
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :real_len] = prompt
-                if (self.cache_dtype == "native"
-                        and not _prefills_whole(self.model, bucket)):
-                    last, row = self._prefill_in_chunks(padded, real_len)
+                # Shared-prefix extension serves the prompt phase whenever
+                # the tree holds ANY prefix of this prompt — including the
+                # interior of a longer cached prompt (the radix point) —
+                # and in speculative mode the draft row extends alongside
+                # the target row (_radix_extend), so spec admissions no
+                # longer fall back to full prefill (ISSUE 20 satellite).
+                extended = (self._radix_extend(prompt, real_len, anc,
+                                               matched)
+                            if tree is not None else None)
+                if extended is not None:
+                    # only the suffix ran a forward; the combined row
+                    # splices below under its own (wider) width
+                    last, row, d_row, loads = extended
+                    self._prefix_hits += 1
+                    flight.record("serve.prefix.hit",
+                                  a=min(matched, real_len - 1),
+                                  b=real_len - min(matched, real_len - 1))
                 else:
-                    last, row, loads = _prefill_runner(
-                        self.model, bucket, self.cache_dtype)(
-                        self.params, jnp.asarray(padded),
-                        jnp.asarray(real_len, jnp.int32))
-                d_row = None
-                self._prefill_tokens += real_len
-                if self.draft is not None and self._k > 0:
-                    # k=0 (controller disabled speculation): the draft
-                    # cache is not read while disabled, so skip its
-                    # prefill + splice; a later re-probe backfills via
-                    # the cache-hit repair above
-                    _, d_row, _ = _prefill_runner(self.draft, bucket,
-                                                  self.cache_dtype)(
-                        self.draft_params, jnp.asarray(padded),
-                        jnp.asarray(real_len, jnp.int32))
-            self._prompt_tokens += real_len
-            if tree is not None:
-                self._admit_to_tree(pkey, last, row, d_row)
-        self._rng, sub = jax.random.split(self._rng)
-        first = int(sample_token(last[None], sub, req_temp,
-                                 self._top_k, self._top_p)[0])
-        device.__exit__(None, None, None)
+                    with self._forward_leg(real_len):
+                        padded = np.zeros((1, bucket), np.int32)
+                        padded[0, :real_len] = prompt
+                        if (self.cache_dtype == "native"
+                                and not _prefills_whole(self.model, bucket)):
+                            last, row = self._prefill_in_chunks(padded,
+                                                                real_len)
+                        else:
+                            last, row, loads = _prefill_runner(
+                                self.model, bucket, self.cache_dtype)(
+                                self.params, jnp.asarray(padded),
+                                jnp.asarray(real_len, jnp.int32))
+                        d_row = None
+                        if self.draft is not None and self._k > 0:
+                            # k=0 (controller disabled speculation): the
+                            # draft cache is not read while disabled, so
+                            # skip its prefill + splice; a later re-probe
+                            # backfills via the cache-hit repair above
+                            _, d_row, _ = _prefill_runner(
+                                self.draft, bucket, self.cache_dtype)(
+                                self.draft_params, jnp.asarray(padded),
+                                jnp.asarray(real_len, jnp.int32))
+                    self._prefill_tokens += real_len
+                self._prompt_tokens += real_len
+                if tree is not None:
+                    self._admit_to_tree(pkey, last, row, d_row)
+            with legs("serve/admit/first_token", hists["first_token"]):
+                self._rng, sub = jax.random.split(self._rng)
+                first = int(sample_token(last[None], sub, req_temp,
+                                         self._top_k, self._top_p)[0])
         if loads is not None:
             # already on the host's side of the fetch of the first token
             self._count_routing(np.asarray(loads), admission=True)
@@ -1117,17 +1195,19 @@ class DecodeServer:
         # row is prefix-bucket + suffix-bucket wide, and the target and
         # draft rows may differ (each extended from its own ancestor
         # width)
-        length = jnp.asarray(real_len, jnp.int32)
-        self._cache = _splice_runner(self.model, int(row[0].shape[1]),
-                                     self.cache_dtype)(
-            self._cache, row, jnp.asarray(slot, jnp.int32), length)
-        if self.draft is not None and d_row is not None:
-            self._d_cache = _splice_runner(self.draft,
-                                           int(d_row[0].shape[1]),
-                                           self.cache_dtype)(
-                self._d_cache, d_row, jnp.asarray(slot, jnp.int32), length)
-            self._d_lengths[slot] = real_len
-            self._prev[slot] = int(prompt[-1])
+        with legs("serve/admit/splice", hists["splice"]):
+            length = jnp.asarray(real_len, jnp.int32)
+            self._cache = _splice_runner(self.model, int(row[0].shape[1]),
+                                         self.cache_dtype)(
+                self._cache, row, jnp.asarray(slot, jnp.int32), length)
+            if self.draft is not None and d_row is not None:
+                self._d_cache = _splice_runner(self.draft,
+                                               int(d_row[0].shape[1]),
+                                               self.cache_dtype)(
+                    self._d_cache, d_row, jnp.asarray(slot, jnp.int32),
+                    length)
+                self._d_lengths[slot] = real_len
+                self._prev[slot] = int(prompt[-1])
         rid = self._next_id
         self._next_id += 1
         self._n_requests += 1
@@ -1398,17 +1478,29 @@ class DecodeServer:
         the caller's time (its loop, its admissions): one observation of
         ``serve.between_rounds_s``; ``serve.round_s`` and it together are
         the period a user sees.  Slots in use and the draft's accept rate
-        are in :attr:`stats`."""
-        t0 = time.perf_counter()
+        are in :attr:`stats`.  The three legs are watched (obs/legs.py):
+        the caller's time LESS the admissions inside it, the device leg,
+        and the block's own time, each a slow leg if it alone is over the
+        limit."""
+        watch = self._watch
+        entered = watch.enter()
+        t0 = entered[0]
         if self._round_returned is not None:
-            self._obs_between.observe(t0 - self._round_returned)
+            self._obs_between.observe(t0 - self._round_returned[0])
+            watch.over(obs_legs.CALLER, self._round_returned, entered,
+                       less=self._admissions)
+        self._admissions = []
         emitted = self._n_emitted
         with obs_trace.timed("serve/round/host", self._obs_round_host,
                              **args) as call:
-            yield call.carve("serve/round/device", self._obs_round_device)
-        now = time.perf_counter()
-        self._round_returned = None if self.idle else now
-        self._obs_round.observe(now - t0)
+            device = watch.wrap("serve/round/device", call.carve(
+                "serve/round/device", self._obs_round_device))
+            yield device
+        returned = watch.mark()
+        watch.over("serve/round/host", entered, returned, less=device.taken,
+                   **args)
+        self._round_returned = None if self.idle else returned
+        self._obs_round.observe(returned[0] - t0)
         self._obs_tokens.add(self._n_emitted - emitted)
 
     def _cache_bytes_by_kind(self) -> dict[str, int]:

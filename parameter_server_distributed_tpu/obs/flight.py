@@ -214,6 +214,10 @@ EVENTS: dict[str, int] = {
                                   # evicted, b = bytes pinned after
     "serve.prefix.split": 162,    # edge split at a divergence point;
                                   # a = split-node depth, b = tree nodes
+    # a serving leg over obs/legs.py's limit (ISSUE 38)
+    "serve.slow_leg": 170,        # a = wall_us, b = the thread's cpu_us;
+                                  # note = leg and evidence
+                                  # (legs.slow_leg_note)
 }
 EVENT_NAMES = {code: name for name, code in EVENTS.items()}
 

@@ -159,6 +159,9 @@ EVENT_DECODE: dict[str, str] = {
     "serve.prefix.evict": "prefix-cache LRU pass; a=nodes evicted "
                           "b=bytes pinned after",
     "serve.prefix.split": "radix edge split; a=split depth b=tree nodes",
+    "serve.slow_leg": "serving leg over 0.1 s; a=wall_us b=thread cpu_us "
+                      "(note = leg gc=ms cs=involuntary/voluntary "
+                      "switches mf=major faults ev=nodes evicted)",
 }
 
 
@@ -166,6 +169,29 @@ def describe_event(name: str) -> str:
     """One-line decode of a flight event name (the name itself when the
     table has no row — old rings can carry codes newer than this build)."""
     return EVENT_DECODE.get(name, name)
+
+
+def decode_slow_leg(event: dict) -> dict:
+    """A ``serve.slow_leg`` flight event back into the fields of the
+    record ``obs/legs.py`` kept (the inverse of ``legs.slow_leg_note``;
+    this module imports nothing of the package, so the note's grammar is
+    mirrored here).  A field the 48-byte note cut short is left out."""
+    leg, *pairs = event["note"].split()
+    out = {"leg": f"serve/{leg}", "wall_s": event["a"] / 1e6,
+           "cpu_s": event["b"] / 1e6, "ended_at": event["ts"]}
+    names = {"gc": ("gc_s",), "mf": ("major_faults",), "ev": ("evicted",),
+             "cs": ("involuntary_switches", "voluntary_switches")}
+    for pair in pairs:
+        key, _, value = pair.partition("=")
+        try:
+            numbers = [int(v) for v in value.split("/")]
+        except ValueError:
+            continue
+        if len(numbers) == len(names.get(key, ())):
+            out.update(zip(names[key], numbers))
+    if "gc_s" in out:
+        out["gc_s"] /= 1e3
+    return out
 
 
 # ------------------------------------------------------------------- loading
@@ -559,7 +585,11 @@ def failure_narrative(rings: list[dict], events: list[dict]) -> dict:
         elastic["quorum_closes"] = quorum_closes
     if stale_count:
         elastic["stale_folds"] = stale_count
+    slow_legs = [dict(decode_slow_leg(e), role=e["role"])
+                 for e in events if e["event"] == "serve.slow_leg"]
     out: dict[str, Any] = {}
+    if slow_legs:
+        out["slow_legs"] = slow_legs
     if elastic:
         out["membership"] = elastic
     if publish:
@@ -667,6 +697,19 @@ def render_report(rep: dict) -> str:
         if elastic.get("stale_folds"):
             parts.append(f"{elastic['stale_folds']} stale folds")
         lines.append(f"  membership: {', '.join(parts)}")
+    for leg in narrative.get("slow_legs", ()):
+        evidence = ", ".join(
+            f"{label} {leg[key]}" for key, label in (
+                ("involuntary_switches", "involuntary switches"),
+                ("voluntary_switches", "voluntary switches"),
+                ("major_faults", "major faults"),
+                ("evicted", "nodes evicted")) if leg.get(key))
+        lines.append(
+            f"  SLOW LEG: {leg['leg']} {_fmt_dt(leg['wall_s'])} "
+            f"(thread cpu {_fmt_dt(leg['cpu_s'])}, collector "
+            f"{_fmt_dt(leg.get('gc_s', 0.0))}"
+            + (f"; {evidence}" if evidence else "")
+            + f") at {leg['role']}, ended {leg['ended_at']:.3f}")
     publish = narrative.get("publication")
     if publish:
         parts = []

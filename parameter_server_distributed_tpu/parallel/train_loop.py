@@ -355,12 +355,11 @@ def run_training(config: TrainLoopConfig) -> dict:
     n_chips = mesh.devices.size
     last_loss = float("nan")
     # obs registry mirrors of the JSONL stream: data-wait vs dispatch
-    # split per step (cheap: two perf_counter reads), synced step time per
-    # window — what `pst-status --metrics` style rollups and the benchmark's
-    # readers read without parsing logs
+    # split per step (cheap: two perf_counter reads) — what `pst-status
+    # --metrics` style rollups and the benchmark's readers read without
+    # parsing logs (the synced step time is in the JSONL stream)
     obs_data = obs_stats.histogram("train.data_s")
     obs_dispatch = obs_stats.histogram("train.dispatch_s")
-    obs_step = obs_stats.histogram("train.step_s")
 
     last_saved_step = -1
     last_eval = (-1, float("nan"))
@@ -384,7 +383,6 @@ def run_training(config: TrainLoopConfig) -> dict:
                     # time / steps.
                     dt = (time.perf_counter() - window_t0) / window_steps
                     timer.record(dt)
-                    obs_step.observe(dt)
                     metrics_log.log(step=step_idx + 1, loss=last_loss,
                                     step_time_s=dt,
                                     samples_per_sec_chip=samples_per_sec(

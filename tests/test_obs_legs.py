@@ -1,6 +1,8 @@
 """The legs of the PS round and of the decode round (ISSUE 24): spans and
 histograms where the work happens, their mirror on the profiler's timeline,
-and the ``jax.named_scope`` names on the device operations.
+and the ``jax.named_scope`` names on the device operations.  An admission
+by its five legs, and the slow leg that keeps its evidence (ISSUE 38,
+``obs/legs.py``).
 
 Recording is process-wide state; every test that turns it on goes through
 the ``recording`` fixture, which puts it back.
@@ -8,6 +10,7 @@ the ``recording`` fixture, which puts it back.
 
 import collections
 import contextlib
+import gc
 import glob
 import re
 import sys
@@ -22,10 +25,13 @@ from parameter_server_distributed_tpu.cli.worker_main import build_worker
 from parameter_server_distributed_tpu.config import (CoordinatorConfig,
                                                      ParameterServerConfig,
                                                      WorkerConfig)
-from parameter_server_distributed_tpu.models import generation
+from parameter_server_distributed_tpu.models import generation, serving
 from parameter_server_distributed_tpu.models.serving import DecodeServer
 from parameter_server_distributed_tpu.models.transformer import (
     Transformer, TransformerConfig)
+from parameter_server_distributed_tpu.obs import flight
+from parameter_server_distributed_tpu.obs import legs as obs_legs
+from parameter_server_distributed_tpu.obs import postmortem
 from parameter_server_distributed_tpu.obs import stats as obs_stats
 from parameter_server_distributed_tpu.obs import trace as obs_trace
 from parameter_server_distributed_tpu.parallel.train_step import (
@@ -37,9 +43,11 @@ from parameter_server_distributed_tpu.server.ps_service import ParameterServer
 WORKER_LEAVES = ("worker/pack", "worker/h2d", "worker/dispatch",
                  "worker/device_wait", "worker/d2h", "rpc/client/encode",
                  "rpc/client/decode", "rpc/shm/copy", "rpc/shm/wait")
+ADMIT_LEGS = ("lookup", "forward", "tree", "first_token", "splice")
 SERVING_HISTOGRAMS = ("serve.admit_s", "serve.admit_device_s",
                       "serve.round_device_s", "serve.round_host_s",
-                      "serve.between_rounds_s", "serve.round_s")
+                      "serve.between_rounds_s", "serve.round_s",
+                      *(f"serve.admit_{leg}_s" for leg in ADMIT_LEGS))
 
 
 @pytest.fixture
@@ -170,7 +178,15 @@ def test_recording_off_opens_nothing(monkeypatch):
     with obs_trace.server_span("off/d", b""):
         pass
     obs_trace.SpanHolder("off/e").finish()
-    assert obs_trace.spans() == [] and hist.count == 1
+    # a watched leg is a ``timed`` and two marks; a slow one keeps its
+    # evidence and still opens nothing
+    watch = obs_legs.SlowLegs(dict)
+    monkeypatch.setattr(obs_legs, "SLOW_LEG_S", 0.0)
+    with watch.leg("off/f", hist, n=1) as leg:
+        leg.args["m"] = 2
+    assert obs_trace.spans() == [] and hist.count == 2
+    assert [(r["leg"], r["n"], r["m"]) for r in watch.records] == \
+        [("off/f", 1, 2)]
 
 
 # --------------------------------------------- (b) the decode round's legs
@@ -201,12 +217,19 @@ def test_decode_server_legs_once_per_admission_and_round(rng, rec):
     moved = {k: after[k] - before[k] for k in after}
     # always on, with recording off too; the first round after idle has no
     # round before it, the second admission falls between two rounds
+    # (no prefix cache: no lookup and no tree leg)
     assert moved == {"serve.admit_s": 2, "serve.admit_device_s": 2,
+                     "serve.admit_lookup_s": 0, "serve.admit_tree_s": 0,
+                     "serve.admit_forward_s": 2,
+                     "serve.admit_first_token_s": 2,
+                     "serve.admit_splice_s": 2,
                      "serve.round_device_s": rounds,
                      "serve.round_host_s": rounds, "serve.round_s": rounds,
                      "serve.between_rounds_s": rounds - 1,
                      "serve.programs": 0}
     assert spans == ({"serve/admit": 2, "serve/admit/device": 2,
+                      "serve/admit/forward": 2,
+                      "serve/admit/first_token": 2, "serve/admit/splice": 2,
                       "serve/round/host": rounds,
                       "serve/round/device": rounds} if rec else {})
 
@@ -224,6 +247,186 @@ def test_round_legs_add_up_and_programs_are_counted(rng):
     parts = (snap["serve.round_device_s"]["sum"]
              + snap["serve.round_host_s"]["sum"])
     assert parts == pytest.approx(snap["serve.round_s"]["sum"], rel=0.05)
+
+
+# --------------------------------------- (f) a slow leg keeps its evidence
+def slow_counters() -> dict:
+    counters = obs_stats.REGISTRY.snapshot()["counters"]
+    return {name: counters[name] for name in obs_legs.COUNTERS}
+
+
+def spin(cpu_s: float) -> None:
+    """Burn ``cpu_s`` of this thread's CPU time."""
+    until = time.thread_time() + cpu_s
+    while time.thread_time() < until:
+        pass
+
+
+def fetch_delayed(srv, monkeypatch):
+    """The round's tokens come late: the host sleeps in the fetch."""
+    real = jax.device_get
+
+    def late(tree):
+        monkeypatch.setattr(jax, "device_get", real)
+        time.sleep(0.15)
+        return real(tree)
+
+    monkeypatch.setattr(jax, "device_get", late)
+    srv.step()
+
+
+def caller_busy(srv, monkeypatch):
+    """The caller computes for 0.15 s between two rounds."""
+    spin(0.15)
+    srv.step()
+
+
+def gc_in_tree(srv, monkeypatch):
+    """A full collection of a large graph during the tree's insert."""
+    evict = srv._prefix_tree.evict_over_budget
+
+    def collecting():
+        graph = [[] for _ in range(300_000)]
+        for node in graph:
+            node.append(graph)
+        del graph, node
+        gc.collect()
+        time.sleep(0.1)      # (slow whatever the collector took)
+        return evict()
+
+    monkeypatch.setattr(srv._prefix_tree, "evict_over_budget", collecting)
+    srv.submit(list(range(40, 52)), max_new_tokens=2)
+
+
+def slow_admission(srv, monkeypatch):
+    """An admission whose lookup takes 0.15 s falls between two rounds:
+    its seconds are the lookup's, and not the caller's as well."""
+    lookup = srv._prefix_tree.lookup
+
+    def late(key):
+        time.sleep(0.15)
+        return lookup(key)
+
+    monkeypatch.setattr(srv._prefix_tree, "lookup", late)
+    srv.submit(list(range(60, 72)), max_new_tokens=2)
+    srv.step()
+
+
+@pytest.mark.parametrize("make_slow,leg,waits_for_chip", [
+    (fetch_delayed, "serve/round/device", True),
+    (caller_busy, "serve/caller", False),
+    (gc_in_tree, "serve/admit/tree", False),
+    (slow_admission, "serve/admit/lookup", False),
+], ids=lambda p: getattr(p, "__name__", None))
+def test_a_slow_leg_is_kept_under_its_name_with_its_evidence(
+        rng, monkeypatch, caplog, make_slow, leg, waits_for_chip):
+    model = tiny()
+    srv = DecodeServer(model, model.init_params(0), slots=4, max_len=64,
+                       prompt_cache=8)
+    for start in (0, 20):       # every program built, then a live request
+        srv.submit(list(range(start, start + 12)), max_new_tokens=40)
+        srv.step()
+        srv.step()
+    srv.slow_legs.clear()       # (the compiles')
+    caplog.clear()
+    before = slow_counters()
+    obs_trace.clear()
+    obs_trace.enable(True)
+    try:
+        with caplog.at_level("WARNING", logger=obs_legs.__name__):
+            make_slow(srv, monkeypatch)
+        spans = [s for s in obs_trace.spans()
+                 if s["name"] == "serve/slow_leg"]
+    finally:
+        obs_trace.enable(False)
+        obs_trace.clear()
+    record, = srv.slow_legs         # counted once, under one name
+    assert record["leg"] == leg
+    assert 0.1 < record["wall_s"] < 5.0
+    assert 0.0 <= record["cpu_s"] <= record["wall_s"]
+    assert abs(record["at"] + record["wall_s"] - time.time()) < 60.0
+    assert record["active_slots"] >= 1
+    assert record["round_in_flight"] is (srv._flight is not None)
+    assert all(record[key] >= 0 for key in (
+        "involuntary_switches", "voluntary_switches", "major_faults",
+        "programs_built"))
+    # the right kind of evidence
+    if make_slow is fetch_delayed:
+        assert record["cpu_s"] < 0.05               # blocked, not computing
+        assert record["voluntary_switches"] >= 1    # it slept
+    elif make_slow is caller_busy:
+        assert record["cpu_s"] >= 0.14              # computing
+    elif make_slow is gc_in_tree:
+        assert record["gc_s"] > 0 and record["gc_collections"][2] >= 1
+        assert record["evicted"] == 0 and record["tree_bytes"] > 0
+        assert record["prompt_tokens"] == 12
+    else:
+        assert record["cpu_s"] < 0.05
+        assert (record["prompt_tokens"], record["matched"]) == (12, 0)
+    # the four places and the five counters
+    line, = [r.getMessage() for r in caplog.records]
+    assert line.startswith(f"slow leg {leg}: ") and '"wall_s"' in line
+    span, = spans
+    assert span["args"]["leg"] == leg
+    assert span["args"]["wall_s"] == record["wall_s"]
+    moved = {name: value - before[name]
+             for name, value in slow_counters().items()}
+    assert moved == {
+        "serve.slow_legs": 1,
+        "serve.slow_leg_s": pytest.approx(record["wall_s"]),
+        "serve.slow_leg_cpu_s": pytest.approx(record["cpu_s"]),
+        "serve.slow_leg_gc_s": pytest.approx(record["gc_s"]),
+        "serve.slow_leg_device_wait_s": pytest.approx(
+            record["wall_s"] if waits_for_chip else 0.0)}
+    assert moved["serve.slow_leg_s"] >= 0.1 * moved["serve.slow_legs"]
+
+
+def test_the_five_counters_read_zero_on_a_fresh_server(monkeypatch):
+    monkeypatch.setattr(obs_stats, "REGISTRY", obs_stats.Registry())
+    model = tiny()
+    srv = DecodeServer(model, model.init_params(0), slots=2, max_len=32)
+    snap = obs_stats.REGISTRY.snapshot()
+    assert {name: snap["counters"].get(name)
+            for name in obs_legs.COUNTERS} == dict.fromkeys(
+                obs_legs.COUNTERS, 0)
+    assert len(obs_legs.COUNTERS) == 5 and not srv.slow_legs
+    # the collector's histogram is there before a collection is
+    assert "proc.gc_s" in snap["histograms"]
+    seen = obs_stats.histogram("proc.gc_s").count
+    gc.collect()
+    assert obs_stats.histogram("proc.gc_s").count == seen + 1
+
+
+def test_a_slow_leg_round_trips_through_the_flight_ring(tmp_path):
+    flight.enable(str(tmp_path), role="serve:test", records=64)
+    try:
+        watch = obs_legs.SlowLegs(lambda: {"active_slots": 3})
+        with watch.leg("serve/admit/tree") as leg:
+            time.sleep(0.11)
+            leg.args.update(evicted=2, tree_bytes=1 << 20)
+    finally:
+        flight.disable()
+    record, = watch.records
+    rings = postmortem.load_rings(str(tmp_path))
+    event, = [e for e in postmortem.merge_events(rings)
+              if e["event"] == "serve.slow_leg"]
+    decoded = postmortem.decode_slow_leg(event)
+    assert decoded["leg"] == record["leg"] == "serve/admit/tree"
+    assert decoded["wall_s"] == pytest.approx(record["wall_s"], abs=2e-6)
+    assert decoded["cpu_s"] == pytest.approx(record["cpu_s"], abs=2e-6)
+    assert decoded["gc_s"] == pytest.approx(record["gc_s"], abs=1e-3)
+    assert decoded["evicted"] == 2
+    assert decoded["voluntary_switches"] == record["voluntary_switches"]
+    assert decoded["involuntary_switches"] == \
+        record["involuntary_switches"]
+    assert decoded["major_faults"] == record["major_faults"]
+    # the leg ended when the event was written
+    assert decoded["ended_at"] == pytest.approx(
+        record["at"] + record["wall_s"], abs=0.05)
+    # and pst-trace says so
+    text = postmortem.render_report(postmortem.report(str(tmp_path)))
+    assert "SLOW LEG: serve/admit/tree" in text and "serve:test" in text
+    assert "serve.slow_leg" in postmortem.EVENT_DECODE
 
 
 # ------------------------------------------------- (b) the PS round's legs

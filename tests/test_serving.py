@@ -11,11 +11,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from parameter_server_distributed_tpu.models import serving
 from parameter_server_distributed_tpu.models.generation import generate
 from parameter_server_distributed_tpu.models.serving import (DecodeServer,
                                                              _bucket)
 from parameter_server_distributed_tpu.models.transformer import (
     Transformer, TransformerConfig)
+from parameter_server_distributed_tpu.obs import stats as obs_stats
+from parameter_server_distributed_tpu.obs import trace as obs_trace
 
 
 def tiny(**kw):
@@ -754,3 +757,150 @@ def test_step_many_speculative_falls_back(rng):
         return srv.result(rid)
 
     assert run(True) == run(False)
+
+
+# --------------------------------------- an admission by its legs (ISSUE 38)
+ADMIT_LEGS = ("lookup", "forward", "tree", "first_token", "splice")
+
+
+def admit_histograms() -> dict:
+    """(count, sum) of ``serve.admit_s`` and of each leg's histogram."""
+    snap = obs_stats.REGISTRY.snapshot()["histograms"]
+    names = ["serve.admit_s", "serve.admit_device_s"] + [
+        f"serve.admit_{leg}_s" for leg in ADMIT_LEGS]
+    return {name: (snap[name]["count"], snap[name]["sum"])
+            for name in names}
+
+
+@pytest.mark.parametrize("path,legs_run", [
+    ("extension", ADMIT_LEGS),
+    ("prefill", ADMIT_LEGS),
+    # a whole-prompt hit replays the row: no forward, nothing to insert
+    ("hit", ("lookup", "first_token", "splice")),
+    # three chunks through _chunk_runner are ONE forward leg
+    ("chunked", ADMIT_LEGS),
+])
+def test_admission_legs_once_each_and_add_up_to_the_block(
+        rng, monkeypatch, path, legs_run):
+    if path == "chunked":
+        monkeypatch.setattr(serving, "_PREFILL_WHOLE", 0)
+        monkeypatch.setattr(serving, "_PREFILL_CHUNK", 8)
+    # wide enough that an admission's forward takes milliseconds on a
+    # CPU, as it does on the chip: the legs' own clocks then weigh little
+    model = tiny(d_model=512, n_heads=8, n_layers=6, d_ff=2048)
+    srv = DecodeServer(model, model.init_params(0), slots=4, max_len=96,
+                       prompt_cache=8)
+    shared = list(rng.integers(0, 96, 20))
+    firsts = iter(range(96))     # no two suffixes share their first token
+
+    def prompt():
+        if path == "extension":
+            return shared + [next(firsts)] + list(rng.integers(0, 96, 4))
+        if path == "hit":
+            return shared
+        return [next(firsts)] + list(rng.integers(0, 96, 19))   # unshared
+
+    # every program the path needs, and the shared prefix in the tree
+    for tokens in (shared, prompt(), prompt()):
+        srv.submit(tokens, max_new_tokens=2)
+        srv.run_to_completion()
+    before = admit_histograms()
+    stats = dict(srv.stats)
+    srv.slow_legs.clear()       # (the warm-up's: every compile is one)
+    admissions = 3
+    for _ in range(admissions):
+        srv.submit(prompt(), max_new_tokens=2)
+        srv.run_to_completion()
+    after = admit_histograms()
+    moved = {name: after[name][0] - before[name][0] for name in after}
+    assert moved == {
+        "serve.admit_s": admissions, "serve.admit_device_s": admissions,
+        **{f"serve.admit_{leg}_s": admissions * (leg in legs_run)
+           for leg in ADMIT_LEGS}}
+    took = {"extension": "prefix_hits", "hit": "prompt_cache_hits"}.get(path)
+    if took:
+        assert srv.stats[took] - stats[took] == admissions
+    else:
+        assert srv.stats["prefill_tokens"] - stats["prefill_tokens"] == \
+            admissions * 20
+    # disjoint, and with the slot bookkeeping they cover the block; the
+    # three under serve/admit/device cover that one
+    spent = {name: after[name][1] - before[name][1] for name in after}
+    # (within 5%; a whole-prompt hit on a CPU is a millisecond of three
+    # dispatches, so there within the clocks' own cost: 16 reads and five
+    # histogram updates, a quarter of a millisecond at the most)
+    slack = 0.25e-3 * admissions if path == "hit" else 0.0
+    legs = sum(spent[f"serve.admit_{leg}_s"] for leg in ADMIT_LEGS)
+    block = spent["serve.admit_s"]
+    assert block - max(0.05 * block, slack) <= legs < block
+    under_device = sum(spent[f"serve.admit_{leg}_s"]
+                       for leg in ("forward", "tree", "first_token"))
+    block = spent["serve.admit_device_s"]
+    assert block - max(0.05 * block, slack) <= under_device < block
+    assert not srv.slow_legs, list(srv.slow_legs)
+
+
+def test_a_refused_forward_leaves_no_span_open(rng, monkeypatch):
+    """``serve/admit/device`` is a ``with`` block: a runner that raises
+    leaves the thread's span stack as it found it, and the histograms with
+    their observation."""
+    model = tiny()
+    srv = DecodeServer(model, model.init_params(0), slots=2, max_len=64,
+                       prompt_cache=4)
+
+    def refusing(*args, **kwargs):
+        def run(*a, **k):
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+        return run
+
+    monkeypatch.setattr(serving, "_prefill_runner", refusing)
+    before = admit_histograms()
+    obs_trace.clear()
+    obs_trace.enable(True)
+    try:
+        with obs_trace.span("test/outer"):
+            found = obs_trace.current()
+            with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+                srv.submit(list(rng.integers(0, 96, 9)), max_new_tokens=2)
+            assert obs_trace.current() == found
+        assert obs_trace.current() is None
+        spans = [s["name"] for s in obs_trace.spans()]
+    finally:
+        obs_trace.enable(False)
+        obs_trace.clear()
+    assert spans == ["serve/admit/lookup", "serve/admit/forward",
+                     "serve/admit/device", "serve/admit", "test/outer"]
+    after = admit_histograms()
+    assert {name: after[name][0] - before[name][0] for name in after} == {
+        "serve.admit_s": 1, "serve.admit_device_s": 1,
+        "serve.admit_lookup_s": 1, "serve.admit_forward_s": 1,
+        "serve.admit_tree_s": 0, "serve.admit_first_token_s": 0,
+        "serve.admit_splice_s": 0}
+    # nothing was admitted, and the next request is
+    assert srv.idle and srv._admission is None
+    monkeypatch.undo()
+    rid = srv.submit(list(rng.integers(0, 96, 9)), max_new_tokens=2)
+    assert len(srv.run_to_completion()[rid]) == 2
+
+
+def test_a_dropped_server_is_freed_without_the_collector(rng):
+    """The slow-leg watch asks the server what it held; it must not keep
+    it (and the slot cache, the prefix rows, the weights) alive: the
+    benchmark drops the server to make room on the device and does not
+    wait for a collection."""
+    import gc
+    import weakref
+
+    model = tiny()
+    srv = DecodeServer(model, model.init_params(0), slots=2, max_len=64,
+                       prompt_cache=4)
+    srv.submit(list(rng.integers(0, 96, 9)), max_new_tokens=3)
+    srv.run_to_completion()
+    cache = weakref.ref(srv._cache.k[0])
+    gone = weakref.ref(srv)
+    gc.disable()
+    try:
+        del srv
+        assert gone() is None and cache() is None
+    finally:
+        gc.enable()

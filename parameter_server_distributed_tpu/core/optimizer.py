@@ -11,29 +11,32 @@ device-side SPMD train path uses optax under jit instead
 Each optimizer applies its update through the fused native C++ kernels
 (native/psdt_native.cpp — the analogue of the reference's C++ hot loop at
 src/parameter_server.cpp:40-91) when the library is available, falling back
-to numpy otherwise.  Both passes are in-place: the native kernel is
-single-sweep and GIL-free; the numpy path runs ``out=`` ufuncs over the
-owned optimizer slots plus ONE thread-local scratch buffer reused across
-tensors (:func:`_scratch_like`), so a step allocates exactly the output
-array per tensor instead of one temporary per sub-op.  Outputs are always
-fresh arrays — previously served parameter copies are never mutated.
+to numpy otherwise.  Both passes read the old parameters and write a
+SEPARATE output (previously served parameter copies are never mutated,
+and nothing is copied before the sweep); slots update in place.  The
+native kernel is single-sweep and GIL-free; the numpy path runs ``out=``
+ufuncs over the owned optimizer slots, the output and ONE thread-local
+scratch buffer reused across tensors (:func:`_scratch_like`), so a step
+allocates nothing per sub-op.
 
-Striping protocol (core/stripes.py, ISSUE 5): optimizer state is keyed
-per tensor name, so an update is **name-sliceable** — the striped barrier
-close calls :meth:`HostOptimizer.tick` once per logical step and then
-:meth:`HostOptimizer.apply_shard` concurrently over disjoint name
-subsets.  ``apply_shard`` over disjoint names is thread-safe by
-construction: each tensor touches only its own slot entries (per-key dict
-writes are GIL-atomic) and the scratch buffer is thread-local.
-``apply()`` (tick + one whole-store shard) remains the serial entry
-point, bit-for-bit unchanged.  The whole-store device-resident jit
+Striping protocol (core/stripes.py; ISSUE 5, PR 39): every host rule is
+elementwise and its state is keyed per tensor name, so an update is
+sliceable by ELEMENT RANGE — the striped barrier close calls
+:meth:`HostOptimizer.tick` once per logical step, :meth:`~HostOptimizer.
+prepare` once (serial: the slots exist before the fan-out), and then
+:meth:`HostOptimizer.update_range` concurrently over disjoint ranges of
+the store.  That is thread-safe by construction: a call touches its own
+range of the output and of the tensor's slots, and the scratch buffer is
+thread-local.  ``apply()`` (tick + one whole-store ``apply_shard``, the
+same rule over whole tensors into fresh arrays) remains the serial entry
+point.  The whole-store device-resident jit
 programs (DeviceOptimizer/PallasOptimizer,
-async_sgd/device_optimizer.py) are NOT name-sliceable and leave
+async_sgd/device_optimizer.py) are NOT sliceable and leave
 ``supports_striping`` False — the PS falls back to the serial
 whole-store apply for them; the sharded device family
-(ShardedDeviceOptimizer, ISSUE 11) IS name-sliceable and takes the
-striped close like the host optimizers, with each stripe's update
-running as jit-compiled device programs over that stripe's
+(ShardedDeviceOptimizer, ISSUE 11) is sliceable by NAME and takes the
+striped close with ``apply_shard`` per stripe of names, each stripe's
+update running as jit-compiled device programs over that stripe's
 device-resident partition.
 """
 
@@ -44,8 +47,7 @@ from typing import Mapping
 
 import numpy as np
 
-from ..native import (adam_native, adamw_native, lib as native_lib,
-                      momentum_native, sgd_native)
+from ..native import adam_native, momentum_native, sgd_native
 from .tensor import TensorStore
 
 
@@ -63,7 +65,7 @@ def _scratch_like(a: np.ndarray) -> np.ndarray:
     """A float32 scratch view shaped like ``a``, backed by a thread-local
     flat buffer reused across sub-ops, tensors, and steps (fresh for
     tensors above ``_SCRATCH_CAP_BYTES``).  Thread-local so
-    stripe-parallel ``apply_shard`` calls never share a buffer."""
+    concurrent ``update_range`` calls never share a buffer."""
     if 4 * a.size > _SCRATCH_CAP_BYTES:
         return np.empty(a.shape, np.float32)
     buf = getattr(_scratch_tls, "buf", None)
@@ -72,11 +74,36 @@ def _scratch_like(a: np.ndarray) -> np.ndarray:
     return buf[:a.size].reshape(a.shape)
 
 
-class HostOptimizer:
-    """Stateful optimizer over a named-tensor store."""
+def _owned_f32(a: np.ndarray) -> np.ndarray:
+    """Contiguous writable float32 view of an optimizer slot, copying only
+    when the stored array is not already kernel-ready (e.g. right after a
+    checkpoint load of a float64 or read-only array)."""
+    out = np.asarray(a, np.float32)
+    if not (out.flags.c_contiguous and out.flags.writeable):
+        out = np.array(out, np.float32)
+    return out
 
-    #: True when state is per-tensor-name and :meth:`apply_shard` may run
-    #: concurrently over disjoint name subsets (the striped PS hot path).
+
+def _flat(a: np.ndarray, lo: int, hi: int) -> np.ndarray:
+    """Elements [lo, hi) of a C-contiguous array in storage order: a view."""
+    return a.reshape(-1)[lo:hi]
+
+
+class HostOptimizer:
+    """Stateful optimizer over a named-tensor store.
+
+    A host rule is :meth:`update_range`: elements [lo, hi) of ONE tensor,
+    read from the old parameters and written to a separate output, the
+    slots looked up by name and updated in place over the same range.
+    Every rule is elementwise, so any cut of a tensor into ranges gives
+    the whole tensor's result bit for bit: the barrier close cuts the
+    store by element ranges (core/ps_core.py), and :meth:`apply_shard`
+    is the same rule over whole tensors."""
+
+    #: True when state is per-tensor-name and one logical step may run as
+    #: concurrent calls over disjoint pieces of the store (the striped PS
+    #: hot path): :meth:`update_range` over disjoint ranges for a host
+    #: rule, :meth:`apply_shard` over disjoint names for a device one.
     supports_striping = False
 
     def __init__(self, learning_rate: float = 1.0):
@@ -85,15 +112,50 @@ class HostOptimizer:
     def tick(self) -> None:
         """Advance per-logical-step state (Adam's bias-correction step
         counter) ONCE per barrier apply.  The striped closer calls
-        ``tick()`` once, then ``apply_shard()`` per stripe; calling
-        :meth:`apply` does both."""
+        ``tick()`` once, then fans the step out; calling :meth:`apply`
+        does both."""
+
+    def _slot_tables(self) -> tuple[TensorStore, ...]:
+        """The per-name slot dicts of this rule (Adam: m and v)."""
+        return ()
+
+    def prepare(self, grads: Mapping[str, np.ndarray]) -> None:
+        """Make every slot of every name in ``grads`` an owned float32
+        array of the gradient's shape (zeros where the name is new).
+        Serial, before :meth:`update_range` fans out: two ranges of one
+        tensor must find its slots, not both create them."""
+        for table in self._slot_tables():
+            for name, g in grads.items():
+                slot = table.get(name)
+                slot = (np.zeros(np.shape(g), np.float32) if slot is None
+                        else _owned_f32(slot))
+                if slot.size != np.size(g):
+                    raise ValueError(
+                        f"optimizer slot of {name!r} has shape "
+                        f"{slot.shape}, its gradient {np.shape(g)}")
+                table[name] = slot
+
+    def update_range(self, name: str, p: np.ndarray, g: np.ndarray,
+                     out: np.ndarray, lo: int, hi: int) -> None:
+        """The rule over elements [lo, hi) (storage order) of tensor
+        ``name``: ``p`` the old parameters, ``g`` the gradient, ``out``
+        the new parameters' array, all whole, float32, C-contiguous and
+        of one shape; :meth:`prepare` has run.  Thread-safe over disjoint
+        ranges: slots update in place over the range only, and the
+        scratch buffer is thread-local."""
+        raise NotImplementedError
 
     def apply_shard(self, params: TensorStore,
                     grads: Mapping[str, np.ndarray]) -> TensorStore:
         """Apply the update rule to a (sub)store WITHOUT advancing the
         step counter.  Same-name slot state updates in place; returned
         params are fresh arrays."""
-        raise NotImplementedError
+        out, todo = split_updates(params, grads)
+        self.prepare({name: g for name, _, g in todo})
+        for name, p, g in todo:
+            out[name] = np.empty_like(p)
+            self.update_range(name, p, g, out[name], 0, p.size)
+        return out
 
     def apply(self, params: TensorStore, grads: Mapping[str, np.ndarray]) -> TensorStore:
         self.tick()
@@ -106,31 +168,48 @@ class HostOptimizer:
         pass
 
 
+def split_updates(params: TensorStore, grads: Mapping[str, np.ndarray]
+                  ) -> tuple[TensorStore, list[tuple]]:
+    """One step's work over a host store: ``params`` as float32 arrays
+    (the new store wherever a name has no gradient: it passes through),
+    and a ``(name, p, g)`` for every name that has one, both C-contiguous
+    float32 of the parameter's shape, which is what
+    :meth:`HostOptimizer.update_range` indexes in storage order.  A
+    gradient of another shape (axes of one aside: a scalar comes back
+    from a checkpoint or the wire as (1,)) is an error."""
+    out: TensorStore = {}
+    todo = []
+    for name, p in params.items():
+        out[name] = p = np.asarray(p, np.float32)
+        if name not in grads:
+            continue
+        # not ascontiguousarray: that makes a 0-d array 1-d
+        p = np.asarray(p, order="C")
+        g = np.asarray(grads[name], np.float32, order="C")
+        if _squeezed(g.shape) != _squeezed(p.shape):
+            raise ValueError(f"gradient of {name!r} has shape {g.shape}, "
+                             f"the parameter {p.shape}")
+        todo.append((name, p, g.reshape(p.shape)))
+    return out, todo
+
+
+def _squeezed(shape: tuple) -> tuple:
+    return tuple(d for d in shape if d != 1)
+
+
 class SGD(HostOptimizer):
     """param -= lr * grad — the reference's rule at lr=1.0."""
 
     supports_striping = True
 
-    def apply_shard(self, params: TensorStore,
-                    grads: Mapping[str, np.ndarray]) -> TensorStore:
+    def update_range(self, name, p, g, out, lo, hi) -> None:
+        p, g, out = _flat(p, lo, hi), _flat(g, lo, hi), _flat(out, lo, hi)
         lr = np.float32(self.learning_rate)
-        use_native = native_lib() is not None
-        out: TensorStore = {}
-        for name, p in params.items():
-            if name not in grads:
-                out[name] = np.asarray(p, np.float32)
-                continue
-            g = np.asarray(grads[name], np.float32)
-            if use_native:
-                p_new = np.array(p, np.float32)  # fresh contiguous copy
-                if sgd_native(p_new, g, float(lr)):
-                    out[name] = p_new
-                    continue
-            p = np.asarray(p, np.float32)
-            scratch = _scratch_like(g)
-            np.multiply(g, lr, out=scratch)
-            out[name] = np.subtract(p, scratch)
-        return out
+        if sgd_native(p, g, out, float(lr)):
+            return
+        scratch = _scratch_like(g)
+        np.multiply(g, lr, out=scratch)
+        np.subtract(p, scratch, out=out)
 
 
 class Momentum(HostOptimizer):
@@ -141,44 +220,32 @@ class Momentum(HostOptimizer):
         self.momentum = momentum
         self.velocity: TensorStore = {}
 
-    def apply_shard(self, params: TensorStore,
-                    grads: Mapping[str, np.ndarray]) -> TensorStore:
+    def _slot_tables(self):
+        return (self.velocity,)
+
+    def prepare(self, grads) -> None:
+        # a new name's velocity starts as -0.0, the additive identity:
+        # mu * -0.0 + g is g bit for bit (a -0.0 stays -0.0, where a seed
+        # of zeros gives +0.0), so the first step copies the gradient
+        # into an owned slot by the rule of every later step
+        for name, g in grads.items():
+            if name not in self.velocity:
+                self.velocity[name] = np.full(np.shape(g), -0.0, np.float32)
+        super().prepare(grads)
+
+    def update_range(self, name, p, g, out, lo, hi) -> None:
+        p, g, out = _flat(p, lo, hi), _flat(g, lo, hi), _flat(out, lo, hi)
+        v = _flat(self.velocity[name], lo, hi)
         lr = np.float32(self.learning_rate)
         mu = np.float32(self.momentum)
-        use_native = native_lib() is not None
-        out: TensorStore = {}
-        for name, p in params.items():
-            p = np.asarray(p, np.float32)
-            if name not in grads:
-                out[name] = p
-                continue
-            g = np.asarray(grads[name], np.float32)
-            v_prev = self.velocity.get(name)
-            if use_native:
-                # fresh params buffer (served dicts hold references to the
-                # old one); velocity updates in place — state_dict
-                # deep-copies on snapshot
-                p_new = np.array(p, np.float32)
-                v_new = (_owned_f32(v_prev) if v_prev is not None
-                         else np.zeros_like(g))
-                if momentum_native(p_new, g, v_new, float(lr), float(mu)):
-                    self.velocity[name] = v_new
-                    out[name] = p_new
-                    continue
-            if v_prev is None:
-                # owned copy: the slot updates in place from now on and
-                # must never alias the caller's gradient array
-                v = np.array(g, np.float32)
-            else:
-                # v = mu * v + g, in place on the owned slot
-                v = _owned_f32(v_prev)
-                np.multiply(v, mu, out=v)
-                np.add(v, g, out=v)
-            self.velocity[name] = v
-            scratch = _scratch_like(v)
-            np.multiply(v, lr, out=scratch)
-            out[name] = np.subtract(p, scratch)  # the one fresh array
-        return out
+        if momentum_native(p, g, v, out, float(lr), float(mu)):
+            return
+        # v = mu * v + g, in place on the owned slot
+        np.multiply(v, mu, out=v)
+        np.add(v, g, out=v)
+        scratch = _scratch_like(v)
+        np.multiply(v, lr, out=scratch)
+        np.subtract(p, scratch, out=out)
 
     def state_dict(self) -> dict:
         # deep copy — the apply path updates velocity in place
@@ -188,16 +255,6 @@ class Momentum(HostOptimizer):
     def load_state_dict(self, state: dict) -> None:
         self.velocity = {k: np.array(v, np.float32)
                          for k, v in state.get("velocity", {}).items()}
-
-
-def _owned_f32(a: np.ndarray) -> np.ndarray:
-    """Contiguous writable float32 view of an optimizer slot, copying only
-    when the stored array is not already kernel-ready (e.g. right after a
-    checkpoint load of a float64 or read-only array)."""
-    out = np.asarray(a, np.float32)
-    if not (out.flags.c_contiguous and out.flags.writeable):
-        out = np.array(out, np.float32)
-    return out
 
 
 class Adam(HostOptimizer):
@@ -214,14 +271,32 @@ class Adam(HostOptimizer):
     def tick(self) -> None:
         self.step += 1
 
-    def _moments(self, name: str, g: np.ndarray,
-                 scratch: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        """In-place EMA update of the (owned) m/v slots for one tensor:
-        m = b1*m + (1-b1)*g, v = b2*v + (1-b2)*g², via out= ufuncs and the
-        shared scratch — no full-size temporaries."""
+    def _slot_tables(self):
+        return (self.m, self.v)
+
+    def _decay(self, p: np.ndarray) -> float | None:
+        """Decoupled weight decay for tensor ``p`` (whole: the mask reads
+        the TENSOR's rank); None is plain Adam, whose expression differs
+        from AdamW's at decay 0 in its order of operations."""
+        return None
+
+    def update_range(self, name, p, g, out, lo, hi) -> None:
+        wd = self._decay(p)
+        p, g, out = _flat(p, lo, hi), _flat(g, lo, hi), _flat(out, lo, hi)
+        m, v = _flat(self.m[name], lo, hi), _flat(self.v[name], lo, hi)
+        lr = np.float32(self.learning_rate)
+        # params never mutate in place (served param dicts hold
+        # references — RCU-style immutability); m/v are private to the
+        # optimizer and update in place (state_dict deep-copies).
+        if adam_native(p, g, m, v, out, float(lr), self.b1, self.b2,
+                       self.eps, self.step, wd):
+            return
         b1, b2 = np.float32(self.b1), np.float32(self.b2)
-        m = _owned_f32(self.m.get(name, np.zeros_like(g)))
-        v = _owned_f32(self.v.get(name, np.zeros_like(g)))
+        bc1 = 1.0 - self.b1 ** self.step
+        bc2 = 1.0 - self.b2 ** self.step
+        scratch = _scratch_like(g)
+        # m = b1*m + (1-b1)*g, v = b2*v + (1-b2)*g², via out= ufuncs and
+        # the shared scratch — no full-size temporaries
         np.multiply(g, np.float32(1.0) - b1, out=scratch)
         np.multiply(m, b1, out=m)
         np.add(m, scratch, out=m)
@@ -229,53 +304,26 @@ class Adam(HostOptimizer):
         np.multiply(scratch, np.float32(1.0) - b2, out=scratch)
         np.multiply(v, b2, out=v)
         np.add(v, scratch, out=v)
-        self.m[name], self.v[name] = m, v
-        return m, v
-
-    def apply_shard(self, params: TensorStore,
-                    grads: Mapping[str, np.ndarray]) -> TensorStore:
-        lr = np.float32(self.learning_rate)
-        bc1 = 1.0 - self.b1 ** self.step
-        bc2 = 1.0 - self.b2 ** self.step
-        use_native = native_lib() is not None
-        out: TensorStore = {}
-        for name, p in params.items():
-            p = np.asarray(p, np.float32)
-            if name not in grads:
-                out[name] = p
-                continue
-            g = np.asarray(grads[name], np.float32)
-            if use_native:
-                # params must NOT mutate in place (served param dicts hold
-                # references — RCU-style immutability), so the new params
-                # get a fresh buffer; m/v are private to the optimizer and
-                # update in place (state_dict deep-copies on snapshot).
-                m = _owned_f32(self.m.get(name, np.zeros_like(g)))
-                v = _owned_f32(self.v.get(name, np.zeros_like(g)))
-                p_new = np.array(p, np.float32)
-                if adam_native(p_new, g, m, v, float(lr), self.b1,
-                               self.b2, self.eps, self.step):
-                    self.m[name], self.v[name] = m, v
-                    out[name] = p_new
-                    continue
-            scratch = _scratch_like(g)
-            m, v = self._moments(name, g, scratch)
-            # denom = sqrt(v / bc2) + eps, staged in scratch
-            np.divide(v, bc2, out=scratch)
-            np.sqrt(scratch, out=scratch)
-            np.add(scratch, self.eps, out=scratch)
-            # p - lr * (m / bc1) / denom, staged in the fresh output —
-            # lr multiplied BEFORE the denom divide, preserving the
-            # pre-in-place expression's evaluation order bit for bit
-            # (explicit empty_like: ufuncs on 0-d arrays without out=
-            # return scalars, which cannot chain as out= targets)
-            p_new = np.empty_like(p)
-            np.divide(m, bc1, out=p_new)
-            np.multiply(p_new, lr, out=p_new)
-            np.divide(p_new, scratch, out=p_new)
-            np.subtract(p, p_new, out=p_new)
-            out[name] = p_new
-        return out
+        # denom = sqrt(v / bc2) + eps, staged in scratch
+        np.divide(v, bc2, out=scratch)
+        np.sqrt(scratch, out=scratch)
+        np.add(scratch, self.eps, out=scratch)
+        np.divide(m, bc1, out=out)
+        if wd is None:
+            # p - lr * (m / bc1) / denom — lr multiplied BEFORE the
+            # denom divide, preserving the pre-in-place expression's
+            # evaluation order bit for bit
+            np.multiply(out, lr, out=out)
+            np.divide(out, scratch, out=out)
+        else:
+            # optax.adamw convention: update = adam_term + wd * p,
+            # applied together from the PRE-update param
+            np.divide(out, scratch, out=out)
+            if wd:
+                np.multiply(p, np.float32(wd), out=scratch)
+                np.add(out, scratch, out=out)
+            np.multiply(out, lr, out=out)
+        np.subtract(p, out, out=out)
 
     def state_dict(self) -> dict:
         # deep copy: the hot apply path updates m/v IN PLACE, so a
@@ -298,57 +346,16 @@ class Adam(HostOptimizer):
 class AdamW(Adam):
     """Adam with decoupled weight decay on matrices only (sub-2D params —
     norm scales, biases — are excluded, matching the device-side optax
-    mask in parallel/train_step.make_optimizer)."""
+    mask in parallel/train_step.make_optimizer; decaying them is a
+    quality bug)."""
 
     def __init__(self, learning_rate: float = 1e-3,
                  weight_decay: float = 1e-4, **kwargs):
         super().__init__(learning_rate, **kwargs)
         self.weight_decay = weight_decay
 
-    def apply_shard(self, params: TensorStore,
-                    grads: Mapping[str, np.ndarray]) -> TensorStore:
-        lr = np.float32(self.learning_rate)
-        bc1 = 1.0 - self.b1 ** self.step
-        bc2 = 1.0 - self.b2 ** self.step
-        use_native = native_lib() is not None
-        out: TensorStore = {}
-        for name, p in params.items():
-            p = np.asarray(p, np.float32)
-            if name not in grads:
-                out[name] = p
-                continue
-            # decay from the PRE-update param, matrices only
-            # (optax.adamw convention: update = adam_term + wd * p,
-            # applied together; decaying norm scales/biases is a quality
-            # bug — mask matches parallel/train_step.make_optimizer)
-            wd = self.weight_decay if p.ndim >= 2 else 0.0
-            g = np.asarray(grads[name], np.float32)
-            if use_native:
-                # fresh params buffer (served dicts hold references to the
-                # old one); m/v update in place — see Adam.apply_shard
-                m = _owned_f32(self.m.get(name, np.zeros_like(g)))
-                v = _owned_f32(self.v.get(name, np.zeros_like(g)))
-                p_new = np.array(p, np.float32)
-                if adamw_native(p_new, g, m, v, float(lr), self.b1,
-                                self.b2, self.eps, self.step, wd):
-                    self.m[name], self.v[name] = m, v
-                    out[name] = p_new
-                    continue
-            scratch = _scratch_like(g)
-            m, v = self._moments(name, g, scratch)
-            np.divide(v, bc2, out=scratch)
-            np.sqrt(scratch, out=scratch)
-            np.add(scratch, self.eps, out=scratch)
-            p_new = np.empty_like(p)
-            np.divide(m, bc1, out=p_new)
-            np.divide(p_new, scratch, out=p_new)  # adam_term
-            if wd:
-                np.multiply(p, np.float32(wd), out=scratch)
-                np.add(p_new, scratch, out=p_new)
-            np.multiply(p_new, lr, out=p_new)
-            np.subtract(p, p_new, out=p_new)
-            out[name] = p_new
-        return out
+    def _decay(self, p: np.ndarray) -> float:
+        return self.weight_decay if p.ndim >= 2 else 0.0
 
 
 class Lion(HostOptimizer):
@@ -368,40 +375,32 @@ class Lion(HostOptimizer):
         self.weight_decay = weight_decay
         self.m: TensorStore = {}
 
-    def apply_shard(self, params: TensorStore,
-                    grads: Mapping[str, np.ndarray]) -> TensorStore:
+    def _slot_tables(self):
+        return (self.m,)
+
+    def update_range(self, name, p, g, out, lo, hi) -> None:
+        wd = self.weight_decay if p.ndim >= 2 else 0.0
+        p, g, out = _flat(p, lo, hi), _flat(g, lo, hi), _flat(out, lo, hi)
+        m = _flat(self.m[name], lo, hi)
         lr = np.float32(self.learning_rate)
         b1, b2 = np.float32(self.b1), np.float32(self.b2)
         one = np.float32(1.0)
-        out: TensorStore = {}
-        for name, p in params.items():
-            p = np.asarray(p, np.float32)
-            if name not in grads:
-                out[name] = p
-                continue
-            g = np.asarray(grads[name], np.float32)
-            m = _owned_f32(self.m.get(name, np.zeros_like(g)))
-            scratch = _scratch_like(g)
-            # update = sign(b1*m + (1-b1)*g), staged in the fresh output
-            # (m itself is still needed for its own EMA below)
-            p_new = np.empty_like(p)
-            np.multiply(m, b1, out=p_new)
-            np.multiply(g, one - b1, out=scratch)
-            np.add(p_new, scratch, out=p_new)
-            np.sign(p_new, out=p_new)
-            # m = b2*m + (1-b2)*g, in place on the owned slot
-            np.multiply(m, b2, out=m)
-            np.multiply(g, one - b2, out=scratch)
-            np.add(m, scratch, out=m)
-            self.m[name] = m
-            wd = self.weight_decay if p.ndim >= 2 else 0.0
-            if wd:
-                np.multiply(p, np.float32(wd), out=scratch)
-                np.add(p_new, scratch, out=p_new)
-            np.multiply(p_new, lr, out=p_new)
-            np.subtract(p, p_new, out=p_new)
-            out[name] = p_new
-        return out
+        scratch = _scratch_like(g)
+        # update = sign(b1*m + (1-b1)*g), staged in the output (m itself
+        # is still needed for its own EMA below)
+        np.multiply(m, b1, out=out)
+        np.multiply(g, one - b1, out=scratch)
+        np.add(out, scratch, out=out)
+        np.sign(out, out=out)
+        # m = b2*m + (1-b2)*g, in place on the owned slot
+        np.multiply(m, b2, out=m)
+        np.multiply(g, one - b2, out=scratch)
+        np.add(m, scratch, out=m)
+        if wd:
+            np.multiply(p, np.float32(wd), out=scratch)
+            np.add(out, scratch, out=out)
+        np.multiply(out, lr, out=out)
+        np.subtract(p, out, out=out)
 
     def state_dict(self) -> dict:
         # deep copy — the apply path updates m in place

@@ -61,8 +61,12 @@ reservation (dedup, seal check) stays under ``_state_lock``, the O(bytes)
 different cores.  The barrier close seals the iteration and DRAINS
 in-flight folds (``IterationState.inflight`` over the barrier condition
 variable) before taking the accumulator, then runs the scale and the
-optimizer apply stripe-parallel (``HostOptimizer.tick`` once +
-``apply_shard`` per stripe) on the shared named executor.
+optimizer apply on the shared named executor: both are elementwise, so
+a host store is cut by ELEMENT RANGES and not by name (S nearly equal
+ranges whatever the tensors are called; ``HostOptimizer.tick`` once,
+``prepare``, then ``update_range`` per piece), out of place into the
+buffers of the store retired two closes ago (core/close_buffers.py);
+a device store keeps ``apply_shard`` per stripe of names.
 ``PSDT_STRIPES=1`` bypasses every striped branch — the exact serial
 code path, timing included.
 
@@ -102,8 +106,10 @@ from ..obs import trace as obs_trace
 from ..replication.messages import STALE_SHARD_MAP
 from . import arena as arena_mod
 from . import device_apply
-from .optimizer import HostOptimizer, SGD
-from .stripes import partition_names, run_striped, stripe_count, stripe_of
+from .close_buffers import CloseBuffers
+from .optimizer import HostOptimizer, SGD, split_updates
+from .stripes import (partition_names, partition_ranges, run_striped,
+                      stripe_count, stripe_of)
 from .tensor import TensorStore, store_nbytes, tree_like
 
 log = logging.getLogger("pst.core")
@@ -407,6 +413,9 @@ class ParameterServerCore:
         # last stripe-parallel optimizer apply
         self._obs_stripe_ms = obs_stats.histogram("ps.apply.stripe_ms")
         self._obs_parallelism = obs_stats.gauge("ps.apply.parallelism")
+        # where the range-cut close writes the store it is about to
+        # publish: the buffers of the store retired two closes ago
+        self._close_buffers = CloseBuffers()
         # accelerator-resident applies (ISSUE 11): count of barrier
         # closes whose fresh store is device-resident (the pst-status
         # "device apply" rollup line reads this)
@@ -1897,9 +1906,11 @@ class ParameterServerCore:
 
     def _scale_striped(self, sums: TensorStore,
                        counts: dict[str, int]) -> None:
-        """In-place sums -> means, fanned per stripe across the shared
-        executor (the per-tensor op is unchanged, so the result is
-        bit-for-bit the serial loop's).  Caller holds _apply_lock."""
+        """In-place sums -> means (the per-element op is unchanged, so the
+        result is bit-for-bit the serial loop's).  Host sums are cut by
+        element ranges like the optimizer sweep after them
+        (:meth:`_sweep_by_range`); device sums keep the grouping by name.
+        Caller holds _apply_lock."""
         def scale_one(name: str) -> None:
             acc = sums[name]
             if isinstance(acc, np.ndarray):
@@ -1910,9 +1921,9 @@ class ParameterServerCore:
                 # buffer and uses the SAME f32 scalar as the numpy path
                 sums[name] = device_apply.scale_mean(acc, counts[name])
 
-        if (self._stripes <= 1 or len(sums) <= 1
-                or (device_apply.is_device_store(sums)
-                    and not device_apply.stripe_dispatch(sums))):
+        on_device = device_apply.is_device_store(sums)
+        if (self._stripes <= 1 or not sums or (on_device and (
+                len(sums) <= 1 or not device_apply.stripe_dispatch(sums)))):
             # large device sums scale from ONE dispatcher for the same
             # reason the device apply does (see _apply_update): big
             # kernels parallelize inside XLA, and stripe-thread
@@ -1921,12 +1932,35 @@ class ParameterServerCore:
                 scale_one(name)
             return
 
-        def scale_group(names: list[str]) -> None:
-            for name in names:
-                scale_one(name)
+        if on_device:
+            def scale_group(names: list[str]) -> None:
+                for name in names:
+                    scale_one(name)
 
-        run_striped([(lambda ns=ns: scale_group(ns))
-                     for ns in partition_names(sums, self._stripes)])
+            run_striped([(lambda ns=ns: scale_group(ns))
+                         for ns in partition_names(sums, self._stripes)])
+            return
+
+        # one contributor: x * 1.0f is x, and the pass would read and
+        # write the whole store to say so
+        flat = []
+        for name, acc in sums.items():
+            if counts[name] == 1:
+                continue
+            if acc.flags.c_contiguous:
+                flat.append((acc.reshape(-1),
+                             np.float32(1.0 / counts[name])))
+            else:
+                scale_one(name)     # reshape would copy: scale it whole
+
+        def scale_ranges(pieces: list) -> None:
+            for i, lo, hi in pieces:
+                acc, inv = flat[i]
+                np.multiply(acc[lo:hi], inv, out=acc[lo:hi])
+
+        run_striped([(lambda ps=ps: scale_ranges(ps)) for ps in
+                     partition_ranges([acc.size for acc, _ in flat],
+                                      self._stripes)])
 
     # ------------------------------------------------------ arena close
     def _arena_fallback_reason(self, sums: "arena_mod.ArenaAccum",
@@ -2039,10 +2073,10 @@ class ParameterServerCore:
     def _apply_striped_sync(self, prev: TensorStore,
                             mean_grads: TensorStore) -> None:
         """Stripe-parallel synchronous apply: tick the optimizer once,
-        then ``apply_shard`` per stripe on the shared executor — each
-        stripe updates its own optimizer-state slice in place and emits
-        fresh param arrays for its names; the merged store is swapped in
-        under _params_lock.  The caller serializes applies (_apply_lock
+        then fan the step across the shared executor — by element ranges
+        where the store and the means are host arrays, by name where
+        they live on a device; the new store is swapped in under
+        _params_lock.  The caller serializes applies (_apply_lock
         on the streaming close, _state_lock on the buffered path), so the
         optimizer never sees two concurrent logical steps.  Serves during
         the compute read the previous store at its previous version —
@@ -2051,29 +2085,17 @@ class ParameterServerCore:
         post-barrier one."""
         opt = self._optimizer
         opt.tick()
-        name_groups = partition_names(prev, self._stripes)
-        stripe_s = [0.0] * len(name_groups)
-
-        def apply_group(idx: int, names: list[str]) -> TensorStore:
-            t1 = time.perf_counter()
-            res = opt.apply_shard(
-                {n: prev[n] for n in names},
-                {n: mean_grads[n] for n in names if n in mean_grads})
-            stripe_s[idx] = time.perf_counter() - t1
-            return res
-
+        by_range = not (device_apply.wants_device_fold(opt)
+                        or device_apply.is_device_store(prev)
+                        or device_apply.is_device_store(mean_grads))
         t0 = time.perf_counter()
-        parts = run_striped([(lambda i=i, ns=ns: apply_group(i, ns))
-                             for i, ns in enumerate(name_groups)])
+        new_params, task_s = (self._sweep_by_range if by_range
+                              else self._sweep_by_name)(prev, mean_grads)
         wall = time.perf_counter() - t0
-        by_name: TensorStore = {}
-        for part in parts:
-            by_name.update(part)
-        new_params = {name: by_name[name] for name in prev}  # stable order
-        for dt in stripe_s:
+        for dt in task_s:
             self._obs_stripe_ms.observe(1e3 * dt)
         if wall > 0:
-            self._obs_parallelism.set(round(sum(stripe_s) / wall, 2))
+            self._obs_parallelism.set(round(sum(task_s) / wall, 2))
         with self._params_lock:
             if self._params is not prev:
                 # initialize_parameters() landed during the striped
@@ -2087,12 +2109,77 @@ class ParameterServerCore:
             self._params = new_params
             self._params_version += 1
             version = self._params_version
+        if by_range:
+            self._close_buffers.publish()
         # readback first, then the delta build, both after the swap and
         # outside _params_lock (the caller's _apply_lock/_state_lock
         # still serializes applies) — the sink's encode then overlaps
         # the D2H copies already in flight
         self._note_device_apply(new_params, t0)
         self._notify_delta(new_params, version)
+
+    def _sweep_by_name(self, prev: TensorStore, mean_grads: TensorStore
+                       ) -> tuple[TensorStore, list[float]]:
+        """One optimizer step over a store on a device, ``apply_shard``
+        per stripe of NAMES (a jax array is not sliced in place): each
+        stripe updates its own optimizer-state slice and emits fresh
+        param arrays for its names.  Returns the new store and each
+        task's seconds."""
+        opt = self._optimizer
+        name_groups = partition_names(prev, self._stripes)
+        task_s = [0.0] * len(name_groups)
+
+        def apply_group(idx: int, names: list[str]) -> TensorStore:
+            t1 = time.perf_counter()
+            res = opt.apply_shard(
+                {n: prev[n] for n in names},
+                {n: mean_grads[n] for n in names if n in mean_grads})
+            task_s[idx] = time.perf_counter() - t1
+            return res
+
+        parts = run_striped([(lambda i=i, ns=ns: apply_group(i, ns))
+                             for i, ns in enumerate(name_groups)])
+        by_name: TensorStore = {}
+        for part in parts:
+            by_name.update(part)
+        return {name: by_name[name] for name in prev}, task_s  # stable order
+
+    def _sweep_by_range(self, prev: TensorStore, mean_grads: TensorStore
+                        ) -> tuple[TensorStore, list[float]]:
+        """One optimizer step over a host store, cut by ELEMENT RANGES:
+        the tensors that have a gradient, laid end to end, are cut into
+        as many nearly equal ranges as there are stripes, one task each;
+        a large tensor is split across tasks, small ones ride together.
+        Every host rule is elementwise (``HostOptimizer.update_range``),
+        so the cut changes no bit of the result, and how wide the sweep
+        runs no longer depends on what the tensors are called (a cut by
+        name cannot run wider than total / largest stripe: 2.7 for a
+        scanned transformer, whose two MLP matrices are half the store).
+
+        Out of place: each task reads the served arrays and writes the
+        ranges of the new ones, which lie in the buffers of the store
+        retired two closes ago when nobody reads them any more
+        (core/close_buffers.py).  Returns the new store and each task's
+        seconds."""
+        opt = self._optimizer
+        new_params, todo = split_updates(prev, mean_grads)
+        opt.prepare({name: g for name, _, g in todo})
+        new_params.update(self._close_buffers.take(
+            {name: p.shape for name, p, _ in todo}))
+        ranges = partition_ranges([p.size for _, p, _ in todo],
+                                  self._stripes)
+        task_s = [0.0] * len(ranges)
+
+        def sweep(idx: int, pieces: list) -> None:
+            t1 = time.perf_counter()
+            for i, lo, hi in pieces:
+                name, p, g = todo[i]
+                opt.update_range(name, p, g, new_params[name], lo, hi)
+            task_s[idx] = time.perf_counter() - t1
+
+        run_striped([(lambda i=i, ps=ps: sweep(i, ps))
+                     for i, ps in enumerate(ranges)])
+        return new_params, task_s
 
     def _apply_update(self, mean_grads: TensorStore) -> None:
         """Applies are serialized by the caller: _state_lock on the
@@ -2135,12 +2222,15 @@ class ParameterServerCore:
         elif (self._stripes > 1
               and getattr(self._optimizer, "supports_striping", False)
               and (not device_apply.wants_device_fold(self._optimizer)
-                   or device_apply.stripe_dispatch(mean_grads))
-              and len(mean_grads) > 1):
+                   or (device_apply.stripe_dispatch(mean_grads)
+                       and len(mean_grads) > 1))):
             # Host optimizers always fan the apply across stripe
-            # threads (real multi-core numpy sweeps).  A device-resident
-            # optimizer fans out only while tensors are SMALL
-            # (dispatch-bound regime); past device_apply's mean-size
+            # threads (real multi-core sweeps, cut by element ranges
+            # whatever the tensors are called and however many there
+            # are: one huge tensor runs as wide as a thousand small
+            # ones).  A device-resident optimizer is cut by name, so
+            # it needs two names, and fans out only while tensors are
+            # SMALL (dispatch-bound regime); past device_apply's mean-size
             # bound its kernels data-parallelize inside the XLA runtime
             # and a second dispatcher only contends with the intra-op
             # pool, so the close dispatches from one thread (the serial

@@ -10,7 +10,11 @@ Stripes never split a single tensor's reduction, so striped results are
 bit-for-bit identical to serial — the parallelism only changes WHICH
 thread runs each tensor's (unchanged) f32 ufunc sweep, and numpy/native
 kernels release the GIL for the sweeps, so S stripes really occupy S
-cores.
+cores.  An ELEMENTWISE sweep needs no such care and is cut by element
+ranges instead (:func:`partition_ranges`: the barrier close's scale and
+optimizer update over a host store), so that it runs S wide whatever the
+tensors are called: by name, a store whose two largest tensors are half
+of it cannot run wider than 4.
 
 ``PSDT_STRIPES`` sets S (default: usable cores; ``1`` keeps the exact
 serial code path — ps_core bypasses the striped branches entirely).
@@ -78,6 +82,34 @@ def partition_names(names: Iterable[str],
     for name in names:
         groups.setdefault(stripe_of(name, stripes), []).append(name)
     return [groups[s] for s in sorted(groups)]
+
+
+def partition_ranges(sizes: Sequence[int],
+                     parts: int) -> list[list[tuple[int, int, int]]]:
+    """Cut tensors of ``sizes`` elements, laid end to end, into at most
+    ``parts`` nearly equal element ranges.  One part is a list of
+    ``(index, lo, hi)``: elements [lo, hi) of tensor ``index`` in storage
+    order.  A tensor larger than a part is split across parts, small
+    ones ride together in one; every element lies in exactly one piece
+    and no part is empty.  Reads sizes only: what an elementwise sweep
+    (the barrier close's scale and optimizer update, ps_core.py) needs
+    to run as wide as the pool whatever the tensors are called."""
+    total = sum(sizes)
+    parts = max(1, min(int(parts), total))
+    out: list[list[tuple[int, int, int]]] = [[] for _ in range(parts)]
+    start = 0      # offset of the current tensor in the whole store
+    k = 0          # the part being filled
+    for index, size in enumerate(sizes):
+        lo = 0
+        while lo < size:
+            end = total * (k + 1) // parts       # where part k ends
+            hi = min(size, end - start)
+            out[k].append((index, lo, hi))
+            lo = hi
+            if start + hi == end:
+                k += 1
+        start += size
+    return [part for part in out if part]
 
 
 # One process-wide pool, created on first use.  Single-flight under a

@@ -109,13 +109,21 @@ def _bind(path: str) -> ctypes.CDLL:
     i64, i32, f32 = ctypes.c_int64, ctypes.c_int32, ctypes.c_float
     pp = ctypes.POINTER(_F32P)
     lib.psdt_mean.argtypes = [pp, i32, i64, _F32P]
-    lib.psdt_sgd.argtypes = [_F32P, _F32P, i64, f32]
-    lib.psdt_momentum.argtypes = [_F32P, _F32P, _F32P, i64, f32, f32]
-    lib.psdt_adam.argtypes = [_F32P, _F32P, _F32P, _F32P, i64, f32, f32, f32,
-                              f32, f32, f32]
-    lib.psdt_adamw.argtypes = [_F32P, _F32P, _F32P, _F32P, i64, f32, f32,
-                               f32, f32, f32, f32, f32]
     lib.psdt_mean_sgd.argtypes = [_F32P, pp, i32, i64, f32]
+    # the optimizers' out-of-place sweeps (param, grad, slots..., out, n,
+    # scalars...).  A library built before they existed (an old build on
+    # a read-only install, which _build cannot replace) lacks them: that
+    # optimizer then runs its numpy rule (_sweep), the rest stays bound.
+    for name, argtypes in (
+            ("psdt_sgd_out", [_F32P] * 3 + [i64, f32]),
+            ("psdt_momentum_out", [_F32P] * 4 + [i64, f32, f32]),
+            ("psdt_adam_out", [_F32P] * 5 + [i64] + [f32] * 6),
+            ("psdt_adamw_out", [_F32P] * 5 + [i64] + [f32] * 7)):
+        try:
+            getattr(lib, name).argtypes = argtypes
+        except AttributeError:
+            log.warning("native library %s has no %s; that optimizer "
+                        "uses numpy", path, name)
     # wire-codec kernels (rpc/codec.py NativeCodec)
     lib.psdt_copy.argtypes = [ctypes.c_void_p, ctypes.c_void_p, i64]
     lib.psdt_pack_bf16.argtypes = [_F32P, i64, _U8P]
@@ -202,16 +210,32 @@ def mean_over_workers_native(arrays: list[np.ndarray]) -> np.ndarray | None:
     return out
 
 
-def sgd_native(param: np.ndarray, grad: np.ndarray, lr: float) -> bool:
-    """In-place param -= lr*grad; returns False if native path unavailable."""
+def _sweep(name: str, out: np.ndarray, *arrays: np.ndarray):
+    """The optimizer entry point ``name``, or None when the library lacks
+    it or the arrays do not suit it: all float32, C-contiguous and of
+    ``out``'s shape, ``out`` writable and overlapping none of the others
+    (the kernels declare it ``__restrict__``).  None means the caller's
+    numpy rule."""
     native = lib()
-    if (native is None or param.dtype != np.float32
-            or not param.flags.c_contiguous
-            or param.shape != np.shape(grad)):
+    fn = getattr(native, name, None) if native is not None else None
+    if fn is None or not out.flags.writeable:
+        return None
+    for a in (out,) + arrays:
+        if (a.dtype != np.float32 or not a.flags.c_contiguous
+                or a.shape != out.shape):
+            return None
+    if any(np.may_share_memory(out, a) for a in arrays):
+        return None
+    return fn
+
+
+def sgd_native(param: np.ndarray, grad: np.ndarray, out: np.ndarray,
+               lr: float) -> bool:
+    """out = param - lr*grad; returns False if native path unavailable."""
+    fn = _sweep("psdt_sgd_out", out, param, grad)
+    if fn is None:
         return False
-    grad_c = np.ascontiguousarray(grad, np.float32)
-    native.psdt_sgd(_fptr(param), _fptr(grad_c), param.size,
-                    ctypes.c_float(lr))
+    fn(_fptr(param), _fptr(grad), _fptr(out), out.size, ctypes.c_float(lr))
     return True
 
 
@@ -232,42 +256,35 @@ def mean_sgd_native(param: np.ndarray, grads: list[np.ndarray],
 
 
 def momentum_native(param: np.ndarray, grad: np.ndarray,
-                    velocity: np.ndarray, lr: float, mu: float) -> bool:
-    """In-place fused velocity = mu*velocity + grad; param -= lr*velocity.
-    Both param and velocity are updated in place."""
-    native = lib()
-    if (native is None
-            or param.dtype != np.float32 or not param.flags.c_contiguous
-            or velocity.dtype != np.float32
-            or not velocity.flags.c_contiguous
-            or param.shape != np.shape(grad)
-            or param.shape != velocity.shape):
+                    velocity: np.ndarray, out: np.ndarray, lr: float,
+                    mu: float) -> bool:
+    """Fused velocity = mu*velocity + grad (in place);
+    out = param - lr*velocity."""
+    fn = _sweep("psdt_momentum_out", out, param, grad, velocity)
+    if fn is None:
         return False
-    grad_c = np.ascontiguousarray(grad, np.float32)
-    native.psdt_momentum(_fptr(param), _fptr(grad_c), _fptr(velocity),
-                         param.size, ctypes.c_float(lr), ctypes.c_float(mu))
+    fn(_fptr(param), _fptr(grad), _fptr(velocity), _fptr(out), out.size,
+       ctypes.c_float(lr), ctypes.c_float(mu))
     return True
 
 
 def adam_native(param: np.ndarray, grad: np.ndarray, m: np.ndarray,
-                v: np.ndarray, lr: float, b1: float, b2: float, eps: float,
-                step: int) -> bool:
-    """In-place fused Adam pass (param, m, v all updated in place); ``step``
-    is the 1-based update count used for bias correction."""
-    native = lib()
-    arrays = (param, m, v)
-    if (native is None or step < 1
-            or any(a.dtype != np.float32 or not a.flags.c_contiguous
-                   for a in arrays)
-            or param.shape != np.shape(grad)
-            or any(a.shape != param.shape for a in (m, v))):
+                v: np.ndarray, out: np.ndarray, lr: float, b1: float,
+                b2: float, eps: float, step: int,
+                wd: float | None = None) -> bool:
+    """Fused Adam pass: m and v update in place, ``out`` takes the new
+    parameters; ``step`` is the 1-based update count used for bias
+    correction.  With ``wd`` the AdamW kernel (Adam + decoupled decay in
+    one sweep; 0 for tensors excluded from decay)."""
+    fn = _sweep("psdt_adam_out" if wd is None else "psdt_adamw_out",
+                out, param, grad, m, v)
+    if fn is None or step < 1:
         return False
-    grad_c = np.ascontiguousarray(grad, np.float32)
-    native.psdt_adam(_fptr(param), _fptr(grad_c), _fptr(m), _fptr(v),
-                     param.size, ctypes.c_float(lr), ctypes.c_float(b1),
-                     ctypes.c_float(b2), ctypes.c_float(eps),
-                     ctypes.c_float(1.0 - b1 ** step),
-                     ctypes.c_float(1.0 - b2 ** step))
+    scalars = [lr, b1, b2, eps, 1.0 - b1 ** step, 1.0 - b2 ** step]
+    if wd is not None:
+        scalars.append(wd)
+    fn(_fptr(param), _fptr(grad), _fptr(m), _fptr(v), _fptr(out), out.size,
+       *map(ctypes.c_float, scalars))
     return True
 
 
@@ -367,26 +384,3 @@ def topk_unpack_native(raw, out: np.ndarray) -> bool:
         return False
     rc = native.psdt_topk_unpack(_u8ptr(u8), out.size, _fptr(out))
     return rc == 0
-
-
-def adamw_native(param: np.ndarray, grad: np.ndarray, m: np.ndarray,
-                 v: np.ndarray, lr: float, b1: float, b2: float, eps: float,
-                 step: int, wd: float) -> bool:
-    """In-place fused AdamW pass (Adam + decoupled decay in one sweep);
-    pass wd=0 for tensors excluded from decay."""
-    native = lib()
-    arrays = (param, m, v)
-    if (native is None or step < 1
-            or any(a.dtype != np.float32 or not a.flags.c_contiguous
-                   for a in arrays)
-            or param.shape != np.shape(grad)
-            or any(a.shape != param.shape for a in (m, v))):
-        return False
-    grad_c = np.ascontiguousarray(grad, np.float32)
-    native.psdt_adamw(_fptr(param), _fptr(grad_c), _fptr(m), _fptr(v),
-                      param.size, ctypes.c_float(lr), ctypes.c_float(b1),
-                      ctypes.c_float(b2), ctypes.c_float(eps),
-                      ctypes.c_float(1.0 - b1 ** step),
-                      ctypes.c_float(1.0 - b2 ** step),
-                      ctypes.c_float(wd))
-    return True

@@ -129,27 +129,40 @@ void psdt_mean(const float** srcs, int32_t count, const int64_t n,
     }
 }
 
-// param -= lr * grad   (the reference's update rule at lr=1.0)
-void psdt_sgd(float* param, const float* grad, const int64_t n,
-              const float lr) {
-    for (int64_t i = 0; i < n; ++i) param[i] -= lr * grad[i];
+// The host optimizers' sweeps (core/optimizer.py).  Each reads the old
+// parameters and writes the new ones to a SEPARATE output, so the caller
+// never copies the store before the sweep; the barrier close hands each
+// call an element range of a tensor (core/ps_core.py), and every kernel
+// is elementwise, so any cut gives the whole tensor's result bit for bit.
+// ``out`` overlaps no input (__restrict__: the loops vectorize without
+// run-time overlap checks); optimizer slots update in place.
+
+// out = param - lr * grad   (the reference's update rule at lr=1.0)
+void psdt_sgd_out(const float* __restrict__ param,
+                  const float* __restrict__ grad, float* __restrict__ out,
+                  const int64_t n, const float lr) {
+    for (int64_t i = 0; i < n; ++i) out[i] = param[i] - lr * grad[i];
 }
 
-// velocity = mu * velocity + grad; param -= lr * velocity  (one pass)
-void psdt_momentum(float* param, const float* grad, float* velocity,
-                   const int64_t n, const float lr, const float mu) {
+// velocity = mu * velocity + grad; out = param - lr * velocity  (one pass)
+void psdt_momentum_out(const float* __restrict__ param,
+                       const float* __restrict__ grad,
+                       float* __restrict__ velocity, float* __restrict__ out,
+                       const int64_t n, const float lr, const float mu) {
     for (int64_t i = 0; i < n; ++i) {
         const float v = mu * velocity[i] + grad[i];
         velocity[i] = v;
-        param[i] -= lr * v;
+        out[i] = param[i] - lr * v;
     }
 }
 
 // Adam fused pass.  bc1/bc2 are the bias-correction denominators.
-void psdt_adam(float* param, const float* grad, float* m, float* v,
-               const int64_t n, const float lr, const float b1,
-               const float b2, const float eps, const float bc1,
-               const float bc2) {
+void psdt_adam_out(const float* __restrict__ param,
+                   const float* __restrict__ grad, float* __restrict__ m,
+                   float* __restrict__ v, float* __restrict__ out,
+                   const int64_t n, const float lr, const float b1,
+                   const float b2, const float eps, const float bc1,
+                   const float bc2) {
     for (int64_t i = 0; i < n; ++i) {
         const float g = grad[i];
         const float m_new = b1 * m[i] + (1.0f - b1) * g;
@@ -158,7 +171,7 @@ void psdt_adam(float* param, const float* grad, float* m, float* v,
         v[i] = v_new;
         const float m_hat = m_new / bc1;
         const float v_hat = v_new / bc2;
-        param[i] -= lr * m_hat / (__builtin_sqrtf(v_hat) + eps);
+        out[i] = param[i] - lr * m_hat / (__builtin_sqrtf(v_hat) + eps);
     }
 }
 
@@ -166,10 +179,12 @@ void psdt_adam(float* param, const float* grad, float* m, float* v,
 // sweep (optax.adamw convention: update = adam_term + wd * p_pre, applied
 // together from the pre-update param).  wd = 0 for non-decayed tensors
 // (the matrices-only mask lives in the Python caller).
-void psdt_adamw(float* param, const float* grad, float* m, float* v,
-                const int64_t n, const float lr, const float b1,
-                const float b2, const float eps, const float bc1,
-                const float bc2, const float wd) {
+void psdt_adamw_out(const float* __restrict__ param,
+                    const float* __restrict__ grad, float* __restrict__ m,
+                    float* __restrict__ v, float* __restrict__ out,
+                    const int64_t n, const float lr, const float b1,
+                    const float b2, const float eps, const float bc1,
+                    const float bc2, const float wd) {
     for (int64_t i = 0; i < n; ++i) {
         const float g = grad[i];
         const float p_old = param[i];
@@ -179,7 +194,7 @@ void psdt_adamw(float* param, const float* grad, float* m, float* v,
         v[i] = v_new;
         const float m_hat = m_new / bc1;
         const float v_hat = v_new / bc2;
-        param[i] = p_old
+        out[i] = p_old
             - lr * (m_hat / (__builtin_sqrtf(v_hat) + eps) + wd * p_old);
     }
 }

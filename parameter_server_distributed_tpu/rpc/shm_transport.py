@@ -93,6 +93,7 @@ from ..analysis.lock_order import checked_lock
 from ..obs import flight
 from ..obs import stats as obs_stats
 from ..obs import trace as obs_trace
+from ..utils.buffers import exported
 from .wire import Field, Message
 
 log = logging.getLogger("pst.shm")
@@ -255,21 +256,6 @@ def _doorbell_connect(addr: str, timeout: float = 10.0) -> socket.socket:
     return sock
 
 
-def _exported(buf: bytearray) -> bool:
-    """Whether any view of ``buf`` is alive, however derived (slices of
-    the memoryview handed out, ``np.frombuffer`` arrays of those, a
-    device transfer still reading one).  The interpreter keeps that count
-    for a bytearray and refuses to resize one under an export, so ask it:
-    a pop/append pair changes nothing when it is allowed (the allocation
-    has room for the byte it just gave up) and raises before touching
-    anything when it is not."""
-    try:
-        buf.append(buf.pop())
-    except BufferError:
-        return True
-    return False
-
-
 def _address(buf: bytearray) -> int:
     # the export dies with the temporary
     return ctypes.addressof(ctypes.c_char.from_buffer(buf))
@@ -285,10 +271,10 @@ class _FramePool:
 
     Two slots, because the consumer of frame k still holds its views
     while frame k+1 is read (the loop variable of whoever iterates the
-    frames).  A slot is reused only when :func:`_exported` says no view
-    of it is left; a consumer that keeps one keeps that buffer, and the
-    ring replaces the slot with a fresh buffer of the frame's own size
-    (``rpc.shm.frame_allocs``).  Free slots grow to the largest frame
+    frames).  A slot is reused only when ``utils.buffers.exported`` says
+    no view of it is left; a consumer that keeps one keeps that buffer,
+    and the ring replaces the slot with a fresh buffer of the frame's own
+    size (``rpc.shm.frame_allocs``).  Free slots grow to the largest frame
     seen (and a little over), at every take and at the end of a frame
     group, so that after one whole exchange nothing is allocated again
     whichever slot a frame falls on."""
@@ -309,7 +295,7 @@ class _FramePool:
         returns one of them, or None when all are held."""
         free = None
         for i, buf in enumerate(self._slots):
-            if _exported(buf):
+            if exported(buf):
                 continue
             if len(buf) < self._largest:
                 buf = self._slots[i] = self._fresh(self._largest)

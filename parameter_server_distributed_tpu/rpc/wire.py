@@ -25,7 +25,6 @@ native C++ fast path (see native/).
 
 from __future__ import annotations
 
-import ctypes
 import struct
 import sys
 from typing import Any, Callable, Iterator
@@ -33,6 +32,7 @@ from typing import Any, Callable, Iterator
 import numpy as np
 
 from ..obs import stats as obs_stats
+from ..utils import buffers
 from . import codec as _codec
 
 # Wire types
@@ -330,12 +330,8 @@ class ArrayPayload:
 # its output directly into the `bytes` object handed to gRPC (whose cython
 # layer accepts nothing else), skipping both bytearray's zero-fill sweep
 # and the final buffer->bytes copy.  Mutating the object is safe because it
-# is unreachable by any other code until encode() returns it.
-_pyapi = ctypes.pythonapi
-_pyapi.PyBytes_FromStringAndSize.restype = ctypes.py_object
-_pyapi.PyBytes_FromStringAndSize.argtypes = [ctypes.c_char_p, ctypes.c_ssize_t]
-_pyapi.PyBytes_AsString.restype = ctypes.c_void_p
-_pyapi.PyBytes_AsString.argtypes = [ctypes.py_object]
+# is unreachable by any other code until encode() returns it
+# (utils/buffers.uninit_bytes).
 
 # Bytes of encoder output that went to NEW memory.  At the sizes a
 # parameter store is chunked into, a new buffer is new address space and
@@ -348,10 +344,7 @@ _obs_fresh_bytes = obs_stats.counter("rpc.wire.fresh_bytes")
 def _alloc_uninit_bytes(size: int) -> tuple[bytes, np.ndarray]:
     """Return (bytes_of_len_size, writable uint8 view into it)."""
     _obs_fresh_bytes.add(size)
-    obj = _pyapi.PyBytes_FromStringAndSize(None, size)
-    addr = _pyapi.PyBytes_AsString(obj)
-    view = np.frombuffer((ctypes.c_ubyte * size).from_address(addr), np.uint8)
-    return obj, view
+    return buffers.uninit_bytes(size)
 
 
 class _Writer:

@@ -62,6 +62,7 @@ from ..rpc.data_plane import (PreEncodedParameterUpdate, decode_gradients,
                               encode_parameter_record_groups, split_tensors,
                               stream_chunk_bytes)
 from ..rpc.service import bind_service, make_server
+from ..utils.buffers import exported
 
 log = logging.getLogger("pst.ps")
 
@@ -90,12 +91,12 @@ class _ServeCacheEntry:
     def take(self, place: int, size: int) -> memoryview:
         """A writable view of exactly ``size`` bytes for body ``place``:
         over the buffer the retired version's body lay in when nothing
-        reads it any more (``shm_transport._exported``) and it is large
+        reads it any more (``utils.buffers.exported``) and it is large
         enough, over a new one otherwise.  Each place has one taker."""
         buffers = self.buffers
         buffers.extend([None] * (place + 1 - len(buffers)))
         buf = buffers[place]
-        if buf is None or len(buf) < size or shm_transport._exported(buf):
+        if buf is None or len(buf) < size or exported(buf):
             _obs_fresh_bytes.add(size)
             buf = buffers[place] = bytearray(size)  # zeroed: touched
         return memoryview(buf)[:size]

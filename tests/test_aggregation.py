@@ -209,9 +209,10 @@ def test_streaming_peak_gradient_buffer_is_one_model():
 class _SlowSGD(SGD):
     apply_delay_s = 0.25
 
-    def apply(self, params, grads):
+    # the rule itself: what the serial apply and a range task both call
+    def update_range(self, *args):
         time.sleep(self.apply_delay_s)
-        return super().apply(params, grads)
+        return super().update_range(*args)
 
 
 @pytest.mark.lockcheck
@@ -279,11 +280,11 @@ class _FlakySGD(SGD):
         super().__init__(lr)
         self.failures_left = 1
 
-    def apply(self, params, grads):
+    def update_range(self, *args):
         if self.failures_left:
             self.failures_left -= 1
             raise RuntimeError("injected apply failure")
-        return super().apply(params, grads)
+        return super().update_range(*args)
 
 
 @pytest.mark.lockcheck
@@ -384,6 +385,191 @@ def test_restore_during_streaming_close_wins():
     r = ps.receive_gradients(0, 1, store(w=[2.0]))
     assert r.aggregation_complete
     np.testing.assert_allclose(ps.get_parameters()["w"], [40.0])
+
+
+# ------------------------------------------ the close's kept store (PR 39)
+# The range-cut close writes each new store over the buffers of the store
+# retired TWO closes ago, and only where no view of them is left.
+
+KEPT = {"embed": (13, 7), "w1": (3, 5, 11), "w2": (3, 11, 5),
+        "bias": (3,), "scale": ()}
+_fresh_close_bytes = obs_stats.counter("ps.close.fresh_bytes")
+
+
+def _kept_core(optimizer=None):
+    core = ParameterServerCore(total_workers=1, stripes=3,
+                               optimizer=optimizer or Adam(0.01),
+                               aggregation="streaming")
+    rng = np.random.default_rng(39)
+    core.initialize_parameters(_random_grads(rng, KEPT))
+    return core, rng
+
+
+def _close(core, rng, iteration):
+    """One barrier close; returns the bytes it had to allocate."""
+    before = _fresh_close_bytes.value
+    r = core.receive_gradients(0, iteration, _random_grads(rng, KEPT))
+    assert r.aggregation_complete, r.message
+    return _fresh_close_bytes.value - before
+
+
+def _addresses(core):
+    return {name: value.ctypes.data
+            for name, value in core.get_parameters().items()}
+
+
+def test_the_close_writes_over_the_store_of_two_versions_ago():
+    """The first two closes have nothing of their own behind them and
+    allocate a store each; from the third on every close lands in the
+    buffers of the store two versions back, and allocates nothing."""
+    core, rng = _kept_core()
+    store_bytes = sum(4 * int(np.prod(shape)) for shape in KEPT.values())
+    assert [_close(core, rng, it) for it in (1, 2)] == [store_bytes] * 2
+    seen = [_addresses(core)]
+    for it in range(3, 8):
+        assert _close(core, rng, it) == 0
+        seen.append(_addresses(core))
+    assert seen[0] != seen[1]               # never over the served store
+    assert seen[0] == seen[2] == seen[4] and seen[1] == seen[3] == seen[5]
+
+
+def _hold_array(core):
+    held = core.get_parameters()["w1"]
+    return held, ["w1"], lambda: held.copy()
+
+
+def _hold_slice(core):
+    held = core.get_parameters()["w1"][1, 2:4]      # a view of a view
+    return held, ["w1"], lambda: held.copy()
+
+
+def _hold_serve_build(core):
+    """A serve-cache body build in flight: the wire tensors hold the
+    served arrays until the encode has read them."""
+    from parameter_server_distributed_tpu.rpc.data_plane import (
+        encode_parameter_records)
+
+    _, served, _, _ = core.serve_view()
+    held = to_wire(served, wire_dtype=m.WIRE_F32)
+    del served
+    return held, list(KEPT), lambda: bytes(encode_parameter_records(
+        held, lambda size: memoryview(bytearray(size))))
+
+
+@pytest.mark.parametrize("hold", [_hold_array, _hold_slice,
+                                  _hold_serve_build],
+                         ids=["array", "slice", "serve_build"])
+def test_a_held_view_keeps_its_bytes_and_the_close_allocates_in_its_place(
+        hold):
+    """Whoever holds an array of a retired store (or a slice of one, or a
+    body build still reading it) keeps that buffer: three further closes
+    change no byte of it, the close that wanted it takes a new one in
+    its place and counts it, and once the holder lets go nothing is
+    allocated again."""
+    core, rng = _kept_core()
+    for it in (1, 2, 3):
+        _close(core, rng, it)
+    held, names, read = hold(core)
+    snapshot = read()
+    allocated = []
+    for it in (4, 5, 6):
+        allocated.append(_close(core, rng, it))
+        got = read()
+        if isinstance(snapshot, bytes):
+            assert got == snapshot
+        else:
+            np.testing.assert_array_equal(got, snapshot)
+    replaced = sum(4 * int(np.prod(KEPT[name])) for name in names)
+    # close 4 writes over store 2's buffers; close 5 wants store 3's,
+    # which are held; close 6 is back on store 4's
+    assert allocated == [0, replaced, 0]
+    del held, read
+    assert [_close(core, rng, it) for it in (7, 8, 9)] == [0, 0, 0]
+
+
+class _FlakyRangeSGD(SGD):
+    """Raises inside ONE range task of the next close when armed."""
+
+    armed = False
+
+    def update_range(self, name, p, g, out, lo, hi):
+        if self.armed and name == "w2":
+            self.armed = False
+            raise RuntimeError("injected range failure")
+        return super().update_range(name, p, g, out, lo, hi)
+
+
+@pytest.mark.lockcheck
+def test_a_failed_range_task_leaves_the_barrier_retryable_and_the_store_whole(
+        numpy_only):
+    """One task of the range-cut close raises after its siblings wrote
+    their ranges into the REUSED buffers: nothing served has changed, the
+    version stands, the iteration is retryable, and the retry lands what
+    a close that never failed lands, bit for bit."""
+    flaky, calm = _FlakyRangeSGD(0.5), SGD(0.5)
+    core, rng = _kept_core(flaky)
+    twin, twin_rng = _kept_core(calm)
+    for it in (1, 2, 3):
+        _close(core, rng, it)
+        _close(twin, twin_rng, it)
+    served = core.get_parameters()
+    snapshot = {name: value.copy() for name, value in served.items()}
+    version = core.params_version
+    grads = _random_grads(rng, KEPT)
+    flaky.armed = True
+    with pytest.raises(RuntimeError, match="injected range failure"):
+        core.receive_gradients(0, 4, grads)
+    assert core.params_version == version
+    for name, value in core.get_parameters().items():
+        assert value is served[name]
+        np.testing.assert_array_equal(value, snapshot[name])
+    _, ready, received, _ = core.check_sync_status(4)   # re-fires the close
+    assert ready and received == 1
+    twin.receive_gradients(0, 4, grads)
+    for name, value in twin.get_parameters().items():
+        np.testing.assert_array_equal(core.get_parameters()[name], value)
+    for name, value in served.items():                   # still untouched
+        np.testing.assert_array_equal(value, snapshot[name])
+
+
+class _SlowRangeSGD(SGD):
+    def update_range(self, *args):
+        time.sleep(0.05)
+        return super().update_range(*args)
+
+
+@pytest.mark.lockcheck
+def test_restore_during_range_cut_close_wins():
+    """A restore that lands while the range tasks run ends with exactly
+    the restored store (the multi-tensor twin of the test above), and the
+    closes after it neither write into the restored arrays nor into the
+    buffers a reader of the dropped store still holds."""
+    core, rng = _kept_core(_SlowRangeSGD(1.0))
+    for it in (1, 2, 3):
+        _close(core, rng, it)
+    grads = _random_grads(rng, KEPT)
+    closer = threading.Thread(
+        target=lambda: core.receive_gradients(0, 4, grads))
+    closer.start()
+    time.sleep(0.02)  # the closer is inside its range tasks
+    restored = _random_grads(rng, KEPT)
+    original = {name: value.copy() for name, value in restored.items()}
+    core.restore(epoch=0, iteration=0, params=restored)
+    closer.join(timeout=5.0)
+    assert not closer.is_alive()
+    held = core.get_parameters()        # a reader of the restored store
+    want = {name: value.copy() for name, value in original.items()}
+    for name, value in original.items():
+        np.testing.assert_array_equal(held[name], value)
+    for it in (1, 2, 3):
+        step = _random_grads(rng, KEPT)
+        assert core.receive_gradients(0, it, step).aggregation_complete
+        for name in want:
+            want[name] = want[name] - step[name]
+            np.testing.assert_array_equal(held[name], original[name])
+            np.testing.assert_array_equal(restored[name], original[name])
+    for name, value in want.items():
+        np.testing.assert_array_equal(core.get_parameters()[name], value)
 
 
 # --------------------------------------------------- barrier_width TTL lock

@@ -21,12 +21,14 @@ def test_native_mean_matches_numpy(rng):
     np.testing.assert_allclose(out, np.mean(arrays, axis=0), rtol=1e-6)
 
 
-def test_native_sgd_in_place(rng):
+def test_native_sgd_out_of_place(rng):
     p = rng.standard_normal(1000).astype(np.float32)
     g = rng.standard_normal(1000).astype(np.float32)
     expect = p - 0.25 * g
-    assert native.sgd_native(p, g, 0.25)
-    np.testing.assert_allclose(p, expect, rtol=1e-6)
+    served, out = p.copy(), np.empty_like(p)
+    assert native.sgd_native(p, g, out, 0.25)
+    np.testing.assert_allclose(out, expect, rtol=1e-6)
+    np.testing.assert_array_equal(p, served)  # the old store is read only
 
 
 def test_native_mean_sgd_fused(rng):
@@ -41,7 +43,12 @@ def test_native_rejects_unsuitable_inputs(rng):
     # float64 param -> fallback requested
     p = rng.standard_normal(10)  # float64
     g = rng.standard_normal(10).astype(np.float32)
-    assert not native.sgd_native(p, g, 0.1)
+    assert not native.sgd_native(p, g, np.empty(10, np.float32), 0.1)
+    # the kernels declare their output __restrict__: writing over an
+    # input is refused, not undefined
+    p32 = p.astype(np.float32)
+    assert not native.sgd_native(p32, g, p32, 0.1)
+    assert not native.sgd_native(p32, g, p32[::2], 0.1)
     assert native.mean_over_workers_native([]) is None
 
 
@@ -51,9 +58,10 @@ def test_native_momentum_matches_numpy(rng):
     v = rng.standard_normal(513).astype(np.float32)
     expect_v = 0.9 * v + g
     expect_p = p - 0.05 * expect_v
-    assert native.momentum_native(p, g, v, 0.05, 0.9)
+    out = np.empty_like(p)
+    assert native.momentum_native(p, g, v, out, 0.05, 0.9)
     np.testing.assert_allclose(v, expect_v, rtol=1e-6)
-    np.testing.assert_allclose(p, expect_p, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(out, expect_p, rtol=1e-5, atol=1e-6)
 
 
 def test_native_adam_matches_numpy(rng):
@@ -65,10 +73,11 @@ def test_native_adam_matches_numpy(rng):
     em = b1 * m + (1 - b1) * g
     ev = b2 * v + (1 - b2) * g * g
     ep = p - lr * (em / (1 - b1**step)) / (np.sqrt(ev / (1 - b2**step)) + eps)
-    assert native.adam_native(p, g, m, v, lr, b1, b2, eps, step)
+    out = np.empty_like(p)
+    assert native.adam_native(p, g, m, v, out, lr, b1, b2, eps, step)
     np.testing.assert_allclose(m, em, rtol=1e-5, atol=1e-7)
     np.testing.assert_allclose(v, ev, rtol=1e-5, atol=1e-7)
-    np.testing.assert_allclose(p, ep, rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(out, ep, rtol=1e-4, atol=1e-6)
 
 
 @pytest.mark.parametrize("name", ["sgd", "momentum", "adam"])
@@ -151,10 +160,11 @@ def test_native_adamw_matches_numpy(rng):
     ev = b2 * v + (1 - b2) * g * g
     adam_term = (em / (1 - b1**step)) / (np.sqrt(ev / (1 - b2**step)) + eps)
     ep = p - lr * (adam_term + wd * p)
-    assert native.adamw_native(p, g, m, v, lr, b1, b2, eps, step, wd)
+    out = np.empty_like(p)
+    assert native.adam_native(p, g, m, v, out, lr, b1, b2, eps, step, wd)
     np.testing.assert_allclose(m, em, rtol=1e-5, atol=1e-7)
     np.testing.assert_allclose(v, ev, rtol=1e-5, atol=1e-7)
-    np.testing.assert_allclose(p, ep, rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(out, ep, rtol=1e-4, atol=1e-6)
 
 
 def test_host_adamw_native_and_numpy_paths_agree(rng):
@@ -199,3 +209,32 @@ def test_optimizer_state_snapshot_isolated_from_in_place_applies(rng):
     opt2.load_state_dict(snap)
     opt2.apply(params, grads)
     np.testing.assert_array_equal(snap["m"]["w"], frozen_m)
+
+
+def test_a_library_without_an_entry_point_means_numpy_for_that_rule(
+        monkeypatch, rng):
+    """An old build (a read-only install ``_build`` cannot replace) lacks
+    the out-of-place sweeps: the rule whose entry point is missing runs
+    its numpy ufuncs, the others keep their kernels, nothing raises."""
+    from parameter_server_distributed_tpu.core.optimizer import Adam
+
+    real = native.lib()
+
+    class OldBuild:
+        psdt_sgd_out = real.psdt_sgd_out        # has SGD's, lacks Adam's
+
+    params = {"w": rng.standard_normal((17, 9)).astype(np.float32)}
+    grads = {"w": rng.standard_normal((17, 9)).astype(np.float32)}
+    native.set_enabled(False)
+    try:
+        want = Adam(0.01).apply(params, grads)
+    finally:
+        native.set_enabled(True)
+    monkeypatch.setattr(native, "lib", lambda: OldBuild())
+    p, g = params["w"], grads["w"]
+    out = np.empty_like(p)
+    assert native.sgd_native(p, g, out, 0.25)
+    assert not native.adam_native(p, g, np.zeros_like(p), np.zeros_like(p),
+                                  out, 0.01, 0.9, 0.999, 1e-8, 1)
+    np.testing.assert_array_equal(Adam(0.01).apply(params, grads)["w"],
+                                  want["w"])

@@ -22,6 +22,7 @@ from parameter_server_distributed_tpu.rpc import shm_transport as st
 from parameter_server_distributed_tpu.rpc.data_plane import (
     PreEncodedParameterUpdate, PSClient, encode_parameter_records)
 from parameter_server_distributed_tpu.server.ps_service import ParameterServer
+from parameter_server_distributed_tpu.utils.buffers import exported
 
 
 def _ring_pair(capacity=1 << 20, doorbell=True):
@@ -274,7 +275,7 @@ def test_ring_kept_view_keeps_its_buffer():
         frame = cons.read_frame(time.monotonic() + 30)
         kept = np.frombuffer(frame, np.uint8)[10:20]    # a view of a view
         assert not kept.flags.writeable
-        held = {id(b) for b in cons._pool._slots if st._exported(b)}
+        held = {id(b) for b in cons._pool._slots if exported(b)}
         assert len(held) == 1
         del frame
         for want in payloads[4:]:
@@ -327,7 +328,7 @@ def test_frame_view_is_read_only_and_to_array_owns_its_data():
         assert not np.shares_memory(arr, np.asarray(wire))
         buffers = list(cons._pool._slots)
         del frame, decoded, wire
-        assert not any(st._exported(b) for b in buffers)
+        assert not any(exported(b) for b in buffers)
         for _ in range(2):          # both buffers refilled
             assert cons.read_frame(time.monotonic() + 10) is not None
         np.testing.assert_array_equal(arr, values.reshape(64, 64))

@@ -7,6 +7,11 @@ of striped optimizer state, lock-checked concurrent push/close/restore
 races, the in-place optimizer peak-allocation regression, the
 error-feedback gate + convergence property, the striped serve-cache
 encode's byte identity, and the stripe observability metrics.
+
+The barrier close's sweep is cut by ELEMENT RANGES (PR 39): range-cut ==
+serial bit for bit for every host optimizer on both arithmetic paths over
+a miniature of the benchmark's store, AdamW's mask under a cut, and the
+cut itself.
 """
 
 from __future__ import annotations
@@ -135,6 +140,178 @@ def test_striped_matches_serial_bit_for_bit(numpy_only, n_stripes,
         np.testing.assert_array_equal(serial[name], striped[name])
 
 
+# The benchmark's store (a scanned GPT-2 medium) in miniature: 660
+# elements in 11 names, two of them a quarter of it each, in the model's
+# order.  No equal cut of it falls on a tensor's or a row's boundary.
+MINI = {"embed/tok": (13, 7), "embed/pos": (3,),
+        "blocks/attn/wq": (3, 3, 4), "blocks/attn/wk": (3, 3, 4),
+        "blocks/attn/wv": (3, 3, 4), "blocks/attn/wo": (3, 4, 3),
+        "blocks/mlp/w1": (3, 5, 11), "blocks/mlp/w2": (3, 11, 5),
+        "ln_f/scale": (), "lm_head/w": (7, 13)}
+MINI_SIZES = [int(np.prod(shape)) for shape in MINI.values()]
+
+
+@pytest.fixture(params=["native", "numpy"])
+def arithmetic(request):
+    """Both paths of every host rule: the kernels of the native library
+    and the numpy ufuncs on the same slices."""
+    if request.param == "native" and native.lib() is None:
+        pytest.skip("native lib unavailable (no g++)")
+    native.set_enabled(request.param == "native")
+    yield request.param
+    native.set_enabled(True)
+
+
+@pytest.mark.parametrize("parts", [2, 3, 7, 13])
+def test_partition_ranges_cuts_the_store_not_the_names(parts):
+    sizes = MINI_SIZES
+    ranges = st.partition_ranges(sizes, parts)
+    assert len(ranges) == parts
+    covered = [[] for _ in sizes]
+    for pieces in ranges:
+        for i, lo, hi in pieces:
+            assert 0 <= lo < hi <= sizes[i]
+            covered[i].append((lo, hi))
+    for size, spans in zip(sizes, covered):     # every element once
+        assert [lo for lo, _ in spans] == [0] + [hi for _, hi in spans[:-1]]
+        assert spans[-1][1] == size
+    lengths = [sum(hi - lo for _, lo, hi in ps) for ps in ranges]
+    assert max(lengths) - min(lengths) <= 1     # nearly equal
+    # where the cut by name cannot pass total / largest (4.0 here)
+    assert sum(sizes) / max(lengths) >= 0.99 * parts
+    if parts > 2:
+        # a large tensor is split, small ones ride together, and a cut
+        # falls inside a row
+        assert any(len({i for i, _, _ in ps}) > 1 for ps in ranges)
+        shapes = list(MINI.values())
+        assert any(lo % shapes[i][-1] for ps in ranges for i, lo, _ in ps
+                   if len(shapes[i]) >= 2)
+
+
+def test_partition_ranges_edges():
+    assert st.partition_ranges([], 4) == []
+    assert st.partition_ranges([0, 0], 4) == []
+    assert st.partition_ranges([5], 8) == [[(0, i, i + 1)] for i in range(5)]
+    assert st.partition_ranges([4, 0, 2], 1) == [[(0, 0, 4), (2, 0, 2)]]
+
+
+every_host_rule = pytest.mark.parametrize("make_opt", [
+    lambda: SGD(0.5), lambda: Momentum(0.1, momentum=0.9),
+    lambda: Adam(0.01), lambda: AdamW(0.01, weight_decay=0.1),
+    lambda: Lion(0.01)], ids=["sgd", "momentum", "adam", "adamw", "lion"])
+
+
+@every_host_rule
+@pytest.mark.parametrize("tasks", [2, 3, 7])
+@pytest.mark.parametrize("contributors", [1, 3])
+def test_range_cut_close_matches_serial_bit_for_bit(arithmetic, make_opt,
+                                                    tasks, contributors):
+    """The close cut by element ranges lands the parameters, the slots and
+    the state_dict of the serial close (PSDT_STRIPES=1) bit for bit after
+    three closes, whatever the task count, on either arithmetic path, with
+    one contributor (no scale pass) and with three (a real multiply)."""
+    rng = np.random.default_rng(39)
+    init = _grads(rng, MINI)
+    cores = {s: ParameterServerCore(total_workers=contributors,
+                                    optimizer=make_opt(), stripes=s)
+             for s in (1, tasks)}
+    for core in cores.values():
+        core.initialize_parameters(init)
+    for it in range(1, 4):
+        pushes = [_grads(rng, MINI) for _ in range(contributors)]
+        for core in cores.values():
+            for wid, grads in enumerate(pushes):
+                r = core.receive_gradients(wid, it, grads)
+            assert r.aggregation_complete, r.message
+    serial, cut = cores[1], cores[tasks]
+    for name, shape in MINI.items():
+        got = cut.get_parameters()[name]
+        assert got.shape == shape and got.dtype == np.float32
+        np.testing.assert_array_equal(serial.get_parameters()[name], got)
+    want, got = serial.optimizer_state(), cut.optimizer_state()
+    assert want.keys() == got.keys()
+    for key, value in want.items():
+        if isinstance(value, dict):
+            assert value.keys() == got[key].keys() and (
+                not value or value.keys() == MINI.keys())
+            for name in value:
+                np.testing.assert_array_equal(value[name], got[key][name])
+        else:
+            assert value == got[key]
+
+
+@every_host_rule
+def test_a_store_of_one_tensor_is_cut_as_wide_as_any(arithmetic, make_opt):
+    """The cut reads sizes only: ONE tensor runs as S range tasks (a cut
+    by name cannot split it), two contributors' scale included, and lands
+    the serial close's bits.  A -0.0 in the first gradient is the
+    witness that a new velocity is seeded with the gradient itself."""
+    rng = np.random.default_rng(7)
+    init = {"w": rng.standard_normal((9, 11)).astype(np.float32)}
+    cores = {s: ParameterServerCore(total_workers=2, optimizer=make_opt(),
+                                    stripes=s) for s in (1, 7)}
+    for core in cores.values():
+        core.initialize_parameters(init)
+    tasks_seen = obs_stats.histogram("ps.apply.stripe_ms").count
+    for it in range(1, 3):
+        pushes = [_grads(rng, {"w": (9, 11)}) for _ in range(2)]
+        for grads in pushes:
+            grads["w"][0, :2] = (-0.0, 0.0)
+        for core in cores.values():
+            for wid, grads in enumerate(pushes):
+                r = core.receive_gradients(wid, it, grads)
+            assert r.aggregation_complete, r.message
+    assert obs_stats.histogram("ps.apply.stripe_ms").count == tasks_seen + 14
+    np.testing.assert_array_equal(cores[1].get_parameters()["w"],
+                                  cores[7].get_parameters()["w"])
+    want, got = cores[1].optimizer_state(), cores[7].optimizer_state()
+    for key, value in want.items():
+        if isinstance(value, dict):
+            # (array_equal alone takes -0.0 for 0.0)
+            np.testing.assert_array_equal(value["w"], got[key]["w"])
+            np.testing.assert_array_equal(np.signbit(value["w"]),
+                                          np.signbit(got[key]["w"]))
+        else:
+            assert value == got[key]
+
+
+def test_momentum_seeds_a_new_velocity_with_the_gradient(arithmetic):
+    """v starts as -0.0, the additive identity, so the first step's
+    mu * v + g is g bit for bit on both arithmetic paths: a -0.0 keeps its
+    sign (a seed of zeros gives +0.0), and the slot is an owned copy."""
+    opt = Momentum(0.5, momentum=0.9)
+    g = np.array([-0.0, 0.0, 1.5, -2.0, np.inf], np.float32)
+    p = np.arange(5, dtype=np.float32)
+    out = opt.apply({"w": p}, {"w": g})
+    v = opt.velocity["w"]
+    assert v is not g and not np.shares_memory(v, g)
+    np.testing.assert_array_equal(v, g)
+    np.testing.assert_array_equal(np.signbit(v), np.signbit(g))
+    np.testing.assert_array_equal(out["w"][:4],
+                                  p[:4] - np.float32(0.5) * g[:4])
+
+
+def test_adamw_decays_a_cut_matrix_and_never_a_vector(arithmetic):
+    """The matrices-only mask reads the TENSOR's rank: a range of a 2-D
+    tensor is a 1-D slice and still decays, a 1-D tensor cut into as
+    many ranges never does."""
+    lr, wd = 0.5, 0.25
+    core = ParameterServerCore(
+        total_workers=1, stripes=7,
+        optimizer=AdamW(lr, weight_decay=wd))
+    init = {"matrix": np.arange(1, 181, dtype=np.float32).reshape(20, 9),
+            "vector": np.arange(1, 151, dtype=np.float32)}
+    core.initialize_parameters(init)
+    zero = {name: np.zeros_like(value) for name, value in init.items()}
+    assert core.receive_gradients(0, 1, zero).aggregation_complete
+    after = core.get_parameters()
+    # a zero gradient leaves Adam's term at 0: what moved is the decay
+    np.testing.assert_array_equal(after["vector"], init["vector"])
+    np.testing.assert_array_equal(
+        after["matrix"],
+        init["matrix"] - np.float32(lr) * (np.float32(wd) * init["matrix"]))
+
+
 def test_striped_chunked_fold_equals_whole_push(numpy_only):
     """A chunk-streamed push through begin_push folds stripe-parallel and
     must land exactly what one whole-store push lands."""
@@ -233,7 +410,7 @@ def test_close_drains_inflight_striped_folds(numpy_only):
 
 
 class _GatedSGD(SGD):
-    """SGD whose striped shards park on an event — pins the striped
+    """SGD whose range tasks park on an event — pins the striped
     apply's compute window open for race tests."""
 
     def __init__(self, gate: threading.Event, entered: threading.Event):
@@ -241,10 +418,10 @@ class _GatedSGD(SGD):
         self._gate = gate
         self._entered = entered
 
-    def apply_shard(self, params, grads):
+    def update_range(self, *args):
         self._entered.set()
         assert self._gate.wait(10.0), "test gate never released"
-        return super().apply_shard(params, grads)
+        return super().update_range(*args)
 
 
 def test_initialize_during_striped_apply_wins(numpy_only):
@@ -277,17 +454,19 @@ def test_initialize_during_striped_apply_wins(numpy_only):
 
 # ------------------------------------------------------------- checkpoint
 
+@pytest.mark.parametrize("shapes", [SHAPES, MINI], ids=["small", "mini"])
 def test_checkpoint_roundtrip_of_striped_optimizer_state(tmp_path,
-                                                         numpy_only):
+                                                         numpy_only, shapes):
     """Optimizer state written by stripe-parallel applies must survive a
-    CheckpointManager save/load into ANY stripe count (the slices are
-    keyed by tensor name, not by stripe id) and continue bit-identically."""
+    CheckpointManager save/load into ANY stripe count (the slots are
+    keyed by tensor name and whole, whatever ranges wrote them) and
+    continue bit-identically."""
     from parameter_server_distributed_tpu.checkpoint.manager import (
         CheckpointManager)
 
     rng = np.random.default_rng(11)
-    init = _grads(rng, SHAPES)
-    steps = [_grads(rng, SHAPES) for _ in range(4)]
+    init = _grads(rng, shapes)
+    steps = [_grads(rng, shapes) for _ in range(4)]
 
     core = ParameterServerCore(total_workers=1, optimizer=Adam(0.05),
                                stripes=3)
@@ -299,7 +478,7 @@ def test_checkpoint_roundtrip_of_striped_optimizer_state(tmp_path,
     path = mgr.save(epoch=1)
 
     finals = {}
-    for restore_stripes in (1, 2, 3):
+    for restore_stripes in (1, 2, 3, 7):
         restored = ParameterServerCore(total_workers=1,
                                        optimizer=Adam(0.05),
                                        stripes=restore_stripes)
@@ -312,7 +491,7 @@ def test_checkpoint_roundtrip_of_striped_optimizer_state(tmp_path,
         core.receive_gradients(0, it, grads)
     live = core.get_parameters()
     for s, params in finals.items():
-        for name in SHAPES:
+        for name in shapes:
             np.testing.assert_array_equal(live[name], params[name])
 
 

@@ -8,7 +8,9 @@ expert and the experts run as ONE grouped matmul per weight
 nothing of size tokens x experts x capacity is ever built and prefill,
 extension and decode share the path.  Gates are the softmax over the
 selected logits; the experts take the model's ``mlp_act`` form (gated:
-``w2(act(w1 x) * (w3 x))``).
+``w2(act(w1 x) * (w3 x))``).  The weights may hold a SHARE of the router's
+experts (``held``): the layer then computes its own experts' part of the
+sum and no more.
 
 :class:`MoELayer` is the capacity-dropping Switch/top-k layer of the
 home-made presets (``moe_lm``, ``switch_lm``, ``moe_350m``;
@@ -212,6 +214,7 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
                      w3: Array | None, *, top_k: int, act: str = "gelu",
                      score: str = "softmax", bias: Array | None = None,
                      scale: float = 1.0, chosen: list | None = None,
+                     held: tuple[int, int] | None = None,
                      ) -> tuple[Array, Array]:
     """Dropless top-k experts over a flat batch of tokens.
 
@@ -226,6 +229,18 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
     The N * top_k assignments are sorted by expert (stable, so ties keep
     token order) and each weight runs once as a grouped matmul over the
     sorted rows; the results are un-sorted by gather and summed per token.
+
+    ``held`` = (first, count): the weights are [count, ...], the experts
+    first .. first + count - 1 of the router's E (a chip's share of an
+    expert-parallel layer).  Routing, the bias and the gates are over all
+    E as ever; only the assignments to held experts are sorted into
+    groups and computed, and ``out`` is THAT part of the sum (what the
+    other ranks' experts would add is left out, and nothing stands in for
+    it).  The grouped matmul gets N * min(top_k, count) static rows, the
+    most a token's choices can send here, so nothing is ever dropped; the
+    rows past the held assignments are zeros and belong to no group.
+    ``loads`` is then [count + 1]: the held experts' assignments, and last
+    the assignments routed to experts held elsewhere.
     """
     n, d = x.shape
     experts = w1.shape[0]
@@ -235,11 +250,27 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
         if chosen is not None:
             chosen.append(top_idx)
         flat = top_idx.reshape(n * top_k)
-        order = jnp.argsort(flat, stable=True)                 # [A]
-        loads = jnp.zeros((experts,), jnp.int32).at[flat].add(1)
-        rows = x[order // top_k]                               # [A, D]
+        if held is None:
+            order = jnp.argsort(flat, stable=True)             # [A]
+            loads = groups = jnp.zeros((experts,), jnp.int32).at[flat].add(1)
+            rows = x[order // top_k]                           # [A, D]
+        else:
+            first, count = held
+            if experts != count:
+                raise ValueError(f"held={held}: the weights hold {experts} "
+                                 "experts")
+            # an assignment to an expert held elsewhere sorts behind every
+            # held one, into no group
+            local = flat - first
+            local = jnp.where((local >= 0) & (local < count), local, count)
+            order = jnp.argsort(local, stable=True)            # [A]
+            loads = jnp.zeros((count + 1,), jnp.int32).at[local].add(1)
+            groups = loads[:count]
+            bound = n * min(top_k, count)
+            mine = (jnp.arange(bound) < jnp.sum(groups))[:, None]
+            rows = jnp.where(mine, x[order[:bound] // top_k], 0)
     with jax.named_scope("experts"):
-        dot = partial(jax.lax.ragged_dot, group_sizes=loads,
+        dot = partial(jax.lax.ragged_dot, group_sizes=groups,
                       preferred_element_type=jnp.float32)
         hidden = dot(rows, w1).astype(x.dtype)
         if act == "gelu":
@@ -249,6 +280,10 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
             hidden = gate(hidden) * dot(rows, w3).astype(x.dtype)
         out = dot(hidden, w2)                                  # [A, D] f32
     with jax.named_scope("router"):
+        if held is not None:
+            # (what a grouped matmul leaves in a row of no group is its own)
+            out = jnp.pad(jnp.where(mine, out, 0.0),
+                          ((0, n * top_k - bound), (0, 0)))
         # back to (token, choice) order, weighted and summed per token
         out = out[jnp.argsort(order)].reshape(n, top_k, d)
         return jnp.sum(out * gates[..., None], axis=1), loads

@@ -650,7 +650,8 @@ class DecodeServer:
         # _count_routing), and the bytes held by kind of layer
         self._moe_assignments = 0
         self._obs_moe = {name: obs_stats.counter(f"serve.moe.{name}")
-                         for name in ("assignments", "layer_rounds",
+                         for name in ("assignments", "assignments_routed",
+                                      "layer_rounds",
                                       "experts_touched", "expert_places",
                                       "load_max_over_mean",
                                       "admit_experts_touched")}
@@ -1540,22 +1541,31 @@ class DecodeServer:
         """One forward's tokens per expert ([L * E], every experts layer
         in order) into the counters a per-layer metric divides.  Of every
         forward: assignments routed (pad positions' and idle lanes' too:
-        the device computes them).  Of an admission's: the distinct
-        experts it touched.  Of a decode round's: (layer, round) pairs
-        seen, distinct experts touched over them, expert places over them,
-        and the largest expert's load over the mean, summed."""
+        the device computes them), and those of them the grouped matmul
+        computed.  Of an admission's: the distinct experts it touched.  Of
+        a decode round's: (layer, round) pairs seen, distinct experts
+        touched over them, expert places over them, and the largest
+        expert's load over the mean, summed.  Where the model holds a
+        share of the experts (``moe_held``) a layer's last entry is what
+        went to experts held elsewhere: it counts as routed, and every
+        other count is over the HELD experts and the rows computed."""
         loads = loads.reshape(self._moe_layers, -1)
-        routed, touched = int(loads.sum()), int((loads > 0).sum())
-        self._moe_assignments += routed
-        self._obs_moe["assignments"].add(routed)
+        self._obs_moe["assignments_routed"].add(int(loads.sum()))
+        if self.model.config.moe_held:
+            loads = loads[:, :-1]
+        computed, touched = int(loads.sum()), int((loads > 0).sum())
+        self._moe_assignments += computed
+        self._obs_moe["assignments"].add(computed)
         if admission:
             self._obs_moe["admit_experts_touched"].add(touched)
             return
         self._obs_moe["layer_rounds"].add(loads.shape[0])
         self._obs_moe["experts_touched"].add(touched)
         self._obs_moe["expert_places"].add(loads.size)
-        self._obs_moe["load_max_over_mean"].add(
-            float((loads.max(axis=1) / loads.mean(axis=1)).sum()))
+        # (a share of the experts may see no token in a round)
+        mean = loads.mean(axis=1)
+        self._obs_moe["load_max_over_mean"].add(float(
+            (loads.max(axis=1) / np.where(mean > 0, mean, 1.0)).sum()))
 
     def _finishes(self, entry: _Slot, token: int) -> bool:
         return (len(entry.tokens) >= entry.max_new

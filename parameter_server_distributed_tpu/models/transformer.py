@@ -181,6 +181,17 @@ class TransformerConfig:
     moe_score: str = "softmax"
     moe_expert_bias: bool = False
     moe_route_scale: float = 1.0
+    # The experts an ``experts`` layer's weights HOLD, ``(first, count)``:
+    # this chip's share of an expert-parallel stage.  The router keeps its
+    # ``moe_experts`` outputs, its bias and its top-k, and gates are normed
+    # over the chosen (held or not); ``moe/w1|w2|w3`` are [count, ...] and
+    # the layer's result is the held experts' part of the sum (nothing
+    # stands in for the other ranks or their exchange).  Empty: all
+    moe_held: tuple = ()
+    # shared experts beside the routed ones of an ``experts`` layer: ONE
+    # MLP of ``mlp_act``'s form and this many experts' width on every
+    # token (``moe/shared/w1|w2|w3``), added ungated to the routed part
+    moe_shared_experts: int = 0
     # taps of a ``conv`` layer's kernel
     conv_kernel: int = 3
     # Scan over layers: store block weights stacked with a leading [L]
@@ -201,6 +212,11 @@ class TransformerConfig:
     norm: str = "rms"             # rms | layernorm (mean-centering + bias)
     bias: bool = False            # biases on attn/mlp projections
     norm_eps: float = 1e-6
+    # where a branch's norm stands.  pre: on the branch's INPUT
+    # (x + f(norm(x))).  post: on its OUTPUT before the residual add
+    # (x + norm(f(x)): the branch, and an ``experts`` layer's router, read
+    # the stream itself); ``ln1`` / ``ln2`` are the same gains either way
+    norm_placement: str = "pre"
     # gelu: w2(gelu(w1 x)); swiglu: w2(silu(w1 x) * (w3 x)) — the
     # LLaMA-family gated MLP (w1 = gate_proj, w3 = up_proj); reglu: the
     # same gate with relu.  An ``experts`` layer's experts take this form
@@ -222,6 +238,9 @@ class TransformerConfig:
         if self.norm not in ("rms", "layernorm"):
             raise ValueError(
                 f"norm must be 'rms' or 'layernorm', got {self.norm!r}")
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(f"norm_placement must be 'pre' or 'post', got "
+                             f"{self.norm_placement!r}")
         if self.mlp_act not in ("gelu", "swiglu", "reglu"):
             raise ValueError(f"mlp_act must be 'gelu', 'swiglu' or "
                              f"'reglu', got {self.mlp_act!r}")
@@ -252,6 +271,20 @@ class TransformerConfig:
             raise ValueError("the stored bias corrects a sigmoid score's "
                              "selection: moe_expert_bias needs "
                              "moe_score='sigmoid'")
+        object.__setattr__(self, "moe_held", tuple(self.moe_held))
+        if self.moe_held:
+            first, count = self.moe_held
+            if not (0 <= first and 1 <= count
+                    and first + count <= self.moe_experts):
+                raise ValueError(
+                    f"moe_held={self.moe_held} is (first, count) of the "
+                    f"router's {self.moe_experts} experts")
+        if ((self.moe_held or self.moe_shared_experts
+             or self.norm_placement == "post")
+                and any(spec.ffn == "moe" for spec in self.specs)):
+            raise ValueError("a share of the experts, a shared expert and "
+                             "a norm on a branch's output belong to "
+                             "``experts`` layers, not ``moe``")
         mixers = {spec.mixer for spec in self.specs}
         if "sparse" in mixers and self.sparse is None:
             raise ValueError("a sparse layer needs config.sparse")
@@ -296,6 +329,12 @@ class TransformerConfig:
     @property
     def expert_width(self) -> int:
         return self.d_expert or self.d_ff
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """(first, count) of the experts an ``experts`` layer's weights
+        hold: ``moe_held``, or all of the router's."""
+        return self.moe_held or (0, self.moe_experts)
 
     def layer_spec(self, i: int) -> LayerSpec:
         if i < len(self.prologue):
@@ -709,14 +748,26 @@ class Transformer:
             if c.bias:
                 block.update({"mlp/b1": (c.d_ff,), "mlp/b2": (c.d_model,)})
             return block
-        width = c.expert_width if spec.ffn == "experts" else c.d_ff
+        if spec.ffn == "moe":
+            block.update({"moe/router/w": (c.d_model, c.moe_experts),
+                          "moe/w1": (c.moe_experts, c.d_model, c.d_ff),
+                          "moe/w2": (c.moe_experts, c.d_ff, c.d_model)})
+            return block
+        # the router keeps its width; the weights are the held experts'
+        width, held = c.expert_width, c.held_experts[1]
         block.update({"moe/router/w": (c.d_model, c.moe_experts),
-                      "moe/w1": (c.moe_experts, c.d_model, width),
-                      "moe/w2": (c.moe_experts, width, c.d_model)})
-        if spec.ffn == "experts" and c.gated_mlp:
-            block["moe/w3"] = (c.moe_experts, c.d_model, width)
-        if spec.ffn == "experts" and c.moe_expert_bias:
+                      "moe/w1": (held, c.d_model, width),
+                      "moe/w2": (held, width, c.d_model)})
+        if c.gated_mlp:
+            block["moe/w3"] = (held, c.d_model, width)
+        if c.moe_expert_bias:
             block["moe/router/bias"] = (c.moe_experts,)
+        if c.moe_shared_experts:
+            shared = c.moe_shared_experts * width
+            block.update({"moe/shared/w1": (c.d_model, shared),
+                          "moe/shared/w2": (shared, c.d_model)})
+            if c.gated_mlp:
+                block["moe/shared/w3"] = (c.d_model, shared)
         return block
 
     def _stacked_suffixes(self) -> list[str]:
@@ -747,7 +798,9 @@ class Transformer:
         (the standard sparse-MoE MFU numerator; an upper bound when
         expert-capacity dropping skips some tokens' experts — callers
         reporting MoE MFU must say "active-expert accounting", as
-        perfbench/families/ does).
+        perfbench/families/ does).  Where the weights hold a share of the
+        experts (``moe_held``) a token meets ``top_k * held / E`` of the
+        held ones on average: ACTIVE and HELD.
 
         ``remat_credited=True`` counts the extra forward the hardware
         actually executes under ``config.remat``: hardware-utilization
@@ -758,9 +811,10 @@ class Transformer:
         c = self.config
         seq = c.max_seq
         n_params = self.num_params()
-        inactive = max(0, c.moe_experts - c.moe_top_k)
         for i in range(c.n_layers):
             shapes = self.block_shapes(c.layer_spec(i))
+            held = shapes.get("moe/w1", (0,))[0]
+            inactive = max(0, held - c.moe_top_k * held / c.moe_experts)
             n_params -= inactive * sum(
                 math.prod(shape[1:]) for suffix, shape in shapes.items()
                 if suffix in ("moe/w1", "moe/w2", "moe/w3"))
@@ -800,7 +854,7 @@ class Transformer:
                 scale = 1.0 / math.sqrt(fan_in)
                 # residual-output projections get depth-scaled init
                 if name.endswith(("attn/wo", "conv/out_proj", "mlp/w2",
-                                  "moe/w2")):
+                                  "moe/w2", "moe/shared/w2")):
                     scale /= math.sqrt(2.0 * c.n_layers)
                 params[name] = jax.random.normal(sub, shape, c.dtype) * scale
         return params
@@ -836,11 +890,29 @@ class Transformer:
                               params[f"{key}/bias"], c.norm_eps)
         return rms_norm(x, params[f"{key}/scale"], c.norm_eps)
 
+    def _branch_input(self, params: Mapping[str, Array], key: str,
+                      h: Array) -> Array:
+        """What a residual branch reads of the stream ``h``: its norm
+        (``key`` as :meth:`_norm` takes it), or ``h`` itself where the
+        config's norm stands on the branch's output."""
+        if self.config.norm_placement == "post":
+            return h
+        return self._norm(params, key, h)
+
+    def _residual(self, params: Mapping[str, Array], key: str, h: Array,
+                  out: Array) -> Array:
+        """``h`` + a branch's output ``out`` (float32) at the config's
+        ``residual_scale``, normed first where the norm stands there."""
+        if self.config.norm_placement == "post":
+            out = self._norm(params, key, out)
+        return h + self._branch(out).astype(self.config.dtype)
+
     @scoped("attn_qkv")
     def qkv(self, params: Mapping[str, Array], prefix: str, h: Array,
             positions: Array, spec: LayerSpec | None = None,
             ) -> tuple[Array, Array, Array]:
-        """ln1 -> q/k/v projections (+ biases) -> head split -> rope (or
+        """ln1 (where the norm stands on a branch's input) -> q/k/v
+        projections (+ biases) -> head split -> rope (or
         pass-through under learned positions and on a layer whose
         ``spec`` has no rotary).  h: [B, S, d].
         K/V come back with ``kv_heads`` heads (UNexpanded under GQA — the
@@ -848,7 +920,7 @@ class Transformer:
         :func:`repeat_kv` before a plain attention kernel."""
         c = self.config
         batch, seq = h.shape[:2]
-        x = self._norm(params, f"{prefix}/ln1", h)
+        x = self._branch_input(params, f"{prefix}/ln1", h)
         # wdot: contracts against int8 QTensor weights too (serving quant)
         dot = partial(wdot, preferred_element_type=jnp.float32)
         q = dot(x, params[f"{prefix}/attn/wq"])
@@ -890,7 +962,7 @@ class Transformer:
                             c.norm_eps)
         attn = attn.reshape(batch, seq, c.attn_dim)
         if spec is not None and spec.gate:
-            x = self._norm(params, f"{prefix}/ln1", h)
+            x = self._branch_input(params, f"{prefix}/ln1", h)
             gate = wdot(x, params[f"{prefix}/attn/wg"],
                         preferred_element_type=jnp.float32)
             attn = attn * jax.nn.sigmoid(gate).astype(c.dtype)
@@ -898,7 +970,7 @@ class Transformer:
                    preferred_element_type=jnp.float32)
         if c.bias:
             out = out + params[f"{prefix}/attn/bo"].astype(jnp.float32)
-        return h + self._branch(out).astype(c.dtype)
+        return self._residual(params, f"{prefix}/ln1", h, out)
 
     def _branch(self, out: Array) -> Array:
         """A residual branch's output at the config's ``residual_scale``."""
@@ -920,7 +992,7 @@ class Transformer:
 
         c = self.config
         with jax.named_scope("attn"), jax.named_scope("conv"):
-            x = self._norm(params, f"{prefix}/ln1", h)
+            x = self._branch_input(params, f"{prefix}/ln1", h)
             bcx = wdot(x, params[f"{prefix}/conv/in_proj"],
                        preferred_element_type=jnp.float32).astype(c.dtype)
             mixed, state = gated_short_conv(
@@ -928,30 +1000,38 @@ class Transformer:
                 state, counts)
             out = wdot(mixed, params[f"{prefix}/conv/out_proj"],
                        preferred_element_type=jnp.float32)
-            return h + self._branch(out).astype(c.dtype), state
+            return self._residual(params, f"{prefix}/ln1", h, out), state
 
-    @scoped("mlp")
-    def mlp_residual(self, params: Mapping[str, Array], prefix: str,
-                     h: Array) -> Array:
-        """h + w2(gelu(w1(ln2(h)))) (+ biases), or the gated form
-        h + w2(act(w1 x) * (w3 x)) under ``mlp_act="swiglu"`` (silu) and
-        ``"reglu"`` (relu)."""
+    def _mlp(self, params: Mapping[str, Array], key: str, x: Array,
+             bias: bool = False) -> Array:
+        """w2(gelu(w1 x)) (+ biases under ``bias``), or the gated form
+        w2(act(w1 x) * (w3 x)) under ``mlp_act="swiglu"`` (silu) and
+        ``"reglu"`` (relu), in float32; the weights are ``key``'s."""
         c = self.config
         dot = partial(wdot, preferred_element_type=jnp.float32)
-        x = self._norm(params, f"{prefix}/ln2", h)
-        ff = dot(x, params[f"{prefix}/mlp/w1"])
-        if c.bias:
-            ff = ff + params[f"{prefix}/mlp/b1"].astype(jnp.float32)
+        ff = dot(x, params[f"{key}/w1"])
+        if bias:
+            ff = ff + params[f"{key}/b1"].astype(jnp.float32)
         if c.gated_mlp:
-            up = dot(x, params[f"{prefix}/mlp/w3"]).astype(c.dtype)
+            up = dot(x, params[f"{key}/w3"]).astype(c.dtype)
             gate = jax.nn.silu if c.mlp_act == "swiglu" else jax.nn.relu
             ff = gate(ff.astype(c.dtype)) * up
         else:
             ff = jax.nn.gelu(ff.astype(c.dtype))
-        out = dot(ff, params[f"{prefix}/mlp/w2"])
-        if c.bias:
-            out = out + params[f"{prefix}/mlp/b2"].astype(jnp.float32)
-        return h + self._branch(out).astype(c.dtype)
+        out = dot(ff, params[f"{key}/w2"])
+        if bias:
+            out = out + params[f"{key}/b2"].astype(jnp.float32)
+        return out
+
+    @scoped("mlp")
+    def mlp_residual(self, params: Mapping[str, Array], prefix: str,
+                     h: Array) -> Array:
+        """The dense MLP's branch (:meth:`_mlp` on ``mlp/*``) with its
+        norm (``ln2``) where the config places it."""
+        x = self._branch_input(params, f"{prefix}/ln2", h)
+        return self._residual(
+            params, f"{prefix}/ln2", h,
+            self._mlp(params, f"{prefix}/mlp", x, self.config.bias))
 
     def layer_view(self, params: Mapping[str, Array],
                    layer: int) -> tuple[Mapping[str, Array], str]:
@@ -985,14 +1065,14 @@ class Transformer:
                              spec: LayerSpec, h: Array) -> Array | None:
         """The logits of an ``experts`` layer's router where it stands
         BEFORE attention (``moe_router_input="attn"``: it reads the
-        attention's normed input; the same norm ``qkv`` computes, which
-        the compiler shares); None for every other layer, and where the
-        router reads the feed-forward branch's own input
-        (:meth:`ffn_residual` computes those logits itself)."""
+        attention's input, normed where the norm stands there; the same
+        norm ``qkv`` computes, which the compiler shares); None for every
+        other layer, and where the router reads the feed-forward branch's
+        own input (:meth:`ffn_residual` computes those logits itself)."""
         if spec.ffn != "experts" or self.config.moe_router_input != "attn":
             return None
-        return self.router_logits(params, prefix,
-                                  self._norm(params, f"{prefix}/ln1", h))
+        return self.router_logits(
+            params, prefix, self._branch_input(params, f"{prefix}/ln1", h))
 
     def ffn_residual(self, params: Mapping[str, Array], prefix: str,
                      spec: LayerSpec, h: Array, decode: bool = False,
@@ -1008,22 +1088,28 @@ class Transformer:
         during KV-cached decoding; ``experts`` never drops, so prefill,
         extension and decode run one path.  ``router_logits`` are those of
         :meth:`pre_attention_router` where an ``experts`` layer's router
-        stands there (None: it reads this branch's normed input);
+        stands there (None: it reads this branch's own input);
         ``route_stats``, where given, gains this layer's tokens per
-        expert ([E] int32) for the caller's counters, and ``chosen`` the
-        experts every token of an ``experts`` layer took ([B, S, k])."""
+        expert ([E] int32; where the weights hold a share of the experts,
+        ``moe_held``, the held experts' and then the assignments routed
+        elsewhere, [count + 1]) for the caller's counters, and ``chosen``
+        the experts every token of an ``experts`` layer took ([B, S, k]).
+        A shared expert (``moe_shared_experts``) runs on the same input
+        under ``moe/shared`` and is added ungated."""
         zero = jnp.zeros((), jnp.float32)
         if spec.ffn == "mlp":
             return self.mlp_residual(params, prefix, h), zero
-        x = self._norm(params, f"{prefix}/ln2", h)
+        c = self.config
         if spec.ffn == "moe":
+            # (a training fixture: its norm stands on the input, always)
+            x = self._norm(params, f"{prefix}/ln2", h)
             cap = h.shape[0] * h.shape[1] if decode else None
             moe_out, aux = self._moe.apply(params, x, prefix=f"{prefix}/",
                                            capacity_override=cap)
-            return h + moe_out.astype(self.config.dtype), aux
+            return h + moe_out.astype(c.dtype), aux
         from .moe import dropless_experts
 
-        c = self.config
+        x = self._branch_input(params, f"{prefix}/ln2", h)
         batch, seq = h.shape[:2]
         if router_logits is None:
             router_logits = self.router_logits(params, prefix, x)
@@ -1035,12 +1121,17 @@ class Transformer:
                 params.get(f"{prefix}/moe/w3"), top_k=c.moe_top_k,
                 act=c.mlp_act, score=c.moe_score,
                 bias=params.get(f"{prefix}/moe/router/bias"),
-                scale=c.moe_route_scale, chosen=chosen)
+                scale=c.moe_route_scale, chosen=chosen,
+                held=c.moe_held or None)
+            out = out.reshape(batch, seq, c.d_model)
+            if c.moe_shared_experts:
+                with jax.named_scope("shared"):
+                    out = out + self._mlp(params, f"{prefix}/moe/shared", x)
         if chosen is not None:
             chosen[-1] = chosen[-1].reshape(batch, seq, c.moe_top_k)
         if route_stats is not None:
             route_stats.append(loads)
-        return h + out.reshape(batch, seq, c.d_model).astype(c.dtype), zero
+        return self._residual(params, f"{prefix}/ln2", h, out), zero
 
     # sequences from this length on run blockwise attention (scores of a
     # block at a time, blocks wholly outside the mask skipped) on the
@@ -1440,6 +1531,7 @@ def transformer_rule(mesh: Mesh):
 
     column-parallel (tensor on output dim): wq wk wv w1 lm_head
     row-parallel  (tensor on input dim):    wo w2
+    (a shared expert's ``moe/shared/w*`` as the dense MLP's)
     vocab-sharded embedding; norm scales replicated (fsdp if divisible);
     MoE expert weights sharded over the ``expert`` axis (router replicated).
     """
@@ -1465,10 +1557,11 @@ def transformer_rule(mesh: Mesh):
         # unsharded — it is the scan axis, and sharding it would gather
         # one shard's slice every scan step
         if name.endswith(("attn/wq", "attn/wk", "attn/wv", "mlp/w1",
-                          "mlp/w3", "lm_head/w")):
+                          "mlp/w3", "moe/shared/w1", "moe/shared/w3",
+                          "lm_head/w")):
             taken = len(shape) - 1 if n_tp > 1 and shape[-1] % n_tp == 0 else None
             return PartitionSpec(*fsdp_on(len(shape) - 2, taken))
-        if name.endswith(("attn/wo", "mlp/w2")):
+        if name.endswith(("attn/wo", "mlp/w2", "moe/shared/w2")):
             taken = (len(shape) - 2
                      if n_tp > 1 and shape[-2] % n_tp == 0 else None)
             return PartitionSpec(*fsdp_on(len(shape) - 1, taken))

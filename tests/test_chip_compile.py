@@ -223,6 +223,51 @@ def test_lfm2s_round_updates_k_v_in_place_beside_its_conv_states(one_chip):
     assert temporaries < cache_bytes / 4
 
 
+def test_k_exaones_round_updates_rings_of_128_where_they_lie(one_chip):
+    """``serve_chat_k_exaone_ep8``'s decode round at its real widths, 32
+    slots x 4,096 positions, four layers (the dense one and two expert
+    layers over rings of 128, one expert layer over full attention), 16 of
+    128 experts held: the rings and the full layer's K and V are updated
+    where they lie, nothing as large as a ring is copied or sliced, and the
+    grouped matmul's weights are the held experts' alone."""
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "k-exaone-236b-a23b-8l-ep8.json")) as handle:
+        config = json.load(handle)
+    family = families.of(config)
+    model = family.model(config, remat=False, n_layers=4)
+    slots, max_len = 32, 4096
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(lambda: family.make_weights(model, 1)))
+    assert params["layer0/mlp/w1"].shape == (6144, 18432)
+    assert params["layer1/moe/w1"].shape == (16, 6144, 2048)
+    assert params["layer1/moe/router/w"].shape == (6144, 128)
+    assert params["layer1/moe/shared/w1"].shape == (6144, 2048)
+    cache = placed(jax.eval_shape(
+        lambda: generation.init_cache(model, slots, max_len)))
+    # 8 K/V heads of 128, one to a row of 128 lanes
+    assert [x.shape for x in cache.k] == [(32, 4096, 8, 128)]
+    assert [x.shape for x in cache.wk] == [(32, 128, 8, 128)] * 3
+    compiled = _compiled_round(model, params, cache, slots, one_chip)
+    aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
+    assert parts == 8 and aliased >= parts
+    # (the compiler turns ONE layer's query projection round, and in this
+    # cut of four layers one key projection: a copy of a weight, 100 MB
+    # and 12.6 MB a round, 0.3 ms; no part of the cache is among them)
+    weights = {6144 * 8192, 6144 * 1024}
+    assert [op for op in moved if op[0] != "copy" or op[2] not in weights] \
+        == []
+    assert len(moved) <= 2
+    assert temporaries < cache_bytes / 4
+    # three grouped matmuls an expert layer, each over [16, ...] weights
+    text = compiled.as_text()
+    assert len(re.findall(r"bf16\[16,6144,2048\]", text)) > 0
+    assert "bf16[128,6144,2048]" not in text
+
+
 # configuration, mesh axes: the two training cells (64 x 1,024 tokens a step)
 STEPS = {"one-chip": ("gpt2-medium", {}),
          "fsdp2-tensor2": ("gpt2-large", {"fsdp": 2, "tensor": 2})}

@@ -17,6 +17,7 @@ from parameter_server_distributed_tpu.models.serving import (DecodeServer,
                                                              _bucket)
 from parameter_server_distributed_tpu.models.transformer import (
     Transformer, TransformerConfig)
+from parameter_server_distributed_tpu.obs import legs as obs_legs
 from parameter_server_distributed_tpu.obs import stats as obs_stats
 from parameter_server_distributed_tpu.obs import trace as obs_trace
 
@@ -826,10 +827,13 @@ def test_admission_legs_once_each_and_add_up_to_the_block(
     # disjoint, and with the slot bookkeeping they cover the block; the
     # three under serve/admit/device cover that one
     spent = {name: after[name][1] - before[name][1] for name in after}
-    # (within 5%; a whole-prompt hit on a CPU is a millisecond of three
-    # dispatches, so there within the clocks' own cost: 16 reads and five
-    # histogram updates, a quarter of a millisecond at the most)
-    slack = 0.25e-3 * admissions if path == "hit" else 0.0
+    # (within 5%, or within 2 ms an admission where that is more: a
+    # whole-prompt hit on a CPU is a millisecond of three dispatches, of
+    # which the clocks' own cost, 16 reads and five histogram updates, is a
+    # quarter; and on a host whose cores are all taken, six workers of the
+    # suite at once, the thread loses its core BETWEEN two legs for a
+    # millisecond or two, which no leg counts and the block does)
+    slack = 2e-3 * admissions
     legs = sum(spent[f"serve.admit_{leg}_s"] for leg in ADMIT_LEGS)
     block = spent["serve.admit_s"]
     assert block - max(0.05 * block, slack) <= legs < block
@@ -837,7 +841,13 @@ def test_admission_legs_once_each_and_add_up_to_the_block(
                        for leg in ("forward", "tree", "first_token"))
     block = spent["serve.admit_device_s"]
     assert block - max(0.05 * block, slack) <= under_device < block
-    assert not srv.slow_legs, list(srv.slow_legs)
+    # no leg is slow by this test's own making: none built a program (the
+    # warm-up above has every one) and none spent the limit on the thread's
+    # own CPU.  A leg that only WAITED for a core on a loaded host is the
+    # host's, and is what the recorder is for (its cpu_s says so)
+    own = [leg for leg in srv.slow_legs
+           if leg["programs_built"] or leg["cpu_s"] > obs_legs.SLOW_LEG_S]
+    assert not own, own
 
 
 def test_a_refused_forward_leaves_no_span_open(rng, monkeypatch):

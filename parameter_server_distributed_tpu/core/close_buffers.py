@@ -25,7 +25,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..obs import stats as obs_stats
-from ..utils.buffers import exported, untouched_bytearray
+from ..utils.buffers import float32_over
 
 # Bytes of close output that went to memory the core had to allocate
 # (beside rpc.wire.fresh_bytes): the first two closes of a store, and
@@ -53,16 +53,10 @@ class CloseBuffers:
         self._taken = {}
         out = {}
         for name, shape in shapes.items():
-            nbytes = 4 * int(np.prod(shape, dtype=np.int64))
-            if not nbytes:
-                out[name] = np.empty(shape, np.float32)
-                continue
-            buf = self._spare.get(name)
-            if buf is None or len(buf) != nbytes or exported(buf):
-                _obs_fresh_bytes.add(nbytes)
-                buf = untouched_bytearray(nbytes)
-            self._taken[name] = buf
-            out[name] = np.frombuffer(buf, np.float32).reshape(shape)
+            buf, out[name] = float32_over(self._spare.get(name), shape,
+                                          _obs_fresh_bytes)
+            if buf is not None:
+                self._taken[name] = buf
         return out
 
     def publish(self) -> None:

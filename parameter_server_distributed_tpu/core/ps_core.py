@@ -107,6 +107,7 @@ from ..replication.messages import STALE_SHARD_MAP
 from . import arena as arena_mod
 from . import device_apply
 from .close_buffers import CloseBuffers
+from .fold_buffers import FoldBuffers
 from .optimizer import HostOptimizer, SGD, split_updates
 from .stripes import (partition_names, partition_ranges, run_striped,
                       stripe_count, stripe_of)
@@ -256,6 +257,17 @@ class PushSink:
         # instead of reporting a bare late push.
         self.stale_redirect: tuple[int, int] | None = None
 
+    @property
+    def folds_at_once(self) -> bool:
+        """Whether :meth:`fold` has read its arrays by the time it returns
+        and keeps none of them: the streaming single-member sink, which
+        sums them into the iteration's accumulator inside the call.  Its
+        caller may then hand it views of a buffer about to be refilled
+        (``decode_gradients(borrow=True)``).  A sink that stages what it
+        is given (buffered, async, a tier group) keeps the arrays and
+        must own them."""
+        return self._buffer is None
+
     def fold(self, gradients: Mapping[str, np.ndarray]) -> None:
         if self._buffer is not None:
             self._buffer.update(gradients)
@@ -291,10 +303,14 @@ class PushSink:
 
 
 def _fold_one(accum: "TensorStore", counts: dict[str, int], name: str, g,
-              weight: int) -> int:
+              weight: int, buffers: FoldBuffers | None = None) -> int:
     """Fold one tensor into the running accumulator — type-driven
     (ISSUE 11): numpy gradients keep the exact pre-existing
-    np.array/np.add sequence (byte-identical with the device path off);
+    np.array/np.add values (byte-identical with the device path off;
+    with ``buffers`` the seed is the same one-pass convert-and-copy, into
+    a buffer the core kept from the accumulator it closed last and not
+    into new pages — core/fold_buffers.py); ``g`` is only READ, so it may
+    be a read-only view of a received frame;
     device-decoded gradients (rpc/data_plane.decode_gradients) seed an
     owned device array and accumulate via the correctly-rounded device
     add, so a leaf aggregator's member folds run as device reductions
@@ -318,7 +334,12 @@ def _fold_one(accum: "TensorStore", counts: dict[str, int], name: str, g,
             # asarray-then-astype would sweep twice for non-f32 decodes)
             # — the exact pre-existing path for numpy AND for duck-typed
             # array-likes that only implement __array__
-            acc = np.array(g, dtype=np.float32)
+            if buffers is None:
+                acc = np.array(g, dtype=np.float32)
+            else:
+                src = np.asarray(g)
+                acc = buffers.take(name, src.shape)
+                np.copyto(acc, src, casting="unsafe")
         accum[name] = acc
         counts[name] = weight
         return int(acc.nbytes)
@@ -416,6 +437,9 @@ class ParameterServerCore:
         # where the range-cut close writes the store it is about to
         # publish: the buffers of the store retired two closes ago
         self._close_buffers = CloseBuffers()
+        # where a host accumulator's sums are seeded: the buffers of the
+        # accumulator closed last
+        self._fold_buffers = FoldBuffers()
         # accelerator-resident applies (ISSUE 11): count of barrier
         # closes whose fresh store is device-resident (the pst-status
         # "device apply" rollup line reads this)
@@ -1250,7 +1274,7 @@ class ParameterServerCore:
                 # mismatch — only THEN is the name marked folded, so a
                 # retry of a failed fold is not silently dropped
                 added += _fold_one(state.accum, state.counts, name, g,
-                                   weight)
+                                   weight, self._fold_buffers)
                 folded.add(name)
         finally:
             if added:
@@ -1290,7 +1314,8 @@ class ParameterServerCore:
                     # mismatch — the name stays unpublished, so a retry
                     # of the failed fold is not silently dropped
                     added_by[idx] += _fold_one(state.accum, state.counts,
-                                               name, g, 1)
+                                               name, g, 1,
+                                               self._fold_buffers)
                     done_by[idx].append(name)
 
         try:
@@ -1682,7 +1707,10 @@ class ParameterServerCore:
         checkpoint restore obsoleted the aggregate.  On an apply failure
         the accumulator is PUT BACK (already-scaled sums are means, so
         their counts reset to 1) and the exception propagates — the next
-        push/poll retries the close instead of wedging the iteration."""
+        push/poll retries the close instead of wedging the iteration.
+        Once the sweep has read a host accumulator its buffers go back
+        for the next iteration's seeds (core/fold_buffers.py); sums that
+        were put back keep theirs."""
         gen = self._restore_epoch
         sums, counts = state.accum, state.counts
         state.accum, state.counts = {}, {}
@@ -1803,6 +1831,10 @@ class ParameterServerCore:
                 state.buffer_bytes = freed
                 self._grad_buffer_note(freed)
             raise
+        if isinstance(sums, dict):
+            # the sweep has read the sums: the next iteration seeds over
+            # their buffers, unless somebody kept one (fold_buffers.py)
+            self._fold_buffers.give_back(sums)
         return self._restore_epoch == gen
 
     def _receive_async(self, worker_id: int, iteration: int,

@@ -85,6 +85,13 @@ class FreeRunSink:
         self._folded: set[str] = set()
         self.stale_map_epoch: int | None = None
 
+    @property
+    def folds_at_once(self) -> bool:
+        """``PushSink.folds_at_once``: no.  The accumulator is this
+        sink's own and lives until its commit; it is handed owned
+        arrays, as ever."""
+        return False
+
     def fold(self, gradients) -> None:
         self._engine.fold(self, gradients)
 

@@ -90,24 +90,49 @@ def bucket_bytes() -> int:
     return stream_chunk_bytes()
 
 
-def decode_gradients(tensors: Iterable[m.Tensor],
-                     device: bool = False) -> dict:
+# Bytes decode_gradients copied out of a received frame so that its
+# caller owns what it gets (the float32 wire's tensors arrive as
+# read-only views of the frame; beside rpc.wire.fresh_bytes).  A sink
+# that folds at once borrows the views and this stays still.
+_obs_decode_copied = obs_stats.counter("rpc.server.decode.copied_bytes")
+
+
+def decode_gradients(tensors: Iterable[m.Tensor], device: bool = False,
+                     borrow: bool = False) -> dict:
     """Decode one push chunk's wire Tensors into fold-ready arrays.
 
     ``device=False`` (the default, and the only behavior before
-    ISSUE 11): host numpy via ``Tensor.to_array`` — byte-identical to
-    the pre-existing fold input.  ``device=True`` (the serving core
-    asked for device folds — ``ParameterServerCore.device_fold``): each
+    ISSUE 11): host numpy, owned and writable as ``Tensor.to_array``
+    gives it — byte-identical to the pre-existing fold input.
+    ``device=True`` (the serving core asked for device folds —
+    ``ParameterServerCore.device_fold``): each
     packed payload lands as a jax device buffer with the dequantize
     running ON DEVICE (core/device_apply.tensor_to_device — int8 wire
     bytes cross the host boundary at a quarter of the f32 volume, bf16
     at half), so the accumulator sums and the sharded optimizer apply
-    never round-trip through host numpy."""
+    never round-trip through host numpy.
+
+    ``borrow=True`` (host decode only) is for a consumer that reads the
+    arrays before it asks for the next chunk and keeps none of them
+    (``PushSink.folds_at_once``): a float32-wire tensor is then the
+    READ-ONLY view of the frame it arrived in (``Tensor.borrow_array``),
+    not a copy of it into new memory.  Packed wires unpack into new
+    arrays either way."""
     if device:
         from ..core import device_apply
 
         return {t.name: device_apply.tensor_to_device(t) for t in tensors}
-    return {t.name: t.to_array() for t in tensors}
+    out = {}
+    copied = 0
+    for t in tensors:
+        arr = t.borrow_array()
+        if not (borrow or arr.flags.writeable):
+            copied += arr.nbytes
+            arr = arr.copy()
+        out[t.name] = arr
+    if copied:
+        _obs_decode_copied.add(copied)
+    return out
 
 
 def _tensor_nbytes(t: m.Tensor) -> int:

@@ -91,7 +91,11 @@ class Tensor(Message):
                    packed=ArrayPayload(flat, wire_dtype, k),
                    packed_dtype=wire_dtype)
 
-    def to_array(self) -> np.ndarray:
+    def _decoded(self) -> np.ndarray:
+        """The payload as a flat array: a new one where the wire had to
+        be unpacked or upcast, a view of ``data`` where it is float32
+        already (read-only when ``data`` is a view of a received
+        frame)."""
         packed = self.packed
         if isinstance(packed, ArrayPayload):
             # locally-built tensor read back without a wire round-trip:
@@ -112,10 +116,25 @@ class Tensor(Message):
             # dtype=1 tensor round-trips at the precision the sender marked
             # (wire payload itself is float-precision, as in the reference)
             arr = arr.astype(np.float64)
+        return arr
+
+    def to_array(self) -> np.ndarray:
+        arr = self._decoded()
         if not arr.flags.writeable:
             # decode paths can yield frombuffer views (zero-copy); callers
             # get writable arrays so in-place aggregation works uniformly
             arr = arr.copy()
+        if self.shape:
+            arr = arr.reshape(self.shape)
+        return arr
+
+    def borrow_array(self) -> np.ndarray:
+        """:meth:`to_array` without its last copy: on the float32 wire a
+        READ-ONLY view of the frame the tensor was decoded from, for a
+        consumer that reads it once and lets go before the frame's
+        buffer is filled again (whoever keeps the view keeps the buffer:
+        ``utils/buffers.exported``)."""
+        arr = self._decoded()
         if self.shape:
             arr = arr.reshape(self.shape)
         return arr

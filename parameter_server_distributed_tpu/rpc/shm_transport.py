@@ -574,8 +574,9 @@ class ShmRing:
         touched.  Consume it (decode, fold, convert) and let go of it;
         whoever keeps a view, of any depth, keeps that buffer, and the
         ring takes another (see :class:`_FramePool`).  Read-only, so that
-        ``Tensor.to_array`` copies out of it exactly once and in-place
-        aggregation can never write into a buffer about to be refilled."""
+        ``Tensor.to_array`` copies out of it exactly once, a fold that
+        borrows it can only read it, and in-place aggregation can never
+        write into a buffer about to be refilled."""
         with self._frame() as frame:
             try:
                 self._read_into(self._prefix, 4, deadline)
@@ -906,8 +907,9 @@ class _ServerConnection:
 
                 def chunks() -> Iterator[m.Message]:
                     # tensor payloads stay views of the frame until the
-                    # handler's decode copies them out (Tensor.to_array),
-                    # which it does before it asks for the next chunk
+                    # handler has copied them out (Tensor.to_array) or
+                    # folded them where they lie (a sink that folds at
+                    # once), which it has before it asks for the next chunk
                     frame = pending.pop()
                     while frame is not None:
                         with obs_trace.span("rpc/server/decode",

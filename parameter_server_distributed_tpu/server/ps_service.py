@@ -11,7 +11,10 @@ Two server-side hot-path optimizations live here (ISSUE 3):
   decoded chunk through a :class:`~..core.ps_core.PushSink` as it arrives,
   so decode ⊕ accumulate overlap the transport of later chunks and the
   core never buffers a whole per-worker gradient store (streaming
-  aggregation mode — core/ps_core.py).
+  aggregation mode — core/ps_core.py).  A sink that folds inside the
+  call is lent the frame's own read-only views
+  (``decode_gradients(borrow=True)``): a float32 gradient goes from its
+  frame into the accumulator in one pass, with no copy between.
 - **Encode-once broadcast cache**: served parameter chunks are encoded to
   wire bytes once per (params version, wire dtype, chunk budget) and
   replayed to every subsequent puller of the same version
@@ -322,12 +325,15 @@ class ParameterServerService:
         return result
 
     @staticmethod
-    def _decode(chunk: m.GradientUpdate, device: bool) -> dict:
+    def _decode(chunk: m.GradientUpdate, device: bool, sink) -> dict:
         """One push chunk's wire tensors to fold-ready arrays: the decode
-        leg of the server's codec."""
+        leg of the server's codec.  A sink that folds at once and keeps
+        nothing (``PushSink.folds_at_once``) is lent the frame's own
+        read-only views; every other sink gets arrays it owns."""
         with obs_trace.span("rpc/server/decode", worker=chunk.worker_id,
                             iteration=chunk.iteration):
-            return decode_gradients(chunk.gradients, device)
+            return decode_gradients(chunk.gradients, device,
+                                    borrow=sink.folds_at_once)
 
     def _commit(self, sink: PushSink):
         """End-of-stream commit of a chunk-folded push, timed/traced like
@@ -487,7 +493,7 @@ class ParameterServerService:
                 # each chunk straight to device buffers
                 device = self.core.device_fold
             if chunk.gradients:
-                sink.fold(self._decode(chunk, device))
+                sink.fold(self._decode(chunk, device, sink))
         if sink is None:
             return m.PushResponse(success=False, message="empty push stream")
         return self._push_result_response(self._commit(sink))
@@ -574,7 +580,7 @@ class ParameterServerService:
                 pull_wire_dtype = chunk.pull_wire_dtype
                 device = self.core.device_fold  # see PushGradientsStream
             if chunk.gradients:
-                sink.fold(self._decode(chunk, device))
+                sink.fold(self._decode(chunk, device, sink))
         if sink is None:
             yield m.PushPullResponse(push=m.PushResponse(
                 success=False, message="empty push stream"))
@@ -737,7 +743,7 @@ class ParameterServerService:
                 held_version = int(dchunk.held_version)
                 device = self.core.device_fold  # see PushGradientsStream
             if chunk.gradients:
-                sink.fold(self._decode(chunk, device))
+                sink.fold(self._decode(chunk, device, sink))
         if sink is None:
             yield dmsg.DeltaFrame(push=m.PushResponse(
                 success=False, message="empty push stream"))

@@ -4,8 +4,8 @@ At the sizes a parameter store is chunked into (far above malloc's mmap
 threshold) a new buffer is new address space and every 4 KB of it a page
 fault, which costs more than the copy or the sweep that fills it.  So the
 ring's frame pool (rpc/shm_transport.py), the serve cache
-(server/ps_service.py) and the barrier close (core/close_buffers.py) each
-keep ``bytearray``s, hand out views of them, and write one again only when
+(server/ps_service.py), the barrier close (core/close_buffers.py) and the
+fold's accumulator (core/fold_buffers.py) each keep ``bytearray``s, hand out views of them, and write one again only when
 :func:`exported` finds no view of it alive: a holder of a view keeps that
 buffer, and the keeper allocates in its place.  That one rule is what keeps
 served memory from being written, and it lives here.
@@ -54,6 +54,22 @@ def untouched_bytearray(nbytes: int) -> bytearray:
     buf = bytearray()
     _pyapi.PyByteArray_Resize(buf, nbytes)   # raises MemoryError itself
     return buf
+
+
+def float32_over(buf: bytearray | None, shape: tuple, fresh
+                 ) -> tuple[bytearray | None, np.ndarray]:
+    """A float32 array of ``shape`` to be overwritten whole, and the
+    buffer it lies in: ``buf`` when it has exactly the size and no view
+    of it is alive, a new :func:`untouched_bytearray` otherwise (its
+    bytes added to the counter ``fresh``).  An empty shape needs no
+    buffer: ``(None, empty array)``."""
+    nbytes = 4 * int(np.prod(shape, dtype=np.int64))
+    if not nbytes:
+        return None, np.empty(shape, np.float32)
+    if buf is None or len(buf) != nbytes or exported(buf):
+        fresh.add(nbytes)
+        buf = untouched_bytearray(nbytes)
+    return buf, np.frombuffer(buf, np.float32).reshape(shape)
 
 
 def uninit_bytes(size: int) -> tuple[bytes, np.ndarray]:

@@ -81,3 +81,24 @@ def _lockcheck_env(request, monkeypatch):
     fixture has set it."""
     if request.node.get_closest_marker("lockcheck"):
         monkeypatch.setenv("PSDT_LOCK_CHECK", "1")
+
+
+@pytest.fixture
+def frame_chunks():
+    """A push as the server meets it: ``frame_chunks(worker_id, iteration,
+    grads, chunks)`` yields ``(buffer, chunk)`` per chunk, the chunk a
+    GradientUpdate decoded from a READ-ONLY view of a buffer its reader
+    will refill (the float32 wire, so its tensors are views of it)."""
+    from parameter_server_distributed_tpu.rpc import messages as m
+
+    def make(worker_id, iteration, grads, chunks=3):
+        names = list(grads)
+        per = -(-len(names) // chunks)
+        for lo in range(0, len(names), per):
+            buf = bytearray(m.GradientUpdate(
+                worker_id=worker_id, iteration=iteration,
+                gradients=[m.Tensor.from_array(n, grads[n])
+                           for n in names[lo:lo + per]]).encode())
+            yield buf, m.GradientUpdate.decode(memoryview(buf).toreadonly())
+
+    return make

@@ -8,6 +8,8 @@ encoded-chunk broadcast cache (single-flight, invalidation on
 apply/restore/initialize, mixed wire dtypes), and the barrier_width TTL
 cache lock."""
 
+import gc
+import os
 import threading
 import time
 
@@ -570,6 +572,464 @@ def test_restore_during_range_cut_close_wins():
             np.testing.assert_array_equal(restored[name], original[name])
     for name, value in want.items():
         np.testing.assert_array_equal(core.get_parameters()[name], value)
+
+
+# ------------------------------------- the fold's kept accumulator (PR 41)
+# A streamed push is summed in the buffers of the accumulator closed last
+# (core/fold_buffers.py), read where its frame lies, and only where no
+# view of those buffers is left.
+
+_fresh_fold_bytes = obs_stats.counter("ps.fold.fresh_bytes")
+_decode_copied = obs_stats.counter("rpc.server.decode.copied_bytes")
+KEPT_BYTES = sum(4 * int(np.prod(shape)) for shape in KEPT.values())
+
+
+class _WatchedSGD(SGD):
+    """Sees the sums as the close hands them to the rule: records where
+    each lies and, when asked, keeps one (an optimizer that adopts a
+    gradient, a hook that holds a mean)."""
+
+    def __init__(self, lr=0.5):
+        super().__init__(lr)
+        self.addresses = []
+        self.keep = None        # name -> what to keep of its sum
+        self.kept = None
+
+    def prepare(self, grads):
+        self.addresses.append({name: g.ctypes.data
+                               for name, g in grads.items()})
+        if self.keep is not None:
+            name, cut = self.keep
+            self.kept, self.keep = cut(grads[name]), None
+        super().prepare(grads)
+
+
+def _fold_close(core, rng, iteration, workers=1):
+    """One round of ``workers`` pushes; returns the accumulator bytes the
+    core had to allocate for it."""
+    before = _fresh_fold_bytes.value
+    for wid in range(workers):
+        r = core.receive_gradients(wid, iteration, _random_grads(rng, KEPT))
+    assert r.aggregation_complete, r.message
+    return _fresh_fold_bytes.value - before
+
+
+@pytest.mark.parametrize("workers", [1, 3])
+@pytest.mark.parametrize("stripes", [1, 3], ids=["serial", "striped"])
+def test_the_accumulator_is_seeded_over_the_one_closed_last(workers, stripes):
+    """The first round allocates an accumulator; from the second on every
+    sum lies where the last round's lay and nothing is allocated,
+    whoever contributes and however the fold is cut."""
+    watched = _WatchedSGD()
+    core = ParameterServerCore(total_workers=workers, stripes=3,
+                               optimizer=watched, aggregation="streaming")
+    rng = np.random.default_rng(41)
+    core.initialize_parameters(_random_grads(rng, KEPT))
+    core._stripes = stripes     # the FOLD's cut; the close stays by range
+    assert _fold_close(core, rng, 1, workers) == KEPT_BYTES
+    for it in range(2, 6):
+        assert _fold_close(core, rng, it, workers) == 0
+    assert all(seen == watched.addresses[0] for seen in watched.addresses)
+    assert len(watched.addresses) == 5
+
+
+@pytest.mark.parametrize("cut", [lambda g: g, lambda g: g[1, 2:4]],
+                         ids=["array", "slice"])
+def test_a_held_sum_keeps_its_bytes_and_the_fold_allocates_in_its_place(cut):
+    """Whoever keeps a sum (or a slice of one) keeps its buffer: three
+    further rounds change no byte of it, the seed that wanted the buffer
+    takes a new one and counts exactly that tensor, and once the holder
+    lets go nothing is allocated again."""
+    watched = _WatchedSGD()
+    core, rng = _kept_core(watched)
+    for it in (1, 2):
+        _fold_close(core, rng, it)
+    watched.keep = ("w1", cut)
+    _fold_close(core, rng, 3)
+    snapshot = watched.kept.copy()
+    allocated = []
+    for it in (4, 5, 6):
+        allocated.append(_fold_close(core, rng, it))
+        np.testing.assert_array_equal(watched.kept, snapshot)
+    assert allocated == [4 * int(np.prod(KEPT["w1"])), 0, 0]
+    held_at = watched.addresses[2]["w1"]
+    assert all(seen["w1"] != held_at for seen in watched.addresses[3:])
+    watched.kept = None
+    assert [_fold_close(core, rng, it) for it in (7, 8)] == [0, 0]
+
+
+def test_a_second_iteration_folding_beside_an_open_one_allocates_and_says_so():
+    """Two iterations' accumulators alive at once (a worker ahead of the
+    barrier) cannot share buffers: the second allocates, counted; each
+    goes back at its close and both rounds after find one."""
+    core = ParameterServerCore(total_workers=2, stripes=3,
+                               optimizer=SGD(0.5), aggregation="streaming")
+    rng = np.random.default_rng(41)
+    core.initialize_parameters(_random_grads(rng, KEPT))
+    assert _fold_close(core, rng, 1, workers=2) == KEPT_BYTES
+    before = _fresh_fold_bytes.value
+    core.receive_gradients(0, 2, _random_grads(rng, KEPT))  # seeds 2
+    assert _fresh_fold_bytes.value == before
+    core.receive_gradients(0, 3, _random_grads(rng, KEPT))  # seeds 3
+    assert _fresh_fold_bytes.value - before == KEPT_BYTES
+    for it in (2, 3):
+        assert core.receive_gradients(
+            1, it, _random_grads(rng, KEPT)).aggregation_complete
+    assert _fresh_fold_bytes.value - before == KEPT_BYTES
+    assert _fold_close(core, rng, 4, workers=2) == 0
+
+
+def test_takers_on_many_threads_never_share_a_buffer():
+    """``FoldBuffers`` has no lock: a take is one dict pop and a give
+    back one assignment, so folds of several iterations on several
+    threads each get a buffer of their own.  More threads than cores, the
+    interpreter switching as often as it can: every thread writes its
+    mark over the sum it took and finds it whole after yielding."""
+    import sys
+
+    from parameter_server_distributed_tpu.core.fold_buffers import FoldBuffers
+
+    kept = FoldBuffers()
+    shape, clashes, done = (64, 33), [], []
+    deadline = time.monotonic() + 1.5
+
+    def fold(mark):
+        laps = 0
+        while time.monotonic() < deadline:
+            acc = kept.take("w", shape)
+            acc[...] = mark
+            time.sleep(0)
+            if not (acc == mark).all():
+                clashes.append(mark)
+            kept.give_back({"w": acc})
+            del acc
+            laps += 1
+        done.append(laps)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=fold, args=(np.float32(i + 1),))
+                   for i in range(4 * (os.cpu_count() or 4))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30.0)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert len(done) == len(threads) and min(done) > 0
+    assert not clashes
+
+
+class _FlakyWatchedSGD(_WatchedSGD):
+    armed = False
+
+    def update_range(self, name, p, g, out, lo, hi):
+        if self.armed and name == "w2":
+            self.armed = False
+            raise RuntimeError("injected range failure")
+        return super().update_range(name, p, g, out, lo, hi)
+
+
+@pytest.mark.lockcheck
+def test_a_failed_apply_keeps_the_sums_in_their_buffers_until_the_retry(
+        numpy_only):
+    """The close puts the (scaled) sums back on a failed apply, buffers
+    and all: a round that folds meanwhile may not seed over them, the
+    retry closes on the very arrays, lands what an unfailed close lands,
+    and only then do the buffers go back."""
+    flaky = _FlakyWatchedSGD()
+    core = ParameterServerCore(total_workers=2, stripes=3, optimizer=flaky,
+                               aggregation="streaming")
+    twin = ParameterServerCore(total_workers=2, stripes=3,
+                               optimizer=SGD(0.5), aggregation="streaming")
+    rng = np.random.default_rng(41)
+    init = _random_grads(rng, KEPT)
+    for c in (core, twin):
+        c.initialize_parameters(init)
+    for it in (1, 2):
+        pushes = [_random_grads(rng, KEPT) for _ in range(2)]
+        for c in (core, twin):
+            for wid, grads in enumerate(pushes):
+                c.receive_gradients(wid, it, grads)
+    pushes = [_random_grads(rng, KEPT) for _ in range(2)]
+    core.receive_gradients(0, 3, pushes[0])
+    flaky.armed = True
+    with pytest.raises(RuntimeError, match="injected range failure"):
+        core.receive_gradients(1, 3, pushes[1])
+    put_back = core._iteration_states[3].accum
+    assert {n: a.ctypes.data for n, a in put_back.items()} \
+        == flaky.addresses[-1]
+    means = {n: a.copy() for n, a in put_back.items()}
+    # iteration 4 folds while 3 waits for its retry: not over 3's sums
+    before = _fresh_fold_bytes.value
+    core.receive_gradients(0, 4, _random_grads(rng, KEPT))
+    assert _fresh_fold_bytes.value - before == KEPT_BYTES
+    for name, acc in put_back.items():
+        np.testing.assert_array_equal(acc, means[name])
+    _, ready, received, _ = core.check_sync_status(3)   # re-fires the close
+    assert ready and received == 2
+    assert flaky.addresses[-1] == flaky.addresses[-2]   # the very arrays
+    for wid, grads in enumerate(pushes):
+        twin.receive_gradients(wid, 3, grads)
+    for name, value in twin.get_parameters().items():
+        np.testing.assert_array_equal(core.get_parameters()[name], value)
+    # the buffers are back: the next round seeds over them (once the
+    # failed attempt's traceback, a cycle that holds the sums, is gone)
+    del put_back, acc
+    gc.collect()
+    before = _fresh_fold_bytes.value
+    core.receive_gradients(0, 5, _random_grads(rng, KEPT))
+    assert _fresh_fold_bytes.value == before
+
+
+@pytest.mark.lockcheck
+def test_restore_during_a_close_on_kept_sums_wins():
+    """A restore that lands while the close sweeps kept sums ends with
+    exactly the restored store, and the rounds after it fold and close
+    as ever (the dropped aggregate's buffers may be seeded over)."""
+    core, rng = _kept_core(_SlowRangeSGD(1.0))
+    for it in (1, 2):
+        _fold_close(core, rng, it)
+    grads = _random_grads(rng, KEPT)
+    closer = threading.Thread(
+        target=lambda: core.receive_gradients(0, 3, grads))
+    closer.start()
+    time.sleep(0.02)  # the closer is inside its range tasks
+    restored = _random_grads(rng, KEPT)
+    want = {name: value.copy() for name, value in restored.items()}
+    core.restore(epoch=0, iteration=0, params=restored)
+    closer.join(timeout=5.0)
+    assert not closer.is_alive()
+    for name, value in want.items():
+        np.testing.assert_array_equal(core.get_parameters()[name], value)
+    for it in (1, 2, 3):
+        step = _random_grads(rng, KEPT)
+        assert core.receive_gradients(0, it, step).aggregation_complete
+        for name in want:
+            want[name] = want[name] - step[name]
+    for name, value in want.items():
+        np.testing.assert_array_equal(core.get_parameters()[name], value)
+
+
+def test_the_seed_push_becomes_the_store_and_is_never_written_again():
+    """Bootstrap: the first aggregate BECOMES the parameters, so its
+    accumulator is the served store.  Two real rounds later a reader of
+    that store still reads the seed's bytes, no later sum lies in its
+    buffers, and the rounds allocated one accumulator in its place."""
+    watched = _WatchedSGD(0.5)
+    core = ParameterServerCore(total_workers=1, stripes=3,
+                               optimizer=watched, aggregation="streaming")
+    rng = np.random.default_rng(41)
+    seed = _random_grads(rng, KEPT)
+    before = _fresh_fold_bytes.value
+    assert core.receive_gradients(0, 0, seed).aggregation_complete
+    assert _fresh_fold_bytes.value - before == KEPT_BYTES
+    reader = core.get_parameters()
+    store_at = {name: value.ctypes.data for name, value in reader.items()}
+    for name, value in seed.items():
+        np.testing.assert_array_equal(reader[name], value)
+    want = {name: value.copy() for name, value in seed.items()}
+    allocated = []
+    for it in (1, 2):
+        step = _random_grads(rng, KEPT)
+        before = _fresh_fold_bytes.value
+        assert core.receive_gradients(0, it, step).aggregation_complete
+        allocated.append(_fresh_fold_bytes.value - before)
+        for name in want:
+            want[name] = want[name] - np.float32(0.5) * step[name]
+        for name, value in seed.items():            # under the reader
+            np.testing.assert_array_equal(reader[name], value)
+    assert allocated == [KEPT_BYTES, 0]
+    for seen in watched.addresses:
+        assert not set(seen.values()) & set(store_at.values())
+    for name, value in want.items():
+        np.testing.assert_array_equal(core.get_parameters()[name], value)
+
+
+def _refilling(frames):
+    """The request iterator of a ring: a frame's buffer is overwritten
+    as soon as the handler asks for the next chunk."""
+    last = None
+    for buf, chunk in frames:
+        if last is not None:
+            last[:] = b"\xff" * len(last)
+        last = buf
+        yield chunk
+        del chunk
+    if last is not None:
+        last[:] = b"\xff" * len(last)
+
+
+def _tier_core():
+    from parameter_server_distributed_tpu.tiers import messages as tmsg
+    agg = tmsg.aggregate_id_for(0)
+    core = ParameterServerCore(total_workers=2, optimizer=SGD(1.0),
+                               contributions_fn=lambda: {agg: (2, (0, 1))})
+    return core, agg
+
+
+@pytest.mark.parametrize("kind", ["streaming", "buffered", "group", "async",
+                                  "freerun"])
+def test_a_sink_that_keeps_what_it_is_given_still_gets_arrays_of_its_own(
+        numpy_only, frame_chunks, kind):
+    """Through the real stream handler: every sink that stages its chunks
+    until the commit (buffered, a tier group, async, free-run) is handed
+    owned, writable arrays, so a frame refilled after its fold returned
+    moves nothing; only the streaming single-member sink, which has
+    summed the chunk by then, borrows the frame's views
+    (``rpc.server.decode.copied_bytes`` says which)."""
+    rng = np.random.default_rng(41)
+    worker = 0
+    if kind == "group":
+        core, worker = _tier_core()
+    else:
+        core = ParameterServerCore(
+            total_workers=1, optimizer=SGD(1.0),
+            aggregation="buffered" if kind == "buffered" else "streaming",
+            staleness_bound=2 if kind == "async" else 0,
+            freerun=(kind == "freerun"))
+    init = _random_grads(rng, KEPT)
+    core.initialize_parameters(init)
+    sink = core.begin_push(worker, 1)
+    assert sink.folds_at_once is (kind == "streaming")
+    service = _make_service(core)
+    grads = _random_grads(rng, KEPT)
+    before = _decode_copied.value
+    response = service.PushGradientsStream(
+        _refilling(frame_chunks(worker, 1, grads, chunks=3)), None)
+    assert response.success, response.message
+    assert _decode_copied.value - before == (
+        0 if kind == "streaming" else KEPT_BYTES)
+    # a group's one push is its two members' SUM: the mean halves it
+    scale = np.float32(0.5 if kind == "group" else 1.0)
+    for name, value in init.items():
+        np.testing.assert_array_equal(
+            core.get_parameters()[name], value - scale * grads[name],
+            err_msg=name)
+
+
+def test_the_quorum_forward_fold_reads_borrowed_views_and_lands_todays_bits(
+        numpy_only, monkeypatch, frame_chunks):
+    """A straggler sealed out of its iteration folds forward, damped,
+    through ``StalenessDamping.damp``, which only reads its input: from
+    the frame's read-only views it lands the bits it lands from owned
+    arrays, and the frame refilled after the fold moves nothing."""
+    monkeypatch.delenv("PSDT_STALENESS_BETA", raising=False)
+    rng = np.random.default_rng(41)
+    init = _random_grads(rng, KEPT)
+    pushes = {key: _random_grads(rng, KEPT)
+              for key in ("w0", "w1", "late", "next")}
+    cores = []
+    for borrowed in (False, True):
+        core = ParameterServerCore(total_workers=3, optimizer=SGD(1.0),
+                                   quorum=0.5, quorum_grace_ms=0.0,
+                                   stripes=3)
+        core.initialize_parameters(init)
+        core.receive_gradients(0, 1, pushes["w0"])
+        core.receive_gradients(1, 1, pushes["w1"])
+        assert core.check_sync_status(1)[1]     # closed without worker 2
+        if borrowed:
+            response = _make_service(core).PushGradientsStream(
+                _refilling(frame_chunks(2, 1, pushes["late"])), None)
+            message = response.message
+        else:
+            message = core.receive_gradients(2, 1, pushes["late"]).message
+        assert "folded into iteration 2" in message
+        core.receive_gradients(0, 2, pushes["next"])
+        assert core.check_sync_status(2)[1]
+        cores.append(core)
+    for name in KEPT:
+        np.testing.assert_array_equal(cores[0].get_parameters()[name],
+                                      cores[1].get_parameters()[name])
+    assert not np.array_equal(cores[1].get_parameters()["w1"], init["w1"])
+
+
+def test_a_replayed_chunk_and_a_wrong_shape_leave_the_kept_sums_as_today(
+        frame_chunks):
+    """From borrowed views, into kept buffers: a replayed chunk folds
+    once; a contributor's tensor of another shape raises, its name stays
+    unmarked, the sums taken so far are whole and nothing is allocated
+    for it; the retry with the right shape contributes."""
+    core = ParameterServerCore(total_workers=2, optimizer=SGD(1.0),
+                               stripes=3, aggregation="streaming")
+    rng = np.random.default_rng(41)
+    init = _random_grads(rng, KEPT)
+    core.initialize_parameters(init)
+    first, second = (_random_grads(rng, KEPT) for _ in range(2))
+    service = _make_service(core)
+    frames = list(frame_chunks(0, 1, first, chunks=2))
+    replayed = frames + [next(frame_chunks(0, 1, first, chunks=2))]
+    assert service.PushGradientsStream(_refilling(replayed), None).success
+    sums = {n: a.copy() for n, a in core._iteration_states[1].accum.items()}
+    for name, value in first.items():
+        np.testing.assert_array_equal(sums[name], value)    # folded once
+    before = _fresh_fold_bytes.value
+    bad = dict(second, w1=np.ones((2, 2), np.float32))
+    with pytest.raises(ValueError):
+        service.PushGradientsStream(
+            _refilling(frame_chunks(1, 1, bad, chunks=1)), None)
+    assert _fresh_fold_bytes.value == before
+    state = core._iteration_states[1]
+    assert "w1" not in state.folded.get(1, ())
+    np.testing.assert_array_equal(state.accum["w1"], sums["w1"])
+    response = service.PushGradientsStream(
+        _refilling(frame_chunks(1, 1, second, chunks=2)), None)
+    assert response.aggregation_complete, response.message
+    for name, value in init.items():
+        mean = (first[name] + second[name]) * np.float32(0.5)
+        np.testing.assert_array_equal(core.get_parameters()[name],
+                                      value - mean, err_msg=name)
+
+
+@pytest.mark.lockcheck
+def test_a_retire_mid_fold_drops_the_moved_sum_as_today(frame_chunks):
+    """A reshard RETIRE landing while a borrowed chunk's adds run outside
+    the state lock (reserved before the fence, so the push is not stale):
+    the moved tensor's sum, kept buffer and all, is dropped when the fold
+    publishes, the rest of the chunk is in the accumulator, the NEXT push
+    of the name answers the stale-shard-map rejection, and the store no
+    longer holds it."""
+    from parameter_server_distributed_tpu.replication.messages import (
+        STALE_SHARD_MAP)
+
+    core = ParameterServerCore(total_workers=2, optimizer=SGD(1.0),
+                               stripes=3, aggregation="streaming")
+    rng = np.random.default_rng(41)
+    core.initialize_parameters(_random_grads(rng, KEPT))
+    grads = _random_grads(rng, KEPT)
+    inside, go = threading.Event(), threading.Event()
+    take = core._fold_buffers.take
+
+    def gated_take(name, shape):
+        if name == "w1":
+            inside.set()
+            assert go.wait(5.0)
+        return take(name, shape)
+
+    core._fold_buffers.take = gated_take
+    result = []
+    service = _make_service(core)
+    pusher = threading.Thread(target=lambda: result.append(
+        service.PushGradientsStream(
+            _refilling(frame_chunks(0, 1, grads, chunks=1)), None)))
+    pusher.start()
+    assert inside.wait(5.0)         # the fold is between its two locks
+    core.retire_tensors(["w1"], map_epoch=7)
+    go.set()
+    pusher.join(timeout=5.0)
+    assert not pusher.is_alive()
+    assert result[0].success, result[0].message
+    state = core._iteration_states[1]
+    assert "w1" not in state.accum and "w1" not in state.counts
+    assert set(state.accum) == set(KEPT) - {"w1"}
+    assert "w1" not in state.folded[0]
+    assert "w1" not in core.get_parameters()
+    core._fold_buffers.take = take
+    late = service.PushGradientsStream(
+        _refilling(frame_chunks(1, 1, grads, chunks=2)), None)
+    assert not late.success and STALE_SHARD_MAP in late.message
 
 
 # --------------------------------------------------- barrier_width TTL lock

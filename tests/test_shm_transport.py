@@ -337,6 +337,74 @@ def test_frame_view_is_read_only_and_to_array_owns_its_data():
         _cleanup(seg)
 
 
+def _stream_handler(core, tmp_path):
+    from parameter_server_distributed_tpu.checkpoint.manager import (
+        CheckpointManager)
+    from parameter_server_distributed_tpu.server.ps_service import (
+        ParameterServerService)
+
+    return ParameterServerService(core, CheckpointManager(
+        core, directory=str(tmp_path), checkpoint_interval=10**9,
+        check_period_s=3600.0)).PushGradientsStream
+
+
+@pytest.mark.parametrize("stripes", [1, 3], ids=["serial", "striped"])
+def test_a_borrowed_multi_chunk_push_leaves_the_pool_alone(tmp_path,
+                                                           stripes):
+    """A whole multi-chunk push read off a real ring as the server reads
+    it (the frame of chunk k still named while frame k+1 is read) and
+    folded where it lies: the handler copies nothing out of the frames
+    (``rpc.server.decode.copied_bytes`` stands), the sums are exact, and
+    from the second exchange on neither receive buffer is replaced: the
+    fold has let go of every view before the next frame is asked for."""
+    from parameter_server_distributed_tpu.core.optimizer import SGD
+    from parameter_server_distributed_tpu.core.ps_core import (
+        ParameterServerCore)
+
+    shapes = {f"layer{i}/w": (40, 25 + i) for i in range(8)}
+    rng = np.random.default_rng(41)
+    core = ParameterServerCore(total_workers=1, optimizer=SGD(1.0),
+                               stripes=stripes)
+    want = {n: rng.standard_normal(sh).astype(np.float32)
+            for n, sh in shapes.items()}
+    core.initialize_parameters(want)
+    handler = _stream_handler(core, tmp_path)
+    copied = obs_stats.counter("rpc.server.decode.copied_bytes")
+    seg, prod, cons = _ring_pair(capacity=CAP)
+    try:
+        def chunks():
+            frame = cons.read_frame(time.monotonic() + 30)
+            while frame is not None:
+                chunk = m.GradientUpdate.decode(frame)
+                yield chunk
+                frame = cons.read_frame(time.monotonic() + 30)
+
+        allocs = []
+        before = copied.value
+        for it in range(1, 5):
+            grads = {n: rng.standard_normal(sh).astype(np.float32)
+                     for n, sh in shapes.items()}
+            names = list(grads)
+            th = _send(prod, [m.GradientUpdate(
+                worker_id=0, iteration=it, gradients=[
+                    m.Tensor.from_array(n, grads[n])
+                    for n in names[lo:lo + 2]]).encode()
+                for lo in range(0, len(names), 2)])
+            response = handler(chunks(), None)
+            th.join(timeout=30)
+            assert response.aggregation_complete, response.message
+            allocs.append(_shm_counters()[1])
+            for n in want:
+                want[n] = want[n] - grads[n]
+                np.testing.assert_array_equal(core.get_parameters()[n],
+                                              want[n])
+        assert copied.value == before
+        assert allocs[1:] == [allocs[0]] * 3
+        assert not any(exported(b) for b in cons._pool._slots)
+    finally:
+        _cleanup(seg)
+
+
 def test_invalidate_during_read_falls_back_then_fails_cleanly():
     """``invalidate()`` under a reader in mid-frame: the rest of the frame
     comes through the memoryview path, and once the segment is unmapped

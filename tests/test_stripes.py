@@ -32,7 +32,7 @@ from parameter_server_distributed_tpu.core.tensor import to_wire
 from parameter_server_distributed_tpu.obs import stats as obs_stats
 from parameter_server_distributed_tpu.rpc import messages as m
 from parameter_server_distributed_tpu.rpc.data_plane import (
-    encode_parameter_record_groups, split_tensors)
+    decode_gradients, encode_parameter_record_groups, split_tensors)
 
 
 @pytest.fixture
@@ -238,6 +238,58 @@ def test_range_cut_close_matches_serial_bit_for_bit(arithmetic, make_opt,
                 np.testing.assert_array_equal(value[name], got[key][name])
         else:
             assert value == got[key]
+
+
+def _assert_same_state(want_core, got_core, shapes):
+    for name, shape in shapes.items():
+        got = got_core.get_parameters()[name]
+        assert got.shape == shape and got.dtype == np.float32
+        np.testing.assert_array_equal(want_core.get_parameters()[name], got)
+    want, got = want_core.optimizer_state(), got_core.optimizer_state()
+    assert want.keys() == got.keys()
+    for key, value in want.items():
+        if isinstance(value, dict):
+            assert value.keys() == got[key].keys()
+            for name in value:
+                np.testing.assert_array_equal(value[name], got[key][name])
+        else:
+            assert value == got[key]
+
+
+@pytest.mark.parametrize("make_opt", [
+    lambda: SGD(0.5), lambda: Momentum(0.1, momentum=0.9),
+    lambda: Adam(0.01)], ids=["sgd", "momentum", "adam"])
+@pytest.mark.parametrize("tasks", [1, 3], ids=["serial", "striped"])
+@pytest.mark.parametrize("contributors", [1, 3])
+def test_a_push_folded_from_read_only_views_lands_the_owned_path_bit_for_bit(
+        arithmetic, frame_chunks, make_opt, tasks, contributors):
+    """The streaming sink is lent the frames' own read-only views
+    (``decode_gradients(borrow=True)``) and sums them into kept buffers;
+    its twin is handed owned arrays, whole.  Parameters, slots and
+    ``state_dict`` agree bit for bit after three closes, and a frame
+    overwritten the moment its fold returned reaches nothing."""
+    rng = np.random.default_rng(41)
+    init = _grads(rng, MINI)
+    owned, lent = (ParameterServerCore(total_workers=contributors,
+                                       optimizer=make_opt(), stripes=tasks)
+                   for _ in range(2))
+    owned.initialize_parameters(init)
+    lent.initialize_parameters(init)
+    for it in range(1, 4):
+        pushes = [_grads(rng, MINI) for _ in range(contributors)]
+        for wid, grads in enumerate(pushes):
+            assert owned.receive_gradients(wid, it, dict(grads)).success
+            sink = lent.begin_push(wid, it)
+            assert sink.folds_at_once
+            for buf, chunk in frame_chunks(wid, it, grads):
+                views = decode_gradients(chunk.gradients, borrow=True)
+                assert not any(v.flags.writeable for v in views.values())
+                sink.fold(views)
+                del views, chunk
+                buf[:] = bytes(len(buf))    # the ring refills the buffer
+            r = sink.commit()
+        assert r.aggregation_complete, r.message
+    _assert_same_state(owned, lent, MINI)
 
 
 @every_host_rule

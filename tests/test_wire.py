@@ -310,3 +310,79 @@ def test_writer_output_is_plain_bytes(rng):
     assert type(buf) is bytes
     back = m.GradientUpdate.decode(buf)
     assert back.worker_id == 1 and back.gradients[0].name == "w"
+
+
+# ------------------------------------------ borrowed decode (PR 41)
+# A sink that folds at once is lent the frame's own views; everybody else
+# still gets what ``Tensor.to_array`` has always given.
+
+def _copied_bytes():
+    from parameter_server_distributed_tpu.obs import stats as obs_stats
+    return obs_stats.counter("rpc.server.decode.copied_bytes").value
+
+
+@pytest.mark.parametrize("wire_dtype", [m.WIRE_F32, m.WIRE_RAW_F32,
+                                        m.WIRE_BF16, m.WIRE_INT8,
+                                        m.WIRE_TOPK],
+                         ids=["f32", "raw_f32", "bf16", "int8", "topk"])
+def test_borrow_array_is_to_array_without_the_last_copy(rng, wire_dtype):
+    """Same values, shape and dtype as ``to_array`` on every wire; on the
+    float32 wire the borrowed array is a read-only view of the frame and
+    ``to_array`` beside it is still an owned, writable copy."""
+    arr = rng.standard_normal((6, 5)).astype(np.float32)
+    frame = m.Tensor.from_array("w", arr, wire_dtype=wire_dtype).encode()
+    decoded = m.Tensor.decode(frame)
+    owned, lent = decoded.to_array(), decoded.borrow_array()
+    assert lent.shape == owned.shape == (6, 5)
+    assert lent.dtype == owned.dtype == np.float32
+    np.testing.assert_array_equal(lent, owned)
+    assert owned.flags.writeable
+    assert not np.shares_memory(owned, lent)
+    if wire_dtype == m.WIRE_F32:
+        assert not lent.flags.writeable
+        assert np.shares_memory(lent, np.frombuffer(frame, np.uint8))
+        with pytest.raises(ValueError):
+            lent += 1.0
+    owned += 1.0    # the contract every other caller relies on
+
+
+def test_borrow_array_honours_the_float64_tag_and_a_scalar(rng):
+    arr = rng.standard_normal((4, 3))   # float64
+    decoded = m.Tensor.decode(m.Tensor.from_array("w", arr).encode())
+    lent = decoded.borrow_array()
+    assert lent.dtype == np.float64 and lent.shape == (4, 3)
+    np.testing.assert_array_equal(lent, decoded.to_array())
+    scalar = m.Tensor.decode(m.Tensor.from_array(
+        "s", np.float32(2.5)).encode())
+    assert scalar.borrow_array().shape == scalar.to_array().shape
+    assert float(scalar.borrow_array().reshape(-1)[0]) == 2.5
+
+
+@pytest.mark.parametrize("borrow", [False, True], ids=["owned", "borrowed"])
+def test_decode_gradients_counts_what_it_copies_out_of_a_frame(rng, borrow):
+    """Owned mode copies every float32-wire tensor out of its frame and
+    counts the bytes (``rpc.server.decode.copied_bytes``); borrowed mode
+    copies and counts nothing and hands out the frame's read-only views;
+    a packed wire makes new arrays either way and counts nothing."""
+    from parameter_server_distributed_tpu.rpc.data_plane import (
+        decode_gradients)
+
+    grads = {"a": rng.standard_normal((7, 3)).astype(np.float32),
+             "b": rng.standard_normal(11).astype(np.float32)}
+    frame = m.GradientUpdate(worker_id=1, iteration=2, gradients=[
+        m.Tensor.from_array(k, v) for k, v in grads.items()]).encode()
+    chunk = m.GradientUpdate.decode(frame)
+    before = _copied_bytes()
+    out = decode_gradients(chunk.gradients, borrow=borrow)
+    assert _copied_bytes() - before == (0 if borrow else 4 * (21 + 11))
+    for name, want in grads.items():
+        np.testing.assert_array_equal(out[name], want)
+        assert out[name].flags.writeable is (not borrow)
+        assert np.shares_memory(
+            out[name], np.frombuffer(frame, np.uint8)) is borrow
+    packed = m.GradientUpdate.decode(m.GradientUpdate(
+        worker_id=1, iteration=2, gradients=[m.Tensor.from_array(
+            "a", grads["a"], wire_dtype=m.WIRE_BF16)]).encode())
+    before = _copied_bytes()
+    out = decode_gradients(packed.gradients, borrow=borrow)
+    assert _copied_bytes() == before and out["a"].flags.writeable

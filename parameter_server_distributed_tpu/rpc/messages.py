@@ -133,7 +133,11 @@ class Tensor(Message):
         READ-ONLY view of the frame the tensor was decoded from, for a
         consumer that reads it once and lets go before the frame's
         buffer is filled again (whoever keeps the view keeps the buffer:
-        ``utils/buffers.exported``)."""
+        ``utils/buffers.exported``).  Two borrow it: the server's fold of
+        a streamed push (``decode_gradients(borrow=True)``) and the
+        worker's landing of a pull in the trainer's upload buffer
+        (``Worker._chunk_converter``).  A packed or float64 wire gives
+        the new, writable array it had to be unpacked or upcast into."""
         arr = self._decoded()
         if self.shape:
             arr = arr.reshape(self.shape)

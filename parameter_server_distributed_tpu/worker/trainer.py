@@ -33,7 +33,9 @@ import numpy as np
 
 from ..analysis.lock_order import checked_lock
 from ..core.tensor import TensorStore
+from ..obs import stats as obs_stats
 from ..obs import trace as obs_trace
+from ..utils.buffers import float32_over
 
 # One dispatch at a time per process: trainer-originated XLA work (step
 # launch, bucket slice fetches) may run from several threads at once —
@@ -45,6 +47,14 @@ from ..obs import trace as obs_trace
 # the client cannot handle.  D2H/compute overlap is unaffected: the lock
 # covers launching work, and async copies still complete in parallel.
 _DISPATCH_LOCK = checked_lock("trainer._DISPATCH_LOCK")
+
+# Bytes of served parameters that went to memory the worker had to
+# allocate (an upload buffer taken in place of a held one here; every
+# tensor a pull could not land, worker/worker.py), and bytes _pack copied
+# because a slot was not in place.  Both stand in steady state over the
+# f32 wire; both move by the payload for a store made elsewhere.
+_obs_fresh_bytes = obs_stats.counter("worker.pull.fresh_bytes")
+_obs_copied_bytes = obs_stats.counter("worker.pack.copied_bytes")
 
 
 class GradientBuckets:
@@ -212,6 +222,12 @@ class Trainer:
         self._padded_in = -(-self._packed_size // self._n_shard) * self._n_shard
         out_size = 1 + self._packed_size  # loss at offset 0
         self._padded_out = -(-out_size // self._n_shard) * self._n_shard
+        # The two flat float32 buffers the step uploads from, by turns.
+        # Kept as ``bytearray``s and never as arrays: whoever holds a view
+        # keeps the buffer (``utils/buffers.exported``), so the trainer
+        # makes its views per call and lets go of them.
+        self._pack_bufs: list[bytearray | None] = [None, None]
+        self._pack_turn = 0   # the buffer a store made elsewhere goes to
 
         layout = self._layout
         mesh = self._mesh
@@ -258,25 +274,78 @@ class Trainer:
             return jax.device_put(x, self._batch_sharded)
         return jax.tree.map(put, batch)
 
-    _pack_bufs: list[np.ndarray] | None = None
-    _pack_turn = 0
+    def _writable(self, i: int) -> np.ndarray:
+        """Upload buffer ``i`` as a flat float32 array that may be
+        written: the kept buffer when no view of it is alive (a store
+        somebody kept, a straggler's converter, a device array that
+        aliases it or a transfer still reading it), a new one in its
+        place otherwise, its padded tail zeroed once."""
+        kept = self._pack_bufs[i]
+        buf, flat = float32_over(kept, (self._padded_in,), _obs_fresh_bytes)
+        if buf is not kept:
+            flat[self._packed_size:] = 0
+            self._pack_bufs[i] = buf
+        return flat
+
+    def lend_store(self) -> dict[str, np.ndarray]:
+        """Where a pull may land the parameters the NEXT step uploads:
+        ``{name: writable float32 view of the layout's slot}`` in the
+        upload buffer whose turn is next (the other one may still be
+        aliased by the running step's input on the CPU client).  A store
+        made of these views is uploaded where it lies (:meth:`_pack`);
+        whoever keeps one, or any slice of it, keeps that buffer, and the
+        next loan of its turn allocates (``worker.pull.fresh_bytes``)."""
+        flat = self._writable(self._pack_turn)
+        return {name: flat[off:off + size].reshape(shape)
+                for name, off, size, shape, _dtype in self._layout}
+
+    def _not_in_place(self, params: Mapping[str, np.ndarray],
+                      buf: bytearray | None) -> list:
+        """The layout's slots that ``params`` does not already hold in
+        ``buf``: all but those whose array IS the slot's own memory
+        (same address, float32, contiguous, same size)."""
+        slots = [slot for slot in self._layout if slot[2]]
+        if buf is None:
+            return slots
+        base = np.frombuffer(buf, np.uint8).__array_interface__["data"][0]
+
+        def in_place(name, off, size, _shape, _dtype) -> bool:
+            a = params[name]
+            return (isinstance(a, np.ndarray) and a.dtype == np.float32
+                    and a.size == size and a.flags.c_contiguous
+                    and a.__array_interface__["data"][0] == base + 4 * off)
+
+        return [slot for slot in slots if not in_place(*slot)]
 
     def _pack(self, params: Mapping[str, np.ndarray]) -> np.ndarray:
-        # Persistent DOUBLE buffer instead of a fresh np.zeros every
-        # iteration: the padded tail stays zero from allocation and every
-        # layout slot is overwritten per call, so reuse is exact.  Two
-        # buffers alternate because the CPU PJRT client may ZERO-COPY a
-        # device_put numpy array (the device buffer aliases it): the
-        # buffer written this iteration must not be the one the previous
-        # iteration's upload may still alias.
-        if self._pack_bufs is None:
-            self._pack_bufs = [np.zeros(self._padded_in, np.float32)
-                               for _ in range(2)]
-        flat = self._pack_bufs[self._pack_turn]
-        self._pack_turn ^= 1
-        for name, off, size, _shape, _dtype in self._layout:
+        """``params`` as the flat float32 buffer the step takes.
+
+        A store landed in :meth:`lend_store`'s views is uploaded where it
+        lies: of the two buffers the one that needs the fewer bytes
+        copied is taken (its turn decides a tie) and only the slots not
+        in place are copied into it (``worker.pack.copied_bytes``).  A
+        store that lies in neither is packed whole into the buffer whose
+        turn it is, by the rule of :meth:`_writable`.
+
+        Two buffers alternate because the CPU PJRT client may ZERO-COPY
+        a device_put numpy array (the device buffer aliases it): the
+        buffer written for the next step, by a pull during this one or
+        by the copy here, is never the one this step uploaded."""
+        turn = self._pack_turn
+        copy = [self._not_in_place(params, buf) for buf in self._pack_bufs]
+        cost = [sum(slot[2] for slot in slots) for slots in copy]
+        if cost[turn ^ 1] < cost[turn]:
+            turn ^= 1
+        if cost[turn] < self._packed_size:
+            # part of the store lies here already: params holds the views
+            flat = np.frombuffer(self._pack_bufs[turn], np.float32)
+        else:
+            flat = self._writable(turn)
+        self._pack_turn = turn ^ 1
+        for name, off, size, _shape, _dtype in copy[turn]:
             flat[off:off + size] = np.asarray(
                 params[name], np.float32).ravel()
+        _obs_copied_bytes.add(4 * cost[turn])
         return flat
 
     def _dispatch_step(self, params: Mapping[str, np.ndarray], batch):

@@ -46,7 +46,7 @@ import grpc
 import numpy as np
 
 from ..config import WorkerConfig
-from ..core.tensor import TensorStore, from_wire, to_wire
+from ..core.tensor import TensorStore, to_wire
 from ..obs import flight
 from ..obs import stats as obs_stats
 from ..obs import trace as obs_trace
@@ -115,6 +115,10 @@ class Worker:
         # uniformly across the unary/stream/fused push paths
         self._obs_push_wire = obs_stats.counter(
             "rpc.client.push.wire_bytes")
+        # bytes of served parameters that went to memory this worker had
+        # to allocate (the trainer adds the upload buffers it had to take
+        # in place of held ones): stands in steady state over the f32 wire
+        self._obs_pull_fresh = obs_stats.counter("worker.pull.fresh_bytes")
         self._coordinator = RpcClient(config.coordinator_address,
                                       m.COORDINATOR_SERVICE, m.COORDINATOR_METHODS)
         self._ps: RpcClient | None = None
@@ -421,13 +425,41 @@ class Worker:
             return None
 
     # ------------------------------------------------------------ data plane
-    @staticmethod
-    def _chunk_converter(local: TensorStore):
-        """The ``on_chunk`` consumer of a pull: wire tensors of one chunk
-        to f32 arrays in ``local`` (the decode leg of the round)."""
+    def _chunk_converter(self, local: TensorStore):
+        """The ``on_chunk`` consumer of one attempt at a pull: wire
+        tensors of one chunk to arrays in ``local`` (the decode leg of
+        the round).
+
+        Where the trainer lends the buffer its next step uploads
+        (``Trainer.lend_store``) a served tensor goes from its frame into
+        its slot there in ONE pass, and ``local`` holds the slot: no new
+        memory, and the step then uploads the store where it lies.  The
+        frame's view (``Tensor.borrow_array``) is let go before the next
+        chunk is asked for.  A name the layout does not have, another
+        size, an empty tensor, or a trainer that lends nothing is
+        ``Tensor.to_array`` as ever, counted in
+        ``worker.pull.fresh_bytes``; so is the array a packed or float64
+        wire had to be unpacked into on its way to the slot.  The loan is
+        taken once per attempt: a straggler thread of a failed sharded
+        pull keeps its converter and with it that buffer, and the retry's
+        loan is another."""
+        lend = getattr(self.trainer, "lend_store", None)
+        dest = lend() if lend is not None else {}
+        fresh = self._obs_pull_fresh
+
         def convert_chunk(tensors) -> None:
             with obs_trace.span("rpc/client/decode", tensors=len(tensors)):
-                local.update(from_wire(tensors))
+                for t in tensors:
+                    slot = dest.get(t.name)
+                    src = None if slot is None else t.borrow_array()
+                    if src is None or src.size != slot.size or not src.size:
+                        local[t.name] = src = t.to_array()
+                        fresh.add(src.nbytes)
+                        continue
+                    if src.flags.writeable:
+                        fresh.add(src.nbytes)
+                    local[t.name] = slot = slot.reshape(src.shape)
+                    np.copyto(slot, src, casting="unsafe")
         return convert_chunk
 
     def pull_parameters(self, iteration: int) -> tuple[int, TensorStore]:
@@ -446,10 +478,6 @@ class Worker:
             # dict, never into this retry's
             local: TensorStore = {}
 
-            # f32 conversion per chunk AS IT ARRIVES, overlapping the
-            # transport of later chunks (rpc/data_plane.py on_chunk)
-            convert_chunk = self._chunk_converter(local)
-
             # Version-aware pull (delta/, ISSUE 10): advertise the held
             # version and let the PS answer O(changed bytes).  The
             # client returns None whenever the plain protocol must run
@@ -467,7 +495,9 @@ class Worker:
                 m.PullRequest(worker_id=self.config.worker_id,
                               iteration=iteration,
                               wire_dtype=self._pull_wire_dtype()),
-                timeout=30.0, on_chunk=convert_chunk)
+                # converted per chunk AS IT ARRIVES, overlapping the
+                # transport of later chunks (rpc/data_plane.py on_chunk)
+                timeout=30.0, on_chunk=self._chunk_converter(local))
             return resp, local
 
         resp, store = self.query_with_retry(attempt)

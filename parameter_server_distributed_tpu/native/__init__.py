@@ -78,7 +78,7 @@ def _build() -> str | None:
                 and os.path.getmtime(so_path) >= os.path.getmtime(_SRC)):
             return so_path
         base = ["g++", "-O3", "-ffp-contract=off", "-shared", "-fPIC",
-                "-std=c++17", "-o", so_path, _SRC]
+                "-pthread", "-std=c++17", "-o", so_path, _SRC]
         try:
             # -march=native lets the codec loops vectorize (the .so is
             # built on the machine that runs it — build_key() — so the
@@ -125,7 +125,16 @@ def _bind(path: str) -> ctypes.CDLL:
             log.warning("native library %s has no %s; that optimizer "
                         "uses numpy", path, name)
     # wire-codec kernels (rpc/codec.py NativeCodec)
-    lib.psdt_copy.argtypes = [ctypes.c_void_p, ctypes.c_void_p, i64]
+    try:
+        # the shm ring's span mover (copy_fn); a library built before it
+        # (an old build on a read-only install) lacks it, and the rings
+        # then move their spans through memoryviews
+        lib.psdt_ring_move.argtypes = [ctypes.c_void_p, i64, i64,
+                                       ctypes.c_void_p, i64, i32, i32]
+        lib.psdt_ring_move.restype = None
+    except AttributeError:
+        log.warning("native library %s has no psdt_ring_move; shm rings "
+                    "copy under the GIL", path)
     lib.psdt_pack_bf16.argtypes = [_F32P, i64, _U8P]
     lib.psdt_unpack_bf16.argtypes = [_U8P, i64, _F32P]
     lib.psdt_quant_int8.argtypes = [_F32P, i64, _U8P]
@@ -309,11 +318,18 @@ def _as_u8(buf) -> np.ndarray:
 
 
 def copy_fn():
-    """GIL-free bulk copy ``fn(dst_addr, src_addr, nbytes)`` (raw
-    addresses), or None without the native lib.  Used by the shm ring
-    transport so large copies overlap across threads."""
+    """GIL-free move of one span between a byte ring and the caller's
+    memory, ``fn(ring_addr, capacity, pos, mem_addr, nbytes, flags,
+    width)`` (raw addresses; ``ring_addr`` the ring's first payload byte;
+    ``pos + nbytes`` may pass ``capacity``: the span wraps; ``flags`` 1:
+    into the ring, else out of it, 2: with stores that go past the
+    cache), or None without the native lib.  The span is cut over
+    ``width`` threads, the caller and helpers the library keeps, and the
+    call returns when every piece is done; ``width`` 1 is one ``memcpy``
+    (two at the wrap) on the caller's thread.  The shm ring transport moves every span with it, so
+    the two ends of a ring copy beside each other."""
     native = lib()
-    return native.psdt_copy if native is not None else None
+    return getattr(native, "psdt_ring_move", None)
 
 
 def pack_bf16_native(src: np.ndarray, dst) -> bool:

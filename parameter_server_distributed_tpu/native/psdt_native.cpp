@@ -14,10 +14,22 @@
 // pure Python/numpy fallback when no compiler is available.  Disable with
 // PSDT_NATIVE=0 (the bench A/B knob).
 
+#include <pthread.h>
+#if defined(__SSE2__)
+#include <emmintrin.h>
+#endif
+
+#include <atomic>
+#include <chrono>
 #include <cmath>
 #include <cstdint>
 #include <cstring>
 #include <algorithm>
+#include <condition_variable>
+#include <deque>
+#include <mutex>
+#include <system_error>
+#include <thread>
 #include <vector>
 
 // ---------------------------------------------------------------------------
@@ -109,6 +121,164 @@ float radix_kth_abs(const float* src, const int64_t n, int64_t r) {
     float out;
     std::memcpy(&out, &bits, 4);
     return out;
+}
+
+
+// ---------------------------------------------------------------------------
+// The ring's wide move (rpc/shm_transport.py ShmRing._move).  A span of a
+// byte ring, wrap and all, is cut into pieces and moved by the caller and
+// a few helper threads the library keeps: fork-join inside ONE call, so
+// everything the caller holds for the call (a mapping, a cursor not yet
+// stored) holds for every piece.
+
+inline void cpu_relax() {
+#if defined(__x86_64__) || defined(__i386__)
+    __builtin_ia32_pause();
+#elif defined(__aarch64__)
+    asm volatile("yield");
+#endif
+}
+
+// memcpy whose stores go past the cache (the destination is read once,
+// later, by someone else: it need not push what the caller has there
+// out, nor be read for ownership first); plain memcpy where the ISA has
+// no such store.
+void stream_copy(uint8_t* dst, const uint8_t* src, const int64_t n) {
+#if defined(__SSE2__)
+    // up to the destination's first 16-byte boundary, then a cache line
+    // (four stores) a turn
+    int64_t i = std::min<int64_t>(
+        n, (16 - (reinterpret_cast<uintptr_t>(dst) & 15)) & 15);
+    std::memcpy(dst, src, static_cast<size_t>(i));
+    for (; i + 64 <= n; i += 64) {
+        const auto* from = reinterpret_cast<const __m128i*>(src + i);
+        auto* to = reinterpret_cast<__m128i*>(dst + i);
+        const __m128i a = _mm_loadu_si128(from);
+        const __m128i b = _mm_loadu_si128(from + 1);
+        const __m128i c = _mm_loadu_si128(from + 2);
+        const __m128i d = _mm_loadu_si128(from + 3);
+        _mm_stream_si128(to, a);
+        _mm_stream_si128(to + 1, b);
+        _mm_stream_si128(to + 2, c);
+        _mm_stream_si128(to + 3, d);
+    }
+    _mm_sfence();
+    std::memcpy(dst + i, src + i, static_cast<size_t>(n - i));
+#else
+    std::memcpy(dst, src, static_cast<size_t>(n));
+#endif
+}
+
+void plain_copy(uint8_t* dst, const uint8_t* src, const int64_t n) {
+    std::memcpy(dst, src, static_cast<size_t>(n));
+}
+
+struct RingSpan {
+    uint8_t* ring;     // first payload byte of the ring
+    int64_t cap;       // the ring's capacity
+    int64_t pos;       // where the span starts in the ring, < cap
+    uint8_t* mem;      // the caller's memory, from the span's first byte
+    bool into_ring;    // mem -> ring, else ring -> mem
+    bool stream;       // with stores past the cache
+};
+
+// bytes [a, b) of the span; the wrap may fall inside
+void move_piece(const RingSpan& s, const int64_t a, const int64_t b) {
+    const int64_t at = (s.pos + a) % s.cap;
+    const int64_t n = b - a;
+    const int64_t first = std::min(n, s.cap - at);
+    const auto copy = s.stream ? stream_copy : plain_copy;
+    if (s.into_ring) {
+        copy(s.ring + at, s.mem + a, first);
+        copy(s.ring, s.mem + a + first, n - first);
+    } else {
+        copy(s.mem + a, s.ring + at, first);
+        copy(s.mem + a + first, s.ring, n - first);
+    }
+}
+
+struct PieceTask {
+    const RingSpan* span;
+    int64_t a, b;
+    std::atomic<int>* left;   // the call's pieces still out
+};
+
+// Both ring ends of one process share it, so a call queues its pieces
+// and whoever is free takes them: a helper, or the caller itself once
+// its own piece is done (a pool that could start no thread, or lost its
+// threads to a fork, still finishes every call).  Spans follow each
+// other within microseconds while a frame moves, so a helper out of work
+// looks at `queued` for a little while before it sleeps on `work`, and a
+// caller at `left` before it sleeps on `done`: a futex wake-up costs a
+// good part of a piece's copy.  Never destroyed: the helpers sleep until
+// the process exits.
+struct Pool {
+    std::mutex mu;
+    std::condition_variable work;   // a piece was queued
+    std::condition_variable done;   // a call's last piece came in
+    std::deque<PieceTask> queue;
+    std::atomic<int> queued{0};     // queue.size(), readable without mu
+    int threads = 0;
+    int idle = 0;                   // helpers asleep on `work`
+};
+constexpr int kMaxHelpers = 16;
+constexpr auto kLook = std::chrono::microseconds(100);
+
+// look until `found()` or for kLook, whichever comes first
+template <class Found>
+bool look_for(Found found) {
+    const auto until = std::chrono::steady_clock::now() + kLook;
+    for (;;) {
+        for (int i = 0; i < 64; ++i) {
+            if (found()) return true;
+            cpu_relax();
+        }
+        if (std::chrono::steady_clock::now() >= until) return found();
+    }
+}
+
+Pool* g_pool = nullptr;
+std::once_flag g_pool_once;
+
+// the first queued piece, moved with the lock let go; false: none queued
+bool take_one(Pool& pool, std::unique_lock<std::mutex>& lock) {
+    if (pool.queue.empty()) return false;
+    const PieceTask task = pool.queue.front();
+    pool.queue.pop_front();
+    pool.queued.fetch_sub(1, std::memory_order_relaxed);
+    lock.unlock();
+    move_piece(*task.span, task.a, task.b);
+    // the caller may return, and `left` die, the moment this reads 0
+    const bool last = task.left->fetch_sub(1, std::memory_order_acq_rel) == 1;
+    lock.lock();
+    if (last) pool.done.notify_all();
+    return true;
+}
+
+void helper_loop(Pool* pool) {
+    std::unique_lock<std::mutex> lock(pool->mu);
+    for (;;) {
+        if (take_one(*pool, lock)) continue;
+        lock.unlock();
+        look_for([pool] {
+            return pool->queued.load(std::memory_order_relaxed) > 0; });
+        lock.lock();
+        if (pool->queue.empty()) {
+            ++pool->idle;
+            pool->work.wait(lock);
+            --pool->idle;
+        }
+    }
+}
+
+Pool* the_pool() {
+    std::call_once(g_pool_once, [] {
+        g_pool = new Pool;
+        // a forked child has the parent's pool and none of its threads
+        // (and perhaps its mutex, held): it starts over with its own
+        pthread_atfork(nullptr, nullptr, [] { g_pool = new Pool; });
+    });
+    return g_pool;
 }
 
 }  // namespace
@@ -211,13 +381,61 @@ void psdt_mean_sgd(float* param, const float** srcs, int32_t count,
     }
 }
 
-// Plain memcpy, exported so Python-side bulk copies (the shm transport
-// rings — rpc/shm_transport.py) run WITHOUT the GIL: ctypes releases it
-// around the call, so a colocated producer/consumer pair really overlaps
-// its copies, where memoryview slice assignment would convoy them a GIL
-// switch-interval at a time.
-void psdt_copy(uint8_t* dst, const uint8_t* src, const int64_t n) {
-    std::memcpy(dst, src, static_cast<size_t>(n));
+// One span between the caller's memory and a byte ring, cut over `width`
+// threads (the caller is one of them); returns when every byte has moved.
+// `pos + n` may pass `cap`: the span wraps.  `flags`: 1 the span goes INTO
+// the ring (else out of it), 2 with stores past the cache.  Pieces are
+// cut on 4 KB boundaries of the span; width 1 is a memcpy on the caller's
+// thread.
+// Exported so that the shm transport's rings (rpc/shm_transport.py) move
+// their bytes WITHOUT the GIL: ctypes releases it around the call, so a
+// colocated producer/consumer pair really overlaps its copies, where
+// memoryview slice assignment would convoy them a GIL switch-interval at
+// a time.
+void psdt_ring_move(uint8_t* ring, const int64_t cap, const int64_t pos,
+                    uint8_t* mem, const int64_t n, const int32_t flags,
+                    const int32_t width) {
+    const RingSpan span{ring, cap, pos % cap, mem, (flags & 1) != 0,
+                        (flags & 2) != 0};
+    const int64_t pieces = std::max<int64_t>(
+        1, std::min<int64_t>(width, n >> 12));
+    if (pieces == 1) {
+        move_piece(span, 0, n);
+        return;
+    }
+    const int64_t step = ((n + pieces - 1) / pieces + 4095) & ~int64_t{4095};
+    Pool& pool = *the_pool();
+    std::atomic<int> left{0};
+    std::unique_lock<std::mutex> lock(pool.mu);
+    for (int64_t a = step; a < n; a += step) {
+        pool.queue.push_back({&span, a, std::min(a + step, n), &left});
+        left.fetch_add(1, std::memory_order_relaxed);
+    }
+    const int queued = static_cast<int>(pool.queue.size());
+    pool.queued.store(queued, std::memory_order_relaxed);
+    // as many helpers as pieces were ever queued at once; those that are
+    // looking find the pieces themselves, sleepers are woken
+    while (pool.threads < std::min(queued, kMaxHelpers)) {
+        try {
+            std::thread(helper_loop, &pool).detach();
+            ++pool.threads;
+        } catch (const std::system_error&) {
+            break;  // no thread to be had: the callers move the pieces
+        }
+    }
+    for (int i = std::min(queued, pool.idle); i > 0; --i)
+        pool.work.notify_one();
+    lock.unlock();
+    move_piece(span, 0, step);
+    const auto all_in = [&left] {
+        return left.load(std::memory_order_acquire) == 0; };
+    lock.lock();
+    while (take_one(pool, lock)) {}  // what no helper has come for yet
+    lock.unlock();
+    if (look_for(all_in)) return;
+    lock.lock();
+    while (!all_in())
+        if (!take_one(pool, lock)) pool.done.wait(lock);
 }
 
 // ---------------------------------------------------------------------------

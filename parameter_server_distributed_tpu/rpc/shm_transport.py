@@ -26,9 +26,16 @@ u64 cursors in the segment header — ``tail`` (bytes ever written, owned
 by the producer) and ``head`` (bytes ever read, owned by the consumer) —
 plus a u32 ``closed`` latch either side may set.  A frame is a u32
 length prefix followed by payload bytes, wrapped modulo the ring
-capacity; frames larger than the ring stream through it in pieces,
-published in ~1 MB blocks so the consumer's copy-out overlaps the
-producer's copy-in.  The DOORBELL is a 1-byte nudge on a per-connection
+capacity; frames larger than the ring stream through it in SPANS:
+whatever is free (writing) or available (reading), up to a quarter of
+the ring, so the ring is a pipeline four spans deep and the consumer's
+copy-out overlaps the producer's copy-in.  A span moves in ONE native
+call (``native.copy_fn``: no GIL, the wrap handled inside, a span of
+2 MB or more cut over a few threads the library keeps and written past
+the cache), and the cursor is stored and the doorbell rung once a span;
+an end that finds less than 2 MB with more of the payload to come waits
+for that much rather than move a sliver.  The DOORBELL is a 1-byte nudge
+on a per-connection
 AF_UNIX socket (abstract namespace — no filesystem litter): after
 advancing a cursor the mover rings it, and a waiter parks in
 ``select`` — a real kernel wakeup, which matters twice: polling sleeps
@@ -42,7 +49,7 @@ latency blip, never a correctness problem (waits recheck the cursors).
 Produce side: a message bound for a ring is encoded INTO the ring
 (``ShmRing.write_message``): the ring writes the frame's length from the
 message's ``encoded_size()`` and hands ``encode_into`` a writer whose
-destination is the ring's own block loop, so a tensor's bytes go from
+destination is the ring's own span loop, so a tensor's bytes go from
 their source array to the ring in one copy and no frame-sized buffer
 exists on this side (at the sizes a store is chunked into, a new buffer
 per frame is new address space, and its page faults, not the copy, set
@@ -66,7 +73,10 @@ Env knobs: ``PSDT_SHM`` (default on; 0 disables both ends),
 ``PSDT_SHM_RING_BYTES`` (per-direction ring capacity, default 32 MB —
 frames larger than the ring stream through it).
 Observability: ``rpc.shm.bytes`` counts payload bytes moved through
-rings by this process; ``rpc.shm.frames`` the data frames read out of
+rings by this process, ``rpc.shm.wide_bytes`` the part of them that moved
+in spans cut over more than one thread (all but prefixes, headers, end
+markers and frames under 2 MB, where the machine has the cores);
+``rpc.shm.frames`` the data frames read out of
 one and ``rpc.shm.frame_allocs`` the receive buffers allocated or grown
 for them (0 once every frame size has been seen); ``rpc.shm.fallback``
 counts downgrades to TCP (refused negotiation, attach failure, or a
@@ -90,6 +100,7 @@ import numpy as np
 
 from .. import native
 from ..analysis.lock_order import checked_lock
+from ..core.stripes import usable_cores
 from ..obs import flight
 from ..obs import stats as obs_stats
 from ..obs import trace as obs_trace
@@ -102,7 +113,7 @@ T = TypeVar("T")
 
 ENV_FLAG = "PSDT_SHM"
 ENV_RING_BYTES = "PSDT_SHM_RING_BYTES"
-# Frames larger than the ring stream through it in blocks, so the ring
+# Frames larger than the ring stream through it in spans, so the ring
 # only needs to be big enough to decouple the two sides — and every ring
 # page is touched at negotiation (see _pretouch), so smaller also means
 # a shorter warm-up.
@@ -118,9 +129,26 @@ _OFF_HEAD = 8
 _OFF_CLOSED = 16
 
 _obs_bytes = obs_stats.counter("rpc.shm.bytes")
+_obs_wide = obs_stats.counter("rpc.shm.wide_bytes")
 _obs_frames = obs_stats.counter("rpc.shm.frames")
 _obs_frame_allocs = obs_stats.counter("rpc.shm.frame_allocs")
 _obs_fallback = obs_stats.counter("rpc.shm.fallback")
+
+
+# How a ring end cuts a payload (ShmRing._transfer, _move).  A span of _WIDE
+# bytes or more is cut over threads, none of which gets less than _PIECE,
+# and written with stores that go past the cache (its reader is another
+# core: a line left modified in this one's cache comes to it core to
+# core, slower than from memory); a shorter one (a length prefix, a
+# header, an end marker, a small tensor) is one memcpy on the caller's
+# thread.  No more threads than still pay on a host's memory
+# (scripts/ring_pace.py, PERF.md section 6) and no more than a quarter of
+# the cores: both ends of a ring, the fold and the D2H run beside each
+# other.
+_PIECE = 1 << 20
+_WIDE = 2 << 20
+_MAX_WIDTH = max(1, min(3, usable_cores() // 4))
+_STREAM = 2  # native.copy_fn's flag: stores that go past the cache
 
 
 def enabled() -> bool:
@@ -334,8 +362,8 @@ class ShmRing:
         self.capacity = capacity
         self._buf = shm.buf
         self.doorbell = doorbell
-        # Bulk copies go through the native GIL-FREE memcpy when the lib
-        # is available (native.copy_fn): a colocated producer/consumer
+        # Spans move through the native GIL-FREE call when the lib is
+        # available (native.copy_fn): a colocated producer/consumer
         # pair then overlaps its copies, where memoryview assignment
         # (the no-compiler fallback) convoys them under the GIL one
         # switch-interval at a time.  The raw base address stays valid
@@ -345,7 +373,7 @@ class ShmRing:
         self._copy = native.copy_fn()
         if self._copy is not None:
             carr = (ctypes.c_ubyte * len(shm.buf)).from_buffer(shm.buf)
-            self._base = ctypes.addressof(carr)
+            self._base = ctypes.addressof(carr) + _HEADER
             del carr  # export released; the address outlives it
         else:
             self._base = 0
@@ -368,11 +396,15 @@ class ShmRing:
     def _head(self) -> int:
         return struct.unpack_from("<Q", self._buf, _OFF_HEAD)[0]
 
+    # A cursor is stored as ONE 8-byte move (a slice assignment), never
+    # with ``struct.pack_into``, which zero-fills its destination before it
+    # packs: a peer in another process could read that zero (in-process
+    # the GIL hides it) and take it for a cursor behind its own.
     def _set_tail(self, v: int) -> None:
-        struct.pack_into("<Q", self._buf, _OFF_TAIL, v)
+        self._buf[_OFF_TAIL:_OFF_TAIL + 8] = struct.pack("<Q", v)
 
     def _set_head(self, v: int) -> None:
-        struct.pack_into("<Q", self._buf, _OFF_HEAD, v)
+        self._buf[_OFF_HEAD:_OFF_HEAD + 8] = struct.pack("<Q", v)
 
     @property
     def closed(self) -> bool:
@@ -393,7 +425,7 @@ class ShmRing:
         takes the memoryview path, whose released-buffer ``ValueError``
         is caught and surfaced as :class:`ShmTransportError` — a clean
         downgrade instead of a SIGSEGV at a stale ``_base``."""
-        self._base = 0  # zeroed FIRST: a racing block re-reads (base,
+        self._base = 0  # zeroed FIRST: a racing span re-reads (base,
         self._copy = None  # copy) and falls back once either is gone
 
     # ------------------------------------------------------------ doorbell
@@ -430,47 +462,79 @@ class ShmRing:
             else:
                 time.sleep(min(remaining, 200e-6))
 
-    # Copies are published in blocks of this size: the consumer starts
-    # draining block 0 while the producer copies block 1, so a large frame
-    # moves at ~memcpy speed instead of write-then-read serial (and no
-    # single GIL-holding copy starves the peer for the whole frame).
-    _BLOCK = 1 << 20
+    # ---------------------------------------------------------------- spans
+    def _transfer(self, into_ring: bool, mem, addr: int, total: int,
+                  deadline: float) -> None:
+        """Move ``total`` bytes between ``mem`` (whose address is ``addr``;
+        0 without the native library) and the ring, span by span: whatever
+        is free (writing) or available (reading), up to a quarter of the
+        ring, so that the peer works on one span while this end moves the
+        next.  The cursor is stored, and the doorbell rung, once a span,
+        after its bytes are in.  A payload's tail of less than ``_WIDE``
+        rides with its last span, and an end that finds less than
+        ``_WIDE`` (or all that is left) waits for that much (under
+        ``rpc/shm/wait``, like every wait) rather than move a sliver.
+        Neither end ever waits for more than half the ring, so the two
+        cannot wait for each other."""
+        cap = self.capacity
+        if into_ring:
+            mine, publish, what = self._tail(), self._set_tail, "writing"
+        else:
+            mine, publish, what = self._head(), self._set_head, "reading"
+        quarter = max(1, cap // 4)
+        rest = quarter + min(_WIDE, quarter)
+        moved = 0
+        while moved < total:
+            left = total - moved
+            want = left if left <= rest else quarter
+            least = want if want == left else min(want, _WIDE)
 
-    # ------------------------------------------------------------- produce
-    def _copy_in(self, pos: int, view, src, src_off: int, n: int) -> None:
-        # re-read the native fast path per block: invalidate() may have
-        # dropped it mid-frame (teardown racing a producer), and the
+            def ready() -> int:
+                found = (cap - (mine - self._head()) if into_ring
+                         else self._tail() - mine)
+                return found if found >= least else 0
+
+            n = min(want, self._wait(ready, deadline, what))
+            self._move(into_ring, mine % cap, mem, addr, moved, n)
+            mine += n
+            moved += n
+            publish(mine)
+            if self.doorbell is not None:
+                self.doorbell.ring()
+
+    def _move(self, into_ring: bool, pos: int, mem, addr: int, off: int,
+              n: int) -> None:
+        """One span between the ring at ``pos`` (it may wrap) and the
+        caller's memory: ``mem[off:off + n]``, whose address is ``addr``
+        (0 without the native library)."""
+        # re-read the native fast path per span: invalidate() may have
+        # dropped it mid-frame (teardown racing this end), and the
         # memoryview fallback fails CLEANLY on a released segment
         base, copy = self._base, self._copy
-        if src is not None and copy is not None and base:
-            copy(base + _HEADER + pos, src.ctypes.data + src_off, n)
-        else:
-            self._buf[_HEADER + pos:_HEADER + pos + n] = \
-                view[src_off:src_off + n]
+        if addr and copy is not None and base:
+            wide = n >= _WIDE
+            width = min(_MAX_WIDTH, n // _PIECE) if wide else 1
+            flags = into_ring | (_STREAM if wide else 0)
+            copy(base, self.capacity, pos, addr + off, n, flags, width)
+            if width > 1:
+                _obs_wide.add(n)
+            return
+        first = min(n, self.capacity - pos)
+        for at, a, b in ((_HEADER + pos, off, off + first),
+                         (_HEADER, off + first, off + n)):
+            if into_ring:
+                self._buf[at:at + b - a] = mem[a:b]
+            else:
+                mem[a:b] = self._buf[at:at + b - a]
 
+    # ------------------------------------------------------------- produce
     def _write_bytes(self, data, deadline: float) -> None:
         view = memoryview(data)
-        total = view.nbytes
         # the local ndarray keeps the source buffer alive for the call
         src = np.frombuffer(view, np.uint8) if self._copy is not None \
             else None
-        cap = self.capacity
-        tail = self._tail()
-        sent = 0
-        while sent < total:
-            free = self._wait(
-                lambda: cap - (tail - self._head()), deadline, "writing")
-            n = min(free, total - sent, self._BLOCK)
-            pos = tail % cap
-            first = min(n, cap - pos)
-            self._copy_in(pos, view, src, sent, first)
-            if n > first:
-                self._copy_in(0, view, src, sent + first, n - first)
-            tail += n
-            self._set_tail(tail)
-            if self.doorbell is not None:
-                self.doorbell.ring()
-            sent += n
+        self._transfer(True, view, src.ctypes.data if src is not None else 0,
+                       view.nbytes, deadline)
 
     @contextlib.contextmanager
     def _frame(self, **args):
@@ -534,37 +598,13 @@ class ShmRing:
         _obs_bytes.add(4)
 
     # ------------------------------------------------------------- consume
-    def _copy_out(self, out: bytearray, dst: int, dst_off: int, pos: int,
-                  n: int) -> None:
-        base, copy = self._base, self._copy  # see _copy_in
-        if dst and copy is not None and base:
-            copy(dst + dst_off, base + _HEADER + pos, n)
-        else:
-            out[dst_off:dst_off + n] = self._buf[_HEADER + pos:
-                                                 _HEADER + pos + n]
-
     def _read_into(self, out: bytearray, n: int, deadline: float) -> None:
-        """Fill ``out[:n]`` from the ring, block by block.  ``out`` is
-        this ring's own (the prefix scratch or a pool buffer nothing else
-        refers to), so its address holds for the call."""
-        dst = _address(out) if self._copy is not None else 0
-        done = 0
-        cap = self.capacity
-        head = self._head()
-        while done < n:
-            avail = self._wait(
-                lambda: self._tail() - head, deadline, "reading")
-            take = min(avail, n - done, self._BLOCK)
-            pos = head % cap
-            first = min(take, cap - pos)
-            self._copy_out(out, dst, done, pos, first)
-            if take > first:
-                self._copy_out(out, dst, done + first, 0, take - first)
-            head += take
-            self._set_head(head)
-            if self.doorbell is not None:
-                self.doorbell.ring()
-            done += take
+        """Fill ``out[:n]`` from the ring.  ``out`` is this ring's own (the
+        prefix scratch or a pool buffer nothing else refers to), so its
+        address holds for the call."""
+        self._transfer(False, out,
+                       _address(out) if self._copy is not None else 0, n,
+                       deadline)
 
     def read_frame(self, deadline: float) -> memoryview | None:
         """The next frame's payload, or None at an end-of-stream marker.
@@ -603,7 +643,7 @@ class ShmRing:
 class _RingWriter:
     """What a message's ``encode_into`` sees when its destination is a
     ring: ``wire._Writer``'s two methods over ``ShmRing._write_bytes``
-    (1 MB blocks, wrap, doorbell, the copy and wait legs as they are).
+    (spans, wrap, doorbell, the copy and wait legs as they are).
 
     A payload whose wire form already lies in memory is written from
     there (``ArrayPayload.wire_view``); one that needs a real pack goes
@@ -612,9 +652,9 @@ class _RingWriter:
 
     __slots__ = ("_ring", "_deadline", "_moving", "_encode_leg", "pos")
 
-    # a few blocks: a cast's pieces keep the consumer's copy-out running
-    # beside the next piece's pack
-    _SCRATCH = 4 * ShmRing._BLOCK
+    # a cast's pieces keep the consumer's copy-out running beside the
+    # next piece's pack, and each is a span wide enough to cut
+    _SCRATCH = 4 << 20
 
     def __init__(self, ring: ShmRing, deadline: float,
                  moving: contextlib.ExitStack, encode_leg: str):
@@ -980,7 +1020,7 @@ class _ServerConnection:
             self._released = True
         flight.record("shm.reap", a=self.index, b=1 if unmap else 0)
         # drop the raw-address fast path BEFORE any unmap: a racing
-        # block copy falls back to the memoryview, which fails cleanly
+        # span falls back to the memoryview, which fails cleanly
         for ring in (self.c2s, self.s2c):
             ring.invalidate()
         for shm in (self._c2s_shm, self._s2c_shm):

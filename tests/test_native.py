@@ -238,3 +238,89 @@ def test_a_library_without_an_entry_point_means_numpy_for_that_rule(
                                   out, 0.01, 0.9, 0.999, 1e-8, 1)
     np.testing.assert_array_equal(Adam(0.01).apply(params, grads)["w"],
                                   want["w"])
+
+
+# ------------------------------------------------- the ring's wide move
+
+def _ring_and_span(rng, cap, pos, n, offset):
+    """A ring of ``cap`` bytes, a span of ``n`` starting at ``pos`` (it may
+    wrap), the caller's memory at an odd ``offset`` into its buffer, and
+    what the ring must hold after the span went in."""
+    mem = rng.integers(0, 256, n + offset, dtype=np.uint8)[offset:]
+    want = np.zeros(cap, np.uint8)
+    want[(pos + np.arange(n)) % cap] = mem
+    return np.zeros(cap, np.uint8), mem, want
+
+
+@pytest.mark.parametrize("width", range(1, 9))
+def test_wide_ring_move_equals_memcpy(rng, width):
+    """Sizes 0 to 9 MB, the span flat, wrapping inside its first piece and
+    inside a later one, the caller's memory at odd alignments: into the
+    ring and back out, byte for byte, whatever the width."""
+    move = native.copy_fn()
+    for n in (0, 1, 4095, 4096, 4097, (1 << 20) + 1, (9 << 20) + 5):
+        cap = n + 12345
+        for pos, offset in ((0, 0), (cap - 7, 3), (cap - n // 2 - 1, 1)):
+            ring, mem, want = _ring_and_span(rng, cap, pos, n, offset)
+            move(ring.ctypes.data, cap, pos, mem.ctypes.data, n, 1, width)
+            np.testing.assert_array_equal(ring, want)
+            out = np.zeros(n + offset, np.uint8)[offset:]
+            move(ring.ctypes.data, cap, pos, out.ctypes.data, n, 0, width)
+            np.testing.assert_array_equal(out, mem)
+
+
+def test_wide_ring_moves_from_two_threads_at_once_finish_and_agree(rng):
+    """Both ring ends of one process share the library's helpers: two
+    callers at once each get their own bytes, and both come back."""
+    import threading
+
+    cap, n = 10 << 20, 8 << 20
+    move = native.copy_fn()
+    jobs = [_ring_and_span(np.random.default_rng(i), cap, (7 << 20) + i, n, i)
+            for i in range(2)]
+    failed = []
+
+    def caller(i):
+        ring, mem, want = jobs[i]
+        try:
+            for _ in range(20):
+                ring[:] = 0
+                move(ring.ctypes.data, cap, (7 << 20) + i, mem.ctypes.data,
+                     n, 1, 4)
+                np.testing.assert_array_equal(ring, want)
+        except AssertionError as exc:
+            failed.append(exc)
+
+    threads = [threading.Thread(target=caller, args=(i,), daemon=True)
+               for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads) and not failed
+
+
+def test_wide_ring_move_releases_the_gil():
+    """A Python thread makes progress beside a 400 MB call."""
+    import threading
+
+    n = 400 << 20
+    mem = np.ones(n, np.uint8)
+    ring = np.empty(n, np.uint8)    # untouched: the call faults it in
+    move = native.copy_fn()
+    started, done = threading.Event(), threading.Event()
+
+    def call():
+        started.set()
+        move(ring.ctypes.data, n, 0, mem.ctypes.data, n, 1, 2)
+        done.set()
+
+    thread = threading.Thread(target=call, daemon=True)
+    thread.start()
+    started.wait(10)
+    turns = 0
+    while not done.is_set():
+        turns += 1
+    thread.join(timeout=60)
+    assert done.is_set() and ring[-1] == 1
+    assert turns > 1000, turns

@@ -1012,3 +1012,175 @@ def test_encoder_raising_mid_frame_latches_the_rings(ps, monkeypatch, side):
         assert not client.shm_active
         np.testing.assert_allclose(params.parameters[0].to_array(),
                                    w0 - 0.10, rtol=1e-6)
+
+
+# ------------------------------------------------- spans and the wide move
+
+WIDE_CAP = 16 << 20              # a quarter of it, the span: 4 MB, 3 pieces
+SPAN = WIDE_CAP // 4
+WIDE_FRAMES = (0, 1, SPAN - 1, SPAN, SPAN + 1, 3 * WIDE_CAP + 7)
+# where the first frame starts in the ring: flat, so that the wrap falls
+# inside a span's first piece, and inside a later piece of a later span
+WIDE_STARTS = {"flat": 0, "wrap_in_first_piece": WIDE_CAP - 1000,
+               "wrap_in_later_piece": WIDE_CAP - SPAN - SPAN // 2 - 3}
+
+_CHILD_READER = """
+import hashlib, sys, time
+from parameter_server_distributed_tpu.rpc import shm_transport as st
+st._MAX_WIDTH = 3
+seg = st._attach_segment(sys.argv[1])
+ring = st.ShmRing(seg, int(sys.argv[2]),
+                  st._Doorbell(st._doorbell_connect(sys.argv[3])))
+while True:
+    frame = ring.read_frame(time.monotonic() + 120)
+    if frame is None:
+        break
+    print(len(frame), hashlib.sha256(frame).hexdigest(), flush=True)
+    del frame
+"""
+
+
+@pytest.mark.parametrize("offset", [0, 3], ids=["aligned", "view_at_3"])
+@pytest.mark.parametrize("start", list(WIDE_STARTS))
+@pytest.mark.parametrize("mode", ["threads", "processes", "memoryview"])
+def test_frames_through_the_wide_move_byte_for_byte(monkeypatch, mode,
+                                                    start, offset):
+    """Frames of 0, 1, span - 1, span, span + 1 and 3 x ring + 7 bytes,
+    from sources at an odd address, through a ring whose spans are cut over
+    three threads: between two threads, between two processes, and through
+    the memoryview path (no native library), which moves the same spans."""
+    import hashlib
+    import subprocess
+    import sys
+
+    from parameter_server_distributed_tpu import native
+
+    if mode == "memoryview":
+        monkeypatch.setattr(native, "copy_fn", lambda: None)
+    elif native.copy_fn() is None:
+        pytest.skip("no native library on this machine")
+    monkeypatch.setattr(st, "_MAX_WIDTH", 3)
+    rng = np.random.default_rng(len(start) + offset)
+    backing = rng.integers(0, 256, max(WIDE_FRAMES) + offset, dtype=np.uint8)
+    payloads = [memoryview(backing)[offset:offset + n] for n in WIDE_FRAMES]
+    seg = st._create_segment(f"psdt-test-{time.monotonic_ns()}",
+                             64 + WIDE_CAP)
+    child = None
+    try:
+        # where the first frame starts, set before any end looks
+        blank = st.ShmRing(seg, WIDE_CAP)
+        blank._set_head(WIDE_STARTS[start])
+        blank._set_tail(WIDE_STARTS[start])
+        del blank
+        if mode == "processes":
+            listener, addr = st._doorbell_listener()
+            child = subprocess.Popen(
+                [sys.executable, "-c", _CHILD_READER, seg.name,
+                 str(WIDE_CAP), addr], stdout=subprocess.PIPE, text=True)
+            listener.settimeout(60)
+            sock, _ = listener.accept()
+            listener.close()
+            prod, cons = st.ShmRing(seg, WIDE_CAP, st._Doorbell(sock)), None
+        else:
+            a, b = socket.socketpair(socket.AF_UNIX, socket.SOCK_STREAM)
+            prod = st.ShmRing(seg, WIDE_CAP, st._Doorbell(a))
+            cons = st.ShmRing(seg, WIDE_CAP, st._Doorbell(b))
+            assert (cons._copy is None) == (mode == "memoryview")
+        wide = obs_stats.counter("rpc.shm.wide_bytes")
+        before = wide.value
+        th = _send(prod, payloads)
+        if cons is not None:
+            got = _consume_group(cons)
+            assert [len(g) for g in got] == list(WIDE_FRAMES)
+            assert all(g == p for g, p in zip(got, payloads))
+        else:
+            said, _ = child.communicate(timeout=120)
+            assert child.returncode == 0
+            assert said.split() == [
+                word for p in payloads
+                for word in (str(len(p)), hashlib.sha256(p).hexdigest())]
+        th.join(timeout=60)
+        assert not th.is_alive()
+        if mode == "memoryview":
+            assert wide.value == before
+        else:   # every span of the four frames of a span or more
+            assert wide.value - before >= (2 - (cons is None)) * sum(
+                n for n in WIDE_FRAMES if n >= SPAN)
+    finally:
+        if child is not None and child.poll() is None:
+            child.kill()
+        del prod, cons
+        _cleanup(seg)
+
+
+def test_wide_bytes_count_large_payloads_at_both_ends_and_no_control_frames(
+        monkeypatch):
+    """``rpc.shm.wide_bytes`` grows by a 64 MB payload's bytes at the
+    writing end and again at the reading end (its length prefix is not
+    wide), and by nothing for a run of 200-byte control frames."""
+    from parameter_server_distributed_tpu import native
+
+    if native.copy_fn() is None:
+        pytest.skip("no native library on this machine")
+    monkeypatch.setattr(st, "_MAX_WIDTH", 2)
+    wide = obs_stats.counter("rpc.shm.wide_bytes")
+    moved = obs_stats.counter("rpc.shm.bytes")
+    seg, prod, cons = _ring_pair(capacity=WIDE_CAP)
+    try:
+        big = np.random.default_rng(7).bytes(64 << 20)
+        for payloads, grows in (([big], 2 * len(big)),
+                                ([bytes([i]) * 200 for i in range(50)], 0)):
+            wide_before, moved_before = wide.value, moved.value
+            th = _send(prod, payloads)
+            assert _consume_group(cons) == payloads
+            th.join(timeout=60)
+            assert not th.is_alive()
+            assert wide.value - wide_before == grows
+            assert moved.value - moved_before == 2 * (
+                sum(4 + len(p) for p in payloads) + 4)
+    finally:
+        _cleanup(seg)
+
+
+@pytest.mark.parametrize("how", ["invalidate_then_unmap", "close"])
+def test_teardown_under_a_writer_in_mid_frame_fails_cleanly(how):
+    """A writer parked in the middle of a frame larger than the ring:
+    ``invalidate()`` sends the rest of its spans through the memoryview
+    path, which fails as ShmTransportError once the segment is unmapped
+    (never a native call at a stale address: the ISSUE 8 rule);
+    ``close()`` wakes it with the same error."""
+    seg, prod, cons = _ring_pair(capacity=CAP)
+    payload = np.random.default_rng(11).bytes(3 * CAP)
+    errs = []
+
+    def writer():
+        try:
+            _write(prod, payload, time.monotonic() + 30)
+        except st.ShmTransportError as exc:
+            errs.append(exc)
+
+    try:
+        def written(least):
+            deadline = time.monotonic() + 20
+            while prod._tail() < least and time.monotonic() < deadline:
+                time.sleep(0.001)
+            assert prod._tail() >= least
+
+        th = threading.Thread(target=writer, daemon=True, name="t-prod")
+        th.start()
+        written(CAP - CAP // 4)     # less than a span is free: it parks
+        if how == "close":
+            cons.close()
+        else:
+            prod.invalidate()
+            assert prod._copy is None and prod._base == 0
+            # a quarter ring through the memoryview path, then the unmap
+            cons._read_into(bytearray(CAP // 2), CAP // 2,
+                            time.monotonic() + 30)
+            written(CAP + CAP // 4)
+            cons.invalidate()
+            seg.close()
+        th.join(timeout=10)
+        assert not th.is_alive() and len(errs) == 1
+    finally:
+        _cleanup(seg)

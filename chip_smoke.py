@@ -592,12 +592,10 @@ def phase_kernels(run: Run) -> dict:
 
     from parameter_server_distributed_tpu.models.transformer import (
         causal_attention)
-    from parameter_server_distributed_tpu.ops.pallas.flash_attention import (
-        flash_attention_gqa)
+    from parameter_server_distributed_tpu.ops.pallas.fused_attention import (
+        fused_causal_attention)
     from parameter_server_distributed_tpu.ops.pallas.fused_update import (
         fused_adam, fused_momentum, fused_sgd)
-    from parameter_server_distributed_tpu.ops.xla_flash import (
-        make_xla_flash_attention)
 
     sizes = run.sizes
     dtype = jnp.dtype(sizes.attn_dtype)
@@ -614,7 +612,6 @@ def phase_kernels(run: Run) -> dict:
         ref = np.asarray(ref, np.float32)
         return _max_abs_diff(x, ref) / float(np.max(np.abs(ref)))
 
-    xla_flash = make_xla_flash_attention()
     for heads, kv_heads, head_dim in ATTENTION_SHAPES:
         shape = f"h{heads}_kv{kv_heads}_d{head_dim}"
         q = normal(sizes.attn_batch, sizes.attn_seq, heads, head_dim)
@@ -631,28 +628,25 @@ def phase_kernels(run: Run) -> dict:
         ref_out = causal_attention(*exact)
         ref_grads = jax.grad(lambda *a: loss(causal_attention, *a),
                              argnums=(0, 1, 2))(*exact)
-        for impl, fn, mosaic in (("flash", flash_attention_gqa, True),
-                                 ("xla_flash", xla_flash, False)):
-            out, fwd_text, s1 = _compile_and_run(fn, *low)
-            grads, bwd_text, s2 = _compile_and_run(
-                jax.grad(lambda *a, fn=fn: loss(fn, *a), argnums=(0, 1, 2)),
-                *low)
-            steady += s1 + s2
-            errors = {"out": rel_err(out, ref_out),
-                      **{name: rel_err(g, r) for name, g, r
-                         in zip(("dq", "dk", "dv"), grads, ref_grads)}}
-            entry = {k_: float(f"{e:.3g}") for k_, e in errors.items()}
-            if mosaic:
-                entry["tpu_custom_calls"] = {
-                    "forward": _require_mosaic(
-                        run, f"flash {shape} forward", fwd_text, 1),
-                    # forward + dQ + dK/dV kernels
-                    "backward": _require_mosaic(
-                        run, f"flash {shape} backward", bwd_text, 3)}
-            checked[f"{impl}_{shape}"] = entry
-            if not max(errors.values()) <= tol:
-                raise AssertionError(
-                    f"{impl} {shape}: {errors} exceeds {tol}")
+        # the default path's kernel (models/transformer.device_arm)
+        out, fwd_text, s1 = _compile_and_run(fused_causal_attention, *low)
+        grads, bwd_text, s2 = _compile_and_run(
+            jax.grad(lambda *a: loss(fused_causal_attention, *a),
+                     argnums=(0, 1, 2)), *low)
+        steady += s1 + s2
+        errors = {"out": rel_err(out, ref_out),
+                  **{name: rel_err(g, r) for name, g, r
+                     in zip(("dq", "dk", "dv"), grads, ref_grads)}}
+        checked[f"kernel_{shape}"] = {
+            **{k_: float(f"{e:.3g}") for k_, e in errors.items()},
+            "tpu_custom_calls": {
+                "forward": _require_mosaic(
+                    run, f"kernel {shape} forward", fwd_text, 1),
+                # forward + dQ + dK/dV kernels
+                "backward": _require_mosaic(
+                    run, f"kernel {shape} backward", bwd_text, 3)}}
+        if not max(errors.values()) <= tol:
+            raise AssertionError(f"kernel {shape}: {errors} exceeds {tol}")
 
     p = {"w": normal(*sizes.fused_shape)}
     g = {"w": normal(*sizes.fused_shape)}

@@ -7,14 +7,13 @@
         --mesh=data:2,fsdp:2,tensor:2 --ckpt-dir=/tmp/ckpt --ckpt-every=50 \
         --ckpt-keep=3 --resume --metrics=/tmp/metrics.jsonl
 
-``--attention=dense|flash|xla_flash|ring|ulysses|ulysses_flash|
-ulysses_xla_flash`` selects the attention implementation for transformer
-models: flash = pallas kernels (shard_mapped over batch/head shards when
-the mesh is >1 device), xla_flash = the same blockwise recurrence as a
-compiled lax.scan (any backend), ring/ulysses = sequence parallelism
-over the mesh's seq axis (pair with --mesh=seq:N); ulysses_flash /
-ulysses_xla_flash run the pallas kernel / the lax.scan recurrence on
-each device's gathered full sequence.
+``--attention=dense|ring|ulysses`` says how a transformer model's
+attention uses the mesh's seq axis: dense = the sequence is not split,
+ring / ulysses = sequence parallelism over the seq axis (pair with
+--mesh=seq:N; K/V rotated around the ring, or an all-to-all that swaps
+seq for heads).  Which implementation then runs on a device (the pallas
+kernel, blockwise in plain XLA, the einsum) follows from the shapes the
+device sees (models/transformer.device_arm); no flag chooses it.
 
 ``--dtype=bf16`` trains in bfloat16 (f32 MXU accumulation) for models
 whose factory takes a dtype; ``--remat`` recomputes layer activations in
@@ -52,8 +51,8 @@ one-forward-one-backward — O(P) instead of O(M) in-flight activations).
 ``--virtual-stages=V`` (with 1f1b) runs the Megatron INTERLEAVED
 schedule: each rank holds V round-robin layer chunks, shrinking the
 pipeline bubble ~V-fold at V x the ppermute count.  Requires n_layers
-divisible by P*V; combine with data:N.  ``--attention`` may be dense or
-flash inside pipeline stages.
+divisible by P*V; combine with data:N.  ``--attention`` stays dense
+with a pipe axis (a stage attends through the einsum).
 
 ``--ema=0.999`` tracks a Polyak/EMA shadow of the parameters at that
 decay inside the optimizer state (checkpointed and sharded like any

@@ -481,7 +481,7 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                 jax.named_scope("window" if spec.window else "full"):
             if (not quant and t >= _BLOCKWISE_QUERIES
                     and cache.max_len >= model.BLOCKWISE_FROM):
-                from ..ops.xla_flash import blockwise_attention
+                from ..ops.blockwise_attention import blockwise_attention
 
                 by_head = keys.shape[:2] + (c.kv_heads, c.head_dim)
                 return blockwise_attention(

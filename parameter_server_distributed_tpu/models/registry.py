@@ -82,7 +82,7 @@ REGISTRY: dict[str, tuple[Callable, Callable[[int, int], Iterator], str]] = {
     "lm_350m_gqa": (partial(lm_350m, kv_heads=4), _lm_350m_batches,
                     "tokens"),
     # head_dim-128 flagship: 8 heads x 128 — a full MXU tile per
-    # attention matmul (the flash kernel's preferred shape)
+    # attention matmul, one head a row of lanes in the attention kernel
     "lm_350m_hd128": (partial(lm_350m, n_heads=8), _lm_350m_batches,
                       "tokens"),
     # LLaMA-architecture flagship (SwiGLU + GQA): the shape from_hf_llama

@@ -24,7 +24,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
-import logging
 import math
 from functools import partial
 from typing import Callable, Mapping
@@ -36,8 +35,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .moe import moe_expert_weight_spec
 from .quant import wdot
-
-log = logging.getLogger("pst.models")
 
 Array = jax.Array
 
@@ -422,156 +419,67 @@ def rope(x: Array, positions: Array, theta: float = 10000.0) -> Array:
     return out.astype(x.dtype)
 
 
-def flash_attention_auto(q: Array, k: Array, v: Array) -> Array:
-    """Causal attention that uses the pallas flash kernels
-    (ops/pallas/flash_attention.py — blockwise fwd+bwd, O(S) residual
-    memory) when the sequence is block-divisible, falling back to the dense
-    einsum otherwise.  GQA K/V stay UNexpanded: the grouped-query kernel
-    folds query groups into the block batch, so K/V HBM stays
-    kv_heads-sized end to end (fwd blocks and dK/dV alike).  On a CPU
-    backend the kernels run in interpret mode, so this is only worth
-    selecting on an accelerator; pass it explicitly as
-    ``Transformer(config, attention_fn=flash_attention_auto)`` or set
-    ``PSDT_FLASH_ATTENTION=1`` to make it the model default.
-
-    ``PSDT_FLASH_BLOCK_Q`` / ``PSDT_FLASH_BLOCK_K`` (default 128) tune
-    the kernel tile sizes without a code change — larger K blocks raise
-    arithmetic intensity per HBM fetch at O(block_q*block_k) VMEM cost;
-    the sequence must divide by both."""
-    import os
-
-    from ..ops.pallas.flash_attention import flash_attention_gqa
-
-    # `or "128"`: an EMPTY env value means unset (shell idiom VAR= ),
-    # matching the package's other PSDT_ flags; non-numeric fails loudly
-    block_q = int(os.environ.get("PSDT_FLASH_BLOCK_Q") or "128")
-    block_k = int(os.environ.get("PSDT_FLASH_BLOCK_K") or "128")
-    seq = q.shape[1]
-    if seq % block_q == 0 and seq % block_k == 0:
-        return flash_attention_gqa(q, k, v, block_q=block_q,
-                                   block_k=block_k)
-    return causal_attention(q, k, v)
-
-
-def make_sharded_flash_attention(mesh: Mesh,
-                                 batch_axes: tuple[str, ...] = ("data", "fsdp"),
-                                 head_axis: str = "tensor",
-                                 inner: Callable | None = None) -> Callable:
-    """Pallas flash attention composed with a mesh: shard_map over the
-    batch and head axes, each device running the single-shard kernel
-    ``inner`` (:func:`flash_attention_auto` where none is given) on its
-    full-sequence [B/n, S, H/n, D] block.  Causal attention is
-    independent across batch and heads, so this is exact.
+def shard_over_batch_and_heads(mesh: Mesh, inner: Callable,
+                               batch_axes: tuple[str, ...] = ("data", "fsdp"),
+                               head_axis: str = "tensor") -> Callable:
+    """A per-device attention composed with a mesh: shard_map over the
+    batch and head axes, each device running ``inner`` on its
+    full-sequence [B/n, S, H/n, D] block.  Causal attention is independent
+    across batch and heads, so this is exact.
 
     The sequence axis must NOT be sharded here — XLA all-gathers seq-sharded
     activations to satisfy the in_specs; for a real ``seq`` axis use ring or
     Ulysses attention (ops/ring_attention.py) instead.  Heads must divide by
     the ``tensor`` axis when that axis is >1 (shard_map divisibility)."""
-    from functools import partial as _partial
-
     from jax import shard_map
 
-    inner = inner or flash_attention_auto
     heads_spec = head_axis if mesh.shape.get(head_axis, 1) > 1 else None
     spec = PartitionSpec(batch_axes, None, heads_spec, None)
 
-    @_partial(shard_map, mesh=mesh, in_specs=(spec, spec, spec),
-              out_specs=spec, check_vma=False)
-    def sharded_flash(q, k, v):
+    @partial(shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+             out_specs=spec, check_vma=False)
+    def sharded(q, k, v):
         return inner(q, k, v)
 
     n_tp = mesh.shape.get(head_axis, 1)
 
-    def sharded_flash_gqa(q, k, v):
+    def sharded_gqa(q, k, v):
         k, v = prepare_gqa_kv(q, k, v, n_tp)
-        return sharded_flash(q, k, v)
+        return sharded(q, k, v)
 
-    return sharded_flash_gqa
+    return sharded_gqa
 
 
-ATTENTION_CHOICES = ("dense", "flash", "xla_flash", "ring", "ulysses",
-                     "ulysses_flash", "ulysses_xla_flash")
+ATTENTION_CHOICES = ("dense", "ring", "ulysses")
+
+
+def check_attention(name: str) -> None:
+    if name not in ATTENTION_CHOICES:
+        raise ValueError(
+            f"unknown attention {name!r}; options {ATTENTION_CHOICES}")
 
 
 def select_attention(name: str, mesh: Mesh | None) -> Callable | None:
-    """Attention implementation by name (the ``--attention`` CLI switch).
+    """What a model's ``attention_fn`` is for a name of the ``--attention``
+    switch.  A name says how the ``seq`` axis is used; which
+    implementation runs on a device follows from the shapes it sees there
+    (:func:`device_arm`).
 
-    dense   — the model's default path (``Transformer.attend``), which
-              chooses by shape (``default_arm``): on a TPU one blockwise
-              pallas kernel that writes no [B, H, S, S] array
-              (ops/pallas/fused_attention.py; under shard_map over batch
-              and heads with a mesh), elsewhere the einsum (GSPMD
-              partitions it) or, long and mesh-less, ops/xla_flash.  On a
-              v5e at [64, 1024, 16, 64], 24 layers forward + backward:
-              481 ms against the einsum's 1,419 (PERF.md, PR 30)
-    flash   — the earlier pallas flash kernels, by name only; with a mesh,
-              shard_mapped over batch/head shards (seq must be unsharded).
-              Same micro-program: 3,049 ms at its 128 blocks, 776 at 512
-    xla_flash — the same blockwise online-softmax recurrence in plain
-              lax.scan (ops/xla_flash.py): compiled natively on every
-              backend, O(S) residuals via per-block remat; the long-
-              context path where pallas is unavailable.  Same
-              micro-program: 2,696 ms
-    ring    — ring attention over the mesh's ``seq`` axis (K/V ppermute)
-    ulysses — all-to-all seq<->heads swap, dense attention per head shard
-    ulysses_flash — same swap, pallas flash kernel on the gathered
-              full sequence (seq parallelism + O(block^2) VMEM)
-    ulysses_xla_flash — same swap, the lax.scan flash recurrence on the
-              gathered sequence (compiled on every backend)
-
-    Returns None for dense (the Transformer default), letting the model
-    pick its arm from the shape."""
+    dense   — the sequence is not split: None, the model's default path
+              (``Transformer.attend``)
+    ring    — split over the mesh's ``seq`` axis, K/V rotated (ppermute)
+    ulysses — split over ``seq``, an all-to-all swaps seq <-> heads and a
+              device attends whole sequences of its share of the heads by
+              :func:`device_arm`"""
+    check_attention(name)
     if name == "dense":
         return None
-    if name == "flash":
-        if mesh is None:
-            return flash_attention_auto
-        return make_sharded_flash_attention(mesh)
-    if name == "xla_flash":
-        from ..ops.xla_flash import make_xla_flash_attention
-        # plain einsums + scan: with a mesh, GSPMD partitions it over the
-        # batch/head axes exactly like dense — no shard_map needed
-        return make_xla_flash_attention()
-    if name in ("ring", "ulysses", "ulysses_flash", "ulysses_xla_flash"):
-        if mesh is None:
-            raise ValueError(f"--attention={name} needs a mesh with a seq axis")
-        from ..ops.ring_attention import (make_ring_attention,
-                                          make_ulysses_attention)
-        if name == "ring":
-            return make_ring_attention(mesh)
-        if name == "ulysses_flash":
-            # pallas flash on each device's gathered full sequence
-            return make_ulysses_attention(mesh, inner=flash_attention_auto)
-        if name == "ulysses_xla_flash":
-            # the lax.scan flash recurrence on the gathered sequence —
-            # compiled on every backend (ops/xla_flash.py)
-            from ..ops.xla_flash import make_xla_flash_attention
-            return make_ulysses_attention(mesh,
-                                          inner=make_xla_flash_attention())
-        return make_ulysses_attention(mesh)
-    raise ValueError(f"unknown attention {name!r}; options {ATTENTION_CHOICES}")
-
-
-def _default_attention() -> Callable:
-    """PSDT_FLASH_ATTENTION=1 opts the model default into the pallas flash
-    path wherever the kernels compile (ops/pallas.interpret_mode).  On a
-    CPU backend they would run interpreted — orders of magnitude slower
-    than the einsum, which is for tests to opt into explicitly, never a
-    shared launch env flag — so there the flag is refused with a warning."""
-    import os
-
-    if os.environ.get("PSDT_FLASH_ATTENTION", "") in ("", "0"):
-        return causal_attention
-    from ..ops.pallas import interpret_mode
-
-    if interpret_mode():
-        log.warning(
-            "PSDT_FLASH_ATTENTION is set but the backend is %s: the pallas "
-            "kernels would run interpreted, using dense attention (pass "
-            "attention_fn=flash_attention_auto to force them)",
-            jax.devices()[0].platform)
-        return causal_attention
-    return flash_attention_auto
+    if mesh is None:
+        raise ValueError(f"--attention={name} needs a mesh with a seq axis")
+    from ..ops.ring_attention import (make_ring_attention,
+                                      make_ulysses_attention)
+    make = make_ring_attention if name == "ring" else make_ulysses_attention
+    return make(mesh)
 
 
 def _kernel_backend() -> bool:
@@ -609,7 +517,7 @@ def prepare_gqa_kv(q: Array, k: Array, v: Array,
     pre-expand K/V to the query head count so shard_map head specs stay
     satisfiable (MQA + tensor parallelism); all other configs keep the
     small kv_heads-sized transfers.  The single home for this rule,
-    shared by the ring/Ulysses/sharded-flash wrappers."""
+    shared by the ring, Ulysses and batch-and-head shard_map wrappers."""
     if q.shape[2] % k.shape[2]:
         raise ValueError(f"query heads {q.shape[2]} must divide by "
                          f"kv heads {k.shape[2]}")
@@ -637,6 +545,53 @@ def causal_attention(q: Array, k: Array, v: Array,
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v,
                       preferred_element_type=jnp.float32).astype(v.dtype)
+
+
+def device_arm(q_shape: tuple[int, ...], kv_shape: tuple[int, ...],
+               window: int = 0) -> str:
+    """Which implementation attends whole sequences ON ONE DEVICE, from
+    the shapes the device holds (q [B, S, H, D], k/v [B, S, KV, D]), the
+    window that binds (0: none) and the backend; the one place that knows:
+
+    ``kernel``    — the blockwise kernel of ops/pallas/fused_attention.py,
+                    which writes no [B, H, S, S] array: on a TPU, where no
+                    window binds, q and k cover the same positions, the
+                    shape tiles (heads of 64 or 128 that fill rows of 128
+                    lanes, positions in blocks of 128) and there is more
+                    than one sequence or a long one (a single prompt
+                    shorter than ``Transformer.BLOCKWISE_FROM`` is faster
+                    through the einsum: PERF.md, PR 30);
+    ``blockwise`` — ops/blockwise_attention.py in plain XLA (scores of a
+                    block at a time, blocks wholly outside the mask
+                    skipped): any other sequence of ``BLOCKWISE_FROM``
+                    positions or more;
+    ``dense``     — the einsum (:func:`causal_attention`), the rest."""
+    blockwise_from = Transformer.BLOCKWISE_FROM
+    if not window and _kernel_backend():
+        # (pallas is imported where a kernel can run, and only there)
+        from ..ops.pallas.fused_attention import fits
+
+        if fits(q_shape, kv_shape) and (q_shape[0] > 1
+                                        or q_shape[1] >= blockwise_from):
+            return "kernel"
+    return "blockwise" if q_shape[1] >= blockwise_from else "dense"
+
+
+def attend_by(arm: str, q: Array, k: Array, v: Array,
+              window: int = 0) -> Array:
+    """Run ``arm`` of :func:`device_arm` on a device's own q, k, v; the
+    kernel under its own ``attn_kernel`` component."""
+    if arm == "kernel":
+        from ..ops.pallas.fused_attention import fused_causal_attention
+
+        with jax.named_scope("attn_kernel"):
+            return fused_causal_attention(q, k, v)
+    if arm == "blockwise":
+        from ..ops.blockwise_attention import blockwise_attention
+
+        starts = jnp.zeros((q.shape[0],), jnp.int32)
+        return blockwise_attention(q, k, v, starts, window=window)
+    return causal_attention(q, k, v, window=window)
 
 
 _INSTANCE_COUNTER = itertools.count()
@@ -671,13 +626,11 @@ class Transformer:
                 capacity_factor=config.moe_capacity, dtype=config.dtype))
         else:
             self._moe = None
-        # causal_attention as attention_fn means the default path: attend()
-        # picks the arm from the shape (default_arm).  Pass
-        # make_ring_attention / make_ulysses_attention — or use
-        # select_attention(name, mesh) — for seq parallelism or to force an
-        # implementation by name.
-        self.attention_fn = attention_fn or (
-            _default_attention() if mesh is None else causal_attention)
+        # None means the default path: attend() picks the arm from the
+        # shape (default_arm).  Pass make_ring_attention /
+        # make_ulysses_attention (select_attention(name, mesh)) for
+        # sequence parallelism, or any (q, k, v) -> out of one's own.
+        self.attention_fn = attention_fn
         self.mesh = mesh  # when set, activations get sharding constraints
         # Never-reused identity for compiled-runner caches (generation.py):
         # id(self) can be recycled after GC, a counter token cannot.
@@ -1139,46 +1092,37 @@ class Transformer:
     # dense einsum
     BLOCKWISE_FROM = 2048
 
+    def on_mesh(self, mesh: Mesh, attention: str = "dense") -> None:
+        """This model placed on ``mesh`` (activations get sharding
+        constraints, the default path takes a device's shard) with the
+        ``--attention`` choice ``attention`` (:func:`select_attention`)."""
+        self.mesh = mesh
+        self.attention_fn = select_attention(attention, mesh)
+
     def default_arm(self, q_shape: tuple[int, ...],
                     kv_shape: tuple[int, ...], window: int) -> str:
         """Which arm the default path takes, from what :meth:`attend` can
-        observe and nothing else: ``kernel`` (the blockwise kernel of
-        ops/pallas/fused_attention.py, which writes no [B, H, S, S] array),
-        ``sharded_kernel`` (the same under ``shard_map`` over the mesh's
-        batch and head axes), ``blockwise`` (ops/xla_flash, long sequences
-        without a mesh) or ``dense`` (the einsum).
-
-        The kernel runs where the backend is a TPU, no window binds, q and
-        k cover the same positions, the shape tiles (heads of 64 or 128
-        that fill rows of 128 lanes, positions in blocks of 128) and there
-        is more than one sequence or a long one (a single prompt shorter
-        than ``BLOCKWISE_FROM`` is faster through the einsum: PERF.md,
-        PR 30); with a mesh of several devices, where also its ``seq`` and
-        ``pipe`` axes are 1 and all of that holds for a device's shard of
-        batch and heads."""
+        observe and nothing else.  Without a mesh, :func:`device_arm` of
+        the shapes: ``kernel``, ``blockwise`` or ``dense``.  With one, what
+        the mesh adds: ``sharded_kernel`` (the kernel under ``shard_map``
+        over the mesh's batch and head axes; ``kernel`` on a mesh of one
+        device) where the ``seq`` and ``pipe`` axes are 1, the mesh
+        divides batch and heads and :func:`device_arm` takes the kernel
+        for a device's shard; else ``dense``, the einsum, which GSPMD
+        partitions (never ``blockwise``)."""
         mesh = self.mesh
-        if not window and _kernel_backend() and (
-                mesh is None
-                or mesh.shape.get("seq", 1) == mesh.shape.get("pipe", 1) == 1):
-            # (pallas is imported where a kernel can run, and only there)
-            from ..ops.pallas.fused_attention import fits
-
-            several = mesh is not None and mesh.size > 1
-            rows = tp = 1
-            if several:
-                rows = mesh.shape.get("data", 1) * mesh.shape.get("fsdp", 1)
-                tp = mesh.shape.get("tensor", 1)
+        if mesh is None:
+            return device_arm(q_shape, kv_shape, window)
+        if mesh.shape.get("seq", 1) == mesh.shape.get("pipe", 1) == 1:
+            rows = mesh.shape.get("data", 1) * mesh.shape.get("fsdp", 1)
+            tp = mesh.shape.get("tensor", 1)
             divides = (q_shape[0] % rows == 0 and q_shape[2] % tp == 0
                        and kv_shape[2] % tp == 0)
             q_shard, kv_shard = (
                 (shape[0] // rows, shape[1], shape[2] // tp, shape[3])
                 for shape in (q_shape, kv_shape))
-            if (divides and fits(q_shard, kv_shard)
-                    and (q_shard[0] > 1
-                         or q_shard[1] >= self.BLOCKWISE_FROM)):
-                return "sharded_kernel" if several else "kernel"
-        if q_shape[1] >= self.BLOCKWISE_FROM and self.mesh is None:
-            return "blockwise"
+            if divides and device_arm(q_shard, kv_shard, window) == "kernel":
+                return "sharded_kernel" if mesh.size > 1 else "kernel"
         return "dense"
 
     def attend(self, q: Array, k: Array, v: Array,
@@ -1186,10 +1130,11 @@ class Transformer:
         """Causal attention of a whole sequence for a layer of kind
         ``spec``, under ``attn/full`` or ``attn/window``.  A caller's
         ``attention_fn`` runs as given (it knows no window, so a window
-        that binds is refused); the default chooses by what it sees
-        (:meth:`default_arm`): the blockwise kernel, under its own
-        ``attn_kernel`` component, wherever its shape fits on a TPU, else
-        blockwise in plain XLA for a long sequence, else the einsum."""
+        that binds is refused); the default (``attention_fn`` None)
+        chooses by what it sees (:meth:`default_arm`): the blockwise
+        kernel, under its own ``attn_kernel`` component, wherever its
+        shape fits on a TPU, else blockwise in plain XLA for a long
+        sequence, else the einsum."""
         seq = q.shape[1]
         window = spec.window if 0 < spec.window < seq else 0
         if spec.mixer == "sparse" and seq >= self.config.sparse.dense_len:
@@ -1198,7 +1143,7 @@ class Transformer:
         kind = ("window" if spec.window
                 else "sparse/attend" if spec.mixer == "sparse" else "full")
         with jax.named_scope("attn"), jax.named_scope(kind):
-            if self.attention_fn is not causal_attention:
+            if self.attention_fn is not None:
                 if window:
                     raise ValueError(
                         f"a window of {spec.window} binds at sequence "
@@ -1207,22 +1152,14 @@ class Transformer:
                         "attention_fn")
                 return self.attention_fn(q, k, v)
             arm = self.default_arm(q.shape, k.shape, window)
-            if arm in ("kernel", "sharded_kernel"):
+            if arm == "sharded_kernel":
                 from ..ops.pallas.fused_attention import (
                     fused_causal_attention)
 
-                kernel = fused_causal_attention
-                if arm == "sharded_kernel":
-                    kernel = make_sharded_flash_attention(self.mesh,
-                                                          inner=kernel)
                 with jax.named_scope("attn_kernel"):
-                    return kernel(q, k, v)
-            if arm == "blockwise":
-                from ..ops.xla_flash import blockwise_attention
-
-                starts = jnp.zeros((q.shape[0],), jnp.int32)
-                return blockwise_attention(q, k, v, starts, window=window)
-            return causal_attention(q, k, v, window=window)
+                    return shard_over_batch_and_heads(
+                        self.mesh, fused_causal_attention)(q, k, v)
+            return attend_by(arm, q, k, v, window)
 
     # positions a linear layer works through at a time
     LINEAR_CHUNK = 128
@@ -1628,8 +1565,8 @@ def lm_350m(vocab: int = 32000, seq: int = 1024, dtype=jnp.bfloat16,
     stacked and scans the layer loop — depth-independent compile time.
     ``kv_heads`` in {1, 2, 4, 8} switches to GQA (0, the default, keeps
     all 16; the `lm_350m_gqa` registry entry uses 4): kv_heads/16 the
-    KV-cache HBM and ring/Ulysses ICI bytes, and the GQA-folded flash
-    kernel keeps K/V unexpanded end to end."""
+    KV-cache HBM and ring/Ulysses ICI bytes, and the attention kernel
+    keeps K/V unexpanded end to end."""
     # n_heads=8 gives head_dim 128 — a full MXU tile per attention
     # matmul, where head_dim 64 fills half of one (the effect on step
     # time is not measured on the chip) — same parameter count either way

@@ -23,8 +23,9 @@ prints — SURVEY.md §5):
   iteration postmortems with critical-path/straggler attribution; the
   ``pst-trace`` CLI renders them.
 
-``utils/metrics.py`` (StepTimer, MetricsLogger, profile_trace) folded in
-here; the old module re-exports for backward compatibility.
+StepTimer, MetricsLogger, profile_trace and samples_per_sec (step timers,
+JSONL metrics, profiler hook) live in ``obs/stats.py`` and are exported
+here.
 """
 
 from . import export, flight, postmortem, stats, trace

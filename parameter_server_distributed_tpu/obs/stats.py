@@ -12,9 +12,8 @@ Design constraints, in order:
    wide), so any percentile read off the bucket midpoints is within ~9%
    of the true value — plenty for p50/p95 latency and straggler spread.
 
-Also home to the pieces folded in from the old ``utils/metrics.py``
-(StepTimer, MetricsLogger, profile_trace, samples_per_sec); that module
-re-exports them for backward compatibility.
+Also home to the training loops' own instruments: StepTimer,
+MetricsLogger, profile_trace, samples_per_sec.
 """
 
 from __future__ import annotations
@@ -271,7 +270,7 @@ def histogram(name: str) -> Histogram:
 
 
 # --------------------------------------------------------------------------
-# Folded in from utils/metrics.py (imports preserved via that module)
+# The training loops' instruments: step timer, JSONL metrics, profiler
 # --------------------------------------------------------------------------
 
 class StepTimer:

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels (flash attention, fused optimizer updates).
+"""Pallas TPU kernels (blockwise attention, fused optimizer updates).
 
 :func:`interpret_mode` is the single place that decides whether a
 ``pallas_call`` lowers through Mosaic or runs in the Pallas interpreter.

@@ -9,9 +9,10 @@ axis.  Causal attention then needs cross-device K/V:
   device and compute overlapped with ICI transfers.  The blockwise-
   parallel-transformer / ring-attention construction, in shard_map.
 - **Ulysses all-to-all** (`make_ulysses_attention`): `all_to_all` swaps the
-  sharded axis from sequence to heads, each device runs dense causal
-  attention on the full sequence for its head subset, then swaps back.
-  Cheaper at moderate sequence lengths, needs heads % seq_axis == 0.
+  sharded axis from sequence to heads, each device runs causal attention
+  on the full sequence for its head subset (by the arm its shapes take,
+  `models.transformer.device_arm`), then swaps back.  Cheaper at moderate
+  sequence lengths, needs heads % seq_axis == 0.
 
 Both return an ``attention_fn(q, k, v) -> out`` with the same signature as
 `models.transformer.causal_attention` ([B, S, H, D] -> [B, S, H, D]), so the
@@ -131,21 +132,18 @@ def make_ring_attention(mesh: Mesh, seq_axis: str = "seq",
 
 def make_ulysses_attention(mesh: Mesh, seq_axis: str = "seq",
                            batch_axes: tuple[str, ...] = ("data", "fsdp"),
-                           head_axis: str = "tensor",
-                           inner=None):
+                           head_axis: str = "tensor"):
     """All-to-all (DeepSpeed-Ulysses style) sequence parallelism: swap the
     sharded axis seq -> heads, run causal attention over the full
     sequence, swap back.  Heads (after any tensor sharding) must divide by
     the seq-axis size.
 
-    ``inner`` is the per-device full-sequence attention kernel (default
-    dense einsum).  After the gather each device holds [B, S, H/n, D] at
-    aligned positions — exactly the pallas flash kernel's contract — so
-    passing ``flash_attention_auto`` (the ``ulysses_flash`` CLI choice)
-    runs the O(block^2)-VMEM kernel on the full sequence per head shard."""
-    if inner is None:
-        from ..models.transformer import causal_attention
-        inner = causal_attention
+    After the gather each device holds [B, S, H/n, D] at aligned positions
+    and attends them as any device attends whole sequences
+    (``models/transformer.device_arm``): the blockwise kernel where
+    the shape fits on a TPU, blockwise in plain XLA for a long sequence
+    elsewhere, the einsum for a short one."""
+    from ..models.transformer import attend_by, device_arm, expand_gqa
 
     n = mesh.shape[seq_axis]
     n_tp = mesh.shape.get(head_axis, 1)
@@ -165,15 +163,12 @@ def make_ulysses_attention(mesh: Mesh, seq_axis: str = "seq",
 
         # GQA: all-to-all the small kv_heads-sized K/V when kv_heads
         # divides the seq axis (groups/n fewer bytes on the wire) and let
-        # the inner kernel expand; otherwise expand first (correct for
-        # any head count, at the old expanded-transfer cost)
-        if k.shape[2] % n == 0:
-            out = inner(gather_seq(q), gather_seq(k), gather_seq(v))
-        else:
-            from ..models.transformer import expand_gqa
-            ke, ve = expand_gqa(q, k, v)
-            out = inner(gather_seq(q), gather_seq(ke), gather_seq(ve))
-        return scatter_seq(out)
+        # the device's attention group them; otherwise expand first
+        # (correct for any head count, at the expanded-transfer cost)
+        if k.shape[2] % n:
+            k, v = expand_gqa(q, k, v)
+        q, k, v = gather_seq(q), gather_seq(k), gather_seq(v)
+        return scatter_seq(attend_by(device_arm(q.shape, k.shape), q, k, v))
 
     def ulysses_gqa(q, k, v):
         k, v = _prepare_gqa_kv(q, k, v, n_tp)

@@ -79,10 +79,8 @@ class PipelinedTransformerLM:
     SCHEDULES = ("gpipe", "1f1b")
 
     def __init__(self, inner, mesh: Mesh, num_microbatches: int = 0,
-                 schedule: str = "gpipe", attention: str | None = None,
-                 virtual_stages: int = 1):
-        from ..models.transformer import (Transformer, causal_attention,
-                                          flash_attention_auto)
+                 schedule: str = "gpipe", virtual_stages: int = 1):
+        from ..models.transformer import Transformer, causal_attention
 
         if not isinstance(inner, Transformer):
             raise ValueError("pipeline parallelism wraps a Transformer LM")
@@ -126,23 +124,10 @@ class PipelinedTransformerLM:
             raise ValueError(
                 f"n_layers={inner.config.n_layers} must divide by "
                 f"pipe x virtual_stages ({n_pipe} x {virtual_stages})")
-        # Stage-internal attention runs per device inside shard_map, so the
-        # single-shard kernels are the contract: dense einsum or the pallas
-        # flash kernel (seq/ring/ulysses need a seq axis, which pipeline
-        # does not compose with).  None = inherit the wrapped model's.
-        if attention == "dense":
-            self._stage_attention = causal_attention
-        elif attention == "flash":
-            self._stage_attention = flash_attention_auto
-        elif attention == "xla_flash":
-            from ..ops.xla_flash import make_xla_flash_attention
-            self._stage_attention = make_xla_flash_attention()
-        elif attention is None:
-            self._stage_attention = inner.attention_fn
-        else:
-            raise ValueError(
-                f"pipeline stages support attention dense|flash|xla_flash, "
-                f"got {attention!r}")
+        # Stage-internal attention runs per device inside shard_map: the
+        # wrapped model's own attention_fn, else the einsum (a sequence
+        # split over a seq axis does not compose with the pipeline)
+        self._stage_attention = inner.attention_fn or causal_attention
         self.inner = inner
         self.config = inner.config
         self.mesh = mesh

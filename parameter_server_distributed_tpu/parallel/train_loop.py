@@ -24,9 +24,9 @@ import numpy as np
 from ..config import MeshConfig
 from ..checkpoint import sharded as sharded_ckpt
 from ..models.registry import get_model_and_batches
+from ..obs import (MetricsLogger, StepTimer, profile_trace,
+                   samples_per_sec)
 from ..obs import stats as obs_stats
-from ..utils.metrics import (MetricsLogger, StepTimer, profile_trace,
-                             samples_per_sec)
 from .mesh import build_mesh, data_parallel_size
 from .sharding import fsdp_rule, fsdp_tp_rule
 from .train_step import ShardedTrainer, make_optimizer
@@ -56,8 +56,8 @@ class TrainLoopConfig:
     eval_steps: int = 4           # batches averaged per evaluation
     eval_data_path: str = ""      # held-out data; empty = shifted-seed
                                   # synthetic stream
-    attention: str = "dense"      # dense | flash | xla_flash | ring |
-                                  # ulysses | ulysses_flash (LM models)
+    attention: str = "dense"      # how the seq axis is used (LM models):
+                                  # models/transformer.ATTENTION_CHOICES
     microbatches: int = 0         # pipeline microbatches (0 = pipe size)
     pipeline_schedule: str = "gpipe"  # gpipe | 1f1b (pipe axis > 1)
     virtual_stages: int = 1       # interleaved 1F1B chunks per pipe rank
@@ -93,6 +93,10 @@ class TrainLoopConfig:
     seed: int = 0
     resume: bool = False
     metrics_path: str = ""
+
+    def __post_init__(self):
+        from ..models.transformer import check_attention
+        check_attention(self.attention)
 
 
 def _pick_rule(model_name: str, mesh):
@@ -172,38 +176,28 @@ def run_training(config: TrainLoopConfig) -> dict:
             data_path=config.data_path, dtype=config.model_dtype,
             remat=config.remat, scan=config.scan_layers,
             seq_len=config.seq_len, remat_policy=config.remat_policy)
-    from ..models.transformer import Transformer, select_attention
+    from ..models.transformer import Transformer
     if isinstance(model, Transformer):
         if mesh.shape["pipe"] > 1:
             # pipeline mode: wrap in the scheduled model (pipe + data axes;
-            # blocks live on their pipe rank).  Attention inside a stage is
-            # the per-device kernel: dense einsum or the pallas flash
-            # kernel (ring/ulysses need a seq axis, which pipe does not
-            # compose with).
-            if config.attention not in ("dense", "flash", "xla_flash"):
+            # blocks live on their pipe rank).  A stage attends whole
+            # sequences on its own device: a sequence split over a seq
+            # axis does not compose with it.
+            if config.attention != "dense":
                 raise ValueError(
-                    "--attention must be dense, flash, or xla_flash with a "
-                    "pipe axis (stage-internal attention runs inside "
-                    "shard_map; ring/ulysses need a seq axis)")
+                    f"--attention={config.attention} splits the sequence "
+                    "over a seq axis, which a pipe axis does not compose "
+                    "with (stage-internal attention runs inside shard_map)")
             from .pipeline import PipelinedTransformerLM
             model = PipelinedTransformerLM(
                 model, mesh, num_microbatches=config.microbatches,
                 schedule=config.pipeline_schedule,
-                attention=config.attention,
                 virtual_stages=config.virtual_stages)
         else:
-            # give the model the mesh (activation sharding constraints) and
-            # the selected attention implementation — flash composes with
-            # the mesh via shard_map over batch/head shards, ring/ulysses
-            # ride the seq axis (models/transformer.select_attention).
-            # Dense resets to causal_attention (the constructor's with-mesh
-            # default): the model may have been built mesh-less with the
-            # PSDT_FLASH_ATTENTION env default, whose single-shard pallas
-            # kernel must not run unsharded under GSPMD.
-            from ..models.transformer import causal_attention
-            model.mesh = mesh
-            attn = select_attention(config.attention, mesh)
-            model.attention_fn = attn or causal_attention
+            # give the model the mesh (activation sharding constraints, a
+            # device's shard for the default attention) and how the seq
+            # axis is used (models/transformer.select_attention)
+            model.on_mesh(mesh, config.attention)
             if mesh.shape["seq"] > 1 and model.config.loss_chunk:
                 # chunked cross-entropy scans over seq chunks, which
                 # under sequence parallelism would slice single devices'

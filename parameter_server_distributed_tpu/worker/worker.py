@@ -47,7 +47,7 @@ import numpy as np
 
 from ..config import WorkerConfig
 from ..core.tensor import TensorStore, to_wire
-from ..obs import flight
+from ..obs import MetricsLogger, StepTimer, flight
 from ..obs import stats as obs_stats
 from ..obs import trace as obs_trace
 from ..obs.export import snapshot_blob
@@ -60,7 +60,6 @@ from ..rpc.service import RpcClient
 # error_feedback_enabled is re-exported here for back-compat (it lived
 # in this module through PR 8).
 from ..tiers.ef import ErrorFeedback, error_feedback_enabled  # noqa: F401
-from ..utils.metrics import MetricsLogger, StepTimer
 
 log = logging.getLogger("pst.worker")
 

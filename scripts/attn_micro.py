@@ -1,10 +1,14 @@
 #!/usr/bin/env python3
 """Micro-programs for the training attention, run by hand on the chip
 (``chiprun -- python3 scripts/attn_micro.py``): LAYERS layers of causal
-attention, forward + backward under ``jax.checkpoint``, for each contender
-at the cells' shapes; forward only at the serving shapes; and the kernel's
-output and gradients against the einsum's, computed on the chip.  One JSON
-line per reading.  PERF.md (PR 30) has what it read.
+attention, forward + backward under ``jax.checkpoint``, for each arm a
+device can take (``models/transformer.device_arm``: ``kernel``,
+``blockwise``, ``dense``) at the cells' shapes; forward only at the serving
+shapes; the kernel's output and gradients against the einsum's, computed
+on the chip; and, on several chips (``chiprun --chips 4 -- python3
+scripts/attn_micro.py seq``), ``ring`` against ``ulysses`` over a ``seq``
+axis of all of them.  One JSON line per reading.  PERF.md (PR 30) has what
+it read of the arms and of the contenders that went in PR 46.
 """
 
 from __future__ import annotations
@@ -20,42 +24,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from parameter_server_distributed_tpu.config import MeshConfig
 from parameter_server_distributed_tpu.models.transformer import (
-    causal_attention)
-from parameter_server_distributed_tpu.ops.pallas.flash_attention import (
-    flash_attention_gqa)
+    attend_by, causal_attention, select_attention)
 from parameter_server_distributed_tpu.ops.pallas.fused_attention import (
     fused_causal_attention)
-from parameter_server_distributed_tpu.ops.xla_flash import (
-    blockwise_attention, make_xla_flash_attention)
+from parameter_server_distributed_tpu.parallel.mesh import build_mesh
 
 LAYERS = 24
 
 
-def jax_flash(block: int):
-    """``jax.experimental.pallas.ops.tpu.flash_attention`` ([B, H, S, D])."""
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
-
-    def attend(q, k, v):
-        b = min(block, q.shape[1])
-        sizes = fa.BlockSizes(
-            block_q=b, block_k_major=b, block_k=b, block_b=1,
-            block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b,
-            block_q_dkv=b, block_k_major_dq=b, block_k_dq=b, block_q_dq=b)
-        out = fa.flash_attention(
-            *(x.transpose(0, 2, 1, 3) for x in (q, k, v)), causal=True,
-            sm_scale=q.shape[-1] ** -0.5, block_sizes=sizes)
-        return out.transpose(0, 2, 1, 3)
-    return attend
-
-
-def fused(block_q=None, block_k=None, rows=128):
-    return lambda q, k, v: fused_causal_attention(
-        q, k, v, block_q=block_q, block_k=block_k, rows=rows)
-
-
-def xla_blockwise(q, k, v):
-    return blockwise_attention(q, k, v, jnp.zeros((q.shape[0],), jnp.int32))
+def arm(name: str):
+    return lambda q, k, v: attend_by(name, q, k, v)
 
 
 def stack(attend, train: bool, groups: int):
@@ -92,28 +72,16 @@ def main():
     device = jax.devices()[0]
     say(platform=device.platform, device_kind=device.device_kind,
         layers=LAYERS)
-    # arguments: the sections to run (all of them where none is given),
-    # and after "--" the contenders of the training section
+    # arguments: the sections to run (all of one chip's where none is
+    # given), and after "--" the arms of the training section
     argv = sys.argv[1:]
     cut = argv.index("--") if "--" in argv else len(argv)
     sections = set(argv[:cut]) or {"train", "small", "against", "prefill"}
     only = set(argv[cut + 1:])
     train_shapes = [(64, 1024, 16, 16, 64), (32, 1024, 10, 10, 64),
                     (2, 256, 16, 16, 64)]
-    contenders = {
-        "einsum": causal_attention,
-        "fused": fused(),
-        "fused_512": fused(512, 512),
-        "fused_256": fused(256, 256),
-        "fused_rows256": fused(rows=256),
-        "fused_rows512": fused(rows=512),
-        "fused_rows1024": fused(rows=1024),
-        "repo_flash_128": lambda q, k, v: flash_attention_gqa(q, k, v),
-        "repo_flash_512": lambda q, k, v: flash_attention_gqa(
-            q, k, v, block_q=512, block_k=512),
-        "jax_flash_512": jax_flash(512),
-        "xla_flash": make_xla_flash_attention(),
-    }
+    contenders = {name: arm(name) for name in ("dense", "kernel",
+                                               "blockwise")}
     # where the kernel overtakes the einsum as the step shrinks
     small = [(b, s, 16, 16, 64) for b, s in (
         (4, 256), (16, 256), (64, 256), (4, 512), (16, 512), (2, 1024),
@@ -126,10 +94,7 @@ def main():
         for name, attend in contenders.items():
             if only and name not in only:
                 continue
-            if (b, s, h, kv, d) in small and name not in ("einsum", "fused"):
-                continue
-            if name.startswith("fused_") and (
-                    s < 1024 or (b, h) != (64, 16)):
+            if (b, s, h, kv, d) in small and name == "blockwise":
                 continue
             try:
                 median, best = timed(stack(attend, True, h // kv), x)
@@ -158,8 +123,8 @@ def main():
 
         exact = both(lambda q, k, v: causal_attention(
             *(x.astype(jnp.float32) for x in (q, k, v))))
-        for name, attend in (("einsum", causal_attention),
-                             ("fused", fused_causal_attention)):
+        for name, attend in (("dense", causal_attention),
+                             ("kernel", fused_causal_attention)):
             got = both(attend)
             say(kind="against_float32", shape=[b, s, h, kv, d],
                 contender=name, **{
@@ -175,11 +140,8 @@ def main():
                            (1, 4096, 28, 4, 128), (1, 16384, 28, 4, 128)
                            ] if "prefill" in sections else []:
         x = jax.random.normal(jax.random.key(s), (b, s, h, d), jnp.bfloat16)
-        today = causal_attention if s < 2048 else xla_blockwise
-        variants = {"today": today, "fused": fused()}
-        if s >= 2048:
-            variants.update({f"fused_{block}": fused(block, block)
-                             for block in (512, 1024)})
+        variants = {"today": arm("dense" if s < 2048 else "blockwise"),
+                    "kernel": arm("kernel")}
         for name, attend in variants.items():
             try:
                 median, best = timed(stack(attend, False, h // kv), x)
@@ -188,6 +150,24 @@ def main():
             except Exception as exc:
                 say(kind="prefill", shape=[b, s, h, kv, d], contender=name,
                     error=str(exc)[:300])
+    # the two ways over a seq axis (ROADMAP R3): every chip of the host
+    # on it, LAYERS layers forward + backward, the whole arrays' shapes
+    if "seq" in sections and jax.device_count() > 1:
+        mesh = build_mesh(MeshConfig(sequence=jax.device_count()))
+        for b, s, h, kv, d in [(4, 4096, 16, 16, 64), (1, 16384, 16, 16, 64),
+                               (1, 16384, 28, 4, 128)]:
+            x = jax.random.normal(jax.random.key(s), (b, s, h, d),
+                                  jnp.bfloat16)
+            for name in ("ring", "ulysses"):
+                try:
+                    with mesh:
+                        median, best = timed(stack(
+                            select_attention(name, mesh), True, h // kv), x)
+                    say(kind="seq", shape=[b, s, h, kv, d], contender=name,
+                        median_ms=1e3 * median, min_ms=1e3 * best)
+                except Exception as exc:
+                    say(kind="seq", shape=[b, s, h, kv, d], contender=name,
+                        error=str(exc)[:300])
 
 
 if __name__ == "__main__":

@@ -299,7 +299,7 @@ def test_the_training_step_holds_no_score_tensor(topology, monkeypatch,
     config_axes = MeshConfig(**axes)
     mesh = build_mesh(config_axes,
                       devices=topology.devices[:config_axes.num_devices])
-    model.mesh = mesh
+    model.on_mesh(mesh)
     optimizer = make_optimizer("adam", 3e-4)
     state = jax.eval_shape(
         lambda p: TrainState.create(p, optimizer),
@@ -329,3 +329,44 @@ def test_the_training_step_holds_no_score_tensor(topology, monkeypatch,
                          text.count('custom_call_target="tpu_custom_call"'))
     assert found[True] == (0, 4)
     assert found[False][0] > 0 and found[False][1] == 0
+
+
+@pytest.mark.parametrize("heads,kv_heads,d,arm", [
+    (16, 16, 64, "kernel"), (28, 4, 128, "kernel"), (8, 4, 64, "blockwise")],
+    ids=["64", "128-grouped", "half-a-row"])
+def test_ulysses_over_four_chips_attends_by_the_devices_arm(
+        topology, monkeypatch, heads, kv_heads, d, arm):
+    """``ulysses`` over ``seq:4`` of the described 2 x 2 host, forward and
+    backward of one layer's attention at 8,192 positions: between the
+    all-to-alls a device holds ``[1, 8192, H/4, D]`` and takes the arm
+    ``device_arm`` names for that: the kernel (forward, dQ, dK/dV) where
+    its share of the heads fills rows of lanes, the plain-XLA blocks where
+    it does not; never a ``[1, H/4, S, S]`` array."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from parameter_server_distributed_tpu.config import MeshConfig
+    from parameter_server_distributed_tpu.models import transformer
+    from parameter_server_distributed_tpu.ops.pallas import fused_attention
+    from parameter_server_distributed_tpu.parallel.mesh import build_mesh
+
+    seq = 8192
+    mesh = build_mesh(MeshConfig(sequence=4), devices=topology.devices)
+    monkeypatch.setattr(fused_attention, "interpret_mode", lambda *_: False)
+    monkeypatch.setattr(transformer, "_kernel_backend", lambda: True)
+    assert transformer.device_arm((1, seq, heads // 4, d),
+                                  (1, seq, kv_heads // 4, d)) == arm
+    attend = transformer.select_attention("ulysses", mesh)
+    split = NamedSharding(mesh, PartitionSpec(None, "seq", None, None))
+    q = jax.ShapeDtypeStruct((1, seq, heads, d), jnp.bfloat16, sharding=split)
+    k = jax.ShapeDtypeStruct((1, seq, kv_heads, d), jnp.bfloat16,
+                             sharding=split)
+
+    def loss(q, k, v):
+        return jnp.sum(attend(q, k, v).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, k).compile(
+        ).as_text()
+    assert "all-to-all" in text
+    assert (text.count('custom_call_target="tpu_custom_call"')
+            == (3 if arm == "kernel" else 0))
+    assert not re.search(r"\w+\[1,%d,%d,%d\]" % (heads // 4, seq, seq), text)

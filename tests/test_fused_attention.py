@@ -1,7 +1,8 @@
 """The default path's attention: which arm ``Transformer.attend`` takes for
-a shape (one rule, ``default_arm``), and the blockwise kernel behind the
-``kernel`` arm against ``causal_attention`` (interpret mode on the CPU;
-``tests/test_chip_compile.py`` compiles it for the chip)."""
+a shape (``default_arm``, over a device's own ``device_arm``), and the
+blockwise kernel behind the ``kernel`` arm against ``causal_attention``
+(interpret mode on the CPU; ``tests/test_chip_compile.py`` compiles it for
+the chip)."""
 
 import dataclasses
 
@@ -124,13 +125,15 @@ def _out_and_grads(attend, q, k, v, weight, checkpoint=False):
 
 # head size, query heads, K/V heads, positions, blocks (None: from the
 # shape): one block in strips; blocks on and below the diagonal, each in
-# strips of its kind; blocks of unequal sides; three blocks a side
+# strips of its kind; blocks of unequal sides; three blocks a side; one
+# K/V head under four query heads
 KERNEL_SHAPES = [
     pytest.param(64, 2, 2, 512, None, id="64-two-heads-a-row"),
     pytest.param(64, 2, 2, 512, (256, 256), id="64-strips-of-blocks"),
     pytest.param(64, 4, 2, 256, (128, 256), id="64-grouped"),
     pytest.param(128, 2, 2, 256, (256, 128), id="128"),
     pytest.param(128, 6, 2, 384, (128, 128), id="128-grouped"),
+    pytest.param(128, 4, 1, 256, None, id="128-multi-query"),
 ]
 
 
@@ -146,6 +149,8 @@ def test_the_kernel_is_the_einsum_in_float32(rng, d, h, kv, s, blocks,
                                                block_k=block_k),
         q, k, v, weight, checkpoint)
     want = _out_and_grads(causal_attention, q, k, v, weight)
+    # dK and dV come back K/V-sized: grouped heads are never expanded
+    assert [a.shape for a in got] == [q.shape, q.shape, k.shape, v.shape]
     for name, a, e in zip(("out", "dq", "dk", "dv"), got, want):
         assert a.shape == e.shape, name
         np.testing.assert_allclose(a, e, rtol=1e-5, atol=1e-5, err_msg=name)
@@ -165,6 +170,7 @@ def test_the_kernel_in_bf16_is_within_the_einsums_own_rounding(
             *(x.astype(jnp.float32) for x in (q, k, v))), q, k, v, weight)
     got = _out_and_grads(fused_causal_attention, q, k, v, weight)
     einsum = _out_and_grads(causal_attention, q, k, v, weight)
+    assert [a.shape for a in got] == [q.shape, q.shape, k.shape, v.shape]
     for name, a, e, x in zip(("out", "dq", "dk", "dv"), got, einsum, exact):
         scale = np.linalg.norm(x)
         kernel_error = np.linalg.norm(a - x) / scale

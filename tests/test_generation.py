@@ -668,22 +668,26 @@ def test_speculative_batched_gqa_target_matches_greedy(rng):
     np.testing.assert_array_equal(out, reference)
 
 
-def test_generation_with_xla_flash_prefill_matches_dense(rng):
-    """A model built with the xla_flash attention kernel serves the same
-    prefill as the dense model (decode then uses the cache einsums either
-    way).  Logits compared with a tolerance, not token equality — the two
-    kernels reorder float accumulation, and a near-tie argmax flip would
-    make discrete comparison flaky across backends."""
+def test_generation_with_a_callers_attention_prefill_matches_dense(rng):
+    """A model built with a caller's attention_fn (the plain-XLA blocks)
+    serves the same prefill as the default model (decode then uses the
+    cache einsums either way).  Logits compared with a tolerance, not
+    token equality — the two reorder float accumulation, and a near-tie
+    argmax flip would make discrete comparison flaky across backends."""
     from parameter_server_distributed_tpu.models.generation import prefill
     from parameter_server_distributed_tpu.models.transformer import (
-        Transformer, TransformerConfig, select_attention)
+        Transformer, TransformerConfig)
+    from parameter_server_distributed_tpu.ops.blockwise_attention import (
+        blockwise_attention)
 
     config = TransformerConfig(vocab=256, d_model=32, n_heads=4,
                                n_layers=2, d_ff=64, max_seq=64,
                                dtype=jnp.float32)
     dense = Transformer(config)
-    flash = Transformer(config,
-                        attention_fn=select_attention("xla_flash", None))
+    flash = Transformer(
+        config, attention_fn=lambda q, k, v: blockwise_attention(
+            q, k, v, jnp.zeros((q.shape[0],), jnp.int32), block_q=4,
+            block_k=4))
     params = dense.init_params(0)
     prompt = jnp.asarray(rng.integers(0, 256, (2, 8)), jnp.int32)
     logits_d, cache_d = prefill(dense, params, prompt, 32)

@@ -203,60 +203,6 @@ def test_transformer_sharded_tp_sp_training():
     assert float(metrics2["loss"]) < base_loss  # one adam step helped
 
 
-def test_transformer_flash_attention_drop_in(rng):
-    """The pallas flash kernels are a numerical drop-in for the dense
-    attention inside the full LM (rope + reshapes + mixed precision):
-    same loss, same gradients."""
-    from functools import partial
-
-    import jax
-
-    from parameter_server_distributed_tpu.models.transformer import (
-        Transformer, TransformerConfig)
-    from parameter_server_distributed_tpu.ops.pallas.flash_attention import (
-        flash_attention)
-
-    config = TransformerConfig(vocab=128, d_model=64, n_heads=4, n_layers=2,
-                               d_ff=128, max_seq=64, dtype=jnp.float32)
-    dense_model = Transformer(config)
-    flash_model = Transformer(
-        config, attention_fn=partial(flash_attention, block_q=32, block_k=32))
-    params = dense_model.init_params(0)
-    tokens = jnp.asarray(rng.integers(0, 128, (2, 64)), jnp.int32)
-
-    ld, gd = jax.value_and_grad(dense_model.loss)(params, tokens)
-    lf, gf = jax.value_and_grad(flash_model.loss)(params, tokens)
-    np.testing.assert_allclose(float(lf), float(ld), rtol=1e-5)
-    for name in gd:
-        np.testing.assert_allclose(np.asarray(gf[name]), np.asarray(gd[name]),
-                                   rtol=1e-3, atol=1e-5, err_msg=name)
-
-
-def test_flash_attention_env_default(rng, monkeypatch):
-    """PSDT_FLASH_ATTENTION=1 switches the single-device model default to
-    the flash-auto path wherever the kernels compile (interpret-mode
-    pallas on a CPU backend is a per-call opt-in, never a launch-env
-    default)."""
-    from parameter_server_distributed_tpu.models import transformer as tr
-    from parameter_server_distributed_tpu.ops import pallas as pallas_ops
-
-    config = tr.TransformerConfig(vocab=64, d_model=32, n_heads=2,
-                                  n_layers=1, d_ff=64, max_seq=32,
-                                  dtype=jnp.float32)
-    monkeypatch.setenv("PSDT_FLASH_ATTENTION", "1")
-    # CPU backend (this test session): env flag alone must NOT select flash
-    assert tr.Transformer(config).attention_fn is tr.causal_attention
-    monkeypatch.setattr(pallas_ops, "interpret_mode", lambda *a: False)
-    assert tr.Transformer(config).attention_fn is tr.flash_attention_auto
-    monkeypatch.delenv("PSDT_FLASH_ATTENTION")
-    assert tr.Transformer(config).attention_fn is tr.causal_attention
-    # indivisible seq falls back to dense inside flash_attention_auto
-    q = jnp.asarray(rng.standard_normal((1, 48, 2, 16)), jnp.float32)
-    ref = tr.causal_attention(q, q, q)
-    np.testing.assert_allclose(np.asarray(tr.flash_attention_auto(q, q, q)),
-                               np.asarray(ref), rtol=1e-5, atol=1e-6)
-
-
 def test_remat_loss_and_gradients_match_non_remat(rng):
     """jax.checkpoint rematerialization must be numerically invisible:
     same loss, same gradients, only the backward memory profile changes."""

@@ -1,50 +1,13 @@
-"""Pallas kernel tests (interpret mode on CPU; compiled on TPU)."""
+"""The fused-update pallas kernels (interpret mode on CPU; compiled on
+TPU).  The attention kernel's tests are tests/test_fused_attention.py."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from parameter_server_distributed_tpu.models.transformer import causal_attention
-from parameter_server_distributed_tpu.ops.pallas.flash_attention import flash_attention
 from parameter_server_distributed_tpu.ops.pallas.fused_update import (
     fused_adam, fused_momentum, fused_sgd)
-
-
-@pytest.mark.parametrize("s,block", [(64, 32), (128, 128), (96, 32)])
-def test_flash_attention_matches_dense(rng, s, block):
-    b, h, d = 2, 2, 16
-    q, k, v = (rng.standard_normal((b, s, h, d)).astype(np.float32)
-               for _ in range(3))
-    dense = np.asarray(causal_attention(*map(jnp.asarray, (q, k, v))))
-    flash = np.asarray(flash_attention(jnp.asarray(q), jnp.asarray(k),
-                                       jnp.asarray(v), block_q=block,
-                                       block_k=block))
-    np.testing.assert_allclose(flash, dense, rtol=2e-5, atol=2e-5)
-
-
-def test_flash_attention_gradients_match_dense(rng):
-    b, s, h, d = 1, 32, 2, 8
-    q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
-               for _ in range(3))
-
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, block_q=16, block_k=16) ** 2)
-
-    def loss_dense(q, k, v):
-        return jnp.sum(causal_attention(q, k, v) ** 2)
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(gf, gd):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   rtol=5e-4, atol=1e-5)
-
-
-def test_flash_rejects_indivisible_seq(rng):
-    q = jnp.zeros((1, 100, 2, 8), jnp.float32)
-    with pytest.raises(ValueError, match="divide"):
-        flash_attention(q, q, q, block_q=64, block_k=64)
 
 
 def test_fused_sgd_matches_reference(rng):
@@ -170,109 +133,3 @@ def test_pallas_optimizer_state_roundtrip(rng):
     out_b = clone.apply(p2, g)
     np.testing.assert_allclose(np.asarray(out_a["w"]), np.asarray(out_b["w"]),
                                rtol=1e-5, atol=1e-7)
-
-
-@pytest.mark.parametrize("block_q,block_k", [(32, 16), (16, 32), (64, 64)])
-def test_flash_backward_blockwise_matches_dense(rng, block_q, block_k):
-    """The blockwise dQ/dK/dV kernels must agree with dense autodiff for
-    every block-shape combination (exercises the causal frontier math on
-    both grids)."""
-    b, s, h, d = 2, 64, 2, 16
-    q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
-               for _ in range(3))
-    cot = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
-
-    def f_flash(q, k, v):
-        return jnp.vdot(flash_attention(q, k, v, block_q=block_q,
-                                        block_k=block_k), cot)
-
-    def f_dense(q, k, v):
-        return jnp.vdot(causal_attention(q, k, v), cot)
-
-    gf = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(f_dense, argnums=(0, 1, 2))(q, k, v)
-    for name, a, b_ in zip("qkv", gf, gd):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   rtol=5e-4, atol=1e-5,
-                                   err_msg=f"d{name} mismatch")
-
-
-def test_flash_backward_bf16(rng):
-    """bf16 inputs: blockwise grads track the f32 dense reference within
-    bf16 resolution (accumulation is f32 inside the kernels)."""
-    b, s, h, d = 1, 64, 2, 16
-    qf, kf, vf = (jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
-                  for _ in range(3))
-    q, k, v = (x.astype(jnp.bfloat16) for x in (qf, kf, vf))
-
-    def f_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, block_q=32, block_k=32)
-                       .astype(jnp.float32) ** 2)
-
-    def f_dense(q, k, v):
-        return jnp.sum(causal_attention(q, k, v).astype(jnp.float32) ** 2)
-
-    gf = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(f_dense, argnums=(0, 1, 2))(qf, kf, vf)
-    for a, b_ in zip(gf, gd):
-        assert a.dtype == jnp.bfloat16
-        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b_),
-                                   rtol=0.1, atol=0.05)
-
-
-# ---------------------------------------------------------------------------
-# GQA-folded flash: unexpanded K/V, group segments in the q-rows axis
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("kv,groups,block", [(2, 4, 64), (1, 4, 32),
-                                             (4, 2, 64)])
-def test_flash_gqa_matches_dense(rng, kv, groups, block):
-    from parameter_server_distributed_tpu.ops.pallas.flash_attention import (
-        flash_attention_gqa)
-
-    b, s, d = 2, 128, 16
-    q = jnp.asarray(rng.standard_normal((b, s, kv * groups, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-    dense = np.asarray(causal_attention(q, k, v))  # expands GQA itself
-    got = np.asarray(flash_attention_gqa(q, k, v, block_q=block,
-                                         block_k=block))
-    np.testing.assert_allclose(got, dense, rtol=2e-4, atol=2e-4)
-
-
-def test_flash_gqa_gradients_match_dense_and_stay_kv_sized(rng):
-    """dK/dV must come back [B, S, KV, D] (the group reduction happens in
-    the kernel's k-block stream, never materializing H-sized grads) and
-    equal the dense GQA gradients."""
-    from parameter_server_distributed_tpu.ops.pallas.flash_attention import (
-        flash_attention_gqa)
-
-    b, s, kv, groups, d = 1, 128, 2, 3, 8
-    q = jnp.asarray(rng.standard_normal((b, s, kv * groups, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-
-    def loss_gqa(q, k, v):
-        return jnp.sum(
-            flash_attention_gqa(q, k, v, block_q=32, block_k=32) ** 2)
-
-    def loss_dense(q, k, v):
-        return jnp.sum(causal_attention(q, k, v) ** 2)
-
-    gf = jax.grad(loss_gqa, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    assert gf[1].shape == (b, s, kv, d)
-    assert gf[2].shape == (b, s, kv, d)
-    for a, b_ in zip(gf, gd):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   rtol=5e-4, atol=1e-5)
-
-
-def test_flash_gqa_rejects_bad_heads(rng):
-    from parameter_server_distributed_tpu.ops.pallas.flash_attention import (
-        flash_attention_gqa)
-
-    q = jnp.zeros((1, 128, 6, 8), jnp.float32)
-    k = jnp.zeros((1, 128, 4, 8), jnp.float32)
-    with pytest.raises(ValueError, match="divide"):
-        flash_attention_gqa(q, k, k)

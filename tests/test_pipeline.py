@@ -346,30 +346,7 @@ def test_pipelined_lm_1f1b_trains_in_sharded_trainer(rng):
                                    atol=1e-6, err_msg=name)
 
 
-def test_pipeline_flash_attention_stage(rng):
-    """--attention=flash inside pipeline stages: the per-device pallas
-    kernel (interpret mode on CPU) gives the same loss as dense stages
-    when seq is block-divisible."""
-    from parameter_server_distributed_tpu.models.transformer import (
-        Transformer, TransformerConfig)
-    from parameter_server_distributed_tpu.parallel.pipeline import (
-        PipelinedTransformerLM)
-
-    mesh = build_mesh(MeshConfig(pipeline=2, data=4))
-    config = TransformerConfig(vocab=64, d_model=32, n_heads=4, n_layers=2,
-                               d_ff=64, max_seq=128, dtype=jnp.float32)
-    tokens = rng.integers(0, 64, (8, 128)).astype(np.int32)
-    dense = PipelinedTransformerLM(Transformer(config), mesh,
-                                   num_microbatches=2, attention="dense")
-    flash = PipelinedTransformerLM(Transformer(config), mesh,
-                                   num_microbatches=2, attention="flash")
-    params = dense.init_params(0)
-    l_dense = float(jax.jit(dense.loss)(params, tokens))
-    l_flash = float(jax.jit(flash.loss)(params, tokens))
-    np.testing.assert_allclose(l_flash, l_dense, rtol=1e-4)
-
-
-def test_pipeline_rejects_bad_schedule_and_attention(rng):
+def test_pipeline_rejects_bad_schedule(rng):
     from parameter_server_distributed_tpu.models.transformer import (
         Transformer, TransformerConfig)
     from parameter_server_distributed_tpu.parallel.pipeline import (
@@ -381,8 +358,6 @@ def test_pipeline_rejects_bad_schedule_and_attention(rng):
                                           dtype=jnp.float32))
     with pytest.raises(ValueError, match="schedule"):
         PipelinedTransformerLM(model, mesh, schedule="pipedream")
-    with pytest.raises(ValueError, match="attention"):
-        PipelinedTransformerLM(model, mesh, attention="ring")
 
 
 def test_run_training_pipeline_1f1b_mode(rng):

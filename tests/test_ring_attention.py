@@ -92,26 +92,28 @@ def test_transformer_with_ring_attention_end_to_end(rng):
 
 
 def test_sharded_flash_matches_dense(rng):
-    """make_sharded_flash_attention on a 3-axis mesh (data x fsdp x tensor)
-    must equal dense causal attention — the flash kernel runs per
+    """shard_over_batch_and_heads on a 3-axis mesh (data x fsdp x tensor)
+    must equal dense causal attention: the kernel (interpreted) runs per
     batch/head shard over the full sequence."""
     from parameter_server_distributed_tpu.models.transformer import (
-        make_sharded_flash_attention)
+        shard_over_batch_and_heads)
+    from parameter_server_distributed_tpu.ops.pallas.fused_attention import (
+        fused_causal_attention)
 
     mesh = build_mesh(MeshConfig(data=2, fsdp=2, tensor=2))
-    q, k, v = qkv(rng, b=4, s=128, h=4, d=16)  # seq 128: real kernel path
+    q, k, v = qkv(rng, b=4, s=128, h=4, d=64)   # a shard: [1, 128, 2, 64]
     dense = np.asarray(causal_attention(*map(jnp.asarray, (q, k, v))))
-    flash = make_sharded_flash_attention(mesh)
-    out = np.asarray(jax.jit(flash)(q, k, v))
-    np.testing.assert_allclose(out, dense, rtol=5e-4, atol=5e-4)
+    sharded = shard_over_batch_and_heads(mesh, fused_causal_attention)
+    out = np.asarray(jax.jit(sharded)(q, k, v))
+    np.testing.assert_allclose(out, dense, rtol=2e-5, atol=2e-5)
 
 
 def test_sharded_flash_lm_step_matches_dense(rng):
-    """Full sharded LM train step on a 2-axis mesh with the pallas flash
-    kernel: loss and updated params must match the dense-attention run
-    (mesh + flash at the same time)."""
+    """Full sharded LM train step on a 3-axis mesh with a caller's
+    attention under shard_map over batch and heads: loss and updated
+    params must match the default-path run (GSPMD's einsum)."""
     from parameter_server_distributed_tpu.models.transformer import (
-        make_sharded_flash_attention)
+        shard_over_batch_and_heads)
 
     mesh = build_mesh(MeshConfig(data=2, fsdp=2, tensor=2))
     config = TransformerConfig(vocab=128, d_model=32, n_heads=4, n_layers=2,
@@ -120,7 +122,8 @@ def test_sharded_flash_lm_step_matches_dense(rng):
 
     results = {}
     for name, attn in (("dense", None),
-                       ("flash", make_sharded_flash_attention(mesh))):
+                       ("sharded", shard_over_batch_and_heads(
+                           mesh, causal_attention))):
         model = Transformer(config, attention_fn=attn, mesh=mesh)
         trainer = ShardedTrainer(model.loss, mesh, transformer_rule(mesh),
                                  make_optimizer("sgd", 0.1))
@@ -129,9 +132,9 @@ def test_sharded_flash_lm_step_matches_dense(rng):
         results[name] = (float(metrics["loss"]),
                          np.asarray(state.params["layer0/attn/wq"]))
     assert np.isfinite(results["dense"][0])
-    np.testing.assert_allclose(results["flash"][0], results["dense"][0],
+    np.testing.assert_allclose(results["sharded"][0], results["dense"][0],
                                rtol=1e-4)
-    np.testing.assert_allclose(results["flash"][1], results["dense"][1],
+    np.testing.assert_allclose(results["sharded"][1], results["dense"][1],
                                rtol=2e-3, atol=2e-5)
 
 
@@ -139,14 +142,15 @@ def test_select_attention_switch(rng):
     """select_attention: every CLI choice returns a working attention_fn
     (or None for dense) on the appropriate mesh."""
     from parameter_server_distributed_tpu.models.transformer import (
-        flash_attention_auto, select_attention)
+        ATTENTION_CHOICES, select_attention)
 
+    assert ATTENTION_CHOICES == ("dense", "ring", "ulysses")
     assert select_attention("dense", None) is None
-    assert select_attention("flash", None) is flash_attention_auto
     mesh = build_mesh(MeshConfig(sequence=2, data=4))
+    assert select_attention("dense", mesh) is None
     q, k, v = qkv(rng)
     dense = np.asarray(causal_attention(*map(jnp.asarray, (q, k, v))))
-    for name in ("ring", "ulysses", "ulysses_flash", "ulysses_xla_flash"):
+    for name in ("ring", "ulysses"):
         fn = select_attention(name, mesh)
         np.testing.assert_allclose(np.asarray(jax.jit(fn)(q, k, v)), dense,
                                    rtol=2e-5, atol=2e-5)
@@ -156,28 +160,129 @@ def test_select_attention_switch(rng):
         select_attention("ring", None)
 
 
-def test_ulysses_flash_inner_kernel_and_gradients(rng):
-    """make_ulysses_attention(inner=flash): the pallas kernel runs on each
-    device's gathered full sequence; output AND gradients match the dense
-    composition."""
+@pytest.mark.parametrize("name", ["flash", "xla_flash", "ulysses_flash",
+                                  "ulysses_xla_flash"])
+def test_a_removed_attention_name_is_refused_with_the_three_that_remain(
+        monkeypatch, name):
+    """By select_attention and by TrainLoopConfig (so by pst-train before a
+    model is built): the message lists dense, ring, ulysses."""
+    from parameter_server_distributed_tpu.cli import train_main
     from parameter_server_distributed_tpu.models.transformer import (
-        flash_attention_auto)
+        select_attention)
+    from parameter_server_distributed_tpu.utils import compile_cache
+    from parameter_server_distributed_tpu.parallel.train_loop import (
+        TrainLoopConfig)
 
+    remain = r"\('dense', 'ring', 'ulysses'\)"
     mesh = build_mesh(MeshConfig(sequence=2, data=4))
-    q, k, v = qkv(rng)
+    with pytest.raises(ValueError, match=remain):
+        select_attention(name, mesh)
+    with pytest.raises(ValueError, match=remain):
+        TrainLoopConfig(model="small_lm", attention=name)
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: None)
+    with pytest.raises(ValueError, match=remain):
+        train_main.main(["--model=small_lm", f"--attention={name}"])
 
-    def loss(fn, q, k, v):
+
+def test_the_documents_name_only_attention_choices_that_exist():
+    """Every ``--attention=`` value README.md, docs/*.md and pst-train's
+    --help name is one of ATTENTION_CHOICES."""
+    import glob
+    import os
+    import re
+
+    from parameter_server_distributed_tpu.cli import train_main
+    from parameter_server_distributed_tpu.models.transformer import (
+        ATTENTION_CHOICES)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    texts = {"pst-train --help": train_main.__doc__}
+    for path in [os.path.join(root, "README.md"),
+                 *glob.glob(os.path.join(root, "docs", "*.md"))]:
+        with open(path, encoding="utf-8") as handle:
+            texts[os.path.relpath(path, root)] = handle.read()
+    named = {(where, value)
+             for where, text in texts.items()
+             for values in re.findall(r"--attention=([a-z_|]+)", text)
+             for value in values.split("|")}
+    assert {"README.md", "docs/parallelism.md", "pst-train --help"} <= {
+        where for where, _ in named}
+    assert {pair for pair in named
+            if pair[1] not in ATTENTION_CHOICES} == set()
+
+
+# what a device holds after Ulysses' gather, [B, S, H/n, D] against
+# [B, S, KV/n, D]: (case, backend is a TPU, q shape, K/V heads, arm)
+AFTER_THE_GATHER = [
+    ("long and grouped on a TPU", True, (1, 8192, 7, 128), 1, "kernel"),
+    ("the same off a TPU", False, (1, 8192, 7, 128), 1, "blockwise"),
+    ("heads that leave half a row of lanes", True, (2, 4096, 3, 64), 1,
+     "blockwise"),
+    ("short", False, (2, 1024, 4, 64), 4, "dense"),
+    ("short on a TPU, several sequences", True, (2, 1024, 4, 64), 4,
+     "kernel"),
+    ("one short sequence on a TPU", True, (1, 1024, 4, 64), 4, "dense"),
+    ("heads of 32 on a TPU, long", True, (2, 2048, 8, 32), 8, "blockwise"),
+]
+
+
+@pytest.mark.parametrize("case,tpu,q_shape,kv_heads,arm", AFTER_THE_GATHER,
+                         ids=[a[0] for a in AFTER_THE_GATHER])
+def test_a_device_takes_the_arm_its_own_shapes_name(monkeypatch, case, tpu,
+                                                    q_shape, kv_heads, arm):
+    from parameter_server_distributed_tpu.models import transformer
+
+    monkeypatch.setattr(transformer, "_kernel_backend", lambda: tpu)
+    b, s, _, d = q_shape
+    assert transformer.device_arm(q_shape, (b, s, kv_heads, d)) == arm
+
+
+def _value_and_grads(fn, q, k, v):
+    def loss(q, k, v):
         return jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)
 
-    uf = make_ulysses_attention(mesh, inner=flash_attention_auto)
-    val_f, grads_f = jax.jit(
-        jax.value_and_grad(lambda *a: loss(uf, *a), argnums=(0, 1, 2)))(q, k, v)
-    val_d, grads_d = jax.jit(
-        jax.value_and_grad(lambda *a: loss(causal_attention, *a),
-                           argnums=(0, 1, 2)))(q, k, v)
-    np.testing.assert_allclose(float(val_f), float(val_d), rtol=1e-5)
-    for gf, gd, name in zip(grads_f, grads_d, "qkv"):
-        np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+
+@pytest.mark.parametrize("seq_shards", [2, 4])
+@pytest.mark.parametrize("arm", ["dense", "blockwise", "kernel"])
+def test_ulysses_attends_by_the_devices_arm(monkeypatch, rng, arm,
+                                            seq_shards):
+    """After the gather a device attends by device_arm: the einsum, the
+    plain-XLA blocks (``BLOCKWISE_FROM`` lowered) or the kernel (a TPU
+    pretended, the kernel interpreted); output AND gradients match the
+    einsum over the whole arrays, grouped K/V unexpanded on the wire."""
+    from parameter_server_distributed_tpu.models import transformer
+
+    mesh = build_mesh(MeshConfig(sequence=seq_shards, data=8 // seq_shards))
+    b, s, h, kv, d = 8 // seq_shards * 2, 128, 16, 8, 64
+    q = rng.standard_normal((b, s, h, d)).astype(np.float32)
+    k = rng.standard_normal((b, s, kv, d)).astype(np.float32)
+    v = rng.standard_normal((b, s, kv, d)).astype(np.float32)
+    if arm == "blockwise":
+        monkeypatch.setattr(Transformer, "BLOCKWISE_FROM", 128)
+    monkeypatch.setattr(transformer, "_kernel_backend",
+                        lambda: arm == "kernel")
+    taken = []
+    real = transformer.device_arm
+
+    def spy(q_shape, kv_shape, *rest):
+        taken.append((q_shape, kv_shape, real(q_shape, kv_shape, *rest)))
+        return taken[-1][-1]
+
+    monkeypatch.setattr(transformer, "device_arm", spy)
+    val_u, grads_u = _value_and_grads(make_ulysses_attention(mesh), q, k, v)
+    # a device's own shapes: its rows of the batch, every position, its
+    # share of the heads
+    rows = b // (8 // seq_shards)
+    assert set(taken) == {((rows, s, h // seq_shards, d),
+                           (rows, s, kv // seq_shards, d), arm)}
+    val_d, grads_d = _value_and_grads(causal_attention, q, k, v)
+    # (a float32 sum over a million squares)
+    np.testing.assert_allclose(float(val_u), float(val_d), rtol=1e-4)
+    for gu, gd, name in zip(grads_u, grads_d, "qkv"):
+        assert gu.shape == gd.shape, name
+        np.testing.assert_allclose(np.asarray(gu), np.asarray(gd),
                                    rtol=2e-4, atol=2e-4, err_msg=f"d{name}")
 
 
@@ -236,7 +341,7 @@ def test_mqa_with_tensor_parallel_heads(rng):
     by the tensor axis, so the wrappers pre-expand K/V — the pre-GQA-
     refactor behavior for this corner (regression test)."""
     from parameter_server_distributed_tpu.models.transformer import (
-        make_sharded_flash_attention, repeat_kv)
+        repeat_kv, shard_over_batch_and_heads)
 
     b, s, h, d = 4, 32, 4, 16
     q = rng.standard_normal((b, s, h, d)).astype(np.float32)
@@ -253,5 +358,6 @@ def test_mqa_with_tensor_parallel_heads(rng):
                                    err_msg=maker.__name__)
 
     fmesh = build_mesh(MeshConfig(tensor=2, data=4))
-    out = np.asarray(jax.jit(make_sharded_flash_attention(fmesh))(q, k, v))
+    out = np.asarray(jax.jit(shard_over_batch_and_heads(
+        fmesh, causal_attention))(q, k, v))
     np.testing.assert_allclose(out, dense, rtol=2e-5, atol=2e-5)

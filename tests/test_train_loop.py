@@ -86,7 +86,7 @@ def test_train_loop_resume(tmp_path):
 @pytest.mark.parametrize("attention,mesh", [
     ("ring", MeshConfig(sequence=2, data=4)),
     ("ulysses", MeshConfig(sequence=2, data=2, fsdp=2)),
-    ("flash", MeshConfig(data=2, fsdp=2, tensor=2)),
+    ("dense", MeshConfig(data=2, fsdp=2, tensor=2)),
 ])
 def test_run_training_attention_selection(attention, mesh):
     """--attention reaches run_training for every implementation: the LM
@@ -133,8 +133,40 @@ def test_seq_mesh_drops_loss_chunk(monkeypatch):
     assert seen["model"].config.loss_chunk == 8
 
 
+@pytest.mark.parametrize("mesh,q_shape,arm", [
+    (MeshConfig(), (64, 1024, 16, 64), "kernel"),
+    (MeshConfig(fsdp=2, tensor=2), (64, 1024, 20, 64), "sharded_kernel"),
+], ids=["one-device", "fsdp2-tensor2"])
+def test_run_training_leaves_the_default_path_to_the_model(monkeypatch, mesh,
+                                                           q_shape, arm):
+    """run_training hands the model its mesh through ``on_mesh``: under
+    ``dense`` the model keeps ``attention_fn`` None, and its default path
+    takes the arm tests/test_fused_attention.py::ARMS names for that mesh
+    (the backend pretended a TPU once the CPU's steps are done)."""
+    from parameter_server_distributed_tpu.models import registry as reg
+    from parameter_server_distributed_tpu.models import transformer
+    from parameter_server_distributed_tpu.parallel import train_loop as tl
+
+    seen = {}
+    real = reg.get_model_and_batches
+
+    def spy(*args, **kwargs):
+        seen["model"], batches = real(*args, **kwargs)
+        return seen["model"], batches
+
+    monkeypatch.setattr(tl, "get_model_and_batches", spy)
+    summary = run_training(TrainLoopConfig(
+        model="small_lm", batch_size=4, steps=1, optimizer="sgd", mesh=mesh))
+    assert np.isfinite(summary["final_loss"])
+    model = seen["model"]
+    assert model.attention_fn is None
+    assert model.mesh is not None and model.mesh.size == mesh.num_devices
+    monkeypatch.setattr(transformer, "_kernel_backend", lambda: True)
+    assert model.default_arm(q_shape, q_shape, 0) == arm
+
+
 def test_attention_flag_rejected_for_non_transformer():
-    config = TrainLoopConfig(model="mnist_mlp", attention="flash", steps=1,
+    config = TrainLoopConfig(model="mnist_mlp", attention="ring", steps=1,
                              mesh=MeshConfig(data=8))
     with pytest.raises(ValueError, match="transformer"):
         run_training(config)

@@ -6,9 +6,8 @@ import os
 import numpy as np
 import pytest
 
-from parameter_server_distributed_tpu.utils.metrics import (MetricsLogger,
-                                                            StepTimer,
-                                                            samples_per_sec)
+from parameter_server_distributed_tpu.obs import (MetricsLogger, StepTimer,
+                                                  samples_per_sec)
 
 
 def test_step_timer_percentiles():

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import stats as obs_stats
+from . import transformer as _transformer
 from .transformer import STATE_MIXERS, Transformer
 
 Array = jax.Array
@@ -54,7 +55,19 @@ def heads_per_row(kv_heads: int, head_dim: int) -> int:
     narrower than the lanes left alone is padded to them on the device, or
     the compiler turns the part around (positions onto the lanes) for its
     products and back for the write: either way a decode round copies the
-    whole cache in and out (PERF.md, PR 28)."""
+    whole cache in and out (PERF.md, PR 28).
+
+    A LATENT layer's row has no heads to pack: the normed latent and the
+    shared key part lie side by side in ONE row, padded with zeros to whole
+    registers (``TransformerConfig.latent_row``: 512 + 64 -> 640 lanes, 4.5
+    registers' worth in 5).  Decided by what the compiler does: a part
+    [slots, max_len, 576] the device lays with POSITIONS along the lanes
+    (no padding that way), and the round copied all of it into rows for
+    its scatter and its products and back, 2 x 1.2 GB a layer; 512 and 64
+    as two arrays pad the 64 to 128 and come to the same 640 with one part
+    more.  At 640 a round's scores read the part once for every head and
+    its weighted sum once more, whole rows both
+    (tests/test_chip_compile.py holds that neither copies the part)."""
     pack = max(1, _LANES // head_dim)
     while kv_heads % pack:
         pack -= 1
@@ -87,11 +100,16 @@ class KVCache:
     layer's COMPRESSED KEYS beside its K/V (index i the mean of positions
     stride i .. stride i + kernel - 1, written once those are complete),
     each [B, KV, max_len / stride, D].  ``state`` holds the STATE of a
-    layer that keeps no K/V (:func:`state_shape`): a linear layer's
-    decayed outer products, [B, H, D, D] float32, or a conv layer's shift
-    register of its last gated inputs, [B, K - 1, d_model]; neither grows
-    with the context, neither can be rolled back.  ``ring_layers``,
-    ``sparse_layers`` and ``state_layers`` name the layers of each kind
+    layer that keeps no K/V, a TUPLE of arrays a layer
+    (:func:`state_shape`): a linear layer's decayed outer products,
+    ([B, H, D, D] float32,), a conv layer's shift register of its last
+    gated inputs, ([B, K - 1, d_model],), a kda layer's both (the
+    register of its three convolutions' inputs and the matrix); none grows
+    with the context, none can be rolled back.  ``latent`` holds a latent
+    layer's rows by position, [B, max_len, latent_row]: the normed latent
+    every head's K and V are expanded from and the key part they share
+    (then zeros to whole registers).  ``ring_layers``, ``sparse_layers``,
+    ``state_layers`` and ``latent_layers`` name the layers of each kind
     (static).  ``length`` is the number of valid positions (a traced
     scalar so decode never retraces)."""
     k: tuple
@@ -101,24 +119,28 @@ class KVCache:
     wv: tuple = ()
     ck: tuple = ()
     state: tuple = ()
+    latent: tuple = ()
     ring_layers: tuple = dataclasses.field(
         default=(), metadata=dict(static=True))
     sparse_layers: tuple = dataclasses.field(
         default=(), metadata=dict(static=True))
     state_layers: tuple = dataclasses.field(
         default=(), metadata=dict(static=True))
+    latent_layers: tuple = dataclasses.field(
+        default=(), metadata=dict(static=True))
     max_len: int = dataclasses.field(default=0, metadata=dict(static=True))
     # the fields that hold a part per layer
-    PARTS: ClassVar[tuple] = ("k", "v", "wk", "wv", "ck", "state")
+    PARTS: ClassVar[tuple] = ("k", "v", "wk", "wv", "ck", "state", "latent")
 
     def place(self, layer: int) -> tuple[bool, int]:
         """(kept as a ring?, index within its part) of a layer that keeps
-        K/V (a linear or conv layer keeps none: ``state_layers`` places
-        it)."""
+        K/V (a state layer keeps none and a latent layer its rows:
+        ``state_layers`` and ``latent_layers`` place them)."""
         if layer in self.ring_layers:
             return True, self.ring_layers.index(layer)
         return False, layer - sum(
-            1 for r in self.ring_layers + self.state_layers if r < layer)
+            1 for r in self.ring_layers + self.state_layers
+            + self.latent_layers if r < layer)
 
     def by_head(self, index: int) -> bool:
         """Whether ``k[index]`` / ``v[index]`` is a sparse layer's."""
@@ -129,7 +151,9 @@ class KVCache:
         (they lie by position beside the K/V they summarise)."""
         return {"full": sum(int(x.nbytes) for x in self.k + self.v + self.ck),
                 "window": sum(int(x.nbytes) for x in self.wk + self.wv),
-                "state": sum(int(x.nbytes) for x in self.state)}
+                "state": sum(int(x.nbytes)
+                             for layer in self.state for x in layer),
+                "latent": sum(int(x.nbytes) for x in self.latent)}
 
 
 def heads_major(x: Array, kv_heads: int) -> Array:
@@ -157,15 +181,19 @@ def ring_layers_of(model: Transformer, max_len: int) -> tuple[int, ...]:
     return rings
 
 
-def state_shape(model: Transformer) -> tuple[tuple[int, ...], Any]:
-    """(shape, dtype) of what ONE slot keeps of one of the model's state
-    layers (a model has one kind of them): a linear layer's [H, D, D]
-    float32, a conv layer's last ``conv_kernel - 1`` gated inputs
-    [K - 1, d_model] in the model's dtype."""
+def state_shape(model: Transformer) -> tuple[tuple, ...]:
+    """What ONE slot keeps of each of the model's state layers, in layer
+    order: a tuple of (shape, dtype) a layer.  A linear layer's [H, D, D]
+    float32; a conv layer's last ``conv_kernel - 1`` gated inputs [K - 1,
+    d_model] in the model's dtype; a kda layer's two, the last
+    ``conv_kernel - 1`` inputs of its three convolutions [K - 1, 3 *
+    attn_dim] in the model's dtype and its matrix [H, D, D] float32."""
     c = model.config
-    if c.layers_of("conv"):
-        return (c.conv_kernel - 1, c.d_model), c.dtype
-    return (c.n_heads, c.head_dim, c.head_dim), jnp.float32
+    matrix = ((c.n_heads, c.head_dim, c.head_dim), jnp.float32)
+    kinds = {"linear": (matrix,),
+             "conv": (((c.conv_kernel - 1, c.d_model), c.dtype),),
+             "kda": (((c.conv_kernel - 1, 3 * c.attn_dim), c.dtype), matrix)}
+    return tuple(kinds[c.layer_spec(i).mixer] for i in c.state_layers)
 
 
 @jax.tree_util.register_dataclass
@@ -210,6 +238,7 @@ def init_cache(model: Transformer, batch: int, max_len: int,
     rings = ring_layers_of(model, max_len)
     pack = heads_per_row(c.kv_heads, c.head_dim)
     sparse, states = c.layers_of("sparse"), c.state_layers
+    latents = c.layers_of("latent")
 
     def parts(count: int, positions: int, dtype) -> tuple:
         # GQA: the cache stores kv_heads (< n_heads) — n_heads/kv_heads x
@@ -220,10 +249,10 @@ def init_cache(model: Transformer, batch: int, max_len: int,
 
     length = jnp.zeros((), jnp.int32)
     if cache_dtype == "int8":
-        if rings or sparse or states:
-            raise ValueError("the int8 cache stores every layer by "
-                             "position; a model with window, sparse, "
-                             "linear or conv layers takes the native cache")
+        if rings or sparse or states or latents:
+            raise ValueError("the int8 cache stores every layer's K/V by "
+                             "position; a model with window, sparse, state "
+                             "or latent layers takes the native cache")
 
         def scales() -> tuple:
             return tuple(jnp.ones((batch, max_len, c.kv_heads), jnp.float32)
@@ -235,10 +264,10 @@ def init_cache(model: Transformer, batch: int, max_len: int,
             k_scale=scales(), v_scale=scales(), length=length,
             max_len=max_len)
     window = c.layer_spec(rings[0]).window if rings else 0
-    if rings and (sparse or states):
-        raise ValueError("rings beside sparse, linear or conv layers: a row "
-                         "holds every layer that keeps K/V, in layer order, "
-                         "and one kind of part beside them")
+    if rings and (sparse or states or latents):
+        raise ValueError("rings beside sparse, state or latent layers: a "
+                         "row holds every layer that keeps K/V, in layer "
+                         "order, and what the other kinds keep beside them")
     if sparse and max_len % c.sparse.block:
         raise ValueError(f"a cache of {max_len} positions does not divide "
                          f"into a sparse layer's blocks of {c.sparse.block}")
@@ -248,18 +277,21 @@ def init_cache(model: Transformer, batch: int, max_len: int,
         return tuple(
             jnp.zeros((batch, c.kv_heads, max_len, c.head_dim), c.dtype)
             if i in sparse else parts(1, max_len, c.dtype)[0]
-            for i in range(c.n_layers) if i not in rings + states)
+            for i in range(c.n_layers) if i not in rings + states + latents)
 
-    shape, dtype = state_shape(model)
     return KVCache(
         k=stored(), v=stored(), length=length,
         wk=parts(len(rings), window, c.dtype),
         wv=parts(len(rings), window, c.dtype),
         ck=tuple(jnp.zeros((batch, c.kv_heads, max_len // c.sparse.stride,
                             c.head_dim), c.dtype) for _ in sparse),
-        state=tuple(jnp.zeros((batch, *shape), dtype) for _ in states),
+        state=tuple(tuple(jnp.zeros((batch, *shape), dtype)
+                          for shape, dtype in layer)
+                    for layer in state_shape(model)),
+        latent=tuple(jnp.zeros((batch, max_len, c.latent_row), c.dtype)
+                     for _ in latents),
         ring_layers=rings, sparse_layers=sparse, state_layers=states,
-        max_len=max_len)
+        latent_layers=latents, max_len=max_len)
 
 
 def ring_of_row(row: Array, length: Array, window: int) -> Array:
@@ -297,13 +329,13 @@ def _seeded(part: Array, block: Array) -> Array:
 
 def check_rolls_back(model: Transformer) -> None:
     """Speculative decoding rolls rejected positions back by moving the
-    cache's length; a linear or conv layer's state has no length to
+    cache's length; a linear, conv or kda layer's states have no length to
     move."""
     if model.config.state_layers:
         raise ValueError(
             "speculative decoding rolls rejected positions back, and a "
-            "linear or conv layer's state cannot be rolled back: decode "
-            "a model with such layers without a draft")
+            "linear, conv or kda layer's state cannot be rolled back: "
+            "decode a model with such layers without a draft")
 
 
 def check_position_budget(model: Transformer, prompt_len: int,
@@ -334,20 +366,22 @@ def prefill(model: Transformer, params: Mapping[str, Array], tokens: Array,
     pack = heads_per_row(c.kv_heads, c.head_dim)
     length = jnp.asarray(prompt_len, jnp.int32)
     states = getattr(cache, "state_layers", ())
-    kvs = [kv for i, kv in enumerate(kept) if i not in states]
-    if states or getattr(cache, "sparse_layers", ()):
+    latents = getattr(cache, "latent_layers", ())
+    kvs = [kv for i, kv in enumerate(kept) if i not in states + latents]
+    if states or latents or getattr(cache, "sparse_layers", ()):
         from ..ops.sparse_attention import compress_keys
 
         def stored(x, i):
             return (x.transpose(0, 2, 1, 3) if cache.by_head(i)
                     else pack_heads(x, pack))
 
-        # (k, v, no rings, the sparse layers' compressed keys, the states)
+        # (k, v, no rings, the sparse layers' compressed keys, the states,
+        # the latent layers' rows)
         fresh = ([stored(k, i) for i, (k, _) in enumerate(kvs)],
                  [stored(v, i) for i, (_, v) in enumerate(kvs)], (), (),
                  [compress_keys(kvs[cache.place(i)[1]][0].transpose(
                      0, 2, 1, 3), c.sparse) for i in cache.sparse_layers],
-                 [kept[i] for i in states])
+                 [kept[i] for i in states], [kept[i] for i in latents])
     elif isinstance(cache, QuantKVCache):
         k, ks = zip(*(_kv_quantize(k) for k, _ in kvs))
         v, vs = zip(*(_kv_quantize(v) for _, v in kvs))
@@ -358,7 +392,7 @@ def prefill(model: Transformer, params: Mapping[str, Array], tokens: Array,
             cache, [pack_heads(k, pack) for k, _ in kvs],   # [B, S, KV', D']
             [pack_heads(v, pack) for _, v in kvs], length)
     return logits[:, -1], dataclasses.replace(cache, length=length, **{
-        name: tuple(map(_seeded, getattr(cache, name), layers))
+        name: jax.tree.map(_seeded, getattr(cache, name), tuple(layers))
         for name, layers in zip(cache.PARTS, fresh)})
 
 
@@ -401,8 +435,14 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
     cached row) takes the window as a mask.  ``route_stats``, where
     given, gains each ``experts`` layer's tokens per expert.
 
-    A LINEAR or CONV layer reads and advances its state (``counts`` keeps
-    pads out of it, and like a ring it cannot be rolled back).  A SPARSE layer
+    A LINEAR, CONV or KDA layer reads and advances its states (``counts``
+    keeps pads out of them, and like a ring they cannot be rolled back).  A
+    LATENT layer writes its rows by position and attends them: a block of
+    ``_BLOCKWISE_QUERIES`` tokens or more against a long cache expands K
+    and V from the rows and runs blockwise; anything shorter, a round's
+    single token first of all, ABSORBS the expansion into the query and the
+    output, so the rows are read as they lie, once for every head
+    (:func:`_latent_cache_attention`).  A SPARSE layer
     writes its K/V by position like a full one and, in a cache that
     reaches ``dense_len``, keeps its compressed keys up to date and
     attends a selection of key blocks: a single token a row gathers them
@@ -512,11 +552,26 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
         router = model.pre_attention_router(lp, p, spec, h)
         # where the layer's part lies among its kind's
         ring, i = ((False, cache.state_layers.index(layer))
-                   if spec.mixer in STATE_MIXERS else cache.place(layer))
-        if spec.mixer == "conv":
+                   if spec.mixer in STATE_MIXERS
+                   else (False, cache.latent_layers.index(layer))
+                   if spec.mixer == "latent" else cache.place(layer))
+        if spec.mixer in ("conv", "kda", "latent"):
             with jax.named_scope("cache_attn"):
-                h, parts["state"][i] = model.conv_residual(
-                    lp, p, h, parts["state"][i], counts)
+                if spec.mixer == "conv":
+                    h, state = model.conv_residual(
+                        lp, p, h, parts["state"][i][0], counts)
+                    parts["state"][i] = (state,)
+                elif spec.mixer == "kda":
+                    h, parts["state"][i] = model.kda_residual(
+                        lp, p, h, parts["state"][i], counts)
+                else:
+                    with jax.named_scope("attn"), jax.named_scope("latent"):
+                        q, rows = model.latent_rows(lp, p, h)
+                        with jax.named_scope("cache_update"):
+                            held = parts["latent"][i] = written(
+                                parts["latent"][i], rows)
+                        h = model.latent_out(lp, p, h, _latent_cache_attention(
+                            model, lp, p, q, held, positions, masks[0]))
             h = ffn(layer, spec, h, router)
             continue
         q, k, v = model.qkv(lp, p, h, positions, spec)  # k/v: [B, T, KV, D]
@@ -525,8 +580,9 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
 
             with jax.named_scope("cache_attn"), jax.named_scope("attn"), \
                     jax.named_scope("linear"):
-                attn, parts["state"][i] = linear_attention(
-                    q, k, v, parts["state"][i], counts, model.LINEAR_CHUNK)
+                attn, state = linear_attention(
+                    q, k, v, parts["state"][i][0], counts, model.LINEAR_CHUNK)
+                parts["state"][i] = (state,)
                 attn = (attn * c.head_dim ** -0.5).astype(c.dtype)
         elif ring:
             attn, parts["wk"][i], parts["wv"][i] = _ring_attention(
@@ -560,6 +616,66 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
     return logits, dataclasses.replace(
         cache, length=cache.length if ragged else pos + t,
         **{name: tuple(layers) for name, layers in parts.items()})
+
+
+def _latent_cache_attention(model: Transformer, params, prefix: str,
+                            q: Array, rows: Array, positions: Array,
+                            mask: Array) -> Array:
+    """A latent layer against its part of the cache.  q [B, T, H, head_dim
+    + qk_shared] at ``positions`` [B, T]; rows [B, M, latent_row] with
+    the block already written; ``mask`` the causal mask
+    [B or 1, 1, 1, T, M].  Two forms of one attention.  A long block
+    against a long cache EXPANDS every position's K and V from its row
+    (``expand``) and runs blockwise, as a full layer's extension does.
+    Anything else ABSORBS the expansion: a head's own query part goes
+    through its key matrix into the latent's space (``absorb``), the
+    scores and the weighted sum are taken against the rows as they lie
+    (``cache``), and the sum comes back through the head's value matrix.
+    Which implementation takes the absorbed queries is
+    ``transformer.latent_decode_arm``'s to say: a round's single token a
+    lane on a TPU the kernel of ops/pallas/latent_decode.py (under
+    ``attn_kernel``: every LIVE position's row is read once for all heads
+    and the positions past a lane's length are not read at all); else
+    plain XLA, where the part is read whole, once for the scores and once
+    for the weighted sum.  Returns attn [B,
+    T, H, head_dim]."""
+    c = model.config
+    t, held = q.shape[1], rows.shape[1]
+    if t >= _BLOCKWISE_QUERIES and held >= model.BLOCKWISE_FROM:
+        from ..ops.blockwise_attention import blockwise_attention
+
+        k, v = model.latent_expand(params, prefix, rows)
+        return blockwise_attention(q, k, v, positions[:, 0])[..., :c.head_dim]
+    up_k, up_v = model.latent_up(params, prefix)
+    with jax.named_scope("absorb"):
+        inner = jnp.einsum("bthd,lhd->bthl", q[..., :c.head_dim], up_k,
+                           preferred_element_type=jnp.float32)
+        wide = jnp.concatenate(
+            [inner.astype(c.dtype), q[..., c.head_dim:],
+             jnp.zeros(q.shape[:3] + (rows.shape[-1] - c.kv_latent
+                                      - c.qk_shared,), c.dtype)],
+            axis=-1)                                       # [B, T, H, row]
+    with jax.named_scope("cache"):
+        if _transformer.latent_decode_arm(wide.shape, rows.shape) == "kernel":
+            from ..ops.pallas import latent_decode
+
+            with jax.named_scope("attn_kernel"):
+                summed = latent_decode.latent_decode_attention(
+                    wide[:, 0], rows, positions[:, 0] + 1,
+                    q.shape[-1] ** -0.5)[:, None]
+        else:
+            scores = jnp.einsum("bthc,bmc->bhtm", wide, rows,
+                                preferred_element_type=jnp.float32)
+            scores = scores / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
+            scores = jnp.where(mask[:, 0], scores, -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
+            # (the whole row: a slice of the part would be a copy of it)
+            summed = jnp.einsum("bhtm,bmc->bthc", probs, rows,
+                                preferred_element_type=jnp.float32)
+    with jax.named_scope("absorb"):
+        return jnp.einsum("bthl,lhd->bthd",
+                          summed[..., :c.kv_latent].astype(c.dtype), up_v,
+                          preferred_element_type=jnp.float32).astype(c.dtype)
 
 
 def _sparse_cache_attention(c, q: Array, keys: Array, values: Array,

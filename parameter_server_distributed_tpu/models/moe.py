@@ -210,6 +210,34 @@ def select_experts(router_logits: Array, top_k: int, score: str = "softmax",
             * scale, top_idx)
 
 
+# a sort of more keys than this takes the TPU compiler 11-12 s to build, in
+# every program that holds one (2.4 s at 16,384, 12.2 s at 20,480, the same
+# at 65,536; compiled for a described v5e, PR 47): past it
+# :func:`_sorted_by_group` counts instead
+_SORT_LIMIT = 16384
+
+
+def _sorted_by_group(keys: Array, groups: int) -> tuple[Array, Array]:
+    """The stable sort of ``keys`` [A] (group ids in [0, groups)) as (order,
+    place): ``keys[order]`` ascends with ties in their first order, and
+    ``place`` is its inverse (assignment a goes to row place[a]).  Up to
+    :data:`_SORT_LIMIT` keys by two sorts; past it by counting (a key's row
+    is its group's first row + how many of its group came before it), the
+    same permutation."""
+    a = keys.shape[0]
+    if a <= _SORT_LIMIT:
+        order = jnp.argsort(keys, stable=True)
+        return order, jnp.argsort(order)
+    member = (keys[:, None] == jnp.arange(groups)[None, :]).astype(jnp.int32)
+    before = jnp.cumsum(member, axis=0) - member           # [A, G]
+    sizes = before[-1] + member[-1]
+    first = jnp.cumsum(sizes) - sizes
+    place = jnp.sum(member * (before + first[None, :]), axis=1)
+    order = jnp.zeros((a,), jnp.int32).at[place].set(
+        jnp.arange(a, dtype=jnp.int32))
+    return order, place
+
+
 def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
                      w3: Array | None, *, top_k: int, act: str = "gelu",
                      score: str = "softmax", bias: Array | None = None,
@@ -251,7 +279,7 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
             chosen.append(top_idx)
         flat = top_idx.reshape(n * top_k)
         if held is None:
-            order = jnp.argsort(flat, stable=True)             # [A]
+            order, place = _sorted_by_group(flat, experts)     # [A]
             loads = groups = jnp.zeros((experts,), jnp.int32).at[flat].add(1)
             rows = x[order // top_k]                           # [A, D]
         else:
@@ -263,7 +291,7 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
             # held one, into no group
             local = flat - first
             local = jnp.where((local >= 0) & (local < count), local, count)
-            order = jnp.argsort(local, stable=True)            # [A]
+            order, place = _sorted_by_group(local, count + 1)  # [A]
             loads = jnp.zeros((count + 1,), jnp.int32).at[local].add(1)
             groups = loads[:count]
             bound = n * min(top_k, count)
@@ -285,7 +313,7 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
             out = jnp.pad(jnp.where(mine, out, 0.0),
                           ((0, n * top_k - bound), (0, 0)))
         # back to (token, choice) order, weighted and summed per token
-        out = out[jnp.argsort(order)].reshape(n, top_k, d)
+        out = out[place].reshape(n, top_k, d)
         return jnp.sum(out * gates[..., None], axis=1), loads
 
 

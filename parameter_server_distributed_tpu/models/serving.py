@@ -34,6 +34,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import os
+import threading
 import time
 import weakref
 from functools import partial
@@ -92,6 +93,27 @@ def _bucket(n: int, lo: int = 16) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _no_layers(positions: int) -> Array:
+    """The K (or V) of a row none of whose layers keeps K/V by position:
+    no layer, ``positions`` wide (a row's first leaf says how wide it is)."""
+    return jnp.zeros((0, positions, 1, 1), jnp.int8)
+
+
+def _row_tail(model: Transformer, row) -> tuple[tuple, tuple]:
+    """What a native row holds past its K and V: (its latent layers' rows,
+    a layer each; its state layers' snapshots, a tuple of states a layer).
+    In the row they lie flat: the latent layers stacked in one leaf, then
+    every state of every state layer a leaf of its own (their shapes may
+    differ: :func:`state_shape`)."""
+    tail = list(row[2:])
+    latent = tuple(tail.pop(0)) if model.config.layers_of("latent") else ()
+    states = []
+    for layer in state_shape(model):
+        states.append(tuple(tail[:len(layer)]))
+        del tail[:len(layer)]
+    return latent, tuple(states)
 
 
 def _row_nbytes(row) -> int:
@@ -156,16 +178,44 @@ def _shard_cache(cache, mesh):
     return jax.tree_util.tree_map(place, cache)
 
 
+def _builds_few(model: Transformer) -> bool:
+    """Whether the server keeps this model's admission programs FEW: a model
+    with kda layers, whose every program is all its layers unrolled around
+    a chunked delta rule (10 to 20 s of the compiler's time each on a cold
+    start; four resident contexts and their turns were 70 programs and 515
+    s, PERF.md section 6, PR 47).  One extension program a prefix bucket
+    (:func:`_suffix_floor`), built ahead (``DecodeServer._build_ahead``),
+    and one prefill program for every prompt of a chunk or more
+    (:func:`_prefills_whole`)."""
+    return bool(model.config.layers_of("kda"))
+
+
+def _suffix_floor(model: Transformer) -> int:
+    """The smallest suffix bucket of an extension: 16; 256 for a model that
+    :func:`_builds_few`.  Every (prefix bucket, suffix bucket) pair is a
+    program of all the layers and a splice for its row's width (8 to 12 s
+    of the compiler's time on a cold start), and such a model's admission is
+    bound by what it READS whatever the block: its weights (a matrix in
+    bfloat16 is read no faster than 240 rows multiply it on a v5e, 197
+    TFLOP/s over 819 GB/s), the row and the snapshot it restores; its delta
+    rule takes a block in chunks of ``DELTA_CHUNK`` either way.  So the
+    turns of a conversation (16 to 256 tokens) share ONE program a prefix
+    bucket, where powers of two from 16 would build five."""
+    return 256 if _builds_few(model) else 16
+
+
 def _prefill_runner(model: Transformer, bucket: int, cache_dtype: str):
     """Jitted per (model, prompt bucket): forward the padded prompt, return
     the last REAL position's logits, the prompt's ROW and the tokens per
     expert of every experts layer ([L * E], else None).  A row is (k, v):
     every layer that keeps K/V by position, heads side by side as the
     cache's parts hold them, [L, S', KV / pack, pack * D] (quantized
-    already when the slot cache is int8, so splicing is dtype-pure); for a
-    model with linear or conv layers (k, v, state), the third their
-    states after the last real position, [L_state, *state_shape]: a
-    SNAPSHOT, good at that depth and no other."""
+    already when the slot cache is int8, so splicing is dtype-pure; no
+    layer at all where none keeps K/V: :func:`_no_layers`).  A model with
+    latent layers adds their rows, [L_latent, S', latent_row];
+    one with state layers every state of every such layer after the last
+    real position, a leaf each (:func:`_row_tail`): a SNAPSHOT, good at
+    that depth and no other."""
     key = (_model_key(model), "serve_prefill", bucket, cache_dtype)
 
     def build():
@@ -183,15 +233,23 @@ def _prefill_runner(model: Transformer, bucket: int, cache_dtype: str):
                     h, real_len - 1, 1, axis=1))[0, 0]      # [vocab]
             c = model.config
             pack = heads_per_row(c.kv_heads, c.head_dim)
-            states = c.state_layers
-            kvs = [kv for i, kv in enumerate(kept) if i not in states]
-            k = jnp.stack([k for k, _ in kvs])[:, 0]        # [L, S', H, D]
-            v = jnp.stack([v for _, v in kvs])[:, 0]
-            if states:
-                # the snapshot: every state layer's state after the last
-                # real position
+            states, latents = c.state_layers, c.layers_of("latent")
+            kvs = [kv for i, kv in enumerate(kept)
+                   if i not in states + latents]
+            if not kvs:
+                k = v = _no_layers(bucket)
+                pack = 1
+            else:
+                k = jnp.stack([k for k, _ in kvs])[:, 0]    # [L, S', H, D]
+                v = jnp.stack([v for _, v in kvs])[:, 0]
+            if states or latents:
+                # the latent layers' rows; then the snapshot: every state
+                # of every state layer after the last real position
+                tail = [jnp.stack([kept[i][0] for i in latents])
+                        ] if latents else []
+                tail += [state[0] for i in states for state in kept[i]]
                 return last, (pack_heads(k, pack), pack_heads(v, pack),
-                              jnp.stack([kept[i][0] for i in states])), loads
+                              *tail), loads
             if cache_dtype == "int8":
                 k, ks = _kv_quantize(k)
                 v, vs = _kv_quantize(v)
@@ -224,9 +282,10 @@ def _splice_runner(model: Transformer, bucket: int, cache_dtype: str):
                     (slot,) + (0,) * (part.ndim - 1))
 
             if cache_dtype != "int8":
-                k, v, *state = row
-                row = split_row(cache, k, v, length)
-                if cache.sparse_layers or cache.state_layers:
+                latent, states = _row_tail(model, row)
+                k, v, wk, wv = split_row(cache, *row[:2], length)
+                ck = ()
+                if cache.sparse_layers:
                     from ..ops.sparse_attention import compress_keys
 
                     # a sparse layer's K/V go in by head; its compressed
@@ -237,12 +296,13 @@ def _splice_runner(model: Transformer, bucket: int, cache_dtype: str):
                     k, v = (tuple(
                         heads_major(layer, kv_heads) if cache.by_head(i)
                         else layer for i, layer in enumerate(layers))
-                        for layers in row[:2])
-                    row = (k, v, (), (), tuple(compress_keys(
+                        for layers in (k, v))
+                    ck = tuple(compress_keys(
                         k[cache.place(i)[1]], model.config.sparse, axis=1)
-                        for i in cache.sparse_layers), *state)
+                        for i in cache.sparse_layers)
+                row = (k, v, wk, wv, ck, states, latent)
             return dataclasses.replace(cache, **{
-                name: tuple(map(put, getattr(cache, name), layers))
+                name: jax.tree.map(put, getattr(cache, name), tuple(layers))
                 for name, layers in zip(cache.PARTS, row)})
 
         return run
@@ -267,9 +327,11 @@ def _row_cache(model: Transformer, row, total: int, cache_dtype: str):
             v_scale=tuple(part(layer, 1) for layer in vs),
             length=length, max_len=total)
     c = model.config
-    k, v, *state = row
+    k, v = row[:2]
+    latent, state = _row_tail(model, row)
     sparse, states = c.layers_of("sparse"), c.state_layers
-    kept = [i for i in range(c.n_layers) if i not in states]
+    latents = c.layers_of("latent")
+    kept = [i for i in range(c.n_layers) if i not in states + latents]
 
     def stored(layers) -> tuple:
         """a sparse layer's part by head, every other as the row has it"""
@@ -284,25 +346,32 @@ def _row_cache(model: Transformer, row, total: int, cache_dtype: str):
         # anew from the keys: decode_block)
         ck=tuple(jnp.zeros((1, c.kv_heads, total // c.sparse.stride,
                             c.head_dim), c.dtype) for _ in sparse),
-        state=tuple(layer[None] for layer in state[0]) if state else (),
-        sparse_layers=sparse, state_layers=states,
+        state=tuple(tuple(leaf[None] for leaf in layer) for layer in state),
+        latent=tuple(map(part, latent)),
+        sparse_layers=sparse, state_layers=states, latent_layers=latents,
         length=length, max_len=total)
 
 
 def _cache_row(cache) -> tuple:
     """The row of a one-slot cache that stores every layer by position
-    (and the states of its linear or conv layers, where it has any)."""
-    def layers(name):
-        held = getattr(cache, name)
-        if name not in ("k", "v") or not getattr(cache, "sparse_layers", ()):
-            return [part[0] for part in held]
-        # (a sparse layer's part lies by head: back to the row's form)
-        return [positions_major(part[0], heads_per_row(*part.shape[1::2]))
-                if cache.by_head(i) else part[0]
-                for i, part in enumerate(held)]
+    (with its latent layers' rows and its state layers' states, where it
+    has any: :func:`_row_tail`)."""
+    if isinstance(cache, QuantKVCache):
+        return tuple(jnp.stack([part[0] for part in getattr(cache, name)])
+                     for name in cache.PARTS)
 
-    return tuple(jnp.stack(layers(name)) for name in cache.PARTS
-                 if name != "ck" and getattr(cache, name))
+    def stacked(held):
+        if not held:
+            return _no_layers(cache.max_len)
+        # (a sparse layer's part lies by head: back to the row's form)
+        return jnp.stack([
+            positions_major(part[0], heads_per_row(*part.shape[1::2]))
+            if cache.by_head(i) else part[0] for i, part in enumerate(held)])
+
+    tail = [jnp.stack([part[0] for part in cache.latent])
+            ] if cache.latent else []
+    tail += [state[0] for layer in cache.state for state in layer]
+    return (stacked(cache.k), stacked(cache.v), *tail)
 
 
 def _extend_runner(model: Transformer, pbucket: int, sbucket: int,
@@ -349,12 +418,21 @@ _PREFILL_CHUNK = 4096
 
 
 def _prefills_whole(model: Transformer, bucket: int) -> bool:
-    """The rule of the two prefill paths, from shapes alone."""
+    """The rule of the two prefill paths, from shapes alone.  A model that
+    :func:`_builds_few` takes every prompt of a chunk or more in chunks:
+    they share ONE program (``DecodeServer._prefill_in_chunks`` fills a
+    row a lane wide and keeps its bucket's worth), where a whole prefill
+    is a program a bucket."""
     c = model.config
+    if _builds_few(model) and bucket >= _PREFILL_CHUNK:
+        return False
     widest = max([c.d_model] + [
         c.d_ff if spec.ffn == "mlp" else c.moe_top_k * (
             c.expert_width if spec.ffn == "experts" else c.d_ff)
-        for spec in c.specs])
+        for spec in c.specs] + [
+        # (a kda layer's q, k and v go through its convolutions side by
+        # side)
+        3 * c.attn_dim for spec in c.specs if spec.mixer == "kda"])
     return bucket * widest <= _PREFILL_WHOLE
 
 
@@ -388,12 +466,14 @@ def _empty_row_runner(model: Transformer, total: int):
     def build():
         c = model.config
         pack = heads_per_row(c.kv_heads, c.head_dim)
-        states = c.state_layers
-        kv = jnp.zeros((c.n_layers - len(states), 16, c.kv_heads // pack,
-                        pack * c.head_dim), c.dtype)
-        shape, dtype = state_shape(model)
-        state = (jnp.zeros((len(states), *shape), dtype),) if states else ()
-        return (jax.jit(lambda: _row_cache(model, (kv, kv, *state), total,
+        latents = c.layers_of("latent")
+        kv = jnp.zeros((c.n_layers - len(c.state_layers) - len(latents), 16,
+                        c.kv_heads // pack, pack * c.head_dim), c.dtype)
+        tail = [jnp.zeros((len(latents), 16, c.latent_row),
+                          c.dtype)] if latents else []
+        tail += [jnp.zeros(shape, dtype) for layer in state_shape(model)
+                 for shape, dtype in layer]
+        return (jax.jit(lambda: _row_cache(model, (kv, kv, *tail), total,
                                            "native")),
                 jax.jit(_cache_row))
 
@@ -580,10 +660,12 @@ class DecodeServer:
         # the layers whose state is a snapshot (good at one depth only):
         # they decide what the prefix tree may match and what cannot be
         # rolled back
-        self._linear_layers = len(config.layers_of("linear"))
+        self._linear_layers = len(config.layers_of("linear")
+                                  + config.layers_of("kda"))
         self._conv_layers = len(config.layers_of("conv"))
         self._state_layers = self._linear_layers + self._conv_layers
         self._sparse_layers = len(config.layers_of("sparse"))
+        self._latent_layers = len(config.layers_of("latent"))
         if draft is not None:
             check_rolls_back(model)
             check_rolls_back(draft)
@@ -657,15 +739,17 @@ class DecodeServer:
                                       "admit_experts_touched")}
         for kind, held in self._cache_bytes_by_kind().items():
             obs_stats.gauge(f"serve.cache.{kind}_bytes").set(held)
-        # what a round's sparse, linear and conv layers read (see
-        # _count_mixers)
+        # what a round's sparse, linear (kda too), conv and latent layers
+        # read (see _count_mixers)
         self._obs_mixers = {
             name: obs_stats.counter(name) for name in (
                 "serve.sparse.positions_selected",
                 "serve.sparse.positions_cached",
                 "serve.sparse.kernels_scored",
                 "serve.linear.state_updates",
-                "serve.conv.state_updates")}
+                "serve.conv.state_updates",
+                "serve.latent.positions_read",
+                "serve.latent.positions_cached")}
         # the mark (obs/legs.py: perf_counter first) at the last round's
         # return, while a slot is active
         self._round_returned: tuple | None = None
@@ -694,6 +778,9 @@ class DecodeServer:
             budget, snapshots=bool(self._state_layers))
             if prompt_cache else None)
         self._prompt_hits = 0
+        # extension programs being built ahead, by (prefix bucket, suffix
+        # bucket): _build_ahead
+        self._ahead: dict[tuple[int, int], threading.Thread] = {}
         # shared-PREFIX reuse: a miss whose prompt shares a cached
         # prefix forwards only the suffix (_extend_runner).  Speculative
         # mode extends the DRAFT row from the same tree node alongside
@@ -930,7 +1017,11 @@ class DecodeServer:
         pre_row = node.handle.row
         pbucket = int(pre_row[0].shape[1])
         slen = real_len - plen
-        sbucket = _bucket(slen)
+        sbucket = _bucket(slen, _suffix_floor(self.model))
+        if pbucket + sbucket > self.max_len:
+            # the floor's bucket does not fit the lane beside the prefix:
+            # the suffix's own (a prefill of the whole prompt costs more)
+            sbucket = _bucket(slen)
         if pbucket + sbucket > self.max_len:
             return None  # combined row would overflow the slot cache
         with self._forward_leg(slen):
@@ -939,6 +1030,9 @@ class DecodeServer:
             suffix = jnp.asarray(padded)
             plen_j = jnp.asarray(plen, jnp.int32)
             slen_j = jnp.asarray(slen, jnp.int32)
+            ahead = self._ahead.get((pbucket, sbucket))
+            if ahead is not None:
+                ahead.join()    # (at once, but for the first few seconds)
             last, row, loads = _extend_runner(self.model, pbucket, sbucket,
                                               self.cache_dtype)(
                 self.params, pre_row, suffix, plen_j, slen_j)
@@ -962,13 +1056,44 @@ class DecodeServer:
         self._prefill_tokens += slen
         return last, row, d_row, loads
 
+    def _build_ahead(self, row) -> None:
+        """Build, on a thread of its own, the program that will extend the
+        row a PREFILL just put into the tree (a system prompt, a document:
+        what others' prompts begin with), for a model that
+        :func:`_builds_few` (one suffix bucket serves its every turn,
+        :func:`_suffix_floor`): the compiler works on
+        it while the caller prefills the next prompt, and a cold start
+        with four resident contexts builds its extensions beside its
+        prefills, not after them.  The program is run once on the row and a
+        block of zeros, as any program's first call builds it; the
+        admission that needs it first waits for the thread
+        (:meth:`_radix_extend`).  A row an extension made is one
+        conversation's own and starts nothing."""
+        floor = _suffix_floor(self.model)
+        pbucket = int(row[0].shape[1])
+        if (not _builds_few(self.model) or self.draft is not None
+                or (pbucket, floor) in self._ahead
+                or pbucket + floor > self.max_len):
+            return
+        run = _extend_runner(self.model, pbucket, floor, self.cache_dtype)
+        one = jnp.asarray(1, jnp.int32)
+        args = (self.params, row, jnp.zeros((1, floor), jnp.int32), one, one)
+        thread = threading.Thread(
+            target=lambda: jax.block_until_ready(run(*args)), daemon=True,
+            name=f"psdt-build-ahead-{pbucket}+{floor}")
+        self._ahead[pbucket, floor] = thread
+        thread.start()
+
     def _prefill_in_chunks(self, padded: np.ndarray, real_len: int):
         """A long prompt (``padded`` [1, bucket]) through _chunk_runner,
         ``_PREFILL_CHUNK`` tokens at a time against the row so far; returns
         (the last real position's logits, the row)."""
         bucket = padded.shape[1]
-        empty, row_of = _empty_row_runner(self.model, bucket)
-        run = _chunk_runner(self.model, bucket)
+        # (one program for every length: the row is filled a lane wide and
+        # its bucket's positions are kept)
+        total = self.max_len if _builds_few(self.model) else bucket
+        empty, row_of = _empty_row_runner(self.model, total)
+        run = _chunk_runner(self.model, total)
         cache = empty()
         for done in range(0, real_len, _PREFILL_CHUNK):
             tokens = np.zeros((1, _PREFILL_CHUNK), np.int32)
@@ -978,7 +1103,14 @@ class DecodeServer:
                 self.params, jnp.asarray(tokens), cache,
                 jnp.asarray(done, jnp.int32),
                 jnp.asarray(min(_PREFILL_CHUNK, real_len - done), jnp.int32))
-        return last, row_of(cache)
+        row = row_of(cache)
+        if total != bucket:
+            # K, V and the latent layers' rows lie by position; a snapshot
+            # has no positions
+            lead = 3 if self._latent_layers else 2
+            row = tuple(leaf[:, :bucket] if i < lead else leaf
+                        for i, leaf in enumerate(row))
+        return last, row
 
     def _forward_leg(self, forwarded: int):
         """The leg in which an admission's forward goes to the device:
@@ -1182,6 +1314,8 @@ class DecodeServer:
                                 self.draft_params, jnp.asarray(padded),
                                 jnp.asarray(real_len, jnp.int32))
                     self._prefill_tokens += real_len
+                    if tree is not None:
+                        self._build_ahead(row)
                 self._prompt_tokens += real_len
                 if tree is not None:
                     self._admit_to_tree(pkey, last, row, d_row)
@@ -1511,17 +1645,19 @@ class DecodeServer:
             return self._cache.nbytes_by_kind()
         return {"full": sum(int(leaf.nbytes) for leaf in
                             jax.tree_util.tree_leaves(self._cache)),
-                "window": 0, "state": 0}
+                "window": 0, "state": 0, "latent": 0}
 
     def _count_mixers(self, selected: np.ndarray | None,
                       positions: int) -> None:
-        """One decode round into the counters the sparse, linear and conv
-        layers' metrics divide.  ``selected`` is the round's own
+        """One decode round into the counters the sparse, linear, conv and
+        latent layers' metrics divide.  ``selected`` is the round's own
         [positions attended, kernels scored] over its sparse layers and
         every lane (idle ones too: the device computes them); beside it
         ``positions``, what those lanes held in THAT round (each lane's
         length with its new token; a sparse layer each) and the states
-        the round advanced (a lane and linear or conv layer each)."""
+        the round advanced (a lane and linear, kda or conv layer each).  A
+        latent layer needs its lanes' ``positions`` and reads its whole
+        part: both are counted, a latent layer each."""
         if selected is not None:
             self._obs_mixers["serve.sparse.positions_selected"].add(
                 float(selected[0]))
@@ -1535,6 +1671,11 @@ class DecodeServer:
         if self._conv_layers:
             self._obs_mixers["serve.conv.state_updates"].add(
                 self.slots * self._conv_layers)
+        if self._latent_layers:
+            self._obs_mixers["serve.latent.positions_read"].add(
+                float(self._latent_layers * positions))
+            self._obs_mixers["serve.latent.positions_cached"].add(
+                float(self._latent_layers * self.slots * self.max_len))
 
     def _count_routing(self, loads: np.ndarray,
                        admission: bool = False) -> None:
@@ -1623,6 +1764,8 @@ class DecodeServer:
         out["cache_window_bytes"] = kinds["window"]
         if kinds["state"]:
             out["cache_state_bytes"] = kinds["state"]
+        if kinds["latent"]:
+            out["cache_latent_bytes"] = kinds["latent"]
         if self._moe_layers:
             out["moe_assignments"] = self._moe_assignments
         if self.draft is not None:

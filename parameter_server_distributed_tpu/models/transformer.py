@@ -40,9 +40,9 @@ Array = jax.Array
 
 
 FFN_KINDS = ("mlp", "moe", "experts")
-MIXER_KINDS = ("softmax", "sparse", "linear", "conv")
+MIXER_KINDS = ("softmax", "sparse", "linear", "conv", "kda", "latent")
 # the mixers that keep a fixed-size STATE in a decode cache and no K/V
-STATE_MIXERS = ("linear", "conv")
+STATE_MIXERS = ("linear", "conv", "kda")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,7 +73,15 @@ class LayerSpec:
     # state (ops/linear_attention.py): no K/V by position, a fixed-size
     # state instead.  conv: a gated depthwise causal convolution of
     # ``config.conv_kernel`` taps (ops/short_conv.py): no heads, no K/V,
-    # its state the last ``conv_kernel - 1`` gated inputs
+    # its state the last ``conv_kernel - 1`` gated inputs.  kda: a per-head
+    # [D, D] state decayed a key channel and corrected by the delta rule
+    # (ops/delta_attention.py) behind short convolutions of q, k and v, with
+    # a low-rank decay gate and output gate: TWO states, the convolutions'
+    # shift register and the matrix.  latent: causal attention without
+    # rotary whose K and V are expanded from ONE normed latent of
+    # ``config.kv_latent`` a position, with a key part of
+    # ``config.qk_shared`` all heads share beside it; its cache keeps that
+    # row, not K/V
     mixer: str = "softmax"
     # K/V heads of this layer; 0 = the config's
     kv_heads: int = 0
@@ -95,10 +103,14 @@ class LayerSpec:
                              f"got {self.mixer!r}")
         if self.mixer != "softmax" and self.window:
             raise ValueError("a window belongs to a softmax layer")
-        if self.mixer == "conv" and (self.kv_heads or self.qk_norm
-                                     or self.gate or self.out_norm):
-            raise ValueError("a conv layer has no heads: kv_heads, qk_norm, "
-                             "gate and out_norm belong to attention")
+        if (self.mixer in ("conv", "kda", "latent")
+                and (self.kv_heads or self.qk_norm or self.gate
+                     or self.out_norm)):
+            raise ValueError(
+                "a conv layer has no heads, a kda layer norms and gates its "
+                "output always and a latent layer has one latent for every "
+                "head: kv_heads, qk_norm, gate and out_norm belong to "
+                "softmax, sparse and linear layers")
         if self.window < 0:
             raise ValueError(f"window must be >= 0, got {self.window}")
 
@@ -189,8 +201,12 @@ class TransformerConfig:
     # MLP of ``mlp_act``'s form and this many experts' width on every
     # token (``moe/shared/w1|w2|w3``), added ungated to the routed part
     moe_shared_experts: int = 0
-    # taps of a ``conv`` layer's kernel
+    # taps of a ``conv`` layer's kernel, and of a ``kda`` layer's three
     conv_kernel: int = 3
+    # a ``latent`` layer's compressed K/V: the width of the normed latent a
+    # position keeps, and of the key part every head shares beside it
+    kv_latent: int = 0
+    qk_shared: int = 0
     # Scan over layers: store block weights stacked with a leading [L]
     # axis (``blocks/<suffix>``) and run the layer loop as one
     # ``lax.scan`` body traced ONCE, instead of n_layers Python-unrolled
@@ -285,23 +301,33 @@ class TransformerConfig:
         mixers = {spec.mixer for spec in self.specs}
         if "sparse" in mixers and self.sparse is None:
             raise ValueError("a sparse layer needs config.sparse")
-        if set(STATE_MIXERS) <= mixers:
-            raise ValueError("linear beside conv layers: a row's snapshot "
-                             "stacks its layers' states, so a model keeps "
-                             "one shape of state")
-        if "conv" in mixers and (self.conv_kernel < 2 or self.bias):
-            raise ValueError(f"a conv layer has a kernel of 2 taps or more "
-                             f"and no bias, got conv_kernel="
+        if mixers & {"conv", "kda"} and (self.conv_kernel < 2 or self.bias):
+            raise ValueError(f"a conv or kda layer has a kernel of 2 taps or "
+                             f"more and no bias, got conv_kernel="
                              f"{self.conv_kernel}, bias={self.bias}")
+        if "latent" in mixers and (self.kv_latent < 1 or self.bias
+                                   or self.pos_emb == "learned"):
+            raise ValueError("a latent layer needs config.kv_latent, and "
+                             "has no bias and no learned positions")
         if (mixers - {"softmax"} or self.prologue) and self.scan_layers:
             raise ValueError("scan_layers stacks one kind of cache part a "
-                             "layer and scans whole periods: sparse, linear "
-                             "and conv layers and a prologue run unrolled")
+                             "layer and scans whole periods: sparse, linear, "
+                             "conv, kda and latent layers and a prologue run "
+                             "unrolled")
 
     @property
     def attn_dim(self) -> int:
         """Width of the attention's inner side: n_heads * head_dim."""
         return self.n_heads * self.head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """Lanes of the row a latent layer's cache keeps a position: the
+        latent and the shared key part side by side, padded with zeros to
+        whole registers of 128 (512 + 64 -> 640).  A row of 576 the device
+        would lay with positions along the lanes, and a round would copy
+        the part into rows and back (generation.heads_per_row)."""
+        return -(-(self.kv_latent + self.qk_shared) // 128) * 128
 
     @property
     def gated_mlp(self) -> bool:
@@ -577,6 +603,39 @@ def device_arm(q_shape: tuple[int, ...], kv_shape: tuple[int, ...],
     return "blockwise" if q_shape[1] >= blockwise_from else "dense"
 
 
+def latent_decode_arm(q_shape: tuple[int, ...],
+                      rows_shape: tuple[int, ...]) -> str:
+    """Which implementation attends a latent layer's ABSORBED queries (q
+    [B, T, H, W], as wide as the rows [B, M, W] of its part of a cache) on
+    one device, beside :func:`device_arm` and by its rule (shapes and the
+    backend, nothing else):
+
+    ``kernel`` — ops/pallas/latent_decode.py, a decode round's single token
+                 a lane on a TPU: every live row read once for all heads;
+    ``dense``  — the two einsums against the part as it lies (read whole,
+                 twice): a block of several tokens, or any backend but a TPU.
+
+    A round's token on a TPU whose shapes the kernel does not take is
+    REFUSED, not sent down the einsums: they cost eight times the kernel a
+    round (4.5 ms a layer against 0.54 at 64 lanes x 16,384 positions;
+    PERF.md, PR 47), and a server that slow would only read as a low
+    roofline."""
+    if q_shape[1] != 1 or not _kernel_backend():
+        return "dense"
+    # (pallas is imported where a kernel can run, and only there)
+    from ..ops.pallas import latent_decode
+
+    if not latent_decode.fits((q_shape[0],) + tuple(q_shape[2:]),
+                              rows_shape):
+        raise ValueError(
+            f"a latent layer's decode round on a TPU runs "
+            f"ops/pallas/latent_decode.py, which takes heads in 16s, rows "
+            f"of whole 128-lane registers and a cache of whole blocks of "
+            f"{latent_decode.BLOCK} positions; got queries {q_shape} "
+            f"against rows {rows_shape}")
+    return "kernel"
+
+
 def attend_by(arm: str, q: Array, k: Array, v: Array,
               window: int = 0) -> Array:
     """Run ``arm`` of :func:`device_arm` on a device's own q, k, v; the
@@ -672,6 +731,38 @@ class Transformer:
                      "conv/in_proj": (c.d_model, 3 * c.d_model),
                      "conv/kernel": (c.conv_kernel, c.d_model),
                      "conv/out_proj": (c.d_model, c.d_model),
+                     "ln2/scale": (c.d_model,)}
+        elif spec.mixer == "kda":
+            # the gates' inner width is a head's (the published layer's)
+            rank = c.head_dim
+            block = {"ln1/scale": (c.d_model,),
+                     "attn/wq": (c.d_model, c.attn_dim),
+                     "attn/wk": (c.d_model, c.attn_dim),
+                     "attn/wv": (c.d_model, c.attn_dim),
+                     "attn/conv_q": (c.conv_kernel, c.attn_dim),
+                     "attn/conv_k": (c.conv_kernel, c.attn_dim),
+                     "attn/conv_v": (c.conv_kernel, c.attn_dim),
+                     "attn/decay/wa": (c.d_model, rank),
+                     "attn/decay/wb": (rank, c.attn_dim),
+                     "attn/decay/a_log": (c.n_heads,),
+                     "attn/decay/dt_bias": (c.attn_dim,),
+                     "attn/gate/wa": (c.d_model, rank),
+                     "attn/gate/wb": (rank, c.attn_dim),
+                     "attn/beta/w": (c.d_model, c.n_heads),
+                     "attn/o_norm/scale": (c.head_dim,),
+                     "attn/wo": (c.attn_dim, c.d_model),
+                     "ln2/scale": (c.d_model,)}
+        elif spec.mixer == "latent":
+            # wq: every head's query, its own part then the shared one;
+            # wkv_a: the latent and the shared key part; wkv_b: every
+            # head's key part and value from the normed latent
+            block = {"ln1/scale": (c.d_model,),
+                     "attn/wq": (c.d_model,
+                                 c.n_heads * (c.head_dim + c.qk_shared)),
+                     "attn/wkv_a": (c.d_model, c.kv_latent + c.qk_shared),
+                     "attn/kv_norm/scale": (c.kv_latent,),
+                     "attn/wkv_b": (c.kv_latent, 2 * c.attn_dim),
+                     "attn/wo": (c.attn_dim, c.d_model),
                      "ln2/scale": (c.d_model,)}
         else:
             block = {"ln1/scale": (c.d_model,),
@@ -776,8 +867,21 @@ class Transformer:
             attn_mult = 16.0
             if c.remat_policy == "full":
                 params_mult = 8.0
-        return (params_mult * n_params * seq
-                + attn_mult * c.n_layers * c.d_model * seq * seq)
+        # attention's products, a token: scores and values over S keys of
+        # d_model (a latent layer's: its heads' own width and the shared key
+        # part for the scores, its heads' for the values); a kda layer's are
+        # three products with its [D, D] states and do not grow with S
+        attn = 0.0
+        for i in range(c.n_layers):
+            mixer = c.layer_spec(i).mixer
+            if mixer == "kda":
+                attn += attn_mult * 1.5 * c.attn_dim * c.head_dim
+            elif mixer == "latent":
+                attn += attn_mult * seq * (c.attn_dim
+                                           + c.n_heads * c.qk_shared / 2)
+            else:
+                attn += attn_mult * c.d_model * seq
+        return params_mult * n_params * seq + attn * seq
 
     def _remat_policy(self):
         """config.remat_policy -> jax.checkpoint policy (None = save
@@ -800,6 +904,11 @@ class Transformer:
                 params[name] = jnp.zeros(shape, c.dtype)
             elif name in ("embed/tok", "embed/pos"):
                 params[name] = jax.random.normal(sub, shape, c.dtype) * 0.02
+            elif name.endswith("/decay/a_log"):
+                params[name] = jnp.zeros(shape, c.dtype)
+            elif name.endswith("/decay/dt_bias"):
+                # softplus(-4) = 0.018: a channel keeps 98% a position
+                params[name] = jnp.full(shape, -4.0, c.dtype)
             else:
                 # fan-in: leading dim for 2D weights, middle dim for the
                 # per-expert [E, in, out] MoE weights
@@ -954,6 +1063,147 @@ class Transformer:
             out = wdot(mixed, params[f"{prefix}/conv/out_proj"],
                        preferred_element_type=jnp.float32)
             return self._residual(params, f"{prefix}/ln1", h, out), state
+
+    # positions a kda layer works through at a time (a [C, C, D] term a
+    # head: ops/delta_attention.py)
+    DELTA_CHUNK = 64
+
+    def kda_residual(self, params: Mapping[str, Array], prefix: str,
+                     h: Array, state: tuple | None = None,
+                     counts: Array | None = None) -> tuple[Array, tuple]:
+        """A ``kda`` layer's whole mixer branch, under ``attn/linear``
+        (``conv``, ``gates``, ``delta`` inside): with x = ln1(h),
+        q, k = l2norm(silu(conv(x W_q))), l2norm(silu(conv(x W_k))),
+        v = silu(conv(x W_v)); a log-decay a head and key channel
+        -exp(a_log) * softplus(W_b (W_a x) + dt_bias); a write strength a
+        head sigmoid(W_beta x); the gated delta rule over them
+        (ops/delta_attention.py), its result over sqrt(head_dim), normed a
+        head, times sigmoid(W_gb (W_ga x)), through W_o.  h [B, T, d] at T
+        consecutive positions; ``state`` is (the three convolutions' shift
+        register [B, K - 1, 3 * attn_dim], the matrix [B, H, D, D] float32)
+        of the positions before them (None: the sequence starts here) and
+        ``counts`` [B] says how many of the T are real (None: all).
+        Returns (new h, both states after the last real position): one
+        function for a whole sequence, a block against cached states and a
+        decode round's single token."""
+        from ..ops.delta_attention import gated_delta_rule
+        from ..ops.short_conv import short_conv
+
+        c = self.config
+        batch, seq = h.shape[:2]
+        shift, matrix = state if state is not None else (None, None)
+        dot = partial(wdot, preferred_element_type=jnp.float32)
+        attn = f"{prefix}/attn"
+        with jax.named_scope("attn"), jax.named_scope("linear"):
+            x = self._branch_input(params, f"{prefix}/ln1", h)
+            with jax.named_scope("conv"):
+                qkv = jnp.concatenate(
+                    [dot(x, params[f"{attn}/w{n}"]).astype(c.dtype)
+                     for n in "qkv"], axis=-1)
+                kernel = jnp.concatenate(
+                    [params[f"{attn}/conv_{n}"] for n in "qkv"], axis=-1)
+                mixed, shift = short_conv(qkv, kernel, shift, counts)
+                q, k, v = (part.reshape(batch, seq, c.n_heads, c.head_dim)
+                           for part in jnp.split(jax.nn.silu(mixed), 3,
+                                                 axis=-1))
+                q, k = (part * jax.lax.rsqrt(
+                    jnp.sum(part * part, axis=-1, keepdims=True) + 1e-6)
+                    for part in (q, k))
+            with jax.named_scope("gates"):
+                def low_rank(name):
+                    inner = dot(x, params[f"{attn}/{name}/wa"]).astype(c.dtype)
+                    return dot(inner, params[f"{attn}/{name}/wb"])
+
+                rate = jnp.exp(params[f"{attn}/decay/a_log"].astype(
+                    jnp.float32))[:, None]
+                fall = -rate * jax.nn.softplus(
+                    low_rank("decay") + params[f"{attn}/decay/dt_bias"].astype(
+                        jnp.float32)).reshape(batch, seq, c.n_heads,
+                                              c.head_dim)
+                beta = jax.nn.sigmoid(dot(x, params[f"{attn}/beta/w"]))
+                gate = jax.nn.sigmoid(low_rank("gate")).astype(c.dtype)
+            with jax.named_scope("delta"):
+                out, matrix = gated_delta_rule(q, k, v, fall, beta, matrix,
+                                               counts, self.DELTA_CHUNK)
+                out = (out * c.head_dim ** -0.5).astype(c.dtype)
+            out = rms_norm(out, params[f"{attn}/o_norm/scale"], c.norm_eps)
+            out = dot(out.reshape(batch, seq, c.attn_dim) * gate,
+                      params[f"{attn}/wo"])
+            return (self._residual(params, f"{prefix}/ln1", h, out),
+                    (shift, matrix))
+
+    def latent_rows(self, params: Mapping[str, Array], prefix: str,
+                    h: Array) -> tuple[Array, Array]:
+        """A ``latent`` layer's queries and what its cache keeps of the
+        same positions: (q [B, T, H, head_dim + qk_shared], rows [B, T,
+        latent_row]: the RMS-normed latent and the key part all heads
+        share, side by side, then zeros to whole registers).  No rotary
+        anywhere."""
+        c = self.config
+        batch, seq = h.shape[:2]
+        dot = partial(wdot, preferred_element_type=jnp.float32)
+        x = self._branch_input(params, f"{prefix}/ln1", h)
+        q = dot(x, params[f"{prefix}/attn/wq"]).astype(c.dtype).reshape(
+            batch, seq, c.n_heads, c.head_dim + c.qk_shared)
+        kv = dot(x, params[f"{prefix}/attn/wkv_a"]).astype(c.dtype)
+        latent = rms_norm(kv[..., :c.kv_latent],
+                          params[f"{prefix}/attn/kv_norm/scale"], c.norm_eps)
+        rows = jnp.concatenate([latent, kv[..., c.kv_latent:]], axis=-1)
+        return q, jnp.pad(rows, ((0, 0), (0, 0),
+                                 (0, c.latent_row - rows.shape[-1])))
+
+    def latent_up(self, params: Mapping[str, Array],
+                  prefix: str) -> tuple[Array, Array]:
+        """``wkv_b`` by head: (every head's key part [kv_latent, H,
+        head_dim], every head's value [kv_latent, H, head_dim])."""
+        c = self.config
+        up = params[f"{prefix}/attn/wkv_b"].reshape(
+            c.kv_latent, c.n_heads, 2 * c.head_dim)
+        return up[..., :c.head_dim], up[..., c.head_dim:]
+
+    def latent_expand(self, params: Mapping[str, Array], prefix: str,
+                      rows: Array) -> tuple[Array, Array]:
+        """K and V of ``rows`` [B, M, latent_row] for an
+        attention that knows nothing of latents, under ``expand``: K [B, M,
+        H, head_dim + qk_shared] (a head's own part, then the shared one)
+        and V the same width, zeros past ``head_dim`` (the attentions here
+        take one width for keys and values, and 1 / sqrt of it for the
+        scale: the keys')."""
+        c = self.config
+        with jax.named_scope("expand"):
+            up_k, up_v = self.latent_up(params, prefix)
+            latent = rows[..., :c.kv_latent]
+            shared = rows[..., c.kv_latent:c.kv_latent + c.qk_shared]
+            k = jnp.einsum("bml,lhd->bmhd", latent, up_k,
+                           preferred_element_type=jnp.float32).astype(c.dtype)
+            v = jnp.einsum("bml,lhd->bmhd", latent, up_v,
+                           preferred_element_type=jnp.float32).astype(c.dtype)
+            k = jnp.concatenate([k, jnp.broadcast_to(
+                shared[:, :, None, :], k.shape[:3] + (c.qk_shared,))], axis=-1)
+            return k, jnp.pad(v, ((0, 0),) * 3 + ((0, c.qk_shared),))
+
+    def latent_out(self, params: Mapping[str, Array], prefix: str,
+                   h: Array, attn: Array) -> Array:
+        """h + wo(attn) for a latent layer's attn [B, T, H, head_dim]."""
+        out = wdot(attn.reshape(*attn.shape[:2], self.config.attn_dim),
+                   params[f"{prefix}/attn/wo"],
+                   preferred_element_type=jnp.float32)
+        return self._residual(params, f"{prefix}/ln1", h, out)
+
+    def latent_residual(self, params: Mapping[str, Array], prefix: str,
+                        h: Array) -> tuple[Array, Array]:
+        """A ``latent`` layer's whole mixer branch over a whole sequence,
+        under ``attn/latent``: K and V expanded from the rows, causal
+        softmax of q k^T / sqrt(head_dim + qk_shared) by the device's arm
+        (:func:`device_arm`).  Returns (new h, the rows [B, S, latent_row]
+        a cache keeps)."""
+        c = self.config
+        with jax.named_scope("attn"), jax.named_scope("latent"):
+            q, rows = self.latent_rows(params, prefix, h)
+            k, v = self.latent_expand(params, prefix, rows)
+            attn = attend_by(device_arm(q.shape, k.shape), q, k, v)
+            return self.latent_out(params, prefix, h,
+                                   attn[..., :c.head_dim]), rows
 
     def _mlp(self, params: Mapping[str, Array], key: str, x: Array,
              bias: bool = False) -> Array:
@@ -1168,7 +1418,8 @@ class Transformer:
             counts: Array | None = None, selections: list | None = None):
         """A whole sequence through the layer's mixer: (attn [B, S, H, D],
         what a cache keeps of the layer: its (k, v), or a linear layer's
-        state after the last real position, ``counts`` [B] of them).  A
+        state after the last real position, ``counts`` [B] of them, as a
+        tuple of one: a state layer keeps a tuple of states).  A
         sparse layer whose sequence reaches ``dense_len`` selects key
         blocks (under ``attn/sparse``: ``select``, ``attend``) and, where
         ``selections`` is given, adds its selection [B, KV, S, NB] to it;
@@ -1181,7 +1432,7 @@ class Transformer:
             with jax.named_scope("attn"), jax.named_scope("linear"):
                 out, state = linear_attention(q, k, v, counts=counts,
                                               chunk=self.LINEAR_CHUNK)
-                return (out / math.sqrt(c.head_dim)).astype(c.dtype), state
+                return (out / math.sqrt(c.head_dim)).astype(c.dtype), (state,)
         if spec.mixer == "sparse" and q.shape[1] >= c.sparse.dense_len:
             from ..ops.sparse_attention import (compress_keys,
                                                 sparse_blockwise_attention)
@@ -1200,12 +1451,18 @@ class Transformer:
         return self.attend(q, k, v, spec), (k, v)
 
     def expert_selections(self, params: Mapping[str, Array],
-                          tokens: Array) -> list:
+                          tokens: Array, kept: list | None = None) -> list:
         """Which experts every token of every ``experts`` layer took in
         the forward pass of ``tokens`` [B, S]: [B, S, k] a layer, in layer
-        order."""
+        order.  ``kept``, a list, gains what a cache keeps of every layer
+        after that same pass (:meth:`_forward`'s second result: a state
+        layer's tuple of states, a latent layer's rows, else (k, v))."""
         chosen: list = []
-        self._forward(params, tokens, collect_kv=False, chosen=chosen)
+        _, layers, _ = self._forward(params, tokens,
+                                     collect_kv=kept is not None,
+                                     chosen=chosen)
+        if kept is not None:
+            kept.extend(layers)
         return chosen
 
     def sparse_selections(self, params: Mapping[str, Array],
@@ -1253,8 +1510,10 @@ class Transformer:
                  selections: list | None = None,
                  chosen: list | None = None,
                  ) -> tuple[Array, list, Array]:
-        """(h, what a cache keeps of every layer (see :meth:`mix` and
-        :meth:`conv_residual`) under ``collect_kv``, aux loss).  ``counts``
+        """(h, what a cache keeps of every layer under ``collect_kv``: a
+        (k, v), a state layer's tuple of states (see :meth:`mix`,
+        :meth:`conv_residual` and :meth:`kda_residual`) or a latent layer's
+        rows (:meth:`latent_residual`); aux loss).  ``counts``
         [B]: how many of a row's tokens are real, for the layers whose
         state must not hold a pad."""
         c = self.config
@@ -1278,6 +1537,12 @@ class Transformer:
             if spec.mixer == "conv":
                 h, kept = self.conv_residual(layer_params, p, h,
                                              counts=counts)
+                kept = (kept,)
+            elif spec.mixer == "kda":
+                h, kept = self.kda_residual(layer_params, p, h,
+                                            counts=counts)
+            elif spec.mixer == "latent":
+                h, kept = self.latent_residual(layer_params, p, h)
             else:
                 q, k, v = self.qkv(layer_params, p, h, positions, spec)
                 # K/V go to the attention fn UNexpanded (kv_heads-sized);
@@ -1466,8 +1731,11 @@ def unstack_layers(params: Mapping[str, Array]) -> dict:
 def transformer_rule(mesh: Mesh):
     """Sharding rule for transformer stores: Megatron TP + fsdp (+ EP).
 
-    column-parallel (tensor on output dim): wq wk wv w1 lm_head
+    column-parallel (tensor on output dim): wq wk wv w1 lm_head, and by
+        head a latent layer's wkv_b and a kda layer's gates' second halves
     row-parallel  (tensor on input dim):    wo w2
+    (a kda layer's conv kernels, a_log, dt_bias, beta and its gates' first
+    halves, a latent layer's wkv_a: small, replicated)
     (a shared expert's ``moe/shared/w*`` as the dense MLP's)
     vocab-sharded embedding; norm scales replicated (fsdp if divisible);
     MoE expert weights sharded over the ``expert`` axis (router replicated).
@@ -1495,7 +1763,8 @@ def transformer_rule(mesh: Mesh):
         # one shard's slice every scan step
         if name.endswith(("attn/wq", "attn/wk", "attn/wv", "mlp/w1",
                           "mlp/w3", "moe/shared/w1", "moe/shared/w3",
-                          "lm_head/w")):
+                          "lm_head/w", "attn/wkv_b", "attn/decay/wb",
+                          "attn/gate/wb")):
             taken = len(shape) - 1 if n_tp > 1 and shape[-1] % n_tp == 0 else None
             return PartitionSpec(*fsdp_on(len(shape) - 2, taken))
         if name.endswith(("attn/wo", "mlp/w2", "moe/shared/w2")):
@@ -1514,7 +1783,9 @@ def transformer_rule(mesh: Mesh):
                      if n_tp > 1 and shape[-1] % n_tp == 0 else None)
             return PartitionSpec(*fsdp_on(0, taken))
         if name.endswith(("/scale", "/bias", "/bq", "/bk", "/bv", "/bo",
-                          "/b1", "/b2")):
+                          "/b1", "/b2", "/a_log", "/dt_bias", "attn/conv_q",
+                          "attn/conv_k", "attn/conv_v", "attn/wkv_a",
+                          "attn/decay/wa", "attn/gate/wa", "attn/beta/w")):
             # norm scales and all biases: tiny 1-D vectors, replicated like
             # their paired scales (an fsdp-sharded bias would force a
             # per-use all-gather against its tensor-sharded activation)

@@ -27,6 +27,29 @@ import jax.numpy as jnp
 Array = jax.Array
 
 
+def short_conv(x: Array, kernel: Array, state: Array | None = None,
+               counts: Array | None = None) -> tuple[Array, Array]:
+    """The depthwise causal convolution alone: x [B, T, d] at T consecutive
+    positions, ``kernel`` [K, d], ``state`` [B, K - 1, d] the inputs of the
+    K - 1 positions before them, oldest first (zeros where None),
+    ``counts`` [B] how many of the T are real (all where None).  Returns
+    (the taps' sum [B, T, d] in float32, the state after the last real
+    position, in x's dtype)."""
+    taps = kernel.shape[0]
+    batch, t, width = x.shape
+    if state is None:
+        state = jnp.zeros((batch, taps - 1, width), x.dtype)
+    held = jnp.concatenate([state.astype(x.dtype), x], axis=1)
+    weights = kernel.astype(jnp.float32)
+    conv = sum(weights[k] * held[:, k:k + t].astype(jnp.float32)
+               for k in range(taps))
+    if counts is None:
+        return conv, held[:, t:]
+    # the real positions end at index counts + K - 2 of ``held``
+    at = counts[:, None] + jnp.arange(taps - 1)[None, :]
+    return conv, jnp.take_along_axis(held, at[:, :, None], axis=1)
+
+
 def gated_short_conv(b: Array, c: Array, x: Array, kernel: Array,
                      state: Array | None = None,
                      counts: Array | None = None) -> tuple[Array, Array]:
@@ -39,19 +62,5 @@ def gated_short_conv(b: Array, c: Array, x: Array, kernel: Array,
     whether its neighbours came from the state or from the block.
     Returns (y [B, T, d] in the inputs' dtype, the state after the last
     real position)."""
-    taps = kernel.shape[0]
-    batch, t, width = x.shape
-    gated = b * x
-    if state is None:
-        state = jnp.zeros((batch, taps - 1, width), gated.dtype)
-    held = jnp.concatenate([state.astype(gated.dtype), gated], axis=1)
-    weights = kernel.astype(jnp.float32)
-    conv = sum(weights[k] * held[:, k:k + t].astype(jnp.float32)
-               for k in range(taps))
-    if counts is None:
-        after = held[:, t:]
-    else:
-        # the real positions end at index counts + K - 2 of ``held``
-        at = counts[:, None] + jnp.arange(taps - 1)[None, :]
-        after = jnp.take_along_axis(held, at[:, :, None], axis=1)
+    conv, after = short_conv(b * x, kernel, state, counts)
     return (c.astype(jnp.float32) * conv).astype(x.dtype), after

@@ -175,8 +175,8 @@ def test_minicpm_salas_round_gathers_and_updates_in_place(one_chip):
         lambda: generation.init_cache(model, slots, max_len)))
     assert [x.shape for x in cache.k] == [(16, 2, 65536, 128)]
     assert [x.shape for x in cache.ck] == [(16, 2, 4096, 128)]
-    assert [(x.shape, x.dtype) for x in cache.state] == [
-        ((16, 32, 128, 128), jnp.float32)] * 2
+    assert [[(x.shape, x.dtype) for x in layer] for layer in cache.state] \
+        == [[((16, 32, 128, 128), jnp.float32)]] * 2
     compiled = _compiled_round(model, params, cache, slots, one_chip)
     aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
     assert parts == 5 and aliased >= parts
@@ -212,8 +212,8 @@ def test_lfm2s_round_updates_k_v_in_place_beside_its_conv_states(one_chip):
         lambda: generation.init_cache(model, slots, max_len)))
     # 8 K/V heads of 64, two to a row of 128 lanes
     assert [x.shape for x in cache.k] == [(64, 4096, 4, 128)]
-    assert [(x.shape, x.dtype) for x in cache.state] == [
-        ((64, 2, 2048), jnp.bfloat16)] * 3
+    assert [[(x.shape, x.dtype) for x in layer] for layer in cache.state] \
+        == [[((64, 2, 2048), jnp.bfloat16)]] * 3
     compiled = _compiled_round(model, params, cache, slots, one_chip)
     aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
     assert parts == 5 and aliased >= parts
@@ -221,6 +221,67 @@ def test_lfm2s_round_updates_k_v_in_place_beside_its_conv_states(one_chip):
     assert [op for op in moved if op[2] != state or op[0] != "copy"] == []
     assert len(moved) <= 3
     assert temporaries < cache_bytes / 4
+
+
+@pytest.mark.parametrize("arm", ["kernel", "plain"])
+def test_kimi_linears_round_copies_no_latent_and_no_matrix_part(
+        one_chip, monkeypatch, arm):
+    """``serve_agents_kimi_linear_ep8``'s decode round at its real widths,
+    64 slots x 16,384 positions, five layers (the dense KDA layer, two KDA
+    layers, an MLA layer and a KDA layer over 32 of 256 experts): the MLA
+    layer's rows (640 lanes: 512 + 64 padded to whole registers) and the
+    KDA layers' matrices are updated where they lie and nothing as large
+    as one of them is copied or sliced; the absorbed attention reads the
+    rows as they lie, on the chip through the kernel of
+    ops/pallas/latent_decode.py (``kernel``: Mosaic takes it at these
+    shapes and no [64, 32, 16384] scores are kept) and elsewhere in plain
+    XLA.  A KDA layer's shift register is rewritten whole by a round
+    (4.7 MB a layer) and the compiler stages it, as LFM2's: the copies of
+    exactly a register's size are let through, and one of ``wkv_b`` (4 MB:
+    its split by head)."""
+    from parameter_server_distributed_tpu.models import transformer
+    from parameter_server_distributed_tpu.ops.pallas import latent_decode
+
+    monkeypatch.setattr(latent_decode, "interpret_mode", lambda *_: False)
+    monkeypatch.setattr(transformer, "_kernel_backend",
+                        lambda: arm == "kernel")
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "kimi-linear-48b-a3b-12l-ep8.json")) as handle:
+        config = json.load(handle)
+    family = families.of(config)
+    model = family.model(config, remat=False, n_layers=5)
+    slots, max_len = 64, 16384
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(lambda: family.make_weights(model, 1)))
+    assert params["layer0/attn/conv_q"].shape == (4, 4096)
+    assert params["layer3/attn/wkv_b"].shape == (512, 8192)
+    assert params["layer1/moe/w1"].shape == (32, 2304, 1024)
+    cache = placed(jax.eval_shape(
+        lambda: generation.init_cache(model, slots, max_len)))
+    assert cache.k == () and [x.shape for x in cache.latent] == [
+        (64, 16384, 640)]
+    assert [[(x.shape, x.dtype) for x in layer] for layer in cache.state] \
+        == [[((64, 3, 12288), jnp.bfloat16),
+             ((64, 32, 128, 128), jnp.float32)]] * 4
+    compiled = _compiled_round(model, params, cache, slots, one_chip)
+    aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
+    assert parts == 9 and aliased >= parts
+    register, up = 64 * 3 * 12288, 512 * 8192
+    assert [op for op in moved
+            if op[0] != "copy" or op[2] not in (register, up)] == []
+    assert len(moved) <= 4 + 1
+    assert temporaries < cache_bytes / 4
+    kernels = compiled.as_text().count("latent/cache/attn_kernel")
+    if arm == "kernel":
+        # the scores of 64 lanes x 32 heads x 16,384 positions (134 MB in
+        # float32) live a block at a time in the kernel's own memory
+        assert kernels > 0 and temporaries < 64 * 32 * 16384 * 4
+    else:
+        assert kernels == 0
 
 
 def test_k_exaones_round_updates_rings_of_128_where_they_lie(one_chip):

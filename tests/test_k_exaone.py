@@ -367,7 +367,8 @@ def test_a_turn_longer_than_the_window_behind_a_resident_prefix(monkeypatch):
     assert node.handle.row[0].shape == (6, 64 + 32, 1, 32)
     kinds = warm._cache.nbytes_by_kind()
     assert kinds == {"full": 4 * 2 * 128 * 32 * 4,
-                     "window": 4 * 5 * 2 * WINDOW * 32 * 4, "state": 0}
+                     "window": 4 * 5 * 2 * WINDOW * 32 * 4, "state": 0,
+                     "latent": 0}
 
 
 def test_a_prompt_prefilled_in_chunks_longer_than_the_window(monkeypatch):

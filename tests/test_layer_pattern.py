@@ -282,6 +282,37 @@ def test_dropless_experts_builds_nothing_of_size_tokens_by_experts_by_capacity()
     np.testing.assert_allclose(np.asarray(out), want, atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("held", [None, (2, 3)])
+def test_past_the_sort_limit_the_rows_are_ordered_by_counting(held,
+                                                              monkeypatch):
+    """More assignments than the TPU compiler sorts quickly (16,384: a
+    4,096-token prefill at top-8 is 32,768) are put in order by counting:
+    the same permutation, so the same rows, loads and sum, with and
+    without a held share."""
+    n, d, e, f, k = 96, 16, 8, 24, 2
+    rng = np.random.default_rng(16)
+    keys = jnp.asarray(rng.integers(0, 5, 400), jnp.int32)
+    order, place = moe._sorted_by_group(keys, 5)
+    monkeypatch.setattr(moe, "_SORT_LIMIT", 64)
+    counted = moe._sorted_by_group(keys, 5)
+    assert np.array_equal(counted[0], order)
+    assert np.array_equal(counted[1], place)
+    assert np.array_equal(np.asarray(order),
+                          np.argsort(np.asarray(keys), kind="stable"))
+    count = e if held is None else held[1]
+    args = (jnp.asarray(rng.normal(size=(n, d)), jnp.float32),
+            jnp.asarray(rng.normal(size=(n, e)), jnp.float32),
+            jnp.asarray(rng.normal(size=(count, d, f)), jnp.float32),
+            jnp.asarray(rng.normal(size=(count, f, d)), jnp.float32),
+            jnp.asarray(rng.normal(size=(count, d, f)), jnp.float32))
+    got = moe.dropless_experts(*args, top_k=k, act="swiglu", held=held)
+    monkeypatch.setattr(moe, "_SORT_LIMIT", 16384)
+    want = moe.dropless_experts(*args, top_k=k, act="swiglu", held=held)
+    assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    assert float(jnp.max(jnp.abs(want[0]))) > 0.1
+
+
 def test_a_nope_layer_ignores_positions_and_a_rotary_layer_sees_distances(
         built):
     model, params, _ = built
@@ -407,7 +438,7 @@ def test_gpt2_is_the_patterns_simplest_member():
     assert [part.shape for part in cache.k] == [(2, 16, 1, 32)] * 3
     assert cache.wk == () and cache.ring_layers == ()
     assert cache.nbytes_by_kind() == {"full": 2 * 3 * cache.k[0].nbytes,
-                                      "window": 0, "state": 0}
+                                      "window": 0, "state": 0, "latent": 0}
     assert jax.tree.structure(cache).num_leaves == 2 * 3 + 1
     # moe_every still says where the capacity-dropping layers are
     moe_lm = tr.TransformerConfig(n_layers=4, moe_every=2)

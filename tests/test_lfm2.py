@@ -135,7 +135,7 @@ def test_a_wrong_conv_state_is_seen_in_the_logits(small, tokens, expected,
         _, cache = generation.prefill(model, params, tokens[:, :prompt], 80)
         _, early = generation.prefill(model, params, tokens[:, :prompt - 1],
                                       80)
-        state = (tuple(jnp.zeros_like(s) for s in cache.state)
+        state = (jax.tree.map(jnp.zeros_like, cache.state)
                  if fault == "zeroed" else early.state)
         broken = dataclasses.replace(cache, state=state)
         return generation.decode_step(model, params, tokens[:, prompt],
@@ -292,12 +292,15 @@ def _server(small, **kwargs):
 
 
 def _rows_close(a, b, n):
-    """Two rows (k, v, state) agree on their first n positions."""
+    """Two rows (k, v, a state each of five conv layers) agree on their
+    first n positions."""
     for x, y in zip(a[:2], b[:2]):
         assert np.max(np.abs(np.asarray(x[:, :n]) - np.asarray(y[:, :n]))) \
             < 1e-5
-    assert a[2].shape == b[2].shape == (5, 2, 64)
-    assert np.max(np.abs(np.asarray(a[2]) - np.asarray(b[2]))) < 1e-5
+    assert len(a) == len(b) == 2 + 5
+    for x, y in zip(a[2:], b[2:]):
+        assert x.shape == y.shape == (2, 64)
+        assert np.max(np.abs(np.asarray(x) - np.asarray(y))) < 1e-5
 
 
 def test_a_prefix_hit_restores_row_and_conv_snapshot(small):
@@ -381,9 +384,10 @@ def test_the_cache_by_kind_and_the_counters_count_the_states(small):
     # 4 slots: one attention layer's K and V of 128 positions x 2 heads of
     # 16 (one row of 32 lanes) x 4 B; five conv layers x 2 columns x 64
     assert [x.shape for x in server._cache.k] == [(4, 128, 1, 32)]
-    assert [x.shape for x in server._cache.state] == [(4, 2, 64)] * 5
+    assert [[x.shape for x in layer] for layer in server._cache.state] \
+        == [[(4, 2, 64)]] * 5
     assert kinds == {"full": 4 * 2 * 128 * 2 * 16 * 4, "window": 0,
-                     "state": 4 * 5 * 2 * 64 * 4}
+                     "state": 4 * 5 * 2 * 64 * 4, "latent": 0}
     assert server.stats["cache_state_bytes"] == kinds["state"]
     before = server._obs_mixers["serve.conv.state_updates"].value
     rid = server.submit(np.arange(1, 41, dtype=np.int32), max_new_tokens=6)
@@ -413,8 +417,7 @@ def test_features_that_cannot_hold_a_state_refuse_the_model(small, feature):
 
 
 @pytest.mark.parametrize("fields,message", [
-    (dict(pattern=(LayerSpec(mixer="conv"), LayerSpec(mixer="linear"))),
-     "one shape of state"),
+    (dict(pattern=(LayerSpec(mixer="latent"),)), "kv_latent"),
     (dict(prologue=(LayerSpec(),), scan_layers=True), "run unrolled"),
     (dict(pattern=(LayerSpec(mixer="conv"),), bias=True), "no bias"),
     (dict(moe_expert_bias=True), "moe_score='sigmoid'"),

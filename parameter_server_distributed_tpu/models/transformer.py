@@ -34,7 +34,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .moe import moe_expert_weight_spec
-from .quant import wdot
+from .quant import QTensor, wdot
 
 Array = jax.Array
 
@@ -969,6 +969,57 @@ class Transformer:
             out = self._norm(params, key, out)
         return h + self._branch(out).astype(self.config.dtype)
 
+    def _column_dots(self, x: Array, weights, biases=None) -> list[Array]:
+        """``x @ w`` (+ its bias) for each of the column-parallel
+        ``weights`` ``[d, out]`` of ONE input ``x`` [B, S, d]: summed and
+        biased in float32, returned in the model's dtype.
+
+        On a mesh whose ``tensor`` axis cuts every weight's columns they
+        are one contraction.  Autodiff transposes a dot into a dot, and
+        GSPMD reduces a dot's partial sums over ``tensor`` where the dot
+        stands: three projections of one input pay three all-reduces of
+        x's gradient, which the compiler does not join; one contraction
+        pays one.  Each weight is viewed ``[d, ways, out / ways]`` (the
+        ``tensor`` axis stays where :func:`transformer_rule` put it) and
+        the views are joined on the last axis, a device's own columns
+        side by side: a local copy, no weight is resharded.  The product
+        is cut back into its parts AFTER the bias and the cast, so that
+        both stay the dot's epilogue and no float32 copy of it is
+        written.  The joined weight exists only here; the store keeps
+        its names and shapes.
+
+        Without a mesh, on a ``tensor`` axis of 1, where a width does not
+        divide (the rule then leaves that weight whole) and for an int8
+        ``QTensor`` (serving quant), a :func:`wdot` each."""
+        dtype = self.config.dtype
+        ways = 1 if self.mesh is None else self.mesh.shape.get("tensor", 1)
+        if ways == 1 or any(isinstance(w, QTensor) or w.shape[-1] % ways
+                            for w in weights):
+            outs = [wdot(x, w, preferred_element_type=jnp.float32)
+                    for w in weights]
+            if biases is not None:
+                outs = [out + b.astype(jnp.float32)
+                        for out, b in zip(outs, biases)]
+            return [out.astype(dtype) for out in outs]
+        d = x.shape[-1]
+        widths = [w.shape[-1] // ways for w in weights]
+
+        def joined(parts, *lead):
+            return jnp.concatenate(
+                [part.reshape(*lead, ways, width)
+                 for part, width in zip(parts, widths)], axis=-1)
+
+        y = jnp.dot(x, joined(weights, d).reshape(d, -1),
+                    preferred_element_type=jnp.float32)
+        y = y.reshape(*x.shape[:-1], ways, -1)
+        if biases is not None:
+            y = y + joined(biases).astype(jnp.float32)
+        y = self._constrain(y.astype(dtype),
+                            ("data", "fsdp"), "seq", "tensor", None)
+        outs = jnp.split(y, list(itertools.accumulate(widths))[:-1], axis=-1)
+        return [out.reshape(*x.shape[:-1], ways * width)
+                for out, width in zip(outs, widths)]
+
     @scoped("attn_qkv")
     def qkv(self, params: Mapping[str, Array], prefix: str, h: Array,
             positions: Array, spec: LayerSpec | None = None,
@@ -983,19 +1034,14 @@ class Transformer:
         c = self.config
         batch, seq = h.shape[:2]
         x = self._branch_input(params, f"{prefix}/ln1", h)
-        # wdot: contracts against int8 QTensor weights too (serving quant)
-        dot = partial(wdot, preferred_element_type=jnp.float32)
-        q = dot(x, params[f"{prefix}/attn/wq"])
-        k = dot(x, params[f"{prefix}/attn/wk"])
-        v = dot(x, params[f"{prefix}/attn/wv"])
-        if c.bias:
-            q = q + params[f"{prefix}/attn/bq"].astype(jnp.float32)
-            k = k + params[f"{prefix}/attn/bk"].astype(jnp.float32)
-            v = v + params[f"{prefix}/attn/bv"].astype(jnp.float32)
+        q, k, v = self._column_dots(
+            x, [params[f"{prefix}/attn/w{name}"] for name in "qkv"],
+            [params[f"{prefix}/attn/b{name}"] for name in "qkv"]
+            if c.bias else None)
         kv_heads = (spec.kv_heads if spec is not None else 0) or c.kv_heads
-        q = q.astype(c.dtype).reshape(batch, seq, c.n_heads, c.head_dim)
-        k = k.astype(c.dtype).reshape(batch, seq, kv_heads, c.head_dim)
-        v = v.astype(c.dtype).reshape(batch, seq, kv_heads, c.head_dim)
+        q = q.reshape(batch, seq, c.n_heads, c.head_dim)
+        k = k.reshape(batch, seq, kv_heads, c.head_dim)
+        v = v.reshape(batch, seq, kv_heads, c.head_dim)
         if spec is not None and spec.qk_norm:
             q = rms_norm(q, params[f"{prefix}/attn/q_norm/scale"],
                          c.norm_eps)

@@ -12,6 +12,7 @@ fixture: only one process may load the TPU's library, and the worker that
 is given this file is the one that loads it.
 """
 
+import functools
 import json
 import os
 import re
@@ -332,16 +333,16 @@ def test_k_exaones_round_updates_rings_of_128_where_they_lie(one_chip):
 # configuration, mesh axes: the two training cells (64 x 1,024 tokens a step)
 STEPS = {"one-chip": ("gpt2-medium", {}),
          "fsdp2-tensor2": ("gpt2-large", {"fsdp": 2, "tensor": 2})}
+BATCH, SEQ = 64, 1024
 
 
-@pytest.mark.parametrize("layout", sorted(STEPS))
-def test_the_training_step_holds_no_score_tensor(topology, monkeypatch,
-                                                 layout):
-    """``jit(step)`` of the cell's widths (2 layers, 64 x 1,024, scan +
-    remat, Adam) compiled for the described v5e: with the default rule its
-    attention is four kernel calls (forward, rematerialised forward, dQ,
-    dK/dV) and no array of a shard's ``[B, H, S, S]`` exists in any dtype;
-    with the rule forced to the einsum, the same search finds them."""
+@functools.cache
+def _step_text(topology, layout, on_tpu):
+    """The text of ``jit(step)`` of the cell's widths (2 layers, 64 x
+    1,024, scan + remat, Adam) compiled for the described v5e, with the
+    attention rule's one question about the backend answered ``on_tpu``;
+    with it the configuration's mesh axes and the model.  One compile a
+    (layout, answer) for the file's tests."""
     from parameter_server_distributed_tpu.config import MeshConfig
     from parameter_server_distributed_tpu.models import transformer
     from parameter_server_distributed_tpu.ops.pallas import fused_attention
@@ -351,7 +352,6 @@ def test_the_training_step_holds_no_score_tensor(topology, monkeypatch,
         TrainState, make_optimizer, make_train_step, state_shardings)
 
     name, axes = STEPS[layout]
-    batch, seq = 64, 1024
     with open(os.path.join(ROOT, "perfbench", "configs",
                            name + ".json")) as handle:
         config = json.load(handle)
@@ -371,25 +371,78 @@ def test_the_training_step_holds_no_score_tensor(topology, monkeypatch,
         lambda x, sharding: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                                  sharding=sharding),
         state, shardings)
-    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+    tokens = jax.ShapeDtypeStruct((BATCH, SEQ), jnp.int32,
                                   sharding=batch_sharding(mesh))
-    scores = re.compile(r"\w+\[%d,%d,%d,%d\]" % (
-        batch // config_axes.fsdp, model.config.n_heads // config_axes.tensor,
-        seq, seq))
-    # the chip is described, not attached: the backend here is the CPU, so
-    # the test answers the rule's one question about the backend itself
-    monkeypatch.setattr(fused_attention, "interpret_mode", lambda *_: False)
-    found = {}
-    for on_tpu in (True, False):
-        monkeypatch.setattr(transformer, "_kernel_backend", lambda: on_tpu)
+    with pytest.MonkeyPatch.context() as patch:
+        # the chip is described, not attached: the backend here is the
+        # CPU, so the test answers the rule's one question about the
+        # backend itself
+        patch.setattr(fused_attention, "interpret_mode", lambda *_: False)
+        patch.setattr(transformer, "_kernel_backend", lambda: on_tpu)
         text = jax.jit(
             make_train_step(model.loss, optimizer),
             in_shardings=(shardings, batch_sharding(mesh)),
             donate_argnums=0).lower(placed, tokens).compile().as_text()
+    return text, config_axes, model
+
+
+@pytest.mark.parametrize("layout", sorted(STEPS))
+def test_the_training_step_holds_no_score_tensor(topology, layout):
+    """The compiled step: with the default rule its attention is four
+    kernel calls (forward, rematerialised forward, dQ, dK/dV) and no array
+    of a shard's ``[B, H, S, S]`` exists in any dtype; with the rule
+    forced to the einsum, the same search finds them."""
+    found = {}
+    for on_tpu in (True, False):
+        text, axes, model = _step_text(topology, layout, on_tpu)
+        scores = re.compile(r"\w+\[%d,%d,%d,%d\]" % (
+            BATCH // axes.fsdp, model.config.n_heads // axes.tensor,
+            SEQ, SEQ))
         found[on_tpu] = (len(scores.findall(text)),
                          text.count('custom_call_target="tpu_custom_call"'))
     assert found[True] == (0, 4)
     assert found[False][0] > 0 and found[False][1] == 0
+
+
+@pytest.mark.parametrize("layout", sorted(STEPS))
+def test_q_k_v_reduce_their_input_gradient_over_tensor_once(topology,
+                                                            layout):
+    """What the compiled step's scanned layer moves between chips.  On
+    ``fsdp 2 x tensor 2`` a layer's body holds five all-reduces of a
+    shard's activations over the ``tensor`` pairs: ``wo``'s and ``w2``'s
+    partial sums in the forward loop; in the backward loop ``wo``'s again
+    (the remat forward), ``w1``'s input gradient and ONE under
+    ``attn_qkv``: q, k and v are one contraction there, whose transpose
+    is one dot (three dots paid three).  On one chip the program holds no
+    collective and the projections are today's three dots."""
+    text, axes, model = _step_text(topology, layout, True)
+    forward = "/jvp()/while/body/closed_call/"
+    backward = "/transpose(jvp())/while/body/closed_call/checkpoint/"
+    dots = [line for line in text.splitlines()
+            if " convolution(" in line
+            and forward + 'attn_qkv/dot_general"' in line]
+    if axes.tensor == 1:
+        assert not re.search(r" (all-reduce|all-gather|reduce-scatter|"
+                             r"all-to-all|collective-permute)(-start)?\(",
+                             text)
+        assert len(dots) == 3
+        return
+    # (the mesh lays fsdp outermost: chips 0,1 and 2,3 are tensor pairs)
+    moved = [name for groups, name in re.findall(
+        r"= \w+\[%d,%d,%d\]\S* all-reduce\(.*?replica_groups=(\S+?), "
+        r".*?op_name=\"([^\"]*)\"" % (
+            BATCH // axes.fsdp, SEQ, model.config.d_model), text)
+        if groups in ("[2,2]<=[4]", "{{0,1},{2,3}}")]
+    assert len(dots) == 1
+    assert len([name for name in moved if "attn_qkv" in name]) == 1
+    assert sorted(name.split("closed_call/")[-1] for name in moved
+                  if forward in name) == [
+        "attn_out/dot_general", "mlp/dot_general"]
+    assert sorted(name.split("checkpoint/")[-1] for name in moved
+                  if backward in name) == [
+        "attn_qkv/dot_general", "mlp/dot_general",
+        "rematted_computation/attn_out/dot_general"]
+    assert len(moved) == 5
 
 
 @pytest.mark.parametrize("heads,kv_heads,d,arm", [

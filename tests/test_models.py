@@ -9,7 +9,7 @@ from parameter_server_distributed_tpu.config import MeshConfig
 from parameter_server_distributed_tpu.models.mlp import MLP, billion_param_mlp, mnist_mlp
 from parameter_server_distributed_tpu.models.resnet import ResNet, resnet18, resnet50
 from parameter_server_distributed_tpu.models.transformer import (
-    Transformer, TransformerConfig, small_lm, transformer_rule)
+    LayerSpec, Transformer, TransformerConfig, small_lm, transformer_rule)
 from parameter_server_distributed_tpu.parallel.mesh import build_mesh
 from parameter_server_distributed_tpu.parallel.train_step import (
     ShardedTrainer, make_optimizer)
@@ -537,3 +537,84 @@ def test_vit_flops_accounting_excludes_non_matmul_params():
     assert math.prod(shapes["embed/pos"]) > 0
     assert model.flops_per_sample() < expected + 6.0 * s * math.prod(
         shapes["embed/pos"])
+
+
+# what of q/k/v's projection a mesh with tensor: 2 can observe: the
+# config, whether the store is quantized, and whether one contraction is
+# then expected in place of three
+JOINED_QKV = {
+    "gpt2": (dict(pos_emb="learned", norm="layernorm", bias=True,
+                  scan_layers=True, remat=True), False, True),
+    "grouped-rope-qk-norm": (dict(
+        n_kv_heads=2, pattern=(LayerSpec(qk_norm=True),)), False, True),
+    "odd-kv-width": (dict(pos_emb="learned", head_dim=5, n_kv_heads=1),
+                     False, False),
+    "int8": ({}, True, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(JOINED_QKV))
+def test_q_k_v_as_one_contraction_on_a_tensor_axis(case, rng):
+    """On ``fsdp 2 x tensor 2`` the three projections of a layer's normed
+    input are one contraction where every width divides and the weights
+    are plain arrays, three dots where not; either way the loss and every
+    parameter's gradient are those of the same weights without a mesh, and
+    the store keeps its names, shapes and shardings."""
+    from jax.sharding import PartitionSpec
+
+    from parameter_server_distributed_tpu.models.quant import quantize_params
+    from parameter_server_distributed_tpu.parallel.mesh import batch_sharding
+    from parameter_server_distributed_tpu.parallel.sharding import shard_store
+
+    changes, quantized, joined = JOINED_QKV[case]
+    config = TransformerConfig(**{**dict(
+        vocab=256, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_seq=16,
+        dtype=jnp.float32), **changes})
+    plain, placed = Transformer(config), Transformer(config)
+    mesh = build_mesh(MeshConfig(fsdp=2, tensor=2), devices=jax.devices()[:4])
+    placed.on_mesh(mesh)
+    params = plain.init_params(3)
+    # biases and norms away from their zeros and ones, so that a bias or
+    # a gain added to the wrong columns would show
+    params = {name: value + 0.1 * jax.random.normal(
+        jax.random.PRNGKey(i), value.shape, value.dtype)
+        for i, (name, value) in enumerate(sorted(params.items()))}
+    tokens = rng.integers(0, config.vocab, (4, 16)).astype(np.int32)
+
+    rule = transformer_rule(mesh)
+    kv_width = config.kv_heads * config.head_dim
+    widths = {"attn/wq": config.attn_dim, "attn/wk": kv_width,
+              "attn/wv": kv_width}
+    projections = {name: value.shape for name, value in params.items()
+                   if name[-7:] in widths}
+    assert len(projections) == 3 * (1 if config.scan_layers else 2)
+    for name, shape in projections.items():
+        assert shape[-2:] == (config.d_model, widths[name[-7:]])
+        assert rule(name, shape) == PartitionSpec(
+            *[None] * (len(shape) - 2), "fsdp",
+            None if shape[-1] % 2 else "tensor")
+    assert not [name for name in params if "qkv" in name]
+
+    if quantized:
+        # a serving store: no gradient to an int8 leaf, so the loss alone
+        params = quantize_params(params)
+        on_mesh = params
+        run = lambda model: jax.jit(model.loss)
+    else:
+        on_mesh = shard_store(params, mesh, rule)
+        run = lambda model: jax.jit(jax.value_and_grad(model.loss))
+    want = run(plain)(params, tokens)
+    got = run(placed)(on_mesh, jax.device_put(tokens, batch_sharding(mesh)))
+    if not quantized:
+        assert {k: v.shape for k, v in got[1].items()} == {
+            k: v.shape for k, v in params.items()}
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=2e-6)
+
+    def dots(model):
+        return str(jax.make_jaxpr(model.loss)(params, tokens)).count(
+            "dot_general")
+
+    bodies = 1 if config.scan_layers else config.n_layers
+    assert dots(plain) - dots(placed) == (2 * bodies if joined else 0)

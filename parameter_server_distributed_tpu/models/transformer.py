@@ -31,6 +31,7 @@ from typing import Callable, Mapping
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .moe import moe_expert_weight_spec
@@ -43,6 +44,9 @@ FFN_KINDS = ("mlp", "moe", "experts")
 MIXER_KINDS = ("softmax", "sparse", "linear", "conv", "kda", "latent")
 # the mixers that keep a fixed-size STATE in a decode cache and no K/V
 STATE_MIXERS = ("linear", "conv", "kda")
+# jax.ad_checkpoint name of a layer's mixer branch as it joins the residual
+# stream (Transformer._residual); Transformer._remat_policy may keep it
+MIXER_OUT = "mixer_out"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,6 +151,18 @@ class TransformerConfig:
     # fwd+bwd budget) to the attention einsums alone (~5% at S=d=1024 —
     # 4·S·d² vs the 72·S·d² + 12·S²·d fwd+bwd per-layer matmul total),
     # for O(L·S·d) saved activations instead of O(1) residuals.
+    # On a mesh whose ``tensor`` axis is larger than 1, "full" keeps ONE
+    # value a layer across the backward: the mixer branch's output as it
+    # joins the residual stream (MIXER_OUT: after the row-parallel output
+    # projection's all-reduce, the bias and the cast to ``dtype``).  "full"
+    # means "recompute a layer's arithmetic", not "pay the link twice":
+    # without it the remat forward repeats that all-reduce, which nothing
+    # hides, because the FFN branch needs its result.  The price is
+    # B/rows x S x d_model x sizeof(dtype) bytes a layer and chip (rows =
+    # the mesh's data x fsdp; 84 MB at 32 x 1,024 x 1,280 in bf16), and the
+    # output projection's dot is not recomputed either.  Who trains at the
+    # edge of memory there takes a smaller micro-batch; there is no switch.
+    # Without a mesh and on ``tensor: 1`` nothing is kept, as before.
     remat_policy: str = "full"
     # Chunked cross-entropy: compute the LM head + softmax in sequence
     # chunks of this many positions (0 = whole sequence at once).  Peak
@@ -851,7 +867,11 @@ class Transformer:
         accounting for rematerialized runs.  Under the "full" policy that
         is the whole forward again (+2*P and +4*L*d*S per token); under
         "dots" the projection/MLP matmuls are saved and only the attention
-        einsums re-run (+4*L*d*S only)."""
+        einsums re-run (+4*L*d*S only).  On a ``tensor`` axis larger than
+        1, "full" keeps the mixer branch's output (``_remat_policy``), so
+        the mixer's output projection (2*d*d of the 2*P a token and
+        layer) is NOT run again there: the credited count stays what it
+        is, an upper bound on what the hardware executes."""
         c = self.config
         seq = c.max_seq
         n_params = self.num_params()
@@ -885,10 +905,20 @@ class Transformer:
 
     def _remat_policy(self):
         """config.remat_policy -> jax.checkpoint policy (None = save
-        nothing, i.e. full recompute)."""
+        nothing, i.e. full recompute).  On a mesh whose ``tensor`` axis is
+        larger than 1, "full" keeps the mixer branch's reduced output
+        (:data:`MIXER_OUT`, see ``TransformerConfig.remat_policy``): the
+        arithmetic is recomputed, the all-reduce is not paid twice."""
         if self.config.remat_policy == "dots":
             return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        if self._tensor_ways > 1:
+            return jax.checkpoint_policies.save_only_these_names(MIXER_OUT)
         return None
+
+    @property
+    def _tensor_ways(self) -> int:
+        """The size of the mesh's ``tensor`` axis; 1 without a mesh."""
+        return 1 if self.mesh is None else self.mesh.shape.get("tensor", 1)
 
     def init_params(self, rng: jax.Array | int = 0) -> dict[str, Array]:
         c = self.config
@@ -964,10 +994,18 @@ class Transformer:
     def _residual(self, params: Mapping[str, Array], key: str, h: Array,
                   out: Array) -> Array:
         """``h`` + a branch's output ``out`` (float32) at the config's
-        ``residual_scale``, normed first where the norm stands there."""
+        ``residual_scale``, normed first where the norm stands there.
+        The mixer's branch (``key`` its norm's, ``.../ln1`` in every
+        family) is named :data:`MIXER_OUT` as it joins the stream."""
         if self.config.norm_placement == "post":
             out = self._norm(params, key, out)
-        return h + self._branch(out).astype(self.config.dtype)
+        out = self._branch(out).astype(self.config.dtype)
+        if key.endswith("/ln1"):
+            # after the cast: what "full" remat keeps on a ``tensor`` axis
+            # (an identity outside a jax.checkpoint whose policy asks for
+            # the name)
+            out = checkpoint_name(out, MIXER_OUT)
+        return h + out
 
     def _column_dots(self, x: Array, weights, biases=None) -> list[Array]:
         """``x @ w`` (+ its bias) for each of the column-parallel
@@ -992,7 +1030,7 @@ class Transformer:
         divide (the rule then leaves that weight whole) and for an int8
         ``QTensor`` (serving quant), a :func:`wdot` each."""
         dtype = self.config.dtype
-        ways = 1 if self.mesh is None else self.mesh.shape.get("tensor", 1)
+        ways = self._tensor_ways
         if ways == 1 or any(isinstance(w, QTensor) or w.shape[-1] % ways
                             for w in weights):
             outs = [wdot(x, w, preferred_element_type=jnp.float32)

@@ -336,13 +336,39 @@ STEPS = {"one-chip": ("gpt2-medium", {}),
 BATCH, SEQ = 64, 1024
 
 
+COLLECTIVE = (r" (all-reduce|all-gather|reduce-scatter|all-to-all|"
+              r"collective-permute)(-start)?\(")
+
+
+def _moved_over_tensor(text, axes, model):
+    """The ``op_name`` of every all-reduce of a shard's activations
+    ``[B / fsdp, S, d]`` over the ``tensor`` pairs in a step's text (the
+    mesh lays fsdp outermost: chips 0,1 and 2,3 are the pairs)."""
+    return [name for groups, name in re.findall(
+        r"= \w+\[%d,%d,%d\]\S* all-reduce\(.*?replica_groups=(\S+?), "
+        r".*?op_name=\"([^\"]*)\"" % (
+            BATCH // axes.fsdp, SEQ, model.config.d_model), text)
+        if groups in ("[2,2]<=[4]", "{{0,1},{2,3}}")]
+
+
+def _instructions(text):
+    """A compiled program's text without its tables of source locations
+    (which name the test that compiled it first) and the references into
+    them."""
+    return re.sub(r" stack_frame_id=\d+", "", re.sub(
+        r"(?ms)^(FileNames|FunctionNames|FileLocations|StackFrames)\n.*?"
+        r"\n\n", "", text))
+
+
 @functools.cache
-def _step_text(topology, layout, on_tpu):
+def _step_text(topology, layout, on_tpu, keeps=True):
     """The text of ``jit(step)`` of the cell's widths (2 layers, 64 x
     1,024, scan + remat, Adam) compiled for the described v5e, with the
     attention rule's one question about the backend answered ``on_tpu``;
-    with it the configuration's mesh axes and the model.  One compile a
-    (layout, answer) for the file's tests."""
+    with it the configuration's mesh axes, the model and the bytes of the
+    program's temporaries on a chip.  ``keeps`` false: with the layer's
+    ``jax.checkpoint`` keeping nothing and no value named, whatever the
+    mesh.  One compile a (layout, answer, keeps) for the file's tests."""
     from parameter_server_distributed_tpu.config import MeshConfig
     from parameter_server_distributed_tpu.models import transformer
     from parameter_server_distributed_tpu.ops.pallas import fused_attention
@@ -379,11 +405,16 @@ def _step_text(topology, layout, on_tpu):
         # backend itself
         patch.setattr(fused_attention, "interpret_mode", lambda *_: False)
         patch.setattr(transformer, "_kernel_backend", lambda: on_tpu)
-        text = jax.jit(
+        if not keeps:
+            patch.setattr(transformer.Transformer, "_remat_policy",
+                          lambda self: None)
+            patch.setattr(transformer, "checkpoint_name", lambda x, _: x)
+        compiled = jax.jit(
             make_train_step(model.loss, optimizer),
             in_shardings=(shardings, batch_sharding(mesh)),
-            donate_argnums=0).lower(placed, tokens).compile().as_text()
-    return text, config_axes, model
+            donate_argnums=0).lower(placed, tokens).compile()
+    return (compiled.as_text(), config_axes, model,
+            compiled.memory_analysis().temp_size_in_bytes)
 
 
 @pytest.mark.parametrize("layout", sorted(STEPS))
@@ -394,7 +425,7 @@ def test_the_training_step_holds_no_score_tensor(topology, layout):
     forced to the einsum, the same search finds them."""
     found = {}
     for on_tpu in (True, False):
-        text, axes, model = _step_text(topology, layout, on_tpu)
+        text, axes, model, _ = _step_text(topology, layout, on_tpu)
         scores = re.compile(r"\w+\[%d,%d,%d,%d\]" % (
             BATCH // axes.fsdp, model.config.n_heads // axes.tensor,
             SEQ, SEQ))
@@ -408,31 +439,27 @@ def test_the_training_step_holds_no_score_tensor(topology, layout):
 def test_q_k_v_reduce_their_input_gradient_over_tensor_once(topology,
                                                             layout):
     """What the compiled step's scanned layer moves between chips.  On
-    ``fsdp 2 x tensor 2`` a layer's body holds five all-reduces of a
-    shard's activations over the ``tensor`` pairs: ``wo``'s and ``w2``'s
-    partial sums in the forward loop; in the backward loop ``wo``'s again
-    (the remat forward), ``w1``'s input gradient and ONE under
+    ``fsdp 2 x tensor 2`` a layer's body holds FOUR all-reduces of a
+    shard's activations over the ``tensor`` pairs, Megatron's floor for
+    the layout: ``wo``'s and ``w2``'s partial sums in the forward loop;
+    in the backward loop ``w1``'s input gradient and ONE under
     ``attn_qkv``: q, k and v are one contraction there, whose transpose
-    is one dot (three dots paid three).  On one chip the program holds no
-    collective and the projections are today's three dots."""
-    text, axes, model = _step_text(topology, layout, True)
+    is one dot (three dots paid three).  The remat forward repeats none:
+    ``w2``'s is dead code there and ``wo``'s reduced output is kept
+    across the backward (``Transformer._remat_policy``).  On one chip the
+    program holds no collective and the projections are today's three
+    dots."""
+    text, axes, model, _ = _step_text(topology, layout, True)
     forward = "/jvp()/while/body/closed_call/"
     backward = "/transpose(jvp())/while/body/closed_call/checkpoint/"
     dots = [line for line in text.splitlines()
             if " convolution(" in line
             and forward + 'attn_qkv/dot_general"' in line]
     if axes.tensor == 1:
-        assert not re.search(r" (all-reduce|all-gather|reduce-scatter|"
-                             r"all-to-all|collective-permute)(-start)?\(",
-                             text)
+        assert not re.search(COLLECTIVE, text)
         assert len(dots) == 3
         return
-    # (the mesh lays fsdp outermost: chips 0,1 and 2,3 are tensor pairs)
-    moved = [name for groups, name in re.findall(
-        r"= \w+\[%d,%d,%d\]\S* all-reduce\(.*?replica_groups=(\S+?), "
-        r".*?op_name=\"([^\"]*)\"" % (
-            BATCH // axes.fsdp, SEQ, model.config.d_model), text)
-        if groups in ("[2,2]<=[4]", "{{0,1},{2,3}}")]
+    moved = _moved_over_tensor(text, axes, model)
     assert len(dots) == 1
     assert len([name for name in moved if "attn_qkv" in name]) == 1
     assert sorted(name.split("closed_call/")[-1] for name in moved
@@ -440,9 +467,33 @@ def test_q_k_v_reduce_their_input_gradient_over_tensor_once(topology,
         "attn_out/dot_general", "mlp/dot_general"]
     assert sorted(name.split("checkpoint/")[-1] for name in moved
                   if backward in name) == [
-        "attn_qkv/dot_general", "mlp/dot_general",
-        "rematted_computation/attn_out/dot_general"]
-    assert len(moved) == 5
+        "attn_qkv/dot_general", "mlp/dot_general"]
+    assert not [name for name in moved if "rematted_computation" in name]
+    assert len(moved) == 4
+
+
+@pytest.mark.parametrize("layout", sorted(STEPS))
+def test_full_remat_keeps_one_reduced_output_a_layer(topology, layout):
+    """What the layer's ``jax.checkpoint`` keeps follows the mesh.  On one
+    chip nothing: the step is the program it is with the policy forced to
+    ``None`` and the name left out, text for text.  On ``fsdp 2 x tensor 2`` the mixer branch's
+    output in the model's dtype, 84 MB a layer and chip, which takes the
+    remat forward's all-reduce (and ``wo``'s dot with it) out of the
+    step: the program's temporaries at 2 layers grow by no more than
+    those two arrays and a tenth, so neither the float32 dot result
+    (twice the bytes) nor the FFN branch's output (dead in the remat
+    forward anyway) is what is kept."""
+    text, axes, model, temporaries = _step_text(topology, layout, True)
+    bare, _, _, bare_temporaries = _step_text(topology, layout, True, False)
+    if axes.tensor == 1:
+        assert _instructions(text) == _instructions(bare)
+        return
+    assert (len(_moved_over_tensor(bare, axes, model)),
+            len(_moved_over_tensor(text, axes, model))) == (5, 4)
+    kept = (BATCH // axes.fsdp * SEQ * model.config.d_model
+            * jnp.dtype(model.config.dtype).itemsize)
+    assert kept == 83_886_080
+    assert 0 < temporaries - bare_temporaries <= 1.1 * 2 * kept
 
 
 @pytest.mark.parametrize("heads,kv_heads,d,arm", [

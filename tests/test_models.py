@@ -542,6 +542,50 @@ def test_vit_flops_accounting_excludes_non_matmul_params():
 # what of q/k/v's projection a mesh with tensor: 2 can observe: the
 # config, whether the store is quantized, and whether one contraction is
 # then expected in place of three
+@pytest.mark.parametrize("scan", [True, False],
+                         ids=["scanned", "unrolled"])
+def test_full_remat_keeps_the_mixers_output_on_a_tensor_axis(scan, rng,
+                                                             monkeypatch):
+    """On ``fsdp 2 x tensor 2`` remat "full" keeps ONE named value a layer
+    (the mixer branch's reduced output, so the remat forward repeats no
+    all-reduce) and nothing without a mesh or on ``tensor: 1``; what is
+    kept is what would have been recomputed, so the loss and every
+    gradient are those of the same mesh with nothing kept."""
+    from parameter_server_distributed_tpu.parallel.mesh import batch_sharding
+    from parameter_server_distributed_tpu.parallel.sharding import shard_store
+
+    config = TransformerConfig(
+        vocab=256, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_seq=16,
+        dtype=jnp.float32, pos_emb="learned", norm="layernorm", bias=True,
+        remat=True, scan_layers=scan)
+    model = Transformer(config)
+    assert model._remat_policy() is None
+    model.on_mesh(build_mesh(MeshConfig(fsdp=4), devices=jax.devices()[:4]))
+    assert model._remat_policy() is None
+    mesh = build_mesh(MeshConfig(fsdp=2, tensor=2), devices=jax.devices()[:4])
+    model.on_mesh(mesh)
+    assert model._remat_policy() is not None
+
+    params = shard_store(model.init_params(3), mesh, transformer_rule(mesh))
+    tokens = jax.device_put(
+        rng.integers(0, config.vocab, (4, 16)).astype(np.int32),
+        batch_sharding(mesh))
+
+    def run():
+        return (jax.jit(jax.value_and_grad(model.loss))(params, tokens),
+                str(jax.make_jaxpr(jax.grad(model.loss))(
+                    params, tokens)).count("dot_general"))
+
+    got, dots_kept = run()
+    monkeypatch.setattr(Transformer, "_remat_policy", lambda self: None)
+    want, dots_full = run()
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-7)
+    # wo's dot is the one product of a layer that is not run again
+    assert dots_full - dots_kept == (1 if scan else config.n_layers)
+
+
 JOINED_QKV = {
     "gpt2": (dict(pos_emb="learned", norm="layernorm", bias=True,
                   scan_layers=True, remat=True), False, True),

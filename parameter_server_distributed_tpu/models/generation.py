@@ -38,7 +38,7 @@ import numpy as np
 
 from ..obs import stats as obs_stats
 from . import transformer as _transformer
-from .transformer import STATE_MIXERS, Transformer
+from .transformer import DELTA_MIXERS, STATE_MIXERS, Transformer
 
 Array = jax.Array
 
@@ -74,6 +74,20 @@ def heads_per_row(kv_heads: int, head_dim: int) -> int:
     return pack
 
 
+def _lies_by_head(rows: int) -> bool:
+    """Whether the device lays a part [B, M, rows, D'] BY HEAD, positions
+    under each row of heads ([B, rows, M, D'] in memory): where the rows of
+    heads a position has neither divide a register's eight sublanes nor
+    fill whole registers (3, 5, 6, 7, 9, 10, ... 30: by head; 1, 2, 4, 8,
+    16, 24, 32, 40: by position), bfloat16 and int8 parts alike: read from
+    the round compiled for a v5e at every such count (PERF.md section 6, PR
+    50; tests/test_chip_compile.py holds 4, 6, 10, 16 and 30).  The products
+    take that layout as it is; a write of [rows, D'] windows does not
+    (:func:`decode_block`'s ``written``), and copies the part there and
+    back, K and V, every layer, every round."""
+    return rows % 8 != 0 and 8 % rows != 0
+
+
 def pack_heads(x: Array, pack: int) -> Array:
     """K or V by head [..., KV, D] as the cache stores it: ``pack`` heads
     side by side in a row, [..., KV / pack, pack * D]."""
@@ -103,7 +117,7 @@ class KVCache:
     layer that keeps no K/V, a TUPLE of arrays a layer
     (:func:`state_shape`): a linear layer's decayed outer products,
     ([B, H, D, D] float32,), a conv layer's shift register of its last
-    gated inputs, ([B, K - 1, d_model],), a kda layer's both (the
+    gated inputs, ([B, K - 1, d_model],), a kda or gdn layer's both (the
     register of its three convolutions' inputs and the matrix); none grows
     with the context, none can be rolled back.  ``latent`` holds a latent
     layer's rows by position, [B, max_len, latent_row]: the normed latent
@@ -187,12 +201,25 @@ def state_shape(model: Transformer) -> tuple[tuple, ...]:
     float32; a conv layer's last ``conv_kernel - 1`` gated inputs [K - 1,
     d_model] in the model's dtype; a kda layer's two, the last
     ``conv_kernel - 1`` inputs of its three convolutions [K - 1, 3 *
-    attn_dim] in the model's dtype and its matrix [H, D, D] float32."""
+    attn_dim] in the model's dtype and its matrix [H, D, D] float32; a gdn
+    layer's the same two at its own sizes, [K - 1, H * (2 Dk + Dv)] and
+    [H, Dk, Dv].
+
+    The matrix lies by head as the delta rule takes it, whatever its sizes.
+    Where Dv fills no whole registers (192 of 256 lanes) the device pads
+    it, a third more bytes a round; the shapes that would not be padded
+    ([Dk, H * Dv], [H, Dk * Dv]) cost more than they save in plain XLA: a
+    round then spreads k and q over the value lanes as arrays of the
+    state's own size (523 to 560 MB moved a layer against 112 at 12 lanes x
+    30 heads x [96, 192], compiled for a v5e; PERF.md section 6, PR 50)."""
     c = model.config
     matrix = ((c.n_heads, c.head_dim, c.head_dim), jnp.float32)
+    keys, values = c.delta_dims
     kinds = {"linear": (matrix,),
              "conv": (((c.conv_kernel - 1, c.d_model), c.dtype),),
-             "kda": (((c.conv_kernel - 1, 3 * c.attn_dim), c.dtype), matrix)}
+             "kda": (((c.conv_kernel - 1, 3 * c.attn_dim), c.dtype), matrix),
+             "gdn": (((c.conv_kernel - 1, c.n_heads * (2 * keys + values)),
+                      c.dtype), ((c.n_heads, keys, values), jnp.float32))}
     return tuple(kinds[c.layer_spec(i).mixer] for i in c.state_layers)
 
 
@@ -329,12 +356,12 @@ def _seeded(part: Array, block: Array) -> Array:
 
 def check_rolls_back(model: Transformer) -> None:
     """Speculative decoding rolls rejected positions back by moving the
-    cache's length; a linear, conv or kda layer's states have no length to
-    move."""
+    cache's length; a linear, conv, kda or gdn layer's states have no length
+    to move."""
     if model.config.state_layers:
         raise ValueError(
             "speculative decoding rolls rejected positions back, and a "
-            "linear, conv or kda layer's state cannot be rolled back: "
+            "linear, conv, kda or gdn layer's state cannot be rolled back: "
             "decode a model with such layers without a draft")
 
 
@@ -435,7 +462,7 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
     cached row) takes the window as a mask.  ``route_stats``, where
     given, gains each ``experts`` layer's tokens per expert.
 
-    A LINEAR, CONV or KDA layer reads and advances its states (``counts``
+    A LINEAR, CONV, KDA or GDN layer reads and advances its states (``counts``
     keeps pads out of them, and like a ring they cannot be rolled back).  A
     LATENT layer writes its rows by position and attends them: a block of
     ``_BLOCKWISE_QUERIES`` tokens or more against a long cache expands K
@@ -490,6 +517,13 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
         """``part`` [B, M, ...] with the block's ``block`` [B, T, ...] at
         its positions: an update of the (donated) part where it lies."""
         block = block.astype(part.dtype)
+        if ragged and part.ndim == 4 and _lies_by_head(part.shape[2]):
+            # a row of D' lanes an index (slot, position, row of heads):
+            # windows of [KV', D'] make the compiler turn the part around
+            # for the write and back (written_by_head's reason)
+            return part.at[bidx[:, :, None], positions[:, :, None],
+                           jnp.arange(part.shape[2])[None, None, :]].set(
+                               block, mode="drop")
         if ragged:
             # mode="drop": rows that finished generating keep advancing
             # their lengths each speculative round, so their scatter
@@ -555,14 +589,14 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                    if spec.mixer in STATE_MIXERS
                    else (False, cache.latent_layers.index(layer))
                    if spec.mixer == "latent" else cache.place(layer))
-        if spec.mixer in ("conv", "kda", "latent"):
+        if spec.mixer in ("conv", "latent") + DELTA_MIXERS:
             with jax.named_scope("cache_attn"):
                 if spec.mixer == "conv":
                     h, state = model.conv_residual(
                         lp, p, h, parts["state"][i][0], counts)
                     parts["state"][i] = (state,)
-                elif spec.mixer == "kda":
-                    h, parts["state"][i] = model.kda_residual(
+                elif spec.mixer in DELTA_MIXERS:
+                    h, parts["state"][i] = model.delta_residual(spec)(
                         lp, p, h, parts["state"][i], counts)
                 else:
                     with jax.named_scope("attn"), jax.named_scope("latent"):

@@ -21,7 +21,17 @@ sequences where
   WHOLE path, so a short turn under a 12,288-token document pins a row
   as wide as the document for the sake of its own few tokens: such
   TAILS (:attr:`RadixNode.is_tail`) go before any other row, or a
-  stream of them pushes the documents themselves out of the budget;
+  stream of them pushes the documents themselves out of the budget.
+  Among the others a row that no later prompt has ever started from
+  (``RadixNode.uses`` 0: one conversation's own turn) goes before any
+  row that one has (a system prompt, a document: :meth:`PrefixTree.use`),
+  however lately it came: five long turns under one context otherwise
+  push out the other context, which half the requests begin with, for
+  rows nobody asks for again.  The rows so kept may hold
+  ``PROTECTED_SHARE`` of the budget: past it the least recently touched
+  of them stand among the others again, so the store never fills with
+  yesterday's contexts while today's find no room to be asked for twice
+  (a segmented LRU; with every row asked for again it is the plain one);
 - every tree path is summarised into a compact **fingerprint** (chained
   CRC32 at block boundaries) the decode fleet heartbeats to the
   coordinator, so the router can score cached-prefix overlap.
@@ -49,7 +59,11 @@ matched NODE END whose own row holds a snapshot there, never a depth
 inside an edge: a split node still shares its descendant's row (the K/V
 are good), but that row's snapshot lies deeper than the node, so the node
 supports no match until a prompt that ends there is admitted with a row of
-its own.  Rows' bytes count their snapshots (the caller sizes a row).
+its own: the server admits what a second prompt shares with a path
+(:meth:`PrefixTree.shared`) as such a prompt before it admits the second
+prompt itself (``DecodeServer._admit``), so a context that fell out of the
+store is a node with a snapshot again from its second request on.  Rows'
+bytes count their snapshots (the caller sizes a row).
 
 Thread model: mutation is single-threaded (the decode loop is the only
 thread that touches a DecodeServer); cross-thread readers (the
@@ -68,6 +82,11 @@ __all__ = [
     "RowRef", "RadixNode", "PrefixTree", "fp_block", "fp_max",
     "block_hashes", "pack_fp", "unpack_fp", "overlap_blocks",
 ]
+
+
+# the share of the budget that rows admissions have started from may hold
+# before the oldest of them are evicted like any other row
+PROTECTED_SHARE = 0.5
 
 
 def fp_block() -> int:
@@ -157,7 +176,7 @@ class RadixNode:
     extend one token instead of replaying)."""
 
     __slots__ = ("edge", "parent", "children", "handle", "dhandle",
-                 "last", "depth", "tick")
+                 "last", "depth", "tick", "uses")
 
     def __init__(self, edge: tuple, parent: "RadixNode | None"):
         self.edge = edge
@@ -168,6 +187,7 @@ class RadixNode:
         self.last: Any = None
         self.depth = (0 if parent is None else parent.depth) + len(edge)
         self.tick = 0
+        self.uses = 0       # admissions that started from this node's row
 
     @property
     def is_tail(self) -> bool:
@@ -180,8 +200,10 @@ class RadixNode:
 class PrefixTree:
     """See module docstring.  ``budget_bytes`` bounds the summed size of
     UNIQUE row handles; inserts over budget evict least-recently-touched
-    leaves, tails first (path-compressing parents left with a single
-    child and no complete-prompt payload)."""
+    leaves, tails first, then rows nothing ever started from (the others
+    as far as ``PROTECTED_SHARE`` of the budget holds them;
+    path-compressing parents left with a single child and no
+    complete-prompt payload)."""
 
     def __init__(self, budget_bytes: int, snapshots: bool = False):
         self.budget_bytes = int(budget_bytes)
@@ -237,6 +259,11 @@ class PrefixTree:
             return node, node.depth, False
         return self._walk_down(tokens)
 
+    def shared(self, tokens: tuple) -> int:
+        """How many leading ``tokens`` some path of the tree holds, a node's
+        end or the inside of an edge, with a snapshot there or without."""
+        return self._walk_down(tokens)[1]
+
     def _walk_down(self, tokens: tuple) -> tuple[RadixNode, int, bool]:
         node = self.root
         matched = 0
@@ -269,6 +296,14 @@ class PrefixTree:
         while node is not None and node is not self.root:
             node.tick = self._tick
             node = node.parent
+
+    def use(self, node: RadixNode) -> None:
+        """An admission started from ``node``'s row (an extension of it, or
+        a replay of the whole prompt): a touch, and the node counts as a
+        row prompts begin with, which eviction keeps over rows that are not
+        (module docstring)."""
+        node.uses += 1
+        self.touch(node)
 
     # -------------------------------------------------------------- insert
     def insert(self, tokens, last: Any, handle: RowRef,
@@ -339,21 +374,36 @@ class PrefixTree:
     def evict_over_budget(self) -> int:
         """Pop least-recently-touched LEAVES until the unique-handle
         byte total fits the budget, the tails (see
-        :attr:`RadixNode.is_tail`) before any other; returns nodes
-        evicted.  Removing a leaf may leave its parent with one child and
+        :attr:`RadixNode.is_tail`) before any other, then the rows no
+        admission started from (:meth:`use`); returns nodes evicted.
+        Removing a leaf may leave its parent with one child and
         no complete-prompt payload — such parents merge back into their
         child (path compression), shedding their handle references."""
         evicted = 0
+        if self.bytes > self.budget_bytes:
+            self._unprotect_over_share()
         while self.bytes > self.budget_bytes and self.nodes:
             leaf = min(
                 (n for n in self._walk() if not n.children),
-                key=lambda n: (not n.is_tail, n.tick))
+                key=lambda n: (not n.is_tail, n.uses > 0, n.tick))
             self._remove_leaf(leaf)
             evicted += 1
         if evicted:
             self.evictions += evicted
             self._refingerprint()
         return evicted
+
+    def _unprotect_over_share(self) -> None:
+        """The rows admissions started from, newest first, as far as
+        ``PROTECTED_SHARE`` of the budget holds them; the rest count as
+        never used again (a row two of them share counts twice)."""
+        room = PROTECTED_SHARE * self.budget_bytes
+        for node in sorted((n for n in self._walk() if n.uses),
+                           key=lambda n: -n.tick):
+            room -= sum(ref.nbytes for ref in (node.handle, node.dhandle)
+                        if ref is not None)
+            if room < 0:
+                node.uses = 0
 
     def _remove_leaf(self, leaf: RadixNode) -> None:
         parent = leaf.parent

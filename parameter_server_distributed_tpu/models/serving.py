@@ -56,7 +56,7 @@ from .generation import (KVCache, QuantKVCache, _cached_runner,
                          init_cache, pack_heads, ring_layers_of, sample_token,
                          sample_token_rowwise, split_row, state_shape)
 from .prefix_tree import PrefixTree, RowRef
-from .transformer import Transformer
+from .transformer import DELTA_MIXERS, Transformer
 
 Array = jax.Array
 
@@ -180,14 +180,15 @@ def _shard_cache(cache, mesh):
 
 def _builds_few(model: Transformer) -> bool:
     """Whether the server keeps this model's admission programs FEW: a model
-    with kda layers, whose every program is all its layers unrolled around
-    a chunked delta rule (10 to 20 s of the compiler's time each on a cold
-    start; four resident contexts and their turns were 70 programs and 515
-    s, PERF.md section 6, PR 47).  One extension program a prefix bucket
+    with delta-rule layers (kda, gdn), whose every program is all its layers
+    unrolled around a chunked delta rule (10 to 20 s of the compiler's time
+    each on a cold start; four resident contexts and their turns were 70
+    programs and 515 s, PERF.md section 6, PR 47).  One extension program a
+    prefix bucket
     (:func:`_suffix_floor`), built ahead (``DecodeServer._build_ahead``),
     and one prefill program for every prompt of a chunk or more
     (:func:`_prefills_whole`)."""
-    return bool(model.config.layers_of("kda"))
+    return any(spec.mixer in DELTA_MIXERS for spec in model.config.specs)
 
 
 def _suffix_floor(model: Transformer) -> int:
@@ -430,9 +431,11 @@ def _prefills_whole(model: Transformer, bucket: int) -> bool:
         c.d_ff if spec.ffn == "mlp" else c.moe_top_k * (
             c.expert_width if spec.ffn == "experts" else c.d_ff)
         for spec in c.specs] + [
-        # (a kda layer's q, k and v go through its convolutions side by
-        # side)
-        3 * c.attn_dim for spec in c.specs if spec.mixer == "kda"])
+        # (a kda or gdn layer's q, k and v go through its convolutions
+        # side by side)
+        3 * c.attn_dim for spec in c.specs if spec.mixer == "kda"] + [
+        c.n_heads * (2 * c.delta_dims[0] + c.delta_dims[1])
+        for spec in c.specs if spec.mixer == "gdn"])
     return bucket * widest <= _PREFILL_WHOLE
 
 
@@ -661,7 +664,8 @@ class DecodeServer:
         # they decide what the prefix tree may match and what cannot be
         # rolled back
         self._linear_layers = len(config.layers_of("linear")
-                                  + config.layers_of("kda"))
+                                  + config.layers_of("kda")
+                                  + config.layers_of("gdn"))
         self._conv_layers = len(config.layers_of("conv"))
         self._state_layers = self._linear_layers + self._conv_layers
         self._sparse_layers = len(config.layers_of("sparse"))
@@ -670,6 +674,9 @@ class DecodeServer:
             check_rolls_back(model)
             check_rolls_back(draft)
         self._cache = init_cache(model, slots, max_len, cache_dtype)
+        # the full softmax layers: K/V by position, the whole context
+        self._full_layers = len(self._cache.k) - len(
+            getattr(self._cache, "sparse_layers", ()))
         if mesh is not None:
             self._cache = _shard_cache(self._cache, mesh)
         self._moe_layers = sum(config.layer_spec(i).ffn == "experts"
@@ -739,8 +746,8 @@ class DecodeServer:
                                       "admit_experts_touched")}
         for kind, held in self._cache_bytes_by_kind().items():
             obs_stats.gauge(f"serve.cache.{kind}_bytes").set(held)
-        # what a round's sparse, linear (kda too), conv and latent layers
-        # read (see _count_mixers)
+        # what a round's sparse, linear (kda and gdn too), conv, latent and
+        # full layers read (see _count_mixers)
         self._obs_mixers = {
             name: obs_stats.counter(name) for name in (
                 "serve.sparse.positions_selected",
@@ -749,7 +756,9 @@ class DecodeServer:
                 "serve.linear.state_updates",
                 "serve.conv.state_updates",
                 "serve.latent.positions_read",
-                "serve.latent.positions_cached")}
+                "serve.latent.positions_cached",
+                "serve.full.positions_live",
+                "serve.full.positions_cached")}
         # the mark (obs/legs.py: perf_counter first) at the last round's
         # return, while a slot is active
         self._round_returned: tuple | None = None
@@ -839,6 +848,7 @@ class DecodeServer:
             self._accept_ema: float | None = None
             self._rounds_since_adapt = 0
             self._ema_proposals = 0  # proposals folded into the EMA so far
+        self._chunks_ahead = self._build_chunks_ahead()
 
     _ADAPT_EVERY = 4        # rounds between depth decisions
     _ADAPT_DECAY = 0.8      # EMA decay on the per-round accept fraction
@@ -1052,7 +1062,7 @@ class DecodeServer:
                                                   self.cache_dtype)(
                         self.draft_params, jnp.asarray(dpadded),
                         jnp.asarray(real_len, jnp.int32))
-        self._prefix_tree.touch(node)  # the whole ancestor path is hot
+        self._prefix_tree.use(node)  # the whole ancestor path is hot
         self._prefill_tokens += slen
         return last, row, d_row, loads
 
@@ -1084,6 +1094,30 @@ class DecodeServer:
         self._ahead[pbucket, floor] = thread
         thread.start()
 
+    def _build_chunks_ahead(self) -> threading.Thread | None:
+        """Build, on a thread of its own and with the server, the ONE
+        program a model that :func:`_builds_few` prefills every prompt of a
+        chunk or more through (:meth:`_prefill_in_chunks`; with it the
+        empty row and the row's reading): a server whose lanes can hold
+        such a prompt will be sent one, and not only in a warm-up: a
+        resident context that fell out of the prefix store comes back
+        through it, with the next request that carries it.  Run once on a
+        chunk of zeros, as any program's first call builds it; the prefill
+        that needs it first waits for the thread."""
+        if (not _builds_few(self.model) or self.cache_dtype != "native"
+                or self.max_len < _PREFILL_CHUNK):
+            return None
+        empty, row_of = _empty_row_runner(self.model, self.max_len)
+        run = _chunk_runner(self.model, self.max_len)
+        zero, one = jnp.asarray(0, jnp.int32), jnp.asarray(1, jnp.int32)
+        args = (self.params, jnp.zeros((1, _PREFILL_CHUNK), jnp.int32))
+        thread = threading.Thread(
+            target=lambda: jax.block_until_ready(
+                row_of(run(*args, empty(), zero, one)[1])),
+            daemon=True, name=f"psdt-build-ahead-chunks-{self.max_len}")
+        thread.start()
+        return thread
+
     def _prefill_in_chunks(self, padded: np.ndarray, real_len: int):
         """A long prompt (``padded`` [1, bucket]) through _chunk_runner,
         ``_PREFILL_CHUNK`` tokens at a time against the row so far; returns
@@ -1092,6 +1126,8 @@ class DecodeServer:
         # (one program for every length: the row is filled a lane wide and
         # its bucket's positions are kept)
         total = self.max_len if _builds_few(self.model) else bucket
+        if self._chunks_ahead is not None:
+            self._chunks_ahead.join()   # (at once, but for a cold start)
         empty, row_of = _empty_row_runner(self.model, total)
         run = _chunk_runner(self.model, total)
         cache = empty()
@@ -1223,6 +1259,60 @@ class DecodeServer:
             self._admissions.append((start, self._watch.mark()))
             self._admission = None
 
+    def _forward_prompt(self, prompt: np.ndarray, pkey: tuple | None,
+                        anc, matched: int):
+        """A prompt no node replays, forwarded and put into the tree:
+        (last logits, its row, the draft's row | None, the experts layers'
+        loads | None).  Shared-prefix extension serves the prompt phase
+        whenever the tree holds ANY prefix of this prompt — including the
+        interior of a longer cached prompt (the radix point) — and in
+        speculative mode the draft row extends alongside the target row
+        (_radix_extend), so spec admissions no longer fall back to full
+        prefill (ISSUE 20 satellite); else a prefill, whole or in chunks
+        (:func:`_prefills_whole`)."""
+        real_len = int(prompt.shape[0])
+        bucket = min(_bucket(real_len), self.max_len)
+        tree = self._prefix_tree
+        extended = (self._radix_extend(prompt, real_len, anc, matched)
+                    if tree is not None else None)
+        if extended is not None:
+            # only the suffix ran a forward; the combined row
+            # splices below under its own (wider) width
+            last, row, d_row, loads = extended
+            self._prefix_hits += 1
+            flight.record("serve.prefix.hit",
+                          a=min(matched, real_len - 1),
+                          b=real_len - min(matched, real_len - 1))
+        else:
+            loads = None
+            with self._forward_leg(real_len):
+                padded = np.zeros((1, bucket), np.int32)
+                padded[0, :real_len] = prompt
+                if (self.cache_dtype == "native"
+                        and not _prefills_whole(self.model, bucket)):
+                    last, row = self._prefill_in_chunks(padded, real_len)
+                else:
+                    last, row, loads = _prefill_runner(
+                        self.model, bucket, self.cache_dtype)(
+                        self.params, jnp.asarray(padded),
+                        jnp.asarray(real_len, jnp.int32))
+                d_row = None
+                if self.draft is not None and self._k > 0:
+                    # k=0 (controller disabled speculation): the
+                    # draft cache is not read while disabled, so
+                    # skip its prefill + splice; a later re-probe
+                    # backfills via the cache-hit repair in _admit
+                    _, d_row, _ = _prefill_runner(
+                        self.draft, bucket, self.cache_dtype)(
+                        self.draft_params, jnp.asarray(padded),
+                        jnp.asarray(real_len, jnp.int32))
+            self._prefill_tokens += real_len
+            if tree is not None:
+                self._build_ahead(row)
+        if tree is not None:
+            self._admit_to_tree(pkey, last, row, d_row)
+        return last, row, d_row, loads
+
     def _admit(self, slot: int, prompt: np.ndarray, real_len: int,
                max_new_tokens: int, req_temp: float,
                stop: frozenset) -> int:
@@ -1239,7 +1329,7 @@ class DecodeServer:
         self._admission = {"prompt_tokens": real_len}
         pkey = hit = None
         anc, matched = None, 0
-        loads = None   # what an admission's forward routed, if it ran one
+        routed = []    # what an admission's forwards routed, if it ran any
         if tree is not None:
             with legs("serve/admit/lookup", hists["lookup"],
                       prompt_tokens=real_len) as leg:
@@ -1252,7 +1342,7 @@ class DecodeServer:
         # from the first dispatch to the first token on the host
         with obs_trace.timed("serve/admit/device", self._obs_admit_device):
             if hit is not None:
-                tree.touch(hit)  # the whole ancestor path, not one entry
+                tree.use(hit)  # the whole ancestor path, not one entry
                 self._prompt_hits += 1
                 self._prompt_tokens += real_len
                 last = hit.last
@@ -1273,59 +1363,30 @@ class DecodeServer:
                             jnp.asarray(real_len, jnp.int32))
                     self._admit_to_tree(pkey, last, row, d_row)
             else:
-                # Shared-prefix extension serves the prompt phase whenever
-                # the tree holds ANY prefix of this prompt — including the
-                # interior of a longer cached prompt (the radix point) —
-                # and in speculative mode the draft row extends alongside
-                # the target row (_radix_extend), so spec admissions no
-                # longer fall back to full prefill (ISSUE 20 satellite).
-                extended = (self._radix_extend(prompt, real_len, anc,
-                                               matched)
-                            if tree is not None else None)
-                if extended is not None:
-                    # only the suffix ran a forward; the combined row
-                    # splices below under its own (wider) width
-                    last, row, d_row, loads = extended
-                    self._prefix_hits += 1
-                    flight.record("serve.prefix.hit",
-                                  a=min(matched, real_len - 1),
-                                  b=real_len - min(matched, real_len - 1))
-                else:
-                    with self._forward_leg(real_len):
-                        padded = np.zeros((1, bucket), np.int32)
-                        padded[0, :real_len] = prompt
-                        if (self.cache_dtype == "native"
-                                and not _prefills_whole(self.model, bucket)):
-                            last, row = self._prefill_in_chunks(padded,
-                                                                real_len)
-                        else:
-                            last, row, loads = _prefill_runner(
-                                self.model, bucket, self.cache_dtype)(
-                                self.params, jnp.asarray(padded),
-                                jnp.asarray(real_len, jnp.int32))
-                        d_row = None
-                        if self.draft is not None and self._k > 0:
-                            # k=0 (controller disabled speculation): the
-                            # draft cache is not read while disabled, so
-                            # skip its prefill + splice; a later re-probe
-                            # backfills via the cache-hit repair above
-                            _, d_row, _ = _prefill_runner(
-                                self.draft, bucket, self.cache_dtype)(
-                                self.draft_params, jnp.asarray(padded),
-                                jnp.asarray(real_len, jnp.int32))
-                    self._prefill_tokens += real_len
-                    if tree is not None:
-                        self._build_ahead(row)
+                if self._state_layers and tree is not None:
+                    shared = tree.shared(pkey)
+                    if matched + _suffix_floor(self.model) <= shared \
+                            < real_len:
+                        # another prompt begins with these tokens and no
+                        # row holds the states there (a snapshot cannot be
+                        # cut back as K/V can): what two prompts share
+                        # gets a row of its own, and this one and every
+                        # later one extend it
+                        routed.append(self._forward_prompt(
+                            prompt[:shared], pkey[:shared], anc, matched)[3])
+                        anc, matched, _ = tree.lookup(pkey)
+                last, row, d_row, loads = self._forward_prompt(
+                    prompt, pkey, anc, matched)
+                routed.append(loads)
                 self._prompt_tokens += real_len
-                if tree is not None:
-                    self._admit_to_tree(pkey, last, row, d_row)
             with legs("serve/admit/first_token", hists["first_token"]):
                 self._rng, sub = jax.random.split(self._rng)
                 first = int(sample_token(last[None], sub, req_temp,
                                          self._top_k, self._top_p)[0])
-        if loads is not None:
-            # already on the host's side of the fetch of the first token
-            self._count_routing(np.asarray(loads), admission=True)
+        for loads in routed:
+            if loads is not None:
+                # already on the host's side of the fetch of the first token
+                self._count_routing(np.asarray(loads), admission=True)
         # splice widths come from the rows themselves: a radix-served
         # row is prefix-bucket + suffix-bucket wide, and the target and
         # draft rows may differ (each extended from its own ancestor
@@ -1655,9 +1716,11 @@ class DecodeServer:
         every lane (idle ones too: the device computes them); beside it
         ``positions``, what those lanes held in THAT round (each lane's
         length with its new token; a sparse layer each) and the states
-        the round advanced (a lane and linear, kda or conv layer each).  A
-        latent layer needs its lanes' ``positions`` and reads its whole
-        part: both are counted, a latent layer each."""
+        the round advanced (a lane and linear, kda, gdn or conv layer each).
+        A latent layer needs its lanes' ``positions`` and reads its whole
+        part: both are counted, a latent layer each; so are a full softmax
+        layer's, whose round reads its part whole whatever the lanes hold
+        (``serve.full.positions_live`` of ``serve.full.positions_cached``)."""
         if selected is not None:
             self._obs_mixers["serve.sparse.positions_selected"].add(
                 float(selected[0]))
@@ -1676,6 +1739,11 @@ class DecodeServer:
                 float(self._latent_layers * positions))
             self._obs_mixers["serve.latent.positions_cached"].add(
                 float(self._latent_layers * self.slots * self.max_len))
+        if self._full_layers:
+            self._obs_mixers["serve.full.positions_live"].add(
+                float(self._full_layers * positions))
+            self._obs_mixers["serve.full.positions_cached"].add(
+                float(self._full_layers * self.slots * self.max_len))
 
     def _count_routing(self, loads: np.ndarray,
                        admission: bool = False) -> None:

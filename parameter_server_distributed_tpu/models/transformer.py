@@ -41,9 +41,11 @@ Array = jax.Array
 
 
 FFN_KINDS = ("mlp", "moe", "experts")
-MIXER_KINDS = ("softmax", "sparse", "linear", "conv", "kda", "latent")
+MIXER_KINDS = ("softmax", "sparse", "linear", "conv", "kda", "latent", "gdn")
 # the mixers that keep a fixed-size STATE in a decode cache and no K/V
-STATE_MIXERS = ("linear", "conv", "kda")
+STATE_MIXERS = ("linear", "conv", "kda", "gdn")
+# the delta-rule mixers: a convolutions' register and a matrix a layer
+DELTA_MIXERS = ("kda", "gdn")
 # jax.ad_checkpoint name of a layer's mixer branch as it joins the residual
 # stream (Transformer._residual); Transformer._remat_policy may keep it
 MIXER_OUT = "mixer_out"
@@ -81,7 +83,14 @@ class LayerSpec:
     # [D, D] state decayed a key channel and corrected by the delta rule
     # (ops/delta_attention.py) behind short convolutions of q, k and v, with
     # a low-rank decay gate and output gate: TWO states, the convolutions'
-    # shift register and the matrix.  latent: causal attention without
+    # shift register and the matrix.  gdn: the gated delta rule with ONE
+    # decay a head (Yang et al.'s gated DeltaNet; the arm of
+    # ops/delta_attention.py that forms no [C, C, D] term), key heads of
+    # ``config.delta_key_dim`` and value heads of ``config.delta_value_dim``
+    # beside the softmax layers' ``head_dim``, a decay projection a head, a
+    # full-rank silu output gate and, under ``config.delta_neg_eigval``, a
+    # write strength of up to 2; the same two states, the matrix
+    # [H, Dk, Dv].  latent: causal attention without
     # rotary whose K and V are expanded from ONE normed latent of
     # ``config.kv_latent`` a position, with a key part of
     # ``config.qk_shared`` all heads share beside it; its cache keeps that
@@ -89,9 +98,11 @@ class LayerSpec:
     mixer: str = "softmax"
     # K/V heads of this layer; 0 = the config's
     kv_heads: int = 0
-    # RMS norm (a learned gain each) on every head of q and of k, before
-    # rotary
-    qk_norm: bool = False
+    # RMS norm (a learned gain each) of q and of k, before rotary.  True:
+    # on every head, a gain of ``head_dim``; "all": over ALL heads' channels
+    # at once, a gain of ``attn_dim`` for q and of the K/V heads' width for
+    # k (the Olmo block's)
+    qk_norm: bool | str = False
     # the attention's output times sigmoid(W_g x), x its normed input,
     # before the output projection
     gate: bool = False
@@ -107,12 +118,16 @@ class LayerSpec:
                              f"got {self.mixer!r}")
         if self.mixer != "softmax" and self.window:
             raise ValueError("a window belongs to a softmax layer")
-        if (self.mixer in ("conv", "kda", "latent")
+        if self.qk_norm not in (False, True, "all"):
+            raise ValueError(f"qk_norm is False, True (a head) or 'all', "
+                             f"got {self.qk_norm!r}")
+        if (self.mixer in ("conv", "kda", "gdn", "latent")
                 and (self.kv_heads or self.qk_norm or self.gate
                      or self.out_norm)):
             raise ValueError(
-                "a conv layer has no heads, a kda layer norms and gates its "
-                "output always and a latent layer has one latent for every "
+                "a conv layer has no heads, a kda or gdn layer norms and "
+                "gates its output always and a latent layer has one latent "
+                "for every "
                 "head: kv_heads, qk_norm, gate and out_norm belong to "
                 "softmax, sparse and linear layers")
         if self.window < 0:
@@ -217,8 +232,15 @@ class TransformerConfig:
     # MLP of ``mlp_act``'s form and this many experts' width on every
     # token (``moe/shared/w1|w2|w3``), added ungated to the routed part
     moe_shared_experts: int = 0
-    # taps of a ``conv`` layer's kernel, and of a ``kda`` layer's three
+    # taps of a ``conv`` layer's kernel, and of a ``kda`` or ``gdn``
+    # layer's three
     conv_kernel: int = 3
+    # a ``gdn`` layer's key and value head sizes (0: ``head_dim``'s), and
+    # whether its write strength is doubled, 2 sigmoid(.), so that I - beta
+    # k k^T has an eigenvalue in (-1, 1) (``linear_allow_neg_eigval``)
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    delta_neg_eigval: bool = False
     # a ``latent`` layer's compressed K/V: the width of the normed latent a
     # position keeps, and of the key part every head shares beside it
     kv_latent: int = 0
@@ -317,10 +339,20 @@ class TransformerConfig:
         mixers = {spec.mixer for spec in self.specs}
         if "sparse" in mixers and self.sparse is None:
             raise ValueError("a sparse layer needs config.sparse")
-        if mixers & {"conv", "kda"} and (self.conv_kernel < 2 or self.bias):
-            raise ValueError(f"a conv or kda layer has a kernel of 2 taps or "
-                             f"more and no bias, got conv_kernel="
+        if (mixers & {"conv", "kda", "gdn"}
+                and (self.conv_kernel < 2 or self.bias)):
+            raise ValueError(f"a conv, kda or gdn layer has a kernel of 2 "
+                             f"taps or more and no bias, got conv_kernel="
                              f"{self.conv_kernel}, bias={self.bias}")
+        if min(self.delta_key_dim, self.delta_value_dim) < 0 or (
+                "gdn" not in mixers and (self.delta_key_dim
+                                         or self.delta_value_dim
+                                         or self.delta_neg_eigval)):
+            raise ValueError("delta_key_dim, delta_value_dim and "
+                             "delta_neg_eigval are a gdn layer's: sizes of "
+                             "0 or more, and a model that has such a layer")
+        if "gdn" in mixers and self.pos_emb == "learned":
+            raise ValueError("a gdn layer has no learned positions")
         if "latent" in mixers and (self.kv_latent < 1 or self.bias
                                    or self.pos_emb == "learned"):
             raise ValueError("a latent layer needs config.kv_latent, and "
@@ -328,13 +360,19 @@ class TransformerConfig:
         if (mixers - {"softmax"} or self.prologue) and self.scan_layers:
             raise ValueError("scan_layers stacks one kind of cache part a "
                              "layer and scans whole periods: sparse, linear, "
-                             "conv, kda and latent layers and a prologue run "
-                             "unrolled")
+                             "conv, kda, gdn and latent layers and a "
+                             "prologue run unrolled")
 
     @property
     def attn_dim(self) -> int:
         """Width of the attention's inner side: n_heads * head_dim."""
         return self.n_heads * self.head_dim
+
+    @property
+    def delta_dims(self) -> tuple[int, int]:
+        """(key, value) head sizes of a ``gdn`` layer."""
+        return (self.delta_key_dim or self.head_dim,
+                self.delta_value_dim or self.head_dim)
 
     @property
     def latent_row(self) -> int:
@@ -768,6 +806,25 @@ class Transformer:
                      "attn/o_norm/scale": (c.head_dim,),
                      "attn/wo": (c.attn_dim, c.d_model),
                      "ln2/scale": (c.d_model,)}
+        elif spec.mixer == "gdn":
+            # key heads and value heads of their own sizes; the decay a
+            # head straight from the stream; the output gate full-rank
+            keys, values = (c.n_heads * size for size in c.delta_dims)
+            block = {"ln1/scale": (c.d_model,),
+                     "attn/wq": (c.d_model, keys),
+                     "attn/wk": (c.d_model, keys),
+                     "attn/wv": (c.d_model, values),
+                     "attn/conv_q": (c.conv_kernel, keys),
+                     "attn/conv_k": (c.conv_kernel, keys),
+                     "attn/conv_v": (c.conv_kernel, values),
+                     "attn/decay/w": (c.d_model, c.n_heads),
+                     "attn/decay/a_log": (c.n_heads,),
+                     "attn/decay/dt_bias": (c.n_heads,),
+                     "attn/beta/w": (c.d_model, c.n_heads),
+                     "attn/wz": (c.d_model, values),
+                     "attn/o_norm/scale": (c.delta_dims[1],),
+                     "attn/wo": (values, c.d_model),
+                     "ln2/scale": (c.d_model,)}
         elif spec.mixer == "latent":
             # wq: every head's query, its own part then the shared one;
             # wkv_a: the latent and the shared key part; wkv_b: every
@@ -787,7 +844,10 @@ class Transformer:
                      "attn/wv": (c.d_model, kv_dim),
                      "attn/wo": (c.attn_dim, c.d_model),
                      "ln2/scale": (c.d_model,)}
-            if spec.qk_norm:
+            if spec.qk_norm == "all":
+                block.update({"attn/q_norm/scale": (c.attn_dim,),
+                              "attn/k_norm/scale": (kv_dim,)})
+            elif spec.qk_norm:
                 block.update({"attn/q_norm/scale": (c.head_dim,),
                               "attn/k_norm/scale": (c.head_dim,)})
             if spec.gate:
@@ -890,12 +950,15 @@ class Transformer:
         # attention's products, a token: scores and values over S keys of
         # d_model (a latent layer's: its heads' own width and the shared key
         # part for the scores, its heads' for the values); a kda layer's are
-        # three products with its [D, D] states and do not grow with S
+        # three products with its [D, D] states (a gdn layer's [Dk, Dv]) and
+        # do not grow with S
         attn = 0.0
         for i in range(c.n_layers):
             mixer = c.layer_spec(i).mixer
             if mixer == "kda":
                 attn += attn_mult * 1.5 * c.attn_dim * c.head_dim
+            elif mixer == "gdn":
+                attn += attn_mult * 1.5 * c.n_heads * math.prod(c.delta_dims)
             elif mixer == "latent":
                 attn += attn_mult * seq * (c.attn_dim
                                            + c.n_heads * c.qk_shared / 2)
@@ -1077,10 +1140,16 @@ class Transformer:
             [params[f"{prefix}/attn/b{name}"] for name in "qkv"]
             if c.bias else None)
         kv_heads = (spec.kv_heads if spec is not None else 0) or c.kv_heads
+        if spec is not None and spec.qk_norm == "all":
+            # (before the heads are split: one norm over all of them)
+            q = rms_norm(q, params[f"{prefix}/attn/q_norm/scale"],
+                         c.norm_eps)
+            k = rms_norm(k, params[f"{prefix}/attn/k_norm/scale"],
+                         c.norm_eps)
         q = q.reshape(batch, seq, c.n_heads, c.head_dim)
         k = k.reshape(batch, seq, kv_heads, c.head_dim)
         v = v.reshape(batch, seq, kv_heads, c.head_dim)
-        if spec is not None and spec.qk_norm:
+        if spec is not None and spec.qk_norm and spec.qk_norm != "all":
             q = rms_norm(q, params[f"{prefix}/attn/q_norm/scale"],
                          c.norm_eps)
             k = rms_norm(k, params[f"{prefix}/attn/k_norm/scale"],
@@ -1148,8 +1217,9 @@ class Transformer:
                        preferred_element_type=jnp.float32)
             return self._residual(params, f"{prefix}/ln1", h, out), state
 
-    # positions a kda layer works through at a time (a [C, C, D] term a
-    # head: ops/delta_attention.py)
+    # positions a kda or gdn layer works through at a time (kda: a
+    # [C, C, D] term a head; both: a triangular solve of C rows;
+    # ops/delta_attention.py)
     DELTA_CHUNK = 64
 
     def kda_residual(self, params: Mapping[str, Array], prefix: str,
@@ -1171,7 +1241,6 @@ class Transformer:
         function for a whole sequence, a block against cached states and a
         decode round's single token."""
         from ..ops.delta_attention import gated_delta_rule
-        from ..ops.short_conv import short_conv
 
         c = self.config
         batch, seq = h.shape[:2]
@@ -1180,19 +1249,7 @@ class Transformer:
         attn = f"{prefix}/attn"
         with jax.named_scope("attn"), jax.named_scope("linear"):
             x = self._branch_input(params, f"{prefix}/ln1", h)
-            with jax.named_scope("conv"):
-                qkv = jnp.concatenate(
-                    [dot(x, params[f"{attn}/w{n}"]).astype(c.dtype)
-                     for n in "qkv"], axis=-1)
-                kernel = jnp.concatenate(
-                    [params[f"{attn}/conv_{n}"] for n in "qkv"], axis=-1)
-                mixed, shift = short_conv(qkv, kernel, shift, counts)
-                q, k, v = (part.reshape(batch, seq, c.n_heads, c.head_dim)
-                           for part in jnp.split(jax.nn.silu(mixed), 3,
-                                                 axis=-1))
-                q, k = (part * jax.lax.rsqrt(
-                    jnp.sum(part * part, axis=-1, keepdims=True) + 1e-6)
-                    for part in (q, k))
+            q, k, v, shift = self._delta_qkv(params, attn, x, shift, counts)
             with jax.named_scope("gates"):
                 def low_rank(name):
                     inner = dot(x, params[f"{attn}/{name}/wa"]).astype(c.dtype)
@@ -1215,6 +1272,89 @@ class Transformer:
                       params[f"{attn}/wo"])
             return (self._residual(params, f"{prefix}/ln1", h, out),
                     (shift, matrix))
+
+    @scoped("conv")
+    def _delta_qkv(self, params: Mapping[str, Array], attn: str, x: Array,
+                   shift: Array | None, counts: Array | None):
+        """A delta-rule layer's q, k, v of its branch input x [B, T, d]:
+        the three projections side by side through one depthwise causal
+        convolution (``shift`` its register, None at a sequence's start),
+        silu, split by head, q and k at unit length (1e-6 under the root).
+        Returns (q, k [B, T, H, Dk], v [B, T, H, Dv], the register after
+        the last real position).  The widths are the weights' own."""
+        from ..ops.short_conv import short_conv
+
+        c = self.config
+        dot = partial(wdot, preferred_element_type=jnp.float32)
+        qkv = jnp.concatenate(
+            [dot(x, params[f"{attn}/w{n}"]).astype(c.dtype)
+             for n in "qkv"], axis=-1)
+        kernels = [params[f"{attn}/conv_{n}"] for n in "qkv"]
+        mixed, shift = short_conv(qkv, jnp.concatenate(kernels, axis=-1),
+                                  shift, counts)
+        ends = list(itertools.accumulate(
+            kernel.shape[-1] for kernel in kernels))[:-1]
+        q, k, v = (part.reshape(*x.shape[:2], c.n_heads, -1)
+                   for part in jnp.split(jax.nn.silu(mixed), ends, axis=-1))
+        q, k = (part * jax.lax.rsqrt(
+            jnp.sum(part * part, axis=-1, keepdims=True) + 1e-6)
+            for part in (q, k))
+        return q, k, v, shift
+
+    def gdn_residual(self, params: Mapping[str, Array], prefix: str,
+                     h: Array, state: tuple | None = None,
+                     counts: Array | None = None) -> tuple[Array, tuple]:
+        """A ``gdn`` layer's whole mixer branch, under ``attn/linear``
+        (``conv``, ``gates``, ``delta`` inside, as ``kda`` has them): with
+        x the branch's input, q, k, v as :meth:`_delta_qkv` makes them
+        (key heads of ``delta_dims[0]``, value heads of ``delta_dims[1]``);
+        a log-decay a HEAD -exp(a_log) * softplus(W_a x + dt_bias), float32;
+        a write strength a head sigmoid(W_beta x), doubled under
+        ``delta_neg_eigval``; the gated delta rule's scalar-decay arm over
+        them (ops/delta_attention.py), its result (q carries the 1 /
+        sqrt(Dk)) normed a head with a gain [Dv], times silu(W_g x), through
+        W_o.  ``state`` is (the convolutions' shift register [B, K - 1,
+        H * (2 Dk + Dv)], the matrix [B, H, Dk, Dv] float32) of the positions
+        before (None: the sequence starts here), ``counts`` [B] how many of
+        the T are real.  Returns (new h, both states after the last real
+        position): one function for a whole sequence, a block against
+        cached states and a decode round's single token."""
+        from ..ops.delta_attention import gated_delta_rule
+
+        c = self.config
+        batch, seq = h.shape[:2]
+        shift, matrix = state if state is not None else (None, None)
+        dot = partial(wdot, preferred_element_type=jnp.float32)
+        attn = f"{prefix}/attn"
+        with jax.named_scope("attn"), jax.named_scope("linear"):
+            x = self._branch_input(params, f"{prefix}/ln1", h)
+            q, k, v, shift = self._delta_qkv(params, attn, x, shift, counts)
+            with jax.named_scope("gates"):
+                rate = jnp.exp(params[f"{attn}/decay/a_log"].astype(
+                    jnp.float32))
+                fall = -rate * jax.nn.softplus(
+                    dot(x, params[f"{attn}/decay/w"])
+                    + params[f"{attn}/decay/dt_bias"].astype(jnp.float32))
+                beta = jax.nn.sigmoid(dot(x, params[f"{attn}/beta/w"]))
+                if c.delta_neg_eigval:
+                    beta = 2.0 * beta
+                gate = jax.nn.silu(dot(x, params[f"{attn}/wz"])).astype(
+                    c.dtype)
+            with jax.named_scope("delta"):
+                out, matrix = gated_delta_rule(
+                    q * c.delta_dims[0] ** -0.5, k, v, fall, beta, matrix,
+                    counts, self.DELTA_CHUNK)
+                out = out.astype(c.dtype)
+            out = rms_norm(out, params[f"{attn}/o_norm/scale"], c.norm_eps)
+            out = dot(out.reshape(batch, seq, -1) * gate,
+                      params[f"{attn}/wo"])
+            return (self._residual(params, f"{prefix}/ln1", h, out),
+                    (shift, matrix))
+
+    def delta_residual(self, spec: LayerSpec) -> Callable:
+        """The mixer branch of a delta-rule layer of kind ``spec``:
+        :meth:`kda_residual` or :meth:`gdn_residual`, one signature."""
+        return self.kda_residual if spec.mixer == "kda" else self.gdn_residual
 
     def latent_rows(self, params: Mapping[str, Array], prefix: str,
                     h: Array) -> tuple[Array, Array]:
@@ -1596,7 +1736,8 @@ class Transformer:
                  ) -> tuple[Array, list, Array]:
         """(h, what a cache keeps of every layer under ``collect_kv``: a
         (k, v), a state layer's tuple of states (see :meth:`mix`,
-        :meth:`conv_residual` and :meth:`kda_residual`) or a latent layer's
+        :meth:`conv_residual`, :meth:`kda_residual` and
+        :meth:`gdn_residual`) or a latent layer's
         rows (:meth:`latent_residual`); aux loss).  ``counts``
         [B]: how many of a row's tokens are real, for the layers whose
         state must not hold a pad."""
@@ -1622,9 +1763,9 @@ class Transformer:
                 h, kept = self.conv_residual(layer_params, p, h,
                                              counts=counts)
                 kept = (kept,)
-            elif spec.mixer == "kda":
-                h, kept = self.kda_residual(layer_params, p, h,
-                                            counts=counts)
+            elif spec.mixer in DELTA_MIXERS:
+                h, kept = self.delta_residual(spec)(layer_params, p, h,
+                                                    counts=counts)
             elif spec.mixer == "latent":
                 h, kept = self.latent_residual(layer_params, p, h)
             else:
@@ -1815,11 +1956,13 @@ def unstack_layers(params: Mapping[str, Array]) -> dict:
 def transformer_rule(mesh: Mesh):
     """Sharding rule for transformer stores: Megatron TP + fsdp (+ EP).
 
-    column-parallel (tensor on output dim): wq wk wv w1 lm_head, and by
-        head a latent layer's wkv_b and a kda layer's gates' second halves
+    column-parallel (tensor on output dim): wq wk wv w1 lm_head, a gdn
+        layer's output gate wz, and by head a latent layer's wkv_b and a
+        kda layer's gates' second halves
     row-parallel  (tensor on input dim):    wo w2
-    (a kda layer's conv kernels, a_log, dt_bias, beta and its gates' first
-    halves, a latent layer's wkv_a: small, replicated)
+    (a kda or gdn layer's conv kernels, a_log, dt_bias, beta, a kda
+    layer's gates' first halves and a gdn layer's decay projection, a
+    latent layer's wkv_a: small, replicated)
     (a shared expert's ``moe/shared/w*`` as the dense MLP's)
     vocab-sharded embedding; norm scales replicated (fsdp if divisible);
     MoE expert weights sharded over the ``expert`` axis (router replicated).
@@ -1848,7 +1991,7 @@ def transformer_rule(mesh: Mesh):
         if name.endswith(("attn/wq", "attn/wk", "attn/wv", "mlp/w1",
                           "mlp/w3", "moe/shared/w1", "moe/shared/w3",
                           "lm_head/w", "attn/wkv_b", "attn/decay/wb",
-                          "attn/gate/wb")):
+                          "attn/gate/wb", "attn/wz")):
             taken = len(shape) - 1 if n_tp > 1 and shape[-1] % n_tp == 0 else None
             return PartitionSpec(*fsdp_on(len(shape) - 2, taken))
         if name.endswith(("attn/wo", "mlp/w2", "moe/shared/w2")):
@@ -1869,7 +2012,8 @@ def transformer_rule(mesh: Mesh):
         if name.endswith(("/scale", "/bias", "/bq", "/bk", "/bv", "/bo",
                           "/b1", "/b2", "/a_log", "/dt_bias", "attn/conv_q",
                           "attn/conv_k", "attn/conv_v", "attn/wkv_a",
-                          "attn/decay/wa", "attn/gate/wa", "attn/beta/w")):
+                          "attn/decay/wa", "attn/gate/wa", "attn/beta/w",
+                          "attn/decay/w")):
             # norm scales and all biases: tiny 1-D vectors, replicated like
             # their paired scales (an fsdp-sharded bias would force a
             # per-use all-gather against its tensor-sharded activation)

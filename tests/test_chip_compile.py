@@ -285,6 +285,107 @@ def test_kimi_linears_round_copies_no_latent_and_no_matrix_part(
         assert kernels == 0
 
 
+def test_olmo_hybrids_round_updates_k_v_and_matrices_where_they_lie(one_chip):
+    """``serve_reasoning_olmo_hybrid``'s decode round at its real widths, 12
+    slots x 4,096 positions, one whole period (three gdn layers and a full
+    one): the full layer's K and V (30 heads of 128, 755 MB together) and
+    the gdn layers' matrices [12, 30, 96, 192] float32 are updated where
+    they lie and nothing as large as one of them is copied, sliced or
+    turned around; the single token takes the one-position recurrence, so
+    no triangular solve is in the round.  A gdn layer's shift register is
+    rewritten whole by a round (0.8 MB a layer) and the compiler may stage
+    it, as LFM2's and Kimi Linear's: copies of exactly a register's size are
+    let through."""
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "olmo-hybrid-7b-16l.json")) as handle:
+        config = json.load(handle)
+    family = families.of(config)
+    model = family.model(config, remat=False, n_layers=4)
+    slots, max_len = 12, 4096
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(lambda: family.make_weights(model, 1)))
+    assert params["layer0/attn/conv_v"].shape == (4, 5760)
+    assert params["layer0/attn/wz"].shape == (3840, 5760)
+    assert params["layer3/attn/q_norm/scale"].shape == (3840,)
+    cache = placed(jax.eval_shape(
+        lambda: generation.init_cache(model, slots, max_len)))
+    assert [x.shape for x in cache.k] == [(12, 4096, 30, 128)]
+    assert [[(x.shape, x.dtype) for x in layer] for layer in cache.state] \
+        == [[((12, 3, 11520), jnp.bfloat16),
+             ((12, 30, 96, 192), jnp.float32)]] * 3
+    compiled = _compiled_round(model, params, cache, slots, one_chip)
+    aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
+    assert parts == 2 + 6 and aliased >= parts
+    register = 12 * 3 * 11520
+    assert [op for op in moved
+            if op[0] != "copy" or op[2] != register] == []
+    assert temporaries < cache_bytes / 4
+    assert "triangular" not in compiled.as_text()
+    # 30 rows of heads a position: the device lays the part by head, and
+    # the round writes a head's row an index (generation._lies_by_head)
+    assert generation._lies_by_head(30)
+    assert re.search(r"cache_k_0_\S* = bf16\[12,4096,30,128\]\{3,1,2,0",
+                     compiled.as_text())
+    # an admission: a row of 2,048 + 256 positions and its snapshot
+    bucket = 2048 + 256
+    row = placed((
+        jax.ShapeDtypeStruct((1, bucket, 30, 128), jnp.bfloat16),) * 2 + (
+        jax.ShapeDtypeStruct((3, 11520), jnp.bfloat16),
+        jax.ShapeDtypeStruct((30, 96, 192), jnp.float32)) * 3)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    spliced = serving._splice_runner(model, bucket, "native").lower(
+        cache, row, scalar, scalar).compile()
+    aliased, parts, moved, temporaries, _ = _held(spliced, cache)
+    assert aliased >= parts and moved == []
+    assert temporaries < cache_bytes / 4
+
+
+@pytest.mark.parametrize("heads,head_dim", [(12, 64), (20, 64), (8, 64),
+                                            (16, 128)])
+def test_a_round_writes_k_and_v_as_the_device_lays_them(one_chip, heads,
+                                                        head_dim):
+    """``generation._lies_by_head`` against the compiler, on GPT-2's block
+    at other head counts (two layers, 12 slots x 1,024): 12 and 20 heads of
+    64 (a served gpt2-small, gpt2-large) are 6 and 10 rows of heads a
+    position, which the device lays BY HEAD, and the round writes a row an
+    index; 8 heads of 64 and 16 of 128 are 4 and 16 rows, laid by position,
+    written a window a slot.  Either way the part is updated where it lies
+    and nothing as large as it is copied (by the other write, eight copies
+    of a part a round: PERF.md section 6, PR 50)."""
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "gpt2-medium.json")) as handle:
+        config = dict(json.load(handle), n_head=heads,
+                      n_embd=heads * head_dim)
+    family = families.of(config)
+    model = family.model(config, remat=False, n_layers=2)
+    slots = 12
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(lambda: family.make_weights(model, 1)))
+    cache = placed(jax.eval_shape(
+        lambda: generation.init_cache(model, slots, 1024)))
+    rows = cache.k[0].shape[2]
+    assert cache.k[0].shape == (12, 1024, rows, 128)
+    compiled = _compiled_round(model, params, cache, slots, one_chip)
+    aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
+    assert aliased >= parts and moved == []
+    assert temporaries < cache_bytes / 4
+    # (the parts as the round is handed them: its parameters)
+    layouts = set(re.findall(
+        r"bf16\[12,1024,%d,128\]\{([\d,]+)[^ ]* parameter\(" % rows,
+        compiled.as_text().split("\nENTRY")[1]))
+    assert layouts == {"3,1,2,0" if generation._lies_by_head(rows)
+                       else "3,2,1,0"}
+    assert generation._lies_by_head(rows) == (rows in (6, 10))
+
+
 def test_k_exaones_round_updates_rings_of_128_where_they_lie(one_chip):
     """``serve_chat_k_exaone_ep8``'s decode round at its real widths, 32
     slots x 4,096 positions, four layers (the dense one and two expert

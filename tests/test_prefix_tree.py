@@ -275,3 +275,61 @@ def test_eviction_counts_a_rows_snapshot_and_keeps_the_documents():
     assert (node.handle.row, matched, partial) == ("doc", 64, False)
     node, matched, _ = tree.lookup(document + (101, 7, 9))
     assert (node.handle.row, matched) == ("turn", 66)
+
+
+@pytest.mark.parametrize("used", [False, True])
+def test_a_row_nothing_started_from_goes_before_one_that_was(used):
+    """Two contexts and the second's long turns (no tails), a budget for
+    four rows: the first context is the least recently touched leaf, and it
+    stays where an admission has started from it (:meth:`PrefixTree.use`)
+    and no other leaf can say that; unused, it goes as the oldest."""
+    tree = PrefixTree(400)
+    first, second = tuple(range(100, 116)), tuple(range(200, 216))
+    tree.insert(first, "a", RowRef("first", 100))
+    tree.insert(second, "b", RowRef("second", 100))
+    if used:
+        tree.use(tree.lookup(first)[0])
+    for turn in range(3):
+        tree.insert(second + tuple(range(300 + 10 * turn, 308 + 10 * turn)),
+                    "t", RowRef(("turn", turn), 100))
+        tree.evict_over_budget()
+    assert tree.bytes == 400 and tree.evictions == 1
+    node, matched, _ = tree.lookup(first + (1,))
+    assert matched == (16 if used else 0)
+    assert (tree.lookup(second + tuple(range(300, 308)))[1] == 24) != used
+
+
+def test_shared_counts_what_a_path_holds_snapshot_or_not():
+    tree = PrefixTree(1000, snapshots=True)
+    path = tuple(range(40))
+    tree.insert(path, "p", RowRef("row", 100, state_at=40))
+    other = path[:25] + (99, 98)
+    assert tree.shared(other) == 25 and tree.lookup(other)[1] == 0
+    # the shared run admitted as a prompt of its own: a node with a
+    # snapshot where the split node had none
+    tree.insert(path[:25], "s", RowRef("shared", 60, state_at=25))
+    node, matched, partial = tree.lookup(other)
+    assert (node.handle.row, matched, partial) == ("shared", 25, False)
+    assert tree.shared(path + (7,)) == 40 and tree.shared((5,)) == 0
+
+
+def test_rows_asked_for_again_hold_a_share_of_the_budget_and_no_more():
+    """Three prompts each replayed once, a budget of four rows: the rows
+    admissions started from may hold half of it, so the oldest of the
+    three stands among the unused again and goes before the rows that came
+    after it, asked for again or not (with every row asked for again the
+    store is the plain LRU)."""
+    from parameter_server_distributed_tpu.models import prefix_tree
+
+    assert prefix_tree.PROTECTED_SHARE == 0.5
+    tree = PrefixTree(400)
+    prompts = [tuple(range(100 * i, 100 * i + 16)) for i in range(1, 6)]
+    for prompt in prompts[:3]:
+        tree.insert(prompt, "x", RowRef(prompt[0], 100))
+        tree.use(tree.lookup(prompt)[0])
+    for prompt in prompts[3:]:
+        tree.insert(prompt, "x", RowRef(prompt[0], 100))
+        tree.evict_over_budget()
+    held = [tree.lookup(prompt)[1] == 16 for prompt in prompts]
+    assert held == [False, True, True, True, True] and tree.evictions == 1
+    assert [tree.lookup(p)[0].uses for p in prompts[1:3]] == [1, 1]

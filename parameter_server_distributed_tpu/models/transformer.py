@@ -34,6 +34,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ..ops.pallas import ATTN_KERNEL_KEPT
 from .moe import moe_expert_weight_spec
 from .quant import QTensor, wdot
 
@@ -156,8 +157,10 @@ class TransformerConfig:
     # residuals instead of O(L), the standard long-context memory/FLOPs
     # trade on TPU (HBM is the bottleneck, MXU FLOPs are cheap).
     remat: bool = False
-    # What remat may keep: "full" recomputes everything (O(1) residuals,
-    # ~33% extra FLOPs — the whole forward again); "dots" applies
+    # What remat may keep: "full" recomputes a layer's arithmetic (O(1)
+    # residuals, ~33% extra FLOPs — the forward again) and keeps, besides
+    # the layer's input, only what is not cheap arithmetic to repeat (the
+    # two cases below); "dots" applies
     # jax.checkpoint_policies.dots_with_no_batch_dims_saveable — the
     # projection/MLP matmul outputs (dot_generals with no batch dims) are
     # SAVED and only the attention score/value einsums (batch dims B, H —
@@ -175,9 +178,25 @@ class TransformerConfig:
     # hides, because the FFN branch needs its result.  The price is
     # B/rows x S x d_model x sizeof(dtype) bytes a layer and chip (rows =
     # the mesh's data x fsdp; 84 MB at 32 x 1,024 x 1,280 in bf16), and the
-    # output projection's dot is not recomputed either.  Who trains at the
-    # edge of memory there takes a smaller micro-batch; there is no switch.
-    # Without a mesh and on ``tensor: 1`` nothing is kept, as before.
+    # output projection's dot is not recomputed either.
+    # Wherever the blockwise kernel attends (ops/pallas/fused_attention.py:
+    # the arms ``kernel`` and ``sharded_kernel`` of ``default_arm``, and
+    # Ulysses where a device's share takes the kernel), "full" also keeps
+    # the kernel's two results that its backward reads, on any mesh and
+    # without one (ATTN_KERNEL_KEPT): the output ``o``, B/rows x S x
+    # d_model/tensor in ``dtype`` a layer and chip, and the rows'
+    # logsumexp, B/rows x H/tensor x S in float32.  They are the one piece
+    # of a layer that costs a kernel call to rebuild (q, k and v are a
+    # norm and one product away); without them the remat forward runs the
+    # whole forward kernel a second time, 6% of a one-chip step.  The
+    # price at 64 x 1,024: 134.2 MB + 4.2 MB a layer for GPT-2 medium on
+    # one chip (24 layers: 3.3 GB, as much again as the layer inputs
+    # whole-layer remat keeps anyway), 41.9 MB + 1.3 MB for GPT-2 large on
+    # fsdp 2 x tensor 2 (36 layers: 1.6 GB a chip).  Where another arm
+    # attends (the einsum, ``blockwise``, ring, a latent, sparse, linear or
+    # state mixer) the names do not exist and nothing more is kept.
+    # Who trains at the edge of memory takes a smaller micro-batch; there
+    # is no switch.
     remat_policy: str = "full"
     # Chunked cross-entropy: compute the LM head + softmax in sequence
     # chunks of this many positions (0 = whole sequence at once).  Peak
@@ -923,15 +942,17 @@ class Transformer:
         held ones on average: ACTIVE and HELD.
 
         ``remat_credited=True`` counts the extra forward the hardware
-        actually executes under ``config.remat``: hardware-utilization
-        accounting for rematerialized runs.  Under the "full" policy that
-        is the whole forward again (+2*P and +4*L*d*S per token); under
-        "dots" the projection/MLP matmuls are saved and only the attention
-        einsums re-run (+4*L*d*S only).  On a ``tensor`` axis larger than
-        1, "full" keeps the mixer branch's output (``_remat_policy``), so
-        the mixer's output projection (2*d*d of the 2*P a token and
-        layer) is NOT run again there: the credited count stays what it
-        is, an upper bound on what the hardware executes."""
+        executes under ``config.remat``: hardware-utilization accounting
+        for rematerialized runs.  Under the "full" policy that is the whole
+        forward again (+2*P and +4*L*d*S per token); under "dots" the
+        projection/MLP matmuls are saved and only the attention einsums
+        re-run (+4*L*d*S only).  The credited count is an UPPER BOUND on
+        what the hardware executes under "full", because this function
+        cannot know what ``_remat_policy``'s names will find to keep: on a
+        ``tensor`` axis larger than 1 the mixer's output projection (2*d*d
+        of the 2*P a token and layer) is NOT run again, and wherever the
+        blockwise kernel attends (an arm a backend chooses from the shape)
+        its forward products (the +4*L*d*S) are NOT repeated either."""
         c = self.config
         seq = c.max_seq
         n_params = self.num_params()
@@ -967,16 +988,20 @@ class Transformer:
         return params_mult * n_params * seq + attn * seq
 
     def _remat_policy(self):
-        """config.remat_policy -> jax.checkpoint policy (None = save
-        nothing, i.e. full recompute).  On a mesh whose ``tensor`` axis is
-        larger than 1, "full" keeps the mixer branch's reduced output
-        (:data:`MIXER_OUT`, see ``TransformerConfig.remat_policy``): the
-        arithmetic is recomputed, the all-reduce is not paid twice."""
+        """config.remat_policy -> jax.checkpoint policy.  "full" recomputes
+        a layer's arithmetic and keeps what is not arithmetic to repeat
+        (see ``TransformerConfig.remat_policy``): the blockwise kernel's
+        output and row sums wherever that kernel attends
+        (``ATTN_KERNEL_KEPT``: the remat forward does not run the kernel a
+        second time), and on a mesh whose ``tensor`` axis is larger than 1
+        the mixer branch's reduced output (:data:`MIXER_OUT`: the
+        all-reduce is not paid twice).  A name nothing in the layer
+        carries keeps nothing."""
         if self.config.remat_policy == "dots":
             return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        if self._tensor_ways > 1:
-            return jax.checkpoint_policies.save_only_these_names(MIXER_OUT)
-        return None
+        kept = ATTN_KERNEL_KEPT + ((MIXER_OUT,) if self._tensor_ways > 1
+                                   else ())
+        return jax.checkpoint_policies.save_only_these_names(*kept)
 
     @property
     def _tensor_ways(self) -> int:

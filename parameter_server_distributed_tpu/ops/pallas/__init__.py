@@ -8,6 +8,15 @@ from __future__ import annotations
 
 import jax
 
+# jax.ad_checkpoint names of the blockwise attention kernel's output and
+# per-row logsumexp as its backward reads them
+# (``fused_attention._attention_fwd``): the two residuals of its vjp that
+# cost a kernel call to rebuild.  A jax.checkpoint around the call whose
+# policy keeps both (``Transformer._remat_policy``) runs the forward kernel
+# once a step; under any other policy the names are identities.  They live
+# here so that a model names them without importing pallas.
+ATTN_KERNEL_KEPT = ("attn_kernel_out", "attn_kernel_lse")
+
 
 def interpret_mode(*operands) -> bool:
     """True when a ``pallas_call`` over ``operands`` must be interpreted.

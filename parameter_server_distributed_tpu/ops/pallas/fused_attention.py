@@ -4,7 +4,9 @@ What the model's default path runs for a full-causal layer on a TPU
 (``models.transformer.Transformer.attend`` holds the rule): a block of
 scores lives in VMEM only, so no ``[B, H, S, S]`` array is ever written to
 HBM.  Online softmax forward; the backward recomputes the probabilities
-from the saved output and per-row logsumexp (O(S) residuals).
+from the saved output and per-row logsumexp (O(S) residuals), both named
+for ``jax.checkpoint`` (``ops.pallas.ATTN_KERNEL_KEPT``): a policy that
+keeps them spares the remat forward the kernel.
 
 **No transposes outside the kernel.**  The projections hand attention
 ``[B, S, H*D]`` (the head split is a reshape), and that is what the kernels
@@ -44,10 +46,11 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret_mode
+from . import ATTN_KERNEL_KEPT, interpret_mode
 
 LANES = 128
 NEG = -1e30          # a hidden score: exp(NEG - m) is exactly 0 in float32
@@ -498,7 +501,8 @@ def _attention(q, k, v, *shape):
 
 
 def _attention_fwd(q, k, v, *shape):
-    o, lse = _calls_for(q, *shape).forward(q, k, v)
+    o, lse = map(checkpoint_name, _calls_for(q, *shape).forward(q, k, v),
+                 ATTN_KERNEL_KEPT)
     return o, (q, k, v, o, lse)     # lse: [B, cells, heads of a cell, S]
 
 

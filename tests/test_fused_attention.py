@@ -15,6 +15,7 @@ from parameter_server_distributed_tpu.config import MeshConfig
 from parameter_server_distributed_tpu.models import transformer
 from parameter_server_distributed_tpu.models.transformer import (
     LayerSpec, Transformer, TransformerConfig, causal_attention)
+from parameter_server_distributed_tpu.ops.pallas import ATTN_KERNEL_KEPT
 from parameter_server_distributed_tpu.ops.pallas.fused_attention import (
     block_for, fits, fused_causal_attention)
 from parameter_server_distributed_tpu.parallel.mesh import build_mesh
@@ -241,3 +242,80 @@ def test_the_sharded_arm_is_the_kernel_on_every_shard(monkeypatch, rng):
     want = _out_and_grads(causal_attention, q, k, v, weight)
     for name, a, e in zip(("out", "dq", "dk", "dv"), got, want):
         np.testing.assert_allclose(a, e, rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+def _stacked_by_the_forward_loop(grad_jaxpr, n_layers):
+    """The shapes a layer of what the forward ``scan`` of a scanned
+    model's ``grad(loss)`` stacks for the backward one: what the layer's
+    ``jax.checkpoint`` keeps."""
+    forward = next(eqn for eqn in grad_jaxpr.jaxpr.eqns
+                   if eqn.primitive.name == "scan")
+    return sorted(var.aval.shape[1:] for var in forward.outvars
+                  if var.aval.ndim > 2 and var.aval.shape[0] == n_layers)
+
+
+# (case, backend is a TPU, mesh axes or None, arm, kept beside the input)
+KEPT = [
+    ("kernel", True, None, "kernel", ["o", "lse"]),
+    ("sharded-kernel", True, {"fsdp": 2, "tensor": 2}, "sharded_kernel",
+     ["o", "lse", "mixer_out"]),
+    ("einsum", False, None, "dense", []),
+    ("einsum-on-a-tensor-axis", False, {"fsdp": 2, "tensor": 2}, "dense",
+     ["mixer_out"]),
+]
+
+
+@pytest.mark.parametrize("case,tpu,axes,arm,kept", KEPT,
+                         ids=[a[0] for a in KEPT])
+def test_full_remat_keeps_the_kernels_output_and_row_sums(
+        monkeypatch, rng, case, tpu, axes, arm, kept):
+    """A 2-layer scanned, rematted model in float32: where the blockwise
+    kernel attends (alone or under ``shard_map``), "full" keeps the
+    kernel's output and its rows' logsumexp a layer, so the backward holds
+    three kernel calls and not four; loss and every gradient are those of
+    the step whose ``jax.checkpoint`` keeps nothing (the kept values ARE
+    the recomputed ones); no ``[B, H, S, S]`` value is kept either way.
+    Where the einsum attends the names do not exist and nothing more is
+    kept than before."""
+    batch, seq, heads, d = 4, 128, 4, 64
+    monkeypatch.setattr(transformer, "_kernel_backend", lambda: tpu)
+    mesh = None if axes is None else _mesh(**axes)
+    config = TransformerConfig(
+        vocab=64, d_model=heads * d, n_heads=heads, n_layers=2, d_ff=128,
+        max_seq=seq, dtype=jnp.float32, remat=True, scan_layers=True)
+    model = Transformer(config, mesh=mesh)
+    assert model.default_arm((batch, seq, heads, d), (batch, seq, heads, d),
+                             0) == arm
+    params = model.init_params(5)
+    tokens = jnp.asarray(rng.integers(0, 64, (batch, seq)), jnp.int32)
+
+    def step():
+        jaxpr = jax.make_jaxpr(jax.grad(model.loss))(params, tokens)
+        loss, grads = jax.jit(jax.value_and_grad(model.loss))(params, tokens)
+        return jaxpr, float(loss), jax.tree.map(np.asarray, grads)
+
+    jaxpr, loss, grads = step()
+    with monkeypatch.context() as patch:
+        patch.setattr(Transformer, "_remat_policy", lambda self: None)
+        bare_jaxpr, bare_loss, bare_grads = step()
+
+    # a shard_map hands the backward each device's own block, the blocks
+    # of all devices stacked on the first axis
+    tp = mesh.shape["tensor"] if arm == "sharded_kernel" else 1
+    shapes = {"o": (batch * tp, seq, heads * d // tp),
+              "lse": (batch * tp, heads * d // tp // 128, 128 // d, seq),
+              "mixer_out": (batch, seq, heads * d)}
+    layer_input = (batch, seq, heads * d)
+    assert _stacked_by_the_forward_loop(bare_jaxpr, 2) == [layer_input]
+    assert _stacked_by_the_forward_loop(jaxpr, 2) == sorted(
+        [layer_input] + [shapes[name] for name in kept])
+    kernel, text = arm != "dense", str(jaxpr)
+    for name in ATTN_KERNEL_KEPT:
+        assert (f"name={name}" in text) == kernel
+    assert (str(bare_jaxpr).count("pallas_call"),
+            text.count("pallas_call")) == ((4, 3) if kernel else (0, 0))
+    np.testing.assert_allclose(loss, bare_loss, rtol=1e-6)
+    assert set(grads) == set(bare_grads)
+    for name in grads:
+        np.testing.assert_allclose(grads[name], bare_grads[name], rtol=1e-6,
+                                   atol=1e-9, err_msg=name)

@@ -230,6 +230,17 @@ def test_remat_loss_and_gradients_match_non_remat(rng):
                                    atol=1e-7, err_msg=name)
 
 
+def _names_full_remat_keeps(model, monkeypatch):
+    """The names ``model._remat_policy()`` asks ``jax.checkpoint`` to keep."""
+    asked = []
+    real = jax.checkpoint_policies.save_only_these_names
+    with monkeypatch.context() as patch:
+        patch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                      lambda *names: asked.append(names) or real(*names))
+        assert model._remat_policy() is not None
+    return asked[-1]
+
+
 def test_remat_dots_policy_matches_full(rng):
     """remat_policy='dots' (save projection/MLP dot outputs, recompute
     only the attention einsums) must be numerically identical to the
@@ -266,6 +277,34 @@ def test_remat_dots_policy_matches_full(rng):
 
     with pytest.raises(ValueError, match="remat_policy"):
         TransformerConfig(remat_policy="bogus")
+
+
+@pytest.mark.parametrize("scan", [False, True], ids=["unrolled", "scan"])
+def test_remat_full_with_the_kernels_names_kept_matches_keeping_nothing(
+        rng, monkeypatch, scan):
+    """remat_policy='full' asks jax.checkpoint to keep the blockwise
+    attention kernel's two results by name (``_remat_policy``).  On the
+    CPU's arm no kernel runs, nothing carries the names and the policy
+    keeps nothing: loss and gradients are those of the policy forced to
+    ``None`` (keep nothing, the rule before the names existed), so the
+    names are harmless wherever another arm attends."""
+    from parameter_server_distributed_tpu.ops.pallas import ATTN_KERNEL_KEPT
+
+    tokens = rng.integers(0, 64, (4, 16)).astype(np.int32)
+    model = Transformer(TransformerConfig(
+        vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_seq=16,
+        dtype=jnp.float32, remat=True, scan_layers=scan))
+    params = model.init_params(0)
+    assert _names_full_remat_keeps(model, monkeypatch) == ATTN_KERNEL_KEPT
+    loss, grads = jax.jit(jax.value_and_grad(model.loss))(params, tokens)
+    monkeypatch.setattr(Transformer, "_remat_policy", lambda self: None)
+    bare_loss, bare_grads = jax.jit(jax.value_and_grad(model.loss))(
+        params, tokens)
+    np.testing.assert_allclose(float(loss), float(bare_loss), rtol=1e-6)
+    for name in bare_grads:
+        np.testing.assert_allclose(np.asarray(grads[name]),
+                                   np.asarray(bare_grads[name]), rtol=1e-6,
+                                   atol=1e-9, err_msg=name)
 
 
 def test_remat_generation_still_exact(rng):
@@ -547,10 +586,14 @@ def test_vit_flops_accounting_excludes_non_matmul_params():
 def test_full_remat_keeps_the_mixers_output_on_a_tensor_axis(scan, rng,
                                                              monkeypatch):
     """On ``fsdp 2 x tensor 2`` remat "full" keeps ONE named value a layer
-    (the mixer branch's reduced output, so the remat forward repeats no
-    all-reduce) and nothing without a mesh or on ``tensor: 1``; what is
-    kept is what would have been recomputed, so the loss and every
-    gradient are those of the same mesh with nothing kept."""
+    of this model (the mixer branch's reduced output, so the remat forward
+    repeats no all-reduce) beside the blockwise kernel's two, which it asks
+    for on every mesh and without one and which nothing carries here (the
+    CPU's arm is the einsum); what is kept is what would have been
+    recomputed, so the loss and every gradient are those of the same mesh
+    with nothing kept."""
+    from parameter_server_distributed_tpu.models.transformer import MIXER_OUT
+    from parameter_server_distributed_tpu.ops.pallas import ATTN_KERNEL_KEPT
     from parameter_server_distributed_tpu.parallel.mesh import batch_sharding
     from parameter_server_distributed_tpu.parallel.sharding import shard_store
 
@@ -559,12 +602,13 @@ def test_full_remat_keeps_the_mixers_output_on_a_tensor_axis(scan, rng,
         dtype=jnp.float32, pos_emb="learned", norm="layernorm", bias=True,
         remat=True, scan_layers=scan)
     model = Transformer(config)
-    assert model._remat_policy() is None
+    assert _names_full_remat_keeps(model, monkeypatch) == ATTN_KERNEL_KEPT
     model.on_mesh(build_mesh(MeshConfig(fsdp=4), devices=jax.devices()[:4]))
-    assert model._remat_policy() is None
+    assert _names_full_remat_keeps(model, monkeypatch) == ATTN_KERNEL_KEPT
     mesh = build_mesh(MeshConfig(fsdp=2, tensor=2), devices=jax.devices()[:4])
     model.on_mesh(mesh)
-    assert model._remat_policy() is not None
+    assert _names_full_remat_keeps(model, monkeypatch) == (
+        ATTN_KERNEL_KEPT + (MIXER_OUT,))
 
     params = shard_store(model.init_params(3), mesh, transformer_rule(mesh))
     tokens = jax.device_put(

@@ -666,8 +666,8 @@ class DecodeServer:
         self._linear_layers = len(config.layers_of("linear")
                                   + config.layers_of("kda")
                                   + config.layers_of("gdn"))
-        self._conv_layers = len(config.layers_of("conv"))
-        self._state_layers = self._linear_layers + self._conv_layers
+        self._state_layers = self._linear_layers + len(
+            config.layers_of("conv"))
         self._sparse_layers = len(config.layers_of("sparse"))
         self._latent_layers = len(config.layers_of("latent"))
         if draft is not None:
@@ -746,15 +746,14 @@ class DecodeServer:
                                       "admit_experts_touched")}
         for kind, held in self._cache_bytes_by_kind().items():
             obs_stats.gauge(f"serve.cache.{kind}_bytes").set(held)
-        # what a round's sparse, linear (kda and gdn too), conv, latent and
-        # full layers read (see _count_mixers)
+        # what a round's sparse, linear (kda and gdn too), latent and full
+        # layers read (see _count_mixers)
         self._obs_mixers = {
             name: obs_stats.counter(name) for name in (
                 "serve.sparse.positions_selected",
                 "serve.sparse.positions_cached",
                 "serve.sparse.kernels_scored",
                 "serve.linear.state_updates",
-                "serve.conv.state_updates",
                 "serve.latent.positions_read",
                 "serve.latent.positions_cached",
                 "serve.full.positions_live",
@@ -1710,13 +1709,13 @@ class DecodeServer:
 
     def _count_mixers(self, selected: np.ndarray | None,
                       positions: int) -> None:
-        """One decode round into the counters the sparse, linear, conv and
+        """One decode round into the counters the sparse, linear and
         latent layers' metrics divide.  ``selected`` is the round's own
         [positions attended, kernels scored] over its sparse layers and
         every lane (idle ones too: the device computes them); beside it
         ``positions``, what those lanes held in THAT round (each lane's
         length with its new token; a sparse layer each) and the states
-        the round advanced (a lane and linear, kda, gdn or conv layer each).
+        the round advanced (a lane and linear, kda or gdn layer each).
         A latent layer needs its lanes' ``positions`` and reads its whole
         part: both are counted, a latent layer each; so are a full softmax
         layer's, whose round reads its part whole whatever the lanes hold
@@ -1731,9 +1730,6 @@ class DecodeServer:
         if self._linear_layers:
             self._obs_mixers["serve.linear.state_updates"].add(
                 self.slots * self._linear_layers)
-        if self._conv_layers:
-            self._obs_mixers["serve.conv.state_updates"].add(
-                self.slots * self._conv_layers)
         if self._latent_layers:
             self._obs_mixers["serve.latent.positions_read"].add(
                 float(self._latent_layers * positions))

@@ -264,6 +264,62 @@ class _Carved:
             self._annotation.__exit__(None, None, None)
 
 
+class phases:
+    """A block cut into spans that follow one another without a gap, on
+    one thread, under one parent: the first is named here, and
+    :meth:`next` ends the one that is open and names the one that begins
+    at the same instant.
+
+    >>> with phases("rpc/round/send") as phase:
+    ...     send()
+    ...     phase.next("rpc/round/turn")
+    ...     wait()
+
+    A cut costs ONE clock read where it is made; the spans are recorded
+    when the block ends.  So a cut may stand where the thread has no
+    time to lose: between two ring reads while a dozen other threads want
+    the interpreter's lock, a span closed and another opened (two ids,
+    two annotations, the buffer's lock) is enough Python for the thread
+    to be caught outside the ring's wait it was heading for, and the
+    wait's time then lies under no leg (PERF.md section 6, PR 52).  The
+    price: the phases are not pushed on the thread's stack, so what the
+    block opens keeps the parent it would have had (the phases hold it in
+    TIME, not by id), and they have no mirror on the profiler's timeline.
+    They are children of the span open when the block ends and take its
+    ``iteration``."""
+
+    __slots__ = ("_names", "_cuts", "args")
+
+    def __init__(self, name: str, **args: Any):
+        self._names = [name]
+        self._cuts: list[float] | None = None
+        self.args = args
+
+    def __enter__(self) -> "phases":
+        if _enabled:
+            self._cuts = [time.time()]
+        return self
+
+    def next(self, name: str) -> None:
+        if self._cuts is not None:
+            self._names.append(name)
+            self._cuts.append(time.time())
+
+    def __exit__(self, *exc) -> None:
+        cuts = self._cuts
+        if cuts is None:
+            return
+        cuts.append(time.time())
+        stack = _stack()
+        trace_id, parent_id, iteration = (stack[-1] if stack
+                                          else (_new_id(), "", None))
+        if iteration is not None:
+            self.args.setdefault("iteration", iteration)
+        for name, t0, t1 in zip(self._names, cuts, cuts[1:]):
+            _record(name, trace_id, _new_id(), parent_id, t0, t1 - t0,
+                    self.args)
+
+
 @contextlib.contextmanager
 def attach(ctx: tuple[str, str] | None) -> Iterator[None]:
     """Make ``ctx`` (a :func:`current` result captured on ANOTHER thread)
@@ -303,9 +359,13 @@ class SpanHolder:
     remote parent arrives on the first request chunk, after the handler
     already started.  Construct at handler entry (stamps t0), call
     :meth:`adopt` as chunks arrive (first parseable context wins — it is
-    pushed onto the thread's span stack so spans the handler opens later,
-    e.g. ``ps/apply`` after draining a streamed push, join the caller's
-    trace), and :meth:`finish` on the way out.  adopt/finish must run on
+    pushed onto the thread's span stack, with the chunk's ``iteration``
+    where the caller has one, so spans the handler opens later, e.g.
+    ``ps/apply`` after draining a streamed push, join the caller's trace
+    and the ring and codec legs of the handler's thread know their round
+    as the worker's do), and :meth:`finish` on the way out.  What the
+    thread did before the context arrived (the ring's wait for the
+    round's first frame) keeps no iteration.  adopt/finish must run on
     the handler's thread (they do: gRPC drains the request iterator inside
     the handler call)."""
 
@@ -322,14 +382,17 @@ class SpanHolder:
         self._parent_id = ""
         self._pushed = False
 
-    def adopt(self, ctx: bytes | str) -> None:
+    def adopt(self, ctx: bytes | str, iteration: int | None = None) -> None:
         if not _enabled or self._pushed:
             return
         parsed = parse_context(ctx)
         if parsed is None:
             return
         self._trace_id, self._parent_id = parsed
-        _stack().append((self._trace_id, self._span_id, None))
+        if iteration is not None:
+            self.args.setdefault("iteration", iteration)
+        _stack().append((self._trace_id, self._span_id,
+                         self.args.get("iteration")))
         self._pushed = True
 
     def finish(self) -> None:

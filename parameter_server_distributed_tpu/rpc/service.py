@@ -88,7 +88,8 @@ def _instrument_handler(behavior: Callable, method: str, style: str):
 
             def chunks():
                 for req in request_iterator:
-                    holder.adopt(getattr(req, "trace_context", b""))
+                    holder.adopt(getattr(req, "trace_context", b""),
+                                 getattr(req, "iteration", None))
                     yield req
 
             try:
@@ -110,7 +111,8 @@ def _instrument_handler(behavior: Callable, method: str, style: str):
 
             def chunks():
                 for req in request_iterator:
-                    holder.adopt(getattr(req, "trace_context", b""))
+                    holder.adopt(getattr(req, "trace_context", b""),
+                                 getattr(req, "iteration", None))
                     yield req
 
             def stream():

@@ -766,6 +766,17 @@ class ShmClientConnection:
         never holds more of the response than the receive pool.  Returns
         what ``consume`` returns.
 
+        The round's three phases are spans of the caller's thread, one
+        after another, which hold the legs in time
+        (``obs_trace.phases``: a cut is one clock read, because the one
+        between the first two response frames stands where the thread
+        has no time to lose): ``rpc/round/send`` (from before the first
+        message is asked of its source until the end marker is in the
+        ring), ``rpc/round/turn`` (until the first response frame has
+        been read: the server's drain of the push and its close) and
+        ``rpc/round/receive`` (until the server's end marker has been
+        read, the consumer's landing of every frame included).
+
         Everything happens INSIDE the round lock, and two things hold by
         construction.  The connection is never left half-read: frames the
         consumer did not take are drained to the server's end marker, and
@@ -777,12 +788,14 @@ class ShmClientConnection:
         take what is needed out of it before asking for the next."""
         deadline = time.monotonic() + (timeout if timeout else 3600.0)
 
-        def response() -> Iterator[memoryview]:
-            while True:
-                frame = self.s2c.read_frame(deadline)
-                if frame is None:
-                    return
+        def response(phase) -> Iterator[memoryview]:
+            frame = self.s2c.read_frame(deadline)
+            # the round turns where the first response frame has left
+            # the ring: until then the server drains the push and closes
+            phase.next("rpc/round/receive")
+            while frame is not None:
                 yield frame
+                frame = self.s2c.read_frame(deadline)
 
         with self._lock:
             if self.c2s.closed or self.s2c.closed:
@@ -791,17 +804,19 @@ class ShmClientConnection:
                 # finds bytes never looks at the latch
                 raise ShmTransportError("shm connection latched closed")
             try:
-                for message in messages:
-                    self.c2s.write_message(message, deadline,
-                                           "rpc/client/encode")
-                self.c2s.write_end(deadline)
-                answer = response()
-                try:
-                    result = consume(answer)
-                    for _ in answer:  # what the consumer left unread
-                        pass
-                finally:
-                    answer.close()
+                with obs_trace.phases("rpc/round/send") as phase:
+                    for message in messages:
+                        self.c2s.write_message(message, deadline,
+                                               "rpc/client/encode")
+                    self.c2s.write_end(deadline)
+                    phase.next("rpc/round/turn")
+                    answer = response(phase)
+                    try:
+                        result = consume(answer)
+                        for _ in answer:  # what the consumer left unread
+                            pass
+                    finally:
+                        answer.close()
             except ShmTransportError:
                 raise
             except BaseException:
@@ -955,7 +970,8 @@ class _ServerConnection:
                         with obs_trace.span("rpc/server/decode",
                                             bytes=len(frame)):
                             chunk = m.GradientUpdate.decode(frame)
-                        holder.adopt(getattr(chunk, "trace_context", b""))
+                        holder.adopt(getattr(chunk, "trace_context", b""),
+                                     chunk.iteration)
                         yield chunk
                         frame = self.c2s.read_frame(
                             time.monotonic() + 3600.0)

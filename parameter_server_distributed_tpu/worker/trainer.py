@@ -57,6 +57,17 @@ _obs_fresh_bytes = obs_stats.counter("worker.pull.fresh_bytes")
 _obs_copied_bytes = obs_stats.counter("worker.pack.copied_bytes")
 
 
+def _wait_for_upload(uploaded) -> None:
+    """Inside ``worker/device_wait``, while spans are recorded: wait for
+    the step's uploaded input first, under a span of its own, so that the
+    wait for the step does not hold the transfer (``jax.device_put``
+    returns before the bytes are on the device).  With recording off
+    there is nothing to wait for (:meth:`Trainer._dispatch_step` hands
+    out None) and no call."""
+    with obs_trace.span("worker/device_wait/upload"):
+        uploaded.block_until_ready()
+
+
 class GradientBuckets:
     """Lazily-fetched packed gradients: the D2H leg of the pipelined data
     plane.
@@ -79,8 +90,12 @@ class GradientBuckets:
     pipelining."""
 
     def __init__(self, layout, device_flat, bucket_bytes: int,
-                 on_fetch: Callable[[int, int], None] | None = None):
+                 on_fetch: Callable[[int, int], None] | None = None,
+                 uploaded=None):
         self._device = device_flat
+        # the step's uploaded input while spans are recorded (see
+        # _wait_for_upload), dropped at the first wait
+        self._uploaded = uploaded
         self.on_fetch = on_fetch
         # greedy plan over the fixed layout: consecutive tensors grouped
         # into ~bucket_bytes f32 slices of the flat output (loss scalar
@@ -131,6 +146,9 @@ class GradientBuckets:
                     self.on_fetch(i, len(self._plan))
                 a, b, _ = self._plan[i]
                 with obs_trace.span(leg, bucket=i, bytes=4 * (b - a)):
+                    if self._uploaded is not None:
+                        uploaded, self._uploaded = self._uploaded, None
+                        _wait_for_upload(uploaded)
                     buf = self._host[i] = np.asarray(self._dev_slice(i))
         return buf
 
@@ -350,7 +368,9 @@ class Trainer:
 
     def _dispatch_step(self, params: Mapping[str, np.ndarray], batch):
         """Pack + upload + launch the jitted step; returns the (async)
-        flat device output without fetching it."""
+        flat device output without fetching it, and beside it the
+        uploaded input (the step does not donate it) while spans are
+        recorded, None otherwise: what :func:`_wait_for_upload` takes."""
         with obs_trace.span("worker/pack", bytes=4 * self._padded_in):
             packed = self._pack(params)
         with _DISPATCH_LOCK:
@@ -358,7 +378,8 @@ class Trainer:
                 flat = jax.device_put(packed, self._flat_sharding)
                 batch = self._shard_batch(batch)
             with obs_trace.span("worker/dispatch"):
-                return self._step(flat, batch)
+                out = self._step(flat, batch)
+        return out, (flat if obs_trace.enabled() else None)
 
     def compute_gradients(self, params: Mapping[str, np.ndarray],
                           batch) -> tuple[TensorStore, float]:
@@ -366,10 +387,13 @@ class Trainer:
 
         One H2D upload (packed params), one D2H fetch (loss + packed
         grads), regardless of tensor count."""
-        out = self._dispatch_step(params, batch)
+        out, uploaded = self._dispatch_step(params, batch)
         # wait for the step first, so that the one whole-output fetch
         # below times the copy alone
         with obs_trace.span("worker/device_wait"):
+            if uploaded is not None:
+                _wait_for_upload(uploaded)
+                del uploaded    # or it holds its HBM through the fetch
             out.block_until_ready()
         with obs_trace.span("worker/d2h", bytes=4 * self._padded_out):
             packed = np.asarray(out)
@@ -390,6 +414,6 @@ class Trainer:
         if bucket_bytes is None:
             from ..rpc.data_plane import bucket_bytes as _bb
             bucket_bytes = _bb()
-        return GradientBuckets(self._layout,
-                               self._dispatch_step(params, batch),
-                               bucket_bytes, on_fetch=on_fetch)
+        out, uploaded = self._dispatch_step(params, batch)
+        return GradientBuckets(self._layout, out, bucket_bytes,
+                               on_fetch=on_fetch, uploaded=uploaded)

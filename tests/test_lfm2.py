@@ -378,7 +378,7 @@ def test_a_prompt_prefilled_in_chunks_across_a_boundary(small, monkeypatch):
     assert served == np.argmax(after[109:115], -1).tolist()
 
 
-def test_the_cache_by_kind_and_the_counters_count_the_states(small):
+def test_the_cache_by_kind_counts_the_states(small):
     server = _server(small, prompt_cache=8, prefix_cache_bytes=1 << 24)
     kinds = server._cache.nbytes_by_kind()
     # 4 slots: one attention layer's K and V of 128 positions x 2 heads of
@@ -389,14 +389,10 @@ def test_the_cache_by_kind_and_the_counters_count_the_states(small):
     assert kinds == {"full": 4 * 2 * 128 * 2 * 16 * 4, "window": 0,
                      "state": 4 * 5 * 2 * 64 * 4, "latent": 0}
     assert server.stats["cache_state_bytes"] == kinds["state"]
-    before = server._obs_mixers["serve.conv.state_updates"].value
     rid = server.submit(np.arange(1, 41, dtype=np.int32), max_new_tokens=6)
     # the row in the tree: K and V of a 64-position bucket and the snapshot
     assert server._prefix_tree.bytes == 2 * 64 * 2 * 16 * 4 + 5 * 2 * 64 * 4
     assert len(server.run_to_completion()[rid]) == 6
-    moved = server._obs_mixers["serve.conv.state_updates"].value - before
-    # every fetched round advanced 4 lanes x 5 conv layers
-    assert moved >= 5 * 4 * 5 and moved % (4 * 5) == 0
 
 
 @pytest.mark.parametrize("feature", ["draft", "int8", "speculative"])

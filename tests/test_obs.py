@@ -149,6 +149,70 @@ def test_span_context_parse_rejects_garbage():
         obs_trace.clear()
 
 
+@pytest.mark.parametrize("iteration", [None, 0, 7])
+def test_span_holder_adopts_the_chunks_iteration(tracing, iteration):
+    """What a handler's thread opens after the context arrived inherits
+    the chunk's iteration (0 is a round like any other); what it did
+    before keeps none, and neither does anything after ``finish``."""
+    with obs_trace.span("caller"):
+        ctx = obs_trace.wire_context()
+    caller_trace, caller_span = obs_trace.parse_context(ctx)
+    holder = obs_trace.SpanHolder("handler")
+    with obs_trace.span("before"):
+        pass
+    holder.adopt(ctx, iteration)
+    holder.adopt(ctx, 99)               # the first context wins
+    with obs_trace.span("leg"):
+        with obs_trace.timed("frame") as frame:
+            with frame.carve("blocked"):
+                pass
+    holder.finish()
+    with obs_trace.span("after"):
+        pass
+    spans = {s["name"]: s for s in obs_trace.spans()}
+    want = {} if iteration is None else {"iteration": iteration}
+    for name in ("handler", "leg", "frame", "blocked"):
+        assert spans[name].get("args", {}) == want, name
+        assert spans[name]["trace_id"] == caller_trace
+    assert spans["handler"]["parent_id"] == caller_span
+    assert spans["leg"]["parent_id"] == spans["handler"]["span_id"]
+    for name in ("before", "after"):
+        assert "args" not in spans[name]
+        assert spans[name]["trace_id"] != caller_trace
+
+
+def test_phases_cut_a_block_without_a_gap_under_one_parent(tracing):
+    """``phases``: spans that follow one another to the clock's digit, each
+    a child of the span open around the block with its iteration, none of
+    them on the stack (what the block opens keeps its parent)."""
+    with obs_trace.span("call", iteration=5):
+        with obs_trace.phases("a", transport="shm") as phase:
+            with obs_trace.span("leg"):
+                pass
+            phase.next("b")
+            phase.next("c")
+            assert obs_trace.current()[1] != ""
+    spans = {s["name"]: s for s in obs_trace.spans()}
+    a, b, c, call = (spans[n] for n in ("a", "b", "c", "call"))
+    assert a["ts"] + a["dur"] == pytest.approx(b["ts"], abs=1e-9)
+    assert b["ts"] + b["dur"] == pytest.approx(c["ts"], abs=1e-9)
+    assert call["ts"] <= a["ts"] and \
+        c["ts"] + c["dur"] <= call["ts"] + call["dur"]
+    for phase in (a, b, c):
+        assert phase["parent_id"] == call["span_id"]
+        assert phase["trace_id"] == call["trace_id"]
+        assert phase["args"] == {"transport": "shm", "iteration": 5}
+    assert len({a["span_id"], b["span_id"], c["span_id"]}) == 3
+    assert spans["leg"]["parent_id"] == call["span_id"]
+
+
+def test_phases_cost_no_span_when_recording_is_off():
+    obs_trace.clear()
+    with obs_trace.phases("a") as phase:
+        phase.next("b")
+    assert obs_trace.spans() == []
+
+
 def test_span_propagates_over_grpc(cluster1, tracing):
     """Client span -> request extension field -> server handler span, in
     one trace; the PS-side ps/serve span nests under the handler."""

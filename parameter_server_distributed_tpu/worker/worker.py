@@ -431,11 +431,14 @@ class Worker:
 
         Where the trainer lends the buffer its next step uploads
         (``Trainer.lend_store``) a served tensor goes from its frame into
-        its slot there in ONE pass, and ``local`` holds the slot: no new
-        memory, and the step then uploads the store where it lies.  The
-        frame's view (``Tensor.borrow_array``) is let go before the next
-        chunk is asked for.  A name the layout does not have, another
-        size, an empty tensor, or a trainer that lends nothing is
+        its slot there in ONE pass (``_Loan.land``, which also starts
+        the upload of every section of the buffer that is whole by
+        then), and ``local`` holds the slot, read-only: no new memory,
+        and the step then takes the store where it lies, most of it on
+        the device already.  The frame's view (``Tensor.borrow_array``)
+        is let go before the next chunk is asked for.  A name the layout
+        does not have, another size, an empty tensor, or a trainer that
+        lends nothing is
         ``Tensor.to_array`` as ever, counted in
         ``worker.pull.fresh_bytes``; so is the array a packed or float64
         wire had to be unpacked into on its way to the slot.  The loan is
@@ -457,8 +460,7 @@ class Worker:
                         continue
                     if src.flags.writeable:
                         fresh.add(src.nbytes)
-                    local[t.name] = slot = slot.reshape(src.shape)
-                    np.copyto(slot, src, casting="unsafe")
+                    local[t.name] = dest.land(t.name, src)
         return convert_chunk
 
     def pull_parameters(self, iteration: int) -> tuple[int, TensorStore]:

@@ -486,8 +486,18 @@ def test_ps_round_legs_are_disjoint_and_cover_the_step(cluster1,
     assert buckets > 2 and len(fetched) == buckets
     # once per unit of work: per round, per bucket, per tensor and chunk,
     # per ring frame (a read also sees the 4-byte end marker)
-    assert count["worker/pack"] == count["worker/h2d"] == \
-        count["worker/dispatch"] == count["worker/device_wait"] == 1
+    assert count["worker/pack"] == count["worker/dispatch"] == \
+        count["worker/device_wait"] == 1
+    # the upload at dispatch (of nothing: the store went up as it landed)
+    # and one put a section of the NEXT step's input, as the pull lands it,
+    # with one more for their join on the device
+    streamed = [s for s in mine if s["name"] == "worker/h2d"
+                and ("section" in s["args"] or "joined" in s["args"])]
+    sections = len(worker.trainer._cuts)
+    assert sections > 2 and count["worker/h2d"] == 1 + sections + 1
+    assert sorted(s["args"]["section"] for s in streamed[:-1]) == \
+        list(range(sections))
+    assert streamed[-1]["args"]["joined"] == sections
     assert count["worker/d2h"] == buckets - 1   # bucket 0 is device_wait
     tensors = len(worker.trainer._layout)
     sent = served["ps/fold"]                     # chunks with gradients
@@ -543,8 +553,13 @@ def test_ps_round_legs_are_disjoint_and_cover_the_step(cluster1,
     # the leaves are disjoint (to the clocks' 0.2 ms) and cover the step
     # but for its glue: the batch, the generators, the bookkeeping after
     # the round (a third of a round of milliseconds; under 5% on the chip)
-    leaves = sorted((s for s in mine if s["name"] in WORKER_LEAVES),
-                    key=lambda s: s["ts"])
+    # (a section's put stands INSIDE the decode leg that landed it)
+    leaves = sorted((s for s in mine if s["name"] in WORKER_LEAVES
+                     and s not in streamed), key=lambda s: s["ts"])
+    converts = [s for s in leaves if s["name"] == "rpc/client/decode"]
+    for put in streamed:
+        assert any(c["ts"] - 2e-4 <= put["ts"] and put["ts"] + put["dur"]
+                   <= c["ts"] + c["dur"] + 2e-4 for c in converts), put
     for a, b in zip(leaves, leaves[1:]):
         assert b["ts"] >= a["ts"] + a["dur"] - 2e-4, (a, b)
     assert leaves[0]["ts"] >= step["ts"] - 2e-4

@@ -600,7 +600,7 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                         lp, p, h, parts["state"][i], counts)
                 else:
                     with jax.named_scope("attn"), jax.named_scope("latent"):
-                        q, rows = model.latent_rows(lp, p, h)
+                        q, rows = model.latent_rows(lp, p, h, positions)
                         with jax.named_scope("cache_update"):
                             held = parts["latent"][i] = written(
                                 parts["latent"][i], rows)
@@ -659,8 +659,12 @@ def _latent_cache_attention(model: Transformer, params, prefix: str,
     + qk_shared] at ``positions`` [B, T]; rows [B, M, latent_row] with
     the block already written; ``mask`` the causal mask
     [B or 1, 1, 1, T, M].  Two forms of one attention.  A long block
-    against a long cache EXPANDS every position's K and V from its row
-    (``expand``) and runs blockwise, as a full layer's extension does.
+    against a long cache runs blockwise, as a full layer's extension
+    does, and EXPANDS K and V from the rows a key block at a time inside
+    that loop (``expand``): the whole row expanded is [B, M, H, head_dim +
+    qk_shared] twice, 0.8 GB each at 128 heads and 16,384 positions, most
+    of it past the context's end, where a block is 25 MB and a block the
+    mask hides is never made.
     Anything else ABSORBS the expansion: a head's own query part goes
     through its key matrix into the latent's space (``absorb``), the
     scores and the weighted sum are taken against the rows as they lie
@@ -678,8 +682,10 @@ def _latent_cache_attention(model: Transformer, params, prefix: str,
     if t >= _BLOCKWISE_QUERIES and held >= model.BLOCKWISE_FROM:
         from ..ops.blockwise_attention import blockwise_attention
 
-        k, v = model.latent_expand(params, prefix, rows)
-        return blockwise_attention(q, k, v, positions[:, 0])[..., :c.head_dim]
+        return blockwise_attention(
+            q, rows, None, positions[:, 0],
+            expand=lambda block: model.latent_expand(params, prefix, block,
+                                                     wide_values=False))
     up_k, up_v = model.latent_up(params, prefix)
     with jax.named_scope("absorb"):
         inner = jnp.einsum("bthd,lhd->bthl", q[..., :c.head_dim], up_k,

@@ -191,20 +191,34 @@ class MoELayer:
 
 def select_experts(router_logits: Array, top_k: int, score: str = "softmax",
                    bias: Array | None = None, scale: float = 1.0,
+                   groups: int = 1, groups_kept: int = 1,
                    ) -> tuple[Array, Array]:
     """(gates [N, k] float32, chosen experts [N, k]) from router logits
     [N, E], in float32.  ``softmax``: the top_k logits, gated by the
     softmax over them.  ``sigmoid``: every expert scores sigmoid(logit);
     the top_k of score + ``bias`` ([E], a stored correction that enters
     the SELECTION only) are chosen and gated by their own scores over
-    their sum (+ 1e-6), times ``scale``."""
+    their sum (+ 1e-6), times ``scale``.  ``groups`` > 1 LIMITS that
+    selection (under ``group_limit``): the experts lie in ``groups`` groups
+    of E / groups neighbours, a group scores the sum of its two best score
+    + bias, and outside the ``groups_kept`` best groups an expert's score +
+    bias counts as 0 (the deepseek routers' node-limited selection: a
+    token's experts lie on the devices of ``groups_kept`` groups)."""
     logits = router_logits.astype(jnp.float32)
     if score == "softmax":
         top_logits, top_idx = jax.lax.top_k(logits, top_k)
         return jax.nn.softmax(top_logits, axis=-1), top_idx
     scores = jax.nn.sigmoid(logits)
-    _, top_idx = jax.lax.top_k(
-        scores if bias is None else scores + bias.astype(jnp.float32), top_k)
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    if groups > 1:
+        with jax.named_scope("group_limit"):
+            by_group = choice.reshape(choice.shape[0], groups, -1)
+            best_two, _ = jax.lax.top_k(by_group, 2)
+            _, kept = jax.lax.top_k(jnp.sum(best_two, axis=-1), groups_kept)
+            open_ = jnp.any(kept[:, :, None] == jnp.arange(groups), axis=1)
+            choice = jnp.where(open_[:, :, None], by_group,
+                               0.0).reshape(choice.shape)
+    _, top_idx = jax.lax.top_k(choice, top_k)
     chosen = jnp.take_along_axis(scores, top_idx, axis=-1)
     return (chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-6)
             * scale, top_idx)
@@ -243,6 +257,7 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
                      score: str = "softmax", bias: Array | None = None,
                      scale: float = 1.0, chosen: list | None = None,
                      held: tuple[int, int] | None = None,
+                     groups: int = 1, groups_kept: int = 1,
                      ) -> tuple[Array, Array]:
     """Dropless top-k experts over a flat batch of tokens.
 
@@ -268,19 +283,24 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
     most a token's choices can send here, so nothing is ever dropped; the
     rows past the held assignments are zeros and belong to no group.
     ``loads`` is then [count + 1]: the held experts' assignments, and last
-    the assignments routed to experts held elsewhere.
+    the assignments routed to experts held elsewhere.  Under a group limit
+    (``groups`` > 1, :func:`select_experts`) one more entry follows,
+    [count + 2]: the RANK PLACES, over the tokens the sum of how many of
+    the stage's E / count ranks (rank r holds the experts r * count ..) a
+    token's choices lie on, which is what the limit bounds and what an
+    exchange between the ranks would send.
     """
     n, d = x.shape
     experts = w1.shape[0]
     with jax.named_scope("router"):
         gates, top_idx = select_experts(router_logits, top_k, score, bias,
-                                        scale)                 # [N, k]
+                                        scale, groups, groups_kept)  # [N, k]
         if chosen is not None:
             chosen.append(top_idx)
         flat = top_idx.reshape(n * top_k)
         if held is None:
             order, place = _sorted_by_group(flat, experts)     # [A]
-            loads = groups = jnp.zeros((experts,), jnp.int32).at[flat].add(1)
+            loads = sizes = jnp.zeros((experts,), jnp.int32).at[flat].add(1)
             rows = x[order // top_k]                           # [A, D]
         else:
             first, count = held
@@ -293,12 +313,18 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
             local = jnp.where((local >= 0) & (local < count), local, count)
             order, place = _sorted_by_group(local, count + 1)  # [A]
             loads = jnp.zeros((count + 1,), jnp.int32).at[local].add(1)
-            groups = loads[:count]
+            sizes = loads[:count]
             bound = n * min(top_k, count)
-            mine = (jnp.arange(bound) < jnp.sum(groups))[:, None]
+            mine = (jnp.arange(bound) < jnp.sum(sizes))[:, None]
             rows = jnp.where(mine, x[order[:bound] // top_k], 0)
+            if groups > 1:
+                ranks = router_logits.shape[-1] // count
+                on = jnp.any((top_idx // count)[:, :, None]
+                             == jnp.arange(ranks), axis=1)         # [N, R]
+                loads = jnp.concatenate(
+                    [loads, jnp.sum(on, dtype=jnp.int32)[None]])
     with jax.named_scope("experts"):
-        dot = partial(jax.lax.ragged_dot, group_sizes=groups,
+        dot = partial(jax.lax.ragged_dot, group_sizes=sizes,
                       preferred_element_type=jnp.float32)
         hidden = dot(rows, w1).astype(x.dtype)
         if act == "gelu":

@@ -183,12 +183,18 @@ def _builds_few(model: Transformer) -> bool:
     with delta-rule layers (kda, gdn), whose every program is all its layers
     unrolled around a chunked delta rule (10 to 20 s of the compiler's time
     each on a cold start; four resident contexts and their turns were 70
-    programs and 515 s, PERF.md section 6, PR 47).  One extension program a
-    prefix bucket
+    programs and 515 s, PERF.md section 6, PR 47), and a model with LATENT
+    layers, whose block of fewer than ``generation._BLOCKWISE_QUERIES``
+    tokens takes the absorbed form in plain XLA: [heads, block, lane] float32
+    scores against the part as it lies, whatever the context's length (0.5
+    GB a layer for 64 tokens at 128 heads and 16,384 positions), where a
+    block of 256 runs blockwise over the context's own key blocks.  One
+    extension program a prefix bucket
     (:func:`_suffix_floor`), built ahead (``DecodeServer._build_ahead``),
     and one prefill program for every prompt of a chunk or more
     (:func:`_prefills_whole`)."""
-    return any(spec.mixer in DELTA_MIXERS for spec in model.config.specs)
+    return any(spec.mixer in DELTA_MIXERS + ("latent",)
+               for spec in model.config.specs)
 
 
 def _suffix_floor(model: Transformer) -> int:
@@ -199,7 +205,9 @@ def _suffix_floor(model: Transformer) -> int:
     bound by what it READS whatever the block: its weights (a matrix in
     bfloat16 is read no faster than 240 rows multiply it on a v5e, 197
     TFLOP/s over 819 GB/s), the row and the snapshot it restores; its delta
-    rule takes a block in chunks of ``DELTA_CHUNK`` either way.  So the
+    rule takes a block in chunks of ``DELTA_CHUNK`` either way, and its
+    latent layers attend a block of 256 by key block where a shorter one
+    reads the whole lane.  So the
     turns of a conversation (16 to 256 tokens) share ONE program a prefix
     bucket, where powers of two from 16 would build five."""
     return 256 if _builds_few(model) else 16
@@ -743,7 +751,8 @@ class DecodeServer:
                                       "layer_rounds",
                                       "experts_touched", "expert_places",
                                       "load_max_over_mean",
-                                      "admit_experts_touched")}
+                                      "admit_experts_touched",
+                                      "rank_places", "tokens_routed")}
         for kind, held in self._cache_bytes_by_kind().items():
             obs_stats.gauge(f"serve.cache.{kind}_bytes").set(held)
         # what a round's sparse, linear (kda and gdn too), latent and full
@@ -1753,10 +1762,20 @@ class DecodeServer:
         expert's load over the mean, summed.  Where the model holds a
         share of the experts (``moe_held``) a layer's last entry is what
         went to experts held elsewhere: it counts as routed, and every
-        other count is over the HELD experts and the rows computed."""
+        other count is over the HELD experts and the rows computed.  Where
+        its selection is under a group limit as well (``moe_groups``), the
+        rank places follow (``moe.dropless_experts``): they and the tokens
+        routed (a layer's assignments over ``moe_top_k``) go to
+        ``serve.moe.rank_places`` and ``serve.moe.tokens_routed``."""
+        config = self.model.config
         loads = loads.reshape(self._moe_layers, -1)
+        if config.moe_held and config.moe_groups > 1:
+            self._obs_moe["rank_places"].add(int(loads[:, -1].sum()))
+            loads = loads[:, :-1]
+            self._obs_moe["tokens_routed"].add(
+                int(loads.sum()) // config.moe_top_k)
         self._obs_moe["assignments_routed"].add(int(loads.sum()))
-        if self.model.config.moe_held:
+        if config.moe_held:
             loads = loads[:, :-1]
         computed, touched = int(loads.sum()), int((loads > 0).sum())
         self._moe_assignments += computed

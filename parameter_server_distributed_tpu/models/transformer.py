@@ -53,6 +53,64 @@ MIXER_OUT = "mixer_out"
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN (Peng et al., arXiv:2309.00071) as a model's ``rope_scaling``
+    states it: rotary pairs that turn more than ``beta_fast`` times over
+    the ``original_max`` positions the model was first trained on keep
+    their frequency, those that turn fewer than ``beta_slow`` times are
+    slowed ``factor`` times, and the pairs between are blended along a
+    linear ramp.  ``mscale`` / ``mscale_all_dim`` give the two gains of
+    the method: one on the rotated parts (:attr:`rotary_gain`), one on the
+    scores (:attr:`softmax_gain`)."""
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def _mscale(self, weight: float) -> float:
+        if self.factor <= 1:
+            return 1.0
+        return 0.1 * weight * math.log(self.factor) + 1.0
+
+    @property
+    def rotary_gain(self) -> float:
+        """What cos and sin are multiplied by."""
+        if self.mscale and self.mscale_all_dim:
+            return self._mscale(self.mscale) / self._mscale(
+                self.mscale_all_dim)
+        return self._mscale(1.0)
+
+    @property
+    def softmax_gain(self) -> float:
+        """What the scores' scale is multiplied by: the square of the
+        all-dimensions gain (1 without ``mscale_all_dim``)."""
+        if not self.mscale_all_dim:
+            return 1.0
+        return self._mscale(self.mscale_all_dim) ** 2
+
+    def ramp_ends(self, dim: int, theta: float) -> tuple[int, int]:
+        """(low, high): the pair the blend starts at and the one it ends
+        at, of ``dim // 2`` pairs."""
+        def turns_at(turns: float) -> float:
+            return (dim * math.log(self.original_max / (turns * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        return (max(math.floor(turns_at(self.beta_fast)), 0),
+                min(math.ceil(turns_at(self.beta_slow)), dim - 1))
+
+    def frequencies(self, dim: int, theta: float) -> np.ndarray:
+        """The ``dim // 2`` pairs' angles a position, float32."""
+        pair = np.arange(dim // 2, dtype=np.float64)
+        plain = theta ** (-pair / (dim // 2))
+        low, high = self.ramp_ends(dim, theta)
+        slowed = np.clip((pair - low) / ((high - low) or 0.001), 0.0, 1.0)
+        return (plain * (1.0 - slowed) + plain / self.factor * slowed
+                ).astype(np.float32)
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """One layer of a model's pattern: what its attention sees and what
     its feed-forward branch is.  ``TransformerConfig.pattern`` holds one
@@ -91,11 +149,11 @@ class LayerSpec:
     # beside the softmax layers' ``head_dim``, a decay projection a head, a
     # full-rank silu output gate and, under ``config.delta_neg_eigval``, a
     # write strength of up to 2; the same two states, the matrix
-    # [H, Dk, Dv].  latent: causal attention without
-    # rotary whose K and V are expanded from ONE normed latent of
-    # ``config.kv_latent`` a position, with a key part of
-    # ``config.qk_shared`` all heads share beside it; its cache keeps that
-    # row, not K/V
+    # [H, Dk, Dv].  latent: causal attention whose K and V are expanded
+    # from ONE normed latent of ``config.kv_latent`` a position, with a
+    # key part of ``config.qk_shared`` all heads share beside it (rotated
+    # under ``config.latent_rope``, else without rotary anywhere); its
+    # cache keeps that row, not K/V
     mixer: str = "softmax"
     # K/V heads of this layer; 0 = the config's
     kv_heads: int = 0
@@ -264,6 +322,25 @@ class TransformerConfig:
     # position keeps, and of the key part every head shares beside it
     kv_latent: int = 0
     qk_shared: int = 0
+    # the rank of a latent layer's QUERY.  0: one matrix ``attn/wq``.
+    # r > 0: the pair ``attn/wq_a`` [d_model, r] and ``attn/wq_b`` [r,
+    # heads x (head_dim + qk_shared)] with an RMS norm of r between them
+    # (``attn/q_norm/scale``)
+    q_latent: int = 0
+    # rotary positions on the SHARED parts of a latent layer: on every
+    # head's last ``qk_shared`` query channels and on the row's shared key
+    # part BEFORE the row is written, so a cache and a prefix store keep
+    # rows rotated at their absolute positions.  False: no rotary anywhere
+    latent_rope: bool = False
+    # YaRN on the model's rotary frequencies (a :class:`RopeScaling`), and
+    # its ``softmax_gain`` on a latent layer's scores; None: plain rotary
+    rope_scaling: object = None
+    # An ``experts`` layer's selection under a GROUP LIMIT (sigmoid scores):
+    # the router's experts lie in ``moe_groups`` groups of neighbours, a
+    # group scores the sum of its two best score + bias, and only experts
+    # of the ``moe_groups_kept`` best groups can be chosen.  1 and 1: none
+    moe_groups: int = 1
+    moe_groups_kept: int = 1
     # Scan over layers: store block weights stacked with a leading [L]
     # axis (``blocks/<suffix>``) and run the layer loop as one
     # ``lax.scan`` body traced ONCE, instead of n_layers Python-unrolled
@@ -376,6 +453,32 @@ class TransformerConfig:
                                    or self.pos_emb == "learned"):
             raise ValueError("a latent layer needs config.kv_latent, and "
                              "has no bias and no learned positions")
+        if (self.q_latent or self.latent_rope) and "latent" not in mixers:
+            raise ValueError("q_latent and latent_rope are a latent "
+                             "layer's")
+        if self.q_latent < 0 or (self.latent_rope and (
+                self.qk_shared < 2 or self.qk_shared % 2)):
+            raise ValueError("q_latent is a rank of 0 or more, and "
+                             "latent_rope turns qk_shared's pairs")
+        if self.rope_scaling is not None and (
+                not isinstance(self.rope_scaling, RopeScaling)
+                or self.pos_emb == "learned"
+                or (self.rope_scaling.softmax_gain != 1.0
+                    and mixers & {"softmax", "sparse", "linear"})):
+            raise ValueError("rope_scaling is a RopeScaling of a rotary "
+                             "model, and its gain on the scores is a "
+                             "latent layer's")
+        if not (1 <= self.moe_groups_kept <= self.moe_groups) or (
+                self.moe_groups > 1 and (
+                    self.moe_score != "sigmoid"
+                    or self.moe_experts % self.moe_groups
+                    or self.moe_experts // self.moe_groups < 2
+                    or any(spec.ffn == "moe" for spec in self.specs))):
+            raise ValueError(
+                f"moe_groups={self.moe_groups} groups of two or more of "
+                f"the router's {self.moe_experts} experts, "
+                f"moe_groups_kept={self.moe_groups_kept} of them kept, "
+                f"limit a sigmoid selection of an ``experts`` layer")
         if (mixers - {"softmax"} or self.prologue) and self.scan_layers:
             raise ValueError("scan_layers stacks one kind of cache part a "
                              "layer and scans whole periods: sparse, linear, "
@@ -505,14 +608,23 @@ def layer_norm(x: Array, scale: Array, bias: Array,
             + bias.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x: Array, positions: Array, theta: float = 10000.0) -> Array:
-    """Rotary position embedding.  x: [..., seq, heads, head_dim]."""
+def rope(x: Array, positions: Array, theta: float = 10000.0,
+         scaling: RopeScaling | None = None) -> Array:
+    """Rotary position embedding.  x: [..., seq, heads, head_dim]; a pair
+    is a channel of the first half and its partner in the second.
+    ``scaling``: the frequencies and the gain on cos and sin are YaRN's
+    (:class:`RopeScaling`)."""
     head_dim = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, head_dim // 2, dtype=jnp.float32)
-                      / (head_dim // 2))
+    if scaling is None:
+        freqs = theta ** (-jnp.arange(0, head_dim // 2, dtype=jnp.float32)
+                          / (head_dim // 2))
+    else:
+        freqs = jnp.asarray(scaling.frequencies(head_dim, theta))
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # [..., S, D/2]
     cos = jnp.cos(angles)[..., :, None, :]  # [..., S, 1, D/2]
     sin = jnp.sin(angles)[..., :, None, :]
+    if scaling is not None and scaling.rotary_gain != 1.0:
+        cos, sin = cos * scaling.rotary_gain, sin * scaling.rotary_gain
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -845,12 +957,16 @@ class Transformer:
                      "attn/wo": (values, c.d_model),
                      "ln2/scale": (c.d_model,)}
         elif spec.mixer == "latent":
-            # wq: every head's query, its own part then the shared one;
-            # wkv_a: the latent and the shared key part; wkv_b: every
-            # head's key part and value from the normed latent
+            # wq: every head's query, its own part then the shared one (a
+            # pair with a norm between under ``q_latent``); wkv_a: the
+            # latent and the shared key part; wkv_b: every head's key part
+            # and value from the normed latent
+            q_dim = c.n_heads * (c.head_dim + c.qk_shared)
             block = {"ln1/scale": (c.d_model,),
-                     "attn/wq": (c.d_model,
-                                 c.n_heads * (c.head_dim + c.qk_shared)),
+                     **({"attn/wq_a": (c.d_model, c.q_latent),
+                         "attn/q_norm/scale": (c.q_latent,),
+                         "attn/wq_b": (c.q_latent, q_dim)} if c.q_latent
+                        else {"attn/wq": (c.d_model, q_dim)}),
                      "attn/wkv_a": (c.d_model, c.kv_latent + c.qk_shared),
                      "attn/kv_norm/scale": (c.kv_latent,),
                      "attn/wkv_b": (c.kv_latent, 2 * c.attn_dim),
@@ -1184,8 +1300,8 @@ class Transformer:
             # added at embedding time) — K/V need no positional transform;
             # a layer without rotary has no position signal at all
             return q, k, v
-        return (rope(q, positions, c.rope_theta),
-                rope(k, positions, c.rope_theta), v)
+        return (rope(q, positions, c.rope_theta, c.rope_scaling),
+                rope(k, positions, c.rope_theta, c.rope_scaling), v)
 
     @scoped("attn_out")
     def attn_residual(self, params: Mapping[str, Array], prefix: str,
@@ -1382,24 +1498,54 @@ class Transformer:
         return self.kda_residual if spec.mixer == "kda" else self.gdn_residual
 
     def latent_rows(self, params: Mapping[str, Array], prefix: str,
-                    h: Array) -> tuple[Array, Array]:
+                    h: Array, positions: Array) -> tuple[Array, Array]:
         """A ``latent`` layer's queries and what its cache keeps of the
         same positions: (q [B, T, H, head_dim + qk_shared], rows [B, T,
         latent_row]: the RMS-normed latent and the key part all heads
-        share, side by side, then zeros to whole registers).  No rotary
-        anywhere."""
+        share, side by side, then zeros to whole registers).  The query
+        is one product, or under ``q_latent`` a pair with a norm between
+        (scope ``q``).  Under ``latent_rope`` the shared parts of the
+        query and of the row are rotated to ``positions`` [B, T], the
+        row's BEFORE anything keeps it; else no rotary anywhere.  YaRN's
+        gain on the scores (``rope_scaling.softmax_gain``) is taken here,
+        on the query's float32 product before its cast, so every form of
+        the attention behind keeps its one scale, 1 / sqrt(the keys'
+        width)."""
         c = self.config
         batch, seq = h.shape[:2]
         dot = partial(wdot, preferred_element_type=jnp.float32)
         x = self._branch_input(params, f"{prefix}/ln1", h)
-        q = dot(x, params[f"{prefix}/attn/wq"]).astype(c.dtype).reshape(
-            batch, seq, c.n_heads, c.head_dim + c.qk_shared)
-        kv = dot(x, params[f"{prefix}/attn/wkv_a"]).astype(c.dtype)
-        latent = rms_norm(kv[..., :c.kv_latent],
-                          params[f"{prefix}/attn/kv_norm/scale"], c.norm_eps)
-        rows = jnp.concatenate([latent, kv[..., c.kv_latent:]], axis=-1)
-        return q, jnp.pad(rows, ((0, 0), (0, 0),
-                                 (0, c.latent_row - rows.shape[-1])))
+        scaling = c.rope_scaling
+
+        def turned(part: Array) -> Array:
+            return rope(part, positions, c.rope_theta, scaling)
+
+        with jax.named_scope("q"):
+            if c.q_latent:
+                low = rms_norm(
+                    dot(x, params[f"{prefix}/attn/wq_a"]).astype(c.dtype),
+                    params[f"{prefix}/attn/q_norm/scale"], c.norm_eps)
+                q = dot(low, params[f"{prefix}/attn/wq_b"])
+            else:
+                q = dot(x, params[f"{prefix}/attn/wq"])
+            if scaling is not None and scaling.softmax_gain != 1.0:
+                q = q * scaling.softmax_gain
+            q = q.astype(c.dtype).reshape(
+                batch, seq, c.n_heads, c.head_dim + c.qk_shared)
+            if c.latent_rope:
+                q = jnp.concatenate([q[..., :c.head_dim],
+                                     turned(q[..., c.head_dim:])], axis=-1)
+        with jax.named_scope("rows"):
+            kv = dot(x, params[f"{prefix}/attn/wkv_a"]).astype(c.dtype)
+            latent = rms_norm(kv[..., :c.kv_latent],
+                              params[f"{prefix}/attn/kv_norm/scale"],
+                              c.norm_eps)
+            shared = kv[..., c.kv_latent:]
+            if c.latent_rope:
+                shared = turned(shared[:, :, None, :])[:, :, 0]
+            rows = jnp.concatenate([latent, shared], axis=-1)
+            return q, jnp.pad(rows, ((0, 0), (0, 0),
+                                     (0, c.latent_row - rows.shape[-1])))
 
     def latent_up(self, params: Mapping[str, Array],
                   prefix: str) -> tuple[Array, Array]:
@@ -1411,13 +1557,15 @@ class Transformer:
         return up[..., :c.head_dim], up[..., c.head_dim:]
 
     def latent_expand(self, params: Mapping[str, Array], prefix: str,
-                      rows: Array) -> tuple[Array, Array]:
+                      rows: Array, wide_values: bool = True
+                      ) -> tuple[Array, Array]:
         """K and V of ``rows`` [B, M, latent_row] for an
         attention that knows nothing of latents, under ``expand``: K [B, M,
         H, head_dim + qk_shared] (a head's own part, then the shared one)
         and V the same width, zeros past ``head_dim`` (the attentions here
         take one width for keys and values, and 1 / sqrt of it for the
-        scale: the keys')."""
+        scale: the keys'; ``wide_values=False``: V [B, M, H, head_dim], for
+        one that takes V's width as it is)."""
         c = self.config
         with jax.named_scope("expand"):
             up_k, up_v = self.latent_up(params, prefix)
@@ -1429,6 +1577,8 @@ class Transformer:
                            preferred_element_type=jnp.float32).astype(c.dtype)
             k = jnp.concatenate([k, jnp.broadcast_to(
                 shared[:, :, None, :], k.shape[:3] + (c.qk_shared,))], axis=-1)
+            if not wide_values:
+                return k, v
             return k, jnp.pad(v, ((0, 0),) * 3 + ((0, c.qk_shared),))
 
     def latent_out(self, params: Mapping[str, Array], prefix: str,
@@ -1444,11 +1594,12 @@ class Transformer:
         """A ``latent`` layer's whole mixer branch over a whole sequence,
         under ``attn/latent``: K and V expanded from the rows, causal
         softmax of q k^T / sqrt(head_dim + qk_shared) by the device's arm
-        (:func:`device_arm`).  Returns (new h, the rows [B, S, latent_row]
+        (:func:`device_arm`); positions count from 0.  Returns (new h, the rows [B, S, latent_row]
         a cache keeps)."""
         c = self.config
         with jax.named_scope("attn"), jax.named_scope("latent"):
-            q, rows = self.latent_rows(params, prefix, h)
+            positions = jnp.arange(h.shape[1], dtype=jnp.int32)[None, :]
+            q, rows = self.latent_rows(params, prefix, h, positions)
             k, v = self.latent_expand(params, prefix, rows)
             attn = attend_by(device_arm(q.shape, k.shape), q, k, v)
             return self.latent_out(params, prefix, h,
@@ -1544,7 +1695,9 @@ class Transformer:
         ``route_stats``, where given, gains this layer's tokens per
         expert ([E] int32; where the weights hold a share of the experts,
         ``moe_held``, the held experts' and then the assignments routed
-        elsewhere, [count + 1]) for the caller's counters, and ``chosen``
+        elsewhere, [count + 1], and under a group limit the rank places
+        after them, ``moe.dropless_experts``) for the caller's counters,
+        and ``chosen``
         the experts every token of an ``experts`` layer took ([B, S, k]).
         A shared expert (``moe_shared_experts``) runs on the same input
         under ``moe/shared`` and is added ungated."""
@@ -1574,7 +1727,8 @@ class Transformer:
                 act=c.mlp_act, score=c.moe_score,
                 bias=params.get(f"{prefix}/moe/router/bias"),
                 scale=c.moe_route_scale, chosen=chosen,
-                held=c.moe_held or None)
+                held=c.moe_held or None, groups=c.moe_groups,
+                groups_kept=c.moe_groups_kept)
             out = out.reshape(batch, seq, c.d_model)
             if c.moe_shared_experts:
                 with jax.named_scope("shared"):
@@ -1982,12 +2136,12 @@ def transformer_rule(mesh: Mesh):
     """Sharding rule for transformer stores: Megatron TP + fsdp (+ EP).
 
     column-parallel (tensor on output dim): wq wk wv w1 lm_head, a gdn
-        layer's output gate wz, and by head a latent layer's wkv_b and a
-        kda layer's gates' second halves
+        layer's output gate wz, and by head a latent layer's wkv_b (and
+        its low-rank query's wq_b) and a kda layer's gates' second halves
     row-parallel  (tensor on input dim):    wo w2
     (a kda or gdn layer's conv kernels, a_log, dt_bias, beta, a kda
     layer's gates' first halves and a gdn layer's decay projection, a
-    latent layer's wkv_a: small, replicated)
+    latent layer's wkv_a and wq_a: small, replicated)
     (a shared expert's ``moe/shared/w*`` as the dense MLP's)
     vocab-sharded embedding; norm scales replicated (fsdp if divisible);
     MoE expert weights sharded over the ``expert`` axis (router replicated).
@@ -2015,8 +2169,8 @@ def transformer_rule(mesh: Mesh):
         # one shard's slice every scan step
         if name.endswith(("attn/wq", "attn/wk", "attn/wv", "mlp/w1",
                           "mlp/w3", "moe/shared/w1", "moe/shared/w3",
-                          "lm_head/w", "attn/wkv_b", "attn/decay/wb",
-                          "attn/gate/wb", "attn/wz")):
+                          "lm_head/w", "attn/wkv_b", "attn/wq_b",
+                          "attn/decay/wb", "attn/gate/wb", "attn/wz")):
             taken = len(shape) - 1 if n_tp > 1 and shape[-1] % n_tp == 0 else None
             return PartitionSpec(*fsdp_on(len(shape) - 2, taken))
         if name.endswith(("attn/wo", "mlp/w2", "moe/shared/w2")):
@@ -2037,7 +2191,7 @@ def transformer_rule(mesh: Mesh):
         if name.endswith(("/scale", "/bias", "/bq", "/bk", "/bv", "/bo",
                           "/b1", "/b2", "/a_log", "/dt_bias", "attn/conv_q",
                           "attn/conv_k", "attn/conv_v", "attn/wkv_a",
-                          "attn/decay/wa", "attn/gate/wa", "attn/beta/w",
+                          "attn/wq_a", "attn/decay/wa", "attn/gate/wa", "attn/beta/w",
                           "attn/decay/w")):
             # norm scales and all biases: tiny 1-D vectors, replicated like
             # their paired scales (an fsdp-sharded bias would force a

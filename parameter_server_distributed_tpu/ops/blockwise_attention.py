@@ -16,6 +16,7 @@ No reference analogue (the reference has no model layer — SURVEY.md §1).
 from __future__ import annotations
 
 import math
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -23,9 +24,10 @@ import jax.numpy as jnp
 Array = jax.Array
 
 
-def blockwise_attention(q: Array, k: Array, v: Array, starts: Array, *,
-                        window: int = 0, block_q: int = 512,
-                        block_k: int = 512) -> Array:
+def blockwise_attention(q: Array, k: Array, v: Array | None, starts: Array,
+                        *, window: int = 0, block_q: int = 512,
+                        block_k: int = 512,
+                        expand: Callable | None = None) -> Array:
     """Causal (and, with ``window`` W > 0, windowed) attention of a block
     of queries against keys stored BY POSITION, a block of scores at a
     time, with the key blocks that the mask hides entirely skipped.
@@ -37,6 +39,15 @@ def blockwise_attention(q: Array, k: Array, v: Array, starts: Array, *,
     j <= i and, under a window, i - j < W.  Returns [B, T, H, D] in q's
     dtype.
 
+    ``expand``: the keys and values are not stored, they are MADE a key
+    block at a time.  ``k`` is then what is stored, [B, M, ...] by
+    position (``v`` None), and ``expand(k[:, block])`` gives the block's
+    (K [B, block_k, H, D], V [B, block_k, H, Dv]), a head each for every
+    query head: what a latent layer's rows are to its K and V.  Nothing M
+    positions long is ever held expanded, and a block the mask hides is
+    not expanded at all; a block is expanded once for every query block
+    that meets it.  Returns [B, T, H, Dv].
+
     Query blocks run one after another (``lax.map``), and for each the key
     blocks from the last one it can see downwards: a ``lax.scan`` of the
     static count a query block can ever meet (all of them without a
@@ -46,10 +57,13 @@ def blockwise_attention(q: Array, k: Array, v: Array, starts: Array, *,
     whole is differentiable.
     """
     b, t, h, d = q.shape
-    m, kv = k.shape[1], k.shape[2]
+    m, kv = k.shape[1], (k.shape[2] if expand is None else h)
     g = h // kv
     block_q = min(block_q, t)
     block_k = min(block_k, m)
+    d_v = v.shape[-1] if expand is None else jax.eval_shape(
+        expand, jax.ShapeDtypeStruct((b, block_k) + k.shape[2:], k.dtype)
+    )[1].shape[-1]
     pad = -t % block_q
     if pad:
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -85,7 +99,10 @@ def blockwise_attention(q: Array, k: Array, v: Array, starts: Array, *,
             def update(carry):
                 acc, top_score, denom = carry
                 k_j = jax.lax.dynamic_slice_in_dim(k, begin, block_k, 1)
-                v_j = jax.lax.dynamic_slice_in_dim(v, begin, block_k, 1)
+                if expand is None:
+                    v_j = jax.lax.dynamic_slice_in_dim(v, begin, block_k, 1)
+                else:
+                    k_j, v_j = expand(k_j)
                 scores = jnp.einsum(
                     "bqegd,bjed->begqj", q_blk, k_j,
                     preferred_element_type=jnp.float32) * scale
@@ -99,14 +116,14 @@ def blockwise_attention(q: Array, k: Array, v: Array, starts: Array, *,
                 shift = jnp.where(jnp.isneginf(new_top), 0.0, new_top)
                 alpha = jnp.exp(top_score - shift)
                 p = jnp.exp(scores - shift[..., None])
-                pv = jnp.einsum("begqj,bjed->begqd", p.astype(v.dtype), v_j,
+                pv = jnp.einsum("begqj,bjed->begqd", p.astype(v_j.dtype), v_j,
                                 preferred_element_type=jnp.float32)
                 return (acc * alpha[..., None] + pv, new_top,
                         denom * alpha + jnp.sum(p, axis=-1))
 
             return jax.lax.cond(outside, lambda c: c, update, carry), None
 
-        init = (jnp.zeros((b, kv, g, block_q, d), jnp.float32),
+        init = (jnp.zeros((b, kv, g, block_q, d_v), jnp.float32),
                 jnp.full((b, kv, g, block_q), -jnp.inf, jnp.float32),
                 jnp.zeros((b, kv, g, block_q), jnp.float32))
         (acc, _, denom), _ = jax.lax.scan(
@@ -116,5 +133,5 @@ def blockwise_attention(q: Array, k: Array, v: Array, starts: Array, *,
 
     blocks = jax.lax.map(query_block, (jnp.arange(nq, dtype=jnp.int32),
                                        jnp.moveaxis(qg, 1, 0)))
-    out = jnp.moveaxis(blocks, 0, 1).reshape(b, nq * block_q, h, d)
+    out = jnp.moveaxis(blocks, 0, 1).reshape(b, nq * block_q, h, d_v)
     return out[:, :t]

@@ -285,6 +285,83 @@ def test_kimi_linears_round_copies_no_latent_and_no_matrix_part(
         assert kernels == 0
 
 
+def _deepseek(one_chip, layers):
+    """(family, model of ``layers`` layers, shapes of its weights placed on
+    the chip, the placing) of ``deepseek-v3-5l-ep16``."""
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "deepseek-v3-5l-ep16.json")) as handle:
+        config = json.load(handle)
+    family = families.of(config)
+    model = family.model(config, remat=False, n_layers=layers)
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(lambda: family.make_weights(model, 1)))
+    assert params["layer0/attn/wq_b"].shape == (1536, 128 * 192)
+    assert params["layer1/moe/w1"].shape == (16, 7168, 2048)
+    return family, model, params, placed
+
+
+def test_deepseek_v3s_round_copies_no_latent_part_at_128_heads(
+        one_chip, monkeypatch):
+    """``serve_docs_deepseek_v3_ep16``'s decode round at its real widths,
+    32 slots x 16,384 positions, the dense layer and an expert layer (16 of
+    256 experts, 4 of 8 groups): both layers' rows (640 lanes) are updated
+    where they lie, rotated, and nothing as large as one of them is copied
+    or sliced; Mosaic takes the kernel of ops/pallas/latent_decode.py at
+    128 heads (its [128, 1024] float32 scores and probabilities a block
+    fit its memory) and no [32, 128, 16384] scores are kept.  A copy of
+    ``wkv_b``'s size is its split by head, as Kimi Linear's."""
+    from parameter_server_distributed_tpu.models import transformer
+    from parameter_server_distributed_tpu.ops.pallas import latent_decode
+
+    monkeypatch.setattr(latent_decode, "interpret_mode", lambda *_: False)
+    monkeypatch.setattr(transformer, "_kernel_backend", lambda: True)
+    _, model, params, placed = _deepseek(one_chip, 2)
+    slots, max_len = 32, 16384
+    cache = placed(jax.eval_shape(
+        lambda: generation.init_cache(model, slots, max_len)))
+    assert cache.k == () and cache.state == () and [
+        x.shape for x in cache.latent] == [(32, 16384, 640)] * 2
+    compiled = _compiled_round(model, params, cache, slots, one_chip)
+    aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
+    assert parts == 2 and aliased >= parts
+    assert [op for op in moved
+            if op[0] != "copy" or op[2] != 512 * 128 * 256] == []
+    assert temporaries < cache_bytes / 4
+    assert temporaries < 32 * 128 * 16384 * 4
+    text = compiled.as_text()
+    assert text.count("latent/cache/attn_kernel") > 0
+    for scope in ("latent/q", "latent/rows", "latent/absorb",
+                  "moe/router/group_limit"):
+        assert scope in text, scope
+
+
+def test_deepseek_v3s_extension_expands_by_key_block(one_chip):
+    """The program that extends the 14,336-position document by a turn of
+    256 (``serving._extend_runner``: a one-slot cache of 14,592 rows), at
+    the real widths: K and V of 128 heads are made a key block at a time
+    inside the blockwise loop (``latent/expand`` inside a ``while``), never
+    [1, 14592, 128, 192] (717 MB each), and the program's temporaries stay
+    under 1.0 GB."""
+    _, model, params, placed = _deepseek(one_chip, 2)
+    pbucket, sbucket = 14336, 256
+    row = placed((serving._no_layers(pbucket), serving._no_layers(pbucket),
+                  jax.ShapeDtypeStruct((2, pbucket, 640), jnp.bfloat16)))
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = serving._extend_runner(model, pbucket, sbucket, "native").lower(
+        params, row, jax.ShapeDtypeStruct((1, sbucket), jnp.int32,
+                                          sharding=one_chip),
+        scalar, scalar).compile()
+    text = compiled.as_text()
+    assert re.search(r"attn/latent/while/[\w/]*/expand/", text)
+    whole = (pbucket + sbucket) * 128 * 192
+    assert [op for op in _entry_operations(text) if op[2] >= whole] == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
 def test_olmo_hybrids_round_updates_k_v_and_matrices_where_they_lie(one_chip):
     """``serve_reasoning_olmo_hybrid``'s decode round at its real widths, 12
     slots x 4,096 positions, one whole period (three gdn layers and a full

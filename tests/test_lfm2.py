@@ -222,8 +222,8 @@ def test_the_router_controls_fail_the_comparison(small, tokens, expected,
     if control == "softmax_gates":
         sound = moe.select_experts
 
-        def softmax_gates(logits, top_k, score, bias, scale):
-            _, chosen = sound(logits, top_k, score, bias, scale)
+        def softmax_gates(logits, top_k, score, bias, scale, *limit):
+            _, chosen = sound(logits, top_k, score, bias, scale, *limit)
             return jax.nn.softmax(jnp.take_along_axis(
                 logits.astype(jnp.float32), chosen, -1), -1), chosen
 

@@ -125,7 +125,9 @@ class KVCache:
     (then zeros to whole registers).  ``ring_layers``, ``sparse_layers``,
     ``state_layers`` and ``latent_layers`` name the layers of each kind
     (static).  ``length`` is the number of valid positions (a traced
-    scalar so decode never retraces)."""
+    scalar so decode never retraces).  ``devices`` says over how many
+    devices the parts are spread (``serving._shard_cache`` sets it):
+    GSPMD partitions a round over them, and it cannot cut a kernel."""
     k: tuple
     v: tuple
     length: Array
@@ -143,6 +145,7 @@ class KVCache:
     latent_layers: tuple = dataclasses.field(
         default=(), metadata=dict(static=True))
     max_len: int = dataclasses.field(default=0, metadata=dict(static=True))
+    devices: int = dataclasses.field(default=1, metadata=dict(static=True))
     # the fields that hold a part per layer
     PARTS: ClassVar[tuple] = ("k", "v", "wk", "wv", "ck", "state", "latent")
 
@@ -561,6 +564,10 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                 return blockwise_attention(
                     q, keys.reshape(by_head), values.reshape(by_head),
                     positions[:, 0], window=spec.window)
+            if (not 0 < spec.window < cache.max_len
+                    and _full_arm(cache, q.shape, keys) == "kernel"):
+                return _kernel_cache_attention(c, q, keys, values,
+                                               positions[:, 0] + 1)
             if spec.window not in masks:
                 masks[spec.window] = position_mask(spec.window)
             return _dense_cache_attention(
@@ -760,6 +767,32 @@ def _sparse_cache_attention(c, q: Array, keys: Array, values: Array,
     return attn, ck
 
 
+def _query_rows(c, q: Array, pack: int) -> Array:
+    """q [B, T, H, D] as the rows that meet a part's rows of ``pack``
+    heads: [B, T, KV / pack, pack * G, pack * D], each head's queries in
+    its own lanes of a row of zeros."""
+    b, t = q.shape[:2]
+    qg = q.reshape(b, t, c.kv_heads // pack, pack, c.kv_groups, c.head_dim)
+    if pack > 1:
+        mine = jnp.eye(pack, dtype=q.dtype)[:, None, :, None]
+        qg = qg[:, :, :, :, :, None, :] * mine    # [B, T, KV', j, G, j', D]
+    return qg.reshape(b, t, c.kv_heads // pack, pack * c.kv_groups,
+                      pack * c.head_dim)
+
+
+def _own_lanes(c, out: Array, pack: int) -> Array:
+    """:func:`_query_rows` undone on a result [B, T, KV / pack, pack * G,
+    pack * D]: a head's result is its own lanes of its row's, [B, T, H,
+    D]."""
+    b, t = out.shape[:2]
+    if pack > 1:
+        out = out.reshape(b, t, c.kv_heads // pack, pack, c.kv_groups, pack,
+                          c.head_dim)
+        mine = jnp.eye(pack, dtype=out.dtype)[:, None, :, None]
+        out = (out * mine).sum(axis=5)
+    return out.reshape(b, t, c.n_heads, c.head_dim)
+
+
 def _cache_scores(c, q: Array, keys: Array) -> Array:
     """q [B, T, H, D] against ``keys`` [B, K, KV / pack, pack * D] (a part
     of the cache, or a block packed like one): [B, KV, G, T, K] in f32,
@@ -767,19 +800,13 @@ def _cache_scores(c, q: Array, keys: Array) -> Array:
     UNexpanded keys — the cache bytes streamed per step stay
     kv_heads-sized (the point of the smaller cache), no materialized
     repeat.  Where ``pack`` heads share a row, each head's queries sit in
-    its own lanes of a row of zeros, so the product reads the part as it
-    is stored: ``pack`` times the multiplications, on a round that waits
-    for the cache's bytes, and sums that differ from the unpacked ones by
-    added zeros."""
+    its own lanes of a row of zeros (:func:`_query_rows`), so the product
+    reads the part as it is stored: ``pack`` times the multiplications, on
+    a round that waits for the cache's bytes, and sums that differ from
+    the unpacked ones by added zeros."""
     b, t = q.shape[:2]
-    pack = c.kv_heads // keys.shape[2]
-    qg = q.reshape(b, t, c.kv_heads // pack, pack, c.kv_groups, c.head_dim)
-    if pack > 1:
-        mine = jnp.eye(pack, dtype=q.dtype)[:, None, :, None]
-        qg = qg[:, :, :, :, :, None, :] * mine    # [B, T, KV', j, G, j', D]
-    qg = qg.reshape(b, t, c.kv_heads // pack, pack * c.kv_groups,
-                    pack * c.head_dim)
-    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, keys,
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk",
+                        _query_rows(c, q, c.kv_heads // keys.shape[2]), keys,
                         preferred_element_type=jnp.float32)
     return scores.reshape(b, c.kv_heads, c.kv_groups, t, keys.shape[1])
 
@@ -787,19 +814,70 @@ def _cache_scores(c, q: Array, keys: Array) -> Array:
 def _cache_weighted(c, probs: Array, values: Array) -> Array:
     """probs [B, KV, G, T, K] over ``values`` [B, K, KV / pack, pack * D]:
     [B, T, H, D] in f32.  The other half of :func:`_cache_scores`: a
-    head's result is its own lanes of its row's."""
+    head's result is its own lanes of its row's (:func:`_own_lanes`)."""
     b, _, _, t, held = probs.shape
     pack = c.kv_heads // values.shape[2]
     out = jnp.einsum(
         "bhgqk,bkhd->bqhgd",
         probs.reshape(b, c.kv_heads // pack, pack * c.kv_groups, t, held),
         values, preferred_element_type=jnp.float32)
-    if pack > 1:
-        out = out.reshape(b, t, c.kv_heads // pack, pack, c.kv_groups, pack,
-                          c.head_dim)
-        mine = jnp.eye(pack, dtype=out.dtype)[:, None, :, None]
-        out = (out * mine).sum(axis=5)
-    return out.reshape(b, t, c.n_heads, c.head_dim)
+    return _own_lanes(c, out, pack)
+
+
+def _full_arm(cache, q_shape: tuple[int, ...], part) -> str:
+    """``transformer.full_decode_arm`` for q [B, T, H, D] against ``part``
+    of ``cache``: a cache spread over several devices keeps the einsums,
+    which GSPMD partitions (it cannot cut a kernel)."""
+    if getattr(cache, "devices", 1) != 1:
+        return "dense"
+    return _transformer.full_decode_arm(q_shape, part.shape, part.dtype)
+
+
+def full_round_block(model: Transformer, cache, lanes: int) -> int:
+    """The positions a plain decode round of ``lanes`` lanes fetches AT A
+    TIME of each full layer's parts of ``cache``: the kernel's block where
+    :func:`decode_block` runs the layer through ops/pallas/full_decode.py,
+    0 where it reads the parts whole (what ``DecodeServer`` counts its
+    ``serve.full.positions_read`` by)."""
+    c = model.config
+    parts = [part for i, part in enumerate(cache.k)
+             if not (isinstance(cache, KVCache) and cache.by_head(i))]
+    if not parts or _full_arm(
+            cache, (lanes, 1, c.n_heads, c.head_dim), parts[0]) != "kernel":
+        return 0
+    from ..ops.pallas import full_decode
+
+    rows = parts[0].shape[2]
+    return full_decode.block_positions(
+        parts[0].shape, parts[0].dtype.itemsize, _lies_by_head(rows),
+        c.n_heads // rows)
+
+
+def _kernel_cache_attention(c, q: Array, keys: Array, values: Array,
+                            lengths: Array) -> Array:
+    """A decode round's single token a lane, q [B, 1, H, D], against a
+    full layer's parts [B, M, KV / pack, pack * D] through the kernel of
+    ops/pallas/full_decode.py (``transformer.full_decode_arm`` says when):
+    K and V are read a block of positions at a time, once, and no block
+    past a lane's ``lengths`` (its live positions, the new token included)
+    is fetched.  The queries meet a row of ``pack`` heads as
+    :func:`_cache_scores` lays them, and a head takes its own lanes of the
+    result as in :func:`_cache_weighted`.  The kernel takes a part where
+    it lies: one the device lays by head (:func:`_lies_by_head`) goes in
+    turned to [B, KV', M, D'], which is the same bytes in the same order
+    (tests/test_chip_compile.py holds that the compiled round copies
+    nothing)."""
+    from ..ops.pallas import full_decode
+
+    pack = c.kv_heads // keys.shape[2]
+    by_head = _lies_by_head(keys.shape[2])
+    if by_head:
+        keys, values = (x.transpose(0, 2, 1, 3) for x in (keys, values))
+    with jax.named_scope("attn_kernel"):
+        out = full_decode.full_decode_attention(
+            _query_rows(c, q, pack)[:, 0], keys, values, lengths,
+            c.head_dim ** -0.5, by_head)
+    return _own_lanes(c, out[:, None], pack).astype(c.dtype)
 
 
 def _dense_cache_attention(c, q: Array, keys: Array, values: Array,

@@ -51,8 +51,8 @@ from ..obs import trace as obs_trace
 from .generation import (KVCache, QuantKVCache, _cached_runner,
                          _kv_quantize, _model_key, _spec_round_runner,
                          check_position_budget, check_rolls_back,
-                         decode_block, heads_major, heads_per_row,
-                         positions_major,
+                         decode_block, full_round_block, heads_major,
+                         heads_per_row, positions_major,
                          init_cache, pack_heads, ring_layers_of, sample_token,
                          sample_token_rowwise, split_row, state_shape)
 from .prefix_tree import PrefixTree, RowRef
@@ -78,6 +78,7 @@ class _Flight:
     out: tuple                 # (tokens [B], counted): the round's outputs
     lanes: dict[int, _Slot]    # slot -> the request it decoded a token for
     positions: int             # positions its lanes held, new token included
+    fetched: int               # positions a full layer's arm fetched for them
 
 
 # rows longer than this are padded to its next multiple, not to the next
@@ -175,7 +176,10 @@ def _shard_cache(cache, mesh):
                                    + [None] * (ndim - 3)))
         return jax.device_put(leaf, NamedSharding(mesh, spec))
 
-    return jax.tree_util.tree_map(place, cache)
+    cache = jax.tree_util.tree_map(place, cache)
+    # (a round over several devices is GSPMD's to partition: no kernel)
+    return (dataclasses.replace(cache, devices=mesh.size)
+            if isinstance(cache, KVCache) else cache)
 
 
 def _builds_few(model: Transformer) -> bool:
@@ -687,6 +691,9 @@ class DecodeServer:
             getattr(self._cache, "sparse_layers", ()))
         if mesh is not None:
             self._cache = _shard_cache(self._cache, mesh)
+        # the positions a block where a round's full layers run the
+        # kernel of ops/pallas/full_decode.py, 0 where they read whole
+        self._full_block = full_round_block(model, self._cache, slots)
         self._moe_layers = sum(config.layer_spec(i).ffn == "experts"
                                for i in range(config.n_layers))
         if draft is not None and (ring_layers_of(model, max_len)
@@ -766,7 +773,8 @@ class DecodeServer:
                 "serve.latent.positions_read",
                 "serve.latent.positions_cached",
                 "serve.full.positions_live",
-                "serve.full.positions_cached")}
+                "serve.full.positions_cached",
+                "serve.full.positions_read")}
         # the mark (obs/legs.py: perf_counter first) at the last round's
         # return, while a slot is active
         self._round_returned: tuple | None = None
@@ -1511,8 +1519,11 @@ class DecodeServer:
         self._obs_rounds.add()
         if (fresh < 0).any():
             self._obs_chained.add()
-        return _Flight((self._last, counted), lanes,
-                       int(lengths.sum()) + self.slots)
+        held = np.minimum(lengths + 1, self.max_len)
+        block = self._full_block
+        return _Flight((self._last, counted), lanes, int(held.sum()),
+                       int((-(-held // block) * block).sum()) if block
+                       else self.slots * self.max_len)
 
     def _land(self, flight: _Flight, device) -> list[tuple[int, int]]:
         """Fetch a round's tokens (``device``: the leg in which the host
@@ -1523,7 +1534,7 @@ class DecodeServer:
             nxt, (loads, selected) = jax.device_get(flight.out)
         if loads is not None:
             self._count_routing(loads)
-        self._count_mixers(selected, flight.positions)
+        self._count_mixers(selected, flight.positions, flight.fetched)
         emitted: list[tuple[int, int]] = []
         for i, entry in flight.lanes.items():
             if self._slot[i] is not entry:
@@ -1716,8 +1727,8 @@ class DecodeServer:
                             jax.tree_util.tree_leaves(self._cache)),
                 "window": 0, "state": 0, "latent": 0}
 
-    def _count_mixers(self, selected: np.ndarray | None,
-                      positions: int) -> None:
+    def _count_mixers(self, selected: np.ndarray | None, positions: int,
+                      fetched: int) -> None:
         """One decode round into the counters the sparse, linear and
         latent layers' metrics divide.  ``selected`` is the round's own
         [positions attended, kernels scored] over its sparse layers and
@@ -1727,8 +1738,12 @@ class DecodeServer:
         the round advanced (a lane and linear, kda or gdn layer each).
         A latent layer needs its lanes' ``positions`` and reads its whole
         part: both are counted, a latent layer each; so are a full softmax
-        layer's, whose round reads its part whole whatever the lanes hold
-        (``serve.full.positions_live`` of ``serve.full.positions_cached``)."""
+        layer's (``serve.full.positions_live`` of
+        ``serve.full.positions_cached``), and beside them ``fetched``, the
+        positions its arm fetched for the round
+        (``serve.full.positions_read``): every lane's length rounded up to
+        whole blocks through the kernel, the whole part through the
+        einsums."""
         if selected is not None:
             self._obs_mixers["serve.sparse.positions_selected"].add(
                 float(selected[0]))
@@ -1749,6 +1764,8 @@ class DecodeServer:
                 float(self._full_layers * positions))
             self._obs_mixers["serve.full.positions_cached"].add(
                 float(self._full_layers * self.slots * self.max_len))
+            self._obs_mixers["serve.full.positions_read"].add(
+                float(self._full_layers * fetched))
 
     def _count_routing(self, loads: np.ndarray,
                        admission: bool = False) -> None:

@@ -821,6 +821,40 @@ def latent_decode_arm(q_shape: tuple[int, ...],
     return "kernel"
 
 
+def full_decode_arm(q_shape: tuple[int, ...], part_shape: tuple[int, ...],
+                    part_dtype) -> str:
+    """Which implementation attends a FULL softmax layer's queries (q [B,
+    T, H, D]) against its parts of a cache (K and V, each [B, M, KV /
+    pack, pack * D] of ``part_dtype``) on one device, beside
+    :func:`latent_decode_arm` and by its rule (shapes and the backend,
+    nothing else):
+
+    ``kernel`` — ops/pallas/full_decode.py, a decode round's single token
+                 a lane on a TPU against an unquantised part whose shape
+                 the kernel takes (rows of whole registers, ``M`` in whole
+                 blocks): K and V read once, a block of positions at a
+                 time, and no block past a lane's length;
+    ``dense``  — the two einsums against the parts as they lie (read whole,
+                 whatever the lanes hold): a block of several tokens (an
+                 extension, a speculative verify), an int8 part, any
+                 backend but a TPU, any other shape.
+
+    Unlike the latent arm it refuses nothing: the einsums are the accepted
+    path wherever the kernel does not run."""
+    if (q_shape[1] != 1 or not _kernel_backend()
+            or not jnp.issubdtype(part_dtype, jnp.floating)):
+        return "dense"
+    # (pallas is imported where a kernel can run, and only there)
+    from ..ops.pallas import full_decode
+
+    # the query rows a row of heads meets: H / KV' (pack * G) of the
+    # part's width
+    rows, width = part_shape[2:]
+    return ("kernel" if q_shape[2] % rows == 0 and full_decode.fits(
+        (q_shape[0], rows, q_shape[2] // rows, width), part_shape)
+        else "dense")
+
+
 def attend_by(arm: str, q: Array, k: Array, v: Array,
               window: int = 0) -> Array:
     """Run ``arm`` of :func:`device_arm` on a device's own q, k, v; the

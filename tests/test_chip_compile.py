@@ -112,6 +112,30 @@ def _held(compiled, cache):
             compiled.memory_analysis().temp_size_in_bytes, cache_bytes)
 
 
+def _take_arm(monkeypatch, arm):
+    """Force what ``transformer.full_decode_arm`` answers on this CPU:
+    ``kernel`` makes the backend a TPU's and sends the kernel of
+    ops/pallas/full_decode.py through Mosaic for the described chip;
+    ``plain`` leaves the einsums.  (A model a fixture shares keeps its
+    traced runners: they are dropped, or the second arm would be handed
+    the first one's program.)"""
+    from parameter_server_distributed_tpu.models import transformer
+    from parameter_server_distributed_tpu.ops.pallas import full_decode
+
+    monkeypatch.setattr(generation, "_RUNNERS", type(generation._RUNNERS)())
+    monkeypatch.setattr(full_decode, "interpret_mode", lambda *_: False)
+    monkeypatch.setattr(transformer, "_kernel_backend",
+                        lambda: arm == "kernel")
+
+
+def _full_kernels(compiled) -> int:
+    """The kernel's calls in a compiled round (each under
+    ``cache_attn/attn/full/attn_kernel``)."""
+    return len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*attn/full/attn_kernel',
+        compiled.as_text()))
+
+
 def _compiled_round(model, params, cache, slots, chip):
     """The decode round as ``DecodeServer`` dispatches it: the round
     before's tokens as a device array (``prev``: what that round returned,
@@ -131,12 +155,22 @@ def _compiled_round(model, params, cache, slots, chip):
     return lowered.compile()
 
 
-def test_the_decode_round_updates_every_part_where_it_lies(cell):
+@pytest.mark.parametrize("arm", ["kernel", "plain"])
+def test_the_decode_round_updates_every_part_where_it_lies(cell, monkeypatch,
+                                                           arm):
+    """With the full layers through ops/pallas/full_decode.py (``kernel``:
+    GPT-2's 8 rows of two heads and SmallThinker's 4 rows a position, both
+    laid by position and handed to the kernel as they lie) and through
+    the einsums (``plain``)."""
     model, params, cache, _, slots, chip = cell
+    _take_arm(monkeypatch, arm)
     compiled = _compiled_round(model, params, cache, slots, chip)
     aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
     assert aliased >= parts
     assert moved == []
+    full = sum(not model.config.layer_spec(i).window
+               for i in range(model.config.n_layers))
+    assert _full_kernels(compiled) == (full if arm == "kernel" else 0)
     # the round's temporaries are activations, not copies of the cache
     # (four padded copies of it made them three times the cache, PR 28)
     assert temporaries < cache_bytes / 4
@@ -186,7 +220,9 @@ def test_minicpm_salas_round_gathers_and_updates_in_place(one_chip):
     assert temporaries < cache_bytes / 4
 
 
-def test_lfm2s_round_updates_k_v_in_place_beside_its_conv_states(one_chip):
+@pytest.mark.parametrize("arm", ["kernel", "plain"])
+def test_lfm2s_round_updates_k_v_in_place_beside_its_conv_states(
+        one_chip, monkeypatch, arm):
     """``serve_manychat_lfm2_24b_a2b``'s decode round at its real widths,
     64 slots x 4,096 positions, four layers (conv and conv with the dense
     SwiGLU, then attention and conv with 64 experts): the attention
@@ -194,7 +230,9 @@ def test_lfm2s_round_updates_k_v_in_place_beside_its_conv_states(one_chip):
     of them is copied or sliced.  A conv layer's state is a shift register:
     a round rewrites all of it (0.5 MB a layer), and the compiler stages it
     in fast memory first, which is the three copies of exactly a state's
-    size that are let through here."""
+    size that are let through here.  ``kernel``: the attention layer
+    through ops/pallas/full_decode.py, 4 rows of two heads a position."""
+    _take_arm(monkeypatch, arm)
     with open(os.path.join(ROOT, "perfbench", "configs",
                            "lfm2-24b-a2b-10l.json")) as handle:
         config = json.load(handle)
@@ -222,6 +260,7 @@ def test_lfm2s_round_updates_k_v_in_place_beside_its_conv_states(one_chip):
     assert [op for op in moved if op[2] != state or op[0] != "copy"] == []
     assert len(moved) <= 3
     assert temporaries < cache_bytes / 4
+    assert _full_kernels(compiled) == (arm == "kernel")
 
 
 @pytest.mark.parametrize("arm", ["kernel", "plain"])
@@ -362,7 +401,9 @@ def test_deepseek_v3s_extension_expands_by_key_block(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
-def test_olmo_hybrids_round_updates_k_v_and_matrices_where_they_lie(one_chip):
+@pytest.mark.parametrize("arm", ["kernel", "plain"])
+def test_olmo_hybrids_round_updates_k_v_and_matrices_where_they_lie(
+        one_chip, monkeypatch, arm):
     """``serve_reasoning_olmo_hybrid``'s decode round at its real widths, 12
     slots x 4,096 positions, one whole period (three gdn layers and a full
     one): the full layer's K and V (30 heads of 128, 755 MB together) and
@@ -372,7 +413,11 @@ def test_olmo_hybrids_round_updates_k_v_and_matrices_where_they_lie(one_chip):
     no triangular solve is in the round.  A gdn layer's shift register is
     rewritten whole by a round (0.8 MB a layer) and the compiler may stage
     it, as LFM2's and Kimi Linear's: copies of exactly a register's size are
-    let through."""
+    let through.  ``kernel``: the full layer through
+    ops/pallas/full_decode.py, which takes the parts turned to [12, 30,
+    4096, 128]: the bytes as the device lays them, so the turn is no
+    operation and nothing is copied in front of the kernel."""
+    _take_arm(monkeypatch, arm)
     with open(os.path.join(ROOT, "perfbench", "configs",
                            "olmo-hybrid-7b-16l.json")) as handle:
         config = json.load(handle)
@@ -407,6 +452,7 @@ def test_olmo_hybrids_round_updates_k_v_and_matrices_where_they_lie(one_chip):
     assert generation._lies_by_head(30)
     assert re.search(r"cache_k_0_\S* = bf16\[12,4096,30,128\]\{3,1,2,0",
                      compiled.as_text())
+    assert _full_kernels(compiled) == (arm == "kernel")
     # an admission: a row of 2,048 + 256 positions and its snapshot
     bucket = 2048 + 256
     row = placed((
@@ -463,13 +509,18 @@ def test_a_round_writes_k_and_v_as_the_device_lays_them(one_chip, heads,
     assert generation._lies_by_head(rows) == (rows in (6, 10))
 
 
-def test_k_exaones_round_updates_rings_of_128_where_they_lie(one_chip):
+@pytest.mark.parametrize("arm", ["kernel", "plain"])
+def test_k_exaones_round_updates_rings_of_128_where_they_lie(
+        one_chip, monkeypatch, arm):
     """``serve_chat_k_exaone_ep8``'s decode round at its real widths, 32
     slots x 4,096 positions, four layers (the dense one and two expert
     layers over rings of 128, one expert layer over full attention), 16 of
     128 experts held: the rings and the full layer's K and V are updated
     where they lie, nothing as large as a ring is copied or sliced, and the
-    grouped matmul's weights are the held experts' alone."""
+    grouped matmul's weights are the held experts' alone.  ``kernel``: the
+    full layer through ops/pallas/full_decode.py, 8 rows of heads a
+    position, 8 query heads a row."""
+    _take_arm(monkeypatch, arm)
     with open(os.path.join(ROOT, "perfbench", "configs",
                            "k-exaone-236b-a23b-8l-ep8.json")) as handle:
         config = json.load(handle)
@@ -502,10 +553,37 @@ def test_k_exaones_round_updates_rings_of_128_where_they_lie(one_chip):
         == []
     assert len(moved) <= 2
     assert temporaries < cache_bytes / 4
+    assert _full_kernels(compiled) == (arm == "kernel")
     # three grouped matmuls an expert layer, each over [16, ...] weights
     text = compiled.as_text()
     assert len(re.findall(r"bf16\[16,6144,2048\]", text)) > 0
     assert "bf16[128,6144,2048]" not in text
+
+
+@pytest.mark.parametrize("tpu,q,part,dtype,arm", [
+    (True, (12, 1, 30, 128), (12, 4096, 30, 128), jnp.bfloat16, "kernel"),
+    (True, (32, 1, 64, 128), (32, 4096, 8, 128), jnp.bfloat16, "kernel"),
+    (True, (16, 1, 28, 128), (16, 16384, 4, 128), jnp.bfloat16, "kernel"),
+    (True, (64, 1, 32, 64), (64, 4096, 4, 128), jnp.bfloat16, "kernel"),
+    (True, (32, 1, 16, 64), (32, 1024, 8, 128), jnp.float32, "kernel"),
+    # several tokens a lane: an extension, a speculative verify
+    (True, (12, 4, 30, 128), (12, 4096, 30, 128), jnp.bfloat16, "dense"),
+    (True, (32, 1, 16, 64), (32, 1024, 8, 128), jnp.int8, "dense"),
+    (False, (12, 1, 30, 128), (12, 4096, 30, 128), jnp.bfloat16, "dense"),
+    # max_len not in whole blocks; rows narrower than a register (a head
+    # of 48 lanes alone in its row); a tiny test's shapes
+    (True, (12, 1, 30, 128), (12, 4000, 30, 128), jnp.bfloat16, "dense"),
+    (True, (4, 1, 6, 48), (4, 1024, 6, 48), jnp.bfloat16, "dense"),
+    (True, (2, 1, 4, 8), (2, 64, 1, 32), jnp.float32, "dense")])
+def test_full_decode_arms_table(monkeypatch, tpu, q, part, dtype, arm):
+    """``transformer.full_decode_arm`` from the shapes and the backend: a
+    round's single token a lane on a TPU against an unquantised part in
+    whole blocks and whole registers takes the kernel, at the five serving
+    cells' shapes; everything else the einsums, never an error."""
+    from parameter_server_distributed_tpu.models import transformer
+
+    monkeypatch.setattr(transformer, "_kernel_backend", lambda: tpu)
+    assert transformer.full_decode_arm(q, part, dtype) == arm
 
 
 # configuration, mesh axes: the two training cells (64 x 1,024 tokens a step)

@@ -38,7 +38,7 @@ import numpy as np
 
 from ..obs import stats as obs_stats
 from . import transformer as _transformer
-from .transformer import DELTA_MIXERS, STATE_MIXERS, Transformer
+from .transformer import RECURRENT_MIXERS, STATE_MIXERS, Transformer
 
 Array = jax.Array
 
@@ -117,8 +117,8 @@ class KVCache:
     layer that keeps no K/V, a TUPLE of arrays a layer
     (:func:`state_shape`): a linear layer's decayed outer products,
     ([B, H, D, D] float32,), a conv layer's shift register of its last
-    gated inputs, ([B, K - 1, d_model],), a kda or gdn layer's both (the
-    register of its three convolutions' inputs and the matrix); none grows
+    gated inputs, ([B, K - 1, d_model],), a kda, gdn or ssm layer's both
+    (the register of its convolutions' inputs and the matrix); none grows
     with the context, none can be rolled back.  ``latent`` holds a latent
     layer's rows by position, [B, max_len, latent_row]: the normed latent
     every head's K and V are expanded from and the key part they share
@@ -206,7 +206,9 @@ def state_shape(model: Transformer) -> tuple[tuple, ...]:
     ``conv_kernel - 1`` inputs of its three convolutions [K - 1, 3 *
     attn_dim] in the model's dtype and its matrix [H, D, D] float32; a gdn
     layer's the same two at its own sizes, [K - 1, H * (2 Dk + Dv)] and
-    [H, Dk, Dv].
+    [H, Dk, Dv]; an ssm layer's register [K - 1, H P + 2 G N] and matrix
+    [H, P, N] float32 (at 64 heads of 64 and a state of 128 the last axis
+    is whole registers: nothing is padded).
 
     The matrix lies by head as the delta rule takes it, whatever its sizes.
     Where Dv fills no whole registers (192 of 256 lanes) the device pads
@@ -222,7 +224,10 @@ def state_shape(model: Transformer) -> tuple[tuple, ...]:
              "conv": (((c.conv_kernel - 1, c.d_model), c.dtype),),
              "kda": (((c.conv_kernel - 1, 3 * c.attn_dim), c.dtype), matrix),
              "gdn": (((c.conv_kernel - 1, c.n_heads * (2 * keys + values)),
-                      c.dtype), ((c.n_heads, keys, values), jnp.float32))}
+                      c.dtype), ((c.n_heads, keys, values), jnp.float32)),
+             "ssm": (((c.conv_kernel - 1, c.ssm_dims[1]), c.dtype),
+                     ((c.ssm_heads, c.ssm_head_dim, c.ssm_state),
+                      jnp.float32))}
     return tuple(kinds[c.layer_spec(i).mixer] for i in c.state_layers)
 
 
@@ -359,12 +364,13 @@ def _seeded(part: Array, block: Array) -> Array:
 
 def check_rolls_back(model: Transformer) -> None:
     """Speculative decoding rolls rejected positions back by moving the
-    cache's length; a linear, conv, kda or gdn layer's states have no length
-    to move."""
+    cache's length; a linear, conv, kda, gdn or ssm layer's states have no
+    length to move."""
     if model.config.state_layers:
         raise ValueError(
             "speculative decoding rolls rejected positions back, and a "
-            "linear, conv, kda or gdn layer's state cannot be rolled back: "
+            "linear, conv, kda, gdn or ssm layer's state cannot be rolled "
+            "back: "
             "decode a model with such layers without a draft")
 
 
@@ -596,14 +602,14 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                    if spec.mixer in STATE_MIXERS
                    else (False, cache.latent_layers.index(layer))
                    if spec.mixer == "latent" else cache.place(layer))
-        if spec.mixer in ("conv", "latent") + DELTA_MIXERS:
+        if spec.mixer in ("conv", "latent") + RECURRENT_MIXERS:
             with jax.named_scope("cache_attn"):
                 if spec.mixer == "conv":
                     h, state = model.conv_residual(
                         lp, p, h, parts["state"][i][0], counts)
                     parts["state"][i] = (state,)
-                elif spec.mixer in DELTA_MIXERS:
-                    h, parts["state"][i] = model.delta_residual(spec)(
+                elif spec.mixer in RECURRENT_MIXERS:
+                    h, parts["state"][i] = model.recurrent_residual(spec)(
                         lp, p, h, parts["state"][i], counts)
                 else:
                     with jax.named_scope("attn"), jax.named_scope("latent"):

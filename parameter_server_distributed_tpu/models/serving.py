@@ -56,7 +56,7 @@ from .generation import (KVCache, QuantKVCache, _cached_runner,
                          init_cache, pack_heads, ring_layers_of, sample_token,
                          sample_token_rowwise, split_row, state_shape)
 from .prefix_tree import PrefixTree, RowRef
-from .transformer import DELTA_MIXERS, Transformer
+from .transformer import RECURRENT_MIXERS, Transformer
 
 Array = jax.Array
 
@@ -184,8 +184,9 @@ def _shard_cache(cache, mesh):
 
 def _builds_few(model: Transformer) -> bool:
     """Whether the server keeps this model's admission programs FEW: a model
-    with delta-rule layers (kda, gdn), whose every program is all its layers
-    unrolled around a chunked delta rule (10 to 20 s of the compiler's time
+    with recurrent layers (kda, gdn, ssm), whose every program is all its
+    layers unrolled around a chunked recurrence (10 to 20 s of the
+    compiler's time
     each on a cold start; four resident contexts and their turns were 70
     programs and 515 s, PERF.md section 6, PR 47), and a model with LATENT
     layers, whose block of fewer than ``generation._BLOCKWISE_QUERIES``
@@ -197,7 +198,7 @@ def _builds_few(model: Transformer) -> bool:
     (:func:`_suffix_floor`), built ahead (``DecodeServer._build_ahead``),
     and one prefill program for every prompt of a chunk or more
     (:func:`_prefills_whole`)."""
-    return any(spec.mixer in DELTA_MIXERS + ("latent",)
+    return any(spec.mixer in RECURRENT_MIXERS + ("latent",)
                for spec in model.config.specs)
 
 
@@ -209,7 +210,8 @@ def _suffix_floor(model: Transformer) -> int:
     bound by what it READS whatever the block: its weights (a matrix in
     bfloat16 is read no faster than 240 rows multiply it on a v5e, 197
     TFLOP/s over 819 GB/s), the row and the snapshot it restores; its delta
-    rule takes a block in chunks of ``DELTA_CHUNK`` either way, and its
+    rule takes a block in chunks of ``DELTA_CHUNK`` either way (an ssm
+    layer's dual form in ONE chunk of ``SSM_CHUNK``), and its
     latent layers attend a block of 256 by key block where a shorter one
     reads the whole lane.  So the
     turns of a conversation (16 to 256 tokens) share ONE program a prefix
@@ -447,7 +449,11 @@ def _prefills_whole(model: Transformer, bucket: int) -> bool:
         # side by side)
         3 * c.attn_dim for spec in c.specs if spec.mixer == "kda"] + [
         c.n_heads * (2 * c.delta_dims[0] + c.delta_dims[1])
-        for spec in c.specs if spec.mixer == "gdn"])
+        for spec in c.specs if spec.mixer == "gdn"] + [
+        # (an ssm layer's gate, convolution channels and steps leave one
+        # projection side by side)
+        sum(c.ssm_dims) + c.ssm_heads
+        for spec in c.specs if spec.mixer == "ssm"])
     return bucket * widest <= _PREFILL_WHOLE
 
 
@@ -677,7 +683,8 @@ class DecodeServer:
         # rolled back
         self._linear_layers = len(config.layers_of("linear")
                                   + config.layers_of("kda")
-                                  + config.layers_of("gdn"))
+                                  + config.layers_of("gdn")
+                                  + config.layers_of("ssm"))
         self._state_layers = self._linear_layers + len(
             config.layers_of("conv"))
         self._sparse_layers = len(config.layers_of("sparse"))
@@ -762,7 +769,7 @@ class DecodeServer:
                                       "rank_places", "tokens_routed")}
         for kind, held in self._cache_bytes_by_kind().items():
             obs_stats.gauge(f"serve.cache.{kind}_bytes").set(held)
-        # what a round's sparse, linear (kda and gdn too), latent and full
+        # what a round's sparse, linear (kda, gdn and ssm too), latent and full
         # layers read (see _count_mixers)
         self._obs_mixers = {
             name: obs_stats.counter(name) for name in (
@@ -1735,7 +1742,7 @@ class DecodeServer:
         every lane (idle ones too: the device computes them); beside it
         ``positions``, what those lanes held in THAT round (each lane's
         length with its new token; a sparse layer each) and the states
-        the round advanced (a lane and linear, kda or gdn layer each).
+        the round advanced (a lane and linear, kda, gdn or ssm layer each).
         A latent layer needs its lanes' ``positions`` and reads its whole
         part: both are counted, a latent layer each; so are a full softmax
         layer's (``serve.full.positions_live`` of

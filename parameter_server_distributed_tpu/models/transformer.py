@@ -42,11 +42,14 @@ Array = jax.Array
 
 
 FFN_KINDS = ("mlp", "moe", "experts")
-MIXER_KINDS = ("softmax", "sparse", "linear", "conv", "kda", "latent", "gdn")
+MIXER_KINDS = ("softmax", "sparse", "linear", "conv", "kda", "latent", "gdn",
+               "ssm")
 # the mixers that keep a fixed-size STATE in a decode cache and no K/V
-STATE_MIXERS = ("linear", "conv", "kda", "gdn")
-# the delta-rule mixers: a convolutions' register and a matrix a layer
-DELTA_MIXERS = ("kda", "gdn")
+STATE_MIXERS = ("linear", "conv", "kda", "gdn", "ssm")
+# the recurrent mixers behind a short convolution (the two delta rules and
+# the state-space layer): a convolution's register and a matrix a layer,
+# the whole branch one function (Transformer.recurrent_residual)
+RECURRENT_MIXERS = ("kda", "gdn", "ssm")
 # jax.ad_checkpoint name of a layer's mixer branch as it joins the residual
 # stream (Transformer._residual); Transformer._remat_policy may keep it
 MIXER_OUT = "mixer_out"
@@ -149,7 +152,13 @@ class LayerSpec:
     # beside the softmax layers' ``head_dim``, a decay projection a head, a
     # full-rank silu output gate and, under ``config.delta_neg_eigval``, a
     # write strength of up to 2; the same two states, the matrix
-    # [H, Dk, Dv].  latent: causal attention whose K and V are expanded
+    # [H, Dk, Dv].  ssm: a state-space layer in its dual form (Mamba-2;
+    # ops/ssd.py): ``config.ssm_heads`` heads of ``config.ssm_head_dim``
+    # behind ONE projection and one convolution WITH a bias, a step and so
+    # a decay a head and position, keys and queries of ``config.ssm_state``
+    # shared by the heads of each of ``config.ssm_groups`` groups, a skip a
+    # head, and the norm AFTER the silu gate; the same two states, the
+    # matrix [H, P, N].  latent: causal attention whose K and V are expanded
     # from ONE normed latent of ``config.kv_latent`` a position, with a
     # key part of ``config.qk_shared`` all heads share beside it (rotated
     # under ``config.latent_rope``, else without rotary anywhere); its
@@ -180,11 +189,11 @@ class LayerSpec:
         if self.qk_norm not in (False, True, "all"):
             raise ValueError(f"qk_norm is False, True (a head) or 'all', "
                              f"got {self.qk_norm!r}")
-        if (self.mixer in ("conv", "kda", "gdn", "latent")
+        if (self.mixer in ("conv", "latent") + RECURRENT_MIXERS
                 and (self.kv_heads or self.qk_norm or self.gate
                      or self.out_norm)):
             raise ValueError(
-                "a conv layer has no heads, a kda or gdn layer norms and "
+                "a conv layer has no heads, a kda, gdn or ssm layer norms and "
                 "gates its output always and a latent layer has one latent "
                 "for every "
                 "head: kv_heads, qk_norm, gate and out_norm belong to "
@@ -309,9 +318,21 @@ class TransformerConfig:
     # MLP of ``mlp_act``'s form and this many experts' width on every
     # token (``moe/shared/w1|w2|w3``), added ungated to the routed part
     moe_shared_experts: int = 0
-    # taps of a ``conv`` layer's kernel, and of a ``kda`` or ``gdn``
-    # layer's three
+    # taps of a ``conv`` layer's kernel, of a ``kda`` or ``gdn`` layer's
+    # three and of an ``ssm`` layer's one
     conv_kernel: int = 3
+    # an ``ssm`` layer's sizes: heads and their width (the inner width is
+    # their product), the width of the keys and queries a group's heads
+    # share, which is the state's last axis, and the number of groups
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 0
+    # what a softmax layer's scores q . k are multiplied by; 0: the usual
+    # ``head_dim ** -0.5``.  Taken on the QUERY as ``qkv`` makes it, so every
+    # form of the attention behind (the einsum, the blockwise kernels, an
+    # extension, a round against a cache) keeps its one scale
+    attn_scale: float = 0.0
     # a ``gdn`` layer's key and value head sizes (0: ``head_dim``'s), and
     # whether its write strength is doubled, 2 sigmoid(.), so that I - beta
     # k k^T has an eigenvalue in (-1, 1) (``linear_allow_neg_eigval``)
@@ -435,11 +456,27 @@ class TransformerConfig:
         mixers = {spec.mixer for spec in self.specs}
         if "sparse" in mixers and self.sparse is None:
             raise ValueError("a sparse layer needs config.sparse")
-        if (mixers & {"conv", "kda", "gdn"}
+        if (mixers & {"conv", *RECURRENT_MIXERS}
                 and (self.conv_kernel < 2 or self.bias)):
-            raise ValueError(f"a conv, kda or gdn layer has a kernel of 2 "
-                             f"taps or more and no bias, got conv_kernel="
-                             f"{self.conv_kernel}, bias={self.bias}")
+            raise ValueError(f"a conv, kda, gdn or ssm layer has a kernel of "
+                             f"2 taps or more and no bias on a projection, "
+                             f"got conv_kernel={self.conv_kernel}, "
+                             f"bias={self.bias}")
+        sizes = (self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                 self.ssm_groups)
+        if (min(sizes) < 1 or self.ssm_heads % self.ssm_groups
+                or self.pos_emb == "learned") if "ssm" in mixers else any(
+                    sizes):
+            raise ValueError(
+                f"ssm_heads, ssm_head_dim, ssm_state and ssm_groups are an "
+                f"ssm layer's: all positive (the groups dividing the heads) "
+                f"in a model that has such a layer and no learned positions, "
+                f"0 in any other; got {sizes}")
+        if self.attn_scale < 0 or (self.attn_scale
+                                   and "softmax" not in mixers):
+            raise ValueError("attn_scale is a softmax layer's: 0 (head_dim "
+                             "** -0.5) or more, and a model that has such a "
+                             "layer")
         if min(self.delta_key_dim, self.delta_value_dim) < 0 or (
                 "gdn" not in mixers and (self.delta_key_dim
                                          or self.delta_value_dim
@@ -482,8 +519,8 @@ class TransformerConfig:
         if (mixers - {"softmax"} or self.prologue) and self.scan_layers:
             raise ValueError("scan_layers stacks one kind of cache part a "
                              "layer and scans whole periods: sparse, linear, "
-                             "conv, kda, gdn and latent layers and a "
-                             "prologue run unrolled")
+                             "conv, kda, gdn, ssm and latent layers and "
+                             "a prologue run unrolled")
 
     @property
     def attn_dim(self) -> int:
@@ -495,6 +532,20 @@ class TransformerConfig:
         """(key, value) head sizes of a ``gdn`` layer."""
         return (self.delta_key_dim or self.head_dim,
                 self.delta_value_dim or self.head_dim)
+
+    @property
+    def ssm_dims(self) -> tuple[int, int]:
+        """(inner width H x P, channels through the convolution: the inner
+        width and every group's key and query) of an ``ssm`` layer."""
+        inner = self.ssm_heads * self.ssm_head_dim
+        return inner, inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def query_gain(self) -> float:
+        """What ``qkv`` multiplies a softmax layer's queries by so that the
+        scores, divided by sqrt(head_dim) wherever they are formed, come out
+        times ``attn_scale``; 1 where that is 0."""
+        return self.attn_scale * math.sqrt(self.head_dim) or 1.0
 
     @property
     def latent_row(self) -> int:
@@ -990,6 +1041,19 @@ class Transformer:
                      "attn/o_norm/scale": (c.delta_dims[1],),
                      "attn/wo": (values, c.d_model),
                      "ln2/scale": (c.d_model,)}
+        elif spec.mixer == "ssm":
+            # (z, xBC, dt) come from one projection; a kernel tap is a row
+            inner, conv = c.ssm_dims
+            block = {"ln1/scale": (c.d_model,),
+                     "ssm/in_proj": (c.d_model, inner + conv + c.ssm_heads),
+                     "ssm/conv/kernel": (c.conv_kernel, conv),
+                     "ssm/conv/bias": (conv,),
+                     "ssm/decay/a_log": (c.ssm_heads,),
+                     "ssm/decay/dt_bias": (c.ssm_heads,),
+                     "ssm/skip": (c.ssm_heads,),
+                     "ssm/norm/scale": (inner,),
+                     "ssm/out_proj": (inner, c.d_model),
+                     "ln2/scale": (c.d_model,)}
         elif spec.mixer == "latent":
             # wq: every head's query, its own part then the shared one (a
             # pair with a norm between under ``q_latent``); wkv_a: the
@@ -1121,8 +1185,8 @@ class Transformer:
         # attention's products, a token: scores and values over S keys of
         # d_model (a latent layer's: its heads' own width and the shared key
         # part for the scores, its heads' for the values); a kda layer's are
-        # three products with its [D, D] states (a gdn layer's [Dk, Dv]) and
-        # do not grow with S
+        # three products with its [D, D] states (a gdn layer's [Dk, Dv]), an
+        # ssm layer's two with its [P, N], and do not grow with S
         attn = 0.0
         for i in range(c.n_layers):
             mixer = c.layer_spec(i).mixer
@@ -1130,6 +1194,9 @@ class Transformer:
                 attn += attn_mult * 1.5 * c.attn_dim * c.head_dim
             elif mixer == "gdn":
                 attn += attn_mult * 1.5 * c.n_heads * math.prod(c.delta_dims)
+            elif mixer == "ssm":
+                # (two products with its [P, N] states: the write, the read)
+                attn += attn_mult * c.ssm_dims[0] * c.ssm_state
             elif mixer == "latent":
                 attn += attn_mult * seq * (c.attn_dim
                                            + c.n_heads * c.qk_shared / 2)
@@ -1165,7 +1232,7 @@ class Transformer:
         params: dict[str, Array] = {}
         for name, shape in self.param_shapes().items():
             rng, sub = jax.random.split(rng)
-            if name.endswith("/scale"):
+            if name.endswith(("/scale", "ssm/skip")):
                 params[name] = jnp.ones(shape, c.dtype)
             elif (name.endswith(("/bias", "/b1", "/b2", "/bq", "/bk",
                                  "/bv", "/bo"))):
@@ -1183,8 +1250,8 @@ class Transformer:
                 fan_in = shape[-2] if len(shape) == 3 else shape[0]
                 scale = 1.0 / math.sqrt(fan_in)
                 # residual-output projections get depth-scaled init
-                if name.endswith(("attn/wo", "conv/out_proj", "mlp/w2",
-                                  "moe/w2", "moe/shared/w2")):
+                if name.endswith(("attn/wo", "conv/out_proj", "ssm/out_proj",
+                                  "mlp/w2", "moe/w2", "moe/shared/w2")):
                     scale /= math.sqrt(2.0 * c.n_layers)
                 params[name] = jax.random.normal(sub, shape, c.dtype) * scale
         return params
@@ -1329,6 +1396,9 @@ class Transformer:
                          c.norm_eps)
             k = rms_norm(k, params[f"{prefix}/attn/k_norm/scale"],
                          c.norm_eps)
+        if c.query_gain != 1.0 and (spec is None or spec.mixer == "softmax"):
+            # ``attn_scale``: here and nowhere else
+            q = (q * c.query_gain).astype(c.dtype)
         if c.pos_emb == "learned" or (spec is not None and not spec.rope):
             # learned positions live in the residual stream (embed/pos,
             # added at embedding time) — K/V need no positional transform;
@@ -1526,10 +1596,81 @@ class Transformer:
             return (self._residual(params, f"{prefix}/ln1", h, out),
                     (shift, matrix))
 
-    def delta_residual(self, spec: LayerSpec) -> Callable:
-        """The mixer branch of a delta-rule layer of kind ``spec``:
-        :meth:`kda_residual` or :meth:`gdn_residual`, one signature."""
-        return self.kda_residual if spec.mixer == "kda" else self.gdn_residual
+    # positions an ssm layer works through at a time (a [C, C] term a
+    # head; ops/ssd.py).  The published ``mamba_chunk_size`` is a kernel's
+    # choice too and happens to be the same
+    SSM_CHUNK = 256
+
+    def ssm_residual(self, params: Mapping[str, Array], prefix: str,
+                     h: Array, state: tuple | None = None,
+                     counts: Array | None = None) -> tuple[Array, tuple]:
+        """An ``ssm`` layer's whole mixer branch, under ``attn/linear``
+        (``conv`` and ``ssd`` inside; the projections and the gated norm
+        outside both): with u the branch's input, [z | xBC | dt] = u W_in;
+        xBC = silu(conv(xBC) + bias) through ONE depthwise causal
+        convolution, split into a value a head x [H, P] and a key and a
+        query a group B, C [G, N]; a step a head softplus(dt + dt_bias) and
+        a rate -exp(a_log), float32; the state-space dual form over them
+        (ops/ssd.py) plus the skip D x; then the gate BEFORE the norm,
+        RMSNorm(y * silu(z)) a group's channels at a time with a gain over
+        all of them, through W_out.  ``state`` is (the convolution's shift
+        register [B, K - 1, H P + 2 G N], the matrix [B, H, P, N] float32)
+        of the positions before (None: the sequence starts here),
+        ``counts`` [B] how many of the T are real.  Returns (new h, both
+        states after the last real position): one function for a whole
+        sequence, a block against cached states and a decode round's single
+        token."""
+        from ..ops.short_conv import short_conv
+        from ..ops.ssd import ssd
+
+        c = self.config
+        batch, seq = h.shape[:2]
+        shift, matrix = state if state is not None else (None, None)
+        dot = partial(wdot, preferred_element_type=jnp.float32)
+        ssm = f"{prefix}/ssm"
+        inner, conv = c.ssm_dims
+        with jax.named_scope("attn"), jax.named_scope("linear"):
+            u = self._branch_input(params, f"{prefix}/ln1", h)
+            z, xbc, dt = jnp.split(dot(u, params[f"{ssm}/in_proj"]),
+                                   [inner, inner + conv], axis=-1)
+            with jax.named_scope("conv"):
+                mixed, shift = short_conv(xbc.astype(c.dtype),
+                                          params[f"{ssm}/conv/kernel"],
+                                          shift, counts)
+                mixed = jax.nn.silu(mixed + params[f"{ssm}/conv/bias"].astype(
+                    jnp.float32)).astype(c.dtype)
+                x, b, q = jnp.split(
+                    mixed, [inner, inner + c.ssm_groups * c.ssm_state],
+                    axis=-1)
+            with jax.named_scope("ssd"):
+                x = x.reshape(batch, seq, c.ssm_heads, c.ssm_head_dim)
+                b, q = (part.reshape(batch, seq, c.ssm_groups, c.ssm_state)
+                        for part in (b, q))
+                step = jax.nn.softplus(dt + params[
+                    f"{ssm}/decay/dt_bias"].astype(jnp.float32))
+                rate = -jnp.exp(params[f"{ssm}/decay/a_log"].astype(
+                    jnp.float32))
+                out, matrix = ssd(x, step, rate, b, q, matrix, counts,
+                                  self.SSM_CHUNK)
+                out = out + x * params[f"{ssm}/skip"].astype(
+                    jnp.float32)[:, None]
+            # the gate, THEN the norm, a group's channels at a time, float32
+            gated = (out.reshape(batch, seq, c.ssm_groups, -1)
+                     * jax.nn.silu(z).reshape(batch, seq, c.ssm_groups, -1))
+            normed = gated * jax.lax.rsqrt(
+                jnp.mean(gated * gated, axis=-1, keepdims=True) + c.norm_eps)
+            out = dot((normed.reshape(batch, seq, inner) * params[
+                f"{ssm}/norm/scale"].astype(jnp.float32)).astype(c.dtype),
+                params[f"{ssm}/out_proj"])
+            return (self._residual(params, f"{prefix}/ln1", h, out),
+                    (shift, matrix))
+
+    def recurrent_residual(self, spec: LayerSpec) -> Callable:
+        """The mixer branch of a recurrent layer of kind ``spec`` (a
+        :data:`RECURRENT_MIXERS` entry): :meth:`kda_residual`,
+        :meth:`gdn_residual` or :meth:`ssm_residual`, one signature."""
+        return {"kda": self.kda_residual, "gdn": self.gdn_residual,
+                "ssm": self.ssm_residual}[spec.mixer]
 
     def latent_rows(self, params: Mapping[str, Array], prefix: str,
                     h: Array, positions: Array) -> tuple[Array, Array]:
@@ -1949,8 +2090,8 @@ class Transformer:
                  ) -> tuple[Array, list, Array]:
         """(h, what a cache keeps of every layer under ``collect_kv``: a
         (k, v), a state layer's tuple of states (see :meth:`mix`,
-        :meth:`conv_residual`, :meth:`kda_residual` and
-        :meth:`gdn_residual`) or a latent layer's
+        :meth:`conv_residual`, :meth:`kda_residual`,
+        :meth:`gdn_residual` and :meth:`ssm_residual`) or a latent layer's
         rows (:meth:`latent_residual`); aux loss).  ``counts``
         [B]: how many of a row's tokens are real, for the layers whose
         state must not hold a pad."""
@@ -1976,9 +2117,9 @@ class Transformer:
                 h, kept = self.conv_residual(layer_params, p, h,
                                              counts=counts)
                 kept = (kept,)
-            elif spec.mixer in DELTA_MIXERS:
-                h, kept = self.delta_residual(spec)(layer_params, p, h,
-                                                    counts=counts)
+            elif spec.mixer in RECURRENT_MIXERS:
+                h, kept = self.recurrent_residual(spec)(layer_params, p, h,
+                                                        counts=counts)
             elif spec.mixer == "latent":
                 h, kept = self.latent_residual(layer_params, p, h)
             else:
@@ -2175,7 +2316,9 @@ def transformer_rule(mesh: Mesh):
     row-parallel  (tensor on input dim):    wo w2
     (a kda or gdn layer's conv kernels, a_log, dt_bias, beta, a kda
     layer's gates' first halves and a gdn layer's decay projection, a
-    latent layer's wkv_a and wq_a: small, replicated)
+    latent layer's wkv_a and wq_a, an ssm layer's kernel, bias, a_log,
+    dt_bias and skip: small, replicated; its in_proj, whose columns are
+    three parts side by side, and out_proj take the fallback)
     (a shared expert's ``moe/shared/w*`` as the dense MLP's)
     vocab-sharded embedding; norm scales replicated (fsdp if divisible);
     MoE expert weights sharded over the ``expert`` axis (router replicated).
@@ -2224,7 +2367,8 @@ def transformer_rule(mesh: Mesh):
             return PartitionSpec(*fsdp_on(0, taken))
         if name.endswith(("/scale", "/bias", "/bq", "/bk", "/bv", "/bo",
                           "/b1", "/b2", "/a_log", "/dt_bias", "attn/conv_q",
-                          "attn/conv_k", "attn/conv_v", "attn/wkv_a",
+                          "attn/conv_k", "attn/conv_v", "ssm/conv/kernel",
+                          "ssm/skip", "attn/wkv_a",
                           "attn/wq_a", "attn/decay/wa", "attn/gate/wa", "attn/beta/w",
                           "attn/decay/w")):
             # norm scales and all biases: tiny 1-D vectors, replicated like

@@ -467,6 +467,62 @@ def test_olmo_hybrids_round_updates_k_v_and_matrices_where_they_lie(
     assert temporaries < cache_bytes / 4
 
 
+@pytest.mark.parametrize("arm", ["kernel", "plain"])
+def test_granites_round_updates_its_matrices_in_one_pass_where_they_lie(
+        one_chip, monkeypatch, arm):
+    """``serve_manychat_granite_4_h_micro``'s decode round at its real
+    widths, 64 slots x 2,048 positions, one whole period (nine ssm layers
+    around an attention layer): the ssm layers' matrices [64, 64, 64, 128]
+    float32 (134 MB each) and the attention layer's K and V are updated
+    where they lie and nothing as large as one of them is copied, sliced or
+    turned around; the single token takes the one-position recurrence (no
+    cumulative sum, no loop), and a layer's decay, write and read are ONE
+    fusion over its matrix: a round moves a state once each way.  A layer's
+    shift register is rewritten whole by a round (1.7 MB a layer) and the
+    compiler may stage it, as the delta-rule models': copies of exactly a
+    register's size are let through.  ``kernel``: the attention layer (8
+    K/V heads of 64, two a row: LFM2's rows) through
+    ops/pallas/full_decode.py."""
+    _take_arm(monkeypatch, arm)
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "granite-4.0-h-micro.json")) as handle:
+        config = json.load(handle)
+    family = families.of(config)
+    model = family.model(config, remat=False, n_layers=10)
+    slots, max_len = 64, 2048
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(lambda: family.make_weights(model, 1)))
+    assert params["layer0/ssm/in_proj"].shape == (2048, 8512)
+    assert params["layer0/ssm/conv/bias"].shape == (4352,)
+    assert params["layer5/attn/wk"].shape == (2048, 512)
+    cache = placed(jax.eval_shape(
+        lambda: generation.init_cache(model, slots, max_len)))
+    assert [x.shape for x in cache.k] == [(64, 2048, 4, 128)]
+    assert [[(x.shape, x.dtype) for x in layer] for layer in cache.state] \
+        == [[((64, 3, 4352), jnp.bfloat16),
+             ((64, 64, 64, 128), jnp.float32)]] * 9
+    compiled = _compiled_round(model, params, cache, slots, one_chip)
+    aliased, parts, moved, temporaries, cache_bytes = _held(compiled, cache)
+    assert parts == 2 + 18 and aliased >= parts
+    register = 64 * 3 * 4352
+    assert [op for op in moved
+            if op[0] != "copy" or op[2] != register] == []
+    assert temporaries < cache_bytes / 4
+    text = compiled.as_text()
+    assert "cumsum" not in text and " while(" not in text
+    # one fusion a layer takes a matrix in and gives it back
+    entry = text.split("\nENTRY")[1]
+    updates = [line for line in entry.splitlines()
+               if " fusion(" in line and "f32[64,64,64,128]" in line]
+    assert len(updates) == 9
+    assert all("attn/linear/ssd" in line for line in updates)
+    assert _full_kernels(compiled) == (arm == "kernel")
+
+
 @pytest.mark.parametrize("heads,head_dim", [(12, 64), (20, 64), (8, 64),
                                             (16, 128)])
 def test_a_round_writes_k_and_v_as_the_device_lays_them(one_chip, heads,

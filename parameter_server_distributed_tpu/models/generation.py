@@ -471,8 +471,12 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
     cached row) takes the window as a mask.  ``route_stats``, where
     given, gains each ``experts`` layer's tokens per expert.
 
-    A LINEAR, CONV, KDA or GDN layer reads and advances its states (``counts``
-    keeps pads out of them, and like a ring they cannot be rolled back).  A
+    A LINEAR, CONV, KDA, GDN or SSM layer reads and advances its states
+    (``counts`` keeps pads out of them, and like a ring they cannot be
+    rolled back; a serving round hands an ssm model 0 for a lane that holds
+    no request, whose states then stay as they are, and on a TPU an ssm
+    layer's single token moves the matrices of the other lanes alone:
+    ops/pallas/ssd_decode.py, ``transformer.round_arm`` says when).  A
     LATENT layer writes its rows by position and attends them: a block of
     ``_BLOCKWISE_QUERIES`` tokens or more against a long cache expands K
     and V from the rows and runs blockwise; anything shorter, a round's
@@ -571,7 +575,8 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                     q, keys.reshape(by_head), values.reshape(by_head),
                     positions[:, 0], window=spec.window)
             if (not 0 < spec.window < cache.max_len
-                    and _full_arm(cache, q.shape, keys) == "kernel"):
+                    and _round_arm("full", cache, q.shape,
+                                   keys) == "kernel"):
                 return _kernel_cache_attention(c, q, keys, values,
                                                positions[:, 0] + 1)
             if spec.window not in masks:
@@ -608,6 +613,12 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                     h, state = model.conv_residual(
                         lp, p, h, parts["state"][i][0], counts)
                     parts["state"][i] = (state,)
+                elif spec.mixer == "ssm":
+                    h, parts["state"][i] = model.ssm_residual(
+                        lp, p, h, parts["state"][i], counts, _round_arm(
+                            "ssm", cache,
+                            (batch, t, c.ssm_heads, c.ssm_head_dim),
+                            parts["state"][i][1]))
                 elif spec.mixer in RECURRENT_MIXERS:
                     h, parts["state"][i] = model.recurrent_residual(spec)(
                         lp, p, h, parts["state"][i], counts)
@@ -830,13 +841,12 @@ def _cache_weighted(c, probs: Array, values: Array) -> Array:
     return _own_lanes(c, out, pack)
 
 
-def _full_arm(cache, q_shape: tuple[int, ...], part) -> str:
-    """``transformer.full_decode_arm`` for q [B, T, H, D] against ``part``
-    of ``cache``: a cache spread over several devices keeps the einsums,
-    which GSPMD partitions (it cannot cut a kernel)."""
-    if getattr(cache, "devices", 1) != 1:
-        return "dense"
-    return _transformer.full_decode_arm(q_shape, part.shape, part.dtype)
+def _round_arm(kind: str, cache, q_shape: tuple[int, ...], part) -> str:
+    """``transformer.round_arm`` for a layer of ``kind``, q [B, T, H, D]
+    against ``part`` of ``cache``, over as many devices as the cache is
+    spread."""
+    return _transformer.round_arm(kind, q_shape, part.shape, part.dtype,
+                                  getattr(cache, "devices", 1))
 
 
 def full_round_block(model: Transformer, cache, lanes: int) -> int:
@@ -848,8 +858,9 @@ def full_round_block(model: Transformer, cache, lanes: int) -> int:
     c = model.config
     parts = [part for i, part in enumerate(cache.k)
              if not (isinstance(cache, KVCache) and cache.by_head(i))]
-    if not parts or _full_arm(
-            cache, (lanes, 1, c.n_heads, c.head_dim), parts[0]) != "kernel":
+    if not parts or _round_arm(
+            "full", cache, (lanes, 1, c.n_heads, c.head_dim),
+            parts[0]) != "kernel":
         return 0
     from ..ops.pallas import full_decode
 

@@ -512,37 +512,63 @@ def _step_runner(model: Transformer, slots: int,
     A lane's token comes from ``prev``, the round before's tokens as that
     round left them on the device, unless the host names one in ``fresh``
     (-1: none): the round can be dispatched before the host has seen the
-    tokens it decodes from."""
+    tokens it decodes from.
+
+    Where a layer of the model reads which lanes hold a request
+    (:func:`_mask_layers`), ``fresh`` also says so, in the same upload:
+    ``_IDLE`` names a lane no request decodes in (its token is whatever
+    ``prev`` holds: nobody reads what it decodes), and the round's
+    ``counts`` are formed from it on the device.  Any other model traces
+    the program it always has."""
     key = (_model_key(model), "serve_step", slots, top_k, top_p,
            cache_dtype)
 
     def build():
+        masked = bool(_mask_layers(model))
+
         # donate the cache: without it every per-token step would copy the
         # whole K/V — doubling HBM traffic in the exact loop this server
         # exists to keep bandwidth-bound
         @partial(jax.jit, donate_argnums=(3,))
         def run(params, prev, fresh, cache, lengths, temps, rng):
-            return _decode_round(model, top_k, top_p, params,
-                                 jnp.where(fresh < 0, prev, fresh), cache,
-                                 lengths, temps, rng)
+            return _decode_round(
+                model, top_k, top_p, params,
+                jnp.where(fresh < 0, prev, fresh), cache, lengths, temps,
+                rng, (fresh != _IDLE).astype(jnp.int32) if masked else None)
 
         return run
 
     return _cached_runner(key, build)
 
 
+# in a round's ``fresh`` (beside -1, a lane chained to the round before)
+# and in a fused block's tokens: a lane that holds no request
+_IDLE = -2
+
+
+def _mask_layers(model: Transformer) -> tuple[int, ...]:
+    """The layers of ``model`` that read which lanes of a decode round hold
+    a request (``decode_block``'s ``counts``: 1 live, 0 idle): today the
+    ``ssm`` layers, whose states of an idle lane then stay where they are,
+    the matrix unread (ops/pallas/ssd_decode.py).  A round of a model with
+    such a layer is told; the host always knows the mask, and it enters a
+    traced program only where this says so."""
+    return tuple(model.config.layers_of("ssm"))
+
+
 def _decode_round(model, top_k, top_p, params, tokens, cache, lengths,
-                  temps, rng):
+                  temps, rng, counts=None):
     """ONE plain decode round — the single definition both the per-round
     program (_step_runner) and the fused scan (_multi_step_runner) jit,
     so step_many's token-exactness vs a step() loop holds by
     construction (same decode_block -> rng split -> rowwise sample
-    sequence)."""
+    sequence).  ``counts`` [B]: 1 for a lane that holds a request, 0 for
+    an idle one (None: the model reads no such mask)."""
     routed: list = []
     selected: list = []
     logits, cache = decode_block(model, params, tokens[:, None], cache,
-                                 lengths=lengths, route_stats=routed,
-                                 sparse_stats=selected)
+                                 lengths=lengths, counts=counts,
+                                 route_stats=routed, sparse_stats=selected)
     with jax.named_scope("sample"):
         rng, sub = jax.random.split(rng)
         nxt = sample_token_rowwise(logits[:, 0], sub, temps, top_k, top_p)
@@ -563,19 +589,28 @@ def _multi_step_runner(model: Transformer, slots: int, top_k: int,
     step() loop (tested).  The host lever for dispatch-bound serving:
     each step() round-trip costs a full host<->device dispatch (not
     measured on the chip), and between admissions those rounds need no
-    host decisions."""
+    host decisions.  Where the model's rounds take a mask
+    (:func:`_mask_layers`) a lane whose token is ``_IDLE`` holds no request,
+    in every one of the N rounds."""
     key = (_model_key(model), "serve_multistep", slots, top_k, top_p,
            cache_dtype, n_rounds)
 
     def build():
+        masked = bool(_mask_layers(model))
+
         @partial(jax.jit, donate_argnums=(2,))
         def run(params, tokens, cache, lengths, temps, rng):
+            counts = None
+            if masked:
+                counts = (tokens != _IDLE).astype(jnp.int32)
+                tokens = jnp.maximum(tokens, 0)
+
             def body(carry, _):
                 tokens, cache, lengths, rng = carry
-                # the fused rounds keep no counts
+                # (the fused rounds keep nothing counted)
                 nxt, cache, rng, _ = _decode_round(
                     model, top_k, top_p, params, tokens, cache, lengths,
-                    temps, rng)
+                    temps, rng, counts)
                 return (nxt, cache, lengths + 1, rng), nxt
 
             (tokens, cache, lengths, rng), outs = jax.lax.scan(
@@ -687,6 +722,10 @@ class DecodeServer:
                                   + config.layers_of("ssm"))
         self._state_layers = self._linear_layers + len(
             config.layers_of("conv"))
+        # a round tells the model which lanes hold a request where a layer
+        # reads it: the ssm layers, which then leave an idle lane's states
+        # as they are
+        self._masked_layers = len(_mask_layers(model))
         self._sparse_layers = len(config.layers_of("sparse"))
         self._latent_layers = len(config.layers_of("latent"))
         if draft is not None:
@@ -777,6 +816,7 @@ class DecodeServer:
                 "serve.sparse.positions_cached",
                 "serve.sparse.kernels_scored",
                 "serve.linear.state_updates",
+                "serve.linear.state_places",
                 "serve.latent.positions_read",
                 "serve.latent.positions_cached",
                 "serve.full.positions_live",
@@ -1501,9 +1541,12 @@ class DecodeServer:
         host has not fetched: a request it decoded for takes its token
         from that round's output on the device, one further into its
         budget; every other lane takes the host's (an admission's first
-        token; an idle lane's stale one, at a position that stays)."""
+        token; an idle lane's stale one, at a position that stays).  Where
+        the model's rounds take the mask (``_mask_layers``), every lane but
+        ``lanes`` goes up as ``_IDLE``."""
         ahead = after.lanes if after is not None else {}
-        fresh = self._tokens.copy()
+        fresh = (np.full_like(self._tokens, _IDLE) if self._masked_layers
+                 else self._tokens.copy())
         lanes: dict[int, _Slot] = {}
         for i, entry in enumerate(self._slot):
             if entry is None:
@@ -1511,8 +1554,7 @@ class DecodeServer:
             chained = ahead.get(i) is entry
             if len(entry.tokens) + chained < entry.max_new:
                 lanes[i] = entry
-                if chained:
-                    fresh[i] = -1
+                fresh[i] = -1 if chained else self._tokens[i]
         if not lanes:
             return None
         # (copies: the mirrors change while the round is in flight)
@@ -1524,7 +1566,7 @@ class DecodeServer:
         for i in lanes:
             self._lengths[i] += 1
         self._obs_rounds.add()
-        if (fresh < 0).any():
+        if (fresh == -1).any():
             self._obs_chained.add()
         held = np.minimum(lengths + 1, self.max_len)
         block = self._full_block
@@ -1541,7 +1583,8 @@ class DecodeServer:
             nxt, (loads, selected) = jax.device_get(flight.out)
         if loads is not None:
             self._count_routing(loads)
-        self._count_mixers(selected, flight.positions, flight.fetched)
+        self._count_mixers(selected, flight.positions, flight.fetched,
+                           len(flight.lanes))
         emitted: list[tuple[int, int]] = []
         for i, entry in flight.lanes.items():
             if self._slot[i] is not entry:
@@ -1598,7 +1641,11 @@ class DecodeServer:
             runner = _multi_step_runner(self.model, self.slots,
                                         self._top_k, self._top_p,
                                         self.cache_dtype, n)
-            inputs = (jnp.asarray(self._tokens), self._cache,
+            tokens = self._tokens
+            if self._masked_layers:
+                tokens = np.where([entry is None for entry in self._slot],
+                                  _IDLE, tokens).astype(tokens.dtype)
+            inputs = (jnp.asarray(tokens), self._cache,
                       jnp.asarray(self._lengths),
                       jnp.asarray(self._temps))
             with device:
@@ -1735,16 +1782,19 @@ class DecodeServer:
                 "window": 0, "state": 0, "latent": 0}
 
     def _count_mixers(self, selected: np.ndarray | None, positions: int,
-                      fetched: int) -> None:
+                      fetched: int, live: int) -> None:
         """One decode round into the counters the sparse, linear and
         latent layers' metrics divide.  ``selected`` is the round's own
         [positions attended, kernels scored] over its sparse layers and
         every lane (idle ones too: the device computes them); beside it
         ``positions``, what those lanes held in THAT round (each lane's
         length with its new token; a sparse layer each) and the states
-        the round advanced (a lane and linear, kda, gdn or ssm layer each).
-        A latent layer needs its lanes' ``positions`` and reads its whole
-        part: both are counted, a latent layer each; so are a full softmax
+        the round advanced (``serve.linear.state_updates``: a lane and
+        linear, kda or gdn layer each, and each of the ``live`` lanes the
+        round decoded for and ssm layer, which leaves an idle lane's states
+        as they are) of the places there are (``serve.linear.state_places``:
+        a lane and layer each).  A latent layer needs its lanes'
+        ``positions`` and reads its whole part: both are counted, a latent layer each; so are a full softmax
         layer's (``serve.full.positions_live`` of
         ``serve.full.positions_cached``), and beside them ``fetched``, the
         positions its arm fetched for the round
@@ -1759,7 +1809,10 @@ class DecodeServer:
             self._obs_mixers["serve.sparse.positions_cached"].add(
                 float(self._sparse_layers * positions))
         if self._linear_layers:
+            masked = self._masked_layers
             self._obs_mixers["serve.linear.state_updates"].add(
+                live * masked + self.slots * (self._linear_layers - masked))
+            self._obs_mixers["serve.linear.state_places"].add(
                 self.slots * self._linear_layers)
         if self._latent_layers:
             self._obs_mixers["serve.latent.positions_read"].add(

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
 import itertools
 import math
 from functools import partial
@@ -839,71 +840,86 @@ def device_arm(q_shape: tuple[int, ...], kv_shape: tuple[int, ...],
     return "blockwise" if q_shape[1] >= blockwise_from else "dense"
 
 
+# A decode round's layers that have a kernel of their own, by kind: the
+# module of ops/pallas that holds it, what the plain form is called, and the
+# shapes its ``fits`` takes, made of the chooser's (q, part); None: no fit.
+_ROUND_KERNELS = {
+    # q [B, T, H, W] absorbed, as wide as the rows [B, M, W] of its part
+    "latent": ("latent_decode", "dense",
+               lambda q, part: ((q[0],) + tuple(q[2:]), part)),
+    # q [B, T, H, D] against K or V [B, M, KV / pack, pack * D]: the query
+    # rows a row of heads meets are H / KV' (pack * G) of the part's width
+    "full": ("full_decode", "dense",
+             lambda q, part: None if q[2] % part[2] else (
+                 (q[0], part[2], q[2] // part[2], part[3]), part)),
+    # x [B, T, H, P] against the matrix [B, H, P, N]
+    "ssm": ("ssd_decode", "plain", lambda q, part: (q, part)),
+}
+
+
+def round_arm(kind: str, q_shape: tuple[int, ...],
+              part_shape: tuple[int, ...], part_dtype=jnp.float32,
+              devices: int = 1) -> str:
+    """Which implementation runs a layer of ``kind`` (a key of
+    ``_ROUND_KERNELS``) against its part of a decode cache, beside
+    :func:`device_arm` and by its rule (shapes and the backend, nothing
+    else), ONE rule for every kind:
+
+    ``kernel`` — the kind's module of ops/pallas: a decode round's single
+                 token a lane (``q_shape[1] == 1``) on ONE TPU device,
+                 against an unquantised part whose shape the module's
+                 ``fits`` takes.  ``latent``: every live row read once for
+                 all heads; ``full``: K and V read once, a block of
+                 positions at a time, and no block past a lane's length;
+                 ``ssm``: the matrix of the lanes that decode, updated
+                 where it lies, an idle lane's neither read nor written;
+    the plain form (``dense``: the einsums against the part as it lies,
+    read whole whatever the lanes hold; ``plain``: ``ops/ssd.py``'s
+    elementwise pass over every lane's matrix) — a block of several tokens
+    (an extension, a speculative verify), a part spread over several
+    devices (GSPMD partitions the plain form; it cannot cut a kernel), an
+    int8 part, any backend but a TPU, any other shape."""
+    module, plain, shapes = _ROUND_KERNELS[kind]
+    if (q_shape[1] != 1 or devices != 1 or not _kernel_backend()
+            or not jnp.issubdtype(part_dtype, jnp.floating)):
+        return plain
+    # (pallas is imported where a kernel can run, and only there)
+    fits = importlib.import_module(
+        f"..ops.pallas.{module}", __package__).fits
+    taken = shapes(tuple(q_shape), tuple(part_shape))
+    return "kernel" if taken is not None and fits(*taken) else plain
+
+
 def latent_decode_arm(q_shape: tuple[int, ...],
                       rows_shape: tuple[int, ...]) -> str:
-    """Which implementation attends a latent layer's ABSORBED queries (q
-    [B, T, H, W], as wide as the rows [B, M, W] of its part of a cache) on
-    one device, beside :func:`device_arm` and by its rule (shapes and the
-    backend, nothing else):
+    """:func:`round_arm` for a latent layer's ABSORBED queries (q [B, T,
+    H, W], as wide as the rows [B, M, W] of its part of a cache), with one
+    thing of its own: a round's token on ONE TPU device whose shapes the
+    kernel does not take is REFUSED, not sent down the einsums: they cost
+    eight times the kernel a round (4.5 ms a layer against 0.54 at 64
+    lanes x 16,384 positions; PERF.md, PR 47), and a server that slow
+    would only read as a low roofline."""
+    arm = round_arm("latent", q_shape, rows_shape)
+    if arm == "dense" and q_shape[1] == 1 and _kernel_backend():
+        from ..ops.pallas import latent_decode
 
-    ``kernel`` — ops/pallas/latent_decode.py, a decode round's single token
-                 a lane on a TPU: every live row read once for all heads;
-    ``dense``  — the two einsums against the part as it lies (read whole,
-                 twice): a block of several tokens, or any backend but a TPU.
-
-    A round's token on a TPU whose shapes the kernel does not take is
-    REFUSED, not sent down the einsums: they cost eight times the kernel a
-    round (4.5 ms a layer against 0.54 at 64 lanes x 16,384 positions;
-    PERF.md, PR 47), and a server that slow would only read as a low
-    roofline."""
-    if q_shape[1] != 1 or not _kernel_backend():
-        return "dense"
-    # (pallas is imported where a kernel can run, and only there)
-    from ..ops.pallas import latent_decode
-
-    if not latent_decode.fits((q_shape[0],) + tuple(q_shape[2:]),
-                              rows_shape):
         raise ValueError(
             f"a latent layer's decode round on a TPU runs "
             f"ops/pallas/latent_decode.py, which takes heads in 16s, rows "
             f"of whole 128-lane registers and a cache of whole blocks of "
             f"{latent_decode.BLOCK} positions; got queries {q_shape} "
             f"against rows {rows_shape}")
-    return "kernel"
+    return arm
 
 
 def full_decode_arm(q_shape: tuple[int, ...], part_shape: tuple[int, ...],
                     part_dtype) -> str:
-    """Which implementation attends a FULL softmax layer's queries (q [B,
-    T, H, D]) against its parts of a cache (K and V, each [B, M, KV /
-    pack, pack * D] of ``part_dtype``) on one device, beside
-    :func:`latent_decode_arm` and by its rule (shapes and the backend,
-    nothing else):
-
-    ``kernel`` — ops/pallas/full_decode.py, a decode round's single token
-                 a lane on a TPU against an unquantised part whose shape
-                 the kernel takes (rows of whole registers, ``M`` in whole
-                 blocks): K and V read once, a block of positions at a
-                 time, and no block past a lane's length;
-    ``dense``  — the two einsums against the parts as they lie (read whole,
-                 whatever the lanes hold): a block of several tokens (an
-                 extension, a speculative verify), an int8 part, any
-                 backend but a TPU, any other shape.
-
-    Unlike the latent arm it refuses nothing: the einsums are the accepted
-    path wherever the kernel does not run."""
-    if (q_shape[1] != 1 or not _kernel_backend()
-            or not jnp.issubdtype(part_dtype, jnp.floating)):
-        return "dense"
-    # (pallas is imported where a kernel can run, and only there)
-    from ..ops.pallas import full_decode
-
-    # the query rows a row of heads meets: H / KV' (pack * G) of the
-    # part's width
-    rows, width = part_shape[2:]
-    return ("kernel" if q_shape[2] % rows == 0 and full_decode.fits(
-        (q_shape[0], rows, q_shape[2] // rows, width), part_shape)
-        else "dense")
+    """:func:`round_arm` for a FULL softmax layer's queries (q [B, T, H,
+    D]) against its parts of a cache (K and V, each [B, M, KV / pack,
+    pack * D] of ``part_dtype``) on one device.  Unlike the latent arm it
+    refuses nothing: the einsums are the accepted path wherever the kernel
+    does not run."""
+    return round_arm("full", q_shape, part_shape, part_dtype)
 
 
 def attend_by(arm: str, q: Array, k: Array, v: Array,
@@ -1603,7 +1619,8 @@ class Transformer:
 
     def ssm_residual(self, params: Mapping[str, Array], prefix: str,
                      h: Array, state: tuple | None = None,
-                     counts: Array | None = None) -> tuple[Array, tuple]:
+                     counts: Array | None = None,
+                     arm: str = "plain") -> tuple[Array, tuple]:
         """An ``ssm`` layer's whole mixer branch, under ``attn/linear``
         (``conv`` and ``ssd`` inside; the projections and the gated norm
         outside both): with u the branch's input, [z | xBC | dt] = u W_in;
@@ -1616,10 +1633,13 @@ class Transformer:
         all of them, through W_out.  ``state`` is (the convolution's shift
         register [B, K - 1, H P + 2 G N], the matrix [B, H, P, N] float32)
         of the positions before (None: the sequence starts here),
-        ``counts`` [B] how many of the T are real.  Returns (new h, both
-        states after the last real position): one function for a whole
-        sequence, a block against cached states and a decode round's single
-        token."""
+        ``counts`` [B] how many of the T are real (in a decode round 1 for
+        a lane that holds a request and 0 for an idle one, whose states stay
+        as they are), ``arm`` what :func:`round_arm` answers for the matrix
+        (``kernel``: the single token through ops/pallas/ssd_decode.py).
+        Returns (new h, both states after the last real position): one
+        function for a whole sequence, a block against cached states and a
+        decode round's single token."""
         from ..ops.short_conv import short_conv
         from ..ops.ssd import ssd
 
@@ -1651,7 +1671,7 @@ class Transformer:
                 rate = -jnp.exp(params[f"{ssm}/decay/a_log"].astype(
                     jnp.float32))
                 out, matrix = ssd(x, step, rate, b, q, matrix, counts,
-                                  self.SSM_CHUNK)
+                                  self.SSM_CHUNK, kernel=arm == "kernel")
                 out = out + x * params[f"{ssm}/skip"].astype(
                     jnp.float32)[:, None]
             # the gate, THEN the norm, a group's channels at a time, float32

@@ -45,6 +45,12 @@ def short_conv(x: Array, kernel: Array, state: Array | None = None,
                for k in range(taps))
     if counts is None:
         return conv, held[:, t:]
+    if t == 1:
+        # one position, real or a pad (a decode round's lane that holds no
+        # request): a select; the gather below costs a round 55 us a layer
+        # on the chip (PERF.md section 6, PR 58)
+        return conv, jnp.where((counts > 0)[:, None, None], held[:, 1:],
+                               held[:, :-1])
     # the real positions end at index counts + K - 2 of ``held``
     at = counts[:, None] + jnp.arange(taps - 1)[None, :]
     return conv, jnp.take_along_axis(held, at[:, :, None], axis=1)

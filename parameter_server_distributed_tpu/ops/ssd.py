@@ -24,7 +24,11 @@ cumulative log-decays, G_i - G_j with j <= i, never positive (as
 ``linear_attention.py``'s ``between``): dividing by a cumulative decay
 overflows as soon as a head forgets fast.  A decode round's single token
 runs the recurrence as written, elementwise on the state as it lies: one
-pass that decays and writes it, one reduction over its last axis.
+pass that decays and writes it, one reduction over its last axis; or, where
+the caller says so (``models/transformer.round_arm`` holds the rule), the
+same products and sums through the kernel of ``pallas/ssd_decode.py``,
+which moves the matrices of the rows that have a real position and of no
+other.
 
 Its neighbours are ``linear_attention.py`` (a constant decay a head, a
 square state, a key a head) and the scalar-decay arm of
@@ -50,13 +54,16 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 def ssd(x: Array, dt: Array, a: Array, b: Array, c: Array,
         state: Array | None = None, counts: Array | None = None,
-        chunk: int = 256) -> tuple[Array, Array]:
+        chunk: int = 256, kernel: bool = False) -> tuple[Array, Array]:
     """x [B, T, H, P] at T consecutive positions; ``dt`` [B, T, H] float32
     steps (> 0); ``a`` [H] float32 rates (< 0); b, c [B, T, G, N], G
     groups of H / G neighbouring heads; ``state`` [B, H, P, N] float32
     holds the positions before them (zeros where None); ``counts`` [B] how
-    many of the T are real (all where None).  Returns (y [B, T, H, P]
-    float32, without the skip; the state after the last real position)."""
+    many of the T are real (all where None); ``kernel``: a single position
+    (T = 1) goes through ops/pallas/ssd_decode.py, and a row whose one
+    position is a pad then reads y = 0 (the plain pass reads its unchanged
+    state).  Returns (y [B, T, H, P] float32, without the skip; the state
+    after the last real position)."""
     batch, t, heads, dim = x.shape
     groups, width = b.shape[2:]
     # a group's key and query meet its heads: heads [G, H / G] throughout
@@ -88,6 +95,14 @@ def ssd(x: Array, dt: Array, a: Array, b: Array, c: Array,
 
     if t == 1:
         with jax.named_scope("state"):
+            if kernel:
+                from .pallas.ssd_decode import ssd_decode
+
+                return heads_again(*ssd_decode(
+                    written[:, 0].reshape(batch, heads, dim),
+                    jnp.exp(fall[:, 0]).reshape(batch, heads), b[:, 0],
+                    c[:, 0], state.reshape(batch, heads, dim, width),
+                    counts > 0))
             return heads_again(*_one_position(
                 written[:, 0], fall[:, 0], b[:, 0], c[:, 0], state))
     chunks = (t + pad) // chunk

@@ -113,17 +113,20 @@ def _held(compiled, cache):
 
 
 def _take_arm(monkeypatch, arm):
-    """Force what ``transformer.full_decode_arm`` answers on this CPU:
-    ``kernel`` makes the backend a TPU's and sends the kernel of
-    ops/pallas/full_decode.py through Mosaic for the described chip;
-    ``plain`` leaves the einsums.  (A model a fixture shares keeps its
-    traced runners: they are dropped, or the second arm would be handed
-    the first one's program.)"""
+    """Force what ``transformer.round_arm`` answers on this CPU:
+    ``kernel`` makes the backend a TPU's and sends the kernels of
+    ops/pallas/full_decode.py and ops/pallas/ssd_decode.py through Mosaic
+    for the described chip; ``plain`` leaves the einsums and the
+    elementwise pass.  (A model a fixture shares keeps its traced runners:
+    they are dropped, or the second arm would be handed the first one's
+    program.)"""
     from parameter_server_distributed_tpu.models import transformer
-    from parameter_server_distributed_tpu.ops.pallas import full_decode
+    from parameter_server_distributed_tpu.ops.pallas import (full_decode,
+                                                              ssd_decode)
 
     monkeypatch.setattr(generation, "_RUNNERS", type(generation._RUNNERS)())
     monkeypatch.setattr(full_decode, "interpret_mode", lambda *_: False)
+    monkeypatch.setattr(ssd_decode, "interpret_mode", lambda *_: False)
     monkeypatch.setattr(transformer, "_kernel_backend",
                         lambda: arm == "kernel")
 
@@ -477,12 +480,16 @@ def test_granites_round_updates_its_matrices_in_one_pass_where_they_lie(
     where they lie and nothing as large as one of them is copied, sliced or
     turned around; the single token takes the one-position recurrence (no
     cumulative sum, no loop), and a layer's decay, write and read are ONE
-    fusion over its matrix: a round moves a state once each way.  A layer's
-    shift register is rewritten whole by a round (1.7 MB a layer) and the
-    compiler may stage it, as the delta-rule models': copies of exactly a
-    register's size are let through.  ``kernel``: the attention layer (8
-    K/V heads of 64, two a row: LFM2's rows) through
-    ops/pallas/full_decode.py."""
+    pass over its matrix: a round moves a state once each way.  ``plain``:
+    one fusion a layer over the matrix of every lane.  ``kernel``: one
+    call a layer of ops/pallas/ssd_decode.py, which takes the matrix in
+    and gives it back as the SAME buffer (the call's own alias) and moves
+    the lanes that hold a request alone; no fusion over a matrix is left;
+    the attention layer (8 K/V heads of 64, two a row: LFM2's rows) goes
+    through ops/pallas/full_decode.py.  A layer's shift register is
+    rewritten whole by a round (1.7 MB a layer) and the compiler may stage
+    it, as the delta-rule models': copies of exactly a register's size are
+    let through."""
     _take_arm(monkeypatch, arm)
     with open(os.path.join(ROOT, "perfbench", "configs",
                            "granite-4.0-h-micro.json")) as handle:
@@ -514,12 +521,21 @@ def test_granites_round_updates_its_matrices_in_one_pass_where_they_lie(
     assert temporaries < cache_bytes / 4
     text = compiled.as_text()
     assert "cumsum" not in text and " while(" not in text
-    # one fusion a layer takes a matrix in and gives it back
+    # one operation a layer takes a matrix in and gives it back
     entry = text.split("\nENTRY")[1]
-    updates = [line for line in entry.splitlines()
+    fusions = [line for line in entry.splitlines()
                if " fusion(" in line and "f32[64,64,64,128]" in line]
-    assert len(updates) == 9
+    calls = [line for line in entry.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "f32[64,64,64,128]" in line]
+    updates = calls if arm == "kernel" else fusions
+    assert (len(fusions), len(calls)) == ((0, 9) if arm == "kernel"
+                                          else (9, 0))
     assert all("attn/linear/ssd" in line for line in updates)
+    # the kernel's matrix operand (the seventh, behind the lanes' order
+    # and count, the columns, the key and the query) is its second result
+    assert all("output_to_operand_aliasing={{1}: (6, {})}" in line
+               for line in calls)
     assert _full_kernels(compiled) == (arm == "kernel")
 
 

@@ -314,11 +314,15 @@ def test_an_extension_against_a_restored_row_and_snapshot(small):
     restores the attention layer's K/V by position and three layers' two
     states at the node's end and forwards only the turn; the node's logits
     are the reference's over the uncut sequence and every served token its
-    argmax.  The ssm layers count into the shared state counter."""
+    argmax.  The ssm layers count into the shared state counter: the lanes
+    a round decoded for (one here: each request is served alone, eleven
+    rounds after its first token) and not the idle ones beside them, which
+    ``serve.linear.state_places`` holds too."""
     rng = np.random.default_rng(5)
     system = rng.integers(0, 512, 50)
     turn = np.concatenate([system, rng.integers(0, 512, 21)])
-    before, = _counters("serve.linear.state_updates")
+    before = _counters("serve.linear.state_updates",
+                       "serve.linear.state_places")
     srv, (_, served) = _served(small, [system, turn])
     stats = srv.stats
     assert stats["prefix_hits"] == 1 and stats["prefill_tokens"] == 50 + 21
@@ -334,8 +338,11 @@ def test_an_extension_against_a_restored_row_and_snapshot(small):
     assert _apart(node.last, logits[70]) < CLOSE
     assert stats["cache_full_bytes"] == 4 * 2 * 128 * 24 * 4
     assert stats["cache_state_bytes"] == 4 * 3 * (3 * 128 + 12 * 8 * 16) * 4
-    after, = _counters("serve.linear.state_updates")
-    assert after > before and (after - before) % (4 * 3) == 0
+    after = _counters("serve.linear.state_updates",
+                      "serve.linear.state_places")
+    assert srv.stats["steps"] == 2 * 11
+    assert after[0] - before[0] == 2 * 11 * 1 * 3
+    assert after[1] - before[1] == 2 * 11 * 4 * 3
 
 
 def test_a_snapshot_stored_evicted_and_restored_under_another_slot(small):
@@ -380,6 +387,176 @@ def test_a_snapshot_stored_evicted_and_restored_under_another_slot(small):
     served = srv.run_to_completion()[rid]
     logits = _reference_logits(small, np.concatenate([again, served]))
     assert served == np.argmax(logits[71:77], -1).tolist()
+
+
+# ------------------------------------------------------- the round's mask
+@pytest.fixture(scope="module")
+def registers():
+    """The tiny copy at a state of 128: a head's matrix [8, 128] is whole
+    registers, the shapes ops/pallas/ssd_decode.py takes."""
+    return _small(mamba_d_state=128)
+
+
+ARMS = ["plain", "kernel"]
+
+
+def _take_round_arm(monkeypatch, arm):
+    """What ``transformer.round_arm`` answers for an ssm layer's token on
+    this CPU: ``kernel`` makes the backend a TPU's (the kernel runs
+    interpreted); a shared model's traced runners are dropped."""
+    monkeypatch.setattr(generation, "_RUNNERS", type(generation._RUNNERS)())
+    monkeypatch.setattr(transformer, "_kernel_backend",
+                        lambda: arm == "kernel")
+    assert transformer.round_arm("ssm", (3, 1, 12, 8), (3, 12, 8, 128)) == arm
+
+
+def _states_of(srv, slot):
+    """Every ssm layer's (register, matrix) of a slot, on the host."""
+    return [np.asarray(part[slot]) for layer in srv._cache.state
+            for part in layer]
+
+
+@pytest.mark.parametrize("arm", ARMS)
+def test_a_retired_lanes_states_stay_until_its_next_request(
+        registers, monkeypatch, arm):
+    """Two requests decode, the short one ends: over the later rounds its
+    lane's registers and matrices are bit for bit what they were when it
+    ended (the other lane's move on), and the request admitted into that
+    lane afterwards decodes the reference's tokens, as the long one does."""
+    _take_round_arm(monkeypatch, arm)
+    _, model, params, _ = registers
+    rng = np.random.default_rng(11)
+    short, long, later = (rng.integers(0, 512, n) for n in (9, 13, 17))
+    srv = serving.DecodeServer(model, params, slots=3, max_len=128)
+    first = srv.submit(short, max_new_tokens=4)
+    second = srv.submit(long, max_new_tokens=24)
+    while first not in srv.finished():
+        srv.step()
+    assert srv._slot[0] is None and srv._slot[1] is not None
+    srv.step()          # the round in flight when it ended decoded for it
+    ended = _states_of(srv, 0)
+    other = _states_of(srv, 1)
+    for _ in range(6):
+        srv.step()
+    assert all(np.array_equal(a, b)
+               for a, b in zip(_states_of(srv, 0), ended))
+    assert not any(np.array_equal(a, b)
+                   for a, b in zip(_states_of(srv, 1), other))
+    third = srv.submit(later, max_new_tokens=8)
+    assert srv._slot[0] is not None
+    served = srv.run_to_completion()
+    for rid, prompt in ((second, long), (third, later)):
+        logits = _reference_logits(registers, np.concatenate(
+            [prompt, served[rid]]))
+        at = len(prompt) - 1
+        assert served[rid] == np.argmax(
+            logits[at:at + len(served[rid])], -1).tolist()
+
+
+@pytest.mark.parametrize("arm", ARMS)
+def test_step_many_agrees_with_step_and_leaves_an_idle_lane(
+        registers, monkeypatch, arm):
+    """Fused rounds take the same mask, constant over their rounds: the
+    tokens are a step() loop's, and the lane of a request that ended before
+    them keeps its states through the fused block."""
+    _take_round_arm(monkeypatch, arm)
+    _, model, params, _ = registers
+    rng = np.random.default_rng(12)
+    prompts = [rng.integers(0, 512, n) for n in (7, 11, 15)]
+    budgets = (2, 18, 18)
+
+    def server():
+        srv = serving.DecodeServer(model, params, slots=4, max_len=128)
+        rids = [srv.submit(prompt, max_new_tokens=budget)
+                for prompt, budget in zip(prompts, budgets)]
+        while rids[0] not in srv.finished():
+            srv.step()
+        srv.land()
+        return srv, rids
+
+    stepped, rids = server()
+    want = stepped.run_to_completion()
+    fused, rids = server()
+    ended = _states_of(fused, 0)
+    emitted = fused.step_many(8)
+    assert len(emitted) == 2 * 8
+    assert all(np.array_equal(a, b)
+               for a, b in zip(_states_of(fused, 0), ended))
+    while not fused.idle:
+        fused.step_many(8)
+    got = {rid: fused.result(rid) for rid in rids}
+    assert got == {rid: want[rid] for rid in rids}
+
+
+@pytest.mark.parametrize("arm", ARMS)
+def test_the_state_counters_follow_the_lanes_that_decode(
+        registers, monkeypatch, arm):
+    """A landed round adds its live lanes x the three ssm layers to
+    ``serve.linear.state_updates`` and slots x layers to
+    ``serve.linear.state_places``, under either arm (the plain pass leaves
+    an idle lane's states as they are too)."""
+    _take_round_arm(monkeypatch, arm)
+    _, model, params, _ = registers
+    rng = np.random.default_rng(13)
+    srv = serving.DecodeServer(model, params, slots=4, max_len=128)
+    names = ("serve.linear.state_updates", "serve.linear.state_places")
+    before = _counters(*names)
+    for n, budget in ((6, 6), (8, 3)):
+        srv.submit(rng.integers(0, 512, n), max_new_tokens=budget)
+    srv.run_to_completion()
+    moved = [b - a for a, b in zip(before, _counters(*names))]
+    # five rounds for the first request, the first two of them for both
+    assert srv.stats["steps"] == 5
+    assert moved == [(5 + 2) * 3, 5 * 4 * 3]
+
+
+def _round_as_it_was(model, top_k=0, top_p=0.0):
+    """The step program of PR 57's ``serving._step_runner``, written out:
+    no mask anywhere."""
+    @functools.partial(jax.jit, donate_argnums=(3,))
+    def run(params, prev, fresh, cache, lengths, temps, rng):
+        routed: list = []
+        selected: list = []
+        tokens = jnp.where(fresh < 0, prev, fresh)
+        logits, cache = generation.decode_block(
+            model, params, tokens[:, None], cache, lengths=lengths,
+            route_stats=routed, sparse_stats=selected)
+        with jax.named_scope("sample"):
+            rng, sub = jax.random.split(rng)
+            nxt = generation.sample_token_rowwise(logits[:, 0], sub, temps,
+                                                  top_k, top_p)
+        counted = (jnp.concatenate(routed) if routed else None,
+                   sum(selected) if selected else None)
+        return nxt, cache, rng, counted
+
+    return run
+
+
+@pytest.mark.parametrize("name", ["gpt2-medium", "olmo-hybrid-7b-16l",
+                                  "granite-4.0-h-micro"])
+def test_only_a_model_with_an_ssm_layer_traces_the_mask(name):
+    """GPT-2's step and a state model's (gdn layers beside softmax ones)
+    are the programs they were, equation for equation: the mask enters a
+    round only where a layer reads it.  Granite's is not."""
+    from perfbench import families
+
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           name + ".json")) as handle:
+        config = json.load(handle)
+    family = families.of(config)
+    model = family.model(family.tiny(config))
+    slots = 3
+    shapes = jax.eval_shape(lambda: (
+        family.make_weights(model, 1), jnp.zeros((slots,), jnp.int32),
+        jnp.zeros((slots,), jnp.int32),
+        generation.init_cache(model, slots, 64),
+        jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), jnp.float32),
+        jax.random.key(0)))
+    now = str(jax.make_jaxpr(serving._step_runner(
+        model, slots, 0, 0.0, "native"))(*shapes))
+    was = str(jax.make_jaxpr(_round_as_it_was(model))(*shapes))
+    assert bool(serving._mask_layers(model)) == (name == "granite-4.0-h-micro")
+    assert (now == was) == (name != "granite-4.0-h-micro")
 
 
 # ------------------------------------------------------- the scores' scale

@@ -38,7 +38,7 @@ import numpy as np
 
 from ..obs import stats as obs_stats
 from . import transformer as _transformer
-from .transformer import RECURRENT_MIXERS, STATE_MIXERS, Transformer
+from .transformer import STATE_MIXERS, Transformer
 
 Array = jax.Array
 
@@ -200,35 +200,12 @@ def ring_layers_of(model: Transformer, max_len: int) -> tuple[int, ...]:
 
 def state_shape(model: Transformer) -> tuple[tuple, ...]:
     """What ONE slot keeps of each of the model's state layers, in layer
-    order: a tuple of (shape, dtype) a layer.  A linear layer's [H, D, D]
-    float32; a conv layer's last ``conv_kernel - 1`` gated inputs [K - 1,
-    d_model] in the model's dtype; a kda layer's two, the last
-    ``conv_kernel - 1`` inputs of its three convolutions [K - 1, 3 *
-    attn_dim] in the model's dtype and its matrix [H, D, D] float32; a gdn
-    layer's the same two at its own sizes, [K - 1, H * (2 Dk + Dv)] and
-    [H, Dk, Dv]; an ssm layer's register [K - 1, H P + 2 G N] and matrix
-    [H, P, N] float32 (at 64 heads of 64 and a state of 128 the last axis
-    is whole registers: nothing is padded).
-
-    The matrix lies by head as the delta rule takes it, whatever its sizes.
-    Where Dv fills no whole registers (192 of 256 lanes) the device pads
-    it, a third more bytes a round; the shapes that would not be padded
-    ([Dk, H * Dv], [H, Dk * Dv]) cost more than they save in plain XLA: a
-    round then spreads k and q over the value lanes as arrays of the
-    state's own size (523 to 560 MB moved a layer against 112 at 12 lanes x
-    30 heads x [96, 192], compiled for a v5e; PERF.md section 6, PR 50)."""
+    order: a tuple of (shape, dtype) a layer, as the layer's kind says
+    (``Mixer.state``, models/mixers.py: a matrix a head in float32, the
+    register of a short convolution's last ``conv_kernel - 1`` inputs in
+    the model's dtype, or both)."""
     c = model.config
-    matrix = ((c.n_heads, c.head_dim, c.head_dim), jnp.float32)
-    keys, values = c.delta_dims
-    kinds = {"linear": (matrix,),
-             "conv": (((c.conv_kernel - 1, c.d_model), c.dtype),),
-             "kda": (((c.conv_kernel - 1, 3 * c.attn_dim), c.dtype), matrix),
-             "gdn": (((c.conv_kernel - 1, c.n_heads * (2 * keys + values)),
-                      c.dtype), ((c.n_heads, keys, values), jnp.float32)),
-             "ssm": (((c.conv_kernel - 1, c.ssm_dims[1]), c.dtype),
-                     ((c.ssm_heads, c.ssm_head_dim, c.ssm_state),
-                      jnp.float32))}
-    return tuple(kinds[c.layer_spec(i).mixer] for i in c.state_layers)
+    return tuple(c.layer_spec(i).kind.state(c) for i in c.state_layers)
 
 
 @jax.tree_util.register_dataclass
@@ -273,7 +250,7 @@ def init_cache(model: Transformer, batch: int, max_len: int,
     rings = ring_layers_of(model, max_len)
     pack = heads_per_row(c.kv_heads, c.head_dim)
     sparse, states = c.layers_of("sparse"), c.state_layers
-    latents = c.layers_of("latent")
+    latents = c.layers_keeping("latent")
 
     def parts(count: int, positions: int, dtype) -> tuple:
         # GQA: the cache stores kv_heads (< n_heads) — n_heads/kv_heads x
@@ -364,14 +341,14 @@ def _seeded(part: Array, block: Array) -> Array:
 
 def check_rolls_back(model: Transformer) -> None:
     """Speculative decoding rolls rejected positions back by moving the
-    cache's length; a linear, conv, kda, gdn or ssm layer's states have no
+    cache's length; a state layer's states (``STATE_MIXERS``) have no
     length to move."""
     if model.config.state_layers:
+        kinds = ", ".join(STATE_MIXERS[:-1]) + " or " + STATE_MIXERS[-1]
         raise ValueError(
-            "speculative decoding rolls rejected positions back, and a "
-            "linear, conv, kda, gdn or ssm layer's state cannot be rolled "
-            "back: "
-            "decode a model with such layers without a draft")
+            f"speculative decoding rolls rejected positions back, and a "
+            f"{kinds} layer's state cannot be rolled back: "
+            f"decode a model with such layers without a draft")
 
 
 def check_position_budget(model: Transformer, prompt_len: int,
@@ -575,7 +552,7 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
                     q, keys.reshape(by_head), values.reshape(by_head),
                     positions[:, 0], window=spec.window)
             if (not 0 < spec.window < cache.max_len
-                    and _round_arm("full", cache, q.shape,
+                    and _round_arm("softmax", cache, q.shape,
                                    keys) == "kernel"):
                 return _kernel_cache_attention(c, q, keys, values,
                                                positions[:, 0] + 1)
@@ -602,34 +579,33 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
         lp, p = model.layer_view(params, layer)
         spec = c.layer_spec(layer)
         router = model.pre_attention_router(lp, p, spec, h)
-        # where the layer's part lies among its kind's
-        ring, i = ((False, cache.state_layers.index(layer))
-                   if spec.mixer in STATE_MIXERS
-                   else (False, cache.latent_layers.index(layer))
-                   if spec.mixer == "latent" else cache.place(layer))
-        if spec.mixer in ("conv", "latent") + RECURRENT_MIXERS:
+        mixer = spec.kind
+        # where the layer's part lies among those of what it keeps
+        ring, i = (cache.place(layer) if mixer.keeps == "kv" else
+                   (False, getattr(cache, mixer.keeps + "_layers").index(
+                       layer)))
+        if mixer.keeps == "state" and mixer.residual is not None:
+            # the whole branch one method over the layer's states; where
+            # the kind has a kernel for a round, told which form runs
+            states = parts["state"][i]
+            arm = {} if mixer.round_kernel is None else {"arm": _round_arm(
+                # (x [B, T, H, P] against the matrix [B, H, P, N])
+                spec.mixer, cache, (batch, t, *states[-1].shape[1:3]),
+                states[-1])}
             with jax.named_scope("cache_attn"):
-                if spec.mixer == "conv":
-                    h, state = model.conv_residual(
-                        lp, p, h, parts["state"][i][0], counts)
-                    parts["state"][i] = (state,)
-                elif spec.mixer == "ssm":
-                    h, parts["state"][i] = model.ssm_residual(
-                        lp, p, h, parts["state"][i], counts, _round_arm(
-                            "ssm", cache,
-                            (batch, t, c.ssm_heads, c.ssm_head_dim),
-                            parts["state"][i][1]))
-                elif spec.mixer in RECURRENT_MIXERS:
-                    h, parts["state"][i] = model.recurrent_residual(spec)(
-                        lp, p, h, parts["state"][i], counts)
-                else:
-                    with jax.named_scope("attn"), jax.named_scope("latent"):
-                        q, rows = model.latent_rows(lp, p, h, positions)
-                        with jax.named_scope("cache_update"):
-                            held = parts["latent"][i] = written(
-                                parts["latent"][i], rows)
-                        h = model.latent_out(lp, p, h, _latent_cache_attention(
-                            model, lp, p, q, held, positions, masks[0]))
+                h, parts["state"][i] = getattr(model, mixer.residual)(
+                    lp, p, h, states, counts, **arm)
+            h = ffn(layer, spec, h, router)
+            continue
+        if mixer.keeps == "latent":
+            with jax.named_scope("cache_attn"), jax.named_scope("attn"), \
+                    jax.named_scope("latent"):
+                q, rows = model.latent_rows(lp, p, h, positions)
+                with jax.named_scope("cache_update"):
+                    held = parts["latent"][i] = written(
+                        parts["latent"][i], rows)
+                h = model.latent_out(lp, p, h, _latent_cache_attention(
+                    model, lp, p, q, held, positions, masks[0], cache))
             h = ffn(layer, spec, h, router)
             continue
         q, k, v = model.qkv(lp, p, h, positions, spec)  # k/v: [B, T, KV, D]
@@ -678,8 +654,8 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
 
 def _latent_cache_attention(model: Transformer, params, prefix: str,
                             q: Array, rows: Array, positions: Array,
-                            mask: Array) -> Array:
-    """A latent layer against its part of the cache.  q [B, T, H, head_dim
+                            mask: Array, cache) -> Array:
+    """A latent layer against its part of ``cache``.  q [B, T, H, head_dim
     + qk_shared] at ``positions`` [B, T]; rows [B, M, latent_row] with
     the block already written; ``mask`` the causal mask
     [B or 1, 1, 1, T, M].  Two forms of one attention.  A long block
@@ -694,8 +670,9 @@ def _latent_cache_attention(model: Transformer, params, prefix: str,
     scores and the weighted sum are taken against the rows as they lie
     (``cache``), and the sum comes back through the head's value matrix.
     Which implementation takes the absorbed queries is
-    ``transformer.latent_decode_arm``'s to say: a round's single token a
-    lane on a TPU the kernel of ops/pallas/latent_decode.py (under
+    ``transformer.round_arm``'s to say, over as many devices as the cache
+    is spread (:func:`_round_arm`): a round's single token a
+    lane on one TPU device the kernel of ops/pallas/latent_decode.py (under
     ``attn_kernel``: every LIVE position's row is read once for all heads
     and the positions past a lane's length are not read at all); else
     plain XLA, where the part is read whole, once for the scores and once
@@ -720,7 +697,7 @@ def _latent_cache_attention(model: Transformer, params, prefix: str,
                                       - c.qk_shared,), c.dtype)],
             axis=-1)                                       # [B, T, H, row]
     with jax.named_scope("cache"):
-        if _transformer.latent_decode_arm(wide.shape, rows.shape) == "kernel":
+        if _round_arm("latent", cache, wide.shape, rows) == "kernel":
             from ..ops.pallas import latent_decode
 
             with jax.named_scope("attn_kernel"):
@@ -859,7 +836,7 @@ def full_round_block(model: Transformer, cache, lanes: int) -> int:
     parts = [part for i, part in enumerate(cache.k)
              if not (isinstance(cache, KVCache) and cache.by_head(i))]
     if not parts or _round_arm(
-            "full", cache, (lanes, 1, c.n_heads, c.head_dim),
+            "softmax", cache, (lanes, 1, c.n_heads, c.head_dim),
             parts[0]) != "kernel":
         return 0
     from ..ops.pallas import full_decode
@@ -874,7 +851,7 @@ def _kernel_cache_attention(c, q: Array, keys: Array, values: Array,
                             lengths: Array) -> Array:
     """A decode round's single token a lane, q [B, 1, H, D], against a
     full layer's parts [B, M, KV / pack, pack * D] through the kernel of
-    ops/pallas/full_decode.py (``transformer.full_decode_arm`` says when):
+    ops/pallas/full_decode.py (``transformer.round_arm`` says when):
     K and V are read a block of positions at a time, once, and no block
     past a lane's ``lengths`` (its live positions, the new token included)
     is fetched.  The queries meet a row of ``pack`` heads as

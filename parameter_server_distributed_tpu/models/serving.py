@@ -56,7 +56,7 @@ from .generation import (KVCache, QuantKVCache, _cached_runner,
                          init_cache, pack_heads, ring_layers_of, sample_token,
                          sample_token_rowwise, split_row, state_shape)
 from .prefix_tree import PrefixTree, RowRef
-from .transformer import RECURRENT_MIXERS, Transformer
+from .transformer import Transformer
 
 Array = jax.Array
 
@@ -109,7 +109,8 @@ def _row_tail(model: Transformer, row) -> tuple[tuple, tuple]:
     every state of every state layer a leaf of its own (their shapes may
     differ: :func:`state_shape`)."""
     tail = list(row[2:])
-    latent = tuple(tail.pop(0)) if model.config.layers_of("latent") else ()
+    latent = (tuple(tail.pop(0)) if model.config.layers_keeping("latent")
+              else ())
     states = []
     for layer in state_shape(model):
         states.append(tuple(tail[:len(layer)]))
@@ -183,7 +184,8 @@ def _shard_cache(cache, mesh):
 
 
 def _builds_few(model: Transformer) -> bool:
-    """Whether the server keeps this model's admission programs FEW: a model
+    """Whether the server keeps this model's admission programs FEW
+    (``Mixer.few_programs`` of any of its layers' kinds): a model
     with recurrent layers (kda, gdn, ssm), whose every program is all its
     layers unrolled around a chunked recurrence (10 to 20 s of the
     compiler's time
@@ -198,8 +200,7 @@ def _builds_few(model: Transformer) -> bool:
     (:func:`_suffix_floor`), built ahead (``DecodeServer._build_ahead``),
     and one prefill program for every prompt of a chunk or more
     (:func:`_prefills_whole`)."""
-    return any(spec.mixer in RECURRENT_MIXERS + ("latent",)
-               for spec in model.config.specs)
+    return any(spec.kind.few_programs for spec in model.config.specs)
 
 
 def _suffix_floor(model: Transformer) -> int:
@@ -248,7 +249,7 @@ def _prefill_runner(model: Transformer, bucket: int, cache_dtype: str):
                     h, real_len - 1, 1, axis=1))[0, 0]      # [vocab]
             c = model.config
             pack = heads_per_row(c.kv_heads, c.head_dim)
-            states, latents = c.state_layers, c.layers_of("latent")
+            states, latents = c.state_layers, c.layers_keeping("latent")
             kvs = [kv for i, kv in enumerate(kept)
                    if i not in states + latents]
             if not kvs:
@@ -345,7 +346,7 @@ def _row_cache(model: Transformer, row, total: int, cache_dtype: str):
     k, v = row[:2]
     latent, state = _row_tail(model, row)
     sparse, states = c.layers_of("sparse"), c.state_layers
-    latents = c.layers_of("latent")
+    latents = c.layers_keeping("latent")
     kept = [i for i in range(c.n_layers) if i not in states + latents]
 
     def stored(layers) -> tuple:
@@ -445,15 +446,9 @@ def _prefills_whole(model: Transformer, bucket: int) -> bool:
         c.d_ff if spec.ffn == "mlp" else c.moe_top_k * (
             c.expert_width if spec.ffn == "experts" else c.d_ff)
         for spec in c.specs] + [
-        # (a kda or gdn layer's q, k and v go through its convolutions
-        # side by side)
-        3 * c.attn_dim for spec in c.specs if spec.mixer == "kda"] + [
-        c.n_heads * (2 * c.delta_dims[0] + c.delta_dims[1])
-        for spec in c.specs if spec.mixer == "gdn"] + [
-        # (an ssm layer's gate, convolution channels and steps leave one
-        # projection side by side)
-        sum(c.ssm_dims) + c.ssm_heads
-        for spec in c.specs if spec.mixer == "ssm"])
+        # (a recurrent layer's channels through its convolution, side by
+        # side: ``Mixer.widest``)
+        spec.kind.widest(c) for spec in c.specs])
     return bucket * widest <= _PREFILL_WHOLE
 
 
@@ -487,7 +482,7 @@ def _empty_row_runner(model: Transformer, total: int):
     def build():
         c = model.config
         pack = heads_per_row(c.kv_heads, c.head_dim)
-        latents = c.layers_of("latent")
+        latents = c.layers_keeping("latent")
         kv = jnp.zeros((c.n_layers - len(c.state_layers) - len(latents), 16,
                         c.kv_heads // pack, pack * c.head_dim), c.dtype)
         tail = [jnp.zeros((len(latents), 16, c.latent_row),
@@ -548,12 +543,16 @@ _IDLE = -2
 
 def _mask_layers(model: Transformer) -> tuple[int, ...]:
     """The layers of ``model`` that read which lanes of a decode round hold
-    a request (``decode_block``'s ``counts``: 1 live, 0 idle): today the
-    ``ssm`` layers, whose states of an idle lane then stay where they are,
-    the matrix unread (ops/pallas/ssd_decode.py).  A round of a model with
-    such a layer is told; the host always knows the mask, and it enters a
-    traced program only where this says so."""
-    return tuple(model.config.layers_of("ssm"))
+    a request (``decode_block``'s ``counts``: 1 live, 0 idle): those whose
+    kind has a round kernel that moves live lanes alone
+    (``RoundKernel.live_lanes``; ops/pallas/ssd_decode.py), whose states of
+    an idle lane then stay where they are, the matrix unread.  A round of a
+    model with such a layer is told; the host always knows the mask, and it
+    enters a traced program only where this says so."""
+    c = model.config
+    kernels = (c.layer_spec(i).kind.round_kernel for i in range(c.n_layers))
+    return tuple(i for i, kernel in enumerate(kernels)
+                 if kernel is not None and kernel.live_lanes)
 
 
 def _decode_round(model, top_k, top_p, params, tokens, cache, lengths,
@@ -716,18 +715,16 @@ class DecodeServer:
         # the layers whose state is a snapshot (good at one depth only):
         # they decide what the prefix tree may match and what cannot be
         # rolled back
-        self._linear_layers = len(config.layers_of("linear")
-                                  + config.layers_of("kda")
-                                  + config.layers_of("gdn")
-                                  + config.layers_of("ssm"))
-        self._state_layers = self._linear_layers + len(
-            config.layers_of("conv"))
+        self._state_layers = len(config.state_layers)
+        # those of them that keep a matrix a head (``serve.linear.*``)
+        self._linear_layers = sum(config.layer_spec(i).kind.matrix
+                                  for i in config.state_layers)
         # a round tells the model which lanes hold a request where a layer
-        # reads it: the ssm layers, which then leave an idle lane's states
-        # as they are
+        # reads it, and such a layer leaves an idle lane's states as they
+        # are
         self._masked_layers = len(_mask_layers(model))
         self._sparse_layers = len(config.layers_of("sparse"))
-        self._latent_layers = len(config.layers_of("latent"))
+        self._latent_layers = len(config.layers_keeping("latent"))
         if draft is not None:
             check_rolls_back(model)
             check_rolls_back(draft)

@@ -36,6 +36,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..ops.pallas import ATTN_KERNEL_KEPT
+from .mixers import MIXERS, Mixer
 from .moe import moe_expert_weight_spec
 from .quant import QTensor, wdot
 
@@ -43,14 +44,16 @@ Array = jax.Array
 
 
 FFN_KINDS = ("mlp", "moe", "experts")
-MIXER_KINDS = ("softmax", "sparse", "linear", "conv", "kda", "latent", "gdn",
-               "ssm")
+# What a kind of mixer is stands in models/mixers.py, a record each; the
+# three names below are read off that table
+MIXER_KINDS = tuple(MIXERS)
 # the mixers that keep a fixed-size STATE in a decode cache and no K/V
-STATE_MIXERS = ("linear", "conv", "kda", "gdn", "ssm")
+STATE_MIXERS = tuple(kind for kind, mixer in MIXERS.items()
+                     if mixer.keeps == "state")
 # the recurrent mixers behind a short convolution (the two delta rules and
-# the state-space layer): a convolution's register and a matrix a layer,
-# the whole branch one function (Transformer.recurrent_residual)
-RECURRENT_MIXERS = ("kda", "gdn", "ssm")
+# the state-space layer): a convolution's register and a matrix a layer
+RECURRENT_MIXERS = tuple(kind for kind, mixer in MIXERS.items()
+                         if mixer.recurrent)
 # jax.ad_checkpoint name of a layer's mixer branch as it joins the residual
 # stream (Transformer._residual); Transformer._remat_policy may keep it
 MIXER_OUT = "mixer_out"
@@ -190,7 +193,7 @@ class LayerSpec:
         if self.qk_norm not in (False, True, "all"):
             raise ValueError(f"qk_norm is False, True (a head) or 'all', "
                              f"got {self.qk_norm!r}")
-        if (self.mixer in ("conv", "latent") + RECURRENT_MIXERS
+        if (self.kind.residual is not None
                 and (self.kv_heads or self.qk_norm or self.gate
                      or self.out_norm)):
             raise ValueError(
@@ -201,6 +204,11 @@ class LayerSpec:
                 "softmax, sparse and linear layers")
         if self.window < 0:
             raise ValueError(f"window must be >= 0, got {self.window}")
+
+    @property
+    def kind(self) -> Mixer:
+        """What the layer's mixer IS: its record of models/mixers.py."""
+        return MIXERS[self.mixer]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -610,11 +618,16 @@ class TransformerConfig:
         return tuple(i for i in range(self.n_layers)
                      if self.layer_spec(i).mixer == mixer)
 
+    def layers_keeping(self, keeps: str) -> tuple[int, ...]:
+        """The layers whose mixer keeps ``keeps`` in a decode cache
+        (``Mixer.keeps``: ``kv``, ``state`` or ``latent``), in order."""
+        return tuple(i for i in range(self.n_layers)
+                     if self.layer_spec(i).kind.keeps == keeps)
+
     @property
     def state_layers(self) -> tuple[int, ...]:
         """The layers whose mixer keeps a state and no K/V, in order."""
-        return tuple(i for i in range(self.n_layers)
-                     if self.layer_spec(i).mixer in STATE_MIXERS)
+        return self.layers_keeping("state")
 
 
 def scoped(name: str):
@@ -840,86 +853,46 @@ def device_arm(q_shape: tuple[int, ...], kv_shape: tuple[int, ...],
     return "blockwise" if q_shape[1] >= blockwise_from else "dense"
 
 
-# A decode round's layers that have a kernel of their own, by kind: the
-# module of ops/pallas that holds it, what the plain form is called, and the
-# shapes its ``fits`` takes, made of the chooser's (q, part); None: no fit.
-_ROUND_KERNELS = {
-    # q [B, T, H, W] absorbed, as wide as the rows [B, M, W] of its part
-    "latent": ("latent_decode", "dense",
-               lambda q, part: ((q[0],) + tuple(q[2:]), part)),
-    # q [B, T, H, D] against K or V [B, M, KV / pack, pack * D]: the query
-    # rows a row of heads meets are H / KV' (pack * G) of the part's width
-    "full": ("full_decode", "dense",
-             lambda q, part: None if q[2] % part[2] else (
-                 (q[0], part[2], q[2] // part[2], part[3]), part)),
-    # x [B, T, H, P] against the matrix [B, H, P, N]
-    "ssm": ("ssd_decode", "plain", lambda q, part: (q, part)),
-}
-
-
 def round_arm(kind: str, q_shape: tuple[int, ...],
               part_shape: tuple[int, ...], part_dtype=jnp.float32,
               devices: int = 1) -> str:
-    """Which implementation runs a layer of ``kind`` (a key of
-    ``_ROUND_KERNELS``) against its part of a decode cache, beside
-    :func:`device_arm` and by its rule (shapes and the backend, nothing
-    else), ONE rule for every kind:
+    """Which implementation runs a layer of ``kind`` (a mixer whose record
+    has a ``round_kernel``: ``softmax`` for a FULL layer's K or V,
+    ``latent``, ``ssm``) against its part of a decode cache spread over
+    ``devices`` devices, beside :func:`device_arm` and by its rule (shapes
+    and the backend, nothing else); the ONE function that answers for a
+    round, for every kind and every caller:
 
     ``kernel`` — the kind's module of ops/pallas: a decode round's single
                  token a lane (``q_shape[1] == 1``) on ONE TPU device,
                  against an unquantised part whose shape the module's
-                 ``fits`` takes.  ``latent``: every live row read once for
-                 all heads; ``full``: K and V read once, a block of
-                 positions at a time, and no block past a lane's length;
-                 ``ssm``: the matrix of the lanes that decode, updated
-                 where it lies, an idle lane's neither read nor written;
+                 ``fits`` takes (what each kernel reads: the records of
+                 models/mixers.py);
     the plain form (``dense``: the einsums against the part as it lies,
     read whole whatever the lanes hold; ``plain``: ``ops/ssd.py``'s
     elementwise pass over every lane's matrix) — a block of several tokens
     (an extension, a speculative verify), a part spread over several
     devices (GSPMD partitions the plain form; it cannot cut a kernel), an
-    int8 part, any backend but a TPU, any other shape."""
-    module, plain, shapes = _ROUND_KERNELS[kind]
+    int8 part, any backend but a TPU, any other shape.
+
+    A kind whose record says so (``refusal``: the latent kind) REFUSES the
+    one case in which the plain form would stand in for a kernel that could
+    have run: a round's token on one TPU device whose shapes the kernel
+    does not take."""
+    kernel = MIXERS[kind].round_kernel
     if (q_shape[1] != 1 or devices != 1 or not _kernel_backend()
             or not jnp.issubdtype(part_dtype, jnp.floating)):
-        return plain
+        return kernel.plain
     # (pallas is imported where a kernel can run, and only there)
-    fits = importlib.import_module(
-        f"..ops.pallas.{module}", __package__).fits
-    taken = shapes(tuple(q_shape), tuple(part_shape))
-    return "kernel" if taken is not None and fits(*taken) else plain
-
-
-def latent_decode_arm(q_shape: tuple[int, ...],
-                      rows_shape: tuple[int, ...]) -> str:
-    """:func:`round_arm` for a latent layer's ABSORBED queries (q [B, T,
-    H, W], as wide as the rows [B, M, W] of its part of a cache), with one
-    thing of its own: a round's token on ONE TPU device whose shapes the
-    kernel does not take is REFUSED, not sent down the einsums: they cost
-    eight times the kernel a round (4.5 ms a layer against 0.54 at 64
-    lanes x 16,384 positions; PERF.md, PR 47), and a server that slow
-    would only read as a low roofline."""
-    arm = round_arm("latent", q_shape, rows_shape)
-    if arm == "dense" and q_shape[1] == 1 and _kernel_backend():
-        from ..ops.pallas import latent_decode
-
-        raise ValueError(
-            f"a latent layer's decode round on a TPU runs "
-            f"ops/pallas/latent_decode.py, which takes heads in 16s, rows "
-            f"of whole 128-lane registers and a cache of whole blocks of "
-            f"{latent_decode.BLOCK} positions; got queries {q_shape} "
-            f"against rows {rows_shape}")
-    return arm
-
-
-def full_decode_arm(q_shape: tuple[int, ...], part_shape: tuple[int, ...],
-                    part_dtype) -> str:
-    """:func:`round_arm` for a FULL softmax layer's queries (q [B, T, H,
-    D]) against its parts of a cache (K and V, each [B, M, KV / pack,
-    pack * D] of ``part_dtype``) on one device.  Unlike the latent arm it
-    refuses nothing: the einsums are the accepted path wherever the kernel
-    does not run."""
-    return round_arm("full", q_shape, part_shape, part_dtype)
+    module = importlib.import_module(
+        f"..ops.pallas.{kernel.module}", __package__)
+    taken = kernel.shapes(tuple(q_shape), tuple(part_shape))
+    if taken is not None and module.fits(*taken):
+        return "kernel"
+    if kernel.refusal:
+        raise ValueError(f"{kernel.refusal.format(kernel=module)}; got "
+                         f"queries {q_shape} against rows {part_shape}")
+    return kernel.plain
 
 
 def attend_by(arm: str, q: Array, k: Array, v: Array,
@@ -1007,106 +980,16 @@ class Transformer:
         return shapes
 
     def block_shapes(self, spec: LayerSpec) -> dict[str, tuple[int, ...]]:
-        """The weights of one layer of kind ``spec``, by suffix."""
+        """The weights of one layer of kind ``spec``, by suffix: its
+        mixer's (the kind's record, models/mixers.py), then the biases and
+        the feed-forward branch's."""
         c = self.config
-        kv_dim = (spec.kv_heads or c.kv_heads) * c.head_dim
-        if spec.mixer == "conv":
-            # (B, C, x) come from one projection; a kernel tap is a row,
-            # so that it lies along the lanes like the channels it scales
-            block = {"ln1/scale": (c.d_model,),
-                     "conv/in_proj": (c.d_model, 3 * c.d_model),
-                     "conv/kernel": (c.conv_kernel, c.d_model),
-                     "conv/out_proj": (c.d_model, c.d_model),
-                     "ln2/scale": (c.d_model,)}
-        elif spec.mixer == "kda":
-            # the gates' inner width is a head's (the published layer's)
-            rank = c.head_dim
-            block = {"ln1/scale": (c.d_model,),
-                     "attn/wq": (c.d_model, c.attn_dim),
-                     "attn/wk": (c.d_model, c.attn_dim),
-                     "attn/wv": (c.d_model, c.attn_dim),
-                     "attn/conv_q": (c.conv_kernel, c.attn_dim),
-                     "attn/conv_k": (c.conv_kernel, c.attn_dim),
-                     "attn/conv_v": (c.conv_kernel, c.attn_dim),
-                     "attn/decay/wa": (c.d_model, rank),
-                     "attn/decay/wb": (rank, c.attn_dim),
-                     "attn/decay/a_log": (c.n_heads,),
-                     "attn/decay/dt_bias": (c.attn_dim,),
-                     "attn/gate/wa": (c.d_model, rank),
-                     "attn/gate/wb": (rank, c.attn_dim),
-                     "attn/beta/w": (c.d_model, c.n_heads),
-                     "attn/o_norm/scale": (c.head_dim,),
-                     "attn/wo": (c.attn_dim, c.d_model),
-                     "ln2/scale": (c.d_model,)}
-        elif spec.mixer == "gdn":
-            # key heads and value heads of their own sizes; the decay a
-            # head straight from the stream; the output gate full-rank
-            keys, values = (c.n_heads * size for size in c.delta_dims)
-            block = {"ln1/scale": (c.d_model,),
-                     "attn/wq": (c.d_model, keys),
-                     "attn/wk": (c.d_model, keys),
-                     "attn/wv": (c.d_model, values),
-                     "attn/conv_q": (c.conv_kernel, keys),
-                     "attn/conv_k": (c.conv_kernel, keys),
-                     "attn/conv_v": (c.conv_kernel, values),
-                     "attn/decay/w": (c.d_model, c.n_heads),
-                     "attn/decay/a_log": (c.n_heads,),
-                     "attn/decay/dt_bias": (c.n_heads,),
-                     "attn/beta/w": (c.d_model, c.n_heads),
-                     "attn/wz": (c.d_model, values),
-                     "attn/o_norm/scale": (c.delta_dims[1],),
-                     "attn/wo": (values, c.d_model),
-                     "ln2/scale": (c.d_model,)}
-        elif spec.mixer == "ssm":
-            # (z, xBC, dt) come from one projection; a kernel tap is a row
-            inner, conv = c.ssm_dims
-            block = {"ln1/scale": (c.d_model,),
-                     "ssm/in_proj": (c.d_model, inner + conv + c.ssm_heads),
-                     "ssm/conv/kernel": (c.conv_kernel, conv),
-                     "ssm/conv/bias": (conv,),
-                     "ssm/decay/a_log": (c.ssm_heads,),
-                     "ssm/decay/dt_bias": (c.ssm_heads,),
-                     "ssm/skip": (c.ssm_heads,),
-                     "ssm/norm/scale": (inner,),
-                     "ssm/out_proj": (inner, c.d_model),
-                     "ln2/scale": (c.d_model,)}
-        elif spec.mixer == "latent":
-            # wq: every head's query, its own part then the shared one (a
-            # pair with a norm between under ``q_latent``); wkv_a: the
-            # latent and the shared key part; wkv_b: every head's key part
-            # and value from the normed latent
-            q_dim = c.n_heads * (c.head_dim + c.qk_shared)
-            block = {"ln1/scale": (c.d_model,),
-                     **({"attn/wq_a": (c.d_model, c.q_latent),
-                         "attn/q_norm/scale": (c.q_latent,),
-                         "attn/wq_b": (c.q_latent, q_dim)} if c.q_latent
-                        else {"attn/wq": (c.d_model, q_dim)}),
-                     "attn/wkv_a": (c.d_model, c.kv_latent + c.qk_shared),
-                     "attn/kv_norm/scale": (c.kv_latent,),
-                     "attn/wkv_b": (c.kv_latent, 2 * c.attn_dim),
-                     "attn/wo": (c.attn_dim, c.d_model),
-                     "ln2/scale": (c.d_model,)}
-        else:
-            block = {"ln1/scale": (c.d_model,),
-                     "attn/wq": (c.d_model, c.attn_dim),
-                     "attn/wk": (c.d_model, kv_dim),
-                     "attn/wv": (c.d_model, kv_dim),
-                     "attn/wo": (c.attn_dim, c.d_model),
-                     "ln2/scale": (c.d_model,)}
-            if spec.qk_norm == "all":
-                block.update({"attn/q_norm/scale": (c.attn_dim,),
-                              "attn/k_norm/scale": (kv_dim,)})
-            elif spec.qk_norm:
-                block.update({"attn/q_norm/scale": (c.head_dim,),
-                              "attn/k_norm/scale": (c.head_dim,)})
-            if spec.gate:
-                block["attn/wg"] = (c.d_model, c.attn_dim)
-            if spec.out_norm:
-                block["attn/o_norm/scale"] = (c.head_dim,)
+        block = dict(spec.kind.shapes(c, spec))
         if c.norm == "layernorm":
             block["ln1/bias"] = (c.d_model,)
             block["ln2/bias"] = (c.d_model,)
         if c.bias:     # (a model with conv layers has none: __post_init__)
+            kv_dim = (spec.kv_heads or c.kv_heads) * c.head_dim
             block.update({"attn/bq": (c.attn_dim,), "attn/bk": (kv_dim,),
                           "attn/bv": (kv_dim,), "attn/bo": (c.d_model,)})
         if spec.ffn == "mlp":
@@ -1198,26 +1081,11 @@ class Transformer:
             attn_mult = 16.0
             if c.remat_policy == "full":
                 params_mult = 8.0
-        # attention's products, a token: scores and values over S keys of
-        # d_model (a latent layer's: its heads' own width and the shared key
-        # part for the scores, its heads' for the values); a kda layer's are
-        # three products with its [D, D] states (a gdn layer's [Dk, Dv]), an
-        # ssm layer's two with its [P, N], and do not grow with S
-        attn = 0.0
-        for i in range(c.n_layers):
-            mixer = c.layer_spec(i).mixer
-            if mixer == "kda":
-                attn += attn_mult * 1.5 * c.attn_dim * c.head_dim
-            elif mixer == "gdn":
-                attn += attn_mult * 1.5 * c.n_heads * math.prod(c.delta_dims)
-            elif mixer == "ssm":
-                # (two products with its [P, N] states: the write, the read)
-                attn += attn_mult * c.ssm_dims[0] * c.ssm_state
-            elif mixer == "latent":
-                attn += attn_mult * seq * (c.attn_dim
-                                           + c.n_heads * c.qk_shared / 2)
-            else:
-                attn += attn_mult * c.d_model * seq
+        # the mixers' products, a token: scores and values over S keys of
+        # d_model, or what a kind's record says (a recurrent layer's are
+        # products with its states and do not grow with S)
+        attn = sum(attn_mult * c.layer_spec(i).kind.products(c, seq)
+                   for i in range(c.n_layers))
         return params_mult * n_params * seq + attn * seq
 
     def _remat_policy(self):
@@ -1454,16 +1322,18 @@ class Transformer:
         return out if scale == 1.0 else out * scale
 
     def conv_residual(self, params: Mapping[str, Array], prefix: str,
-                      h: Array, state: Array | None = None,
-                      counts: Array | None = None) -> tuple[Array, Array]:
+                      h: Array, state: tuple | None = None,
+                      counts: Array | None = None) -> tuple[Array, tuple]:
         """A ``conv`` layer's whole mixer branch, under ``attn/conv``:
         h + W_out(C * conv(B * x)) with (B, C, x) = split(W_in ln1(h)).
-        h [B, T, d] at T consecutive positions; ``state`` [B, K - 1, d]
-        holds the gated inputs B * x of the K - 1 positions before them
-        (None: the sequence starts here) and ``counts`` [B] says how many
-        of the T are real (None: all).  Returns (new h, the state after
-        the last real position): one function for a whole sequence, a
-        block against a cached state and a decode round's single token."""
+        h [B, T, d] at T consecutive positions; ``state`` is a tuple of
+        ONE array [B, K - 1, d] (the signature every state kind's branch
+        has: ``Mixer.residual``), the gated inputs B * x of the K - 1
+        positions before them (None: the sequence starts here), and
+        ``counts`` [B] says how many of the T are real (None: all).
+        Returns (new h, the state after the last real position): one
+        function for a whole sequence, a block against a cached state and
+        a decode round's single token."""
         from ..ops.short_conv import gated_short_conv
 
         c = self.config
@@ -1471,12 +1341,13 @@ class Transformer:
             x = self._branch_input(params, f"{prefix}/ln1", h)
             bcx = wdot(x, params[f"{prefix}/conv/in_proj"],
                        preferred_element_type=jnp.float32).astype(c.dtype)
-            mixed, state = gated_short_conv(
+            mixed, register = gated_short_conv(
                 *jnp.split(bcx, 3, axis=-1), params[f"{prefix}/conv/kernel"],
-                state, counts)
+                None if state is None else state[0], counts)
             out = wdot(mixed, params[f"{prefix}/conv/out_proj"],
                        preferred_element_type=jnp.float32)
-            return self._residual(params, f"{prefix}/ln1", h, out), state
+            return (self._residual(params, f"{prefix}/ln1", h, out),
+                    (register,))
 
     # positions a kda or gdn layer works through at a time (kda: a
     # [C, C, D] term a head; both: a triangular solve of C rows;
@@ -1684,13 +1555,6 @@ class Transformer:
                 params[f"{ssm}/out_proj"])
             return (self._residual(params, f"{prefix}/ln1", h, out),
                     (shift, matrix))
-
-    def recurrent_residual(self, spec: LayerSpec) -> Callable:
-        """The mixer branch of a recurrent layer of kind ``spec`` (a
-        :data:`RECURRENT_MIXERS` entry): :meth:`kda_residual`,
-        :meth:`gdn_residual` or :meth:`ssm_residual`, one signature."""
-        return {"kda": self.kda_residual, "gdn": self.gdn_residual,
-                "ssm": self.ssm_residual}[spec.mixer]
 
     def latent_rows(self, params: Mapping[str, Array], prefix: str,
                     h: Array, positions: Array) -> tuple[Array, Array]:
@@ -2133,15 +1997,14 @@ class Transformer:
 
         def layer_body(layer_params, p, spec, h):
             router = self.pre_attention_router(layer_params, p, spec, h)
-            if spec.mixer == "conv":
-                h, kept = self.conv_residual(layer_params, p, h,
-                                             counts=counts)
-                kept = (kept,)
-            elif spec.mixer in RECURRENT_MIXERS:
-                h, kept = self.recurrent_residual(spec)(layer_params, p, h,
-                                                        counts=counts)
-            elif spec.mixer == "latent":
-                h, kept = self.latent_residual(layer_params, p, h)
+            mixer = spec.kind
+            if mixer.residual is not None:
+                # the whole branch one method; a state kind's keeps the
+                # pads out of its states
+                branch = getattr(self, mixer.residual)
+                h, kept = (branch(layer_params, p, h, counts=counts)
+                           if mixer.keeps == "state"
+                           else branch(layer_params, p, h))
             else:
                 q, k, v = self.qkv(layer_params, p, h, positions, spec)
                 # K/V go to the attention fn UNexpanded (kv_heads-sized);

@@ -1,7 +1,7 @@
 """A decode round's full attention as one kernel over K and V AS THEY LIE.
 
 What ``models/generation.py`` runs on a TPU for a full softmax layer's
-single token a lane (``transformer.full_decode_arm`` holds the rule),
+single token a lane (``transformer.round_arm`` holds the rule),
 ``latent_decode.py``'s pattern over two parts in place of one: a block of
 positions of K and the same block of V are fetched ONCE, scored against the
 lane's query rows and summed into their accumulators under an online

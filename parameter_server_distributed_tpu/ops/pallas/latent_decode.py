@@ -1,7 +1,7 @@
 """A decode round's latent attention as one kernel over the rows AS STORED.
 
 What ``models/generation.py`` runs on a TPU for a latent layer's single
-token a lane (``_latent_cache_attention`` holds the rule): the query
+token a lane (``transformer.round_arm`` holds the rule): the query
 arrives ABSORBED (every head's own part already through its key matrix,
 the shared part beside it, zeros to the row's width), so a head's score
 against a position is one dot product with that position's row and its

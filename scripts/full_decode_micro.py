@@ -3,7 +3,7 @@
 (``chiprun -- python3 scripts/full_decode_micro.py [cell ...] [-- BLOCK
 ...]``): one layer's single token a lane against K and V parts at the
 serving cells' shapes, the lanes filled as the cells' traffic fills them,
-through each arm of ``models/transformer.full_decode_arm``: ``dense`` (the
+through each arm of ``models/transformer.round_arm``'s ``softmax`` kind: ``dense`` (the
 two einsums over the whole parts) and ``kernel``
 (ops/pallas/full_decode.py), the kernel at each block size named after
 ``--`` (default, and 0: the module's own rule, ``block_positions``;

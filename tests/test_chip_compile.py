@@ -648,14 +648,15 @@ def test_k_exaones_round_updates_rings_of_128_where_they_lie(
     (True, (4, 1, 6, 48), (4, 1024, 6, 48), jnp.bfloat16, "dense"),
     (True, (2, 1, 4, 8), (2, 64, 1, 32), jnp.float32, "dense")])
 def test_full_decode_arms_table(monkeypatch, tpu, q, part, dtype, arm):
-    """``transformer.full_decode_arm`` from the shapes and the backend: a
+    """``transformer.round_arm``'s ``softmax`` kind (a FULL layer's K or V)
+    from the shapes and the backend: a
     round's single token a lane on a TPU against an unquantised part in
     whole blocks and whole registers takes the kernel, at the five serving
     cells' shapes; everything else the einsums, never an error."""
     from parameter_server_distributed_tpu.models import transformer
 
     monkeypatch.setattr(transformer, "_kernel_backend", lambda: tpu)
-    assert transformer.full_decode_arm(q, part, dtype) == arm
+    assert transformer.round_arm("softmax", q, part, dtype) == arm
 
 
 # configuration, mesh axes: the two training cells (64 x 1,024 tokens a step)

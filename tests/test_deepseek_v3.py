@@ -180,8 +180,8 @@ def test_rounds_through_the_kernel_at_128_heads(monkeypatch):
         model, p, t, 1024))(params, tokens[:, :12])
     assert np.max(np.abs(logits - expected[:, 11])) < CLOSE
     monkeypatch.setattr(transformer, "_kernel_backend", lambda: True)
-    assert transformer.latent_decode_arm((2, 1, 128, 128),
-                                         (2, 1024, 128)) == "kernel"
+    assert transformer.round_arm("latent", (2, 1, 128, 128),
+                                 (2, 1024, 128)) == "kernel"
     step = jax.jit(lambda p, t, c: generation.decode_step(model, p, t, c))
     for i in range(12, 18):
         logits, cache = step(params, tokens[:, i], cache)

@@ -4,6 +4,7 @@ positions) against the plain path a decode round's full layers take
 and group size the serving cells have, and inside ``decode_block`` with
 the arm forced to ``kernel``."""
 
+import functools
 import types
 
 import jax
@@ -116,7 +117,7 @@ def test_a_round_through_the_kernel_gives_the_dense_rounds_logits(
             model, params, tokens[:, -1:], cache)[0])
 
     dense = rounds()
-    arm = transformer.full_decode_arm
+    arm = functools.partial(transformer.round_arm, "softmax")
     q_shape = (3, 1, kv_heads * groups, head_dim)
     assert arm(q_shape, cache.k[0].shape, jnp.float32) == "dense"  # no TPU
     monkeypatch.setattr(transformer, "_kernel_backend", lambda: True)

@@ -620,8 +620,8 @@ def test_attn_scale_reaches_every_arm_against_a_cache(wide, monkeypatch,
         model, p, t, c))(params, sequence[:, 40:168], cache)
     assert _apart(block, want[:, 40:168]) < 2 * CLOSE
     part = cache.k[0]
-    assert transformer.full_decode_arm((2, 1, 2, 64), part.shape,
-                                       part.dtype) == (
+    assert transformer.round_arm("softmax", (2, 1, 2, 64), part.shape,
+                                 part.dtype) == (
         "kernel" if arm == "full_decode" else "dense")
     step = jax.jit(lambda p, t, c: generation.decode_step(model, p, t, c))
     for i in range(168, 180):

@@ -8,6 +8,7 @@ routed experts beside a shared one.
 """
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -299,7 +300,7 @@ def test_the_decode_kernel_is_the_plain_absorbed_attention(monkeypatch):
             lengths=jnp.asarray([19, 19]))[0])
 
     plain = round_()
-    arm = transformer.latent_decode_arm
+    arm = functools.partial(transformer.round_arm, "latent")
     assert arm((2, 1, 16, 128), (2, 1024, 128)) == "dense"      # no TPU
     monkeypatch.setattr(transformer, "_kernel_backend", lambda: True)
     assert np.max(np.abs(round_() - plain)) < 1e-5
@@ -317,6 +318,48 @@ def test_the_decode_kernel_is_the_plain_absorbed_attention(monkeypatch):
     with pytest.raises(ValueError, match="whole blocks of 1024"):
         generation.decode_block(model, params, tokens[:, 19:], short,
                                 lengths=jnp.asarray([19, 19]))
+
+
+@pytest.mark.parametrize("heads", [16, 12])
+def test_a_latent_round_over_a_mesh_takes_the_einsums(monkeypatch, heads):
+    """A latent layer's round against a cache spread over four devices, on
+    a TPU backend: GSPMD cannot cut a kernel, so the round takes the plain
+    form, whether the kernel would have taken the shapes (16 heads) or not
+    (12), and refuses nothing; on ONE device the shapes the kernel does not
+    take are refused, as ever.  (Before PR 59 the latent layer's chooser
+    was never told the devices: it took the kernel, or refused.)"""
+    from parameter_server_distributed_tpu.models import transformer
+
+    config = TransformerConfig(
+        vocab=64, d_model=32, n_heads=heads, head_dim=8, n_layers=2,
+        d_ff=48, max_seq=1024, dtype=jnp.float32, kv_latent=24, qk_shared=8,
+        pattern=(LayerSpec(mixer="latent"),))
+    model = Transformer(config)
+    params = model.init_params(2)
+    tokens = jnp.asarray(np.random.default_rng(3).integers(0, 64, (2, 20)))
+    _, cache = generation.prefill(model, params, tokens[:, :19], 1024)
+    calls = []
+    monkeypatch.setattr(
+        "parameter_server_distributed_tpu.ops.pallas.latent_decode."
+        "latent_decode_attention", lambda *args: calls.append(1))
+
+    def round_(devices):
+        return np.asarray(generation.decode_block(
+            model, params, tokens[:, 19:],
+            dataclasses.replace(cache, devices=devices),
+            lengths=jnp.asarray([19, 19]))[0])
+
+    plain = round_(1)                                           # no TPU
+    monkeypatch.setattr(transformer, "_kernel_backend", lambda: True)
+    assert transformer.round_arm("latent", (2, 1, heads, 128),
+                                 (2, 1024, 128), devices=4) == "dense"
+    assert np.array_equal(round_(4), plain) and not calls
+    if heads == 12:
+        with pytest.raises(ValueError, match="latent_decode.py, which takes "
+                           "heads in 16s, rows of whole 128-lane registers "
+                           "and a cache of whole blocks of 1024 positions; "
+                           "got queries"):
+            round_(1)
 
 
 def test_a_latent_part_is_one_row_a_position_in_whole_registers(small):

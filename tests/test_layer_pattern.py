@@ -9,6 +9,7 @@ routing under total imbalance, the two kinds of layer told apart, and GPT-2
 through the same pattern and cache.
 """
 
+import importlib
 import os
 import sys
 
@@ -19,12 +20,14 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from parameter_server_distributed_tpu.models import generation, moe
+from parameter_server_distributed_tpu.models import (generation, mixers, moe,
+                                                     serving)
 from parameter_server_distributed_tpu.models import transformer as tr
 from parameter_server_distributed_tpu.models.serving import (DecodeServer,
                                                              _bucket)
 from parameter_server_distributed_tpu.ops.blockwise_attention import (
     blockwise_attention)
+from parameter_server_distributed_tpu.ops.sparse_attention import SparseSpec
 from perfbench.families import smallthinker as family
 from perfbench.reference import smallthinker as reference
 
@@ -615,3 +618,152 @@ def test_rows_read_back_are_the_layers_own_by_position(cached):
                     np.testing.assert_allclose(
                         part[slot, :upto], want[slot, :upto],
                         atol=TOLERANCE)
+
+
+# ------------------------------------------------- the table of layer kinds
+# What the functions that READ a kind (state_shape, block_shapes,
+# flops_per_sample, _builds_few, _prefills_whole) answered for a tiny model
+# of each at PR 58's commit, before the kinds stood in one table
+# (models/mixers.py): a record that says something else than the chains did
+# fails here.  ``block``: suffix:shape in the store's order.
+PINNED = {
+    "softmax": {
+        "state": [],
+        "block": ("ln1/scale:32 attn/wq:32x32 attn/wk:32x32 attn/wv:32x32 "
+                  "attn/wo:32x32 ln2/scale:32 mlp/w1:32x48 mlp/w2:48x32"),
+        "flops": 10285056.0, "few": False,
+        "whole": [True, True, True, False, True, True]},
+    "sparse": {
+        "state": [],
+        "block": ("ln1/scale:32 attn/wq:32x32 attn/wk:32x32 attn/wv:32x32 "
+                  "attn/wo:32x32 ln2/scale:32 mlp/w1:32x48 mlp/w2:48x32"),
+        "flops": 10285056.0, "few": False,
+        "whole": [True, True, True, False, True, True]},
+    "linear": {
+        "state": [[((4, 8, 8), "float32")]] * 2,
+        "block": ("ln1/scale:32 attn/wq:32x32 attn/wk:32x32 attn/wv:32x32 "
+                  "attn/wo:32x32 ln2/scale:32 mlp/w1:32x48 mlp/w2:48x32"),
+        "flops": 10285056.0, "few": False,
+        "whole": [True, True, True, False, True, True]},
+    "conv": {
+        "state": [[((2, 32), "float32")]] * 2,
+        "block": ("ln1/scale:32 conv/in_proj:32x96 conv/kernel:3x32 "
+                  "conv/out_proj:32x32 ln2/scale:32 mlp/w1:32x48 "
+                  "mlp/w2:48x32"),
+        "flops": 10358784.0, "few": False,
+        "whole": [True, True, True, False, True, True]},
+    "kda": {
+        "state": [[((3, 96), "float32"), ((4, 8, 8), "float32")]] * 2,
+        "block": ("ln1/scale:32 attn/wq:32x32 attn/wk:32x32 attn/wv:32x32 "
+                  "attn/conv_q:4x32 attn/conv_k:4x32 attn/conv_v:4x32 "
+                  "attn/decay/wa:32x8 attn/decay/wb:8x32 attn/decay/a_log:4 "
+                  "attn/decay/dt_bias:32 attn/gate/wa:32x8 "
+                  "attn/gate/wb:8x32 attn/beta/w:32x4 attn/o_norm/scale:8 "
+                  "attn/wo:32x32 ln2/scale:32 mlp/w1:32x48 mlp/w2:48x32"),
+        "flops": 8942592.0, "few": True,
+        "whole": [True, False, False, False, True, False]},
+    "latent": {
+        "state": [],
+        "block": ("ln1/scale:32 attn/wq_a:32x12 attn/q_norm/scale:12 "
+                  "attn/wq_b:12x64 attn/wkv_a:32x32 attn/kv_norm/scale:24 "
+                  "attn/wkv_b:24x64 attn/wo:32x32 ln2/scale:32 mlp/w1:32x48 "
+                  "mlp/w2:48x32"),
+        "flops": 12377088.0, "few": True,
+        "whole": [True, False, False, False, True, True]},
+    "gdn": {
+        "state": [[((3, 96), "float32"), ((4, 6, 12), "float32")]] * 2,
+        "block": ("ln1/scale:32 attn/wq:32x24 attn/wk:32x24 attn/wv:32x48 "
+                  "attn/conv_q:4x24 attn/conv_k:4x24 attn/conv_v:4x48 "
+                  "attn/decay/w:32x4 attn/decay/a_log:4 "
+                  "attn/decay/dt_bias:4 attn/beta/w:32x4 attn/wz:32x48 "
+                  "attn/o_norm/scale:12 attn/wo:48x32 ln2/scale:32 "
+                  "mlp/w1:32x48 mlp/w2:48x32"),
+        "flops": 9882624.0, "few": True,
+        "whole": [True, False, False, False, True, False]},
+    "ssm": {
+        "state": [[((3, 112), "float32"), ((6, 8, 16), "float32")]] * 2,
+        "block": ("ln1/scale:32 ssm/in_proj:32x166 ssm/conv/kernel:4x112 "
+                  "ssm/conv/bias:112 ssm/decay/a_log:6 ssm/decay/dt_bias:6 "
+                  "ssm/skip:6 ssm/norm/scale:48 ssm/out_proj:48x32 "
+                  "ln2/scale:32 mlp/w1:32x48 mlp/w2:48x32"),
+        "flops": 10913280.0, "few": True,
+        "whole": [True, False, False, False, True, False]},
+}
+
+# what each kind's tiny model needs beside the common sizes, and the ONE
+# size that makes its branch the widest thing a token meets (98,304 to
+# 102,064 channels: a prompt of 1,024 is then forwarded whole and one of
+# 2,048 is not)
+KIND_FIELDS = {
+    "sparse": dict(sparse=SparseSpec(kernel=8, stride=4, block=8, window=16,
+                                     topk=2, dense_len=32)),
+    "conv": dict(conv_kernel=3), "kda": dict(conv_kernel=4),
+    "gdn": dict(conv_kernel=4, delta_key_dim=6, delta_value_dim=12,
+                delta_neg_eigval=True),
+    "ssm": dict(conv_kernel=4, ssm_heads=6, ssm_head_dim=8, ssm_state=16,
+                ssm_groups=2),
+    "latent": dict(kv_latent=24, qk_shared=8, q_latent=12)}
+WIDE = {"ssm": dict(ssm_heads=6000)}
+
+
+def _model_of(kind, **changes):
+    fields = {"d_model": 32, "n_heads": 4, "head_dim": 8, "d_ff": 48,
+              **KIND_FIELDS.get(kind, {}), **changes}
+    return tr.Transformer(tr.TransformerConfig(
+        vocab=64, n_layers=2, max_seq=64, dtype=jnp.float32,
+        pattern=(tr.LayerSpec(mixer=kind),), **fields))
+
+
+def _answers(kind):
+    """What the functions that read a kind answer for its tiny model (and
+    for one whose branch is the widest thing a token meets)."""
+    model = _model_of(kind)
+    wide = _model_of(kind, **WIDE.get(kind, dict(n_heads=4096)))
+    return {
+        "state": [[(shape, jnp.dtype(dtype).name) for shape, dtype in layer]
+                  for layer in generation.state_shape(model)],
+        "block": " ".join(
+            f"{suffix}:{'x'.join(map(str, shape))}" for suffix, shape
+            in model.block_shapes(model.config.pattern[0]).items()),
+        "flops": model.flops_per_sample(),
+        "few": serving._builds_few(model),
+        # (tiny at 2,048, 4,096, 2**21 and 2**22; wide at 1,024 and 2,048)
+        "whole": [serving._prefills_whole(m, bucket) for m, buckets in (
+            (model, (2048, 4096, 1 << 21, 1 << 22)), (wide, (1024, 2048)))
+            for bucket in buckets]}
+
+
+def test_the_names_are_read_off_the_table():
+    assert tr.MIXER_KINDS == tuple(mixers.MIXERS) == tuple(PINNED)
+    assert tr.STATE_MIXERS == ("linear", "conv", "kda", "gdn", "ssm")
+    assert tr.RECURRENT_MIXERS == ("kda", "gdn", "ssm")
+
+
+@pytest.mark.parametrize("kind", tr.MIXER_KINDS)
+def test_a_kinds_record_is_whole_and_answers_as_the_chains_did(kind):
+    mixer = mixers.MIXERS[kind]
+    model = _model_of(kind)
+    c = model.config
+    # a whole record: every field says something the readers can use
+    assert mixer.keeps in ("kv", "state", "latent")
+    assert (mixer.state is not None) == (mixer.keeps == "state")
+    assert (kind in tr.STATE_MIXERS) == (mixer.keeps == "state")
+    states = mixer.state(c) if mixer.state else ()
+    assert mixer.matrix == any(len(shape) == 3 and dtype == jnp.float32
+                               for shape, dtype in states)
+    assert mixer.recurrent == (len(states) == 2)
+    assert mixer.residual is None or callable(getattr(model, mixer.residual))
+    assert mixer.products(c, c.max_seq) > 0 and mixer.widest(c) >= 0
+    assert set(mixer.shapes(c, c.pattern[0])) >= {"ln1/scale", "ln2/scale"}
+    if mixer.round_kernel is not None:
+        module = importlib.import_module(
+            "parameter_server_distributed_tpu.ops.pallas."
+            + mixer.round_kernel.module)
+        assert callable(module.fits)
+        assert tr.round_arm(kind, (2, 1, 4, 8), (2, 64, 4, 8)) \
+            == mixer.round_kernel.plain                      # no TPU
+        # (the sentence a refusal is made of names what the module has)
+        assert "{" not in mixer.round_kernel.refusal.format(kernel=module)
+    assert (c.layers_keeping(mixer.keeps), c.layers_of(kind)) == ((0, 1),) * 2
+    # ... and what reads it answers what it answered before the table
+    assert _answers(kind) == PINNED[kind]

@@ -149,15 +149,16 @@ def test_the_rule_for_an_ssm_round(monkeypatch, tpu, x, matrix, dtype,
 def test_one_rule_for_every_kind(monkeypatch):
     """The three kinds answer through the one function: a token on a TPU
     takes each kind's kernel, a block or a mesh its plain form; the two
-    older names are that function under a kind."""
+    older names (the function under a kind, one of them blind to a mesh)
+    are gone."""
     monkeypatch.setattr(transformer, "_kernel_backend", lambda: True)
     full = ((8, 1, 32, 64), (8, 2048, 4, 128), jnp.bfloat16)
     latent = ((16, 1, 64, 640), (16, 4096, 640), jnp.bfloat16)
-    assert transformer.round_arm("full", *full) == "kernel"
-    assert transformer.full_decode_arm(*full) == "kernel"
+    assert transformer.round_arm("softmax", *full) == "kernel"
     assert transformer.round_arm("latent", *latent) == "kernel"
-    assert transformer.latent_decode_arm(*latent[:2]) == "kernel"
-    for kind, shapes, plain in (("full", full, "dense"),
+    assert not hasattr(transformer, "full_decode_arm")
+    assert not hasattr(transformer, "latent_decode_arm")
+    for kind, shapes, plain in (("softmax", full, "dense"),
                                 ("latent", latent, "dense"),
                                 ("ssm", ((64, 1, 64, 64), MATRIX,
                                          jnp.float32), "plain")):
@@ -165,7 +166,7 @@ def test_one_rule_for_every_kind(monkeypatch):
         several = (shapes[0][0], 5) + shapes[0][2:]
         assert transformer.round_arm(kind, several, *shapes[1:]) == plain
     monkeypatch.setattr(transformer, "_kernel_backend", lambda: False)
-    assert transformer.round_arm("full", *full) == "dense"
+    assert transformer.round_arm("softmax", *full) == "dense"
     assert transformer.round_arm("ssm", (64, 1, 64, 64), MATRIX) == "plain"
 
 

@@ -446,7 +446,11 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
     of a row's T tokens are real (default: all) and only those are
     written.  A window layer stored by position (an extension against a
     cached row) takes the window as a mask.  ``route_stats``, where
-    given, gains each ``experts`` layer's tokens per expert.
+    given, gains each ``experts`` layer's tokens per expert; under
+    ``counts`` such a layer routes the real tokens alone (a pad position's
+    or an idle lane's assignments belong to no group, an expert only they
+    chose is not read, and they are counted nowhere:
+    ``moe.dropless_experts``'s ``live``).
 
     A LINEAR, CONV, KDA, GDN or SSM layer reads and advances its states
     (``counts`` keeps pads out of them, and like a ring they cannot be
@@ -568,10 +572,11 @@ def decode_block(model: Transformer, params: Mapping[str, Array],
         # scan_layers a view is slices, and their place in the program is
         # part of what the compiler is handed)
         lp, p = model.layer_view(params, layer)
-        # experts never drop and moe decodes drop-free; aux loss unused
+        # experts never drop and moe decodes drop-free; aux loss unused.
+        # (An experts layer routes the block's real tokens alone.)
         return model.ffn_residual(lp, p, spec, h, decode=True,
                                   router_logits=router,
-                                  route_stats=route_stats)[0]
+                                  route_stats=route_stats, counts=counts)[0]
 
     for layer in range(c.n_layers):
         # layer_view resolves either param layout (unrolled layer<i>/* or

@@ -258,6 +258,7 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
                      scale: float = 1.0, chosen: list | None = None,
                      held: tuple[int, int] | None = None,
                      groups: int = 1, groups_kept: int = 1,
+                     live: Array | None = None,
                      ) -> tuple[Array, Array]:
     """Dropless top-k experts over a flat batch of tokens.
 
@@ -289,6 +290,16 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
     the stage's E / count ranks (rank r holds the experts r * count ..) a
     token's choices lie on, which is what the limit bounds and what an
     exchange between the ranks would send.
+
+    ``live`` [N] bool: which tokens are somebody's (None: every one, and
+    the function traces what it always has).  A token that is nobody's (a
+    decode round's idle lane, an admission's pad position) is routed like
+    any other, and its assignments then sort behind every group as those to
+    experts held elsewhere do: the row count stays N * top_k (nothing is
+    dropped, nothing recompiles by occupancy), the grouped matmul gets the
+    live assignments' rows and zeros behind them, an expert only such
+    tokens chose has no rows and is not read, and the token's output is
+    zeros.  ``loads``, every entry, counts live tokens only.
     """
     n, d = x.shape
     experts = w1.shape[0]
@@ -298,29 +309,42 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
         if chosen is not None:
             chosen.append(top_idx)
         flat = top_idx.reshape(n * top_k)
-        if held is None:
+        if held is None and live is None:
             order, place = _sorted_by_group(flat, experts)     # [A]
             loads = sizes = jnp.zeros((experts,), jnp.int32).at[flat].add(1)
             rows = x[order // top_k]                           # [A, D]
+            mine = None
         else:
-            first, count = held
+            first, count = held or (0, experts)
             if experts != count:
                 raise ValueError(f"held={held}: the weights hold {experts} "
                                  "experts")
             # an assignment to an expert held elsewhere sorts behind every
-            # held one, into no group
-            local = flat - first
-            local = jnp.where((local >= 0) & (local < count), local, count)
-            order, place = _sorted_by_group(local, count + 1)  # [A]
-            loads = jnp.zeros((count + 1,), jnp.int32).at[local].add(1)
+            # held one, into no group; so does a token's that is nobody's
+            local, each = flat, 1
+            if held is not None:
+                local = flat - first
+                local = jnp.where((local >= 0) & (local < count), local,
+                                  count)
+            key = local
+            if live is not None:
+                each = jnp.repeat(live, top_k)                 # [A]
+                key = jnp.where(each, local, count)
+                each = each.astype(jnp.int32)
+            order, place = _sorted_by_group(key, count + 1)    # [A]
+            loads = jnp.zeros((count + 1,), jnp.int32).at[local].add(each)
             sizes = loads[:count]
+            if held is None:
+                loads = sizes
             bound = n * min(top_k, count)
             mine = (jnp.arange(bound) < jnp.sum(sizes))[:, None]
             rows = jnp.where(mine, x[order[:bound] // top_k], 0)
-            if groups > 1:
+            if held is not None and groups > 1:
                 ranks = router_logits.shape[-1] // count
                 on = jnp.any((top_idx // count)[:, :, None]
                              == jnp.arange(ranks), axis=1)         # [N, R]
+                if live is not None:
+                    on &= live[:, None]
                 loads = jnp.concatenate(
                     [loads, jnp.sum(on, dtype=jnp.int32)[None]])
     with jax.named_scope("experts"):
@@ -334,7 +358,7 @@ def dropless_experts(x: Array, router_logits: Array, w1: Array, w2: Array,
             hidden = gate(hidden) * dot(rows, w3).astype(x.dtype)
         out = dot(hidden, w2)                                  # [A, D] f32
     with jax.named_scope("router"):
-        if held is not None:
+        if mine is not None:
             # (what a grouped matmul leaves in a row of no group is its own)
             out = jnp.pad(jnp.where(mine, out, 0.0),
                           ((0, n * top_k - bound), (0, 0)))

@@ -510,11 +510,17 @@ def _step_runner(model: Transformer, slots: int,
     tokens it decodes from.
 
     Where a layer of the model reads which lanes hold a request
-    (:func:`_mask_layers`), ``fresh`` also says so, in the same upload:
-    ``_IDLE`` names a lane no request decodes in (its token is whatever
-    ``prev`` holds: nobody reads what it decodes), and the round's
-    ``counts`` are formed from it on the device.  Any other model traces
-    the program it always has."""
+    (:func:`_mask_layers`: a state layer whose kernel moves live lanes
+    alone, an ``experts`` layer), ``fresh`` also says so, in the same
+    upload: ``_IDLE`` names a lane no request decodes in (its token is
+    whatever ``prev`` holds: nobody reads what it decodes), and the round's
+    ``counts`` are formed from it on the device.  Every consumer of
+    ``counts`` in the model then sees 0 for that lane: its experts route
+    nothing for it (an expert only idle lanes chose has no rows and is not
+    read), its rings, registers and states stand still (an idle lane's
+    contents are nobody's: an admission's splice writes every part of the
+    lane it is given).  Any other model traces the program it always
+    has."""
     key = (_model_key(model), "serve_step", slots, top_k, top_p,
            cache_dtype)
 
@@ -543,16 +549,17 @@ _IDLE = -2
 
 def _mask_layers(model: Transformer) -> tuple[int, ...]:
     """The layers of ``model`` that read which lanes of a decode round hold
-    a request (``decode_block``'s ``counts``: 1 live, 0 idle): those whose
-    kind has a round kernel that moves live lanes alone
-    (``RoundKernel.live_lanes``; ops/pallas/ssd_decode.py), whose states of
-    an idle lane then stay where they are, the matrix unread.  A round of a
-    model with such a layer is told; the host always knows the mask, and it
-    enters a traced program only where this says so."""
+    a request (``decode_block``'s ``counts``: 1 live, 0 idle;
+    ``LayerSpec.reads_live_lanes``): those whose kind has a round kernel
+    that moves live lanes alone (ops/pallas/ssd_decode.py), whose states of
+    an idle lane then stay where they are, the matrix unread, and those
+    whose feed-forward branch is ``experts``, which route the live lanes'
+    tokens alone, so that an expert only idle lanes chose is not read.  A
+    round of a model with such a layer is told; the host always knows the
+    mask, and it enters a traced program only where this says so."""
     c = model.config
-    kernels = (c.layer_spec(i).kind.round_kernel for i in range(c.n_layers))
-    return tuple(i for i, kernel in enumerate(kernels)
-                 if kernel is not None and kernel.live_lanes)
+    return tuple(i for i in range(c.n_layers)
+                 if c.layer_spec(i).reads_live_lanes)
 
 
 def _decode_round(model, top_k, top_p, params, tokens, cache, lengths,
@@ -720,9 +727,12 @@ class DecodeServer:
         self._linear_layers = sum(config.layer_spec(i).kind.matrix
                                   for i in config.state_layers)
         # a round tells the model which lanes hold a request where a layer
-        # reads it, and such a layer leaves an idle lane's states as they
-        # are
-        self._masked_layers = len(_mask_layers(model))
+        # reads it (an experts layer, or a state layer that then leaves an
+        # idle lane's states as they are: those are counted)
+        self._masked = bool(_mask_layers(model))
+        self._live_lane_layers = sum(
+            config.layer_spec(i).moves_live_lanes
+            for i in config.state_layers)
         self._sparse_layers = len(config.layers_of("sparse"))
         self._latent_layers = len(config.layers_keeping("latent"))
         if draft is not None:
@@ -802,7 +812,9 @@ class DecodeServer:
                                       "experts_touched", "expert_places",
                                       "load_max_over_mean",
                                       "admit_experts_touched",
-                                      "rank_places", "tokens_routed")}
+                                      "rank_places", "tokens_routed",
+                                      "round_assignments",
+                                      "round_assignment_places")}
         for kind, held in self._cache_bytes_by_kind().items():
             obs_stats.gauge(f"serve.cache.{kind}_bytes").set(held)
         # what a round's sparse, linear (kda, gdn and ssm too), latent and full
@@ -1542,7 +1554,7 @@ class DecodeServer:
         the model's rounds take the mask (``_mask_layers``), every lane but
         ``lanes`` goes up as ``_IDLE``."""
         ahead = after.lanes if after is not None else {}
-        fresh = (np.full_like(self._tokens, _IDLE) if self._masked_layers
+        fresh = (np.full_like(self._tokens, _IDLE) if self._masked
                  else self._tokens.copy())
         lanes: dict[int, _Slot] = {}
         for i, entry in enumerate(self._slot):
@@ -1639,7 +1651,7 @@ class DecodeServer:
                                         self._top_k, self._top_p,
                                         self.cache_dtype, n)
             tokens = self._tokens
-            if self._masked_layers:
+            if self._masked:
                 tokens = np.where([entry is None for entry in self._slot],
                                   _IDLE, tokens).astype(tokens.dtype)
             inputs = (jnp.asarray(tokens), self._cache,
@@ -1806,9 +1818,9 @@ class DecodeServer:
             self._obs_mixers["serve.sparse.positions_cached"].add(
                 float(self._sparse_layers * positions))
         if self._linear_layers:
-            masked = self._masked_layers
+            alone = self._live_lane_layers
             self._obs_mixers["serve.linear.state_updates"].add(
-                live * masked + self.slots * (self._linear_layers - masked))
+                live * alone + self.slots * (self._linear_layers - alone))
             self._obs_mixers["serve.linear.state_places"].add(
                 self.slots * self._linear_layers)
         if self._latent_layers:
@@ -1827,13 +1839,19 @@ class DecodeServer:
     def _count_routing(self, loads: np.ndarray,
                        admission: bool = False) -> None:
         """One forward's tokens per expert ([L * E], every experts layer
-        in order) into the counters a per-layer metric divides.  Of every
-        forward: assignments routed (pad positions' and idle lanes' too:
-        the device computes them), and those of them the grouped matmul
-        computed.  Of an admission's: the distinct experts it touched.  Of
-        a decode round's: (layer, round) pairs seen, distinct experts
-        touched over them, expert places over them, and the largest
-        expert's load over the mean, summed.  Where the model holds a
+        in order) into the counters a per-layer metric divides.  The
+        loads are of the forward's REAL tokens: a pad position's and an
+        idle lane's assignments belong to no group and are counted nowhere
+        (``moe.dropless_experts``'s ``live``; the device still scores
+        them).  Of every forward: assignments routed, and those of them the
+        grouped matmul computed.  Of an admission's: the distinct experts
+        it touched.  Of a decode round's: (layer, round) pairs seen,
+        distinct experts touched over them, expert places over them, the
+        largest expert's load over the mean, summed, and the round's
+        assignments routed (``serve.moe.round_assignments``) of the places
+        its static rows have (``serve.moe.round_assignment_places``: slots
+        x ``moe_top_k`` x experts layers), whose ratio is the share of a
+        round's rows that were somebody's.  Where the model holds a
         share of the experts (``moe_held``) a layer's last entry is what
         went to experts held elsewhere: it counts as routed, and every
         other count is over the HELD experts and the rows computed.  Where
@@ -1848,7 +1866,12 @@ class DecodeServer:
             loads = loads[:, :-1]
             self._obs_moe["tokens_routed"].add(
                 int(loads.sum()) // config.moe_top_k)
-        self._obs_moe["assignments_routed"].add(int(loads.sum()))
+        routed = int(loads.sum())
+        self._obs_moe["assignments_routed"].add(routed)
+        if not admission:
+            self._obs_moe["round_assignments"].add(routed)
+            self._obs_moe["round_assignment_places"].add(
+                self.slots * config.moe_top_k * self._moe_layers)
         if config.moe_held:
             loads = loads[:, :-1]
         computed, touched = int(loads.sum()), int((loads > 0).sum())

@@ -210,6 +210,20 @@ class LayerSpec:
         """What the layer's mixer IS: its record of models/mixers.py."""
         return MIXERS[self.mixer]
 
+    @property
+    def moves_live_lanes(self) -> bool:
+        """Its decode round moves the states of the lanes that hold a
+        request and no other (``RoundKernel.live_lanes``)."""
+        kernel = self.kind.round_kernel
+        return kernel is not None and kernel.live_lanes
+
+    @property
+    def reads_live_lanes(self) -> bool:
+        """A serving round tells the layer which lanes hold a request
+        (``decode_block``'s ``counts``): its kernel moves those lanes'
+        states alone, or its experts route those lanes' tokens alone."""
+        return self.moves_live_lanes or self.ffn == "experts"
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -1740,7 +1754,8 @@ class Transformer:
                      spec: LayerSpec, h: Array, decode: bool = False,
                      router_logits: Array | None = None,
                      route_stats: list | None = None,
-                     chosen: list | None = None) -> tuple[Array, Array]:
+                     chosen: list | None = None,
+                     counts: Array | None = None) -> tuple[Array, Array]:
         """The layer's FFN branch by its kind: the dense MLP, the
         capacity-dropping ``moe`` layer or dropless ``experts``.
         Returns (new_h, aux_loss) — aux is 0 but for ``moe``.  ``params``
@@ -1759,7 +1774,12 @@ class Transformer:
         and ``chosen``
         the experts every token of an ``experts`` layer took ([B, S, k]).
         A shared expert (``moe_shared_experts``) runs on the same input
-        under ``moe/shared`` and is added ungated."""
+        under ``moe/shared`` and is added ungated.  ``counts`` [B] says
+        how many of a row's S tokens are somebody's (None: all; a decode
+        round's idle lane has none, an admission's row its real tokens):
+        an ``experts`` layer sorts the others' assignments into no group
+        and counts them nowhere (``moe.dropless_experts``'s ``live``); the
+        other kinds compute every token as ever."""
         zero = jnp.zeros((), jnp.float32)
         if spec.ffn == "mlp":
             return self.mlp_residual(params, prefix, h), zero
@@ -1787,7 +1807,11 @@ class Transformer:
                 bias=params.get(f"{prefix}/moe/router/bias"),
                 scale=c.moe_route_scale, chosen=chosen,
                 held=c.moe_held or None, groups=c.moe_groups,
-                groups_kept=c.moe_groups_kept)
+                groups_kept=c.moe_groups_kept,
+                # (the rings' expression: a row's first ``counts`` tokens)
+                live=None if counts is None else (
+                    jnp.arange(seq, dtype=jnp.int32)[None, :]
+                    < counts[:, None]).reshape(batch * seq))
             out = out.reshape(batch, seq, c.d_model)
             if c.moe_shared_experts:
                 with jax.named_scope("shared"):
@@ -1978,7 +2002,8 @@ class Transformer:
         :meth:`gdn_residual` and :meth:`ssm_residual`) or a latent layer's
         rows (:meth:`latent_residual`); aux loss).  ``counts``
         [B]: how many of a row's tokens are real, for the layers whose
-        state must not hold a pad."""
+        state must not hold a pad and for the ``experts`` layers, which
+        route the real tokens alone (:meth:`ffn_residual`)."""
         c = self.config
         batch, seq = tokens.shape
         if c.pos_emb == "learned" and seq > c.max_seq:
@@ -2016,7 +2041,7 @@ class Transformer:
             h, aux = self.ffn_residual(layer_params, p, spec, h,
                                        router_logits=router,
                                        route_stats=route_stats,
-                                       chosen=chosen)
+                                       chosen=chosen, counts=counts)
             h = self._constrain(h, ("data", "fsdp"), "seq", None)
             return h, aux, kept
 

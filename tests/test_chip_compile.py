@@ -179,6 +179,26 @@ def test_the_decode_round_updates_every_part_where_it_lies(cell, monkeypatch,
     assert temporaries < cache_bytes / 4
 
 
+def test_the_round_told_its_idle_lanes_keeps_its_grouped_matmuls(cell):
+    """SmallThinker's round reads which lanes hold a request (PR 60: an
+    idle lane's assignments belong to no group) and is still three grouped
+    matmuls an experts layer over the same static rows, 16 slots x top-6 =
+    96 (12 calls at the 4 layers compiled here, 24 at the cell's 8): nothing
+    is dropped, nothing is sized by occupancy, and no cache part is copied
+    for the mask.  GPT-2's round holds none and is not told."""
+    model, params, cache, _, slots, chip = cell
+    c = model.config
+    experts = sum(c.layer_spec(i).ffn == "experts" for i in range(c.n_layers))
+    assert bool(serving._mask_layers(model)) == bool(experts)
+    compiled = _compiled_round(model, params, cache, slots, chip)
+    calls = re.findall(r"%ragged-dot-none[.\d]* = f32\[(\d+),",
+                       compiled.as_text())
+    assert calls == [str(slots * c.moe_top_k)] * 3 * experts
+    aliased, parts, moved, _, _ = _held(compiled, cache)
+    assert aliased >= parts
+    assert moved == []
+
+
 def test_an_admission_splices_its_row_where_the_slot_lies(cell):
     model, _, cache, row, _, chip = cell
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)

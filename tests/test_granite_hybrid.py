@@ -532,12 +532,20 @@ def _round_as_it_was(model, top_k=0, top_p=0.0):
     return run
 
 
+# the models whose rounds are told which lanes hold a request: an ssm
+# layer's kernel moves those lanes' states alone (PR 58), an experts layer
+# routes those lanes' tokens alone (PR 60)
+_TOLD = ("granite-4.0-h-micro", "smallthinker-21b-a3b-8l")
+
+
 @pytest.mark.parametrize("name", ["gpt2-medium", "olmo-hybrid-7b-16l",
-                                  "granite-4.0-h-micro"])
-def test_only_a_model_with_an_ssm_layer_traces_the_mask(name):
+                                  *_TOLD])
+def test_only_a_model_with_a_layer_that_reads_the_lanes_traces_the_mask(
+        name):
     """GPT-2's step and a state model's (gdn layers beside softmax ones)
     are the programs they were, equation for equation: the mask enters a
-    round only where a layer reads it.  Granite's is not."""
+    round only where a layer reads it (``LayerSpec.reads_live_lanes``).
+    Granite's (ssm layers) and SmallThinker's (experts layers) are not."""
     from perfbench import families
 
     with open(os.path.join(ROOT, "perfbench", "configs",
@@ -555,8 +563,8 @@ def test_only_a_model_with_an_ssm_layer_traces_the_mask(name):
     now = str(jax.make_jaxpr(serving._step_runner(
         model, slots, 0, 0.0, "native"))(*shapes))
     was = str(jax.make_jaxpr(_round_as_it_was(model))(*shapes))
-    assert bool(serving._mask_layers(model)) == (name == "granite-4.0-h-micro")
-    assert (now == was) == (name != "granite-4.0-h-micro")
+    assert bool(serving._mask_layers(model)) == (name in _TOLD)
+    assert (now == was) == (name not in _TOLD)
 
 
 # ------------------------------------------------------- the scores' scale

@@ -407,9 +407,12 @@ def test_the_counters_count_the_held_experts_and_the_rows_computed(
     assert len(server.run_to_completion()[rid]) == 6
     moved = {name: c.value - before[name] for name, c in counters.items()}
     rounds = moved["layer_rounds"] / 5
-    # every forward routes tokens x 3 in each of 5 expert layers: the
-    # admission's bucket of 64 and every fetched round's 4 lanes
-    assert moved["assignments_routed"] == 5 * 3 * (64 + 4 * rounds)
+    # every forward routes its REAL tokens x 3 in each of 5 expert layers:
+    # the admission's 40 of a bucket of 64, and of every fetched round's 4
+    # lanes the one that holds the request (PR 60)
+    assert moved["assignments_routed"] == 5 * 3 * (40 + 1 * rounds)
+    assert moved["round_assignments"] == 5 * 3 * rounds
+    assert moved["round_assignment_places"] == 5 * 3 * 4 * rounds
     assert 0 < moved["assignments"] < 0.6 * moved["assignments_routed"]
     assert server.stats["moe_assignments"] >= moved["assignments"]
     # places: the 4 HELD experts of a layer, not the router's 16

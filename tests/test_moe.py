@@ -340,3 +340,96 @@ def test_moe_350m_preset_shape(rng):
                    + 12.0 * c.n_layers * c.d_model * c.max_seq ** 2)
     tokens, = (next(batches),)
     assert tokens.shape == (2, 1024)
+
+
+# ---- dropless_experts(live=...): the tokens that are somebody's ----
+
+_MODES = {
+    # name: (held, groups, groups_kept)
+    "plain": (None, 1, 1),
+    "held": ((4, 8), 1, 1),
+    "held_fewer_than_top_k": ((6, 2), 1, 1),
+    "held_group_limit": ((4, 4), 4, 2),
+}
+
+
+def _dropless_case(mode, score, n=40, seed=5):
+    """(args, options) of a call at 16 experts, top-3, in float32."""
+    from parameter_server_distributed_tpu.models import moe
+
+    held, groups, kept = _MODES[mode]
+    d, e, f = 16, 16, 8
+    count = e if held is None else held[1]
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    args = (normal(n, d), normal(n, e), normal(count, d, f) / 4,
+            normal(count, f, d) / 3, normal(count, d, f) / 4)
+    options = dict(top_k=3, act="swiglu", score=score, held=held,
+                   groups=groups, groups_kept=kept,
+                   bias=0.05 * normal(e) if score == "sigmoid" else None)
+    live = jnp.asarray(rng.random(n) < 0.45)
+    return moe.dropless_experts, args, options, live
+
+
+@pytest.mark.parametrize("past_sort_limit", [False, True],
+                         ids=["sorted", "counted"])
+@pytest.mark.parametrize("score", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("mode", list(_MODES))
+def test_dropless_experts_routes_the_live_tokens_alone(mode, score,
+                                                       past_sort_limit,
+                                                       monkeypatch):
+    """Under ``live`` a dead token's assignments belong to no group: a live
+    token's row is the unmasked call's bit for bit (which other rows share
+    its group does not enter it), a dead token's is zeros, and every entry
+    of ``loads`` (the held experts', what went elsewhere, the rank places)
+    is that of a call on the live tokens alone.  The row count is static:
+    the same under any mask.  Past ``_SORT_LIMIT`` the counting sort takes
+    one more key and gives the same."""
+    from parameter_server_distributed_tpu.models import moe
+
+    if past_sort_limit:
+        monkeypatch.setattr(moe, "_SORT_LIMIT", 64)
+    fn, args, options, live = _dropless_case(mode, score)
+    run = jax.jit(lambda live, *a: fn(*a, live=live, **options))
+    whole, whole_loads = jax.jit(lambda *a: fn(*a, **options))(*args)
+    out, loads = run(live, *args)
+    keep = np.asarray(live)
+    assert 5 < keep.sum() < 35
+    assert np.array_equal(np.asarray(out)[keep], np.asarray(whole)[keep])
+    assert float(np.max(np.abs(np.asarray(whole)[keep]))) > 0.05
+    assert not np.asarray(out)[~keep].any()
+    x, logits, *weights = args
+    _, alone = jax.jit(lambda *a: fn(*a, **options))(
+        x[keep], logits[keep], *weights)
+    assert np.array_equal(np.asarray(loads), np.asarray(alone))
+    assert loads.shape == whole_loads.shape
+    assert int(loads.sum()) < int(whole_loads.sum())
+    # every token live is the call without a mask; none is zeros and no load
+    everyone, every_loads = run(jnp.ones_like(live), *args)
+    assert np.array_equal(np.asarray(everyone), np.asarray(whole))
+    assert np.array_equal(np.asarray(every_loads), np.asarray(whole_loads))
+    nobody, no_loads = run(jnp.zeros_like(live), *args)
+    assert not np.asarray(nobody).any() and not np.asarray(no_loads).any()
+
+
+@pytest.mark.parametrize("mode,digest", [
+    ("plain",
+     "a8d265bc256e0516ad2c09a1c084a8661db0da9313f4f69e078c4a23b6ea90a0"),
+    ("held_group_limit",
+     "6b451d60bebc96f2233ad452e257a57d9ba2b2ad633b93c39030087a7b92c676"),
+])
+def test_dropless_experts_without_a_mask_traces_what_it_traced(mode, digest):
+    """``live=None`` is the function PR 59 had, equation for equation (a
+    jaxpr's text holds no names): training, ``Transformer.apply`` and
+    every caller without a mask keep their programs.  Where a later change
+    means to alter them, print the text on both sides, read the difference
+    and replace the digests."""
+    import hashlib
+
+    fn, args, options, _ = _dropless_case(mode, "sigmoid")
+    text = str(jax.make_jaxpr(lambda *a: fn(*a, live=None, **options))(
+        *args))
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
